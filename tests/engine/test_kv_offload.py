@@ -261,17 +261,21 @@ def test_offload_metrics_render_and_validate(tiny_model):
     assert "vllm_omni_tpu_kv_parked_tokens_total" in text
 
 
-def test_policy_auto_vetoes_tiny_runs(tiny_model):
-    """mode=auto on this model: parking a handful of tokens over a
-    0.15 GB/s tunnel with fixed overhead loses to recompute, so the
-    scheduler degrades to the classic recompute path."""
+def test_policy_auto_refuses_until_told_the_rates(tiny_model):
+    """mode=auto assumes no host bandwidth or prefill rate: an engine
+    asking for it (the config default) is refused with the way out, and
+    a policy TOLD slow-link rates vetoes tiny runs — parking a handful
+    of tokens with fixed overhead loses to recompute."""
+    from vllm_omni_tpu.kvcache import OffloadPolicy
+
     params, cfg = tiny_model
-    eng = _offload_engine(params, cfg, num_pages=6,
-                          enable_prefix_caching=False,
-                          kv_offload_policy="auto")
-    prompts = [[1, 5, 9, 2, 7, 3, 8, 4], [2, 6, 1, 7, 3, 9, 5, 8]]
-    want = _toks(_engine(params, cfg).generate(
-        [list(p) for p in prompts], GREEDY))
-    got = _toks(eng.generate([list(p) for p in prompts], GREEDY))
-    assert got == want
-    assert eng.scheduler.kv.parked_tokens == 0
+    with pytest.raises(ValueError, match="kv_offload_policy"):
+        _offload_engine(params, cfg, num_pages=6,
+                        kv_offload_policy="auto")
+    told = OffloadPolicy.for_model(
+        cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, 4, mode="auto",
+        host_bandwidth_bytes_s=1e8, prefill_tokens_per_s=2000.0)
+    assert not told.worth_offloading(8)
+    assert told.worth_offloading(100_000) == (
+        2 * told.transfer_seconds(100_000)
+        < told.recompute_seconds(100_000))

@@ -72,6 +72,28 @@ def test_rejects_unknown_kv_cache_dtype(tiny_model):
         _engine(params, cfg, kv_cache_dtype="fp4")
 
 
+def test_untileable_geometry_refused_on_a_kernel_platform(monkeypatch):
+    """On a platform that compiles the paged kernels (stubbed: the CPU
+    lane interprets them), an engine whose pages the kernels cannot tile
+    is refused at construction — int8 or dense — instead of being served
+    through the gather reference.  The CPU lane keeps constructing it."""
+    from vllm_omni_tpu.ops import _dispatch
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, hidden_size=128, num_layers=1, num_heads=1,
+        num_kv_heads=1, head_dim=128, intermediate_size=128)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    _engine(params, cfg, page_size=12, kv_cache_dtype="int8")
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    for dtype in ("int8", "auto"):
+        with pytest.raises(ValueError, match="page_size 12 % 8"):
+            _engine(params, cfg, page_size=12, kv_cache_dtype=dtype)
+    tiny = tfm.TransformerConfig.tiny(vocab_size=64)
+    with pytest.raises(ValueError, match="head_dim 16 % 128"):
+        _engine(tfm.init_params(jax.random.PRNGKey(0), tiny, jnp.float32),
+                tiny, page_size=16)
+
+
 # ----------------------------------------------------------- capacity
 def test_int8_pool_holds_1p8x_the_bf16_pages(tiny_model):
     """Same HBM budget (the bf16 config's num_pages worth of bytes):

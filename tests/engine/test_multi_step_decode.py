@@ -58,7 +58,7 @@ def test_multi_step_knob_is_accepted_noop(tiny_model):
 def test_warmup_precompiles_all_traffic_shapes(tiny_model):
     """engine.warmup() => serving traffic hits zero new executables on
     the unified/decode paths (a mid-traffic XLA compile stalls every
-    in-flight request 20-40 s on a remote chip).  The warmup surface is
+    in-flight request for its whole duration).  The warmup surface is
     the 1-D token-bucket line plus the decode buckets × {plain,
     logprobs} — the (batch, seq) grid of the deleted split executor is
     gone."""

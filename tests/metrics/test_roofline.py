@@ -134,6 +134,10 @@ def test_live_engine_gauges_render_and_bound():
     eng = LLMEngine(params, cfg, EngineConfig(
         num_pages=64, page_size=4, max_model_len=128, max_num_seqs=4,
         dtype=jnp.float32))
+    # no peak is assumed for the CPU (the gauges would read 0 =
+    # unknown); tell the tracker some so the live wiring has values
+    assert eng.roofline.peak_flops == eng.roofline.peak_bytes == 0.0
+    eng.roofline.peak_flops, eng.roofline.peak_bytes = 0.5e12, 50e9
     eng.generate([[1, 2, 3, 4, 5, 6, 7, 8]] * 2,
                  SamplingParams(temperature=0.0, max_tokens=6))
     snap = eng.metrics_snapshot()

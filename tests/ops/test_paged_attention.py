@@ -92,3 +92,24 @@ def test_paged_decode_empty_context(rng):
         use_pallas=True,
     )
     np.testing.assert_allclose(np.asarray(out), 0.0)
+
+
+def test_auto_dispatch_counts_native_fallbacks(monkeypatch):
+    """On a platform that compiles the kernels, a shape that misses a
+    tiling gate is counted (and takes the XLA path); on the interpreted
+    CPU lane nothing is counted."""
+    from vllm_omni_tpu.ops import _dispatch
+    from vllm_omni_tpu.ops.paged_attention import paged_tiling_reason
+
+    assert paged_tiling_reason(128, 16) == ""
+    reason = paged_tiling_reason(16, 4)
+    assert "head_dim 16" in reason
+    monkeypatch.setattr(_dispatch, "native_fallbacks",
+                        type(_dispatch.native_fallbacks)())
+    assert not _dispatch.auto_use_pallas("paged_attention", reason)
+    assert not _dispatch.native_fallbacks
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    assert _dispatch.auto_use_pallas("paged_attention")
+    assert not _dispatch.auto_use_pallas("paged_attention", reason)
+    assert not _dispatch.auto_use_pallas("paged_attention", reason)
+    assert _dispatch.native_fallbacks["paged_attention", reason] == 2

@@ -20,7 +20,8 @@ def test_platform_surface():
     assert p.name in ("cpu", "tpu")
     assert p.device_count() >= 1
     assert isinstance(p.device_kind(), str)
-    assert p.peak_tflops_bf16() > 0
+    # no peak is assumed for the CPU; a TPU's comes from the table
+    assert p.peak_tflops_bf16() > 0 or p.name == "cpu"
     assert os.path.isdir(p.default_stage_config_dir())
     # every in-tree stage YAML is discoverable through the platform
     yamls = os.listdir(p.default_stage_config_dir())
@@ -47,10 +48,43 @@ def test_tpu_platform_peak_table():
         def device_kind(self):
             return "TPU v6e"
 
+    class FakeUnlisted(TpuPlatform):
+        def device_kind(self):
+            return "TPU v9 hyper"
+
     assert FakeV5e().peak_tflops_bf16() == 197.0
     assert FakeV6().peak_tflops_bf16() == 918.0
-    env = FakeV5e().stage_device_env("0,1")
-    assert env["TPU_VISIBLE_CHIPS"] == "0,1"
+    # an unlisted generation is an error, never the v5e row
+    for peak in (FakeUnlisted().peak_tflops_bf16,
+                 FakeUnlisted().peak_hbm_gbps):
+        with pytest.raises(ValueError, match="v9 hyper"):
+            peak()
+    # one chip per worker is scoped; a larger subset is refused
+    env = FakeV5e().stage_device_env("2")
+    assert env["TPU_VISIBLE_CHIPS"] == "2"
+    assert env["TPU_PROCESS_PORT"] != FakeV5e().stage_device_env(
+        "3")["TPU_PROCESS_PORT"]
+    assert FakeV5e().stage_device_env("all") == {}
+    with pytest.raises(ValueError, match="multi-chip subset"):
+        FakeV5e().stage_device_env("0,1")
+
+
+def test_cpu_must_be_asked_for():
+    """Detection never drifts onto the CPU: a process whose JAX fell back
+    to the cpu backend without JAX_PLATFORMS=cpu (libtpu could not take
+    the chip) is refused, not served from the XLA references."""
+    import jax
+
+    from vllm_omni_tpu import platforms
+
+    assert jax.config.jax_platforms == "cpu"  # tests/conftest.py asked
+    assert platforms._detect().name == "cpu"
+    try:
+        jax.config.update("jax_platforms", None)
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            platforms._detect()
+    finally:
+        jax.config.update("jax_platforms", "cpu")
 
 
 def test_env_plugin_loading(tmp_path, monkeypatch):
@@ -119,6 +153,49 @@ def test_bench_flop_model_sanity():
                              txt_len=cfg.max_text_len,
                              cfg_scale_doubling=True)
     assert f2 > 3.5 * f
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    """The chip check fails at its device gate on the CPU: non-zero exit,
+    a message that no TPU was found, and no result line."""
+    import subprocess
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=root, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True)
+    assert proc.returncode != 0
+    assert "no TPU found" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_bench_has_no_unresolved_global_name():
+    """bench.py ran for nine PRs with ``max_tokens=(waves + 2) * w`` —
+    ``w`` defined nowhere — because the NameError was caught and written
+    into the JSON under exit 0.  Every global a bench function loads
+    must exist at module level or in builtins."""
+    import builtins
+    import dis
+    import types
+
+    import bench
+
+    missing = set()
+
+    def walk(code):
+        for ins in dis.get_instructions(code):
+            if (ins.opname in ("LOAD_GLOBAL", "LOAD_NAME")
+                    and ins.argval not in vars(bench)
+                    and not hasattr(builtins, ins.argval)):
+                missing.add((code.co_name, ins.argval))
+        for const in code.co_consts:
+            if isinstance(const, types.CodeType):
+                walk(const)
+
+    with open(bench.__file__) as f:
+        walk(compile(f.read(), bench.__file__, "exec"))
+    assert not missing
 
 
 # ----------------------------------------------------------------- SD3

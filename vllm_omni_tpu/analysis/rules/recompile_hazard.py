@@ -2,8 +2,8 @@
 cache keys blind to dispatch variants, and unwarmed executables.
 
 XLA compiles one executable per input signature and a mid-traffic
-cache miss stalls every in-flight request for the full compile
-(20-40 s per shape on a remote-attached chip — docs/performance.md).
+cache miss stalls every in-flight request for the full compile (tens
+of seconds per executable at a 152k vocabulary — docs/performance.md).
 The whole per-shape discipline therefore hangs on three invariants at
 every ``_run_jit(kind, shape_key, thunk)`` dispatch site
 (``RECOMPILE`` manifest, analysis/manifest.py):

@@ -289,8 +289,8 @@ def run_denoise_loop(cache_cfg, schedule, eval_velocity, latents, num_steps,
     multistep, scheduler.multistep_step — fewer steps for the same
     quality; reference: scheduling_flow_unipc_multistep.py:741).
 
-    Chunked host loops (remote-attached chips run K steps per device
-    call on a schedule rolled to the chunk start) pass ``step_offset``
+    Chunked host loops (``step_loop="host"``: K steps per device call
+    on a schedule rolled to the chunk start) pass ``step_offset``
     (global index of local step 0), ``total_steps`` (the full run
     length, for the warmup/tail window), and thread the cache carry
     through ``carry_in``/``return_carry`` — the loop indexes the

@@ -57,7 +57,7 @@ def _add_common(p: argparse.ArgumentParser):
                      choices=("none", "int8"),
                      help="cold-path payload storage: none keeps "
                           "restores bit-exact, int8 halves the bytes "
-                          "over the host tunnel")
+                          "moved between host and HBM")
     eng.add_argument("--kv-cache-dtype", default=None,
                      choices=("auto", "int8", "bf16"),
                      help="HBM-RESIDENT paged-KV layout: int8 stores "

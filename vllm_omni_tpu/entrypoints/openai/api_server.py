@@ -462,15 +462,20 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
         surface); ``/metrics?format=json`` keeps the JSON summary."""
         omni = getattr(self.state.omni, "_omni", self.state.omni)
         # device memory snapshot (per-process accounting analogue,
-        # reference: worker/gpu_memory_utils.py NVML probes)
-        from vllm_omni_tpu.platforms import current_platform
+        # reference: worker/gpu_memory_utils.py NVML probes) — only when
+        # this process runs a stage itself: the parent of an all-process
+        # pipeline must never touch JAX, or it would take the chip its
+        # workers hold (one process per chip)
+        device = None
+        if any(not s.config.runtime.process for s in omni.stages):
+            from vllm_omni_tpu.platforms import current_platform
 
-        p = current_platform()
-        device = {
-            "platform": p.name,
-            "kind": p.device_kind(),
-            "hbm_bytes": p.hbm_bytes(),
-        }
+            p = current_platform()
+            device = {
+                "platform": p.name,
+                "kind": p.device_kind(),
+                "hbm_bytes": p.hbm_bytes(),
+            }
         if query.get("format", ["prometheus"])[0] == "json":
             summary = (omni.stats_summary()
                        if hasattr(omni, "stats_summary")

@@ -75,8 +75,8 @@ _register("OMNI_TPU_DUMP_COOLDOWN_S", "30", float)
 # Stall-watchdog deadline in seconds (introspection/watchdog.py): a
 # busy engine making no step progress for this long — with no XLA
 # compile in flight — trips the watchdog (dump + /health 503).
-# 0 disables the monitor thread (the default: compiles on remote chips
-# legitimately stall for tens of seconds, so the deadline is a
+# 0 disables the monitor thread (the default: a compile legitimately
+# stalls the engine for tens of seconds, so the deadline is a
 # deployment decision).
 _register("OMNI_TPU_WATCHDOG_S", "0", float)
 

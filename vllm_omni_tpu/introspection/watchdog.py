@@ -12,8 +12,8 @@ Two kinds of "no progress" look identical from outside and must not be
 conflated (docs/debugging.md):
 
 - **XLA compile stalls** — a shape-cache miss mid-traffic blocks every
-  in-flight request for a full compile (20-40 s on a remote-attached
-  chip).  The runner's PR 5 compile telemetry distinguishes them: a
+  in-flight request for a full compile (tens of seconds).  The
+  runner's PR 5 compile telemetry distinguishes them: a
   fresh compile in flight (``compile_stats["in_flight"]``) or the
   ``jit_compiles_total`` counter advancing since the stall began means
   the device is compiling, not hung.  Those windows EXTEND the deadline

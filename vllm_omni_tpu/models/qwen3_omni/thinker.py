@@ -20,11 +20,12 @@ import jax.numpy as jnp
 
 from vllm_omni_tpu.models.common.transformer import TransformerConfig, init_params
 
-# Real Qwen3-Omni-30B-A3B thinker geometry (for weight loading later):
-# hidden 2048, 48 layers, 32 heads / 4 kv, head_dim 128, 128 experts top-8,
-# moe_intermediate 768 (HF config of Qwen3-Omni-MoE thinker text model).
+# Published Qwen3-Omni-30B-A3B-Instruct thinker geometry
+# (thinker_config.text_config of the HF config.json): hidden 2048, 48
+# layers, 32 heads / 4 kv, head_dim 128, 128 experts top-8,
+# moe_intermediate 768, vocabulary 152064.
 QWEN3_OMNI_THINKER_30B = TransformerConfig(
-    vocab_size=151936,
+    vocab_size=152064,
     hidden_size=2048,
     num_layers=48,
     num_heads=32,

@@ -325,8 +325,8 @@ def walk_blocks(blocks, cfg: QwenImageDiTConfig, img, txt, temb_act,
     lax.scan over a DICT stacked on a leading layer axis.
 
     The scan form keeps the compiled program at ONE block's HLO instead
-    of L copies — at the real 60-layer geometry the unrolled program is
-    large enough to break remote-compile services outright — and pins
+    of L copies — at the real 60-layer geometry the unrolled program
+    takes L times as long to compile — and pins
     quantized-weight dequant inside the loop body where LICM can't hoist
     L dequantized bf16 blocks out of the step loop (= 41 GB).  Same
     math, identical per-block MXU shapes."""

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_omni_tpu.ops._dispatch import interpret_flag
+from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 
 _NEG_INF = -1e30
 
@@ -490,16 +490,14 @@ def flash_attention(
     (``_auto_blocks``); pass explicit sizes to pin the tiling.
     """
     if use_pallas is None:
-        from vllm_omni_tpu.ops._dispatch import pallas_mode
-
-        use_pallas = pallas_mode() == "native"
         # Mosaic tiling: a KV shorter than one sublane tile makes the
         # mask/kv block shapes unsatisfiable ((1, 8) block over a (1, 5)
         # array). Sub-tile shapes gain nothing from the kernel — route
         # them to the blockwise XLA path. Explicit use_pallas=True is
         # honored as-is (kernel tests), failing loudly if unsupported.
-        if k.shape[1] < 8:
-            use_pallas = False
+        use_pallas = auto_use_pallas(
+            "flash_attention",
+            f"kv length {k.shape[1]} < 8" if k.shape[1] < 8 else "")
     if block_q is None or block_k is None:
         if use_pallas:
             abq, abk = _auto_blocks(q.shape[1], k.shape[1], q.shape[3],

@@ -85,15 +85,13 @@ def auto_ragged_blocks(
     decode_heavy: bool = True,
     vmem_cap_bytes: int = RAGGED_VMEM_CAP,
     quantized: bool = False,
-    num_pages: int = 0,
 ) -> tuple[int, int]:
     """(token_block, dma_slots) for the ragged paged-attention kernel.
 
-    The search runs PER LAYOUT (the lru key includes ``quantized`` and
-    the scale-array width ``num_pages``): the int8 layout halves the
-    page DMA buffers (kv_itemsize is the int8 data's) but adds the
-    resident per-(head, page) f32 scale rows pinned in VMEM plus an f32
-    dequant staging copy of the in-flight page — a deeper pipeline may
+    The search runs PER LAYOUT (the lru key includes ``quantized``): the
+    int8 layout halves the page DMA buffers (kv_itemsize is the int8
+    data's) but adds an f32 dequant staging copy of the in-flight page
+    (its per-page scales live in SMEM, not VMEM) — a deeper pipeline may
     fit quantized where bf16 took 2 slots, and the warmup log shows
     which choice each layout got.
 
@@ -121,10 +119,8 @@ def auto_ragged_blocks(
         # f32 score block per page
         fixed += rows * page_size * 4
         if quantized:
-            # k/v per-(head, page) f32 scale rows pinned in VMEM for
-            # the whole launch + the f32 dequant staging copy of the
-            # page being consumed (k and v)
-            fixed += 2 * max(num_pages, 0) * 4
+            # the f32 dequant staging copy of the page being consumed
+            # (k and v)
             fixed += 2 * page_size * head_dim * 4
         for slots in (4, 3, 2):
             kv = 2 * slots * page_size * head_dim * kv_itemsize

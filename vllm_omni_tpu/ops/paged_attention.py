@@ -36,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from vllm_omni_tpu.kvcache.quant import QMAX, SCALE_EPS
-from vllm_omni_tpu.ops._dispatch import interpret_flag
+from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 
 _NEG_INF = -1e30
 
@@ -207,8 +207,9 @@ def _paged_decode_kernel(
     q_ref,  # [1, 1, group_p, D] VMEM
     k_hbm,  # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
     v_hbm,
-    # quantized only: k_sc_ref/v_sc_ref [1, P] VMEM per-page scales,
-    # then outputs o_ref [1, 1, group_p, D] and scratch
+    # quantized only: k_sc_ref/v_sc_ref [1, max_pages] SMEM — this
+    # sequence's per-page scales in block-table order — then outputs
+    # o_ref [1, 1, group_p, D] and scratch
     # k_buf/v_buf [2, page, D], sems [2, 2], acc_scr [group_p, D]
     *refs,
     page_size: int,
@@ -259,10 +260,9 @@ def _paged_decode_kernel(
             v = v_buf[slot].astype(jnp.float32)
             if quantized:
                 # dequantize in-register: one f32 scale per (head, page),
-                # fetched alongside the int8 page bytes
-                page_id = block_tables_ref[b, p_idx]
-                k = k * k_sc_ref[0, page_id]
-                v = v * v_sc_ref[0, page_id]
+                # a scalar read at the page's table position
+                k = k * k_sc_ref[0, p_idx]
+                v = v * v_sc_ref[0, p_idx]
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
             pos = p_idx * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
@@ -294,6 +294,42 @@ def _paged_decode_kernel(
         o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
 
 
+def paged_tiling_reason(head_dim: int, page_size: int) -> str:
+    """Why Mosaic cannot tile the paged kernels' (page_size, head_dim)
+    page buffers at this geometry ("" when it can): the lane dim must be
+    a multiple of 128 and the sublane dim of 8, in either layout (bf16
+    and int8 page tiles both compiled and matched the reference at page
+    16 on a v5e, CHANGES.md PR 21).  The engine refuses such a geometry
+    at construction on a platform that compiles the kernels; the ops
+    count it (``_dispatch.auto_use_pallas``)."""
+    if head_dim % 128:
+        return f"head_dim {head_dim} % 128 != 0"
+    if page_size % 8:
+        return f"page_size {page_size} % 8 != 0"
+    return ""
+
+
+def _table_order_scales(scale, page_tables):
+    """[Hkv, P] per-page scales -> [Hkv, S, 1, max_pages] in each
+    sequence's page-table order.  Mosaic can neither tile a (1, P) block
+    of an [Hkv, P] array, nor read a VMEM scalar at a dynamic lane
+    index, nor broadcast a (1, 1) vector over a page tile — so the few
+    scales one sequence needs travel as an SMEM block and the kernels
+    read them as true scalars."""
+    return scale[:, page_tables][:, :, None, :]
+
+
+def _page_scale_spec(max_pages: int, head_seq):
+    """SMEM block over ``_table_order_scales`` output: the (1, max_pages)
+    row of the (kv-head, sequence) pair ``head_seq(*grid_idx,
+    *prefetch_refs)`` names."""
+    return pl.BlockSpec(
+        (None, None, 1, max_pages),
+        lambda *a: (*head_seq(*a), 0, 0),
+        memory_space=pltpu.SMEM,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "use_pallas"))
 def _paged_attention(
     q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas
@@ -302,7 +338,7 @@ def _paged_attention(
     quantized = isinstance(k_cache, tuple)
     k_data, k_scale = k_cache if quantized else (k_cache, None)
     v_data, v_scale = v_cache if quantized else (v_cache, None)
-    hkv, num_pages_total, page_size, _ = k_data.shape
+    hkv, _, page_size, _ = k_data.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if not use_pallas:
@@ -333,14 +369,10 @@ def _paged_attention(
         v_data,
     ]
     if quantized:
-        # per-page scales ride in VMEM, one (1, P) row per kv head
-        sc_spec = pl.BlockSpec(
-            (1, num_pages_total),
-            lambda b_, h_, *_: (h_, 0),
-            memory_space=pltpu.VMEM,
-        )
+        sc_spec = _page_scale_spec(max_pages, lambda b_, h_, *_: (h_, b_))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [_table_order_scales(k_scale, block_tables),
+                     _table_order_scales(v_scale, block_tables)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv),
@@ -380,22 +412,13 @@ def paged_attention(
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
 ):
-    """Single-token-per-sequence paged decode attention."""
+    """Single-token-per-sequence paged decode attention.  An explicit
+    ``use_pallas=True`` is honored as-is (kernel tests rely on it;
+    unsupported shapes then fail loudly at compile)."""
     if use_pallas is None:
-        from vllm_omni_tpu.ops._dispatch import pallas_mode
-
-        use_pallas = pallas_mode() == "native"
-        # Mosaic tiling constraints: page tiles are (page_size, head_dim)
-        # VMEM buffers → need lane dim % 128 and sublane dim % 8 (f32),
-        # % 32 for int8 page tiles (docs/performance.md capacity notes).
-        # Auto-dispatch routes tiny/test shapes to the XLA ref path;
-        # production shapes (D=128, page_size>=16) take the kernel.  An
-        # explicit use_pallas=True is honored as-is (kernel tests rely on
-        # it; unsupported shapes then fail loudly at compile).
-        page_size = cache_shape(k_cache)[2]
-        sublane = 32 if cache_is_quantized(k_cache) else 8
-        if q.shape[-1] % 128 != 0 or page_size % sublane != 0:
-            use_pallas = False
+        use_pallas = auto_use_pallas(
+            "paged_attention",
+            paged_tiling_reason(q.shape[-1], cache_shape(k_cache)[2]))
     return _paged_attention(
         q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas
     )

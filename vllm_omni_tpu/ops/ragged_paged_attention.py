@@ -52,13 +52,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_omni_tpu.ops._dispatch import interpret_flag
+from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
 from vllm_omni_tpu.ops.paged_attention import (
+    _page_scale_spec,
+    _table_order_scales,
     cache_data,
     cache_is_quantized,
     cache_shape,
     gather_pages,
+    paged_tiling_reason,
 )
 
 _NEG_INF = -1e30
@@ -153,8 +156,9 @@ def _ragged_kernel(
     q_ref,        # [1, 1, token_block * group, D] VMEM
     k_hbm,        # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
     v_hbm,
-    # quantized only: k_sc_ref/v_sc_ref [1, P] VMEM per-page scales,
-    # then outputs o_ref [1, 1, token_block * group, D] and scratch
+    # quantized only: k_sc_ref/v_sc_ref [1, max_pages] SMEM — the owning
+    # sequence's per-page scales in page-table order — then outputs
+    # o_ref [1, 1, token_block * group, D] and scratch
     # k_buf/v_buf [dma_slots, page, D], sems [dma_slots, 2],
     # acc_scr [token_block * group, D]
     *refs,
@@ -241,10 +245,10 @@ def _ragged_kernel(
             v = v_buf[slot].astype(jnp.float32)
             if quantized:
                 # dequantize in-register: the page's int8 bytes were
-                # DMAed; its (head, page) f32 scale rides a VMEM row
-                page_id = tables_ref[i_safe, p_idx]
-                k = k * k_sc_ref[0, page_id]
-                v = v * v_sc_ref[0, page_id]
+                # DMAed; its (head, page) f32 scale is a scalar read at
+                # the page's table position
+                k = k * k_sc_ref[0, p_idx]
+                v = v * v_sc_ref[0, p_idx]
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
             k_pos = p_idx * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
@@ -290,7 +294,7 @@ def _ragged_attention(
     quantized = isinstance(k_cache, tuple)
     k_data, k_scale = k_cache if quantized else (k_cache, None)
     v_data, v_scale = v_cache if quantized else (v_cache, None)
-    hkv, num_pages_total, page_size, _ = k_data.shape
+    hkv, _, page_size, _ = k_data.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if not use_pallas:
@@ -346,12 +350,15 @@ def _ragged_attention(
         v_data,
     ]
     if quantized:
-        # per-page scales ride in VMEM, one (1, P) row per kv head
-        sc_spec = pl.BlockSpec((1, num_pages_total),
-                               lambda kvh, j, *_: (kvh, 0),
-                               memory_space=pltpu.VMEM)
+        # the block's owning sequence comes from the prefetched lookup
+        # (clamped like the kernel's own SMEM reads; padding blocks
+        # never touch the scales)
+        sc_spec = _page_scale_spec(
+            page_tables.shape[1],
+            lambda kvh, j, bs, *_: (kvh, jnp.maximum(bs[j], 0)))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [_table_order_scales(k_scale, page_tables),
+                     _table_order_scales(v_scale, page_tables)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(hkv, nb),
@@ -402,32 +409,27 @@ def ragged_paged_attention(
     """Mixed prefill+decode paged attention over a token-packed batch.
 
     See the module docstring for the layout/metadata contract.  Auto
-    dispatch mirrors ``paged_attention``: the Pallas kernel needs
-    lane-dim ``D % 128 == 0``, sublane ``page_size % 8 == 0``, and a
-    ``token_block``-aligned packed length; anything else (CPU tests,
-    tiny shapes) takes the XLA reference.  An explicit
-    ``use_pallas=True`` is honored as-is and fails loudly if
-    unsupported.  ``dma_slots`` (page-DMA pipeline depth) defaults to
-    the per-shape ``auto_ragged_blocks`` choice."""
+    dispatch mirrors ``paged_attention``: the Pallas kernel needs the
+    geometry ``paged_tiling_reason`` accepts and a ``token_block``-aligned
+    packed length; anything else (CPU tests, tiny shapes) takes the XLA
+    reference.  An explicit ``use_pallas=True`` is honored as-is and
+    fails loudly if unsupported.  ``dma_slots`` (page-DMA pipeline depth)
+    defaults to the per-shape ``auto_ragged_blocks`` choice."""
     quantized = cache_is_quantized(k_cache)
     k_data = cache_data(k_cache)
     if use_pallas is None:
-        from vllm_omni_tpu.ops._dispatch import pallas_mode
-
-        use_pallas = pallas_mode() == "native"
-        # int8 page tiles need sublane % 32 (vs % 8 for bf16/f32)
-        sublane = 32 if quantized else 8
-        if (q.shape[-1] % 128 != 0 or k_data.shape[2] % sublane != 0
-                or q.shape[0] % token_block != 0):
-            use_pallas = False
+        reason = paged_tiling_reason(q.shape[-1], k_data.shape[2])
+        if not reason and q.shape[0] % token_block:
+            reason = (f"packed length {q.shape[0]} % token_block "
+                      f"{token_block} != 0")
+        use_pallas = auto_use_pallas("ragged_paged_attention", reason)
     if dma_slots is None:
         _, dma_slots = auto_ragged_blocks(
             head_dim=q.shape[-1], page_size=k_data.shape[2],
             group=q.shape[1] // k_data.shape[0],
             kv_itemsize=k_data.dtype.itemsize,
             q_itemsize=q.dtype.itemsize,
-            quantized=quantized,
-            num_pages=k_data.shape[1])
+            quantized=quantized)
     num_seqs = jnp.asarray(num_seqs, jnp.int32)
     return _ragged_attention(
         q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,

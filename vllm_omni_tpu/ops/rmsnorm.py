@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_omni_tpu.ops._dispatch import interpret_flag
+from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 
 
 def rms_norm_ref(
@@ -110,9 +110,7 @@ def rms_norm(
     """RMSNorm over the last axis. Any leading shape; optionally fused
     residual add (returns (normed, new_residual))."""
     if use_pallas is None:
-        from vllm_omni_tpu.ops._dispatch import pallas_mode
-
-        use_pallas = pallas_mode() == "native"
+        use_pallas = auto_use_pallas("rms_norm")
     lead = x.shape[:-1]
     h = x.shape[-1]
     x2 = x.reshape(-1, h)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_omni_tpu.ops._dispatch import interpret_flag
+from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 
 
 def compute_rope_freqs(
@@ -85,7 +85,10 @@ def apply_rope_ref(
     s = sin[:, None, :]
     o1 = x1 * c - x2 * s
     o2 = x2 * c + x1 * s
-    return jnp.concatenate([o1, o2], axis=-1).astype(x.dtype)
+    # stack + reshape, not concatenate on the last axis: joining two
+    # 64-wide halves there aborts the XLA TPU compiler (libtpu 0.0.34,
+    # fusion_emitter "IsFusibleUnalignedDUS" check) at [256, 32, 128]
+    return jnp.stack([o1, o2], axis=-2).reshape(x.shape).astype(x.dtype)
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, dim: int):
@@ -105,8 +108,7 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, dim: int):
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def _apply_rope(x, cos, sin, use_pallas):
     t, h, d = x.shape
-    # Pallas pays off only for MXU-aligned head dims; XLA fuses the rest.
-    if not use_pallas or d % 64 != 0:
+    if not use_pallas:
         return apply_rope_ref(x, cos, sin)
     # Size the token block from a VMEM budget: the kernel holds several
     # fp32 intermediates of the block shape, so keep one copy ~<=1MB.
@@ -138,7 +140,9 @@ def apply_rope(
 ) -> jax.Array:
     """Apply rotary embedding to [T, H, D] activations."""
     if use_pallas is None:
-        from vllm_omni_tpu.ops._dispatch import pallas_mode
-
-        use_pallas = pallas_mode() == "native"
+        # Pallas pays off only for MXU-aligned head dims; XLA fuses the
+        # rest (elementwise, no gather)
+        d = x.shape[-1]
+        use_pallas = auto_use_pallas(
+            "apply_rope", f"head_dim {d} % 64 != 0" if d % 64 else "")
     return _apply_rope(x, cos, sin, use_pallas)

@@ -23,9 +23,10 @@ class TpuPlatform(OmniPlatform):
             return override
         return "pallas_flash"
 
-    # (peak dense bf16 TFLOP/s, peak HBM GB/s) per chip generation —
-    # public spec sheet numbers; MFU / MBU denominators.  One table so a
-    # new generation cannot land in one metric and not the other.
+    # (peak dense bf16 TFLOP/s, peak HBM GB/s) per chip generation, from
+    # Google Cloud's published TPU specifications; MFU / MBU
+    # denominators.  One table so a new generation cannot land in one
+    # metric and not the other.
     _PEAK_TABLE = {
         "v4": (275.0, 1228.0),
         "v5 lite": (197.0, 819.0), "v5e": (197.0, 819.0),
@@ -38,7 +39,9 @@ class TpuPlatform(OmniPlatform):
         for k, v in self._PEAK_TABLE.items():
             if k in kind:
                 return v
-        return (197.0, 819.0)  # unlisted generation: v5e floor
+        raise ValueError(
+            f"device_kind {self.device_kind()!r} is not in TpuPlatform."
+            "_PEAK_TABLE — add its published peaks; none is assumed")
 
     def peak_tflops_bf16(self) -> float:
         return self._peaks()[0]
@@ -47,12 +50,25 @@ class TpuPlatform(OmniPlatform):
         return self._peaks()[1]
 
     def stage_device_env(self, devices: str = "all") -> dict:
+        """libtpu chip scoping for a spawned stage worker.  "all": the
+        worker takes every chip — no scoping; its parent must never
+        touch JAX (one process per chip).  One chip id: that chip as a
+        one-process slice of its own, on a port of its own, with
+        libtpu's one-load-per-host guard lifted so sibling workers can
+        each hold their chip (shown to work on a four-chip v5e host with
+        libtpu 0.0.34, CHANGES.md PR 21).  A larger subset needs
+        per-topology bounds nobody has run: refused, not guessed — give
+        such a stage every chip and ``tensor_parallel_size`` instead."""
         if devices in ("", "all"):
             return {}
-        # libtpu chip-scoping recipe (as used for single-host
-        # multi-process): visible chips + process bounds + chips-per-
-        # process bounds matching the subset size
-        n = len([d for d in devices.split(",") if d])
-        return {"TPU_VISIBLE_CHIPS": devices,
+        chips = [d for d in devices.split(",") if d]
+        if len(chips) != 1:
+            raise ValueError(
+                f"stage devices {devices!r}: scoping a process to a "
+                "multi-chip subset is not supported on TPU (only \"all\" "
+                "or one chip id)")
+        return {"TPU_VISIBLE_CHIPS": chips[0],
                 "TPU_PROCESS_BOUNDS": "1,1,1",
-                "TPU_CHIPS_PER_PROCESS_BOUNDS": f"{n},1,1"}
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_PORT": str(8476 + int(chips[0])),
+                "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}

@@ -1,7 +1,7 @@
 """Per-request distributed tracing across pipeline stages.
 
-The gap VERDICT.md:116 names: the repo had jax.profiler fan-out and
-aggregate stats jsonl but no request-trace propagation — once stages run
+The repo had jax.profiler fan-out and aggregate stats jsonl but no
+request-trace propagation — once stages run
 in separate processes nobody can answer "where did request X spend its
 900 ms".  This module is the span layer underneath:
 
