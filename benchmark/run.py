@@ -1,0 +1,255 @@
+"""One run of one cell of BENCHMARK.json.
+
+    python3 benchmark/run.py --workload NAME --seed N --seconds S --trace 0|1
+
+Set-up (weights from the seed, warm-up of every shape, the reference check,
+the lead-in) is timed as ``setup_s``; then the window is measured for
+``--seconds``.  The last line of standard output is one JSON object.  A
+cell never runs on a CPU: without a TPU, or with fewer chips than the cell
+asks for, the exit code is not 0 and no result is printed.
+"""
+
+from __future__ import annotations
+
+import time
+
+_T_START = time.monotonic()  # before the heavy imports: setup_s counts them
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+from benchmark import e2e, readers, spec, trace  # noqa: E402
+
+#: build outputs of the benchmark, at a fixed path inside the checkout (the
+#: path is part of the compile cache's key); listed in .gitignore
+CACHE = ROOT / ".benchmark_cache"
+#: seconds of the window that a ``--trace 1`` run traces, and where they
+#: start: traces are large and tracing slows the host
+TRACE_AFTER_S, TRACE_SECONDS = 4.0, 3.0
+
+
+def say(msg: str) -> None:
+    print(f"[benchmark {time.monotonic() - _T_START:7.1f}s] {msg}",
+          flush=True)
+
+
+def sleep_until(t: float) -> None:
+    while (wait := t - time.monotonic()) > 0:
+        time.sleep(min(wait, 0.5))
+
+
+def device_gate(chips: int, allow_cpu: bool) -> dict:
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu" and not allow_cpu:
+        raise SystemExit(f"benchmark: no TPU: JAX found "
+                         f"{devices[0].platform!r}; a cell never runs there")
+    if len(devices) < chips:
+        raise SystemExit(f"benchmark: the cell asks for {chips} chips, JAX "
+                         f"found {len(devices)}")
+    if devices[0].platform == "tpu":
+        trace.peaks_of(devices[0].device_kind)  # an unlisted kind is an error
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
+def prepare_jax() -> None:
+    """Before JAX and the program are imported: the persistent compile
+    cache at a fixed path inside the checkout (or where the environment
+    says), every program kept in it, and a flight-recorder ring that holds
+    a whole window's steps."""
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(CACHE / "jax"))
+    os.environ["OMNI_TPU_FLIGHT_CAPACITY"] = "16384"
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def find_problems(records: list, connect_ms: list, check: dict,
+                  compiles_in_window: int, before: dict, after: dict,
+                  device: dict) -> list[str]:
+    """What makes the run incorrect; also says what the load was."""
+    from vllm_omni_tpu.ops import _dispatch
+
+    failed = [r for r in records if r["status"] != "ok"]
+    drawn = {"prefill_tokens": sum(r["prompt_tokens"] for r in records),
+             "tokens_generated": sum(r["output_tokens"] for r in records)}
+    counted = {k: after["counters"][k] - before["counters"][k]
+               for k in drawn}
+    late = [(r["sent"] - r["due"]) * 1e3 for r in records if "due" in r]
+    say(f"load: {len(records)} requests sent, {len(failed)} failed, "
+        f"{len(connect_ms)} connections (connect max "
+        f"{max(connect_ms):.1f} ms), generator late p99 "
+        f"{e2e.nearest_rank(late, 0.99) if late else 0.0:.2f} ms; engine "
+        f"counted {counted}, drawn {drawn}")
+    problems = []
+    if not check["ok"]:
+        problems.append(f"reference check: {check}")
+    if compiles_in_window:
+        problems.append(f"{compiles_in_window} compilations in the window")
+    if _dispatch.native_fallbacks:
+        problems.append(f"ops fell back to XLA: "
+                        f"{dict(_dispatch.native_fallbacks)}")
+    if failed:
+        problems.append(f"{len(failed)} requests failed, e.g. {failed[0]}")
+    elif counted != drawn:
+        problems.append(f"token counts: engine {counted}, drawn {drawn}")
+    if device["platform"] != "tpu":
+        problems.append("not a TPU")
+    for p in problems:
+        say(f"NOT CORRECT: {p}")
+    return problems
+
+
+def run_cell(args) -> dict:
+    cell = spec.resolve_cell(args.workload, Path(args.bench_root))
+    config, traffic = cell["config"], cell["traffic"]
+    chips = cell["cell"]["chips"]
+    prepare_jax()
+    import jax
+
+    device = device_gate(chips, args.allow_cpu)
+    say(f"cell {args.workload}: config {cell['cell']['config']}, traffic "
+        f"{cell['cell']['traffic']}, {chips} chip(s) of {device['count']} x "
+        f"{device['kind']} ({device['platform']}), seed {args.seed}, "
+        f"{args.seconds:g} s")
+
+    compiles: list[float] = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(time.monotonic())
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+
+    from benchmark import serve
+
+    timings: dict = {}
+    server, state, engine = serve.start_server(config, args.seed, timings)
+    port = server.server_address[1]
+    serve_thread = threading.Thread(target=server.serve_forever, daemon=True)
+    serve_thread.start()
+    try:
+        t = time.monotonic()
+        check = serve.reference_check(state, engine, config, args.seed)
+        template = serve.template_tokens(port, traffic)
+        timings["reference_s"] = time.monotonic() - t
+        say(f"setup: weights {timings['weights_s']:.1f} s, warm-up or cache "
+            f"load {timings['warmup_s']:.1f} s "
+            f"({engine.runner.compile_stats['compiles']} executables, "
+            f"{len(compiles)} backend compilations so far), reference check "
+            f"{timings['reference_s']:.1f} s, lead-in "
+            f"{traffic['lead_in_s']:g} s")
+        say(f"reference: max |served - float32| logprob "
+            f"{check.get('max_abs_diff')} (tolerance {serve.LOGPROB_TOL}); "
+            f"chat template adds {template} tokens")
+
+        def trace_part(w0: float) -> None:
+            trace_dir = CACHE / "trace" / args.workload
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            sleep_until(w0 + min(TRACE_AFTER_S, args.seconds / 4))
+            traced.append(time.time())
+            jax.profiler.start_trace(str(trace_dir))
+            sleep_until(time.monotonic() + min(TRACE_SECONDS,
+                                               args.seconds / 2))
+            jax.profiler.stop_trace()
+            traced.append(time.time())
+
+        traced: list[float] = []
+        load = serve.drive(port, traffic, args.seed, args.seconds, template,
+                           in_window=trace_part if args.trace else None)
+        w0, w1 = load["window"]
+        setup_s = w0 - _T_START
+        compiles_in_window = sum(1 for t in compiles if w0 <= t < w1)
+        memory_peak = max(
+            (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+            for d in jax.local_devices()[:chips])
+    finally:
+        server.shutdown()
+        state.shutdown()
+        server.server_close()
+        serve_thread.join(timeout=10)
+
+    records, connect_ms = load["records"], load["connect_ms"]
+    engine0, engine1, steps = load["engine0"], load["engine1"], load["steps"]
+    problems = find_problems(records, connect_ms, check, compiles_in_window,
+                             load["engine_before"], load["engine_after"],
+                             device)
+    window = (w0, w1)
+    edge = "due" if traffic["loop"] == "open" else "sent"
+    in_window = [r for r in records if w0 <= r[edge] < w1]
+    metrics: dict = {}
+    device_out = {**device, "memory_peak_bytes": memory_peak}
+    result = {"correct": not problems, "attempted": len(in_window),
+              "failed": sum(1 for r in in_window if r["status"] != "ok"),
+              "metrics": metrics, "device": device_out}
+    detail = {"records": records, "window": window, "timings": timings,
+              "connect_ms": connect_ms, "reference": check}
+    if not args.trace:
+        for m in cell["end_to_end"]:
+            value = (setup_s if m["name"] == "setup_s"
+                     else e2e.METRICS[m["name"]](records, w0, w1))
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        keep_run(args, result, detail)
+        return result
+
+    lo, hi = traced
+    reduced = trace.reduce_trace(CACHE / "trace" / args.workload)
+    obs = {"records": records, "window": window, "engine0": engine0,
+           "engine1": engine1, "compiles": compiles_in_window,
+           "steps": [s for s in steps if lo <= s["ts"] <= hi],
+           "trace": reduced, "config": config, "traffic": traffic,
+           "device": device, "chips": chips}
+    for m in cell["per_layer"]:
+        value = readers.resolve(m["reader"])(obs, **m.get("params", {}))
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if reduced:
+        device_out["busy_s"] = reduced["busy_s"]
+        device_out["window_s"] = reduced["window_s"]
+        result["breakdown"] = {"device_ops": trace.top(reduced["self_s"]),
+                               "idle_gaps": trace.top(reduced["gaps_s"])}
+    keep_run(args, result, {**detail, "trace": reduced, "steps": obs["steps"],
+                            "engine0": engine0, "engine1": engine1})
+    return result
+
+
+def keep_run(args, result: dict, detail: dict) -> None:
+    """Leave the run's records and whole tables beside the cache, for the
+    builder who wants more than the last line (the driver reads none)."""
+    out = CACHE / "runs"
+    out.mkdir(parents=True, exist_ok=True)
+    name = (f"{args.workload}-seed{args.seed}-trace{args.trace}-"
+            f"{int(time.time())}.json")
+    with open(out / name, "w") as f:
+        json.dump({"result": result, **detail}, f)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    # for rehearsals and tests only; the driver passes neither
+    ap.add_argument("--bench-root", default=str(ROOT),
+                    help="directory whose BENCHMARK.json and data files "
+                         "are read (tests point it at a tiny cell)")
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="rehearse on a CPU: the result says so and is "
+                         "never correct")
+    args = ap.parse_args()
+    result = run_cell(args)
+    print(json.dumps(result), flush=True)
+
+
+if __name__ == "__main__":
+    main()
