@@ -1,12 +1,21 @@
 """One run of one cell of BENCHMARK.json.
 
-    python3 benchmark/run.py --workload NAME --seed N --seconds S --trace 0|1
+    python3 benchmark/run.py --workload NAME --seed N --seconds S --trace 0|1|2
 
 Set-up (weights from the seed, warm-up of every shape, the reference check,
 the lead-in) is timed as ``setup_s``; then the window is measured for
 ``--seconds``.  The last line of standard output is one JSON object.  A
 cell never runs on a CPU: without a TPU, or with fewer chips than the cell
 asks for, the exit code is not 0 and no result is printed.
+
+``--trace 0`` measures and reports the end-to-end metrics.  ``--trace 1``
+traces three seconds inside the measured window, in a run of its own, and
+reports the per-layer metrics.  ``--trace 2`` is a ``--trace 0`` run that
+goes on once its window has closed and what was in flight has run to its
+end: it offers the same traffic again and traces a few seconds of that
+tail through the program's own control (``start_profile`` /
+``stop_profile``), so that its last line holds both kinds of metric and
+the end-to-end numbers are ``--trace 0``'s by construction.
 """
 
 from __future__ import annotations
@@ -34,6 +43,11 @@ CACHE = ROOT / ".benchmark_cache"
 #: seconds of the window that a ``--trace 1`` run traces, and where they
 #: start: traces are large and tracing slows the host
 TRACE_AFTER_S, TRACE_SECONDS = 4.0, 3.0
+#: the traced tail of a ``--trace 2`` run: the traffic's own lead-in, then
+#: a window of this many seconds of which ``TRACE_SECONDS`` are traced from
+#: ``TAIL_TRACE_AFTER_S`` on; the rest covers the time the profiler takes
+#: to stop, so that the generator is still sending while it is traced
+TAIL_SECONDS, TAIL_TRACE_AFTER_S = 5.0, 1.0
 
 
 def say(msg: str) -> None:
@@ -111,6 +125,49 @@ def find_problems(records: list, connect_ms: list, check: dict,
     return problems
 
 
+def trace_tail(state, port: int, traffic: dict, args, template: int
+               ) -> dict:
+    """The traced tail of a ``--trace 2`` run, after the measured window
+    has closed and what was in flight has run to its end: the same traffic
+    file offered again (its own lead-in, ``TAIL_SECONDS`` of window, other
+    message bytes, so that the prefix cache holds none of them), of which
+    ``TRACE_SECONDS`` are traced through the program's own control.  The
+    profiler's first start in a process costs seconds, so it is started
+    and stopped once before, on an idle server, and that trace is thrown
+    away.  Returns the tail's load and the traced interval (wall clock),
+    with the directory the trace landed in."""
+    from benchmark import serve
+
+    trace_dir = CACHE / "trace" / args.workload
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    t = time.monotonic()
+    state.omni.start_profile(str(trace_dir / "first_start"))
+    state.omni.stop_profile()
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    say(f"tail: the profiler's first start and stop took "
+        f"{time.monotonic() - t:.1f} s (that trace is thrown away)")
+    traced: dict = {}
+
+    def trace_part(w0: float) -> None:
+        sleep_until(w0 + TAIL_TRACE_AFTER_S)
+        t_start = time.monotonic()
+        state.omni.start_profile(str(trace_dir))
+        traced["lo"] = time.time()
+        sleep_until(time.monotonic() + TRACE_SECONDS)
+        traced["hi"], t_stop = time.time(), time.monotonic()
+        traced["dir"] = state.omni.stop_profile()
+        say(f"tail: traced {traced['hi'] - traced['lo']:.2f} s from "
+            f"{t_start - w0:.2f} s into the tail's window; stopping and "
+            f"writing took {time.monotonic() - t_stop:.1f} s")
+
+    load = serve.drive(port, traffic, args.seed + 1, TAIL_SECONDS, template,
+                       in_window=trace_part)
+    if traced.get("dir") is None:
+        raise SystemExit("benchmark: the program's stop_profile named no "
+                         "directory: nothing was traced")
+    return {**load, **traced}
+
+
 def run_cell(args) -> dict:
     cell = spec.resolve_cell(args.workload, Path(args.bench_root))
     config, traffic = cell["config"], cell["traffic"]
@@ -164,10 +221,12 @@ def run_cell(args) -> dict:
 
         traced: list[float] = []
         load = serve.drive(port, traffic, args.seed, args.seconds, template,
-                           in_window=trace_part if args.trace else None)
+                           in_window=trace_part if args.trace == 1 else None)
         w0, w1 = load["window"]
         setup_s = w0 - _T_START
         compiles_in_window = sum(1 for t in compiles if w0 <= t < w1)
+        tail = (trace_tail(state, port, traffic, args, template)
+                if args.trace == 2 else None)
         memory_peak = max(
             (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
             for d in jax.local_devices()[:chips])
@@ -192,16 +251,22 @@ def run_cell(args) -> dict:
               "metrics": metrics, "device": device_out}
     detail = {"records": records, "window": window, "timings": timings,
               "connect_ms": connect_ms, "reference": check}
-    if not args.trace:
+    if args.trace != 1:
         for m in cell["end_to_end"]:
             value = (setup_s if m["name"] == "setup_s"
                      else e2e.METRICS[m["name"]](records, w0, w1))
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if not args.trace:
         keep_run(args, result, detail)
         return result
 
-    lo, hi = traced
+    if tail is not None:
+        # counters and histograms read the measured window's snapshots,
+        # untraced; the trace and the steps are the tail's
+        lo, hi, steps = tail["lo"], tail["hi"], tail["steps"]
+    else:
+        lo, hi = traced
     reduced = trace.reduce_trace(CACHE / "trace" / args.workload)
     obs = {"records": records, "window": window, "engine0": engine0,
            "engine1": engine1, "compiles": compiles_in_window,
@@ -217,9 +282,41 @@ def run_cell(args) -> dict:
         device_out["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": trace.top(reduced["self_s"]),
                                "idle_gaps": trace.top(reduced["gaps_s"])}
+    if tail is not None:
+        tail_report(result, tail, compiles, w1)
+        # the trace is reduced: the tables are kept, the file is not
+        shutil.rmtree(CACHE / "trace" / args.workload, ignore_errors=True)
+        detail["tail"] = {"window": tail["window"], "records": tail["records"],
+                          "traced": (tail["lo"], tail["hi"])}
     keep_run(args, result, {**detail, "trace": reduced, "steps": obs["steps"],
                             "engine0": engine0, "engine1": engine1})
     return result
+
+
+def tail_report(result: dict, tail: dict, compiles: list, w1: float) -> None:
+    """What a ``--trace 2`` run says of its traced tail: idle gaps named by
+    the host phase that covered them (``in_omni.schedule``; ``after_<op>``
+    only where no annotation did), and what happened in the tail that the
+    measured window's ``correct`` does not cover."""
+    from benchmark import span_readers
+
+    red = span_readers.reduction()
+    if red and red["gaps_s"] and "breakdown" in result:
+        result["breakdown"]["idle_gaps"] = trace.top(red["gaps_s"])
+    if red and red["skew_ms"]:
+        skew = sorted(red["skew_ms"])
+        say(f"tail: one clock: a step's omni.readback ends "
+            f"{skew[len(skew) // 2]:.2f} ms (median of {len(skew)}, "
+            f"{skew[0]:.2f} to {skew[-1]:.2f}) after its last device "
+            f"operation")
+    records = tail["records"]
+    said = {"compiles": sum(1 for t in compiles if t >= w1),
+            "requests": len(records),
+            "failed": sum(1 for r in records if r["status"] != "ok"),
+            "traced_s": tail["hi"] - tail["lo"]}
+    result.setdefault("breakdown", {})["tail"] = said
+    say(f"tail: {said['requests']} requests sent, {said['failed']} failed, "
+        f"{said['compiles']} compilations after the measured window")
 
 
 def keep_run(args, result: dict, detail: dict) -> None:
@@ -238,7 +335,7 @@ def main() -> None:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     # for rehearsals and tests only; the driver passes neither
     ap.add_argument("--bench-root", default=str(ROOT),
                     help="directory whose BENCHMARK.json and data files "

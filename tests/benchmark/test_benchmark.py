@@ -32,7 +32,10 @@ CATALOG = Path("/opt/skills/guides/model-configs/architectures.jsonl")
 # ------------------------------------------------------------ the contract
 def test_benchmark_json_keeps_to_the_contract():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    # the per-layer metrics are traced in the run that measures (--trace 2)
+    assert BENCH["trace_in_run"] is True
     assert 1 <= BENCH["run_seconds"] <= 51
     e2e_names = {m["name"] for m in BENCH["end_to_end"]}
     assert "setup_s" in e2e_names

@@ -167,3 +167,30 @@ def test_live_engine_gauges_render_and_bound():
         in text
     assert 'vllm_omni_tpu_engine_step_mbu{stage="0",phase="prefill"}' \
         in text
+
+
+@pytest.mark.parametrize("rows,experts", [(1, 8.0), (64, 125.942)])
+def test_moe_bytes_count_the_experts_a_step_touches(rows, experts):
+    """A step reads the experts its tokens are expected to route to,
+    E x (1 - (1 - k/E)^tokens): top-8 of 128 for one row, nearly all 128
+    for 64 rows (what benchmark/trace.py counts), not 8 whatever the
+    batch."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        tfm.TransformerConfig.tiny(vocab_size=64), moe=True,
+        num_experts=128, num_experts_per_tok=8, moe_intermediate_size=32)
+    g = ModelGeometry.from_transformer_config(cfg, dtype_bytes=2)
+    assert g.experts_touched(rows) == pytest.approx(experts, abs=0.01)
+    one_expert = 2 * 3 * cfg.hidden_size * 32
+    assert g.expert_bytes == one_expert
+    assert g.step_bytes(rows, 0.0) == pytest.approx(
+        g.weight_bytes + cfg.num_layers * experts * one_expert
+        + g.kv_bytes_per_pos * rows, rel=1e-4)
+    # FLOPs stay the routed cost: 8 experts a token
+    router = cfg.hidden_size * 128
+    attn = 4 * cfg.hidden_size * cfg.num_heads * cfg.head_dim \
+        - 2 * cfg.hidden_size * (cfg.num_heads - cfg.num_kv_heads) \
+        * cfg.head_dim
+    assert g.flops_per_token == 2.0 * cfg.num_layers * (
+        attn + router + 8 * one_expert // 2)

@@ -86,6 +86,7 @@ def synthetic_engine_snapshot() -> dict:
         # per-phase saturation — the drift guard must cover every
         # series the loadgen harness reads mid-flight
         "queue_wait_ms": hist,
+        "intake_ms": hist,
         "queue": {"depth_by_tenant": {"default": 1, "acme": 2}},
         "shed": {"queue_depth/acme": 3, "deadline_headroom/default": 1},
         # weighted-fair overload scheduling (docs/control_plane.md)

@@ -33,6 +33,7 @@ from vllm_omni_tpu.introspection import (
     register_engine,
 )
 from vllm_omni_tpu.logger import init_logger
+from vllm_omni_tpu.metrics.roofline import ctx_positions
 from vllm_omni_tpu.metrics.stats import EngineStepMetrics
 from vllm_omni_tpu.resilience.faults import fault_point
 from vllm_omni_tpu.tracing import get_recorder
@@ -48,6 +49,10 @@ from vllm_omni_tpu.outputs import OmniRequestOutput
 from vllm_omni_tpu.request import Request, RequestStatus
 from vllm_omni_tpu.sampling_params import SamplingParams
 from vllm_omni_tpu.worker.model_runner import ARModelRunner
+
+# a host phase of the step in the profiler's trace (the runner adds
+# omni.assemble / omni.dispatch / omni.readback around its own halves)
+_phase = jax.profiler.TraceAnnotation
 
 logger = init_logger(__name__)
 
@@ -559,6 +564,12 @@ class LLMEngine:
         if injected_kv is not None:
             injected_len = min(payload_seq_len(injected_kv),
                                max(req.num_tokens - 1, 0))
+        accepted = req.additional_information.get("accept_mono")
+        if isinstance(accepted, float):
+            # the front end's stamp at HTTP accept, on this host's
+            # monotonic clock (entrypoints/openai/api_server.py)
+            self.step_metrics.intake_ms.observe(
+                max(req.arrival_mono - accepted, 0.0) * 1e3)
         self.scheduler.add_request(req, injected_len=injected_len)
         if injected_kv is not None and req.status is RequestStatus.WAITING:
             self._inject_prefix_kv(req, injected_kv)
@@ -865,21 +876,22 @@ class LLMEngine:
         # start_pos/num_new_tokens are scheduler state, the wall time
         # is the host_ms/device_ms sum already computed; NO device syncs
         roofline = None
+        # attended (new token x context position) pairs of the step, by
+        # phase: what a roofline of the attention kernels needs, and
+        # what the benchmark reads in place of a traffic mix's mean
+        prefill_ctx = sum(ctx_positions(s.start_pos, s.num_new_tokens)
+                          for s in sched_out.prefills)
+        decode_ctx = sum(ctx_positions(s.start_pos, s.num_new_tokens)
+                         for s in sched_out.decodes)
         tracker = getattr(self, "roofline", None)  # duck-typed fakes
         if tracker is not None:
-            from vllm_omni_tpu.metrics.roofline import ctx_positions
-
             roofline = tracker.on_step(
                 prefill_tokens=sum(s.num_new_tokens
                                    for s in sched_out.prefills),
-                prefill_ctx=sum(ctx_positions(s.start_pos,
-                                              s.num_new_tokens)
-                                for s in sched_out.prefills),
+                prefill_ctx=prefill_ctx,
                 decode_tokens=sum(s.num_new_tokens
                                   for s in sched_out.decodes),
-                decode_ctx=sum(ctx_positions(s.start_pos,
-                                             s.num_new_tokens)
-                               for s in sched_out.decodes),
+                decode_ctx=decode_ctx,
                 sampled_rows=len(sched_out.prefills)
                 + len(sched_out.decodes),
                 wall_s=(host_ms + device_ms) / 1e3,
@@ -905,6 +917,8 @@ class LLMEngine:
             "new_tokens": new_tokens,
             "prefill_tokens": sum(s.num_new_tokens
                                   for s in sched_out.prefills),
+            "prefill_ctx": int(prefill_ctx),
+            "decode_ctx": int(decode_ctx),
             "waiting": len(self.scheduler.waiting),
             "running": len(self.scheduler.running),
             "host_ms": round(host_ms, 3),
@@ -1061,6 +1075,15 @@ class LLMEngine:
         return snap
 
     def step(self) -> list[OmniRequestOutput]:
+        """One engine step.  The step and its host phases (``omni.*``,
+        docs/observability.md) are annotations in the profiler's own
+        trace, where they share a clock with the device's operations;
+        with no profiler session each is a flag test."""
+        with jax.profiler.StepTraceAnnotation(
+                "omni.step", step_num=self._steps_completed):
+            return self._step()
+
+    def _step(self) -> list[OmniRequestOutput]:
         # deterministic stall injection for the watchdog/debugz tests
         # (resilience/faults.py site "step": delay_ms stalls every step,
         # fail_step raises into the caller) — one dict lookup when no
@@ -1070,10 +1093,11 @@ class LLMEngine:
         # deadline sweep BEFORE scheduling: expired requests become
         # deadline_exceeded outputs this very step instead of consuming
         # another forward (resilience/deadline.py)
-        self.scheduler.expire_deadlines()
-        # surface intake-rejected requests as errored outputs instead of
-        # silently dropping them
-        errored_reqs = self.scheduler.drain_errored()
+        with _phase("omni.schedule"):
+            self.scheduler.expire_deadlines()
+            # surface intake-rejected requests as errored outputs instead
+            # of silently dropping them
+            errored_reqs = self.scheduler.drain_errored()
         for r in errored_reqs:
             self._req_lat.pop(r.request_id, None)
             self._trace_started.discard(r.request_id)
@@ -1090,7 +1114,8 @@ class LLMEngine:
         if self.config.async_scheduling:
             outs = errored + self._step_async(t_step0)
         else:
-            sched_out = self.scheduler.schedule()
+            with _phase("omni.schedule"):
+                sched_out = self.scheduler.schedule()
             outs = errored + self._run_scheduled(sched_out, t_step0)
         # counted at COMPLETION: a step wedged mid-flight never
         # advances the watchdog's progress signal
@@ -1114,11 +1139,12 @@ class LLMEngine:
         synchronous path, counted per reason in ``async_fallback``."""
         ready, reason = self._pipeline_ready()
         if ready:
-            sched_out = self.scheduler.schedule()
-            self.step_metrics.on_schedule(
-                waiting=len(self.scheduler.waiting),
-                running=len(self.scheduler.running),
-            )
+            with _phase("omni.schedule"):
+                sched_out = self.scheduler.schedule()
+                self.step_metrics.on_schedule(
+                    waiting=len(self.scheduler.waiting),
+                    running=len(self.scheduler.running),
+                )
             if sched_out.num_scheduled == 0 and self._inflight is not None:
                 # pipeline bubble: everything schedulable is waiting on
                 # the in-flight retire (e.g. a spec verify's accept
@@ -1151,7 +1177,8 @@ class LLMEngine:
         if reason is not None:
             self._note_fallback(reason)
         outs, drain_wait = self._drain_pipeline()
-        sched_out = self.scheduler.schedule()
+        with _phase("omni.schedule"):
+            sched_out = self.scheduler.schedule()
         return outs + self._run_scheduled(sched_out, t_step0,
                                           drained_wait_s=drain_wait,
                                           fallback=reason)
@@ -1217,8 +1244,9 @@ class LLMEngine:
         rec = get_recorder()
         prev = self._inflight
         scheduled = sched_out.prefills + sched_out.decodes
-        self._note_first_scheduled(scheduled)
-        self._observe_saturation(sched_out)
+        with _phase("omni.record"):
+            self._note_first_scheduled(scheduled)
+            self._observe_saturation(sched_out)
         t_d0, w_d0 = time.perf_counter(), time.time()
         u0, p0 = self._padding_totals()
         if self.runner._plain_decode_only(sched_out):
@@ -1231,18 +1259,19 @@ class LLMEngine:
             # logprobs, and embeds batches pipeline too
             handle = self.runner.dispatch_unified(
                 sched_out, prev.handle if prev is not None else None)
-        # schedule-ahead accounting: the dispatched rows' tokens are
-        # now in flight; the next schedule() counts them without seeing
-        # their values
-        self.scheduler.note_async_dispatch(sched_out)
-        self._observe_padding(u0, p0)
-        dur_disp = time.perf_counter() - t_d0
-        for s in scheduled:
-            rec.record(s.request.additional_information.get("trace"),
-                       "dispatch", w_d0, dur_disp,
-                       stage_id=self.stage_id,
-                       args={"batch": len(scheduled)},
-                       **self.span_tags)
+        with _phase("omni.record"):
+            # schedule-ahead accounting: the dispatched rows' tokens are
+            # now in flight; the next schedule() counts them without seeing
+            # their values
+            self.scheduler.note_async_dispatch(sched_out)
+            self._observe_padding(u0, p0)
+            dur_disp = time.perf_counter() - t_d0
+            for s in scheduled:
+                rec.record(s.request.additional_information.get("trace"),
+                           "dispatch", w_d0, dur_disp,
+                           stage_id=self.stage_id,
+                           args={"batch": len(scheduled)},
+                           **self.span_tags)
         self._inflight = _InflightStep(sched_out=sched_out, handle=handle)
         outs: list[OmniRequestOutput] = []
         new_total = 0
@@ -1258,21 +1287,22 @@ class LLMEngine:
                 extra, drain_wait = self._drain_pipeline()
                 outs += extra
                 wait_s += drain_wait
-        total_s = time.perf_counter() - t_step0
-        host_ms = max(total_s - wait_s, 0.0) * 1e3
-        # with a predecessor in flight, schedule+dispatch overlapped ITS
-        # compute and the retire's post-wait work overlaps the step just
-        # dispatched — the only unoverlapped host time is the wait
-        self.step_metrics.on_step(
-            step_ms=total_s * 1e3, new_tokens=new_total,
-            prefill_tokens=sum(s.num_new_tokens
-                               for s in sched_out.prefills),
-            host_ms=host_ms, device_ms=wait_s * 1e3,
-            overlapped_host_ms=host_ms if prev is not None else 0.0,
-        )
-        self._record_step("pipelined", sched_out, scheduled, new_total,
-                          host_ms=host_ms, device_ms=wait_s * 1e3)
-        return outs
+        with _phase("omni.record"):
+            total_s = time.perf_counter() - t_step0
+            host_ms = max(total_s - wait_s, 0.0) * 1e3
+            # with a predecessor in flight, schedule+dispatch overlapped ITS
+            # compute and the retire's post-wait work overlaps the step just
+            # dispatched — the only unoverlapped host time is the wait
+            self.step_metrics.on_step(
+                step_ms=total_s * 1e3, new_tokens=new_total,
+                prefill_tokens=sum(s.num_new_tokens
+                                   for s in sched_out.prefills),
+                host_ms=host_ms, device_ms=wait_s * 1e3,
+                overlapped_host_ms=host_ms if prev is not None else 0.0,
+            )
+            self._record_step("pipelined", sched_out, scheduled, new_total,
+                              host_ms=host_ms, device_ms=wait_s * 1e3)
+            return outs
 
     def _consolidate_hidden(self, finished) -> None:
         """Fold per-step hidden chunks into the next-stage payload
@@ -1298,27 +1328,28 @@ class LLMEngine:
         t_g0, w_g0 = time.perf_counter(), time.time()
         sampled = self.runner.retire_step(inflight.handle)
         wait_s = time.perf_counter() - t_g0
-        finished = self.scheduler.update_from_async_retire(
-            inflight.sched_out, sampled)
-        self._consolidate_hidden(finished)
-        scheds = (inflight.sched_out.prefills
-                  + inflight.sched_out.decodes)
-        # only requests that could have appended a token this retire:
-        # an overshoot row for a request that finished at the PREVIOUS
-        # retire (or was aborted/expired mid-flight) already had its
-        # latency entry popped — setdefault would resurrect it with a
-        # zero token count, re-counting the whole stream into
-        # tokens_generated/TTFT and leaking the entry forever
-        just_finished = {r.request_id for r in finished}
-        live = [s for s in scheds
-                if not s.request.is_finished
-                or s.request.request_id in just_finished]
-        new_total = self._observe_token_latencies(live, finished)
-        dur = time.perf_counter() - t_g0
-        for s in scheds:
-            rec.record(s.request.additional_information.get("trace"),
-                       "retire", w_g0, dur, stage_id=self.stage_id,
-                       args={"batch": len(scheds)}, **self.span_tags)
+        with _phase("omni.update"):
+            finished = self.scheduler.update_from_async_retire(
+                inflight.sched_out, sampled)
+            self._consolidate_hidden(finished)
+            scheds = (inflight.sched_out.prefills
+                      + inflight.sched_out.decodes)
+            # only requests that could have appended a token this retire:
+            # an overshoot row for a request that finished at the PREVIOUS
+            # retire (or was aborted/expired mid-flight) already had its
+            # latency entry popped — setdefault would resurrect it with a
+            # zero token count, re-counting the whole stream into
+            # tokens_generated/TTFT and leaking the entry forever
+            just_finished = {r.request_id for r in finished}
+            live = [s for s in scheds
+                    if not s.request.is_finished
+                    or s.request.request_id in just_finished]
+            new_total = self._observe_token_latencies(live, finished)
+            dur = time.perf_counter() - t_g0
+            for s in scheds:
+                rec.record(s.request.additional_information.get("trace"),
+                           "retire", w_g0, dur, stage_id=self.stage_id,
+                           args={"batch": len(scheds)}, **self.span_tags)
         outs = [OmniRequestOutput.from_pipeline(r) for r in finished]
         return outs, new_total, wait_s
 
@@ -1419,7 +1450,8 @@ class LLMEngine:
                        drained_wait_s: float = 0.0,
                        fallback: Optional[str] = None
                        ) -> list[OmniRequestOutput]:
-        failed_restores = self._drain_kv_moves()
+        with _phase("omni.kv_moves"):
+            failed_restores = self._drain_kv_moves()
         if failed_restores:
             # a restore came up short: this step's chunks for those
             # requests are positionally misaligned (start_pos past the
@@ -1493,71 +1525,78 @@ class LLMEngine:
         self._starved_ticks = 0
         rec = get_recorder()
         scheduled = sched_out.prefills + sched_out.decodes
-        self._note_first_scheduled(scheduled)
-        self._observe_saturation(sched_out)
+        with _phase("omni.record"):
+            self._note_first_scheduled(scheduled)
+            self._observe_saturation(sched_out)
         t_ex0, w_ex0 = time.perf_counter(), time.time()
         u0, p0 = self._padding_totals()
         run_out = self.runner.execute(
             sched_out, extract_kv=self.kv_transfer_sink is not None
         )
-        self._observe_padding(u0, p0)
-        dur_ex = time.perf_counter() - t_ex0
-        for s in sched_out.prefills:
-            rec.record(s.request.additional_information.get("trace"),
-                       "prefill", w_ex0, dur_ex, stage_id=self.stage_id,
-                       args={"tokens": s.num_new_tokens,
-                             "start_pos": s.start_pos},
-                       **self.span_tags)
-        for s in sched_out.decodes:
-            rec.record(s.request.additional_information.get("trace"),
-                       "decode", w_ex0, dur_ex, stage_id=self.stage_id,
-                       args={"tokens": s.num_new_tokens},
-                       **self.span_tags)
-        if self.kv_transfer_sink is not None:
-            for req, _, _ in sched_out.kv_transfer_requests:
-                payload = run_out.extracted_kv.get(req.request_id)
-                if payload is not None:
-                    self.kv_transfer_sink(req, payload)
-        t_up0, w_up0 = time.perf_counter(), time.time()
-        finished = self.scheduler.update_from_output(
-            sched_out, run_out.sampled, run_out.kv_extracted_req_ids
-        )
-        dur_up = time.perf_counter() - t_up0
-        for s in scheduled:
-            rec.record(s.request.additional_information.get("trace"),
-                       "sampling", w_up0, dur_up, stage_id=self.stage_id,
-                       args={"batch": len(scheduled)}, **self.span_tags)
-        new_total = self._observe_token_latencies(scheduled, finished)
-        total_s = time.perf_counter() - t_step0
-        self.step_metrics.on_step(
-            step_ms=total_s * 1e3,
-            new_tokens=new_total,
-            prefill_tokens=sum(s.num_new_tokens
-                               for s in sched_out.prefills),
-            # execute() syncs internally, so its span (plus any
-            # pipeline-drain wait that preceded it) is the device-bound
-            # portion; no host work overlaps it
-            host_ms=max(total_s - dur_ex - drained_wait_s, 0.0) * 1e3,
-            device_ms=(dur_ex + drained_wait_s) * 1e3,
-            overlapped_host_ms=0.0,
-        )
-        self._record_step(
-            "sync", sched_out, scheduled, new_total,
-            host_ms=max(total_s - dur_ex - drained_wait_s, 0.0) * 1e3,
-            device_ms=(dur_ex + drained_wait_s) * 1e3,
-            fallback=fallback)
-        self._consolidate_hidden(finished)
-        if not self.scheduler.has_unfinished:
-            # no further step will run: drain transfers triggered just now
-            # so finished requests still ship their KV
-            for req, block_ids, seq_len in \
-                    self.scheduler.drain_pending_kv_transfers():
-                if self.kv_transfer_sink is not None:
-                    payload = self.runner.extract_kv(block_ids, seq_len)
-                    self.kv_transfer_sink(req, payload)
-                self.scheduler.update_from_output(
-                    SchedulerOutput(), {}, {req.request_id})
-        return [OmniRequestOutput.from_pipeline(r) for r in finished]
+        with _phase("omni.record"):
+            self._observe_padding(u0, p0)
+            dur_ex = time.perf_counter() - t_ex0
+            for s in sched_out.prefills:
+                rec.record(s.request.additional_information.get("trace"),
+                           "prefill", w_ex0, dur_ex,
+                           stage_id=self.stage_id,
+                           args={"tokens": s.num_new_tokens,
+                                 "start_pos": s.start_pos},
+                           **self.span_tags)
+            for s in sched_out.decodes:
+                rec.record(s.request.additional_information.get("trace"),
+                           "decode", w_ex0, dur_ex,
+                           stage_id=self.stage_id,
+                           args={"tokens": s.num_new_tokens},
+                           **self.span_tags)
+            if self.kv_transfer_sink is not None:
+                for req, _, _ in sched_out.kv_transfer_requests:
+                    payload = run_out.extracted_kv.get(req.request_id)
+                    if payload is not None:
+                        self.kv_transfer_sink(req, payload)
+        with _phase("omni.update"):
+            t_up0, w_up0 = time.perf_counter(), time.time()
+            finished = self.scheduler.update_from_output(
+                sched_out, run_out.sampled, run_out.kv_extracted_req_ids
+            )
+            dur_up = time.perf_counter() - t_up0
+            for s in scheduled:
+                rec.record(s.request.additional_information.get("trace"),
+                           "sampling", w_up0, dur_up,
+                           stage_id=self.stage_id,
+                           args={"batch": len(scheduled)}, **self.span_tags)
+            new_total = self._observe_token_latencies(scheduled, finished)
+        with _phase("omni.record"):
+            total_s = time.perf_counter() - t_step0
+            self.step_metrics.on_step(
+                step_ms=total_s * 1e3,
+                new_tokens=new_total,
+                prefill_tokens=sum(s.num_new_tokens
+                                   for s in sched_out.prefills),
+                # execute() syncs internally, so its span (plus any
+                # pipeline-drain wait that preceded it) is the
+                # device-bound portion; no host work overlaps it
+                host_ms=max(total_s - dur_ex - drained_wait_s, 0.0) * 1e3,
+                device_ms=(dur_ex + drained_wait_s) * 1e3,
+                overlapped_host_ms=0.0,
+            )
+            self._record_step(
+                "sync", sched_out, scheduled, new_total,
+                host_ms=max(total_s - dur_ex - drained_wait_s, 0.0) * 1e3,
+                device_ms=(dur_ex + drained_wait_s) * 1e3,
+                fallback=fallback)
+            self._consolidate_hidden(finished)
+            if not self.scheduler.has_unfinished:
+                # no further step will run: drain transfers triggered
+                # just now so finished requests still ship their KV
+                for req, block_ids, seq_len in \
+                        self.scheduler.drain_pending_kv_transfers():
+                    if self.kv_transfer_sink is not None:
+                        payload = self.runner.extract_kv(block_ids, seq_len)
+                        self.kv_transfer_sink(req, payload)
+                    self.scheduler.update_from_output(
+                        SchedulerOutput(), {}, {req.request_id})
+            return [OmniRequestOutput.from_pipeline(r) for r in finished]
 
     def _observe_token_latencies(self, scheduled, finished) -> int:
         """TTFT / ITL / TPOT bookkeeping from the host-visible token

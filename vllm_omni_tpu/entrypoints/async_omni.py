@@ -15,7 +15,10 @@ import asyncio
 import itertools
 import queue
 import threading
+import time
 from typing import Any, AsyncIterator, Optional, Union
+
+from jax.profiler import TraceAnnotation as _phase
 
 from vllm_omni_tpu.analysis.runtime import traced
 from vllm_omni_tpu.config.stage import StageConfig
@@ -105,8 +108,9 @@ class AsyncOmni:
         profile RPC chain, omni.py:398-497)."""
         self._omni.start_profile(trace_dir)
 
-    def stop_profile(self) -> None:
-        self._omni.stop_profile()
+    def stop_profile(self) -> Optional[str]:
+        """End the trace; returns the directory it landed in."""
+        return self._omni.stop_profile()
 
     # ------------------------------------------------------- pause/resume
     async def pause_generation(
@@ -265,31 +269,38 @@ class AsyncOmni:
         entry_stages = [s for s in omni.stages
                         if -1 in s.config.engine_input_source]
         entry_stage = entry_stages[0] if entry_stages else omni.stages[0]
-        import time as _time
+        self._last_stats = time.monotonic()
+        while self._running:
+            # the phases of this loop in the profiler's own trace, beside
+            # the engine's (omni.step and below, docs/observability.md):
+            # what this thread does while the device waits for it
+            with _phase("omni.loop"):
+                self._loop_once(entry_stage)
 
+    def _loop_once(self, entry_stage) -> None:
+        omni = self._omni
         # periodic engine-level stats heartbeat (reference: the
         # do_log_stats keep-alive task, omni_stage.py:1134-1146)
-        last_stats = _time.monotonic()
-        while self._running:
-            now = _time.monotonic()
-            if now - last_stats >= self._stats_interval:
-                last_stats = now
-                # harvest stage request stats continuously (the offline
-                # path collects at end-of-generate) so long-running
-                # servers aggregate + stream jsonl as they go
-                omni.harvest_stage_stats()
-                # JSONL only: the full Chrome rewrite is shutdown-time
-                # work, not something to run on the engine thread
-                omni.flush_traces(export_chrome=False)
-                if self._streams:
-                    summ = omni.stats_summary()
-                    logger.info(
-                        "stats: %d in flight, e2e p50 %.0fms, stages %s",
-                        len(self._streams), summ["e2e"]["p50_ms"],
-                        {i: st["tps"]
-                         for i, st in summ["stages"].items()},
-                    )
-            # 1. drain intake
+        now = time.monotonic()
+        if now - self._last_stats >= self._stats_interval:
+            self._last_stats = now
+            # harvest stage request stats continuously (the offline
+            # path collects at end-of-generate) so long-running
+            # servers aggregate + stream jsonl as they go
+            omni.harvest_stage_stats()
+            # JSONL only: the full Chrome rewrite is shutdown-time
+            # work, not something to run on the engine thread
+            omni.flush_traces(export_chrome=False)
+            if self._streams:
+                summ = omni.stats_summary()
+                logger.info(
+                    "stats: %d in flight, e2e p50 %.0fms, stages %s",
+                    len(self._streams), summ["e2e"]["p50_ms"],
+                    {i: st["tps"]
+                     for i, st in summ["stages"].items()},
+                )
+        # 1. drain intake
+        with _phase("omni.intake"):
             pending = []
             try:
                 while True:
@@ -304,24 +315,25 @@ class AsyncOmni:
                         omni.trace_finish(r.request_id)
                         self._emit(r.request_id, e)
                         self._emit(r.request_id, _SENTINEL)
-            # 2. step stages + forward
-            progressed = False
-            for stage in omni.stages:
-                try:
-                    outs = stage.poll()
-                except Exception as e:
-                    # last resort: a poll failure can't be attributed to one
-                    # request (engine-level starvation is error-finished per
-                    # request inside LLMEngine.step and arrives as outputs)
-                    logger.exception("stage %d poll failed", stage.stage_id)
-                    for rid in list(self._streams):
-                        omni.trace_finish(rid)
-                        self._emit(rid, e)
-                        self._emit(rid, _SENTINEL)
-                    continue
-                if not outs:
-                    continue
-                progressed = True
+        # 2. step stages + forward
+        progressed = False
+        for stage in omni.stages:
+            try:
+                outs = stage.poll()
+            except Exception as e:
+                # last resort: a poll failure can't be attributed to one
+                # request (engine-level starvation is error-finished per
+                # request inside LLMEngine.step and arrives as outputs)
+                logger.exception("stage %d poll failed", stage.stage_id)
+                for rid in list(self._streams):
+                    omni.trace_finish(rid)
+                    self._emit(rid, e)
+                    self._emit(rid, _SENTINEL)
+                continue
+            if not outs:
+                continue
+            progressed = True
+            with _phase("omni.emit"):
                 # errored outputs terminate their streams and are not
                 # forwarded downstream
                 errs = [o for o in outs if o.is_error]
@@ -357,6 +369,7 @@ class AsyncOmni:
                         omni.trace_finish(o.request_id)
                         self._emit(o.request_id, e)
                         self._emit(o.request_id, _SENTINEL)
-            if not progressed and not pending:
-                # idle: avoid a hot spin on the GIL
+        if not progressed and not pending:
+            # idle: avoid a hot spin on the GIL
+            with _phase("omni.idle_wait"):
                 threading.Event().wait(0.002)

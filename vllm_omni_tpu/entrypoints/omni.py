@@ -498,15 +498,22 @@ class Omni:
         for stage in self.stages:
             stage.start_profile(trace_dir)
 
-    def stop_profile(self) -> None:
+    def stop_profile(self) -> Optional[str]:
+        """End every stage's trace; returns the directory the first
+        in-process stage's trace landed in (it covers every in-process
+        stage; a process stage writes under the ``trace_dir`` it was
+        started with, in its own process), so that a caller holding
+        the pipeline in-process can read the trace it asked for."""
         # two-phase for proc stages: send every stop first, then wait on
         # the acks — serial stop+wait would stack timeouts per stage
         waiters = []
+        landed = None
         for stage in self.stages:
             if hasattr(stage, "wait_profile_ack"):
                 stage.stop_profile(wait=False)
                 waiters.append(stage)
             else:
-                stage.stop_profile()
+                landed = stage.stop_profile() or landed
         for stage in waiters:
             stage.wait_profile_ack()
+        return landed

@@ -147,8 +147,9 @@ class OmniStage:
         PROFILER_START task, omni_stage.py:740-777)."""
         self.profiler.start(trace_dir)
 
-    def stop_profile(self) -> None:
-        self.profiler.stop()
+    def stop_profile(self) -> Optional[str]:
+        """End the trace; returns the directory it landed in."""
+        return self.profiler.stop()
 
     # -------------------------------------------------------- engine build
     def _build_engine(self):

@@ -301,7 +301,7 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
         the WFQ overload scheduler (docs/control_plane.md).  Both are
         CLIENT input: sanitized/clamped at the Request property, never
         trusted here."""
-        info = {}
+        info = {"accept_mono": self._accept_mono}
         # bound the RAW bytes at ingress (OL10 first-harvest): the
         # values ride request metadata — and every stage-handoff
         # serialization — until the Request properties sanitize at
@@ -494,6 +494,9 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
 
     # --------------------------------------------------------------- POST
     def do_POST(self):
+        # stamped before the body is read: ``intake_ms`` (engine arrival
+        # minus this, docs/observability.md) then covers the handler too
+        self._accept_mono = time.monotonic()
         try:
             body = self._body()
         except (json.JSONDecodeError, ValueError) as e:
@@ -518,9 +521,9 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
                     self._json(200, {"status": "profiling",
                                      "trace_dir": trace_dir})
                 else:
-                    self.state.omni.stop_profile()
+                    landed = self.state.omni.stop_profile()
                     self._json(200, {"status": "stopped",
-                                     "trace_dir": trace_dir})
+                                     "trace_dir": landed or trace_dir})
             elif self.path == "/v1/chat/completions":
                 self._chat_completions(body)
             elif self.path == "/v1/completions":

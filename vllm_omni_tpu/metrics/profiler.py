@@ -62,7 +62,15 @@ class StageProfiler:
 
         path = os.path.join(trace_dir, f"stage_{self.stage_id}")
         os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
+        # the Python tracer stays off: recording every call of every
+        # thread slowed the traced steps of a serving engine by a quarter
+        # and tripled the device's idle share (PERF.md, PR 25), so a
+        # trace taken with it says little of the untraced program.  The
+        # host's phases are in the trace by name instead (``omni.*``
+        # annotations, docs/observability.md)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(path, profiler_options=options)
         _process_owner = self.stage_id
         self._active_dir = path
         logger.info("stage %d: profiling -> %s", self.stage_id, path)

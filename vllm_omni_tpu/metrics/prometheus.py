@@ -211,6 +211,10 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     "queue_wait_ms": (
         "histogram", "Arrival to first scheduled, per request",
         ("stage",)),
+    "intake_ms": (
+        "histogram",
+        "HTTP accept to engine arrival, per request the front end stamped",
+        ("stage",)),
     "phase_saturation_ratio": (
         "gauge",
         "Fraction of the capacity ceiling used per phase (prefill/"
@@ -698,6 +702,8 @@ def render_exposition(summary: dict, engine_snaps: dict,
                                doc["tenants_tracked"])
         if snap.get("queue_wait_ms"):
             exp.histogram("queue_wait_ms", labels, snap["queue_wait_ms"])
+        if snap.get("intake_ms"):
+            exp.histogram("intake_ms", labels, snap["intake_ms"])
         for phase, v in sorted((snap.get("saturation") or {}).items()):
             exp.sample("phase_saturation_ratio",
                        {**labels, "phase": phase}, v)

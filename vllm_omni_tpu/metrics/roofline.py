@@ -15,15 +15,19 @@ roofline, from quantities the step loop already holds on the host:
   ``num_new_tokens`` — host ints, **zero device syncs** (the same
   stance as the PR 8 memory ledger; this module lives in the omnilint
   OL2 HOT_PATHS manifest).
-- **achieved HBM bytes** = active weight bytes read once per dispatch
-  + KV read over every attended context position + KV write for every
-  new position.  Decode is the bandwidth-bound phase; this is the
-  quantity that explains why its MFU is structurally low.
+- **achieved HBM bytes** = the weights a dispatch touches, read once
+  (everything but the routed experts, plus as many experts per layer
+  as the step's tokens are expected to route to,
+  ``E x (1 - (1 - k/E)^tokens)``: 8 of 128 for one row, ~126 for 64 —
+  the count ``benchmark/trace.py`` takes) + KV read over every
+  attended context position + KV write for every new position.
+  Decode is the bandwidth-bound phase; this is the quantity that
+  explains why its MFU is structurally low.
 - **denominators** come from ``platforms/`` (``peak_tflops_bf16`` /
   ``peak_hbm_gbps``) and the step's WALL time — the operator quantity:
   host stalls and pipeline bubbles count against utilization, exactly
-  as they count against goodput.  Kernel-level numbers stay bench.py's
-  job.
+  as they count against goodput.  Shares of a roofline over DEVICE
+  time are the benchmark's job (benchmark/span_readers.py).
 
 Surfaces: ``engine_step_mfu`` / ``engine_step_mbu{phase}`` gauges on
 /metrics (rolling-window means), per-record ``mfu``/``mbu``/``phase``
@@ -62,40 +66,55 @@ class ModelGeometry:
     attn_flops_per_ctx: float
     #: LM-head FLOPs per sampled row (2 × hidden × vocab)
     lm_head_flops_per_row: float
-    #: bytes of (active) weights read per dispatch
+    #: bytes of the weights every dispatch reads: all but the routed
+    #: experts
     weight_bytes: float
     #: KV-cache bytes per token position (all layers, K+V)
     kv_bytes_per_pos: float
+    #: routed experts (all 0 for a dense model): bytes of one expert in
+    #: one layer, experts per layer, experts per token, layers
+    expert_bytes: float = 0.0
+    num_experts: int = 0
+    experts_per_tok: int = 0
+    num_layers: int = 0
 
     @classmethod
     def from_transformer_config(cls, cfg, dtype_bytes: int
                                 ) -> "ModelGeometry":
         """Derive the cost model from a ``TransformerConfig``.  MoE
-        counts ACTIVE parameters per token (top-k routed + shared
-        expert); attention uses the dense per-layer shape."""
+        FLOPs count ACTIVE parameters per token (top-k routed + shared
+        expert); MoE bytes count the experts a step's tokens touch
+        (``step_bytes``); attention uses the dense per-layer shape."""
         h = cfg.hidden_size
         q_dim = cfg.num_heads * cfg.head_dim
         kv_dim = cfg.num_kv_heads * cfg.head_dim
         attn_params = h * q_dim + 2 * h * kv_dim + q_dim * h
+        expert_params = experts = top_k = 0
         if getattr(cfg, "moe", False):
             inter = cfg.moe_intermediate_size or cfg.intermediate_size
-            mlp_params = (cfg.num_experts_per_tok * 3 * h * inter
-                          + (3 * h * cfg.shared_expert_size
-                             if getattr(cfg, "shared_expert_size", 0)
-                             else 0))
+            experts, top_k = cfg.num_experts, cfg.num_experts_per_tok
+            expert_params = 3 * h * inter
+            # read by every dispatch: the router and the shared expert
+            fixed_mlp = h * experts + (
+                3 * h * cfg.shared_expert_size
+                if getattr(cfg, "shared_expert_size", 0) else 0)
         else:
-            mlp_params = 3 * h * cfg.intermediate_size
-        per_layer = attn_params + mlp_params
-        active_params = cfg.num_layers * per_layer
+            fixed_mlp = 3 * h * cfg.intermediate_size
+        active_params = cfg.num_layers * (
+            attn_params + fixed_mlp + top_k * expert_params)
         return cls(
             flops_per_token=2.0 * active_params,
             attn_flops_per_ctx=(4.0 * cfg.num_heads * cfg.head_dim
                                 * cfg.num_layers),
             lm_head_flops_per_row=2.0 * h * cfg.vocab_size,
-            weight_bytes=float(active_params * dtype_bytes
-                               + h * cfg.vocab_size * dtype_bytes),
+            weight_bytes=float(dtype_bytes * (
+                cfg.num_layers * (attn_params + fixed_mlp)
+                + h * cfg.vocab_size)),
             kv_bytes_per_pos=float(2 * cfg.num_layers * kv_dim
                                    * dtype_bytes),
+            expert_bytes=float(expert_params * dtype_bytes),
+            num_experts=experts, experts_per_tok=top_k,
+            num_layers=cfg.num_layers,
         )
 
     # ----------------------------------------------------------- costs
@@ -109,11 +128,23 @@ class ModelGeometry:
                 + self.attn_flops_per_ctx * ctx_positions
                 + self.lm_head_flops_per_row * sampled_rows)
 
+    def experts_touched(self, new_tokens: int) -> float:
+        """Experts of one layer that ``new_tokens`` tokens are expected
+        to route to under uniform routing, ``E x (1 - (1 - k/E)^n)``:
+        ``k`` for one token, nearly all ``E`` for a full batch."""
+        e, k = self.num_experts, self.experts_per_tok
+        if e <= 0 or new_tokens <= 0:
+            return 0.0
+        return e * (1.0 - (1.0 - k / e) ** new_tokens)
+
     def step_bytes(self, new_tokens: int, ctx_positions: float) -> float:
-        """Achieved HBM traffic of one step: weights read once per
-        dispatch, KV read per attended position, KV written per new
+        """Achieved HBM traffic of one step: the weights it touches
+        read once (a 64-row step reads ~126 of 128 experts a layer,
+        not 8), KV read per attended position, KV written per new
         position."""
         return (self.weight_bytes
+                + (self.num_layers * self.experts_touched(new_tokens)
+                   * self.expert_bytes)
                 + self.kv_bytes_per_pos * ctx_positions
                 + self.kv_bytes_per_pos * new_tokens)
 

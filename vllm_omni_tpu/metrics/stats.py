@@ -342,6 +342,11 @@ class EngineStepMetrics:
         # arrival -> FIRST time scheduled, per request (the queueing
         # component the serving curve bends on)
         self.queue_wait_ms = Histogram()
+        # HTTP accept -> LLMEngine.add_request, per request that the
+        # front end stamped (``accept_mono`` in additional_information):
+        # what the handler, the orchestrator's intake queue and the
+        # stage's tokenizer add in front of the engine
+        self.intake_ms = Histogram()
         # SLO targets (None = unconfigured leg always passes) + the
         # per-tenant attainment/goodput ledger they gate
         self.slo_ttft_ms: Optional[float] = None
@@ -480,6 +485,7 @@ class EngineStepMetrics:
             "batched_tokens": self.batched_tokens.snapshot(),
             "kv_restore_seconds": self.kv_restore_s.snapshot(),
             "queue_wait_ms": self.queue_wait_ms.snapshot(),
+            "intake_ms": self.intake_ms.snapshot(),
             "saturation": dict(self.saturation),
             "slo": {
                 "targets": {"ttft_ms": self.slo_ttft_ms,

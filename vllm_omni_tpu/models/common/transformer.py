@@ -259,6 +259,7 @@ def _mlp(layer, cfg: TransformerConfig, x):
     return nn.linear(layer["down"], silu_mul(nn.linear(layer["gate_up"], x)))
 
 
+@jax.named_scope("omni.proj")
 def _rope_tables(cfg: TransformerConfig, positions):
     """cos/sin tables from positions, 1-D or multimodal 3-D.
 
@@ -279,6 +280,7 @@ def _rope_tables(cfg: TransformerConfig, positions):
     )
 
 
+@jax.named_scope("omni.embed")
 def _embed_input(params, token_ids, inputs_embeds, embeds_mask):
     """Token embedding, or upstream-stage hidden states as inputs.
 
@@ -311,6 +313,11 @@ def _embed_input(params, token_ids, inputs_embeds, embeds_mask):
     return x
 
 
+@jax.named_scope("omni.proj")
+def _final_norm(params, cfg: TransformerConfig, x):
+    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps)
+
+
 def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
     """One transformer block: norm → qkv+rope → ``attend`` → residual →
     norm → MLP → residual.  ``attend(q, k, v)`` supplies the attention
@@ -318,21 +325,24 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
     returns o with leading dims matching ``x``'s.  One body serves every
     forward so the variants cannot silently diverge."""
     b = x.shape[:-1]
-    h = rms_norm(x, layer["input_norm"]["w"], cfg.rms_eps)
-    q, k, v = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("omni.proj"):
+        h = rms_norm(x, layer["input_norm"]["w"], cfg.rms_eps)
+        q, k, v = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     o = attend(q, k, v)
-    o = o.reshape(*b, -1) @ layer["o_proj"]["w"]
-    if cfg.tp_axis is not None:
-        # row-parallel o_proj: each shard holds a partial sum
-        o = jax.lax.psum(o, cfg.tp_axis)
-    x = x + o
-    h = rms_norm(x, layer["post_norm"]["w"], cfg.rms_eps)
-    y = _mlp(layer, cfg, h)
-    if cfg.tp_axis is not None:
-        y = jax.lax.psum(y, cfg.tp_axis)
-    return x + y
+    with jax.named_scope("omni.proj"):
+        o = o.reshape(*b, -1) @ layer["o_proj"]["w"]
+        if cfg.tp_axis is not None:
+            # row-parallel o_proj: each shard holds a partial sum
+            o = jax.lax.psum(o, cfg.tp_axis)
+        x = x + o
+        h = rms_norm(x, layer["post_norm"]["w"], cfg.rms_eps)
+    with jax.named_scope("omni.moe" if cfg.moe else "omni.mlp"):
+        y = _mlp(layer, cfg, h)
+        if cfg.tp_axis is not None:
+            y = jax.lax.psum(y, cfg.tp_axis)
+        return x + y
 
 
 def forward_hidden(
@@ -373,13 +383,14 @@ def forward_hidden(
     cos, sin = _rope_tables(cfg, positions)
 
     def attend(q, k, v):
-        return flash_attention(
-            q.reshape(b, s, -1, cfg.head_dim),
-            k.reshape(b, s, -1, cfg.head_dim),
-            v.reshape(b, s, -1, cfg.head_dim),
-            causal=True,
-            kv_mask=attn_mask,
-        )
+        with jax.named_scope("omni.attn"):
+            return flash_attention(
+                q.reshape(b, s, -1, cfg.head_dim),
+                k.reshape(b, s, -1, cfg.head_dim),
+                v.reshape(b, s, -1, cfg.head_dim),
+                causal=True,
+                kv_mask=attn_mask,
+            )
 
     layers = params["layers"]
     if drop_last_layers:
@@ -399,9 +410,10 @@ def forward_hidden(
             [collected[k] for k in collect_hidden_layers], axis=-1)
     if not apply_final_norm:
         return x
-    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps)
+    return _final_norm(params, cfg, x)
 
 
+@jax.named_scope("omni.lm_head")
 def logits_from_hidden(params, cfg: TransformerConfig, hidden: jax.Array):
     if cfg.tie_word_embeddings:
         # embed table is replicated under TP — logits already full
@@ -443,21 +455,24 @@ def forward_prefill(
     for i, (layer, (k_cache, v_cache)) in enumerate(
             zip(params["layers"], kv_caches)):
         def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
-            k_cache, v_cache = write_kv_cache(
-                k_cache, v_cache, k, v, flat_slots
-            )
+            with jax.named_scope("omni.kv_write"):
+                k_cache, v_cache = write_kv_cache(
+                    k_cache, v_cache, k, v, flat_slots
+                )
             new_caches.append((k_cache, v_cache))
-            return flash_attention(
-                q.reshape(b, s, -1, cfg.head_dim),
-                k.reshape(b, s, -1, cfg.head_dim),
-                v.reshape(b, s, -1, cfg.head_dim),
-                causal=True,
-            )
+            with jax.named_scope("omni.attn"):
+                return flash_attention(
+                    q.reshape(b, s, -1, cfg.head_dim),
+                    k.reshape(b, s, -1, cfg.head_dim),
+                    v.reshape(b, s, -1, cfg.head_dim),
+                    causal=True,
+                )
 
         x = _layer_step(layer, cfg, x, cos, sin, attend)
         if deepstack is not None and i < deepstack.shape[1]:
-            x = x + deepstack[:, i].astype(x.dtype)
-    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps), new_caches
+            with jax.named_scope("omni.embed"):
+                x = x + deepstack[:, i].astype(x.dtype)
+    return _final_norm(params, cfg, x), new_caches
 
 
 def forward_prefill_chunked(
@@ -500,27 +515,32 @@ def forward_prefill_chunked(
     for i, (layer, (k_cache, v_cache)) in enumerate(
             zip(params["layers"], kv_caches)):
         def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
-            k_cache, v_cache = write_kv_cache(
-                k_cache, v_cache, k, v, flat_slots
-            )
+            with jax.named_scope("omni.kv_write"):
+                k_cache, v_cache = write_kv_cache(
+                    k_cache, v_cache, k, v, flat_slots
+                )
             new_caches.append((k_cache, v_cache))
             # gather context pages: [Hkv, B, P, page, D] -> [B, ctx, Hkv, D]
-            # (gather_pages dequantizes the int8 layout's pages)
-            kg = jnp.transpose(
-                gather_pages(k_cache, block_tables), (1, 2, 3, 0, 4)
-            ).reshape(b, ctx_width, hkv, d).astype(k.dtype)
-            vg = jnp.transpose(
-                gather_pages(v_cache, block_tables), (1, 2, 3, 0, 4)
-            ).reshape(b, ctx_width, hkv, d).astype(v.dtype)
-            return flash_attention(
-                q.reshape(b, s, -1, cfg.head_dim), kg, vg,
-                causal=True, kv_mask=kv_mask, q_offsets=q_starts,
-            )
+            # (gather_pages dequantizes the int8 layout's pages); the
+            # gather is this path's way to attend a paged context, so it
+            # is billed to attention
+            with jax.named_scope("omni.attn"):
+                kg = jnp.transpose(
+                    gather_pages(k_cache, block_tables), (1, 2, 3, 0, 4)
+                ).reshape(b, ctx_width, hkv, d).astype(k.dtype)
+                vg = jnp.transpose(
+                    gather_pages(v_cache, block_tables), (1, 2, 3, 0, 4)
+                ).reshape(b, ctx_width, hkv, d).astype(v.dtype)
+                return flash_attention(
+                    q.reshape(b, s, -1, cfg.head_dim), kg, vg,
+                    causal=True, kv_mask=kv_mask, q_offsets=q_starts,
+                )
 
         x = _layer_step(layer, cfg, x, cos, sin, attend)
         if deepstack is not None and i < deepstack.shape[1]:
-            x = x + deepstack[:, i].astype(x.dtype)
-    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps), new_caches
+            with jax.named_scope("omni.embed"):
+                x = x + deepstack[:, i].astype(x.dtype)
+    return _final_norm(params, cfg, x), new_caches
 
 
 def forward_unified(
@@ -566,19 +586,22 @@ def forward_unified(
     for i, (layer, (k_cache, v_cache)) in enumerate(
             zip(params["layers"], kv_caches)):
         def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
-            k_cache, v_cache = write_kv_cache(
-                k_cache, v_cache, k, v, slot_mapping
-            )
+            with jax.named_scope("omni.kv_write"):
+                k_cache, v_cache = write_kv_cache(
+                    k_cache, v_cache, k, v, slot_mapping
+                )
             new_caches.append((k_cache, v_cache))
-            return ragged_paged_attention(
-                q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
-                seq_lens, num_seqs,
-            )
+            with jax.named_scope("omni.attn"):
+                return ragged_paged_attention(
+                    q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
+                    seq_lens, num_seqs,
+                )
 
         x = _layer_step(layer, cfg, x, cos, sin, attend)
         if deepstack is not None and i < deepstack.shape[0]:
-            x = x + deepstack[i].astype(x.dtype)
-    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps), new_caches
+            with jax.named_scope("omni.embed"):
+                x = x + deepstack[i].astype(x.dtype)
+    return _final_norm(params, cfg, x), new_caches
 
 
 def forward_decode(
@@ -595,18 +618,20 @@ def forward_decode(
 
     Returns (hidden [B, hidden], new kv_caches).
     """
-    x = nn.embedding(params["embed"], token_ids)  # [B, hidden]
+    x = _embed_input(params, token_ids, None, None)  # [B, hidden]
     cos, sin = _rope_tables(cfg, positions)
     new_caches = []
     for layer, (k_cache, v_cache) in zip(params["layers"], kv_caches):
         def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
-            k_cache, v_cache = write_kv_cache(
-                k_cache, v_cache, k, v, slot_mapping
-            )
+            with jax.named_scope("omni.kv_write"):
+                k_cache, v_cache = write_kv_cache(
+                    k_cache, v_cache, k, v, slot_mapping
+                )
             new_caches.append((k_cache, v_cache))
-            return paged_attention(
-                q, k_cache, v_cache, block_tables, context_lens
-            )
+            with jax.named_scope("omni.attn"):
+                return paged_attention(
+                    q, k_cache, v_cache, block_tables, context_lens
+                )
 
         x = _layer_step(layer, cfg, x, cos, sin, attend)
-    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps), new_caches
+    return _final_norm(params, cfg, x), new_caches

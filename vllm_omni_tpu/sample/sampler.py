@@ -91,6 +91,7 @@ class SamplingTensors:
 
 
 @jax.jit
+@jax.named_scope("omni.sampler")
 def _build_keys(seeds: jax.Array, salts: jax.Array, step: jax.Array):
     """[B] seeds + [B] salts + scalar step -> [B, 2] key data, vmapped into
     a single compiled dispatch. Seeded requests pass salt 0 so their stream
@@ -147,6 +148,7 @@ def _filtered_scaled(logits, temperature, top_k, top_p):
 
 
 @jax.jit
+@jax.named_scope("omni.sampler")
 def sample_tokens(
     logits: jax.Array,       # [B, vocab]
     temperature: jax.Array,  # [B]
@@ -180,6 +182,7 @@ def sample_tokens(
 
 
 @jax.jit
+@jax.named_scope("omni.sampler")
 def spec_verify_tokens(
     cand_logits: jax.Array,  # [S, V, vocab] logits at candidate rows
     drafts: jax.Array,       # [S, V-1] draft token ids (0-padded)
@@ -298,6 +301,7 @@ def spec_verify_tokens(
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
+@jax.named_scope("omni.logprobs")
 def compute_logprobs(
     logits: jax.Array,   # [B, vocab]
     tokens: jax.Array,   # [B] sampled ids
@@ -317,6 +321,7 @@ def compute_logprobs(
 
 
 @jax.jit
+@jax.named_scope("omni.sampler")
 def filtered_probs(
     logits: jax.Array,       # [B, vocab]
     temperature: jax.Array,  # [B]

@@ -423,13 +423,15 @@ class ARModelRunner:
                 num_seqs, inputs_embeds=inputs_embeds,
                 embeds_mask=embeds_mask, deepstack=deepstack,
             )
-            cand_hidden = hidden[verify_idx]          # [S, V, H]
+            with jax.named_scope("omni.lm_head"):
+                cand_hidden = hidden[verify_idx]      # [S, V, H]
             logits = tfm.logits_from_hidden(params, cfg_, cand_hidden)
             toks, counts = spec_verify_tokens(
                 logits, drafts, n_cand, temperature, top_k, top_p, keys)
-            ar = jnp.arange(toks.shape[0])
-            last = jnp.maximum(counts - 1, 0)
-            last_tok = toks[ar, last]
+            with jax.named_scope("omni.sampler"):
+                ar = jnp.arange(toks.shape[0])
+                last = jnp.maximum(counts - 1, 0)
+                last_tok = toks[ar, last]
             chosen, top_v, top_i = compute_logprobs(
                 logits[:, 0], toks[:, 0], LOGPROBS_K)
             out = {"tokens": toks, "counts": counts,
@@ -442,7 +444,8 @@ class ARModelRunner:
                 # shape) needs them; without a draft head the [S, H]
                 # array would be dead weight on every lagged retire
                 # transfer
-                out["accept_hidden"] = hidden[verify_idx[ar, last]]
+                with jax.named_scope("omni.sampler"):
+                    out["accept_hidden"] = hidden[verify_idx[ar, last]]
             if collect_:
                 out["hidden"] = hidden
             return out, new_caches
@@ -589,13 +592,16 @@ class ARModelRunner:
         key = (kind,) + tuple(shape_key)
         if key in self._jit_seen:
             self.compile_stats["cache_hits"] += 1
-            return thunk()
+            # a host phase in the profiler's trace (docs/observability.md)
+            with jax.profiler.TraceAnnotation("omni.dispatch"):
+                return thunk()
         self._jit_seen.add(key)
         t0 = time.perf_counter()
         self.compile_stats["in_flight"] = 1
         try:
-            result = thunk()
-            jax.block_until_ready(result)
+            with jax.profiler.TraceAnnotation("omni.dispatch"):
+                result = thunk()
+                jax.block_until_ready(result)
         finally:
             self.compile_stats["in_flight"] = 0
         self.compile_stats["compiles"] += 1
@@ -977,36 +983,50 @@ class ARModelRunner:
         scheds = decodes + prefills
         spec_rows = {i for i, s in enumerate(decodes)
                      if s.num_new_tokens > 1}
-        asm = self._assemble_unified(scheds, spec_rows)
-        tensors, final = self._unified_sampling(scheds, "unified",
-                                                asm.t_pad)
-        feed_dst: list[int] = []
-        feed_src: list[int] = []
-        for i, sc in enumerate(scheds):
-            if sc.start_pos >= sc.request.num_tokens and (
-                    prev is not None
-                    and sc.request.request_id in prev.rows):
-                # input token sampled by the previous dispatch, still
-                # device-resident
-                feed_dst.append(int(asm.cu_q_lens[i]))
-                feed_src.append(prev.rows[sc.request.request_id])
-        token_ids = jnp.asarray(asm.token_ids)
-        if feed_dst:
-            token_ids = token_ids.at[jnp.asarray(feed_dst)].set(
-                prev.tokens[jnp.asarray(feed_src)])
-        # verify tokens are USEFUL work (each is a candidate position
-        # the model scores); only block-alignment slack pads
-        self._note_padding(int(asm.q_lens.sum()), asm.t_pad)
-        if spec_rows:
-            self.spec_stats["verify_steps"] += 1
-        kwargs = {}
-        if asm.embeds is not None:
-            kwargs["inputs_embeds"] = jnp.asarray(
-                asm.embeds, dtype=self.params_dtype)
-            kwargs["embeds_mask"] = jnp.asarray(asm.embeds_mask)
-        if asm.deepstack is not None:
-            kwargs["deepstack"] = jnp.asarray(
-                asm.deepstack, dtype=self.params_dtype)
+        # host phases on the profiler's clock (docs/observability.md):
+        # packing, sampling tensors and host-to-device puts are
+        # omni.assemble; the jitted call alone is omni.dispatch (_run_jit)
+        with jax.profiler.TraceAnnotation("omni.assemble"):
+            asm = self._assemble_unified(scheds, spec_rows)
+            tensors, final = self._unified_sampling(scheds, "unified",
+                                                    asm.t_pad)
+            feed_dst: list[int] = []
+            feed_src: list[int] = []
+            for i, sc in enumerate(scheds):
+                if sc.start_pos >= sc.request.num_tokens and (
+                        prev is not None
+                        and sc.request.request_id in prev.rows):
+                    # input token sampled by the previous dispatch, still
+                    # device-resident
+                    feed_dst.append(int(asm.cu_q_lens[i]))
+                    feed_src.append(prev.rows[sc.request.request_id])
+            token_ids = jnp.asarray(asm.token_ids)
+            if feed_dst:
+                token_ids = token_ids.at[jnp.asarray(feed_dst)].set(
+                    prev.tokens[jnp.asarray(feed_src)])
+            # verify tokens are USEFUL work (each is a candidate position
+            # the model scores); only block-alignment slack pads
+            self._note_padding(int(asm.q_lens.sum()), asm.t_pad)
+            if spec_rows:
+                self.spec_stats["verify_steps"] += 1
+            kwargs = {}
+            if asm.embeds is not None:
+                kwargs["inputs_embeds"] = jnp.asarray(
+                    asm.embeds, dtype=self.params_dtype)
+                kwargs["embeds_mask"] = jnp.asarray(asm.embeds_mask)
+            if asm.deepstack is not None:
+                kwargs["deepstack"] = jnp.asarray(
+                    asm.deepstack, dtype=self.params_dtype)
+            args = (
+                jnp.asarray(asm.positions), jnp.asarray(asm.slots),
+                jnp.asarray(asm.tables), jnp.asarray(asm.seq_lens),
+                jnp.asarray(asm.cu_q_lens), jnp.asarray(asm.q_lens),
+                # numpy first: a Python list would compile a convert op
+                jnp.asarray(np.asarray([len(scheds)], np.int32)),
+                jnp.asarray(asm.verify_idx), jnp.asarray(asm.n_cand),
+                jnp.asarray(asm.drafts),
+                tensors.temperature, tensors.top_k, tensors.top_p,
+                tensors.keys)
         outs, self.kv_caches = self._run_jit(
             "unified",
             # the deepstack LEVEL COUNT is part of the operand shape —
@@ -1018,16 +1038,7 @@ class ARModelRunner:
              asm.deepstack.shape[0] if asm.deepstack is not None else 0,
              self._kv_quant),
             lambda: self._unified_fn(
-                self.params, token_ids, self.kv_caches,
-                jnp.asarray(asm.positions), jnp.asarray(asm.slots),
-                jnp.asarray(asm.tables), jnp.asarray(asm.seq_lens),
-                jnp.asarray(asm.cu_q_lens), jnp.asarray(asm.q_lens),
-                # numpy first: a Python list would compile a convert op
-                jnp.asarray(np.asarray([len(scheds)], np.int32)),
-                jnp.asarray(asm.verify_idx), jnp.asarray(asm.n_cand),
-                jnp.asarray(asm.drafts),
-                tensors.temperature, tensors.top_k, tensors.top_p,
-                tensors.keys, **kwargs))
+                self.params, token_ids, self.kv_caches, *args, **kwargs))
         return InflightDecode(
             tokens=outs["last_tok"],
             rows={sc.request.request_id: i for i, sc in final},
@@ -1048,47 +1059,48 @@ class ARModelRunner:
         still in flight) are gathered device-side from ``prev.tokens``.
         The engine retires the handle one step later (``retire_step``)."""
         self._step += 1
-        b = self._decode_bucket(len(scheds))
-        token_host = np.zeros((b,), np.int32)
-        feed_rows: list[int] = []
-        feed_src: list[int] = []
-        params_list = [_PAD_SAMPLING] * b
-        salts = [0] * b
-        want_lp = False
-        for i, sc in enumerate(scheds):
-            req = sc.request
-            if sc.start_pos < req.num_tokens:
-                token_host[i] = req.all_token_ids[sc.start_pos]
-            else:
-                # input token still in flight from the previous dispatch
-                feed_rows.append(i)
-                feed_src.append(prev.rows[req.request_id])
-            params_list[i] = req.sampling_params
-            salts[i] = self._salt_of(req.request_id)
-            if req.sampling_params.logprobs is not None:
-                want_lp = True
-        positions, slots, tables, ctx = self._assemble_decode_rows(
-            scheds, b)
-        token_ids = jnp.asarray(token_host)
-        if feed_rows:
-            token_ids = token_ids.at[jnp.asarray(feed_rows)].set(
-                prev.tokens[jnp.asarray(feed_src)])
-        kind = "dispatch_lp" if want_lp else "dispatch"
-        fn = self._decode_lp_fn if want_lp else self._decode_sample_fn
-        key = (kind, b) + tuple(
-            (sc.request.request_id,) + _params_key(
-                sc.request.sampling_params) for sc in scheds)
-        tensors = self._sampling_tensors(key, params_list, salts)
-        self._note_padding(len(scheds), b)
-        outs, self.kv_caches = self._run_jit(
-            kind, (b, self._kv_quant), lambda: fn(
-                self.params, token_ids, self.kv_caches,
+        with jax.profiler.TraceAnnotation("omni.assemble"):
+            b = self._decode_bucket(len(scheds))
+            token_host = np.zeros((b,), np.int32)
+            feed_rows: list[int] = []
+            feed_src: list[int] = []
+            params_list = [_PAD_SAMPLING] * b
+            salts = [0] * b
+            want_lp = False
+            for i, sc in enumerate(scheds):
+                req = sc.request
+                if sc.start_pos < req.num_tokens:
+                    token_host[i] = req.all_token_ids[sc.start_pos]
+                else:
+                    # input token still in flight from the previous
+                    # dispatch
+                    feed_rows.append(i)
+                    feed_src.append(prev.rows[req.request_id])
+                params_list[i] = req.sampling_params
+                salts[i] = self._salt_of(req.request_id)
+                if req.sampling_params.logprobs is not None:
+                    want_lp = True
+            positions, slots, tables, ctx = self._assemble_decode_rows(
+                scheds, b)
+            token_ids = jnp.asarray(token_host)
+            if feed_rows:
+                token_ids = token_ids.at[jnp.asarray(feed_rows)].set(
+                    prev.tokens[jnp.asarray(feed_src)])
+            kind = "dispatch_lp" if want_lp else "dispatch"
+            fn = self._decode_lp_fn if want_lp else self._decode_sample_fn
+            key = (kind, b) + tuple(
+                (sc.request.request_id,) + _params_key(
+                    sc.request.sampling_params) for sc in scheds)
+            tensors = self._sampling_tensors(key, params_list, salts)
+            self._note_padding(len(scheds), b)
+            args = (
                 jnp.asarray(positions), jnp.asarray(slots),
                 jnp.asarray(tables), jnp.asarray(ctx),
                 tensors.temperature, tensors.top_k, tensors.top_p,
-                tensors.keys,
-            )
-        )
+                tensors.keys)
+        outs, self.kv_caches = self._run_jit(
+            kind, (b, self._kv_quant), lambda: fn(
+                self.params, token_ids, self.kv_caches, *args))
         return InflightDecode(
             tokens=outs["tokens"],
             rows={sc.request.request_id: i for i, sc in enumerate(scheds)},
@@ -1105,6 +1117,11 @@ class ARModelRunner:
         spec-accepted lists), appends logprob entries and hidden
         chunks, and proposes the next drafts — every per-request side
         effect of the step happens here, behind the single transfer."""
+        with jax.profiler.TraceAnnotation("omni.readback"):
+            return self._readback(handle)
+
+    def _readback(self, handle: InflightDecode
+                  ) -> dict[str, "int | list[int]"]:
         # omnilint: disable=OL2 - the single lagged retire sync of the
         # async pipeline: by the time the engine calls this, the NEXT
         # step is already dispatched, so this get overlaps its compute
