@@ -1,0 +1,182 @@
+"""Time the ragged paged-attention kernel alone on the chip, at the
+shapes of the benchmark's cells, over candidate (q_block,
+pages_per_block, dma_slots) triples (ops/autotune.py
+``auto_ragged_blocks`` is checked against this table; PERF.md holds it).
+
+    chiprun -- python -m scripts.ragged_kernel_sweep
+
+Each case is one packed step (Hkv 4, group 8, D 128, page 16, a pool of
+8192 pages, 64 metadata rows over 528 table columns, bf16): decode rows
+first, then one prefill chunk, packed as ``ARModelRunner`` packs them.
+Every candidate is checked against a plain per-sequence attention
+computed in float32 on the same device, then timed (median of
+``--reps`` calls, each ended by ``block_until_ready``).  A candidate the
+compiler refuses is reported, not fatal.  ``--tiny`` runs a small
+version anywhere (the CPU takes the kernel in interpret mode): a
+rehearsal of the script, never a speed.
+"""
+
+import argparse
+import importlib
+import json
+import os
+import statistics
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+rpa = importlib.import_module("vllm_omni_tpu.ops.ragged_paged_attention")
+from vllm_omni_tpu.ops.autotune import auto_ragged_blocks  # noqa: E402
+
+TB = rpa.DEFAULT_TOKEN_BLOCK
+
+# (name, packed bucket T, decode rows, decode context, chunk tokens,
+#  chunk context including the chunk)
+CASES = [
+    ("doc_ctx4096", 4096, 15, 4100, 2048, 4096),
+    ("doc_ctx8192", 4096, 15, 4100, 2048, 8192),
+    ("chat_p1024", 2048, 63, 400, 1024, 1024),
+    ("chat_p256", 1024, 63, 400, 256, 256),
+]
+TINY_CASES = [("tiny", 128, 3, 40, 64, 96)]
+
+# (q_block, pages_per_block, dma_slots); the first row is the tile the
+# kernel had before PR 26 (one 8-token block, one page an iteration)
+CANDIDATES = [
+    (8, 1, 4), (8, 16, 2), (16, 16, 2), (32, 8, 2), (32, 16, 2),
+    (32, 16, 3), (32, 32, 2), (64, 16, 2), (64, 16, 3), (64, 32, 2),
+    (64, 32, 3), (64, 64, 2), (128, 16, 2), (128, 32, 2),
+]
+
+
+def build(case, hkv, group, d, page, pool, s_max, max_pages, dtype, seed):
+    """One packed step: (args of _ragged_attention, per-sequence specs)."""
+    _, t, n_dec, dec_ctx, chunk, chunk_ctx = case
+    rng = np.random.default_rng(seed)
+    specs = [(dec_ctx + 7 * i, 1) for i in range(n_dec)]
+    specs.append((chunk_ctx, chunk))
+    cu = np.zeros(s_max + 1, np.int32)
+    q_lens = np.zeros(s_max, np.int32)
+    seq_lens = np.zeros(s_max, np.int32)
+    tables = np.zeros((s_max, max_pages), np.int32)
+    perm = rng.permutation(np.arange(1, pool))  # scattered, page 0 unused
+    total = used = 0
+    for i, (ctx, qn) in enumerate(specs):
+        cu[i], q_lens[i], seq_lens[i] = total, qn, ctx
+        total += rpa.align_to_block(qn, TB)
+        pn = -(-ctx // page)
+        tables[i, :pn] = perm[used: used + pn]
+        used += pn
+    assert total <= t and used <= perm.size, (total, t, used)
+    cu[len(specs):] = total
+    key = jax.random.PRNGKey(seed)
+    kq, kk, kv = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (t, hkv * group, d), jnp.float32).astype(dtype)
+    shape = (hkv, pool, page, d)
+    k_cache = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+    v_cache = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
+    args = (q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(cu),
+            jnp.asarray(q_lens), jnp.asarray(seq_lens),
+            jnp.asarray([len(specs)], jnp.int32))
+    return args, specs, cu
+
+
+@jax.jit
+def _dense_seq(q, k, v, ctx_len):
+    """Plain causal attention of one sequence's chunk in float32:
+    q [qn, Hkv, G, D] sits at the LAST qn positions of k/v [Hkv, C, D]."""
+    qn, c = q.shape[0], k.shape[1]
+    s = jnp.einsum("thgd,hcd->hgtc", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / np.sqrt(q.shape[-1])
+    q_pos = ctx_len - qn + jnp.arange(qn)
+    mask = jnp.arange(c)[None, :] <= q_pos[:, None]
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("hgtc,hcd->thgd", p, v.astype(jnp.float32))
+
+
+def reference(args, specs, cu, page):
+    q, k_cache, v_cache, tables = args[:4]
+    t, h, d = q.shape
+    hkv = k_cache.shape[0]
+    out = np.zeros((t, h, d), np.float32)
+    for i, (ctx, qn) in enumerate(specs):
+        pn = -(-ctx // page)
+        ids = tables[i, :pn]
+        k = k_cache[:, ids].reshape(hkv, pn * page, d)[:, :ctx]
+        v = v_cache[:, ids].reshape(hkv, pn * page, d)[:, :ctx]
+        lo = int(cu[i])
+        qi = q[lo: lo + qn].reshape(qn, hkv, h // hkv, d)
+        out[lo: lo + qn] = np.asarray(
+            _dense_seq(qi, k, v, ctx)).reshape(qn, h, d)
+    return out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--reps", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=26)
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/ragged_sweep.json")
+    ns = ap.parse_args()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
+    if ns.tiny:
+        geo = dict(hkv=2, group=2, d=128, page=8, pool=64, s_max=8,
+                   max_pages=16, dtype=jnp.float32)
+        cases, cands, tol = TINY_CASES, [(8, 1, 2), (16, 2, 2), (32, 4, 3)], 1e-4
+    else:
+        assert dev.platform == "tpu", "times come from the chip only"
+        geo = dict(hkv=4, group=8, d=128, page=16, pool=8192, s_max=64,
+                   max_pages=528, dtype=jnp.bfloat16)
+        cases, cands, tol = CASES, CANDIDATES, 4e-2
+    auto = auto_ragged_blocks(
+        head_dim=geo["d"], page_size=geo["page"], group=geo["group"],
+        kv_itemsize=jnp.dtype(geo["dtype"]).itemsize,
+        q_itemsize=jnp.dtype(geo["dtype"]).itemsize)
+    print(f"auto_ragged_blocks -> {auto}")
+    if auto not in cands:
+        cands = cands + [auto]
+    rows = []
+    for case in cases:
+        args, specs, cu = build(case, seed=ns.seed, **geo)
+        want = reference(args, specs, cu, geo["page"])
+        for bq, ppb, slots in cands:
+            def run(a=args, bq=bq, ppb=ppb, slots=slots):
+                return rpa._ragged_attention(
+                    *a, None, TB, True, bq, ppb, slots)
+            row = {"case": case[0], "q_block": bq, "pages_per_block": ppb,
+                   "dma_slots": slots, "auto": (bq, ppb, slots) == auto}
+            try:
+                got = np.asarray(jax.block_until_ready(run()), np.float32)
+                times = []
+                for _ in range(ns.reps):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run())
+                    times.append((time.perf_counter() - t0) * 1e3)
+                row["ms"] = statistics.median(times)
+                row["max_err"] = float(np.abs(got - want).max())
+                row["ok"] = bool(row["max_err"] <= tol)
+            except Exception as e:  # a refused candidate is a finding
+                row["refused"] = str(e).splitlines()[0][:200]
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    os.makedirs(os.path.dirname(ns.out) or ".", exist_ok=True)
+    with open(ns.out, "w") as f:
+        json.dump({"device": dev.device_kind, "platform": dev.platform,
+                   "auto": auto, "rows": rows}, f, indent=1)
+    print(f"\n{'case':<12} {'bq':>3} {'ppb':>3} {'slots':>5} "
+          f"{'ms':>9} {'max_err':>9}")
+    for r in rows:
+        tail = (f"{r['ms']:9.3f} {r['max_err']:9.2e}"
+                + ("" if r["ok"] else "  WRONG") if "ms" in r
+                else f"  refused: {r['refused']}")
+        print(f"{r['case']:<12} {r['q_block']:>3} {r['pages_per_block']:>3} "
+              f"{r['dma_slots']:>5} {tail}" + ("  <- auto" if r["auto"] else ""))
+    bad = [r for r in rows if "ms" in r and not r["ok"]]
+    raise SystemExit(1 if bad else 0)
+
+
+if __name__ == "__main__":
+    main()

@@ -202,7 +202,8 @@ def test_engine_appends_step_records(stepped_engine):
     r = records[-1]
     for key in ("path", "unified", "fallback", "prefills", "decodes",
                 "spec_rows", "verify_tokens", "new_tokens",
-                "prefill_tokens", "waiting", "running",
+                "prefill_tokens", "prefill_ctx", "decode_ctx",
+                "wide_q_tokens", "waiting", "running",
                 "host_ms", "device_ms", "kv_offloads", "kv_restores",
                 "slot", "compiles", "requests", "seq", "ts"):
         assert key in r, f"record missing {key}"
@@ -245,6 +246,39 @@ def test_spec_step_records_verify_rows():
     plain = [r for r in eng.flight.tail() if not r["spec_rows"]]
     for rec in plain:
         assert rec["verify_tokens"] == 0
+
+
+def test_flight_record_carries_wide_q_tokens_and_the_snapshot_their_sum():
+    """A 20-token prompt alone in its step packs as three sub-blocks of
+    one owner: whatever q block the runner derived, every block is that
+    sequence's, so all 20 tokens ride wide tiles.  Two prompts of 5 and
+    3 tokens share a q block wider than the alignment (narrow); pure
+    decode steps never reach the ragged kernel."""
+    from tests.helpers import tiny_lm_factory
+    from vllm_omni_tpu.engine.llm_engine import EngineConfig, LLMEngine
+    from vllm_omni_tpu.ops.ragged_paged_attention import wide_q_tokens
+    from vllm_omni_tpu.sampling_params import SamplingParams
+
+    params, cfg, _ = tiny_lm_factory()
+    engine = LLMEngine(params, cfg, EngineConfig(
+        num_pages=32, page_size=4, max_model_len=64, max_num_seqs=4))
+    sp = SamplingParams(temperature=0.0, max_tokens=2, ignore_eos=True)
+    engine.add_request(list(range(1, 21)), sp)
+    engine.step()
+    engine.step()
+    prefill, decode = engine.flight.tail(2)
+    assert (prefill["prefill_tokens"], prefill["wide_q_tokens"]) == (20, 20)
+    assert (decode["decodes"], decode["wide_q_tokens"]) == (1, 0)
+    engine.add_request([1, 2, 3, 4, 5], sp)
+    engine.add_request([6, 7, 8], sp)
+    engine.step()
+    pair = engine.flight.tail(1)[0]
+    assert pair["prefills"] == 2
+    assert pair["wide_q_tokens"] == wide_q_tokens(
+        [5, 3], engine.runner.q_block)
+    assert isinstance(pair["wide_q_tokens"], int)
+    padding = engine.step_metrics.snapshot()["padding"]
+    assert padding["wide_q_tokens_total"] == 20 + pair["wide_q_tokens"]
 
 
 def test_kv_move_counts_consumed_per_record():

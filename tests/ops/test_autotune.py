@@ -1,12 +1,19 @@
 """Shared per-shape block selection (ops/autotune.py): the dense
 kernel's (block_q, block_k) picker and the ragged paged kernel's
-(token_block, dma_slots) picker, guaranteed-fit fallbacks included."""
+(q_block, pages_per_block, dma_slots) picker, guaranteed-fit fallbacks
+included."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import pytest
+
 from vllm_omni_tpu.ops.autotune import (
+    RAGGED_KV_BLOCK,
+    RAGGED_MAX_PAGES_PER_BLOCK,
+    RAGGED_MAX_Q_BLOCK,
+    RAGGED_ROWS,
     RAGGED_VMEM_CAP,
     auto_blocks,
     auto_ragged_blocks,
@@ -33,42 +40,67 @@ def test_auto_blocks_guaranteed_fit():
     assert bq * bk <= cap
 
 
-def test_auto_ragged_blocks_decode_heavy_pins_min_tile():
-    """Serving default: decode-heavy pins the q block at the minimum
-    tile (a decode row costs token_block packed rows) and takes the
-    deepest DMA pipeline that fits."""
-    tb, slots = auto_ragged_blocks(head_dim=128, page_size=16, group=4,
-                                   kv_itemsize=2, q_itemsize=2)
-    assert tb == 8
-    assert slots == 4  # 2*4*16*128*2 = 32 KiB of KV buffers: fits easily
+def test_auto_ragged_blocks_thinker_shape():
+    """The benchmark's shape (32 q / 4 kv heads x 128, 16-token bf16
+    pages): the row the chip sweep chose (PERF.md, PR 26) — 64-token q
+    tiles (512 rows), 32 pages (512 keys) an iteration, double
+    buffering — and the same for the int8 layout."""
+    assert auto_ragged_blocks(head_dim=128, page_size=16, group=8,
+                              kv_itemsize=2, q_itemsize=2) == (64, 32, 2)
+    assert auto_ragged_blocks(head_dim=128, page_size=16, group=8,
+                              kv_itemsize=1, q_itemsize=2,
+                              quantized=True) == (64, 32, 2)
+
+
+@pytest.mark.parametrize("group", [1, 2, 4, 8, 16, 64])
+@pytest.mark.parametrize("page_size", [8, 16, 128])
+def test_auto_ragged_blocks_derives_the_tile_from_the_shapes(group,
+                                                             page_size):
+    """q_block comes from the arithmetic (rows = q_block x group), not
+    from a constant: a power-of-two multiple of the 8-token packing
+    alignment, the widest at or under RAGGED_ROWS rows and
+    RAGGED_MAX_Q_BLOCK tokens; the KV block aims at RAGGED_KV_BLOCK
+    keys whatever the page size, in at most RAGGED_MAX_PAGES_PER_BLOCK
+    page copies."""
+    bq, ppb, slots = auto_ragged_blocks(head_dim=128, page_size=page_size,
+                                        group=group, kv_itemsize=2,
+                                        q_itemsize=2)
+    assert bq % 8 == 0 and bq & (bq - 1) == 0
+    assert bq == max(8, min(RAGGED_MAX_Q_BLOCK, RAGGED_ROWS // group))
+    assert ppb == min(RAGGED_MAX_PAGES_PER_BLOCK,
+                      RAGGED_KV_BLOCK // page_size)
+    assert slots == 2
 
 
 def test_auto_ragged_blocks_guaranteed_fit():
     """A VMEM budget below every candidate degrades to the smallest
-    working set (classic double buffering) instead of failing."""
-    tb, slots = auto_ragged_blocks(head_dim=4096, page_size=512,
-                                   group=16, kv_itemsize=4,
-                                   q_itemsize=4, vmem_cap_bytes=1 << 16)
-    assert (tb, slots) == (8, 2)
+    working set the kernel supports (one 8-token sub-block, one page,
+    classic double buffering) instead of failing."""
+    assert auto_ragged_blocks(head_dim=4096, page_size=512, group=16,
+                              kv_itemsize=4, q_itemsize=4,
+                              vmem_cap_bytes=1 << 16) == (8, 1, 2)
 
 
 def test_auto_ragged_blocks_budget_monotone():
-    """Shrinking the budget never deepens the pipeline."""
-    depths = []
-    for cap in (RAGGED_VMEM_CAP, RAGGED_VMEM_CAP // 8,
-                RAGGED_VMEM_CAP // 64):
-        _, slots = auto_ragged_blocks(head_dim=256, page_size=128,
-                                      group=8, kv_itemsize=2,
-                                      q_itemsize=2, vmem_cap_bytes=cap)
-        depths.append(slots)
-    assert depths == sorted(depths, reverse=True)
-    assert depths[-1] >= 2
+    """Shrinking the budget never grows the tile: the KV block halves
+    first, then the q block."""
+    picks = [auto_ragged_blocks(head_dim=256, page_size=128, group=8,
+                                kv_itemsize=2, q_itemsize=2,
+                                vmem_cap_bytes=cap)
+             for cap in (RAGGED_VMEM_CAP, RAGGED_VMEM_CAP // 8,
+                         RAGGED_VMEM_CAP // 64, RAGGED_VMEM_CAP // 512)]
+    for (bq0, ppb0, _), (bq1, ppb1, _) in zip(picks, picks[1:]):
+        assert bq1 <= bq0 and ppb1 <= ppb0
+    assert picks[0] != picks[-1]
+    # the q block gives way only once the KV block is down to a page
+    assert all(ppb == 1 for bq, ppb, _ in picks if bq < picks[0][0])
+    assert all(slots >= 2 for _, _, slots in picks)
 
 
 def test_ragged_kernel_matches_ref_at_deeper_dma(monkeypatch):
-    """The N-deep page-DMA pipeline (interpret mode) is numerically
-    identical to the XLA reference at every supported depth — the
-    autotuner may pick any of them."""
+    """The N-deep KV-block DMA pipeline (interpret mode) is numerically
+    identical to the XLA reference at every supported depth, over
+    one-page KV blocks so that a context is several of them."""
     from vllm_omni_tpu.ops.ragged_paged_attention import (
         ragged_paged_attention,
         ragged_paged_attention_ref,
@@ -95,7 +127,7 @@ def test_ragged_kernel_matches_ref_at_deeper_dma(monkeypatch):
     want = ragged_paged_attention_ref(*args)
     for slots in (2, 3, 4):
         got = ragged_paged_attention(*args, use_pallas=True,
-                                     dma_slots=slots)
+                                     pages_per_block=1, dma_slots=slots)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), atol=2e-5,
             err_msg=f"dma_slots={slots}")

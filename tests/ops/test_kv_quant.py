@@ -162,21 +162,30 @@ CASES = {
     "mixed": [(24, 9), (1, 1), (13, 13), (30, 1)],
     "decode_only": [(9, 1), (4, 1), (14, 1)],
     "prefill_only": [(16, 16), (11, 11)],
+    # tests/ops/test_ragged_paged_attention.py's case of the same name:
+    # six 8-key KV blocks of two pages, each page under its own scale,
+    # the last block ragged; behind a decode row, so the narrow path
+    # reads another sequence's scales in the same q block
+    "kv_blocks_ragged_tail": [(9, 1), (45, 19)],
 }
+#: the kernel's tile where a case pins it (q_block, pages_per_block)
+TILES = {"kv_blocks_ragged_tail": dict(q_block=16, pages_per_block=2)}
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_ragged_quant_kernel_matches_quant_ref(name, use_pallas):
-    """Kernel-side in-register dequant == reference gather-dequant on
-    the same int8 cache: the scales ride the DMA identically."""
+    """Kernel-side in-loop dequant (per-page scales on the score and
+    probability columns) == reference gather-dequant on the same int8
+    cache."""
     hkv, d, page = 2, 32, 4
     (q, _, quant, tables, cu, q_lens, seq_lens, n) = _build_pair(
         CASES[name], hkv, d, page, s_max=6, max_pages=12,
         seed=sum(map(ord, name)) % 89)
     kq, vq = quant
     got = ragged_paged_attention(q, kq, vq, tables, cu, q_lens,
-                                 seq_lens, n, use_pallas=use_pallas)
+                                 seq_lens, n, use_pallas=use_pallas,
+                                 **TILES.get(name, {}))
     ref = ragged_paged_attention_ref(q, kq, vq, tables, cu, q_lens,
                                      seq_lens, n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -255,10 +264,10 @@ def test_decode_quant_matches_quant_ref(use_pallas):
 
 # ------------------------------------------------------- autotune
 def test_autotune_runs_per_layout():
-    """The (token_block, dma_slots) search keys on the layout: the int8
-    layout's budget halves the page buffers and adds dequant staging,
-    so the two layouts are distinct lru entries (and may pick
-    differently)."""
+    """The (q_block, pages_per_block, dma_slots) search keys on the
+    layout: the int8 layout's budget halves the KV buffers and adds a
+    float staging copy, so the two layouts are distinct lru entries
+    (and may pick differently)."""
     base = auto_ragged_blocks(128, 16)
     quant = auto_ragged_blocks(128, 16, quantized=True, kv_itemsize=1)
     assert isinstance(base, tuple) and isinstance(quant, tuple)

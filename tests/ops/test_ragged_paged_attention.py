@@ -15,9 +15,13 @@ from vllm_omni_tpu.ops import (
     write_kv_cache,
 )
 from vllm_omni_tpu.ops.paged_attention import init_kv_cache
-from vllm_omni_tpu.ops.ragged_paged_attention import align_to_block
+from vllm_omni_tpu.ops.ragged_paged_attention import (
+    _block_owners,
+    align_to_block,
+    wide_q_tokens,
+)
 
-TB = 8  # DEFAULT_TOKEN_BLOCK
+TB = 8  # DEFAULT_TOKEN_BLOCK: the packing alignment
 
 
 def _pack(specs, h, hkv, d, page, s_max, max_pages, seed=0):
@@ -87,6 +91,10 @@ def _oracle(q, cu, q_lens, seq_lens, dense, h, d):
     return out
 
 
+# name -> per-sequence (ctx_len, q_len) specs, or a dict that also sets
+# the geometry and the kernel's tile (q_block tokens a grid cell,
+# pages_per_block pages a loop iteration); the plain lists run at the
+# tile ``auto_ragged_blocks`` derives
 CASES = {
     "mixed": [(24, 9), (1, 1), (13, 13), (30, 1)],
     "decode_only": [(9, 1), (4, 1), (14, 1)],
@@ -95,20 +103,50 @@ CASES = {
     "page_boundary": [(8, 8), (16, 1), (4, 4)],   # page=4 multiples
     "block_boundary": [(8, 8), (24, 16), (9, 9)],  # q-block multiples +1
     "single": [(5, 5)],
+    # a chunk over prior context: six 8-key KV blocks, the last ragged
+    # (45 = 5 * 8 + 5), under one whole and one partial 16-token q tile
+    "kv_blocks_ragged_tail": dict(
+        specs=[(45, 19)], q_block=16, pages_per_block=2),
+    # a chunk that starts off a q-block boundary behind two decode
+    # rows: the first 32-token block holds three sequences (sub-block
+    # by sub-block), the second lies inside the chunk (one tile)
+    "wide_block_three_seqs": dict(
+        specs=[(9, 1), (14, 1), (40, 40)], q_block=32,
+        pages_per_block=2),
+    # the first q tile's causal end (key 16 of 80) lies many pages
+    # before the context's: its loop must stop there
+    "causal_skip": dict(
+        specs=[(64, 64), (80, 48)], q_block=16, pages_per_block=2,
+        max_pages=20),
+    # rows = q_block x group: no GQA sharing, and 8 heads a kv head
+    "group_1": dict(
+        specs=[(24, 9), (7, 1), (30, 22)], h=2, hkv=2, q_block=16,
+        pages_per_block=2),
+    "group_8": dict(
+        specs=[(24, 9), (7, 1), (30, 22)], h=8, hkv=1, q_block=16,
+        pages_per_block=2),
 }
+
+
+def _case(name):
+    case = CASES[name]
+    return dict(case) if isinstance(case, dict) else {"specs": case}
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_matches_dense_oracle(name, use_pallas):
-    h, hkv, d, page = 4, 2, 32, 4
-    specs = CASES[name]
+    case = _case(name)
+    specs = case.pop("specs")
+    h, hkv = case.pop("h", 4), case.pop("hkv", 2)
+    d, page = 32, 4
     (q, kc, vc, tables, cu, q_lens, seq_lens, n, dense) = _pack(
-        specs, h, hkv, d, page, s_max=6, max_pages=12,
+        specs, h, hkv, d, page, s_max=6,
+        max_pages=case.pop("max_pages", 12),
         seed=sum(map(ord, name)) % 97)
     got = ragged_paged_attention(
         q, kc, vc, tables, cu, q_lens, seq_lens, n,
-        use_pallas=use_pallas)
+        use_pallas=use_pallas, **case)
     want = _oracle(q, cu, q_lens, seq_lens, dense, h, d)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
                                atol=2e-5)
@@ -206,3 +244,51 @@ def test_num_seqs_zero():
         q, kc, vc, tables, cu, q_lens, seq_lens, 0, use_pallas=True)
     assert kern.shape == q.shape
     assert np.all(np.asarray(kern) == 0.0)
+
+
+def test_wide_q_tokens_hand_made_schedule():
+    """Three decode rows, then a 70-token chunk, packed in that order
+    at 8-token alignment, under 32-token q blocks:
+
+        rows   0..23   three decode sub-blocks
+        rows  24..31   the chunk's first sub-block   -> block 0: four
+                                                        sequences, narrow
+        rows  32..63   chunk tokens  8..39           -> block 1: wide
+        rows  64..95   chunk tokens 40..69 and 2 pad -> block 2: wide
+
+    so 62 of the chunk's 70 tokens ride wide tiles, and no decode row."""
+    assert wide_q_tokens([1, 1, 1, 70], 32) == 62
+    # a q block as narrow as the alignment: every block has one owner
+    assert wide_q_tokens([1, 1, 1, 70], 8) == 73
+    # decode rows alone share their blocks; a lone row has its own
+    assert wide_q_tokens([1] * 8, 32) == 0
+    assert wide_q_tokens([1], 32) == 1
+    # zero-length metadata rows hold no sub-block
+    assert wide_q_tokens([0, 40, 0], 32) == 40
+    assert wide_q_tokens([], 32) == 0
+
+
+@pytest.mark.parametrize("q_lens", [[1, 1, 1, 70], [5, 3], [40], [1] * 9,
+                                    [9, 1, 33, 1]])
+def test_wide_q_tokens_is_the_kernels_lookup(q_lens):
+    """The host count and the in-jit classification the kernel runs on
+    agree: tokens of sub-blocks whose q block has a wide owner."""
+    q_block, s_max = 32, 12
+    cu = np.zeros(s_max + 1, np.int32)
+    ql = np.zeros(s_max, np.int32)
+    total = 0
+    for i, n in enumerate(q_lens):
+        cu[i], ql[i] = total, n
+        total += align_to_block(n, TB)
+    cu[len(q_lens):] = total
+    nbw = -(-max(total, 1) // q_block)
+    nsub = q_block // TB
+    wide_seq, sub_seq = _block_owners(
+        jnp.asarray(cu), jnp.asarray(ql), len(q_lens), nbw, nsub, TB)
+    wide_seq, sub_seq = np.asarray(wide_seq), np.asarray(sub_seq)
+    tokens = 0
+    for b, i in enumerate(sub_seq):
+        if i >= 0 and wide_seq[b // nsub] >= 0:
+            assert wide_seq[b // nsub] == i
+            tokens += min(TB, ql[i] - (b * TB - cu[i]))
+    assert tokens == wide_q_tokens(q_lens, q_block)

@@ -350,7 +350,8 @@ RECOMPILE: dict[str, tuple[str, ...]] = {
     # so the flag MUST ride every dispatch key, threaded through the
     # warmup walker so both layouts compile before traffic)
     "bucket_attrs": ("_token_buckets", "_batch_buckets", "_seq_buckets",
-                     "_token_block", "_dma_slots", "_kv_quant"),
+                     "_token_block", "q_block", "_pages_per_block",
+                     "_dma_slots", "_kv_quant"),
     # attribute reads that ARE per-request counts
     "per_request_attrs": ("num_new_tokens", "num_tokens",
                           "num_computed_tokens", "num_inflight_tokens",

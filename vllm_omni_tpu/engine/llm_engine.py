@@ -45,6 +45,10 @@ from vllm_omni_tpu.core.scheduler import (
     SchedulerOutput,
 )
 from vllm_omni_tpu.models.common import transformer as tfm
+from vllm_omni_tpu.ops.ragged_paged_attention import (
+    DEFAULT_TOKEN_BLOCK,
+    wide_q_tokens,
+)
 from vllm_omni_tpu.outputs import OmniRequestOutput
 from vllm_omni_tpu.request import Request, RequestStatus
 from vllm_omni_tpu.sampling_params import SamplingParams
@@ -883,6 +887,16 @@ class LLMEngine:
                           for s in sched_out.prefills)
         decode_ctx = sum(ctx_positions(s.start_pos, s.num_new_tokens)
                          for s in sched_out.decodes)
+        # new tokens the ragged kernel attends as whole q-block tiles
+        # (decodes pack first; a pure-decode step never reaches it)
+        wide = wide_q_tokens(
+            [s.num_new_tokens
+             for s in sched_out.decodes + sched_out.prefills],
+            getattr(self.runner, "q_block", DEFAULT_TOKEN_BLOCK),
+        ) if unified else 0
+        step_metrics = getattr(self, "step_metrics", None)  # fakes
+        if step_metrics is not None:
+            step_metrics.wide_q_tokens_total += wide
         tracker = getattr(self, "roofline", None)  # duck-typed fakes
         if tracker is not None:
             roofline = tracker.on_step(
@@ -919,6 +933,7 @@ class LLMEngine:
                                   for s in sched_out.prefills),
             "prefill_ctx": int(prefill_ctx),
             "decode_ctx": int(decode_ctx),
+            "wide_q_tokens": wide,
             "waiting": len(self.scheduler.waiting),
             "running": len(self.scheduler.running),
             "host_ms": round(host_ms, 3),

@@ -371,6 +371,9 @@ class EngineStepMetrics:
         # every dispatch (bucketed split path vs. token-packed unified)
         self.useful_tokens_total = 0
         self.padded_tokens_total = 0
+        # new tokens the ragged kernel attended as whole q-block tiles
+        # (LLMEngine._record_step; ops/ragged_paged_attention.py)
+        self.wide_q_tokens_total = 0
 
     def on_schedule(self, waiting: int, running: int) -> None:
         self.num_waiting = waiting
@@ -501,6 +504,7 @@ class EngineStepMetrics:
             "padding": {
                 "useful_tokens_total": self.useful_tokens_total,
                 "padded_tokens_total": self.padded_tokens_total,
+                "wide_q_tokens_total": self.wide_q_tokens_total,
                 "efficiency": round(self.padding_efficiency, 4),
             },
             "overlap": {

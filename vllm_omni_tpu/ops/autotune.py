@@ -7,8 +7,9 @@ PAPERS.md: per-shape tile choice is where the MFU lives; a fixed grid
 ran 13% MFU on the DiT joint sequence, the tuned one 68%).
 
 ``auto_ragged_blocks`` applies it to the ragged paged-attention kernel's
-two knobs — the per-sequence q block (``token_block``) and the page-DMA
-pipeline depth (``dma_slots``) — under the VMEM budget a grid cell
+three — the q tile in tokens (``q_block``), the pages a loop iteration
+fetches and multiplies at once (``pages_per_block``) and the KV-block
+DMA pipeline depth (``dma_slots``) — under the VMEM budget a grid cell
 actually has.  Both pickers preserve the guaranteed-fit fallback: a cap
 below every candidate shrinks the choice instead of crashing.
 """
@@ -21,10 +22,11 @@ import functools
 SCORE_CAP = 2_097_152
 
 #: VMEM byte budget for one ragged grid cell's working set (q block +
-#: f32 accumulator + output + KV DMA buffers + score block).  VMEM is
-#: ~16 MiB/core; the budget leaves headroom for compiler temporaries
-#: and the second grid cell XLA may keep in flight.
-RAGGED_VMEM_CAP = 4 * 1024 * 1024
+#: f32 accumulator + output + KV DMA buffers + score block and its
+#: temporaries).  Mosaic's scoped VMEM limit is 16 MiB a kernel on a
+#: v5e; the budget leaves half of it to what the arithmetic below does
+#: not see (a 64 x 8-row tile over 512 keys counts 5.3 MiB and runs).
+RAGGED_VMEM_CAP = 8 * 1024 * 1024
 
 
 def auto_blocks(sq: int, skv: int, d: int,
@@ -75,6 +77,27 @@ def auto_blocks(sq: int, skv: int, d: int,
     return best[1], best[2]
 
 
+#: q-tile rows (tokens x GQA group) and keys per KV block the ragged
+#: kernel aims for.  Timed on the chip (scripts/ragged_kernel_sweep.py,
+#: PERF.md section 6, PR 26): an iteration costs ~0.5 us beside its
+#: matrix and softmax work (the DMA wait -> QK -> softmax -> PV chain
+#: does not overlap across iterations), so the kernel wants few, large
+#: iterations; 512 x 512 is the last step that pays on every shape of
+#: the benchmark's cells (1024 keys time 4% slower; 1024 rows gain 13%
+#: on 2048-token chunks, nothing on chat steps, and need a whole
+#: 128-token block inside one segment).
+RAGGED_ROWS = 512
+RAGGED_KV_BLOCK = 512
+
+#: page copies one loop iteration may issue per cache half (the loop
+#: body unrolls them)
+RAGGED_MAX_PAGES_PER_BLOCK = 32
+
+#: widest q block in tokens, whatever the group: a block that holds
+#: several sequences walks its 8-token sub-blocks one by one
+RAGGED_MAX_Q_BLOCK = 128
+
+
 @functools.lru_cache(maxsize=64)
 def auto_ragged_blocks(
     head_dim: int,
@@ -82,49 +105,67 @@ def auto_ragged_blocks(
     group: int = 1,
     kv_itemsize: int = 2,
     q_itemsize: int = 4,
-    decode_heavy: bool = True,
     vmem_cap_bytes: int = RAGGED_VMEM_CAP,
     quantized: bool = False,
-) -> tuple[int, int]:
-    """(token_block, dma_slots) for the ragged paged-attention kernel.
+) -> tuple[int, int, int]:
+    """(q_block, pages_per_block, dma_slots) for the ragged
+    paged-attention kernel.
 
     The search runs PER LAYOUT (the lru key includes ``quantized``): the
-    int8 layout halves the page DMA buffers (kv_itemsize is the int8
-    data's) but adds an f32 dequant staging copy of the in-flight page
-    (its per-page scales live in SMEM, not VMEM) — a deeper pipeline may
-    fit quantized where bf16 took 2 slots, and the warmup log shows
-    which choice each layout got.
+    int8 layout halves the KV DMA buffers (kv_itemsize is the int8
+    data's) but adds a float staging copy of the block being consumed
+    (its per-page scales live in SMEM, not VMEM), and the warmup log
+    shows which choice each layout got.
 
-    ``token_block`` is the per-sequence q block in TOKENS and doubles as
-    the host packer's segment alignment — every (packed) decode row
-    costs ``token_block`` rows, so a decode-heavy serving mix
-    (``decode_heavy=True``, the engine default) pins it at 8 (the f32
-    sublane tile at group=1); a prefill-dominated deployment may take 16
-    to halve the number of q blocks — each block re-reads its
-    sequence's whole paged context, so fewer blocks = half the HBM
-    traffic — at 16 rows/decode-row padding cost.
+    ``q_block`` is the kernel's q tile in TOKENS, a power-of-two
+    multiple of the packer's 8-token segment alignment
+    (``DEFAULT_TOKEN_BLOCK``, which it does NOT change: a decode row
+    still costs 8 packed rows).  A q block inside one sequence's
+    segment attends as one ``q_block x group``-row tile, the widest at
+    or under ``RAGGED_ROWS`` rows: group 8 takes 64 tokens, group 1
+    ``RAGGED_MAX_Q_BLOCK``.  Each q block re-reads its sequence's paged
+    context once, so wider also means fewer HBM reads.
 
-    ``dma_slots`` is the HBM→VMEM page pipeline depth: ``slots - 1``
-    pages are in flight while one is being consumed, so deeper pipelines
-    hide more HBM latency (the decode inner loop is DMA-bound — the
-    whole context streams through VMEM once per q block).  Deeper costs
-    ``2 * page_size * head_dim * kv_itemsize`` bytes per extra slot;
-    the picker takes the deepest of (4, 3, 2) that fits the cell
-    budget, and the guaranteed-fit fallback degrades to classic double
-    buffering (2) rather than failing."""
-    for tb in ((8, 16) if decode_heavy else (16, 8)):
-        rows = tb * max(group, 1)
-        # q block (input itemsize) + f32 accumulator + output block
-        fixed = rows * head_dim * (q_itemsize + 4 + kv_itemsize)
-        # f32 score block per page
-        fixed += rows * page_size * 4
+    ``pages_per_block`` is the pages one loop iteration fetches into a
+    contiguous VMEM buffer and multiplies at once: ``RAGGED_KV_BLOCK``
+    keys (32 pages of 16), at least one page.
+
+    ``dma_slots`` is the HBM->VMEM pipeline depth in KV blocks
+    (``slots - 1`` in flight while one is consumed; the kernel takes
+    any depth >= 2).  At these block sizes a third slot timed within
+    2% of two on every shape, either way, so the picker stays at
+    classic double buffering.
+
+    A budget below the first choice halves the KV block, then the q
+    tile; the guaranteed-fit fallback is the smallest working set the
+    kernel supports, (8, 1, 2), rather than a failure."""
+    group = max(group, 1)
+    bq = 8
+    while bq * 2 * group <= RAGGED_ROWS and bq < RAGGED_MAX_Q_BLOCK:
+        bq *= 2
+    ppb = max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
+                     RAGGED_KV_BLOCK // page_size))
+    slots = 2
+
+    def fits(bq, ppb):
+        rows, kvb = bq * group, ppb * page_size
+        # q block and output block (double-buffered by the grid
+        # pipeline) + f32 accumulator
+        need = rows * head_dim * (4 * q_itemsize + 4)
+        # f32 score block and its live temporaries (mask, p, p in the
+        # matmul dtype)
+        need += 4 * rows * kvb * 4
+        need += 2 * slots * kvb * head_dim * kv_itemsize
         if quantized:
-            # the f32 dequant staging copy of the page being consumed
-            # (k and v)
-            fixed += 2 * page_size * head_dim * 4
-        for slots in (4, 3, 2):
-            kv = 2 * slots * page_size * head_dim * kv_itemsize
-            if fixed + kv <= vmem_cap_bytes:
-                return tb, slots
-    # guaranteed fit: the smallest working set the kernel supports
-    return 8, 2
+            # the float staging copy of the block being consumed (k, v)
+            need += 2 * kvb * head_dim * 4
+        return need <= vmem_cap_bytes
+
+    while not fits(bq, ppb):
+        if ppb > 1:
+            ppb //= 2
+        elif bq > 8:
+            bq //= 2
+        else:
+            break
+    return bq, ppb, slots

@@ -13,10 +13,13 @@ Token-packed layout (the metadata contract, see docs/ragged_batching.md):
 - ``q [T, H, D]`` — queries of every scheduled sequence, concatenated.
   Sequence ``i``'s ``q_lens[i]`` query tokens occupy rows
   ``cu_q_lens[i] .. cu_q_lens[i] + q_lens[i])``.  Segment starts are
-  aligned to ``token_block`` rows (the per-sequence q-block size), so a
-  (sequence, q-block) grid cell owns an EXCLUSIVE output region — the
-  alignment gap is at most ``token_block - 1`` rows per sequence,
-  replacing the ``batch_bucket x seq_bucket`` padding of the split path.
+  aligned to ``token_block`` rows (the PACKING alignment, 8: what the
+  packer honours), so a ``token_block``-token sub-block belongs to at
+  most one sequence — the alignment gap is at most ``token_block - 1``
+  rows per sequence, replacing the ``batch_bucket x seq_bucket``
+  padding of the split path.  The kernel's q block (``q_block``, a
+  multiple of ``token_block``) is NOT part of the contract: the kernel
+  derives it from the shapes and the packer never sees it.
 - ``cu_q_lens [S+1]`` — aligned segment starts (row offsets into ``q``);
   ``cu_q_lens[num_seqs]`` is the packed end.  NOT simply the cumsum of
   ``q_lens`` — alignment rounds each segment up.
@@ -33,12 +36,23 @@ sits at ``seq_lens[i] - q_lens[i] + j`` and attends keys at positions
 decode row is the degenerate ``q_lens[i] == 1`` case — last position,
 full context — so decodes and prefill chunks need no special-casing.
 
-The kernel follows ``_paged_decode_kernel``'s structure: each grid cell
-owns one (sequence, q-block) pair — the grid is (kv-head, global
-q-block) and the owning sequence comes from a host-computed SMEM lookup
-(alignment makes the mapping unique) — and online-softmaxes over
-double-buffered HBM→VMEM page DMAs; ``ragged_paged_attention_ref`` is
-the XLA fallback / test oracle.
+The kernel: the grid is (kv-head, global q block of ``q_block``
+tokens), and an in-jit SMEM lookup (``_block_owners``) says what each
+block holds.  A block inside ONE sequence's segment — nearly every
+block of a prefill chunk — attends as one ``q_block x group``-row tile;
+a block that holds several sequences (decode rows, a segment's head or
+tail: the packer puts decodes first, so a chunk starts at a multiple of
+8, not of ``q_block``) walks its ``token_block``-token sub-blocks, each
+against its own sequence's pages.  Either way the cell owns its output
+rows exclusively.  A tile online-softmaxes over KV blocks of
+``pages_per_block`` pages — that many page DMAs into one contiguous
+double-buffered VMEM buffer, one wait, one ``rows x D x keys`` product
+and one back, in the stored dtype with float32 accumulation — and its
+loop ends at the tile's last causal page; only the blocks crossing the
+diagonal are masked.  ``auto_ragged_blocks`` (ops/autotune.py) derives
+(q_block, pages_per_block, dma_slots) from group, head dim, page size
+and cache dtype; ``ragged_paged_attention_ref`` is the XLA fallback /
+test oracle.
 """
 
 from __future__ import annotations
@@ -55,8 +69,6 @@ from jax.experimental.pallas import tpu as pltpu
 from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
 from vllm_omni_tpu.ops.paged_attention import (
-    _page_scale_spec,
-    _table_order_scales,
     cache_data,
     cache_is_quantized,
     cache_shape,
@@ -66,14 +78,22 @@ from vllm_omni_tpu.ops.paged_attention import (
 
 _NEG_INF = -1e30
 
-# Per-sequence q-block size in TOKENS (also the segment alignment the
-# packer must honor).  8 keeps the f32 sublane tile exact at group=1 and
+# Segment alignment in TOKENS that the packer must honor, and the
+# kernel's sub-block.  8 keeps the f32 sublane tile exact at group=1 and
 # bounds per-sequence alignment waste at 7 rows — a decode row costs 8
 # packed rows, vs the full (batch, seq) bucket pad of the split path.
-# ``auto_ragged_blocks`` (ops/autotune.py) picks the per-shape
-# (token_block, dma_slots) pair; this stays the packing contract's
-# default.
+# The q block the kernel tiles with is a multiple of it that
+# ``auto_ragged_blocks`` (ops/autotune.py) derives per shape.
 DEFAULT_TOKEN_BLOCK = 8
+
+
+# Page copies a turn of the kernel's DMA-issue loop, per cache half.
+# Timed on the chip at 32 pages a KV block (PERF.md, PR 26): one copy a
+# turn 3.97 ms on a 2048-token chunk, two 3.74, eight 3.69, all 32
+# unrolled 3.39 — but the unrolled copies were most of the kernel's
+# program: +0.6 s to lower and +5 s to compile EVERY executable that
+# holds it (10 s of a 57 s warm set-up).
+_PAGE_COPY_UNROLL = 2
 
 
 def align_to_block(n: int, token_block: int = DEFAULT_TOKEN_BLOCK) -> int:
@@ -145,27 +165,83 @@ def ragged_paged_attention_ref(
     return o.reshape(t, h, d).astype(q.dtype)
 
 
+def wide_q_tokens(q_lens, q_block: int,
+                  token_block: int = DEFAULT_TOKEN_BLOCK) -> int:
+    """New tokens of a packed step that the kernel attends as whole
+    ``q_block``-token tiles (the wide path of ``_ragged_kernel``).
+
+    Host arithmetic on the scheduler's ``q_lens`` in packing order —
+    the same classification ``_block_owners`` makes inside the jit: a q
+    block is wide when every ``token_block``-token sub-block it holds
+    belongs to the sequence of its first one (or to nobody)."""
+    nsub = q_block // token_block
+    subs: list[int] = []  # real tokens per sub-block, in packed order
+    owner: list[int] = []
+    for i, n in enumerate(q_lens):
+        n = int(n)
+        for lo in range(0, n, token_block):
+            subs.append(min(token_block, n - lo))
+            owner.append(i)
+    total = 0
+    for b in range(0, len(subs), nsub):
+        if all(o == owner[b] for o in owner[b: b + nsub]):
+            total += sum(subs[b: b + nsub])
+    return total
+
+
+def _block_owners(cu_q_lens, q_lens, num_seqs, nbw: int, nsub: int,
+                  token_block: int):
+    """(wide_seq [NBW], sub_seq [NBW * nsub]) — the kernel's lookup.
+
+    ``sub_seq`` is the owning sequence of each ``token_block``-token
+    sub-block (-1 = padding): segment starts are ``token_block``-
+    aligned, so a sub-block belongs to at most one sequence.
+    ``wide_seq`` is the owner of a q block that lies inside ONE
+    sequence's segment (every sub-block its first one's, or nobody's),
+    else -1: such a block attends as one tile, any other sub-block by
+    sub-block."""
+    s_max = q_lens.shape[0]
+    nseq = jnp.asarray(num_seqs, jnp.int32).reshape(())
+    starts = cu_q_lens[:s_max].astype(jnp.int32)
+    bs = jnp.arange(nbw * nsub, dtype=jnp.int32) * token_block
+    in_seq = (
+        (bs[None, :] >= starts[:, None])
+        & (bs[None, :] < (starts + q_lens.astype(jnp.int32))[:, None])
+        & (jnp.arange(s_max)[:, None] < nseq)
+    )
+    sub_seq = jnp.where(jnp.any(in_seq, axis=0),
+                        jnp.argmax(in_seq, axis=0), -1).astype(jnp.int32)
+    sub = sub_seq.reshape(nbw, nsub)
+    first = sub[:, :1]
+    wide = (first >= 0) & jnp.all((sub == first) | (sub < 0), axis=1,
+                                  keepdims=True)
+    return jnp.where(wide, first, -1)[:, 0], sub_seq
+
+
 def _ragged_kernel(
     # scalar prefetch (SMEM)
-    block_seq_ref,  # [NB] owning sequence per q block (-1 = padding)
-    cu_ref,       # [S+1] aligned segment starts
-    qlen_ref,     # [S]
-    slen_ref,     # [S] context lengths
-    tables_ref,   # [S, max_pages]
+    wide_seq_ref,  # [NBW] owner of a one-sequence q block, else -1
+    sub_seq_ref,   # [NBW * nsub] owner per sub-block (-1 = padding)
+    cu_ref,        # [S+1] aligned segment starts
+    qlen_ref,      # [S]
+    slen_ref,      # [S] context lengths
+    tables_ref,    # [S, max_pages]
     # inputs
-    q_ref,        # [1, 1, token_block * group, D] VMEM
-    k_hbm,        # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
+    q_ref,         # [nsub, token_block * group, D] VMEM: one q block
+    k_hbm,         # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
     v_hbm,
-    # quantized only: k_sc_ref/v_sc_ref [1, max_pages] SMEM — the owning
-    # sequence's per-page scales in page-table order — then outputs
-    # o_ref [1, 1, token_block * group, D] and scratch
-    # k_buf/v_buf [dma_slots, page, D], sems [dma_slots, 2],
-    # acc_scr [token_block * group, D]
+    # quantized only: k_sc_ref/v_sc_ref [nsub, max_pages] SMEM — per
+    # sub-block, its sequence's per-page scales in page-table order —
+    # then outputs o_ref [nsub, token_block * group, D] and scratch
+    # k_buf/v_buf [dma_slots, ppb * page, D], sems [dma_slots, 2],
+    # acc_scr [nsub * token_block * group, D]
     *refs,
     page_size: int,
     token_block: int,
+    nsub: int,
     group: int,
     scale: float,
+    ppb: int,
     dma_slots: int,
     quantized: bool,
 ):
@@ -175,120 +251,209 @@ def _ragged_kernel(
         o_ref, k_buf, v_buf, sems, acc_scr = refs
         k_sc_ref = v_sc_ref = None
     kvh = pl.program_id(0)
-    j = pl.program_id(1)   # GLOBAL q block: segment alignment means it
-    #                        belongs to exactly one sequence — the grid
-    #                        is (Hkv, NB), no per-sequence dimension and
-    #                        no inactive cells beyond the packed tail
-    i = block_seq_ref[j]
-    # clamp for SMEM reads; every use below is masked by ``active``
-    i_safe = jnp.maximum(i, 0)
-    q_len = qlen_ref[i_safe]
-    ctx_len = slen_ref[i_safe]
-    active = i >= 0
-    num_pages = jax.lax.div(ctx_len + page_size - 1, page_size)
+    j = pl.program_id(1)   # GLOBAL q block of nsub sub-blocks: the grid
+    #                        is (Hkv, NBW), no per-sequence dimension
+    sub_rows = token_block * group
+    kvb = ppb * page_size  # keys per KV block (one loop iteration)
+    unroll = math.gcd(ppb, _PAGE_COPY_UNROLL)
+    d = q_ref.shape[-1]
 
-    def page_dma(slot, p_idx):
-        page_id = tables_ref[i_safe, p_idx]
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[kvh, page_id], k_buf.at[slot], sems.at[slot, 0]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[kvh, page_id], v_buf.at[slot], sems.at[slot, 1]
-            ),
-        )
+    def attend(i, tok0, sub, ntok, q, write):
+        """Online softmax of one q tile — ``ntok`` packed tokens of
+        sequence ``i`` from its chunk token ``tok0`` (rows pack
+        ``group`` query heads per token, token-major) — over KV blocks
+        of ``ppb`` pages; ``write`` takes the f32 [ntok * group, D]
+        result.  ``sub`` names the tile's row of the scale blocks."""
+        rows = ntok * group
+        q_len = qlen_ref[i]
+        ctx_len = slen_ref[i]
+        # the loop ends at the last causal key of the tile's last real
+        # token, not at the context's end: a chunk's early tiles never
+        # fetch the pages above their diagonal
+        kv_end = ctx_len - q_len + jnp.minimum(tok0 + ntok, q_len)
+        last_page = jax.lax.div(kv_end + page_size - 1, page_size) - 1
+        n_blk = jax.lax.div(kv_end + kvb - 1, kvb)
 
-    rows = token_block * group
+        def page_idx(blk, p):
+            # the ragged last block refetches the last page into its
+            # spare slots: every slot holds real cache rows (finite,
+            # masked by position), and the loop body needs no branch
+            return jnp.minimum(blk * ppb + p, last_page)
 
-    @pl.when(jnp.logical_and(active, num_pages > 0))
-    def _run():
-        # prime the page pipeline: up to ``dma_slots - 1`` pages in
-        # flight before the loop body consumes page 0 (dma_slots == 2
-        # is classic double buffering; deeper pipelines hide more HBM
-        # latency — ops/autotune.py picks the depth per shape)
-        for dma in page_dma(0, 0):
-            dma.start()
-        for ahead in range(1, dma_slots - 1):
-            @pl.when(ahead < num_pages)
-            def _prime(ahead=ahead):
-                for dma in page_dma(ahead, ahead):
-                    dma.start()
+        def start_block(slot, blk):
+            def copy_pages(g, carry):
+                for u in range(unroll):
+                    p = g * unroll + u
+                    page_id = tables_ref[i, page_idx(blk, p)]
+                    dst = pl.ds(pl.multiple_of(p * page_size, page_size),
+                                page_size)
+                    pltpu.make_async_copy(
+                        k_hbm.at[kvh, page_id], k_buf.at[slot, dst],
+                        sems.at[slot, 0]).start()
+                    pltpu.make_async_copy(
+                        v_hbm.at[kvh, page_id], v_buf.at[slot, dst],
+                        sems.at[slot, 1]).start()
+                return carry
 
-        # token index within the chunk / global position per q row
-        # (rows pack ``group`` query heads per token, token-major);
-        # this block's first packed row is j*tb, so its first chunk
-        # token is j*tb - cu[i]
-        row_tok = j * token_block - cu_ref[i_safe] + jax.lax.div(
-            jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0),
-            group)
-        q_pos = ctx_len - q_len + row_tok
-        row_valid = row_tok < q_len
+            jax.lax.fori_loop(0, ppb // unroll, copy_pages, 0)
 
-        def body(p_idx, carry):
-            m_prev, l_prev, _ = carry  # acc lives in scratch
-            slot = jax.lax.rem(p_idx, dma_slots)
-            # keep the pipeline ``dma_slots - 1`` pages deep: the slot
-            # being refilled is the one consumed longest ago
-            pre = p_idx + dma_slots - 1
-            nxt = jax.lax.rem(pre, dma_slots)
+        def wait_block(slot):
+            # one wait per half for the block's ppb page copies: a DMA
+            # semaphore counts bytes, and the descriptor only sizes it
+            for buf, half in ((k_buf, 0), (v_buf, 1)):
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sems.at[slot, half]
+                ).wait()
 
-            @pl.when(pre < num_pages)
-            def _prefetch():
-                for dma in page_dma(nxt, pre):
-                    dma.start()
+        def page_scales(sc_ref, blk):
+            # [1, kvb] per-key scale of the block's pages (int8 layout):
+            # scalar SMEM reads at each page's table position
+            col_page = jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, (1, kvb), 1),
+                page_size)
+            out = jnp.zeros((1, kvb), jnp.float32)
+            for p in range(ppb):
+                out = jnp.where(col_page == p,
+                                sc_ref[sub, page_idx(blk, p)], out)
+            return out
 
-            for dma in page_dma(slot, p_idx):
-                dma.wait()
+        @pl.when(n_blk > 0)
+        def _run():
+            # prime the pipeline: up to ``dma_slots - 1`` KV blocks in
+            # flight before the loop body consumes block 0
+            start_block(0, 0)
+            for ahead in range(1, dma_slots - 1):
+                @pl.when(ahead < n_blk)
+                def _prime(ahead=ahead):
+                    start_block(ahead, ahead)
 
-            q = q_ref[0, 0].astype(jnp.float32)
-            k = k_buf[slot].astype(jnp.float32)
-            v = v_buf[slot].astype(jnp.float32)
-            if quantized:
-                # dequantize in-register: the page's int8 bytes were
-                # DMAed; its (head, page) f32 scale is a scalar read at
-                # the page's table position
-                k = k * k_sc_ref[0, p_idx]
-                v = v * v_sc_ref[0, p_idx]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-            k_pos = p_idx * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
-            )
-            mask = (k_pos < ctx_len) & (k_pos <= q_pos) & row_valid
-            s = jnp.where(mask, s, _NEG_INF)
+            # global position per q row (ascending); rows past the
+            # segment's real tokens (alignment tail) are zeroed at the
+            # end instead of masked in every block
+            row_tok = tok0 + jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group)
+            q_pos = ctx_len - q_len + row_tok
+            # KV blocks wholly at or below the FIRST row's position are
+            # visible to every row: they run without the mask, which
+            # only the diagonal blocks need
+            n_full = jnp.minimum(
+                jax.lax.div(ctx_len - q_len + tok0 + 1, kvb), n_blk)
+            acc = acc_scr.at[pl.ds(0, rows)]
 
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            # explicit zero for fully-masked rows (segment-tail padding):
-            # there s == m_new == _NEG_INF and exp(0) would count them
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-                p, v, preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, 0
+            def body(masked, blk, carry):
+                m_prev, l_prev = carry  # acc lives in scratch
+                slot = jax.lax.rem(blk, dma_slots)
+                # keep the pipeline ``dma_slots - 1`` blocks deep: the
+                # slot being refilled is the one consumed longest ago
+                pre = blk + dma_slots - 1
 
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m0 = jnp.full((rows, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((rows, 1), jnp.float32)
-        _, l_fin, _ = jax.lax.fori_loop(0, num_pages, body, (m0, l0, 0))
-        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+                @pl.when(pre < n_blk)
+                def _prefetch():
+                    start_block(jax.lax.rem(pre, dma_slots), pre)
 
-    @pl.when(jnp.logical_not(jnp.logical_and(active, num_pages > 0)))
-    def _padding():
-        # trailing padding blocks (and the defensive empty-context
-        # case) own their output block too — zero it so padded rows of
-        # the packed hidden state stay exactly zero
-        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
+                wait_block(slot)
+                k = k_buf[slot]
+                v = v_buf[slot]
+                if k.dtype != q.dtype:
+                    # int8 values are exact in any float dtype: the
+                    # page's (head, page) scale multiplies its score and
+                    # probability columns, not its 128-wide rows
+                    k = k.astype(jnp.float32).astype(q.dtype)
+                    v = v.astype(jnp.float32).astype(q.dtype)
+                # the MXU takes the stored dtype (bf16 on the chip) and
+                # accumulates in f32, as ops/attention.py does
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    s = s * page_scales(k_sc_ref, blk)
+                if masked:
+                    k_pos = blk * kvb + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 1)
+                    s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # a masked key underflows to an exact zero: block 0
+                # holds key 0, which every real row sees, so m_new is a
+                # real score from the first iteration on
+                p = jnp.exp(s - m_new)
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1,
+                                                 keepdims=True)
+                if quantized:
+                    p = p * page_scales(v_sc_ref, blk)
+                acc[...] = acc[...] * alpha + jnp.dot(
+                    p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+                return m_new, l_new
+
+            acc[...] = jnp.zeros((rows, d), jnp.float32)
+            m0 = jnp.full((rows, 1), _NEG_INF, jnp.float32)
+            # the first real block's alpha is an exact 0, so the ones
+            # never count; they keep the discarded alignment-tail rows
+            # away from 0 / 0
+            l0 = jnp.ones((rows, 1), jnp.float32)
+            carry = jax.lax.fori_loop(
+                0, n_full, functools.partial(body, False), (m0, l0))
+            _, l_fin = jax.lax.fori_loop(
+                n_full, n_blk, functools.partial(body, True), carry)
+            write(jnp.where(row_tok < q_len, acc[...] / l_fin, 0.0))
+
+        @pl.when(n_blk <= 0)
+        def _empty():
+            # the defensive empty-context case owns its rows too
+            write(jnp.zeros((rows, d), jnp.float32))
+
+    w = wide_seq_ref[j]
+
+    @pl.when(w >= 0)
+    def _wide():
+        # the whole block inside one sequence's segment (nearly every
+        # block of a prefill chunk): all its rows attend as ONE tile
+        def write(out):
+            o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+
+        attend(w, j * (nsub * token_block) - cu_ref[w], 0,
+               nsub * token_block, q_ref[...].reshape(nsub * sub_rows, d),
+               write)
+
+    @pl.when(w < 0)
+    def _narrow():
+        # several sequences (decode rows, a segment's head or tail) or
+        # padding: each sub-block against its own sequence's pages
+        def sub_block(sub, carry):
+            i = sub_seq_ref[j * nsub + sub]
+            # clamp for SMEM reads; the tile below runs only when active
+            i_safe = jnp.maximum(i, 0)
+
+            def write(out):
+                o_ref[sub] = out.astype(o_ref.dtype)
+
+            @pl.when(i >= 0)
+            def _tile():
+                attend(i_safe,
+                       (j * nsub + sub) * token_block - cu_ref[i_safe],
+                       sub, token_block, q_ref[sub], write)
+
+            @pl.when(i < 0)
+            def _padding():
+                # padding sub-blocks own their rows too — zero them so
+                # padded rows of the packed hidden state stay zero
+                write(jnp.zeros((sub_rows, d), jnp.float32))
+
+            return carry
+
+        jax.lax.fori_loop(0, nsub, sub_block, 0)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "token_block", "use_pallas", "dma_slots"))
+    static_argnames=("scale", "token_block", "use_pallas", "q_block",
+                     "pages_per_block", "dma_slots"))
 def _ragged_attention(
     q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
-    num_seqs, scale, token_block, use_pallas, dma_slots,
+    num_seqs, scale, token_block, use_pallas, q_block, pages_per_block,
+    dma_slots,
 ):
     t, h, d = q.shape
     quantized = isinstance(k_cache, tuple)
@@ -302,45 +467,40 @@ def _ragged_attention(
             q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
             seq_lens, num_seqs, scale,
         )
-    if t % token_block:
+    if t % token_block or q_block % token_block:
         raise ValueError(
-            f"packed length {t} not a multiple of token_block "
-            f"{token_block}")
+            f"packed length {t} and q block {q_block} must be multiples "
+            f"of token_block {token_block}")
     group = h // hkv
-    s_max = q_lens.shape[0]
-    nb = t // token_block
-    rows = token_block * group
-    # [T, H, D] -> [Hkv, NB, token_block * group, D]: token-major rows
-    # so q row r of block b is (token b*tb + r // group, head r % group)
+    nsub = q_block // token_block
+    sub_rows = token_block * group
+    nbw = -(-t // q_block)
+    t_pad = nbw * q_block
+    max_pages = page_tables.shape[1]
+    ppb = max(1, min(pages_per_block, max_pages))
+    # [T, H, D] -> [Hkv, NBW, nsub, token_block * group, D]: token-major
+    # rows, so row r of sub-block b is (token b*tb + r // group, head
+    # r % group); the packed tail pads to a whole q block
+    qx = jnp.pad(q, ((0, t_pad - t), (0, 0), (0, 0)))
     qx = jnp.transpose(
-        q.reshape(t, hkv, group, d), (1, 0, 2, 3)
-    ).reshape(hkv, nb, rows, d)
+        qx.reshape(t_pad, hkv, group, d), (1, 0, 2, 3)
+    ).reshape(hkv, nbw, nsub, sub_rows, d)
+    wide_seq, sub_seq = _block_owners(
+        cu_q_lens, q_lens, num_seqs, nbw, nsub, token_block)
 
-    # Owning sequence per GLOBAL q block (-1 = packed-tail padding):
-    # segment starts are token_block-aligned, so every block belongs to
-    # at most one sequence — the grid is (Hkv, NB) with no dead
-    # per-sequence dimension, and the block specs need no
-    # prefetch-dependent index math.
-    nseq = jnp.asarray(num_seqs, jnp.int32).reshape(())
-    starts = cu_q_lens[:s_max].astype(jnp.int32)
-    bs = jnp.arange(nb, dtype=jnp.int32) * token_block
-    in_seq = (
-        (bs[None, :] >= starts[:, None])
-        & (bs[None, :] < (starts + q_lens.astype(jnp.int32))[:, None])
-        & (jnp.arange(s_max)[:, None] < nseq)
-    )
-    block_seq = jnp.where(jnp.any(in_seq, axis=0),
-                          jnp.argmax(in_seq, axis=0), -1).astype(jnp.int32)
+    def q_spec():
+        return pl.BlockSpec((None, None, nsub, sub_rows, d),
+                            lambda kvh, j, *_: (kvh, j, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rows, d),
-                     lambda kvh, j, *_: (kvh, j, 0, 0),
-                     memory_space=pltpu.VMEM),
+        q_spec(),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [
-        block_seq,
+        wide_seq,
+        sub_seq,
         cu_q_lens.astype(jnp.int32),
         q_lens.astype(jnp.int32),
         seq_lens.astype(jnp.int32),
@@ -350,27 +510,28 @@ def _ragged_attention(
         v_data,
     ]
     if quantized:
-        # the block's owning sequence comes from the prefetched lookup
-        # (clamped like the kernel's own SMEM reads; padding blocks
-        # never touch the scales)
-        sc_spec = _page_scale_spec(
-            page_tables.shape[1],
-            lambda kvh, j, bs, *_: (kvh, jnp.maximum(bs[j], 0)))
-        in_specs += [sc_spec, sc_spec]
-        operands += [_table_order_scales(k_scale, page_tables),
-                     _table_order_scales(v_scale, page_tables)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(hkv, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, d),
+        # per SUB-BLOCK, its sequence's scales in page-table order (a
+        # q block may hold several sequences): [Hkv, NBW, nsub,
+        # max_pages], one (nsub, max_pages) SMEM block a grid cell.
+        # Padding sub-blocks never touch theirs.
+        sub_tables = page_tables.astype(jnp.int32)[jnp.maximum(sub_seq, 0)]
+        sc_spec = pl.BlockSpec((None, None, nsub, max_pages),
                                lambda kvh, j, *_: (kvh, j, 0, 0),
-                               memory_space=pltpu.VMEM),
+                               memory_space=pltpu.SMEM)
+        in_specs += [sc_spec, sc_spec]
+        operands += [
+            sc[:, sub_tables].reshape(hkv, nbw, nsub, max_pages)
+            for sc in (k_scale, v_scale)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(hkv, nbw),
+        in_specs=in_specs,
+        out_specs=q_spec(),
         scratch_shapes=[
-            pltpu.VMEM((dma_slots, page_size, d), k_data.dtype),
-            pltpu.VMEM((dma_slots, page_size, d), v_data.dtype),
+            pltpu.VMEM((dma_slots, ppb * page_size, d), k_data.dtype),
+            pltpu.VMEM((dma_slots, ppb * page_size, d), v_data.dtype),
             pltpu.SemaphoreType.DMA((dma_slots, 2)),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((nsub * sub_rows, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -378,17 +539,19 @@ def _ragged_attention(
             _ragged_kernel,
             page_size=page_size,
             token_block=token_block,
+            nsub=nsub,
             group=group,
             scale=scale,
+            ppb=ppb,
             dma_slots=dma_slots,
             quantized=quantized,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, nb, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qx.shape, q.dtype),
         interpret=interpret_flag(),
     )(*operands)
-    # [Hkv, NB, tb*group, D] -> [T, H, D]
-    out = out.reshape(hkv, t, group, d)
+    # [Hkv, NBW, nsub, tb*group, D] -> [T, H, D]
+    out = out.reshape(hkv, t_pad, group, d)[:, :t]
     return jnp.transpose(out, (1, 0, 2, 3)).reshape(t, h, d)
 
 
@@ -404,6 +567,8 @@ def ragged_paged_attention(
     scale: Optional[float] = None,
     token_block: int = DEFAULT_TOKEN_BLOCK,
     use_pallas: Optional[bool] = None,
+    q_block: Optional[int] = None,
+    pages_per_block: Optional[int] = None,
     dma_slots: Optional[int] = None,
 ):
     """Mixed prefill+decode paged attention over a token-packed batch.
@@ -413,8 +578,10 @@ def ragged_paged_attention(
     geometry ``paged_tiling_reason`` accepts and a ``token_block``-aligned
     packed length; anything else (CPU tests, tiny shapes) takes the XLA
     reference.  An explicit ``use_pallas=True`` is honored as-is and
-    fails loudly if unsupported.  ``dma_slots`` (page-DMA pipeline depth)
-    defaults to the per-shape ``auto_ragged_blocks`` choice."""
+    fails loudly if unsupported.  ``q_block`` (tokens a grid cell),
+    ``pages_per_block`` (pages a loop iteration) and ``dma_slots`` (KV
+    blocks in the DMA pipeline) default to the per-shape
+    ``auto_ragged_blocks`` choice."""
     quantized = cache_is_quantized(k_cache)
     k_data = cache_data(k_cache)
     if use_pallas is None:
@@ -423,15 +590,19 @@ def ragged_paged_attention(
             reason = (f"packed length {q.shape[0]} % token_block "
                       f"{token_block} != 0")
         use_pallas = auto_use_pallas("ragged_paged_attention", reason)
-    if dma_slots is None:
-        _, dma_slots = auto_ragged_blocks(
+    if None in (q_block, pages_per_block, dma_slots):
+        auto = auto_ragged_blocks(
             head_dim=q.shape[-1], page_size=k_data.shape[2],
             group=q.shape[1] // k_data.shape[0],
             kv_itemsize=k_data.dtype.itemsize,
             q_itemsize=q.dtype.itemsize,
             quantized=quantized)
+        q_block, pages_per_block, dma_slots = (
+            a if x is None else x
+            for x, a in zip((q_block, pages_per_block, dma_slots), auto))
     num_seqs = jnp.asarray(num_seqs, jnp.int32)
     return _ragged_attention(
         q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
-        num_seqs, scale, token_block, use_pallas, dma_slots,
+        num_seqs, scale, token_block, use_pallas, q_block,
+        pages_per_block, dma_slots,
     )

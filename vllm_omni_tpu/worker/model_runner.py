@@ -266,25 +266,24 @@ class ARModelRunner:
         # emits a batch beyond them, so _bucket cannot overflow
         self._batch_buckets = _make_buckets(1, max(max_num_seqs, 1))
         self._seq_buckets = _make_buckets(16, max(max_model_len, 16))
-        # ragged block choice (ops/autotune.py): the per-sequence
-        # q block doubles as the packer's segment alignment, so it is
-        # fixed here and honored by BOTH the assembler and the kernel;
-        # the DMA pipeline depth is the kernel's own knob.  Serving is
-        # decode-heavy, which pins the q block at the minimum tile.
-        _, dma_slots = auto_ragged_blocks(
-            head_dim=cfg.head_dim, page_size=page_size,
-            group=max(cfg.num_heads // max(cfg.num_kv_heads, 1), 1),
-            kv_itemsize=1 if self._kv_quant else jnp.dtype(dtype).itemsize,
-            q_itemsize=jnp.dtype(dtype).itemsize,
-            quantized=self._kv_quant)
-        # the packer's segment alignment is pinned to the kernel's
-        # packing contract (decode-heavy serving keeps the autotuner at
-        # the same minimum tile; plumb the block through forward_unified
-        # before honoring a larger choice here).  dma_slots is recorded
-        # for the warmup log — the kernel re-derives the identical value
-        # through the same lru-cached helper at dispatch.
+        # ragged block choice (ops/autotune.py).  Two different things:
+        # the packer's segment alignment is the kernel's packing
+        # contract (DEFAULT_TOKEN_BLOCK, honored by the assembler: a
+        # decode row costs 8 packed rows); the q block, the pages per
+        # KV block and the DMA depth are the kernel's own tile, derived
+        # from the shapes.  They are recorded here for the warmup log
+        # and the flight record's wide_q_tokens — the kernel re-derives
+        # the identical values through the same lru-cached helper at
+        # dispatch.
+        self.q_block, self._pages_per_block, self._dma_slots = (
+            auto_ragged_blocks(
+                head_dim=cfg.head_dim, page_size=page_size,
+                group=max(cfg.num_heads // max(cfg.num_kv_heads, 1), 1),
+                kv_itemsize=(1 if self._kv_quant
+                             else jnp.dtype(dtype).itemsize),
+                q_itemsize=jnp.dtype(dtype).itemsize,
+                quantized=self._kv_quant))
         self._token_block = DEFAULT_TOKEN_BLOCK
-        self._dma_slots = dma_slots
         # unified ragged batching pads to TOKEN-count buckets: a 1-D
         # bucket line replacing the (batch, seq) grid of the split path.
         # Worst packed size under the AR scheduler = the step token
@@ -675,9 +674,10 @@ class ARModelRunner:
             return res
 
         logger.info(
-            "ragged blocks: token_block=%d dma_slots=%d (head_dim=%d "
-            "page_size=%d kv_cache_dtype=%s) — ops/autotune.py picks "
-            "per layout", self._token_block, self._dma_slots,
+            "ragged blocks: token_block=%d q_block=%d pages_per_block=%d "
+            "dma_slots=%d (head_dim=%d page_size=%d kv_cache_dtype=%s) "
+            "— ops/autotune.py picks per layout", self._token_block,
+            self.q_block, self._pages_per_block, self._dma_slots,
             self.cfg.head_dim, self.page_size, self.kv_cache_dtype)
 
         def pos_shape(b):
