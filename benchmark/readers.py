@@ -20,11 +20,11 @@ from __future__ import annotations
 import fnmatch
 import importlib
 
-from benchmark import e2e, trace, traffic
+from benchmark import e2e, spec, trace, traffic
 
 
-def resolve(spec: str):
-    module, _, fn = spec.rpartition(":")
+def resolve(reader: str):
+    module, _, fn = reader.rpartition(":")
     return getattr(importlib.import_module(module or __name__), fn)
 
 
@@ -109,8 +109,9 @@ def device_roofline_pct(obs: dict) -> float | None:
     mean_prefill_ctx = sum(p * p for p, _ in pairs) / (2.0 * p_sum)
     mean_decode_ctx = (sum(o * (p + o / 2.0) for p, o in pairs)
                        / sum(o for _, o in pairs))
+    arch, config = spec.arch_of(obs["config"]), obs["config"]
     least = trace.least_seconds(
-        obs["steps"], trace.model_costs(obs["config"]),
-        trace.peaks_of(obs["device"]["kind"]), mean_prefill_ctx,
-        mean_decode_ctx, chips=obs["chips"])
+        (arch.step_cost(s, config, mean_prefill_ctx, mean_decode_ctx)
+         for s in obs["steps"]),
+        trace.peaks_of(obs["device"]["kind"]), chips=obs["chips"])
     return 100.0 * least / tr["busy_s"]

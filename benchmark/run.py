@@ -283,17 +283,19 @@ def run_cell(args) -> dict:
         result["breakdown"] = {"device_ops": trace.top(reduced["self_s"]),
                                "idle_gaps": trace.top(reduced["gaps_s"])}
     if tail is not None:
-        tail_report(result, tail, compiles, w1)
+        planes = tail_report(result, tail, compiles, w1)
         # the trace is reduced: the tables are kept, the file is not
         shutil.rmtree(CACHE / "trace" / args.workload, ignore_errors=True)
         detail["tail"] = {"window": tail["window"], "records": tail["records"],
-                          "traced": (tail["lo"], tail["hi"])}
+                          "traced": (tail["lo"], tail["hi"]),
+                          "planes": planes}
     keep_run(args, result, {**detail, "trace": reduced, "steps": obs["steps"],
                             "engine0": engine0, "engine1": engine1})
     return result
 
 
-def tail_report(result: dict, tail: dict, compiles: list, w1: float) -> None:
+def tail_report(result: dict, tail: dict, compiles: list, w1: float
+                ) -> dict | None:
     """What a ``--trace 2`` run says of its traced tail: idle gaps named by
     the host phase that covered them (``in_omni.schedule``; ``after_<op>``
     only where no annotation did), and what happened in the tail that the
@@ -303,6 +305,11 @@ def tail_report(result: dict, tail: dict, compiles: list, w1: float) -> None:
     red = span_readers.reduction()
     if red and red["gaps_s"] and "breakdown" in result:
         result["breakdown"]["idle_gaps"] = trace.top(red["gaps_s"])
+    if red and len(red["planes"]) > 1:
+        for plane, own in sorted(red["planes"].items()):
+            say(f"tail: {plane}: busy {own['busy_s']:.3f} s; " + ", ".join(
+                f"{k or 'unnamed'} {v:.3f}" for k, v in trace.top(
+                    own["scope_s"], 6)))
     if red and red["skew_ms"]:
         skew = sorted(red["skew_ms"])
         say(f"tail: one clock: a step's omni.readback ends "
@@ -317,6 +324,7 @@ def tail_report(result: dict, tail: dict, compiles: list, w1: float) -> None:
     result.setdefault("breakdown", {})["tail"] = said
     say(f"tail: {said['requests']} requests sent, {said['failed']} failed, "
         f"{said['compiles']} compilations after the measured window")
+    return red["planes"] if red else None
 
 
 def keep_run(args, result: dict, detail: dict) -> None:

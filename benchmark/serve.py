@@ -8,9 +8,7 @@ except the limits the configuration file writes down.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-import importlib
 import json
 import subprocess
 import sys
@@ -20,61 +18,21 @@ from pathlib import Path
 
 import numpy as np
 
-
-def transformer_config(config: dict):
-    """The published keys of a configuration file -> the program's
-    ``TransformerConfig``.  Where the file names the program's own constant
-    for this model (``program_config``), the two must agree on everything
-    but depth: a width that drifted in the program is an error here."""
-    from vllm_omni_tpu.models.common.transformer import TransformerConfig
-
-    rope = config.get("rope_scaling") or {}
-    cfg = TransformerConfig(
-        vocab_size=config["vocab_size"],
-        hidden_size=config["hidden_size"],
-        num_layers=config["num_hidden_layers"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        intermediate_size=config["intermediate_size"],
-        rope_theta=float(config["rope_theta"]),
-        rms_eps=config["rms_norm_eps"],
-        mrope_sections=(tuple(rope["mrope_section"])
-                        if rope.get("mrope_section") else None),
-        qk_norm=bool(config.get("use_qk_norm", False)),
-        attention_bias=bool(config.get("attention_bias", False)),
-        tie_word_embeddings=bool(config.get("tie_word_embeddings", False)),
-        moe=config.get("num_experts", 0) > 0,
-        num_experts=config.get("num_experts", 0),
-        num_experts_per_tok=config.get("num_experts_per_tok", 0),
-        moe_intermediate_size=config.get("moe_intermediate_size", 0),
-        moe_renormalize=bool(config.get("norm_topk_prob", True)),
-        shared_expert_size=config.get("shared_expert_intermediate_size", 0),
-    )
-    if config.get("program_config"):
-        module, _, name = config["program_config"].partition(":")
-        theirs = dataclasses.replace(
-            getattr(importlib.import_module(module), name),
-            num_layers=cfg.num_layers)
-        if theirs != cfg:
-            raise SystemExit(
-                f"benchmark: {config['program_config']} differs from the "
-                f"published configuration: {theirs} != {cfg}")
-    return cfg
+from benchmark import spec
 
 
 def model_factory(config: dict, seed: int, timings: dict):
     """``model_factory`` of the stage: seeded random weights generated on
-    the device(s) — under tensor parallelism directly in the engine's
-    sharded layout, so that no chip ever holds the whole tree."""
+    the device(s) by the configuration's architecture module — under
+    tensor parallelism directly in the engine's sharded layout, so that no
+    chip ever holds the whole tree."""
     import jax
     import jax.numpy as jnp
 
-    from vllm_omni_tpu.models.common import transformer as tfm
-
+    arch = spec.arch_of(config)
+    cfg = arch.program_config(config)  # imports the program: not weights
     t0 = time.monotonic()
-    cfg = transformer_config(config)
-    init = functools.partial(tfm.init_params, cfg=cfg,
+    init = functools.partial(arch.init_weights, cfg=cfg,
                              dtype=jnp.dtype(config["serve_dtype"]))
     tp = config.get("tensor_parallel_size", 1)
     shardings = None
@@ -82,13 +40,12 @@ def model_factory(config: dict, seed: int, timings: dict):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
         from vllm_omni_tpu.parallel.mesh import AXIS_TP
-        from vllm_omni_tpu.parallel.sharding import ar_param_specs_tree
 
         mesh = Mesh(np.array(jax.devices()[:tp]), (AXIS_TP,))
-        specs = ar_param_specs_tree(
+        specs = arch.param_specs(
             jax.eval_shape(init, jax.random.PRNGKey(0)))
         shardings = jax.tree.map(
-            lambda spec: NamedSharding(mesh, spec), specs,
+            lambda s: NamedSharding(mesh, s), specs,
             is_leaf=lambda x: isinstance(x, PartitionSpec))
     # --seed may exceed 32 signed bits; a key takes less
     key = jax.random.PRNGKey(seed % (2 ** 31 - 1))
@@ -210,17 +167,23 @@ def template_tokens(port: int, traffic: dict) -> int:
 #: between two bfloat16 paths of the same model.  An int8 cache or fp8
 #: weights move logits by several tenths and fail this.
 LOGPROB_TOL = 0.15
+#: lengths of the check where the configuration file gives none under
+#: ``reference_check``
+CHECK_LENGTHS = {"prompt_tokens": 40, "new_tokens": 8}
 
 
 def reference_check(state, engine, config: dict, seed: int) -> dict:
     """One seeded prompt, decoded greedily by the served engine — the first
     token from prefill, the rest through the paged cache — against the
-    plain float32 forward over the same ids (benchmark/reference.py)."""
-    from benchmark import reference
-
+    plain float32 forward over the same ids, which the configuration's
+    architecture module gives (``reference_logprobs``).  The file's
+    ``reference_check`` sets the lengths, so that a configuration can
+    check past a window or at a context where its cache layout matters."""
+    lengths = {**CHECK_LENGTHS, **config.get("reference_check", {})}
     rng = np.random.default_rng(seed)
-    prompt = rng.integers(3, config["vocab_size"], 40).tolist()
-    n_new = 8
+    prompt = rng.integers(3, config["vocab_size"],
+                          lengths["prompt_tokens"]).tolist()
+    n_new = lengths["new_tokens"]
     outs = state.collect(
         {"prompt_token_ids": prompt},
         {"max_tokens": n_new, "temperature": 0.0, "ignore_eos": True,
@@ -230,10 +193,10 @@ def reference_check(state, engine, config: dict, seed: int) -> dict:
     served = [float(e["logprob"]) for e in out.outputs[0].logprobs]
     if len(ids) != n_new or len(served) != n_new:
         return {"ok": False, "why": f"asked {n_new} tokens, got {len(ids)}"}
-    ref = reference.logprobs(engine.runner.params, config,
-                             prompt + ids[:-1],
-                             tp=config.get("tensor_parallel_size", 1))
-    want = [float(ref[len(prompt) - 1 + i, t]) for i, t in enumerate(ids)]
+    ref = spec.arch_of(config).reference_logprobs(
+        engine.runner.params, config, prompt + ids[:-1], n_new,
+        tp=config.get("tensor_parallel_size", 1))
+    want = [float(ref[i, t]) for i, t in enumerate(ids)]
     diff = max(abs(a - b) for a, b in zip(served, want))
     return {"ok": bool(np.isfinite(diff) and diff <= LOGPROB_TOL),
             "max_abs_diff": diff, "tolerance": LOGPROB_TOL,

@@ -25,7 +25,7 @@ import functools
 from collections import defaultdict
 from pathlib import Path
 
-from benchmark import trace, xplane_proto
+from benchmark import spec, trace, xplane_proto
 
 TRACE_ROOT = Path(__file__).resolve().parent.parent / ".benchmark_cache" / "trace"
 
@@ -296,21 +296,28 @@ def reduce_planes(devices: dict, phases: list) -> dict | None:
     scope; None where no operation carries a scope), ``unnamed_s`` (by
     group key), ``idle_s`` (idle seconds by innermost annotation; None
     where the trace holds no annotation), ``gaps_s`` (the same by name),
-    ``window_s``, ``busy_s`` and ``skew_ms``."""
-    per_device = [ev for ev in devices.values() if ev]
+    ``window_s``, ``busy_s`` and ``skew_ms``; and ``planes``, each
+    plane's own busy seconds and scope seconds (under tensor parallelism
+    they say whether a piece of work is divided among the chips or done
+    on each)."""
+    per_device = {name: ev for name, ev in devices.items() if ev}
     if not per_device:
         return None
     n = len(per_device)
+    first = next(iter(per_device.values()))
     out = {"scope_s": defaultdict(float), "unnamed_s": defaultdict(float),
            "idle_s": defaultdict(float), "gaps_s": defaultdict(float),
-           "window_s": 0.0, "busy_s": 0.0, "devices": n,
-           "skew_ms": readback_skew_ms(per_device[0], phases)}
-    for events in per_device:
+           "window_s": 0.0, "busy_s": 0.0, "devices": n, "planes": {},
+           "skew_ms": readback_skew_ms(first, phases)}
+    for plane, events in per_device.items():
         scope_s, unnamed_s = scope_self_seconds(events)
         idle_s, gaps_s = idle_seconds(events, phases)
         plain = trace.reduce_events([e[:3] for e in events])
         out["window_s"] += plain["window_s"] / n
         out["busy_s"] += plain["busy_s"] / n
+        out["planes"][plane] = {
+            "busy_s": plain["busy_s"],
+            "scope_s": {k or "": v for k, v in scope_s.items()}}
         for name, table in (("scope_s", scope_s), ("unnamed_s", unnamed_s),
                             ("idle_s", idle_s), ("gaps_s", gaps_s)):
             for k, v in table.items():
@@ -366,56 +373,15 @@ def idle_layer_pct(obs: dict, layer: str) -> float | None:
 
 
 # --------------------------------------------------------------- rooflines
-def attn_least_seconds(step: dict, config: dict, peaks: dict,
-                       dtype_bytes: int = 2) -> float:
-    """The least time one chip could take for the attention of one step
-    (a flight-recorder record with ``prefill_ctx`` / ``decode_ctx``, the
-    attended pairs of its prefill tokens and of its decode rows): the
-    larger of its operations over the peak rate and its bytes over the
-    peak bandwidth.  Bytes: every decode row reads its context once
-    (``decode_ctx`` positions in all), a prefill chunk reads the context
-    it attends once (its tokens' mean context plus half its length, exact
-    for one chunk a step), and queries come in and outputs go out; the
-    cache write is ``omni.kv_write``'s, not attention's."""
-    costs = trace.model_costs(config, dtype_bytes)
-    tokens = step["prefill_tokens"] + step["decodes"]
-    kv_read = step["decode_ctx"]
-    if step["prefill_tokens"] > 0:
-        kv_read += (step["prefills"] * step["prefill_ctx"]
-                    / step["prefill_tokens"] + step["prefill_tokens"] / 2.0)
-    q_dim = config["num_attention_heads"] * config["head_dim"]
-    flops = costs["attn_flops_per_pair"] * (step["prefill_ctx"]
-                                            + step["decode_ctx"])
-    nbytes = (costs["kv_bytes_per_pos"] * kv_read
-              + 2.0 * dtype_bytes * costs["layers"] * tokens * q_dim)
-    return max(flops / peaks["bf16_flop_s"], nbytes / peaks["hbm_byte_s"])
-
-
-def moe_least_seconds(step: dict, config: dict, peaks: dict,
-                      dtype_bytes: int = 2) -> float:
-    """The same for the sparse MLP of one step: router and the routed
-    experts' three matrices per token; the router's weights and as many
-    experts as the step's tokens are expected to touch, read once a layer,
-    and the activations in and out."""
-    costs = trace.model_costs(config, dtype_bytes)
-    tokens = step["prefill_tokens"] + step["decodes"]
-    h, e, k = config["hidden_size"], costs["experts"], costs["top_k"]
-    touched = e * (1.0 - (1.0 - k / e) ** tokens)
-    flops = 2.0 * costs["layers"] * tokens * (
-        h * e + k * costs["expert_bytes"] / dtype_bytes)
-    nbytes = costs["layers"] * (
-        touched * costs["expert_bytes"]
-        + dtype_bytes * (h * e + 2.0 * tokens * h))
-    return max(flops / peaks["bf16_flop_s"], nbytes / peaks["hbm_byte_s"])
-
-
 def roofline_ctx_pct(obs: dict, part: str) -> float | None:
     """The least time the traced steps need on this chip's published
     peaks, each step with the context it recorded (``prefill_ctx`` /
-    ``decode_ctx``) in place of the traffic mix's mean, over device time:
-    ``part`` ``"step"`` is the whole step (``trace.least_seconds``) over
-    the device's busy time; ``"attn"`` and ``"moe"`` are that kernel's
-    term alone over the device time under its scope."""
+    ``decode_ctx``) in place of the traffic mix's mean, over device time.
+    The operations and bytes are the configuration's architecture's
+    (``step_cost``, ``attn_cost``, ``moe_cost`` of its ``arch`` module):
+    ``part`` ``"step"`` is the whole step over the device's busy time;
+    any other is that kernel's count alone (``<part>_cost``) over the
+    device time under its scope (``omni.<part>``)."""
     tr = obs["trace"]
     steps = [s for s in obs["steps"]
              if "prefill_ctx" in s and s["prefill_tokens"] + s["decodes"] > 0]
@@ -423,20 +389,21 @@ def roofline_ctx_pct(obs: dict, part: str) -> float | None:
         return None
     peaks = trace.peaks_of(obs["device"]["kind"])
     config, chips = obs["config"], obs["chips"]
+    arch = spec.arch_of(config)
     if part == "step":
-        costs = trace.model_costs(config)
-        least = sum(trace.least_seconds(
-            [s], costs, peaks,
-            s["prefill_ctx"] / max(s["prefill_tokens"], 1),
-            s["decode_ctx"] / max(s["decodes"], 1), chips=chips)
-            for s in steps)
+        least = trace.least_seconds(
+            (arch.step_cost(s, config,
+                            s["prefill_ctx"] / max(s["prefill_tokens"], 1),
+                            s["decode_ctx"] / max(s["decodes"], 1))
+             for s in steps), peaks, chips=chips)
         return 100.0 * least / tr["busy_s"] if tr["busy_s"] > 0 else None
     red = reduction()
     if not red or not red["scope_s"]:
         return None
-    fn, scope = {"attn": (attn_least_seconds, "omni.attn"),
-                 "moe": (moe_least_seconds, "omni.moe")}[part]
-    device_s = red["scope_s"].get(scope, 0.0)
-    if device_s <= 0:
+    device_s = red["scope_s"].get(PREFIX + part, 0.0)
+    cost_of = getattr(arch, f"{part}_cost")
+    costs = [c for s in steps if (c := cost_of(s, config)) is not None]
+    if device_s <= 0 or not costs:
         return None
-    return 100.0 * sum(fn(s, config, peaks) for s in steps) / chips / device_s
+    return 100.0 * sum(trace.roof_seconds(c, peaks)
+                       for c in costs) / chips / device_s

@@ -1,11 +1,13 @@
 """Resolve a cell of BENCHMARK.json into its files, by name.
 
-A later PR adds a configuration, a traffic mix, a cell or a per-layer
-metric as new files and new entries; nothing here names one.
+A later PR adds a configuration, a traffic mix, a cell, a per-layer metric
+or a model architecture as new files and new entries; nothing here names
+one.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 from pathlib import Path
 
@@ -15,6 +17,16 @@ ROOT = Path(__file__).resolve().parent.parent
 def load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def arch_of(config: dict):
+    """The architecture module a configuration file names under ``arch``
+    (a dotted module path): the model's builder, plain reference and
+    counts, under the fixed names of ``benchmark/archs/``'s contract."""
+    if "arch" not in config:
+        raise SystemExit(f"benchmark: configuration {config.get('name')!r} "
+                         "names no 'arch' module")
+    return importlib.import_module(config["arch"])
 
 
 def metrics_of(entries: list[dict], cell: str) -> list[dict]:

@@ -23,8 +23,12 @@ PEAKS = {
     },
 }
 
+#: collective operations by printed name.  The trace prints an instruction
+#: under the name its HLO carries, and JAX names the all-reduce made by
+#: ``lax.psum`` ``%psum.N`` (my chip run, PR 27: ``psum_bf16_2048_2048``
+#: beside ``all-gather_bf16_64_152064``)
 COLLECTIVES = ("all-reduce*", "all-gather*", "reduce-scatter*",
-               "all-to-all*", "collective-permute*")
+               "all-to-all*", "collective-permute*", "psum*")
 
 _SHAPE = re.compile(r"=\s*\(?\s*([a-z]+\d*)\[([\d,]*)\]")
 
@@ -136,60 +140,21 @@ def top(table: dict[str, float], n: int = 10) -> list[list]:
 
 
 # ------------------------------------------------------------- roofline
-def model_costs(config: dict, dtype_bytes: int = 2) -> dict:
-    """Operations and bytes the decoder's algorithm needs, from the
-    published sizes of the configuration file."""
-    h, d = config["hidden_size"], config["head_dim"]
-    q_dim, kv_dim = config["num_attention_heads"] * d, \
-        config["num_key_value_heads"] * d
-    layers, experts = config["num_hidden_layers"], config["num_experts"]
-    attn_params = h * q_dim + 2 * h * kv_dim + q_dim * h
-    expert_params = 3 * h * config["moe_intermediate_size"]
-    return {
-        "layers": layers, "experts": experts,
-        "top_k": config["num_experts_per_tok"],
-        "flops_per_token": 2.0 * layers * (
-            attn_params + h * experts
-            + config["num_experts_per_tok"] * expert_params),
-        "attn_flops_per_pair": 4.0 * q_dim * layers,
-        "head_flops_per_row": 2.0 * h * config["vocab_size"],
-        "fixed_weight_bytes": dtype_bytes * (
-            layers * (attn_params + h * experts) + h * config["vocab_size"]),
-        "expert_bytes": dtype_bytes * expert_params,
-        "kv_bytes_per_pos": dtype_bytes * 2 * layers * kv_dim,
-    }
+def roof_seconds(cost: tuple[float, float], peaks: dict) -> float:
+    """The least time one chip could take for ``cost`` = (operations,
+    bytes): the larger of operations over the peak rate and bytes over the
+    peak bandwidth."""
+    flops, nbytes = cost
+    return max(flops / peaks["bf16_flop_s"], nbytes / peaks["hbm_byte_s"])
 
 
-def least_seconds(steps: list[dict], costs: dict, peaks: dict,
-                  mean_prefill_ctx: float, mean_decode_ctx: float,
-                  chips: int = 1) -> float:
-    """The least time the chip(s) could take for these steps: for each
-    step the larger of operations over the peak rate and bytes over the
-    peak bandwidth.  A step is a flight-recorder record (``prefill_tokens``,
-    ``decodes``, ``prefills``).  A step reads the weights it touches once:
-    all but the experts, and of the experts as many as its tokens are
-    expected to route to, E x (1 - (1 - k/E)^tokens).  The records carry no
-    context lengths, so attention's pairs and the cache read take the
-    traffic mix's mean context per prefill token and per decode row."""
+def least_seconds(costs, peaks: dict, chips: int = 1) -> float:
+    """The least time the chip(s) could take for a run of steps, each
+    given as the (operations, bytes) its architecture counts for it
+    (``step_cost`` of the configuration's ``arch`` module), or None for a
+    step with no work."""
     total = 0.0
-    e, k = costs["experts"], costs["top_k"]
-    for s in steps:
-        tokens = s["prefill_tokens"] + s["decodes"]
-        if tokens <= 0:
-            continue
-        pairs = (s["prefill_tokens"] * mean_prefill_ctx
-                 + s["decodes"] * mean_decode_ctx)
-        flops = (costs["flops_per_token"] * tokens
-                 + costs["attn_flops_per_pair"] * pairs
-                 + costs["head_flops_per_row"] * (s["prefills"]
-                                                  + s["decodes"]))
-        touched = e * (1.0 - (1.0 - k / e) ** tokens)
-        # the cache is read once per decode row and once per prefill chunk
-        kv_read = (s["decodes"] * mean_decode_ctx
-                   + s["prefills"] * 2 * mean_prefill_ctx)
-        nbytes = (costs["fixed_weight_bytes"]
-                  + costs["layers"] * touched * costs["expert_bytes"]
-                  + costs["kv_bytes_per_pos"] * (kv_read + tokens))
-        total += max(flops / peaks["bf16_flop_s"],
-                     nbytes / peaks["hbm_byte_s"]) / chips
+    for cost in costs:
+        if cost is not None:
+            total += roof_seconds(cost, peaks) / chips
     return total

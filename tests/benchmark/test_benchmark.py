@@ -20,6 +20,7 @@ from pathlib import Path
 import pytest
 
 from benchmark import e2e, readers, spec, trace, traffic
+from benchmark.archs import qwen3_moe
 
 ROOT = spec.ROOT
 BENCH = spec.load_json(ROOT / "BENCHMARK.json")
@@ -27,6 +28,8 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 TRAFFIC = sorted(p.stem for p in (ROOT / "benchmark" / "traffic").glob("*.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 CATALOG = Path("/opt/skills/guides/model-configs/architectures.jsonl")
+#: every configuration file, also one that no cell uses yet
+CONFIG_FILES = sorted((ROOT / "benchmark" / "configs").glob("*.json"))
 
 
 # ------------------------------------------------------------ the contract
@@ -69,22 +72,27 @@ def test_benchmark_json_keeps_to_the_contract():
         c["name"] for c in BENCH["configs"]}
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_file_holds_the_published_numbers(entry):
+@pytest.mark.parametrize("path", CONFIG_FILES, ids=lambda p: p.stem)
+def test_config_file_holds_the_published_numbers(path):
     """Every number of the catalog's config under the same key, but for the
-    keys the entry lists as reduced; no width is among those."""
+    keys listed as reduced; no width is among those.  The file states its
+    own source and cuts, and its entry in BENCHMARK.json says the same."""
     if not CATALOG.exists():
         pytest.skip("the catalog of public architectures is not here")
     rows = [json.loads(line) for line in CATALOG.read_text().splitlines()]
-    published = next(r for r in rows if r["source_url"] == entry["source"])
-    ours = spec.load_json(ROOT / entry["file"])
-    for key in entry["reduced"]:
+    ours = spec.load_json(path)
+    published = next(r for r in rows if r["source_url"] == ours["source"])
+    for key in ours["reduced"]:
         assert not re.search(r"(_dim|_rank|_size|per_tok)$", key), key
     for key, value in published["config"].items():
-        if key not in entry["reduced"]:
+        if key not in ours["reduced"]:
             assert ours[key] == value, key
-    assert str((ROOT / entry["file"]).relative_to(ROOT)).startswith(
-        tuple(BENCH["paths"]))
+    rel = str(path.relative_to(ROOT))
+    assert rel.startswith(tuple(BENCH["paths"]))
+    for entry in BENCH["configs"]:
+        if entry["file"] == rel:
+            assert entry["source"] == ours["source"]
+            assert entry["reduced"] == list(ours["reduced"])
 
 
 # ------------------------------------------------------------ driven by data
@@ -264,6 +272,14 @@ def test_trace_reduction_on_a_small_recorded_trace():
     assert readers.device_op_share_pct(obs, ["ragged-dot*", "attn"]) == \
         pytest.approx(100 * 20 / 30)
     assert readers.collective_share_pct(obs) == 0.0
+    # collectives by printed name: an all-reduce made by lax.psum is
+    # printed as %psum.N
+    across = {"trace": {"self_s": {"psum_bf16_64_2048": 1.5,
+                                   "all-reduce_bf16_64_2048": 0.5,
+                                   "all-gather-start_bf16_64_38016": 0.5,
+                                   "all-gather_bf16_64_152064": 0.5,
+                                   "fusion_bf16_64_2048": 7.0}}}
+    assert readers.collective_share_pct(across) == pytest.approx(30.0)
     assert trace.top(got["self_s"], 2) == [["attn", pytest.approx(15e-6)],
                                            ["sort_f32_8", pytest.approx(6e-6)]]
     assert trace.reduce_events([])["busy_s"] == 0.0
@@ -277,7 +293,7 @@ def test_least_seconds_takes_the_larger_of_the_two_bounds():
               "num_key_value_heads": 1, "num_hidden_layers": 2,
               "num_experts": 4, "num_experts_per_tok": 1,
               "moe_intermediate_size": 8, "vocab_size": 16}
-    costs = trace.model_costs(config)
+    costs = qwen3_moe.model_costs(config)
     # per layer: attention 8*8 + 2*8*4 + 8*8 = 192, router 32, expert 192
     assert costs["flops_per_token"] == 2.0 * 2 * (192 + 32 + 192)
     assert costs["attn_flops_per_pair"] == 4.0 * 8 * 2
@@ -288,12 +304,17 @@ def test_least_seconds_takes_the_larger_of_the_two_bounds():
     step = {"prefill_tokens": 0, "decodes": 1, "prefills": 0}
     flops = 1664 + 64 * 10 + 256
     nbytes = 1152 + 2 * 1.0 * 384 + 32 * (10 + 1)
+    assert qwen3_moe.step_cost(step, config, 0.0, 10.0) == (flops, nbytes)
+    assert qwen3_moe.step_cost({**step, "decodes": 0}, config, 0.0,
+                               10.0) is None
     slow_memory = {"bf16_flop_s": 1e12, "hbm_byte_s": 1.0}
     slow_compute = {"bf16_flop_s": 1.0, "hbm_byte_s": 1e12}
-    assert trace.least_seconds([step], costs, slow_memory, 0.0, 10.0) == \
+    cost = (flops, nbytes)
+    assert trace.roof_seconds(cost, slow_memory) == pytest.approx(nbytes)
+    assert trace.least_seconds([cost, None], slow_memory) == \
         pytest.approx(nbytes)
-    assert trace.least_seconds([step, step], costs, slow_compute, 0.0, 10.0,
-                               chips=2) == pytest.approx(flops)
+    assert trace.least_seconds([cost, cost], slow_compute, chips=2) == \
+        pytest.approx(flops)
 
 
 def test_counter_readers_take_the_window_between_two_snapshots():
@@ -391,6 +412,7 @@ TINY = {
     "norm_topk_prob": True, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
     "rope_scaling": {"mrope_section": [4, 2, 2]}, "use_qk_norm": True,
     "vocab_size": 512, "serve_dtype": "float32",
+    "arch": "benchmark.archs.qwen3_moe",
 }
 
 
@@ -402,7 +424,7 @@ def test_reference_agrees_with_the_engine_at_a_tiny_width():
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import reference, serve
+    from benchmark import serve
     from vllm_omni_tpu.engine import EngineConfig, LLMEngine
     from vllm_omni_tpu.sampling_params import SamplingParams
 
@@ -415,25 +437,31 @@ def test_reference_agrees_with_the_engine_at_a_tiny_width():
         temperature=0.0, max_tokens=5, ignore_eos=True, logprobs=0))
     ids = [int(t) for t in out.outputs[0].token_ids]
     served = [e["logprob"] for e in out.outputs[0].logprobs]
-    ref = reference.logprobs(params, TINY, prompt + ids[:-1])
+    ref = qwen3_moe.reference_logprobs(params, TINY, prompt + ids[:-1],
+                                       len(prompt) + 4)
     assert ref.shape == (len(prompt) + 4, 512)
     want = [float(ref[len(prompt) - 1 + i, t]) for i, t in enumerate(ids)]
     assert served == pytest.approx(want, abs=2e-4)
     # greedy: the served token is the reference's most likely one
     assert ids[0] == int(jnp.argmax(ref[len(prompt) - 1]))
+    # the last n positions are the tail of all of them
+    tail = qwen3_moe.reference_logprobs(params, TINY, prompt + ids[:-1], 5)
+    assert tail.shape == (5, 512)
+    np.testing.assert_array_equal(np.asarray(tail), np.asarray(ref[-5:]))
 
 
 def test_published_sizes_become_the_programs_config():
-    from benchmark import serve
     from vllm_omni_tpu.models.qwen3_omni.thinker import QWEN3_OMNI_THINKER_30B
 
     config = spec.load_json(ROOT / BENCH["configs"][0]["file"])
-    cfg = serve.transformer_config(config)
+    cfg = spec.arch_of(config).program_config(config)
     assert cfg.num_layers == config["num_hidden_layers"]
     assert cfg.hidden_size == QWEN3_OMNI_THINKER_30B.hidden_size == 2048
     drifted = {**config, "head_dim": 64}
     with pytest.raises(SystemExit):
-        serve.transformer_config(drifted)
+        qwen3_moe.program_config(drifted)
+    with pytest.raises(SystemExit):
+        spec.arch_of({"name": "a file that names no architecture"})
 
 
 def test_the_command_refuses_a_cell_without_a_tpu():

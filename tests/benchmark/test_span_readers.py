@@ -229,7 +229,8 @@ def test_every_new_metric_file_resolves_by_name_and_reads(name, monkeypatch):
     config = {"hidden_size": 8, "head_dim": 4, "num_attention_heads": 2,
               "num_key_value_heads": 1, "num_hidden_layers": 2,
               "num_experts": 4, "num_experts_per_tok": 1,
-              "moe_intermediate_size": 8, "vocab_size": 16}
+              "moe_intermediate_size": 8, "vocab_size": 16,
+              "arch": "benchmark.archs.qwen3_moe"}
     step = {"prefill_tokens": 0, "prefills": 0, "decodes": 1,
             "prefill_ctx": 0, "decode_ctx": 10}
     obs = {"engine0": {"intake_ms": {"sum": 10.0, "count": 2}},
@@ -244,10 +245,10 @@ def test_every_new_metric_file_resolves_by_name_and_reads(name, monkeypatch):
         return
     # rooflines: the step's least time on the chip's peaks over device time
     peaks = trace.peaks_of("TPU v5 lite")
-    costs = trace.model_costs(config)
     if name == "device_roofline_ctx_pct":
-        least, device_s = trace.least_seconds(
-            [step], costs, peaks, 0.0, 10.0), 30e-6
+        # the whole step: 2 560 operations, 2 272 bytes (worked out in
+        # test_least_seconds_takes_the_larger_of_the_two_bounds)
+        least, device_s = max(2560 / 197e12, 2272 / 819e9), 30e-6
     elif name == "attn_roofline_pct":
         # 64 operations a pair x 10 pairs; 32 bytes a position x 10 read,
         # and 2 layers x 8 wide queries in and out
@@ -284,6 +285,7 @@ TINY = {
     "norm_topk_prob": True, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
     "rope_scaling": {"mrope_section": [4, 2, 2]}, "use_qk_norm": True,
     "vocab_size": 512, "serve_dtype": "float32",
+    "arch": "benchmark.archs.qwen3_moe",
     "engine": {"max_num_seqs": 4, "max_num_batched_tokens": 64,
                "max_model_len": 128, "num_pages": 64, "page_size": 16},
 }
