@@ -103,3 +103,71 @@ def test_transformer_forward_routed_matches_dense(rng, devices8):
         moe_ops.set_ep_mesh(None)
     np.testing.assert_allclose(np.asarray(got_ep), np.asarray(want),
                                atol=2e-5, rtol=1e-4)
+
+
+# ---- the chip's share of an expert-parallel deployment (ISSUE 28)
+def _shared_expert_layer(rng, hidden=16, e=8, inter=8, shared=8):
+    ks = jax.random.split(rng, 6)
+    return {
+        "router": {"w": jax.random.normal(ks[0], (hidden, e)) * 0.5},
+        "experts": {
+            "gate_up": jax.random.normal(ks[1], (e, hidden, 2 * inter)) * 0.2,
+            "down": jax.random.normal(ks[2], (e, inter, hidden)) * 0.2},
+        "shared_expert": {
+            "gate_up": {"w": jax.random.normal(ks[3], (hidden, 2 * shared))
+                        * 0.2},
+            "down": {"w": jax.random.normal(ks[4], (shared, hidden)) * 0.2}},
+        "shared_gate": {"w": jax.random.normal(ks[5], (hidden, 1))},
+    }
+
+
+@pytest.mark.parametrize("dispatch", ["routed", "dense"])
+def test_the_four_shares_and_the_shared_expert_add_up_to_the_layer(
+        rng, dispatch):
+    """The routed parts that four chips give (experts 0-1, 2-3, 4-5, 6-7
+    of an 8-wide router, top-2 renormalised over the picks wherever they
+    live) plus the shared expert counted once equal the uncut layer.
+    float32 throughout: the sums differ by their order only (1e-6)."""
+    layer = _shared_expert_layer(rng)
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, 16))
+    whole_cfg = tfm.TransformerConfig(
+        moe=True, num_experts=8, num_experts_per_tok=2,
+        shared_expert_size=8, moe_dispatch=dispatch)
+    whole = tfm._moe_mlp(layer, whole_cfg, x)
+    routed_only = {k: v for k, v in layer.items()
+                   if not k.startswith("shared")}
+    parts = []
+    for offset in (0, 2, 4, 6):
+        share = dict(routed_only, experts={
+            k: v[offset:offset + 2] for k, v in layer["experts"].items()})
+        cfg = dataclasses.replace(whole_cfg, num_experts=2, router_experts=8,
+                                  expert_offset=offset)
+        parts.append(tfm._moe_mlp(share, cfg, x))
+    shared_once = tfm._moe_mlp(layer, whole_cfg, x) - tfm._moe_mlp(
+        routed_only, whole_cfg, x)
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared_once),
+                               np.asarray(whole), atol=2e-6, rtol=1e-5)
+    # a share is no stand-in for the layer: alone it gives less
+    assert float(jnp.abs(parts[0] + shared_once - whole).max()) > 1e-3
+
+
+def test_a_share_computes_only_the_pairs_of_its_own_experts(rng):
+    """Picks that live elsewhere sort behind the last held expert's group:
+    the grouped matmul's group sizes sum to the local pairs, not T * k."""
+    x, rw, gu, dn = _mk_weights(rng, t=32, e=8)
+    idx, _ = moe_ops.router_topk(x, rw, 2)
+    local = int(jnp.sum((idx >= 4) & (idx < 6)))
+    seen = {}
+    real = moe_ops._grouped_mlp
+
+    def spy(xs, gate_up, down, group_sizes):
+        seen["sizes"] = np.asarray(group_sizes)
+        return real(xs, gate_up, down, group_sizes)
+
+    moe_ops._grouped_mlp = spy
+    try:
+        moe_ops.routed_moe_share(x, rw, gu[4:6], dn[4:6], 2, 4)
+    finally:
+        moe_ops._grouped_mlp = real
+    assert seen["sizes"].shape == (2,) and int(seen["sizes"].sum()) == local
+    assert 0 < local < 64

@@ -42,6 +42,10 @@ SHAPES = {
     "group4_bf16": dict(t=1024, hkv=4, group=4, quantized=False),
     "group1_bf16": dict(t=1024, hkv=4, group=1, quantized=False),
     "group1_int8": dict(t=1024, hkv=4, group=1, quantized=True),
+    # Qwen3-Next's gated-attention layers: head width 256, 2 kv heads,
+    # contexts to 33280 (the second shape the kernel is measured at)
+    "head256_bf16": dict(t=4096, hkv=2, group=8, quantized=False, d=256,
+                         pool=32768, s_max=32, max_pages=2080),
 }
 
 
@@ -50,7 +54,9 @@ def test_ragged_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
     shape = SHAPES[name]
     t, hkv, group, quantized = (shape[k] for k in
                                 ("t", "hkv", "group", "quantized"))
-    d, page, pool, s_max, max_pages = 128, 16, 8192, 64, 528
+    d, page = shape.get("d", 128), 16
+    pool, s_max, max_pages = (shape.get(k, v) for k, v in (
+        ("pool", 8192), ("s_max", 64), ("max_pages", 528)))
     monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
 
     def sds(dims, dtype):
@@ -74,3 +80,37 @@ def test_ragged_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
         sds((s_max,), jnp.int32), sds((s_max,), jnp.int32),
         sds((1,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gated_delta_net_compiles_for_v5e(one_chip):
+    """The Gated DeltaNet mixer of ops/gated_delta_rule.py (plain
+    jax.numpy: the convolution, the chunked rule with its triangular solve
+    and scan, the one-token form) at Qwen3-Next's widths: what the TPU's
+    compiler would refuse (a solve or a scan it cannot lower, too much
+    memory) fails here."""
+    from vllm_omni_tpu.ops import gated_delta_rule as gdr
+
+    heads, conv_dim, s_max = (16, 32, 128, 128), 8192, 32
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def states(rows):
+        return (sds((rows, 3, conv_dim), jnp.bfloat16),
+                sds((rows, 32, 128, 128), jnp.float32))
+
+    def mixed(rows):
+        return (sds((rows, conv_dim), jnp.bfloat16),
+                sds((rows, 32), jnp.float32), sds((rows, 32), jnp.float32),
+                sds((4, conv_dim), jnp.bfloat16))
+
+    ragged = jax.jit(
+        lambda m, g, b, w, c, r, cu, ql: gdr.gated_delta_net_ragged(
+            m, g, b, w, c, r, cu, ql, heads)).lower(
+        *mixed(4096), *states(s_max), sds((s_max + 1,), jnp.int32),
+        sds((s_max,), jnp.int32)).compile()
+    # the chunks' tensors in float32: well inside what the step may take
+    assert ragged.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+    jax.jit(lambda m, g, b, w, c, r: gdr.gated_delta_net_decode(
+        m, g, b, w, c, r, heads)).lower(
+        *mixed(s_max), *states(s_max)).compile()

@@ -81,6 +81,10 @@ def synthetic_engine_snapshot() -> dict:
             "parked_tokens": 32, "offload_evictions": 2,
         },
         "kv_restore_seconds": hist,
+        # the state cache of a model with recurrent layers
+        "state_slots": hist,
+        "state": {"state_slot_allocs_total": 5, "state_resets_total": 1,
+                  "prefix_hits_refused_total": 4},
         # serving-curve observability (docs/load_testing.md): tenant-
         # labeled SLO/goodput ledger, queue depth + wait, shed ledger,
         # per-phase saturation — the drift guard must cover every

@@ -94,11 +94,26 @@ class KVCacheManager:
                  tiers: Optional[TieredKVStore] = None,
                  policy: Optional[OffloadPolicy] = None,
                  cache_dtype: Optional[str] = None,
-                 bytes_per_token: Optional[float] = None):
+                 bytes_per_token: Optional[float] = None,
+                 num_state_slots: int = 0):
         if num_pages < 1 or page_size < 1:
             raise ValueError("num_pages and page_size must be positive")
         self.num_pages = num_pages
         self.page_size = page_size
+        # ---- the second kind of state (docs/kv_cache.md): a model with
+        # recurrent layers keeps, beside its pages, one STATE SLOT a
+        # sequence (the runner's per-layer conv + recurrent state arrays
+        # are indexed by it).  A sequence takes a slot with its first
+        # page and gives it back in free(); the runner reads zeros for a
+        # sequence's first chunk, which is the slot's zeroing.  0 => the
+        # model has no recurrent layer and nothing below applies.
+        self.num_state_slots = num_state_slots
+        self._free_slots: list[int] = list(range(num_state_slots))[::-1]
+        self._state_slots: dict[str, int] = {}
+        self.state_slot_allocs = 0
+        # prefix hits found in the index and NOT handed out: pages hold
+        # no recurrent state, so a recurrent model recomputes them
+        self.prefix_hits_refused = 0
         # resident KV layout metadata for /debug/kv (informational —
         # the allocator is layout-agnostic): the pool dtype label
         # ("int8" / "bfloat16" / None when the engine didn't say) and
@@ -187,6 +202,14 @@ class KVCacheManager:
     def block_table(self, request_id: str) -> list[int]:
         return list(self._tables.get(request_id, ()))
 
+    def state_slot(self, request_id: str) -> int:
+        """The sequence's state slot; -1 where it holds none."""
+        return self._state_slots.get(request_id, -1)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return len(self._state_slots)
+
     def can_allocate(self, request: Request, num_new_tokens: int) -> bool:
         have = len(self._tables.get(request.request_id, ()))
         need = self.pages_needed(request.num_computed_tokens + num_new_tokens)
@@ -246,6 +269,15 @@ class KVCacheManager:
             "prefix_index": (self.index.debug_stats()
                              if self.enable_prefix_caching
                              else {"enabled": False}),
+            # the second kind of state: one slot a sequence of a model
+            # with recurrent layers (num 0 => the model has none)
+            "state_slots": {
+                "total": self.num_state_slots,
+                "in_use": len(self._state_slots),
+                "by_request": dict(sorted(self._state_slots.items())),
+                "allocs": self.state_slot_allocs,
+                "prefix_hits_refused": self.prefix_hits_refused,
+            },
             "pending_moves": {
                 "offloads": len(self.pending_offloads),
                 "restores": len(self.pending_restores),
@@ -341,6 +373,15 @@ class KVCacheManager:
             request._apc_keys = keys
         nodes = self.index.match(keys=keys)
         if not nodes:
+            return 0
+        if self.num_state_slots:
+            # a hit hands out pages, and pages hold no recurrent state:
+            # serving it would skip the prefix in the linear layers.
+            # Refused (and counted, once a request) until state snapshots
+            # exist (ROADMAP: state snapshots for prefix reuse)
+            if not getattr(request, "_apc_refused", False):
+                request._apc_refused = True
+                self.prefix_hits_refused += 1
             return 0
         # acquire the WHOLE match up front: referenced nodes are
         # invisible to eviction, so allocating pages for cold restores
@@ -478,6 +519,8 @@ class KVCacheManager:
         matched)."""
         rid = request.request_id
         fresh = rid not in self._tables
+        if fresh and self.num_state_slots and not self._free_slots:
+            return None  # cannot happen while slots == max_num_seqs
         table = self._tables.setdefault(rid, [])
         need = self.pages_needed(request.num_computed_tokens + num_new_tokens)
         grow = need - len(table)
@@ -498,6 +541,9 @@ class KVCacheManager:
             return None
         if grow > 0:
             self._stamp_pages(request)
+        if fresh and self.num_state_slots:
+            self._state_slots[rid] = self._free_slots.pop()
+            self.state_slot_allocs += 1
         return list(table)
 
     def adopt_streamed(self, request: Request, n_tokens: int
@@ -557,6 +603,9 @@ class KVCacheManager:
         table = self._tables.pop(request.request_id, None)
         if table is None:
             return
+        slot = self._state_slots.pop(request.request_id, None)
+        if slot is not None:
+            self._free_slots.append(slot)
         for node in self._adopted.pop(request.request_id, ()):
             self.index.release(node)
         owned = set(self.index._by_page)

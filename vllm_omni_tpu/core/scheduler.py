@@ -123,6 +123,9 @@ class ScheduledRequest:
     # RETIRED (PR 11): the multi-step decode window is always 1 — kept
     # only so stored/constructed ScheduledRequests keep their shape
     window: int = 1
+    # the sequence's state slot in a model with recurrent layers
+    # (KVCacheManager.state_slot); -1 for every other model
+    state_slot: int = -1
 
     @property
     def is_prefill(self) -> bool:
@@ -187,6 +190,8 @@ class ARScheduler:
         # lifetime counters for step-level metrics (/metrics gauges)
         self.num_preemptions = 0
         self.num_rejections = 0
+        # preemptions that threw away a sequence's recurrent state
+        self.num_state_resets = 0
         # load-shed counters, keyed (reason, tenant) — rendered as
         # shed_requests_total{reason, tenant} on /metrics
         self.shed_counts: dict[tuple[str, str], int] = {}
@@ -735,6 +740,11 @@ class ARScheduler:
             self.waiting.pop(0)
             req.status = RequestStatus.RUNNING
             self.running.append(req)
+        if self.kv.num_state_slots:
+            # the slot column: taken with the sequence's first page, so
+            # every scheduled row of a recurrent model has one by now
+            for sc in out.prefills + out.decodes:
+                sc.state_slot = self.kv.state_slot(sc.request.request_id)
         return out
 
     def _preempt(self, req: Request) -> None:
@@ -746,6 +756,10 @@ class ARScheduler:
         fallback: in-flight async tokens, a policy veto, or a lost
         payload all degrade to the classic path bit-identically."""
         self.num_preemptions += 1
+        if self.kv.state_slot(req.request_id) >= 0:
+            # the victim's recurrent state goes with its slot: it
+            # recomputes from position 0, where a row reads zeros
+            self.num_state_resets += 1
         if self.config.kv_offload:
             # the manager parks only the COMMITTED prefix (in-flight
             # async slots excluded — their tokens are discarded below

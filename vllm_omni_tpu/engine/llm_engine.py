@@ -206,6 +206,17 @@ class EngineConfig:
     tensor_parallel_size: int = 1
 
 
+def _prompt_tokens_computed(sched_out: SchedulerOutput) -> int:
+    """Prompt tokens a step computed, for the ``prefill_tokens`` counter:
+    its prefill chunks, and a decode row that is still inside its prompt
+    (a chunked prompt whose remainder is one token rides the decode rows,
+    core/scheduler.py ``remaining > 1``; counted as a chunk or not, it is a
+    prompt token, and the counter must add up to the prompts served)."""
+    return (sum(s.num_new_tokens for s in sched_out.prefills)
+            + sum(s.num_new_tokens for s in sched_out.decodes
+                  if s.is_prefill))
+
+
 @dataclass
 class _InflightStep:
     """One slot of the two-slot pipeline: a dispatched-but-unretired
@@ -249,6 +260,7 @@ class LLMEngine:
             # line of executables that can never dispatch
             config = dataclasses.replace(config, async_scheduling=False,
                                          unified_batching=False)
+        self._refuse_for_recurrent(model_cfg, config, draft_fn)
         self.config = config
         self.eos_token_id = eos_token_id
         # tiered KV offload: the cold-side store + break-even policy
@@ -274,7 +286,7 @@ class LLMEngine:
                 host_capacity_bytes=config.kv_host_tier_bytes,
                 remote=remote)
             kv_policy = OffloadPolicy.for_model(
-                model_cfg.num_layers, model_cfg.num_kv_heads,
+                model_cfg.num_kv_layers, model_cfg.num_kv_heads,
                 model_cfg.head_dim,
                 jnp.dtype(config.dtype).itemsize,
                 mode=config.kv_offload_policy,
@@ -303,12 +315,12 @@ class LLMEngine:
             if self._kv_quant or config.kv_hbm_budget_bytes is not None:
                 budget = config.kv_hbm_budget_bytes
                 if budget is None:
-                    budget = config.num_pages * model_cfg.num_layers * \
+                    budget = config.num_pages * model_cfg.num_kv_layers * \
                         page_bytes(model_cfg.num_kv_heads,
                                    config.page_size, model_cfg.head_dim,
                                    quantized=False, itemsize=itemsize)
                 num_pages = pages_for_budget(
-                    budget, model_cfg.num_layers, model_cfg.num_kv_heads,
+                    budget, model_cfg.num_kv_layers, model_cfg.num_kv_heads,
                     config.page_size, model_cfg.head_dim,
                     quantized=self._kv_quant, itemsize=itemsize)
                 logger.info(
@@ -317,7 +329,7 @@ class LLMEngine:
                     config.kv_cache_dtype, num_pages, budget / 2**20,
                     config.num_pages)
             self._kv_bytes_per_token = bytes_per_token(
-                model_cfg.num_layers, model_cfg.num_kv_heads,
+                model_cfg.num_kv_layers, model_cfg.num_kv_heads,
                 config.page_size, model_cfg.head_dim,
                 quantized=self._kv_quant, itemsize=itemsize)
         # prefix caching skips the forward for cached positions, so it
@@ -333,7 +345,13 @@ class LLMEngine:
                             cache_dtype=(
                                 "int8" if self._kv_quant
                                 else str(jnp.dtype(config.dtype))),
-                            bytes_per_token=self._kv_bytes_per_token)
+                            bytes_per_token=self._kv_bytes_per_token,
+                            # one state slot a sequence for a model with
+                            # recurrent layers (docs/kv_cache.md)
+                            num_state_slots=(
+                                max(config.max_num_seqs, 1)
+                                if getattr(model_cfg, "num_state_layers", 0)
+                                else 0))
         sched_cfg = SchedulerConfig(
             max_num_seqs=config.max_num_seqs,
             max_num_batched_tokens=config.max_num_batched_tokens,
@@ -496,6 +514,39 @@ class LLMEngine:
             logger.info(
                 "engine warmup: %d executables precompiled before "
                 "serving", n)
+
+    @staticmethod
+    def _refuse_for_recurrent(model_cfg, config: "EngineConfig",
+                              draft_fn) -> None:
+        """A model with recurrent layers keeps part of every sequence in
+        a state slot (docs/kv_cache.md).  Whatever moves, copies or rolls
+        back pages alone would serve it wrongly, so each such mechanism
+        is refused here, by name, before anything is built; prefix hits
+        are refused one by one in ``KVCacheManager.match_prefix`` and
+        preemption recomputes from a zeroed slot.  State snapshots lift
+        these (ROADMAP, Reach)."""
+        if not getattr(model_cfg, "num_state_layers", 0):
+            return
+        refused = []
+        if config.kv_offload:
+            refused.append("kv_offload (parking / tiered offload of pages)")
+        if config.kv_transfer is not None or config.engine_role != "colocated":
+            refused.append("kv_transfer (inject_kv / extract_kv; engine_role "
+                           f"{config.engine_role!r})")
+        if config.num_speculative_tokens > 0 or draft_fn is not None:
+            refused.append("speculative decoding (verify rows cannot roll "
+                           "a recurrent state back)")
+        if config.kv_cache_dtype == "int8":
+            refused.append("kv_cache_dtype int8 (no quantized layout for "
+                           "the recurrent state)")
+        if config.tensor_parallel_size > 1:
+            refused.append("tensor_parallel_size > 1 (no sharded layout "
+                           "for the state cache)")
+        if refused:
+            raise ValueError(
+                "a model with recurrent (linear-attention) layers keeps "
+                "per-sequence state beside its KV pages, which these "
+                "mechanisms do not carry yet: " + "; ".join(refused))
 
     # ------------------------------------------------------------- warmup
     def warmup(self, prefill_shapes=(), progress_fn=None) -> int:
@@ -897,6 +948,17 @@ class LLMEngine:
         step_metrics = getattr(self, "step_metrics", None)  # fakes
         if step_metrics is not None:
             step_metrics.wide_q_tokens_total += wide
+        # sequences whose recurrent state this step read and wrote (every
+        # scheduled row of a model with recurrent layers; 0 otherwise):
+        # what a roofline of the Gated DeltaNet layers needs
+        kv = getattr(self.scheduler, "kv", None)  # duck-typed fakes
+        state_rows = (len(sched_out.prefills) + len(sched_out.decodes)
+                      if getattr(kv, "num_state_slots", 0) else 0)
+        if state_rows and step_metrics is not None:
+            # slots in use while the step ran: a sequence that finished in
+            # it has given its slot back by now, but was one of its rows
+            step_metrics.state_slots.observe(
+                max(kv.state_slots_in_use, state_rows))
         tracker = getattr(self, "roofline", None)  # duck-typed fakes
         if tracker is not None:
             roofline = tracker.on_step(
@@ -934,6 +996,7 @@ class LLMEngine:
             "prefill_ctx": int(prefill_ctx),
             "decode_ctx": int(decode_ctx),
             "wide_q_tokens": wide,
+            "state_rows": state_rows,
             "waiting": len(self.scheduler.waiting),
             "running": len(self.scheduler.running),
             "host_ms": round(host_ms, 3),
@@ -1053,6 +1116,17 @@ class LLMEngine:
             "bytes_per_token": self._kv_bytes_per_token,
         }
         snap["prefix_cache"] = self.prefix_cache_stats
+        if getattr(kv, "num_state_slots", 0):
+            # the second kind of cache (docs/kv_cache.md); the
+            # ``state_slots`` histogram rides step_metrics' snapshot
+            snap["state"] = {
+                "slots_total": kv.num_state_slots,
+                "slots_in_use": kv.state_slots_in_use,
+                "state_slot_allocs_total": kv.state_slot_allocs,
+                "state_resets_total": getattr(
+                    self.scheduler, "num_state_resets", 0),
+                "prefix_hits_refused_total": kv.prefix_hits_refused,
+            }
         if self.kv_tiers is not None:
             st = self.kv_tiers.stats()
             snap["kv_tiers"] = {
@@ -1310,8 +1384,7 @@ class LLMEngine:
             # dispatched — the only unoverlapped host time is the wait
             self.step_metrics.on_step(
                 step_ms=total_s * 1e3, new_tokens=new_total,
-                prefill_tokens=sum(s.num_new_tokens
-                                   for s in sched_out.prefills),
+                prefill_tokens=_prompt_tokens_computed(sched_out),
                 host_ms=host_ms, device_ms=wait_s * 1e3,
                 overlapped_host_ms=host_ms if prev is not None else 0.0,
             )
@@ -1586,8 +1659,7 @@ class LLMEngine:
             self.step_metrics.on_step(
                 step_ms=total_s * 1e3,
                 new_tokens=new_total,
-                prefill_tokens=sum(s.num_new_tokens
-                                   for s in sched_out.prefills),
+                prefill_tokens=_prompt_tokens_computed(sched_out),
                 # execute() syncs internally, so its span (plus any
                 # pipeline-drain wait that preceded it) is the
                 # device-bound portion; no host work overlaps it

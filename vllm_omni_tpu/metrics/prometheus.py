@@ -68,6 +68,22 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     "prefix_cache_hit_tokens_total": (
         "counter", "Prompt tokens served from the prefix cache",
         ("stage",)),
+    # ---- the state cache of models with recurrent layers
+    # (docs/kv_cache.md): one slot a sequence beside its pages
+    "state_slots": (
+        "histogram", "State slots in use, observed once a step",
+        ("stage",)),
+    "state_slot_allocs_total": (
+        "counter", "State slots handed to a sequence with its first page",
+        ("stage",)),
+    "state_resets_total": (
+        "counter",
+        "Preemptions that threw away a sequence's recurrent state",
+        ("stage",)),
+    "prefix_hits_refused_total": (
+        "counter",
+        "Prefix-cache hits not handed out because pages hold no "
+        "recurrent state", ("stage",)),
     # ---- kvcache subsystem: radix prefix index + tiered offload
     # (docs/kv_cache.md)
     "kv_prefix_hit_tokens_total": (
@@ -557,6 +573,12 @@ def render_exposition(summary: dict, engine_snaps: dict,
             exp.sample("prefix_cache_hits_total", labels, pc.get("hits", 0))
             exp.sample("prefix_cache_hit_tokens_total", labels,
                        pc.get("hit_tokens", 0))
+        state = snap.get("state")
+        if state:
+            exp.histogram("state_slots", labels, snap["state_slots"])
+            for name in ("state_slot_allocs_total", "state_resets_total",
+                         "prefix_hits_refused_total"):
+                exp.sample(name, labels, state.get(name, 0))
         tiers = snap.get("kv_tiers")
         if tiers:
             exp.sample("kv_prefix_hit_tokens_total", labels,

@@ -84,8 +84,14 @@ class ModelGeometry:
         """Derive the cost model from a ``TransformerConfig``.  MoE
         FLOPs count ACTIVE parameters per token (top-k routed + shared
         expert); MoE bytes count the experts a step's tokens touch
-        (``step_bytes``); attention uses the dense per-layer shape."""
+        (``step_bytes``); attention uses the dense per-layer shape.
+        In a hybrid stack only the softmax-attention layers attend a
+        context and hold pages (``num_kv_layers``); a linear-attention
+        layer's mixer is counted at an attention layer's parameters, a
+        few per cent from its own (the benchmark's arch module counts
+        it exactly, benchmark/archs/)."""
         h = cfg.hidden_size
+        kv_layers = getattr(cfg, "num_kv_layers", cfg.num_layers)
         q_dim = cfg.num_heads * cfg.head_dim
         kv_dim = cfg.num_kv_heads * cfg.head_dim
         attn_params = h * q_dim + 2 * h * kv_dim + q_dim * h
@@ -105,12 +111,12 @@ class ModelGeometry:
         return cls(
             flops_per_token=2.0 * active_params,
             attn_flops_per_ctx=(4.0 * cfg.num_heads * cfg.head_dim
-                                * cfg.num_layers),
+                                * kv_layers),
             lm_head_flops_per_row=2.0 * h * cfg.vocab_size,
             weight_bytes=float(dtype_bytes * (
                 cfg.num_layers * (attn_params + fixed_mlp)
                 + h * cfg.vocab_size)),
-            kv_bytes_per_pos=float(2 * cfg.num_layers * kv_dim
+            kv_bytes_per_pos=float(2 * kv_layers * kv_dim
                                    * dtype_bytes),
             expert_bytes=float(expert_params * dtype_bytes),
             num_experts=experts, experts_per_tok=top_k,

@@ -86,6 +86,9 @@ LATENCY_BUCKETS_MS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 TOKEN_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                  512.0, 1024.0, 2048.0, 4096.0, 8192.0)
 
+# state slots in use (a count of sequences, up to max_num_seqs)
+STATE_SLOT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
+
 # KV tier-restore latency buckets, in SECONDS: sub-ms for small
 # host-tier hits up to tens of seconds for big runs over a slow remote
 # tier (docs/kv_cache.md).
@@ -347,6 +350,10 @@ class EngineStepMetrics:
         # what the handler, the orchestrator's intake queue and the
         # stage's tokenizer add in front of the engine
         self.intake_ms = Histogram()
+        # state slots in use, observed once a step: the occupancy of the
+        # second kind of cache a model with recurrent layers holds
+        # (core/kv_cache_manager.py; never observed for other models)
+        self.state_slots = Histogram(buckets=STATE_SLOT_BUCKETS)
         # SLO targets (None = unconfigured leg always passes) + the
         # per-tenant attainment/goodput ledger they gate
         self.slo_ttft_ms: Optional[float] = None
@@ -489,6 +496,7 @@ class EngineStepMetrics:
             "kv_restore_seconds": self.kv_restore_s.snapshot(),
             "queue_wait_ms": self.queue_wait_ms.snapshot(),
             "intake_ms": self.intake_ms.snapshot(),
+            "state_slots": self.state_slots.snapshot(),
             "saturation": dict(self.saturation),
             "slo": {
                 "targets": {"ttft_ms": self.slo_ttft_ms,

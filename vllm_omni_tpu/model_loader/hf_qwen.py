@@ -80,6 +80,27 @@ def config_from_hf(model_dir: str,
         mrope_sections=tuple(
             (hf.get("rope_scaling") or {}).get("mrope_section"))
         if (hf.get("rope_scaling") or {}).get("mrope_section") else None,
+        **_hybrid_keys(hf, model_type),
+    )
+
+
+def _hybrid_keys(hf: dict, model_type: str) -> dict:
+    """The Qwen3-Next family's extra switches: the layer pattern, the
+    Gated DeltaNet head geometry, and what its attention layers and norms
+    do differently (models/qwen3_next)."""
+    if model_type != "qwen3_next":
+        return {}
+    return dict(
+        full_attention_interval=hf.get("full_attention_interval", 4),
+        linear_num_key_heads=hf["linear_num_key_heads"],
+        linear_num_value_heads=hf["linear_num_value_heads"],
+        linear_key_head_dim=hf["linear_key_head_dim"],
+        linear_value_head_dim=hf["linear_value_head_dim"],
+        linear_conv_kernel_dim=hf.get("linear_conv_kernel_dim", 4),
+        attn_output_gate=True,
+        partial_rotary_factor=hf.get("partial_rotary_factor", 0.25),
+        norm_zero_centered=True,
+        residual_in_fp32=True,
     )
 
 
@@ -117,6 +138,46 @@ _DIRECT = {
 }
 
 _EXPERT_RE = re.compile(r"^mlp\.experts\.(\d+)\.(gate_proj|up_proj|down_proj)$")
+# Gated DeltaNet tensors (Qwen3-Next); A_log and dt_bias carry no
+# .weight suffix, so they get a pattern of their own
+_GDN_RE = re.compile(
+    r"^(?:(?:model|language_model)\.)?layers\.(\d+)\.linear_attn\.(.+)$")
+
+
+def _load_gdn(la: dict, cfg: tfm.TransformerConfig, sub: str, arr) -> bool:
+    """One ``linear_attn.*`` tensor into a layer's ``linear_attn`` tree.
+
+    The checkpoint fuses the input projections and interleaves them by
+    KEY head: ``in_proj_qkvz`` holds, sixteen times, the rows
+    [q Dk | k Dk | v r*Dv | z r*Dv] of one key head and the r = Hv / Hk
+    value heads it serves; ``in_proj_ba`` holds [b r | a r] likewise.
+    Ours are apart and flat, in the order the convolution sees them
+    ([q | k | v], z; [b | a]), so the forward needs no per-token
+    reshuffle."""
+    n_k, n_v, d_k, d_v = cfg.gdn_heads
+    r = n_v // n_k
+    if sub == "in_proj_qkvz.weight":
+        w = arr.reshape(n_k, 2 * d_k + 2 * r * d_v, -1)  # [Hk, rows, hidden]
+        q, k, v, z = np.split(w, [d_k, 2 * d_k, 2 * d_k + r * d_v], axis=1)
+        flat = [a.reshape(-1, a.shape[-1]) for a in (q, k, v, z)]
+        la["in_proj_qkv"]["w"][...] = np.concatenate(flat[:3], axis=0).T
+        la["in_proj_z"]["w"][...] = flat[3].T
+    elif sub == "in_proj_ba.weight":
+        w = arr.reshape(n_k, 2 * r, -1)
+        la["in_proj_ba"]["w"][...] = np.concatenate(
+            [w[:, :r].reshape(n_v, -1), w[:, r:].reshape(n_v, -1)],
+            axis=0).T
+    elif sub == "conv1d.weight":  # [C, 1, K] -> [K, C]
+        la["conv"]["w"][...] = arr[:, 0, :].T
+    elif sub in ("A_log", "dt_bias"):
+        la[sub][...] = arr
+    elif sub == "norm.weight":
+        la["norm"]["w"][...] = arr
+    elif sub == "out_proj.weight":
+        la["out_proj"]["w"][...] = arr.T
+    else:
+        return False
+    return True
 
 
 def load_qwen_lm(
@@ -163,6 +224,18 @@ def load_qwen_lm(
 
     loaded, unmapped = 0, []
     for name, arr in iter_safetensors(model_dir, keep):
+        if name.startswith("mtp."):
+            continue  # the multi-token-prediction module is not served
+        g = _GDN_RE.match(name)
+        if g:
+            li = int(g.group(1))
+            la = (params["layers"][li].get("linear_attn")
+                  if li < cfg.num_layers else None)
+            if la is not None and _load_gdn(la, cfg, g.group(2), arr):
+                loaded += 1
+            else:
+                unmapped.append(name)
+            continue
         m = _LAYER_RE.match(name)
         if m:
             li, sub, kind = int(m.group(1)), m.group(2), m.group(3)
@@ -217,6 +290,11 @@ def load_qwen_lm(
             em = _EXPERT_RE.match(sub)
             if em and cfg.moe:
                 e, which = int(em.group(1)), em.group(2)
+                if cfg.router_experts:
+                    # an expert share holds ids offset .. offset + E only
+                    e -= cfg.expert_offset
+                    if not 0 <= e < cfg.num_experts:
+                        continue
                 if which == "gate_proj":
                     layer["experts"]["gate_up"][e, :, :inter] = arr.T
                 elif which == "up_proj":

@@ -12,13 +12,14 @@ for the continuous-batching engine.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from vllm_omni_tpu.models.common import nn
 from vllm_omni_tpu.ops import (
+    apply_partial_rope,
     apply_rope,
     cache_shape,
     compute_mrope_freqs,
@@ -31,6 +32,7 @@ from vllm_omni_tpu.ops import (
     silu_mul,
     write_kv_cache,
 )
+from vllm_omni_tpu.ops import gated_delta_rule as gdr
 
 
 @dataclass(frozen=True)
@@ -78,6 +80,75 @@ class TransformerConfig:
     # collectives (reference: tensor_parallel_size in stage YAML,
     # model_executor/stage_configs/qwen3_omni_moe.yaml:27).
     tp_axis: Optional[str] = None
+    # ---- hybrid stacks (Qwen3-Next): layer i is softmax attention iff
+    # (i + 1) % full_attention_interval == 0, every other one a Gated
+    # DeltaNet linear-attention layer (ops/gated_delta_rule.py) that
+    # carries a per-sequence recurrent state instead of KV pages.
+    # 0 => every layer is softmax attention
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # gated attention: q_proj is twice as wide, a head's columns are
+    # [q | gate], and the attention output is scaled by sigmoid(gate)
+    attn_output_gate: bool = False
+    # rotary embedding on the first head_dim * factor dims of a head only
+    partial_rotary_factor: float = 1.0
+    # RMSNorm weights stored zero-centred and applied as (1 + w) in
+    # float32 (the GDN layer's gated output norm keeps a plain weight)
+    norm_zero_centered: bool = False
+    # the chip's share of an expert-parallel deployment (ops/moe.py
+    # routed_moe_share): the router keeps its published width
+    # ``router_experts`` (0 => num_experts) while ``num_experts`` counts
+    # the experts HELD here, ids expert_offset .. expert_offset +
+    # num_experts; picks that live elsewhere add nothing here
+    router_experts: int = 0
+    expert_offset: int = 0
+    # keep the residual stream in float32 between layers: the mixers' and
+    # MLPs' out projections leave their matmuls in float32 and are added
+    # unrounded; norm outputs are cast to the weights' dtype, so every
+    # matmul still takes bfloat16 operands.  For stacks that amplify what
+    # they are fed: a Gated DeltaNet layer's output is normalised to unit
+    # size a head, so it is most of what the layer adds to the stream, and
+    # with random weights a perturbation grows 1.4-2.2x through each such
+    # layer (29x through 8 layers, measured).  With a bfloat16 stream
+    # Qwen3-Next's served logprobs read 0.09-0.14 from the float32
+    # reference, against the benchmark's limit of 0.15; with this,
+    # 0.03-0.13 (PERF.md, PR 28)
+    residual_in_fp32: bool = False
+
+    @property
+    def layer_types(self) -> tuple[str, ...]:
+        n = self.full_attention_interval
+        return tuple(
+            "linear_attention" if n and (i + 1) % n else "full_attention"
+            for i in range(self.num_layers))
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that hold KV pages (the softmax-attention ones)."""
+        return self.layer_types.count("full_attention")
+
+    @property
+    def num_state_layers(self) -> int:
+        """Layers that hold a per-sequence recurrent state."""
+        return self.num_layers - self.num_kv_layers
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def linear_conv_dim(self) -> int:
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
+    def gdn_heads(self) -> tuple[int, int, int, int]:
+        return (self.linear_num_key_heads, self.linear_num_value_heads,
+                self.linear_key_head_dim, self.linear_value_head_dim)
 
     @staticmethod
     def tiny(vocab_size: int = 128) -> "TransformerConfig":
@@ -112,7 +183,6 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
     keys = jax.random.split(key, cfg.num_layers + 3)
     params = {
         "embed": nn.embedding_init(keys[0], cfg.vocab_size, cfg.hidden_size, dtype),
-        "final_norm": nn.rmsnorm_init(cfg.hidden_size, dtype),
         "layers": [],
     }
     if not cfg.tie_word_embeddings:
@@ -121,17 +191,36 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
         )
     q_dim = cfg.num_heads * cfg.head_dim
     kv_dim = cfg.num_kv_heads * cfg.head_dim
-    for i in range(cfg.num_layers):
+
+    def norm_init(key, dim):
+        if not cfg.norm_zero_centered:
+            return nn.rmsnorm_init(dim, dtype)
+        # stored zero-centred and applied as (1 + w); the published
+        # initialiser writes zeros, a little noise here keeps the (1 + w)
+        # visible to every comparison with the reference
+        return {"w": jax.random.uniform(key, (dim,), dtype,
+                                        minval=-0.1, maxval=0.1)}
+
+    params["final_norm"] = norm_init(keys[2], cfg.hidden_size)
+    for i, kind in enumerate(cfg.layer_types):
         k = jax.random.split(keys[i + 3], 8)
+        kn = jax.random.split(k[4], 4)
         qkv_bias = cfg.attention_bias
         layer = {
-            "input_norm": nn.rmsnorm_init(cfg.hidden_size, dtype),
-            "q_proj": nn.linear_init(k[0], cfg.hidden_size, q_dim, bias=qkv_bias, dtype=dtype),
-            "k_proj": nn.linear_init(k[1], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
-            "v_proj": nn.linear_init(k[2], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
-            "o_proj": nn.linear_init(k[3], q_dim, cfg.hidden_size, bias=False, dtype=dtype),
-            "post_norm": nn.rmsnorm_init(cfg.hidden_size, dtype),
+            "input_norm": norm_init(kn[0], cfg.hidden_size),
+            "post_norm": norm_init(kn[1], cfg.hidden_size),
         }
+        if kind == "linear_attention":
+            layer["linear_attn"] = _gdn_init(k, cfg, dtype)
+        else:
+            # gated attention doubles q_proj: a head's columns are [q | gate]
+            q_out = q_dim * (2 if cfg.attn_output_gate else 1)
+            layer.update({
+                "q_proj": nn.linear_init(k[0], cfg.hidden_size, q_out, bias=qkv_bias, dtype=dtype),
+                "k_proj": nn.linear_init(k[1], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
+                "v_proj": nn.linear_init(k[2], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
+                "o_proj": nn.linear_init(k[3], q_dim, cfg.hidden_size, bias=False, dtype=dtype),
+            })
         if cfg.moe:
             e = cfg.num_experts
             inter = cfg.moe_intermediate_size or cfg.intermediate_size
@@ -139,7 +228,8 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
             scale_out = 1.0 / (inter ** 0.5)
             k6, k7 = jax.random.split(k[6])
             layer["router"] = nn.linear_init(
-                k[5], cfg.hidden_size, e, bias=False, dtype=dtype
+                k[5], cfg.hidden_size, cfg.router_experts or e, bias=False,
+                dtype=dtype
             )
             # stacked expert weights: leading E axis is the EP shard axis
             layer["experts"] = {
@@ -171,11 +261,47 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
             layer["down"] = nn.linear_init(
                 k[5], cfg.intermediate_size, cfg.hidden_size, bias=False, dtype=dtype
             )
-        if cfg.qk_norm:
-            layer["q_norm"] = nn.rmsnorm_init(cfg.head_dim, dtype)
-            layer["k_norm"] = nn.rmsnorm_init(cfg.head_dim, dtype)
+        if cfg.qk_norm and kind == "full_attention":
+            layer["q_norm"] = norm_init(kn[2], cfg.head_dim)
+            layer["k_norm"] = norm_init(kn[3], cfg.head_dim)
         params["layers"].append(layer)
     return params
+
+
+def _gdn_init(k, cfg: TransformerConfig, dtype):
+    """One Gated DeltaNet mixer's weights.  The published checkpoint fuses
+    q, k, v and z into ``in_proj_qkvz`` and b, a into ``in_proj_ba``, both
+    interleaved by key head; here they are kept apart in the order the
+    convolution sees them ([q | k | v], then z; [b | a]) and the loader
+    de-interleaves (models/qwen3_next).  ``A_log`` and ``dt_bias`` are drawn
+    as the published initialiser draws them (A uniform in (0, 16), the time
+    step log-uniform in [0.001, 0.1] through the inverse softplus), so that
+    a token's decay exp(g) is neither 0 nor 1."""
+    n_v = cfg.linear_num_value_heads
+    v_dim = n_v * cfg.linear_value_head_dim
+    ka, kd = jax.random.split(k[7])
+    dt = jnp.exp(jax.random.uniform(kd, (n_v,), jnp.float32)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    kc = cfg.linear_conv_kernel_dim
+    return {
+        "in_proj_qkv": nn.linear_init(k[0], cfg.hidden_size,
+                                      cfg.linear_conv_dim, bias=False,
+                                      dtype=dtype),
+        "in_proj_z": nn.linear_init(k[1], cfg.hidden_size, v_dim,
+                                    bias=False, dtype=dtype),
+        "in_proj_ba": nn.linear_init(k[2], cfg.hidden_size, 2 * n_v,
+                                     bias=False, dtype=dtype),
+        # depthwise, no bias: w[K - 1] multiplies the current token
+        "conv": {"w": jax.random.uniform(
+            k[5], (kc, cfg.linear_conv_dim), dtype,
+            minval=-kc ** -0.5, maxval=kc ** -0.5)},
+        "A_log": jnp.log(jax.random.uniform(
+            ka, (n_v,), jnp.float32, minval=1e-3, maxval=16.0)).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "norm": nn.rmsnorm_init(cfg.linear_value_head_dim, dtype),
+        "out_proj": nn.linear_init(k[3], v_dim, cfg.hidden_size, bias=False,
+                                   dtype=dtype),
+    }
 
 
 def _qkv(layer, cfg: TransformerConfig, x):
@@ -183,13 +309,29 @@ def _qkv(layer, cfg: TransformerConfig, x):
     layout.  Head counts derive from the weights, not the config: under
     tensor parallelism each shard carries num_heads/tp heads."""
     t = x.shape[0]
-    q = nn.linear(layer["q_proj"], x).reshape(t, -1, cfg.head_dim)
+    gate = None
+    if cfg.attn_output_gate:
+        # a head's columns are [q | gate]; the gate scales the attention
+        # output after the kernel (sigmoid, _layer_step)
+        qg = nn.linear(layer["q_proj"], x).reshape(t, -1, 2 * cfg.head_dim)
+        q, gate = qg[..., :cfg.head_dim], qg[..., cfg.head_dim:]
+    else:
+        q = nn.linear(layer["q_proj"], x).reshape(t, -1, cfg.head_dim)
     k = nn.linear(layer["k_proj"], x).reshape(t, -1, cfg.head_dim)
     v = nn.linear(layer["v_proj"], x).reshape(t, -1, cfg.head_dim)
     if cfg.qk_norm:
-        q = rms_norm(q, layer["q_norm"]["w"], cfg.rms_eps)
-        k = rms_norm(k, layer["k_norm"]["w"], cfg.rms_eps)
-    return q, k, v
+        q = _rms(cfg, q, layer["q_norm"])
+        k = _rms(cfg, k, layer["k_norm"])
+    return q, k, v, gate
+
+
+def _rms(cfg: TransformerConfig, x, norm):
+    """RMSNorm with the model's weight convention: plain ``w``, or
+    ``(1 + w)`` in float32 where the weights are stored zero-centred."""
+    w = norm["w"]
+    if cfg.norm_zero_centered:
+        w = 1.0 + w.astype(jnp.float32)
+    return rms_norm(x, w, cfg.rms_eps)
 
 
 def _moe_mlp_dense(layer, cfg: TransformerConfig, x):
@@ -208,6 +350,10 @@ def _moe_mlp_dense(layer, cfg: TransformerConfig, x):
     combine = jnp.zeros_like(probs).at[
         jnp.arange(t)[:, None], topk_idx
     ].set(topk_w)
+    held = layer["experts"]["gate_up"].shape[0]
+    if held != probs.shape[-1]:  # an expert share: the held columns only
+        combine = jax.lax.dynamic_slice_in_dim(
+            combine, cfg.expert_offset, held, axis=1)
     h = jnp.einsum("th,ehf->etf", x, layer["experts"]["gate_up"])
     h = silu_mul(h)
     y = jnp.einsum("etf,efh->eth", h, layer["experts"]["down"])
@@ -235,6 +381,16 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
                 x, layer["router"]["w"], layer["experts"]["gate_up"],
                 layer["experts"]["down"], cfg.num_experts_per_tok, mesh,
                 renormalize=cfg.moe_renormalize,
+            )
+        elif layer["router"]["w"].shape[-1] != \
+                layer["experts"]["gate_up"].shape[0]:
+            # the chip's share of an expert-parallel deployment: route
+            # over the router's whole width, compute what the experts
+            # held here give, no exchange
+            out = moe_ops.routed_moe_share(
+                x, layer["router"]["w"], layer["experts"]["gate_up"],
+                layer["experts"]["down"], cfg.num_experts_per_tok,
+                cfg.expert_offset, renormalize=cfg.moe_renormalize,
             )
         else:
             out = moe_ops.routed_moe(
@@ -269,7 +425,7 @@ def _rope_tables(cfg: TransformerConfig, positions):
     """
     if cfg.mrope_sections is None:
         return compute_rope_freqs(
-            positions.reshape(-1), cfg.head_dim, cfg.rope_theta
+            positions.reshape(-1), cfg.rotary_dim, cfg.rope_theta
         )
     if positions.ndim == 3:  # [B, 3, S] -> [3, B*S]
         p = positions.transpose(1, 0, 2).reshape(3, -1)
@@ -315,34 +471,162 @@ def _embed_input(params, token_ids, inputs_embeds, embeds_mask):
 
 @jax.named_scope("omni.proj")
 def _final_norm(params, cfg: TransformerConfig, x):
-    return rms_norm(x, params["final_norm"]["w"], cfg.rms_eps)
+    return _rms(cfg, x, params["final_norm"]).astype(
+        params["final_norm"]["w"].dtype)
+
+
+def _rope(cfg: TransformerConfig, x, cos, sin):
+    if cfg.partial_rotary_factor == 1.0:
+        return apply_rope(x, cos, sin)
+    return apply_partial_rope(x, cos, sin)
+
+
+def _gdn_mixer(la, cfg: TransformerConfig, h, recur):
+    """The Gated DeltaNet sequence mixer on normed rows h [T, hidden]:
+    projections -> ``recur`` -> gated per-head norm -> out projection.
+    ``recur(mixed, g, beta, conv_w)`` supplies what differs between the
+    forwards (where the carried state comes from and goes to, and the
+    chunked or the one-token form of the rule; ops/gated_delta_rule.py)
+    and returns o [T, Hv, Dv] float32.  One body serves every forward, as
+    ``_layer_step`` does for attention."""
+    t = h.shape[0]
+    n_v, d_v = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    with jax.named_scope("omni.proj"):
+        # the mixer's inputs leave their matmuls in float32 and stay so up
+        # to the out projection: everything between is float32 arithmetic
+        # anyway, and a rounding in here reaches the residual stream at
+        # full weight (see ``residual_in_fp32``)
+        def f32_proj(name):
+            return jnp.dot(h, la[name]["w"],
+                           preferred_element_type=jnp.float32)
+
+        mixed = f32_proj("in_proj_qkv")
+        z = f32_proj("in_proj_z").reshape(t, n_v, d_v)
+        ba = f32_proj("in_proj_ba")
+    with jax.named_scope("omni.gdn"):
+        beta = jax.nn.sigmoid(ba[:, :n_v])
+        g = -jnp.exp(la["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            ba[:, n_v:] + la["dt_bias"].astype(jnp.float32))
+        o = recur(mixed, g, beta, la["conv"]["w"])
+        # per head: w * o * rsqrt(mean(o^2) + eps), then the silu(z) gate
+        o = o * jax.lax.rsqrt(
+            jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps)
+        o = (o * la["norm"]["w"].astype(jnp.float32)
+             * jax.nn.silu(z)).astype(h.dtype)
+    with jax.named_scope("omni.proj"):
+        return _out_proj(cfg, o.reshape(t, -1), la["out_proj"]["w"])
+
+
+def _out_proj(cfg: TransformerConfig, o, w):
+    """A mixer's output projection, in the residual stream's precision."""
+    if cfg.residual_in_fp32:
+        return jnp.dot(o, w, preferred_element_type=jnp.float32)
+    return o @ w
 
 
 def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
-    """One transformer block: norm → qkv+rope → ``attend`` → residual →
-    norm → MLP → residual.  ``attend(q, k, v)`` supplies the attention
-    variant (dense causal / cached-context chunked / paged decode) and
-    returns o with leading dims matching ``x``'s.  One body serves every
-    forward so the variants cannot silently diverge."""
+    """One transformer block: norm → mixer → residual → norm → MLP →
+    residual.  In a softmax-attention layer the mixer is qkv+rope →
+    ``attend(q, k, v)``, which supplies the attention variant (dense
+    causal / cached-context chunked / paged decode) and returns o with
+    leading dims matching ``x``'s; in a Gated DeltaNet layer
+    (``"linear_attn"`` in the layer's weights) ``attend`` is the ``recur``
+    of ``_gdn_mixer``.  One body serves every forward so the variants
+    cannot silently diverge."""
     b = x.shape[:-1]
+    act = layer["input_norm"]["w"].dtype
+    if cfg.residual_in_fp32:
+        x = x.astype(jnp.float32)
     with jax.named_scope("omni.proj"):
-        h = rms_norm(x, layer["input_norm"]["w"], cfg.rms_eps)
-        q, k, v = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-    o = attend(q, k, v)
+        h = _rms(cfg, x, layer["input_norm"]).astype(act)
+    if "linear_attn" in layer:
+        o = _gdn_mixer(layer["linear_attn"], cfg,
+                       h.reshape(-1, h.shape[-1]), attend).reshape(*b, -1)
+    else:
+        with jax.named_scope("omni.proj"):
+            q, k, v, gate = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
+            q = _rope(cfg, q, cos, sin)
+            k = _rope(cfg, k, cos, sin)
+        o = attend(q, k, v).reshape(*b, -1)
+        if gate is not None:
+            # the output gate is part of gated attention: billed to it
+            with jax.named_scope("omni.attn"):
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.reshape(*b, -1).astype(jnp.float32))).astype(o.dtype)
+        with jax.named_scope("omni.proj"):
+            o = _out_proj(cfg, o, layer["o_proj"]["w"])
     with jax.named_scope("omni.proj"):
-        o = o.reshape(*b, -1) @ layer["o_proj"]["w"]
         if cfg.tp_axis is not None:
             # row-parallel o_proj: each shard holds a partial sum
             o = jax.lax.psum(o, cfg.tp_axis)
         x = x + o
-        h = rms_norm(x, layer["post_norm"]["w"], cfg.rms_eps)
+        h = _rms(cfg, x, layer["post_norm"]).astype(act)
     with jax.named_scope("omni.moe" if cfg.moe else "omni.mlp"):
         y = _mlp(layer, cfg, h)
         if cfg.tp_axis is not None:
             y = jax.lax.psum(y, cfg.tp_axis)
         return x + y
+
+
+class HybridCaches(NamedTuple):
+    """The cache operand of a model with recurrent layers, where other
+    models pass their list of pages: two kinds of state in one pytree, so
+    that a step donates and rebinds both together (docs/kv_cache.md)."""
+
+    kv: list     # per softmax-attention layer: (k, v) pages
+    state: list  # per Gated DeltaNet layer: (conv, recurrent) state slots
+
+
+def _layer_caches(params, kv_caches, state_caches):
+    """(layer, its cache) down the stack: the (k, v) pages of a
+    softmax-attention layer, the (conv, recurrent) state slots of a Gated
+    DeltaNet layer.  ``kv_caches`` holds one entry for each attention
+    layer only, ``state_caches`` one for each GDN layer."""
+    kv, st = iter(kv_caches), iter(state_caches or ())
+    for layer in params["layers"]:
+        yield layer, next(st if "linear_attn" in layer else kv)
+
+
+def _read_state(cache, slots, fresh):
+    """The rows' carried state out of their slots ([rows, ...]); zeros for
+    a row that starts its sequence (``fresh``), which is how a slot is
+    zeroed when it is handed out.  Padding rows carry a slot past the end:
+    they read a clipped row here and their write is dropped."""
+    with jax.named_scope("omni.gdn_state"):
+        conv = cache[0].at[slots].get(mode="clip")
+        rec = cache[1].at[slots].get(mode="clip")
+        return (jnp.where(fresh[:, None, None], 0, conv),
+                jnp.where(fresh[:, None, None, None], 0, rec))
+
+
+def _write_state(cache, slots, conv, rec):
+    with jax.named_scope("omni.gdn_state"):
+        return (cache[0].at[slots].set(conv.astype(cache[0].dtype),
+                                       mode="drop"),
+                cache[1].at[slots].set(rec.astype(cache[1].dtype),
+                                       mode="drop"))
+
+
+def _stateful_recur(cache, slots, fresh, new_states, rule):
+    """The ``recur`` of ``_gdn_mixer`` for a forward that carries state:
+    the rows' slots are read (``omni.gdn_state``), ``rule(mixed, g, beta,
+    conv_w, conv, rec)`` runs the chunked or the one-token form, and the
+    slots are written back into ``new_states``."""
+    def recur(mixed, g, beta, conv_w):
+        conv, rec = _read_state(cache, slots, fresh)
+        o, conv, rec = rule(mixed, g, beta, conv_w, conv, rec)
+        new_states.append(_write_state(cache, slots, conv, rec))
+        return o
+
+    return recur
+
+
+def _no_state_forward(cfg: TransformerConfig, name: str):
+    if cfg.num_state_layers:
+        raise NotImplementedError(
+            f"{name} has no recurrent-state cache: a model with Gated "
+            "DeltaNet layers runs forward_hidden (no cache), "
+            "forward_unified and forward_decode")
 
 
 def forward_hidden(
@@ -392,12 +676,26 @@ def forward_hidden(
                 kv_mask=attn_mask,
             )
 
+    def recur(mixed, g, beta, conv_w):
+        # no cache: every sequence starts from a zero state and the final
+        # state is dropped; the [B, S] batch is B packed segments of S rows
+        conv = jnp.zeros((b, conv_w.shape[0] - 1, mixed.shape[-1]),
+                         mixed.dtype)
+        rec = jnp.zeros((b, cfg.linear_num_value_heads,
+                         cfg.linear_key_head_dim,
+                         cfg.linear_value_head_dim), jnp.float32)
+        return gdr.gated_delta_net_ragged(
+            mixed, g, beta, conv_w, conv, rec,
+            jnp.arange(b + 1, dtype=jnp.int32) * s,
+            jnp.full((b,), s, jnp.int32), cfg.gdn_heads)[0]
+
     layers = params["layers"]
     if drop_last_layers:
         layers = layers[:len(layers) - drop_last_layers]
     collected = {0: x} if 0 in collect_hidden_layers else {}
     for li, layer in enumerate(layers):
-        x = _layer_step(layer, cfg, x, cos, sin, attend)
+        x = _layer_step(layer, cfg, x, cos, sin,
+                        recur if "linear_attn" in layer else attend)
         if li + 1 in collect_hidden_layers:
             collected[li + 1] = x
     if collect_hidden_layers:
@@ -447,6 +745,7 @@ def forward_prefill(
 
     Returns (hidden [B, S, hidden], new kv_caches).
     """
+    _no_state_forward(cfg, "forward_prefill")
     b, s = token_ids.shape
     x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
     cos, sin = _rope_tables(cfg, positions)
@@ -503,6 +802,7 @@ def forward_prefill_chunked(
 
     Returns (hidden [B, S, hidden], new kv_caches).
     """
+    _no_state_forward(cfg, "forward_prefill_chunked")
     b, s = token_ids.shape
     hkv, _, page_size, d = cache_shape(kv_caches[0][0])
     x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
@@ -558,6 +858,7 @@ def forward_unified(
     inputs_embeds: Optional[jax.Array] = None,  # [T, embed_width]
     embeds_mask: Optional[jax.Array] = None,    # [T] True=row uses embeds
     deepstack: Optional[jax.Array] = None,      # [n_deep, T, hidden]
+    state_slots: Optional[jax.Array] = None,    # [S] slot of each sequence
 ):
     """Unified ragged mixed-batch forward: prefill chunks and 1-token
     decode rows share ONE token-packed execution (ops/
@@ -574,18 +875,28 @@ def forward_unified(
     ``forward_prefill``, so embeds/deepstack batches ride the unified
     dispatch instead of a separately padded executable.
 
-    Returns (hidden [T, hidden], new kv_caches).
+    A model with Gated DeltaNet layers passes ``HybridCaches`` as
+    ``kv_caches`` (beside the pages, for each such layer the convolution's
+    and the rule's state, one slot a sequence) and ``state_slots``: a
+    sequence's rows read its slot at the chunk's start (zeros where the
+    chunk starts the sequence) and write it at the chunk's end, through
+    the chunked form of the rule.
+
+    Returns (hidden [T, hidden], new kv_caches of the type passed).
     """
+    hybrid = isinstance(kv_caches, HybridCaches)
+    kv_caches, state_caches = kv_caches if hybrid else (kv_caches, None)
     x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
     if cfg.mrope_sections is None:
         cos, sin = _rope_tables(cfg, positions)
     else:
         # [3, T] -> the [B, 3, S] convention with B=1
         cos, sin = _rope_tables(cfg, positions[None])
-    new_caches = []
-    for i, (layer, (k_cache, v_cache)) in enumerate(
-            zip(params["layers"], kv_caches)):
-        def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
+    new_caches, new_states = [], []
+    for i, (layer, cache) in enumerate(
+            _layer_caches(params, kv_caches, state_caches)):
+        def attend(q, k, v, cache=cache):
+            k_cache, v_cache = cache
             with jax.named_scope("omni.kv_write"):
                 k_cache, v_cache = write_kv_cache(
                     k_cache, v_cache, k, v, slot_mapping
@@ -597,10 +908,19 @@ def forward_unified(
                     seq_lens, num_seqs,
                 )
 
-        x = _layer_step(layer, cfg, x, cos, sin, attend)
+        # a sequence starts where its context is this step's tokens alone
+        recur = _stateful_recur(
+            cache, state_slots, seq_lens == q_lens, new_states,
+            lambda *a: gdr.gated_delta_net_ragged(
+                *a, cu_q_lens, q_lens, cfg.gdn_heads))
+
+        x = _layer_step(layer, cfg, x, cos, sin,
+                        recur if "linear_attn" in layer else attend)
         if deepstack is not None and i < deepstack.shape[0]:
             with jax.named_scope("omni.embed"):
                 x = x + deepstack[i].astype(x.dtype)
+    if hybrid:
+        new_caches = HybridCaches(new_caches, new_states)
     return _final_norm(params, cfg, x), new_caches
 
 
@@ -613,16 +933,23 @@ def forward_decode(
     slot_mapping: jax.Array,  # [B]
     block_tables: jax.Array,  # [B, max_pages]
     context_lens: jax.Array,  # [B] (including the new token)
+    state_slots: Optional[jax.Array] = None,  # [B] slot of each row
 ):
-    """One decode step over a batch of sequences with paged attention.
+    """One decode step over a batch of sequences with paged attention;
+    the Gated DeltaNet layers of a hybrid model update each row's state
+    slot through the one-token form of the rule (``HybridCaches`` and
+    ``state_slots`` as in ``forward_unified``).
 
-    Returns (hidden [B, hidden], new kv_caches).
+    Returns (hidden [B, hidden], new kv_caches of the type passed).
     """
+    hybrid = isinstance(kv_caches, HybridCaches)
+    kv_caches, state_caches = kv_caches if hybrid else (kv_caches, None)
     x = _embed_input(params, token_ids, None, None)  # [B, hidden]
     cos, sin = _rope_tables(cfg, positions)
-    new_caches = []
-    for layer, (k_cache, v_cache) in zip(params["layers"], kv_caches):
-        def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
+    new_caches, new_states = [], []
+    for layer, cache in _layer_caches(params, kv_caches, state_caches):
+        def attend(q, k, v, cache=cache):
+            k_cache, v_cache = cache
             with jax.named_scope("omni.kv_write"):
                 k_cache, v_cache = write_kv_cache(
                     k_cache, v_cache, k, v, slot_mapping
@@ -633,5 +960,12 @@ def forward_decode(
                     q, k_cache, v_cache, block_tables, context_lens
                 )
 
-        x = _layer_step(layer, cfg, x, cos, sin, attend)
+        recur = _stateful_recur(
+            cache, state_slots, context_lens <= 1, new_states,
+            lambda *a: gdr.gated_delta_net_decode(*a, cfg.gdn_heads))
+
+        x = _layer_step(layer, cfg, x, cos, sin,
+                        recur if "linear_attn" in layer else attend)
+    if hybrid:
+        new_caches = HybridCaches(new_caches, new_states)
     return _final_norm(params, cfg, x), new_caches

@@ -163,6 +163,11 @@ _AR_MODELS: dict[str, _Entry] = {
     "Qwen3MoeForCausalLM": _Entry(
         "vllm_omni_tpu.model_loader.hf_qwen", "load_qwen_lm"
     ),
+    # hybrid Gated-DeltaNet / gated-attention stack with a state cache
+    # beside the paged KV cache (docs/kv_cache.md)
+    "Qwen3NextForCausalLM": _Entry(
+        "vllm_omni_tpu.models.qwen3_next", "real_factory"
+    ),
 }
 
 

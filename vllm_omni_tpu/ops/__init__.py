@@ -10,6 +10,7 @@ fallback on CPU.
 
 from vllm_omni_tpu.ops.rmsnorm import rms_norm, rms_norm_ref
 from vllm_omni_tpu.ops.rope import (
+    apply_partial_rope,
     apply_rope,
     apply_rope_ref,
     compute_rope_freqs,
@@ -34,6 +35,7 @@ from vllm_omni_tpu.ops.activation import silu_mul, gelu_tanh_mul
 __all__ = [
     "rms_norm",
     "rms_norm_ref",
+    "apply_partial_rope",
     "apply_rope",
     "apply_rope_ref",
     "compute_rope_freqs",

@@ -1,0 +1,255 @@
+"""Gated DeltaNet's sequence mixer: a causal depthwise convolution with a
+carried state, and the gated delta rule in two forms that must agree.
+
+The rule, a head at a time, over a state ``S`` of ``[Dk, Dv]`` (key x
+value) kept in float32::
+
+    S   = exp(g_t) * S                 # g_t <= 0: the gate's log decay
+    u_t = beta_t * (v_t - S^T k_t)     # what the state does not hold yet
+    S   = S + k_t u_t^T
+    o_t = S^T q_t
+
+(reference: Qwen3-Next's ``Qwen3NextGatedDeltaNet`` and the flash-linear-
+attention kernels it calls.)  ``gated_delta_rule_recurrent`` is that
+recurrence for one token a row: the decode step.  ``gated_delta_rule_ragged``
+is the chunked form for the prefill rows of a token-packed ragged batch
+(the layout of ops/ragged_paged_attention.py: sequence ``i`` owns the rows
+``cu_q_lens[i] .. cu_q_lens[i] + q_lens[i]``): inside a chunk of 64 tokens
+the recurrence becomes the unit-lower-triangular solve
+``(I + tril(beta K K^T . decay, -1)) X = [beta V | beta K exp(cumsum g)]``,
+between chunks only the ``[Dk, Dv]`` state passes.  A chunk holds tokens of
+one sequence only, so a sequence never reads its neighbour's tokens; every
+exponent is a difference ``cumsum(g)_i - cumsum(g)_j`` with ``i >= j``, so
+nothing overflows and what underflows is a product that is zero in the
+recurrence too.
+
+There is no Pallas kernel yet: the ``jax.numpy`` forms below are the op on
+every platform (ISSUE 28 leaves the kernel to the PR whose trace shows
+these bound by launches or relayouts), so nothing here counts as a native
+fallback in ops/_dispatch.py.
+
+Layouts: the convolution's state is ``[rows, K - 1, C]`` (the last ``K - 1``
+inputs of the sequence, oldest first; channels last so that the 8192
+channels lie along the lanes), its weight ``[K, C]`` with ``w[K - 1]`` on
+the current token; the recurrent state is ``[rows, Hv, Dk, Dv]`` float32.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+#: tokens a chunk of the chunked form holds
+CHUNK = 64
+#: the rule's matrix products run at full float32 precision: they are a few
+#: per cent of a layer's arithmetic, and the state they feed is carried over
+#: thousands of tokens
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def l2_normalize(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def token_segments(cu_q_lens: jax.Array, q_lens: jax.Array, t: int):
+    """For every packed row: (its sequence, its offset in the sequence's
+    segment, whether it is a real token of it)."""
+    s = q_lens.shape[0]
+    rows = jnp.arange(t, dtype=jnp.int32)
+    seg = jnp.clip(jnp.searchsorted(cu_q_lens[1:], rows, side="right"),
+                   0, s - 1).astype(jnp.int32)
+    local = rows - cu_q_lens[seg]
+    return seg, local, (local >= 0) & (local < q_lens[seg])
+
+
+# ------------------------------------------------------------ convolution
+def causal_conv_ragged(x, w, conv_state, cu_q_lens, q_lens):
+    """Depthwise causal convolution over the segments of a packed batch.
+
+    x [T, C]; w [K, C]; conv_state [S, K - 1, C]: what each sequence saw
+    before this step (zeros at its start).  Returns (y [T, C] float32,
+    before the activation; the sequences' new state).  Rows outside every
+    segment give finite values that nobody reads."""
+    t, c = x.shape
+    k = w.shape[0]
+    wf = w.astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    _, local, _ = token_segments(cu_q_lens, q_lens, t)
+    y = xf * wf[k - 1]
+    for d in range(1, k):
+        back = jnp.pad(xf, ((d, 0), (0, 0)))[:t]           # x[row - d]
+        y = y + jnp.where((local >= d)[:, None], back, 0.0) * wf[k - 1 - d]
+    # the first K - 1 tokens of a segment also see the carried inputs
+    sf = conv_state.astype(jnp.float32)
+    hist = jnp.stack([
+        sum(wf[k - 1 - d] * sf[:, k - 1 + l - d] for d in range(l + 1, k))
+        for l in range(k - 1)], axis=1)                     # [S, K - 1, C]
+    offs = jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+    rows = jnp.where(offs < q_lens[:, None], cu_q_lens[:-1, None] + offs, t)
+    y = y.at[rows.reshape(-1)].add(hist.reshape(-1, c), mode="drop")
+    # new state: the last K - 1 inputs of (carried ++ this step's)
+    pos = q_lens[:, None] - (k - 1) + offs                  # [S, K - 1]
+    from_x = x[jnp.clip(cu_q_lens[:-1, None] + pos, 0, t - 1)]
+    from_state = jnp.take_along_axis(
+        conv_state, jnp.clip(q_lens[:, None] + offs, 0, k - 2)[..., None],
+        axis=1)
+    new_state = jnp.where((pos >= 0)[..., None], from_x.astype(
+        conv_state.dtype), from_state)
+    return y, new_state
+
+
+def causal_conv_update(x, w, conv_state):
+    """The same convolution for one token a row: x [B, C], conv_state
+    [B, K - 1, C] -> (y [B, C] float32, new state)."""
+    window = jnp.concatenate(
+        [conv_state, x[:, None].astype(conv_state.dtype)], axis=1)
+    y = jnp.sum(window.astype(jnp.float32)
+                * w.astype(jnp.float32)[None], axis=1)
+    return y, window[:, 1:]
+
+
+# ------------------------------------------------------------------- rule
+def gated_delta_rule_recurrent(q, k, v, g, beta, state):
+    """One token a row.  q, k [B, Hv, Dk] (normalised, q scaled), v
+    [B, Hv, Dv], g, beta [B, Hv], state [B, Hv, Dk, Dv] float32 ->
+    (o [B, Hv, Dv] float32, new state)."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    state = state * jnp.exp(g.astype(f32))[..., None, None]
+    held = jnp.sum(state * k[..., None], axis=-2)           # S^T k
+    u = beta.astype(f32)[..., None] * (v - held)
+    state = state + k[..., None] * u[..., None, :]
+    return jnp.sum(state * q[..., None], axis=-2), state
+
+
+def gated_delta_rule_scan(q, k, v, g, beta, state):
+    """The recurrence token by token over one sequence (q, k [T, Hv, Dk],
+    v [T, Hv, Dv], g, beta [T, Hv], state [Hv, Dk, Dv]): the plain form
+    the chunked one is tested against."""
+    def step(s, x):
+        o, s = gated_delta_rule_recurrent(*(a[None] for a in x), s[None])
+        return s[0], o[0]
+
+    state, o = jax.lax.scan(step, state.astype(jnp.float32),
+                            (q, k, v, g, beta))
+    return o, state
+
+
+def _mm(spec, a, b):
+    return jnp.einsum(spec, a, b, precision=PRECISION)
+
+
+def gated_delta_rule_ragged(q, k, v, g, beta, state, cu_q_lens, q_lens,
+                            chunk: int = CHUNK):
+    """The chunked form over a packed ragged batch.
+
+    q, k [T, Hv, Dk] (normalised, q scaled; key heads already repeated to
+    the value heads), v [T, Hv, Dv], g, beta [T, Hv]; state [S, Hv, Dk, Dv]
+    float32, each sequence's state before this step; ``cu_q_lens`` [S + 1]
+    and ``q_lens`` [S] as the ragged attention kernel takes them.  Returns
+    (o [T, Hv, Dv] float32, the sequences' state after their last token).
+
+    Every sequence's tokens are regrouped into chunks of their own (at most
+    ``T / chunk + S`` of them, a static count); one scan then walks the
+    chunks in order, reading and writing the state of the chunk's
+    sequence."""
+    f32 = jnp.float32
+    t, hv, dk = q.shape
+    dv = v.shape[-1]
+    s = q_lens.shape[0]
+    n = -(-t // chunk) + s
+    per_seq = -(-q_lens // chunk)
+    ends = jnp.cumsum(per_seq)
+    first = ends - per_seq
+    cid = jnp.arange(n, dtype=jnp.int32)
+    seq_of = jnp.clip(jnp.searchsorted(ends, cid, side="right"),
+                      0, s - 1).astype(jnp.int32)
+    local = ((cid - first[seq_of]) * chunk)[:, None] \
+        + jnp.arange(chunk, dtype=jnp.int32)[None, :]       # [N, C]
+    valid = (cid < ends[-1])[:, None] & (local < q_lens[seq_of][:, None])
+    rows = jnp.where(valid, cu_q_lens[seq_of][:, None] + local, 0)
+
+    def chunked(a):  # [T, Hv, ...] -> [N, Hv, C, ...], zero where no token
+        a = jnp.where(valid.reshape(n, chunk, *(1,) * (a.ndim - 1)),
+                      a[rows].astype(f32), 0.0)
+        return jnp.moveaxis(a, 2, 1)
+
+    qc, kc, vc = chunked(q), chunked(k), chunked(v)
+    gc = jnp.cumsum(chunked(g), axis=-1)                    # [N, Hv, C]
+    bc = chunked(beta)
+    idx = jnp.arange(chunk)
+    lower = idx[:, None] > idx[None, :]
+    decay = jnp.exp(jnp.where(idx[:, None] >= idx[None, :],
+                              gc[..., :, None] - gc[..., None, :], -jnp.inf))
+    kb = kc * bc[..., None]
+    a_mat = jnp.where(lower, _mm("nhid,nhjd->nhij", kb, kc) * decay, 0.0)
+    rhs = jnp.concatenate(
+        [vc * bc[..., None], kb * jnp.exp(gc)[..., None]], axis=-1)
+    solved = jax.lax.linalg.triangular_solve(
+        a_mat + jnp.eye(chunk, dtype=f32), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    u_c, w_c = solved[..., :dv], solved[..., dv:]
+    attn = _mm("nhid,nhjd->nhij", qc, kc) * decay           # diagonal kept
+    q_dec = qc * jnp.exp(gc)[..., None]
+    g_last = gc[..., -1]
+    k_dec = kc * jnp.exp(g_last[..., None] - gc)[..., None]
+
+    def step(states, x):
+        sq, q_i, w_i, u_i, a_i, k_i, gl = x
+        st = states[sq]
+        v_new = u_i - _mm("hcd,hdv->hcv", w_i, st)
+        o = _mm("hcd,hdv->hcv", q_i, st) + _mm("hij,hjv->hiv", a_i, v_new)
+        st = st * jnp.exp(gl)[:, None, None] \
+            + _mm("hcd,hcv->hdv", k_i, v_new)
+        return states.at[sq].set(st), o
+
+    state, o_c = jax.lax.scan(
+        step, state.astype(f32),
+        (seq_of, q_dec, w_c, u_c, attn, k_dec, g_last))
+    # back onto the packed rows
+    seg, off, _ = token_segments(cu_q_lens, q_lens, t)
+    at = jnp.clip(first[seg] + off // chunk, 0, n - 1)
+    return jnp.moveaxis(o_c, 2, 1)[at, off % chunk], state
+
+
+# ----------------------------------------------------------- the two steps
+def _split_heads(mixed, n_k: int, n_v: int, d_k: int, d_v: int):
+    """silu(conv output) [T, 2 Hk Dk + Hv Dv], kept in float32 from the
+    convolution on (everything downstream is float32 anyway, so rounding
+    it to the serve dtype here would only lose bits) -> q, k [T, Hv, Dk] (L2-
+    normalised over the head, q scaled by Dk^-0.5, each key head serving
+    Hv / Hk value heads), v [T, Hv, Dv]."""
+    t = mixed.shape[0]
+    key_dim = n_k * d_k
+    q = mixed[:, :key_dim].reshape(t, n_k, d_k)
+    k = mixed[:, key_dim:2 * key_dim].reshape(t, n_k, d_k)
+    v = mixed[:, 2 * key_dim:].reshape(t, n_v, d_v)
+    rep = n_v // n_k
+    q = jnp.repeat(l2_normalize(q) * d_k ** -0.5, rep, axis=1)
+    k = jnp.repeat(l2_normalize(k), rep, axis=1)
+    return q, k, v
+
+
+def gated_delta_net_ragged(mixed, g, beta, conv_w, conv_state, rec_state,
+                           cu_q_lens, q_lens, heads):
+    """Convolution, activation and the chunked rule for a packed batch.
+    mixed [T, C]; g, beta [T, Hv] float32; conv_state [S, K - 1, C] and
+    rec_state [S, Hv, Dk, Dv]: the sequences' state before the step;
+    ``heads`` = (Hk, Hv, Dk, Dv).  Returns (o [T, Hv, Dv] float32,
+    new conv state, new recurrent state)."""
+    y, conv_state = causal_conv_ragged(mixed, conv_w, conv_state,
+                                       cu_q_lens, q_lens)
+    q, k, v = _split_heads(jax.nn.silu(y), *heads)
+    o, rec_state = gated_delta_rule_ragged(
+        q, k, v, g, beta, rec_state, cu_q_lens, q_lens)
+    return o, conv_state, rec_state
+
+
+def gated_delta_net_decode(mixed, g, beta, conv_w, conv_state, rec_state,
+                           heads):
+    """The same for one token a row (mixed [B, C], states [B, ...])."""
+    y, conv_state = causal_conv_update(mixed, conv_w, conv_state)
+    q, k, v = _split_heads(jax.nn.silu(y), *heads)
+    o, rec_state = gated_delta_rule_recurrent(q, k, v, g, beta, rec_state)
+    return o, conv_state, rec_state

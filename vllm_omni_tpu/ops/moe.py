@@ -55,13 +55,19 @@ def ep_mesh():
 
 
 def router_topk(x, router_w, num_experts_per_tok: int,
-                renormalize: bool = True):
+                renormalize: bool = True, float32_logits: bool = False):
     """Softmax router -> top-k (idx [T,k], weights [T,k]).
 
     ``renormalize`` divides the kept weights by their sum (Qwen3-MoE
     norm_topk_prob=True); the Qwen3-Omni talker keeps the raw softmax
-    mass (norm_topk_prob=False)."""
-    logits = x @ router_w  # [T, E]
+    mass (norm_topk_prob=False).  ``float32_logits`` leaves the router's
+    matmul in float32 instead of rounding its output to the activations'
+    dtype first: the k-th and (k+1)-th of 512 logits lie a few bfloat16
+    steps apart, and a rounding that swaps them swaps an expert."""
+    if float32_logits:
+        logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+    else:
+        logits = x @ router_w  # [T, E]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     topk_w, topk_idx = jax.lax.top_k(probs, num_experts_per_tok)
     if renormalize:
@@ -103,31 +109,47 @@ def routed_moe(
     return out.astype(x.dtype)
 
 
-def _routed_moe_ep_shard(x, router_w, gate_up, down, k: int,
-                         renormalize: bool = True):
-    """Per-ep-shard body: full token set, local expert slab.  Pairs routed
-    to remote experts keep their slot (static shapes) but are masked to
-    weight zero and land in a local expert group; the psum over ep sums
-    exactly one live contribution per pair."""
-    e_local = gate_up.shape[0]
-    shard = jax.lax.axis_index("ep")
-    lo = shard * e_local
-
-    topk_idx, topk_w = router_topk(x, router_w, k, renormalize)
-    flat_e = topk_idx.reshape(-1)
-    flat_w = topk_w.reshape(-1)
-    mine = (flat_e >= lo) & (flat_e < lo + e_local)
-    local_e = jnp.where(mine, flat_e - lo, 0)
-    flat_w = jnp.where(mine, flat_w, 0.0)
+def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
+                     renormalize: bool = True,
+                     float32_logits: bool = True) -> jax.Array:
+    """What the experts held here give: ``gate_up`` / ``down`` stack the
+    experts ``expert_offset .. expert_offset + E_held`` of a router that
+    is ``router_w.shape[-1]`` wide.  Every token is routed over the whole
+    router and its kept weights are renormalised over all k picks,
+    wherever they live; a pick that lives elsewhere adds nothing here and
+    nothing stands in for it.  The pair count stays the static T*k: the
+    pairs of absent experts sort behind the last held expert's group,
+    where the grouped matmul computes no row, and are masked out of the
+    sum.  This is one chip's share of an expert-parallel deployment (the
+    model-configs cut) and, followed by a psum, the body of every ``ep``
+    shard."""
+    e_held = gate_up.shape[0]
+    topk_idx, topk_w = router_topk(x, router_w, k, renormalize,
+                                   float32_logits=float32_logits)
+    flat_e = topk_idx.reshape(-1) - expert_offset
+    mine = (flat_e >= 0) & (flat_e < e_held)
+    local_e = jnp.where(mine, flat_e, e_held)
 
     order = jnp.argsort(local_e)
     token_of = order // k
     xs = x[token_of]
-    group_sizes = jnp.bincount(local_e, length=e_local)
+    group_sizes = jnp.bincount(local_e, length=e_held + 1)[:e_held]
     y = _grouped_mlp(xs, gate_up, down, group_sizes)
-    y = y * flat_w[order][:, None].astype(y.dtype)
+    w = topk_w.reshape(-1)[order]
+    y = jnp.where(mine[order][:, None], y * w[:, None].astype(y.dtype), 0)
     out = jnp.zeros((x.shape[0], x.shape[1]), y.dtype).at[token_of].add(y)
-    return jax.lax.psum(out, "ep").astype(x.dtype)
+    return out.astype(x.dtype)
+
+
+def _routed_moe_ep_shard(x, router_w, gate_up, down, k: int,
+                         renormalize: bool = True):
+    """Per-ep-shard body: full token set, local expert slab; the psum over
+    ep sums exactly one live contribution per pair."""
+    lo = jax.lax.axis_index("ep") * gate_up.shape[0]
+    # the ep shards keep the routing of routed_moe, their one-chip twin
+    out = routed_moe_share(x, router_w, gate_up, down, k, lo, renormalize,
+                           float32_logits=False)
+    return jax.lax.psum(out, "ep")
 
 
 def _moe_a2a_shard(x, router_w, gate_up, down, k: int, capacity: int,

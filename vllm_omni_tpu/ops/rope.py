@@ -91,6 +91,25 @@ def apply_rope_ref(
     return jnp.stack([o1, o2], axis=-2).reshape(x.shape).astype(x.dtype)
 
 
+def apply_partial_rope(x: jax.Array, cos: jax.Array, sin: jax.Array
+                       ) -> jax.Array:
+    """Rotary embedding on the first ``2 * cos.shape[-1]`` dims of every
+    head, the rest passed through (``partial_rotary_factor`` < 1).  The
+    head is cut into blocks as wide as the rotary part and put together
+    again by stack + reshape: a ``concatenate`` of a 64-wide and a
+    192-wide piece on the last axis is the unaligned join that aborts the
+    XLA TPU compiler (see ``apply_rope_ref``).  Elementwise work that XLA
+    fuses; there is no kernel to fall back from."""
+    d, r = x.shape[-1], 2 * cos.shape[-1]
+    if d % r:
+        raise ValueError(f"head_dim {d} is no multiple of rotary dim {r}")
+    blocks = x.reshape(*x.shape[:-1], d // r, r)
+    rot = apply_rope_ref(blocks[..., 0, :], cos, sin)
+    return jnp.stack(
+        [rot] + [blocks[..., i, :] for i in range(1, d // r)],
+        axis=-2).reshape(x.shape)
+
+
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, dim: int):
     # x_ref: [bt, H, D] 3D block — no in-kernel reshape (Mosaic layout
     # inference rejects 2D->3D shape casts for small head dims).

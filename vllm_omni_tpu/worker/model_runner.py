@@ -217,6 +217,19 @@ class ARModelRunner:
             raise ValueError(
                 f"unknown kv_cache_dtype {kv_cache_dtype!r} "
                 "(expected auto, bf16, or int8)")
+        # a model with recurrent (Gated DeltaNet) layers holds two kinds
+        # of cache: pages for its attention layers and one state slot a
+        # sequence for the others (docs/kv_cache.md)
+        self._hybrid = cfg.num_state_layers > 0
+        if self._hybrid and kv_cache_dtype == "int8":
+            raise ValueError(
+                "kv_cache_dtype int8: a model with recurrent layers keeps "
+                "a float32 state beside its pages and has no quantized "
+                "layout for it")
+        if self._hybrid and mesh is not None:
+            raise ValueError(
+                "tensor parallelism: the state cache of a model with "
+                "recurrent layers has no sharded layout yet")
         # int8 = the quantized resident layout (per-(head, page) absmax
         # scales, ops/paged_attention.py); auto/bf16 keep the dense
         # layout in the runner ``dtype``.  The flag is part of every
@@ -319,10 +332,30 @@ class ARModelRunner:
         self.compile_stats = {"compiles": 0, "cache_hits": 0,
                               "compile_s": 0.0, "in_flight": 0}
         self._jit_seen: set[tuple] = set()
-        self.kv_caches = init_kv_cache(
-            cfg.num_layers, num_pages, page_size, cfg.num_kv_heads,
+        # one (k, v) for each softmax-attention layer only
+        pages = init_kv_cache(
+            cfg.num_kv_layers, num_pages, page_size, cfg.num_kv_heads,
             cfg.head_dim, dtype, quantized=self._kv_quant,
         )
+        # and, for each recurrent layer, the convolution's last inputs and
+        # the rule's state, both float32 (the rule's as the published
+        # implementation keeps it; the convolution's because its inputs
+        # are, see transformer._gdn_mixer), one slot a sequence (slots =
+        # max_num_seqs; layouts: ops/gated_delta_rule.py).  A row's slot
+        # index rides next to its page table; padding rows carry
+        # ``num_state_slots``, past the end, so their write is dropped
+        self.num_state_slots = max(max_num_seqs, 1) if self._hybrid else 0
+        state = [
+            (jnp.zeros((self.num_state_slots,
+                        cfg.linear_conv_kernel_dim - 1,
+                        cfg.linear_conv_dim), jnp.float32),
+             jnp.zeros((self.num_state_slots, cfg.linear_num_value_heads,
+                        cfg.linear_key_head_dim,
+                        cfg.linear_value_head_dim), jnp.float32))
+            for _ in range(cfg.num_state_layers)]
+        # the operand every step donates and rebinds
+        self.kv_caches = (tfm.HybridCaches(pages, state) if self._hybrid
+                          else pages)
         # device-memory ledger components (introspection/memory_ledger):
         # static buffer sizes, summed ONCE from array metadata — .nbytes
         # never syncs the device.  Spec-decode verify buffers are added
@@ -332,7 +365,9 @@ class ARModelRunner:
             getattr(x, "nbytes", 0)
             for x in jax.tree_util.tree_leaves(params))
         self._kv_bytes = sum(
-            x.nbytes for x in jax.tree_util.tree_leaves(self.kv_caches))
+            x.nbytes for x in jax.tree_util.tree_leaves(pages))
+        self._state_bytes = sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(state))
         self._spec_bytes = 0
         if mesh is not None:
             from jax.sharding import NamedSharding
@@ -372,7 +407,7 @@ class ARModelRunner:
         def _decode_core(params, token_ids, kv_caches, positions,
                          slot_mapping, block_tables, context_lens,
                          temperature, top_k, top_p, keys,
-                         want_lp: bool):
+                         state_slots=None, *, want_lp: bool):
             # the [B]-row pure-decode step: forward + ON-DEVICE sampling
             # (the hoist that enables the async pipelined engine step —
             # sampled tokens stay device-resident and feed the NEXT
@@ -380,9 +415,12 @@ class ARModelRunner:
             # retire, engine/llm_engine.py).  The want_lp variant also
             # computes chosen/top-k logprobs in the step, so logprobs
             # decode batches pipeline instead of draining.
+            # ``kv_caches`` is the donated operand: the pages, or for a
+            # model with recurrent layers HybridCaches(pages, state slots)
             hidden, new_caches = tfm.forward_decode(
                 params, cfg_, token_ids, positions, kv_caches,
                 slot_mapping, block_tables, context_lens,
+                state_slots=state_slots,
             )
             logits = tfm.logits_from_hidden(params, cfg_, hidden)
             toks = sample_tokens(logits, temperature, top_k, top_p, keys)
@@ -405,8 +443,8 @@ class ARModelRunner:
                           slot_mapping, page_tables, seq_lens, cu_q_lens,
                           q_lens, num_seqs, verify_idx, n_cand, drafts,
                           temperature, top_k, top_p, keys,
-                          inputs_embeds=None, embeds_mask=None,
-                          deepstack=None):
+                          state_slots=None, inputs_embeds=None,
+                          embeds_mask=None, deepstack=None):
             # ONE executable for every non-pure-decode step: the
             # token-packed ragged forward serves prefill chunks,
             # decode rows, and k+1-token spec verify rows in the same
@@ -421,6 +459,7 @@ class ARModelRunner:
                 slot_mapping, page_tables, seq_lens, cu_q_lens, q_lens,
                 num_seqs, inputs_embeds=inputs_embeds,
                 embeds_mask=embeds_mask, deepstack=deepstack,
+                state_slots=state_slots,
             )
             with jax.named_scope("omni.lm_head"):
                 cand_hidden = hidden[verify_idx]      # [S, V, H]
@@ -564,6 +603,10 @@ class ARModelRunner:
         executable's input shapes."""
         import inspect
 
+        if self._hybrid:
+            raise ValueError(
+                "speculative decoding: a rejected draft cannot roll a "
+                "recurrent state back, and this model has recurrent layers")
         self.draft_fn = draft_fn
         self.num_draft_tokens = num_draft_tokens
         # memory-ledger estimate of the verify-path buffers: the widest
@@ -613,6 +656,8 @@ class ARModelRunner:
         from array metadata — never a device sync."""
         comps = {"weights": self._weights_bytes,
                  "kv_pages": self._kv_bytes}
+        if self._state_bytes:
+            comps["state_slots"] = self._state_bytes
         if self._spec_bytes:
             comps["spec_buffers"] = self._spec_bytes
         return comps
@@ -620,6 +665,32 @@ class ARModelRunner:
     def _note_padding(self, useful: int, padded: int) -> None:
         self.useful_tokens += int(useful)
         self.padded_tokens += int(padded)
+
+    # ------------------------------------------------ the state cache
+    @property
+    def state_caches(self) -> list:
+        """For each recurrent layer its (conv, recurrent) state slots; []
+        for a model without such layers.  Held inside ``kv_caches``
+        (``HybridCaches``) so that a step donates both kinds together."""
+        return self.kv_caches.state if self._hybrid else []
+
+    @state_caches.setter
+    def state_caches(self, value) -> None:
+        self.kv_caches = self.kv_caches._replace(state=list(value))
+
+    def _slot_column(self, scheds, width: int) -> tuple:
+        """The state-slot operand of a step, next to the page tables:
+        ``()`` for a model without recurrent layers."""
+        if not self._hybrid:
+            return ()
+        col = np.full((width,), self.num_state_slots, np.int32)
+        for i, sc in enumerate(scheds):
+            if sc.state_slot < 0:
+                raise RuntimeError(
+                    f"request {sc.request.request_id} of a model with "
+                    "recurrent layers was scheduled without a state slot")
+            col[i] = sc.state_slot
+        return (col,)
 
     def _decode_bucket(self, n: int) -> int:
         """Batch bucket for the single-token decode family.  With
@@ -708,7 +779,8 @@ class ARModelRunner:
                             jnp.zeros(pos_shape(b), jnp.int32),
                             jnp.full((b,), -1, jnp.int32), tables,
                             jnp.ones((b,), jnp.int32),
-                            t.temperature, t.top_k, t.top_p, t.keys))
+                            t.temperature, t.top_k, t.top_p, t.keys,
+                            *map(jnp.asarray, self._slot_column((), b))))
         # ONE executable per token bucket — the 1-D shape-cache line
         # that replaces the (batch, seq) grid
         s_max = self._batch_buckets[-1]
@@ -741,7 +813,8 @@ class ARModelRunner:
                     jnp.zeros((s_max, v), jnp.int32),
                     jnp.ones((s_max,), jnp.int32),
                     jnp.zeros((s_max, v - 1), jnp.int32),
-                    t.temperature, t.top_k, t.top_p, t.keys))
+                    t.temperature, t.top_k, t.top_p, t.keys,
+                    *map(jnp.asarray, self._slot_column((), s_max))))
         return built
 
     # ---------------------------------------------------------------- step
@@ -1026,7 +1099,10 @@ class ARModelRunner:
                 jnp.asarray(asm.verify_idx), jnp.asarray(asm.n_cand),
                 jnp.asarray(asm.drafts),
                 tensors.temperature, tensors.top_k, tensors.top_p,
-                tensors.keys)
+                tensors.keys,
+                # the slot column of a model with recurrent layers
+                *map(jnp.asarray, self._slot_column(
+                    scheds, self._batch_buckets[-1])))
         outs, self.kv_caches = self._run_jit(
             "unified",
             # the deepstack LEVEL COUNT is part of the operand shape —
@@ -1097,7 +1173,8 @@ class ARModelRunner:
                 jnp.asarray(positions), jnp.asarray(slots),
                 jnp.asarray(tables), jnp.asarray(ctx),
                 tensors.temperature, tensors.top_k, tensors.top_p,
-                tensors.keys)
+                tensors.keys,
+                *map(jnp.asarray, self._slot_column(scheds, b)))
         outs, self.kv_caches = self._run_jit(
             kind, (b, self._kv_quant), lambda: fn(
                 self.params, token_ids, self.kv_caches, *args))
@@ -1413,6 +1490,7 @@ class ARModelRunner:
         the cross-path no-double-quantize contract); quantized into a
         dense pool dequantizes first; dense into an int8 pool quantizes
         through the write op's shared rounding."""
+        self._refuse_kv_transfer("inject_kv")
         if len(payload) != len(self.kv_caches):
             raise ValueError(
                 f"KV payload has {len(payload)} layers, cache has "
@@ -1478,11 +1556,20 @@ class ARModelRunner:
         return seq_len
 
     # -------------------------------------------------------- kv extraction
+    def _refuse_kv_transfer(self, what: str) -> None:
+        if self._hybrid:
+            raise NotImplementedError(
+                f"{what}: a model with recurrent layers keeps part of a "
+                "sequence in state slots, which a page payload does not "
+                "carry (KV transfer, parking and offload are refused for "
+                "it at engine construction)")
+
     def _extract_layer_slices(self, ids, seq_len: int) -> list:
         """Per-layer device slices for one page run.  Dense pools emit
         [Hkv, seq_len, D] halves; int8 pools emit the quantized wire
         layout ((data[:, :seq_len], page scales)) — the bytes leave the
         device as stored, so a later inject restores them bit-exact."""
+        self._refuse_kv_transfer("extract_kv")
         slices = []
         for k_cache, v_cache in self.kv_caches:
             if isinstance(k_cache, tuple):
