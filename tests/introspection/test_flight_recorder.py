@@ -203,7 +203,7 @@ def test_engine_appends_step_records(stepped_engine):
     for key in ("path", "unified", "fallback", "prefills", "decodes",
                 "spec_rows", "verify_tokens", "new_tokens",
                 "prefill_tokens", "prefill_ctx", "decode_ctx",
-                "wide_q_tokens", "waiting", "running",
+                "wide_q_tokens", "sampler_path", "waiting", "running",
                 "host_ms", "device_ms", "kv_offloads", "kv_restores",
                 "slot", "compiles", "requests", "seq", "ts"):
         assert key in r, f"record missing {key}"
@@ -324,3 +324,44 @@ def test_engine_progress_probe(stepped_engine):
     assert p["progress"] >= stepped_engine.flight.total_steps > 0
     assert p["compiles"] > 0
     assert p["compile_in_flight"] is False
+
+
+def test_sampler_path_counters_ride_the_record_and_the_snapshot():
+    """The step's readback carries which way the sampler's filter went
+    (sample/sampler.py PATH_*): a top_k 20 batch counts as bounded, a
+    top_p-alone batch over nearly flat logits (the CAP candidates do
+    not reach p) as a full sort, a greedy batch as neither."""
+    import jax
+    import jax.numpy as jnp
+
+    from vllm_omni_tpu.engine.llm_engine import EngineConfig, LLMEngine
+    from vllm_omni_tpu.models.common import transformer as tfm
+    from vllm_omni_tpu.sample.sampler import CAP
+    from vllm_omni_tpu.sampling_params import SamplingParams
+
+    cfg = tfm.TransformerConfig.tiny(vocab_size=4 * CAP)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    engine = LLMEngine(params, cfg, EngineConfig(
+        num_pages=32, page_size=4, max_model_len=64, max_num_seqs=4))
+
+    def run(**sampling):
+        engine.generate([[1, 2, 3], [4, 5, 6, 7]], SamplingParams(
+            max_tokens=3, ignore_eos=True, **sampling))
+        n = engine.flight.total_steps
+        paths = [r["sampler_path"] for r in engine.flight.tail(n - run.seen)]
+        run.seen = n
+        return paths, dict(engine.step_metrics.snapshot()["sampler"])
+
+    run.seen = 0
+    paths, totals = run(temperature=0.0, top_k=20, top_p=0.5)
+    assert set(paths) == {None}
+    assert totals == {"bounded_steps_total": 0, "full_sort_steps_total": 0}
+    paths, totals = run(temperature=0.7, top_k=20, top_p=0.8, seed=1)
+    assert set(paths) == {"bounded"}
+    assert totals == {"bounded_steps_total": len(paths),
+                      "full_sort_steps_total": 0}
+    bounded = len(paths)
+    paths, totals = run(temperature=1.0, top_k=0, top_p=0.99, seed=2)
+    assert set(paths) == {"full_sort"}
+    assert totals == {"bounded_steps_total": bounded,
+                      "full_sort_steps_total": len(paths)}

@@ -152,9 +152,9 @@ def test_on_device_rejection_preserves_target_distribution():
     for step in range(16):
         t = SamplingTensors.build([sp] * s, step=step, base_seed=123,
                                   salts=list(range(s)))
-        tk, ct = spec_verify_tokens(logits, drafts, n_cand,
-                                    t.temperature, t.top_k, t.top_p,
-                                    t.keys)
+        tk, ct, _ = spec_verify_tokens(logits, drafts, n_cand,
+                                       t.temperature, t.top_k, t.top_p,
+                                       t.keys)
         tk, ct = np.asarray(tk), np.asarray(ct)
         for i in range(s):
             counts[tk[i, 0]] += 1
@@ -163,9 +163,9 @@ def test_on_device_rejection_preserves_target_distribution():
         # determinism: the same (seed, salt, step) keys reproduce
         t2 = SamplingTensors.build([sp] * s, step=step, base_seed=123,
                                    salts=list(range(s)))
-        tk2, ct2 = spec_verify_tokens(logits, drafts, n_cand,
-                                      t2.temperature, t2.top_k,
-                                      t2.top_p, t2.keys)
+        tk2, ct2, _ = spec_verify_tokens(logits, drafts, n_cand,
+                                         t2.temperature, t2.top_k,
+                                         t2.top_p, t2.keys)
         assert np.array_equal(tk, np.asarray(tk2))
         assert np.array_equal(ct, np.asarray(ct2))
     emp = counts / counts.sum()

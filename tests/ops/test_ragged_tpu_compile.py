@@ -114,3 +114,28 @@ def test_gated_delta_net_compiles_for_v5e(one_chip):
     jax.jit(lambda m, g, b, w, c, r: gdr.gated_delta_net_decode(
         m, g, b, w, c, r, heads)).lower(
         *mixed(s_max), *states(s_max)).compile()
+
+
+def test_sampler_keeps_its_selection_a_selection_on_v5e(one_chip):
+    """``sample_tokens`` at the cells' shape compiles for a v5e with its
+    bounded selection as the TopK custom call and ONE sort, the
+    fallback's (PR 29).  Without the optimization barrier behind
+    ``lax.top_k`` this compiler expands the selection into a second sort
+    of the whole vocabulary, because the fallback's predicate reads its
+    output: 15 ms a step where the selection takes 2.6 (my chip run,
+    PR 29); the numbers come from the chip, this holds the shape of the
+    program.  (It lives here because TPU compiles stay in this file.)"""
+    import re
+
+    from vllm_omni_tpu.sample import sampler
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    b, v = 64, 152064
+    text = sampler.sample_tokens.lower(
+        sds((b, v), jnp.float32), sds((b,), jnp.float32),
+        sds((b,), jnp.int32), sds((b,), jnp.float32),
+        sds((b, 2), jnp.uint32)).compile().as_text()
+    assert text.count('custom_call_target="TopK"') == 1
+    assert len(re.findall(r" sort\(", text)) == 1

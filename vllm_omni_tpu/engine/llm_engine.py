@@ -51,6 +51,7 @@ from vllm_omni_tpu.ops.ragged_paged_attention import (
 )
 from vllm_omni_tpu.outputs import OmniRequestOutput
 from vllm_omni_tpu.request import Request, RequestStatus
+from vllm_omni_tpu.sample.sampler import PATH_BOUNDED, PATH_FULL_SORT
 from vllm_omni_tpu.sampling_params import SamplingParams
 from vllm_omni_tpu.worker.model_runner import ARModelRunner
 
@@ -948,6 +949,17 @@ class LLMEngine:
         step_metrics = getattr(self, "step_metrics", None)  # fakes
         if step_metrics is not None:
             step_metrics.wide_q_tokens_total += wide
+        # dispatches retired since the last record whose sampler applied
+        # a top-k / top-p filter, by the way it found its thresholds (this
+        # step's own on the sync path, its predecessor's when pipelined)
+        tally = getattr(self.runner, "sampler_steps", None)  # fakes
+        bounded = full_sort = 0
+        if tally is not None:
+            bounded, full_sort = tally[PATH_BOUNDED], tally[PATH_FULL_SORT]
+            tally[:] = [0, 0, 0]  # consumed, as _last_kv_moves is
+            if step_metrics is not None:
+                step_metrics.sampler_bounded_steps_total += bounded
+                step_metrics.sampler_full_sort_steps_total += full_sort
         # sequences whose recurrent state this step read and wrote (every
         # scheduled row of a model with recurrent layers; 0 otherwise):
         # what a roofline of the Gated DeltaNet layers needs
@@ -996,6 +1008,8 @@ class LLMEngine:
             "prefill_ctx": int(prefill_ctx),
             "decode_ctx": int(decode_ctx),
             "wide_q_tokens": wide,
+            "sampler_path": ("full_sort" if full_sort
+                             else "bounded" if bounded else None),
             "state_rows": state_rows,
             "waiting": len(self.scheduler.waiting),
             "running": len(self.scheduler.running),

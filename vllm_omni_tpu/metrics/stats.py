@@ -381,6 +381,11 @@ class EngineStepMetrics:
         # new tokens the ragged kernel attended as whole q-block tiles
         # (LLMEngine._record_step; ops/ragged_paged_attention.py)
         self.wide_q_tokens_total = 0
+        # steps whose sampler applied a top-k / top-p filter, by where
+        # its thresholds came from: the bounded set of candidates, or a
+        # sort of the whole vocabulary (sample/sampler.py)
+        self.sampler_bounded_steps_total = 0
+        self.sampler_full_sort_steps_total = 0
 
     def on_schedule(self, waiting: int, running: int) -> None:
         self.num_waiting = waiting
@@ -514,6 +519,11 @@ class EngineStepMetrics:
                 "padded_tokens_total": self.padded_tokens_total,
                 "wide_q_tokens_total": self.wide_q_tokens_total,
                 "efficiency": round(self.padding_efficiency, 4),
+            },
+            "sampler": {
+                "bounded_steps_total": self.sampler_bounded_steps_total,
+                "full_sort_steps_total":
+                    self.sampler_full_sort_steps_total,
             },
             "overlap": {
                 "ratio": round(self.overlap_ratio, 4),

@@ -104,47 +104,108 @@ def _build_keys(seeds: jax.Array, salts: jax.Array, step: jax.Array):
     return jax.vmap(one)(seeds, salts)
 
 
-def _mask_top_k(logits: jax.Array, k: jax.Array) -> jax.Array:
-    """Per-row top-k mask; k==0 disables."""
-    vocab = logits.shape[-1]
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    k_eff = jnp.where(k <= 0, vocab, jnp.minimum(k, vocab))
-    thresh = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], axis=-1)
-    return jnp.where(logits < thresh, _NEG_INF, logits)
+# Candidates a row's thresholds are taken from: the CAP largest scaled
+# logits, by one bounded selection.  A constant, not a setting: the
+# largest value within 1 ms a step of the cheapest in the chip sweep
+# (scripts/sampler_select_sweep.py; the table is in PERF.md, PR 29:
+# sample_tokens at [64, 152064] takes 2.4 / 3.3 / 5.1 / 8.8 / 16.2 ms
+# at CAP 32 / 64 / 128 / 256 / 512, and 31.7 ms with the two sorts it
+# had), and not under 64: it must cover the top_k of 20-50 that chat
+# clients send.
+CAP = 64
+
+# which way a step's filter went (the scalar that rides the step's
+# output pytree; LLMEngine._record_step counts the last two)
+PATH_NONE, PATH_BOUNDED, PATH_FULL_SORT = 0, 1, 2
 
 
-def _mask_top_p(logits: jax.Array, p: jax.Array) -> jax.Array:
-    """Nucleus mask: keep the smallest prefix of the sorted distribution
-    with cumulative prob >= p (always keeps the argmax)."""
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
+def _thresholds(desc, whole_sum, top_k, top_p, use_k, use_p):
+    """The ONE definition of the top-k / nucleus filter, as a threshold
+    per row: entries under it are masked, so ties at it are kept.
+
+    ``desc`` [B, n] holds each row's n largest scaled logits in
+    descending order — the whole sorted row (n == vocab), or its CAP
+    candidates; ``whole_sum`` [B] is ``sum(exp(row - max))`` over the
+    WHOLE row, the nucleus' normaliser where no top-k mask applies.
+    Top-k keeps the entries at or above the k-th largest.  The nucleus
+    keeps the smallest prefix of the top-k survivors whose probability
+    reaches p (entries whose cumulative mass *before* them is < p; the
+    argmax always), normalised over those survivors.
+
+    Returns ``(thresh [B], decided [B])``.  ``decided`` says that the n
+    entries prove the threshold to be the whole row's: every top-k
+    survivor is among them (the last one lies strictly under the k-th),
+    or, without top-k, the nucleus closes before the last.  It means
+    nothing where n == vocab."""
+    n = desc.shape[-1]
+    neg_inf = jnp.float32(-jnp.inf)
+    k = jnp.maximum(top_k, 1)
+    kth = jnp.take_along_axis(
+        desc, (jnp.minimum(k, n) - 1)[:, None], axis=-1)[:, 0]
+    thresh_k = jnp.where(use_k, kth, neg_inf)
+    surv = desc >= thresh_k[:, None]
+    e = jnp.where(surv, jnp.exp(desc - desc[:, :1]), 0.0)
+    probs = e / jnp.where(use_k, jnp.sum(e, axis=-1), whole_sum)[:, None]
     cum = jnp.cumsum(probs, axis=-1)
-    # keep entries where the cumulative mass *before* them is < p
-    keep = (cum - probs) < p[:, None]
-    thresh = jnp.min(
-        jnp.where(keep, sorted_desc, jnp.inf), axis=-1, keepdims=True
-    )
-    return jnp.where(logits < thresh, _NEG_INF, logits)
+    keep = surv & ((cum - probs) < top_p[:, None])
+    thresh_p = jnp.where(
+        use_p, jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1), neg_inf)
+    # (k > n clips the k-th to the last entry, which is not under itself)
+    decided = jnp.where(
+        use_k, desc[:, -1] < thresh_k, ~(use_p & keep[:, -1]))
+    return jnp.maximum(thresh_k, thresh_p), decided
 
 
 def _filtered_scaled(logits, temperature, top_k, top_p):
-    """Temperature-scaled logits with top-k/top-p masks applied — the
-    ONE definition of the sampling distribution, shared by the draw path
-    (``sample_tokens``) and the spec-decode verify path
-    (``filtered_probs``), which must score exactly the distribution the
-    draw path samples from.  Each mask costs a full-vocab sort, so it
-    runs only when some SAMPLING row requests it (greedy rows' filters
-    are discarded downstream and must not trip the predicate — OpenAI
-    clients routinely send top_p alongside temperature=0)."""
+    """Temperature-scaled logits with the top-k/top-p filter applied —
+    the ONE definition of the sampling distribution, shared by the draw
+    path (``sample_tokens``, ``spec_verify_tokens``) and the spec-decode
+    scoring path (``filtered_probs``), which must score exactly the
+    distribution the draw path samples from.  Returns ``(scaled [B,
+    vocab], path)`` with ``path`` one of ``PATH_*``.
+
+    The thresholds come from the CAP largest entries of a row (one
+    bounded selection) whenever those candidates decide every SAMPLING
+    row's filter; a batch holding a row they cannot decide (``top_k``
+    past CAP, ties running through the CAP-th candidate, ``top_p``
+    alone over a distribution so flat that CAP entries do not reach p)
+    sorts the whole vocabulary, once.  Either way the mask is ``scaled
+    < thresh`` over the full row, so both paths keep the same set, ties
+    included, up to float rounding of a cumulative mass against p.
+    Greedy rows' filters are discarded downstream and neither trip the
+    predicates nor get a mask (OpenAI clients routinely send top_p
+    alongside temperature=0); a batch in which no sampling row asks for
+    a filter skips even the selection."""
     safe_t = jnp.where(temperature <= 0.0, 1.0, temperature)
     scaled = logits / safe_t[:, None]
     sampling = temperature > 0.0
-    scaled = jax.lax.cond(
-        jnp.any(sampling & (top_k > 0)),
-        lambda x: _mask_top_k(x, top_k), lambda x: x, scaled)
+    use_k = sampling & (top_k > 0)
+    use_p = sampling & (top_p < 1.0)
+    vocab = scaled.shape[-1]
+    n = min(CAP, vocab)
+
+    def _filter(x):
+        # the barrier keeps the selection a selection: where a
+        # conditional's predicate depends on its output, the TPU
+        # compiler (libtpu 0.0.34) otherwise expands the TopK custom
+        # call into a sort of the whole row (15 ms for 2.6 at 64 rows;
+        # PERF.md, PR 29; tests/ops/test_ragged_tpu_compile.py)
+        cand = jax.lax.optimization_barrier(jax.lax.top_k(x, n)[0])
+        whole_sum = jnp.sum(jnp.exp(x - cand[:, :1]), axis=-1)
+        rows = (whole_sum, top_k, top_p, use_k, use_p)
+        thresh, decided = _thresholds(cand, *rows)
+        path = jnp.int32(PATH_BOUNDED)
+        if n < vocab:  # else the candidates are the whole row
+            bounded = jnp.all(decided)
+            thresh = jax.lax.cond(
+                bounded, lambda: thresh, lambda: _thresholds(
+                    jnp.sort(x, axis=-1)[:, ::-1], *rows)[0])
+            path = jnp.where(bounded, path, PATH_FULL_SORT)
+        return jnp.where(x < thresh[:, None], _NEG_INF, x), path
+
     return jax.lax.cond(
-        jnp.any(sampling & (top_p < 1.0)),
-        lambda x: _mask_top_p(x, top_p), lambda x: x, scaled)
+        jnp.any(use_k | use_p), _filter,
+        lambda x: (x, jnp.int32(PATH_NONE)), scaled)
 
 
 @jax.jit
@@ -155,30 +216,30 @@ def sample_tokens(
     top_k: jax.Array,        # [B]
     top_p: jax.Array,        # [B]
     keys: jax.Array,         # [B, 2] key data
-) -> jax.Array:
-    """Returns sampled token ids [B] i32.
+) -> tuple[jax.Array, jax.Array]:
+    """Returns ``(token ids [B] i32, path)``: the sampled tokens and the
+    scalar ``PATH_*`` the filter took.
 
-    The top-k/top-p masks each cost a FULL-vocab sort per row — the
-    dominant non-matmul work in a decode step (two sorts of
-    [B, 151936] f32) — so an all-greedy batch (the common serving case,
-    and every step inside the greedy multi-step decode scan) skips the
-    whole sampling branch with a lax.cond rather than computing it and
-    discarding it through the final where."""
+    An all-greedy batch (the common serving case, and every step inside
+    the greedy multi-step decode scan) skips the whole sampling branch
+    with a lax.cond rather than computing it and discarding it through
+    the final where."""
     logits = logits.astype(jnp.float32)
     greedy_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def _sampled(_):
-        scaled = _filtered_scaled(logits, temperature, top_k, top_p)
+        scaled, path = _filtered_scaled(logits, temperature, top_k, top_p)
 
         def draw(key_data, row):
             return jax.random.categorical(
                 jax.random.wrap_key_data(key_data), row)
 
-        return jax.vmap(draw)(keys, scaled).astype(jnp.int32)
+        return jax.vmap(draw)(keys, scaled).astype(jnp.int32), path
 
-    sampled_ids = jax.lax.cond(
-        jnp.any(temperature > 0.0), _sampled, lambda _: greedy_ids, None)
-    return jnp.where(temperature <= 0.0, greedy_ids, sampled_ids)
+    sampled_ids, path = jax.lax.cond(
+        jnp.any(temperature > 0.0), _sampled,
+        lambda _: (greedy_ids, jnp.int32(PATH_NONE)), None)
+    return jnp.where(temperature <= 0.0, greedy_ids, sampled_ids), path
 
 
 @jax.jit
@@ -191,13 +252,14 @@ def spec_verify_tokens(
     top_k: jax.Array,        # [S]
     top_p: jax.Array,        # [S]
     keys: jax.Array,         # [S, 2] key data
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """ON-DEVICE speculative verify + accept for a batch of candidate
     rows — the accept-mask rebuild of the split path's host-side
     ``_run_spec_decode`` loop (which paid an argmax ``device_get``, a
     filtered-probs ``device_get``, and a numpy RNG walk per verify
-    step).  Returns ``(tokens [S, V] i32, counts [S] i32)``: row ``s``
-    emits ``tokens[s, :counts[s]]``.
+    step).  Returns ``(tokens [S, V] i32, counts [S] i32, path)``: row
+    ``s`` emits ``tokens[s, :counts[s]]``; ``path`` is the scalar
+    ``PATH_*`` the filter took.
 
     Row semantics (``V`` = 1 + max draft length; row ``s`` carries
     ``n_cand[s] - 1`` real drafts):
@@ -232,15 +294,16 @@ def spec_verify_tokens(
     def _sampled(_):
         flat = logits.reshape(s * v, vocab)
         rep = lambda x: jnp.repeat(x, v)  # noqa: E731
-        scaled = _filtered_scaled(flat, rep(temperature), rep(top_k),
-                                  rep(top_p)).reshape(s, v, vocab)
+        scaled, path = _filtered_scaled(flat, rep(temperature), rep(top_k),
+                                        rep(top_p))
+        scaled = scaled.reshape(s, v, vocab)
         base = jax.vmap(jax.random.wrap_key_data)(keys)
         # position-0 draw on the SAME stream as sample_tokens: a plain
         # row folded into the verify executable samples identically
         draw0 = jax.vmap(jax.random.categorical)(
             base, scaled[:, 0]).astype(jnp.int32)
         if v == 1:
-            return draw0[:, None], jnp.ones((s,), jnp.int32)
+            return draw0[:, None], jnp.ones((s,), jnp.int32), path
         probs = jax.nn.softmax(scaled, axis=-1)  # [S, V, vocab]
         # acceptance tests: u_j < p_j(d_j), stopped at the first miss
         u = jax.vmap(lambda k: jax.vmap(
@@ -289,15 +352,15 @@ def spec_verify_tokens(
         plain = (n_cand <= 1)
         toks = toks.at[:, 0].set(jnp.where(plain, draw0, toks[:, 0]))
         counts = jnp.where(plain, 1, counts)
-        return toks, counts
+        return toks, counts, path
 
-    s_toks, s_counts = jax.lax.cond(
+    s_toks, s_counts, path = jax.lax.cond(
         jnp.any(temperature > 0.0), _sampled,
-        lambda _: (greedy, g_counts), None)
+        lambda _: (greedy, g_counts, jnp.int32(PATH_NONE)), None)
     is_greedy = temperature <= 0.0
     tokens = jnp.where(is_greedy[:, None], greedy, s_toks)
     counts = jnp.where(is_greedy, g_counts, s_counts)
-    return tokens.astype(jnp.int32), counts.astype(jnp.int32)
+    return tokens.astype(jnp.int32), counts.astype(jnp.int32), path
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -338,5 +401,5 @@ def filtered_probs(
     vocab = logits.shape[-1]
     greedy = jax.nn.one_hot(jnp.argmax(logits, axis=-1), vocab)
     probs = jax.nn.softmax(
-        _filtered_scaled(logits, temperature, top_k, top_p), axis=-1)
+        _filtered_scaled(logits, temperature, top_k, top_p)[0], axis=-1)
     return jnp.where((temperature <= 0.0)[:, None], greedy, probs)

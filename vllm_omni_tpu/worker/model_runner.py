@@ -185,9 +185,9 @@ def _params_key(sp: SamplingParams) -> tuple:
     return (sp.temperature, sp.top_k, sp.top_p, sp.seed)
 
 
-# Bucket-padding rows must be GREEDY: sample_tokens skips its
-# full-vocab-sort sampling branch only when no row has temperature > 0,
-# and default-temperature padding would defeat that fast path for every
+# Bucket-padding rows must be GREEDY: sample_tokens skips its sampling
+# branch (filter and draw) only when no row has temperature > 0, and
+# default-temperature padding would defeat that fast path for every
 # batch that doesn't exactly fill its bucket (padding tokens are
 # discarded either way).
 _PAD_SAMPLING = SamplingParams(temperature=0.0)
@@ -423,8 +423,9 @@ class ARModelRunner:
                 state_slots=state_slots,
             )
             logits = tfm.logits_from_hidden(params, cfg_, hidden)
-            toks = sample_tokens(logits, temperature, top_k, top_p, keys)
-            out = {"tokens": toks}
+            toks, path = sample_tokens(logits, temperature, top_k, top_p,
+                                       keys)
+            out = {"tokens": toks, "sampler_path": path}
             if want_lp:
                 chosen, top_v, top_i = compute_logprobs(
                     logits, toks, LOGPROBS_K)
@@ -464,7 +465,7 @@ class ARModelRunner:
             with jax.named_scope("omni.lm_head"):
                 cand_hidden = hidden[verify_idx]      # [S, V, H]
             logits = tfm.logits_from_hidden(params, cfg_, cand_hidden)
-            toks, counts = spec_verify_tokens(
+            toks, counts, path = spec_verify_tokens(
                 logits, drafts, n_cand, temperature, top_k, top_p, keys)
             with jax.named_scope("omni.sampler"):
                 ar = jnp.arange(toks.shape[0])
@@ -473,7 +474,7 @@ class ARModelRunner:
             chosen, top_v, top_i = compute_logprobs(
                 logits[:, 0], toks[:, 0], LOGPROBS_K)
             out = {"tokens": toks, "counts": counts,
-                   "last_tok": last_tok,
+                   "last_tok": last_tok, "sampler_path": path,
                    "lp_chosen": chosen, "lp_topv": top_v,
                    "lp_topi": top_i}
             if drafts.shape[1] > 0:
@@ -525,7 +526,8 @@ class ARModelRunner:
                 )
                 return jax.jit(sm, donate_argnums=(2,))
 
-            dec_keys = ("tokens",) + (("hidden",) if collect_ else ())
+            dec_keys = ("tokens", "sampler_path") + (
+                ("hidden",) if collect_ else ())
             self._decode_sample_fn = wrap(_decode_step, 8, dec_keys)
             self._decode_lp_fn = wrap(
                 _decode_step_lp, 8,
@@ -542,7 +544,7 @@ class ARModelRunner:
                 has_e = inputs_embeds is not None
                 has_d = deepstack is not None
                 has_dr = args[12].shape[1] > 0  # drafts operand
-                uni_keys = ("tokens", "counts", "last_tok",
+                uni_keys = ("tokens", "counts", "last_tok", "sampler_path",
                             "lp_chosen", "lp_topv", "lp_topi")
                 if has_dr:
                     uni_keys += ("accept_hidden",)
@@ -578,6 +580,10 @@ class ARModelRunner:
         self.draft_fn = None
         self.num_draft_tokens = 0
         self.spec_stats = {"verify_steps": 0, "proposed": 0, "accepted": 0}
+        # dispatches retired since the engine last looked, by the way the
+        # sampler's filter went (indexed by sample/sampler.py PATH_*; read
+        # in the step's own readback, consumed by LLMEngine._record_step)
+        self.sampler_steps = [0, 0, 0]
         # width of upstream embeds accepted by this model: the embed_proj
         # input dim when present (thinker width for the talker), else the
         # model's own hidden size
@@ -1203,6 +1209,7 @@ class ARModelRunner:
         # async pipeline: by the time the engine calls this, the NEXT
         # step is already dispatched, so this get overlaps its compute
         outs = jax.device_get(handle.outs)
+        self.sampler_steps[int(outs["sampler_path"])] += 1
         sampled: dict[str, "int | list[int]"] = {}
         if handle.kind == "decode":
             toks = np.asarray(outs["tokens"])
