@@ -2,9 +2,8 @@
 # Async pipelined-step gate: the sync-vs-async greedy token-equality
 # oracle, stop/EOS one-step-lag rollback, preemption/deadline/abort with
 # a step in flight, pipelined spec/logprobs/collect_hidden/embeds
-# batches (the retired fallback reasons asserted absent), the retired
-# multi-step knob's no-op contract, and the CPU-backend overlap
-# microbench (overlap ratio > 0).
+# batches (the retired fallback reasons asserted absent), and the
+# CPU-backend overlap microbench (overlap ratio > 0).
 #
 # Standalone face of the same coverage tier-1 carries — tests/engine is
 # a fast directory, so tests/engine/test_async_step.py rides
@@ -20,5 +19,5 @@ cd "$(dirname "$0")/.."
 # fake-device path; it must never touch a real chip a colocated serving
 # process owns
 exec env JAX_PLATFORMS=cpu python -m pytest \
-    tests/engine/test_async_step.py tests/engine/test_multi_step_decode.py \
+    tests/engine/test_async_step.py \
     -q -p no:cacheprovider -m "not slow" "$@"

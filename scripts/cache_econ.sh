@@ -6,12 +6,9 @@
 # against a hand-oracled 3-replica fixture, torn-read immunity on
 # /debug/kv and /debug/cache under a mutating writer thread, the
 # prefix_hit_rate_low fake-clock alert lifecycle, the shared-prefix
-# workload's determinism, both bench modes in smoke (cache-blind AND
-# prefix-affinity 2 prefill x 2 decode in-proc fleets, mid-flight
-# /metrics probes, bounded digests), and the pre-registered
-# omniaffinity win over the committed baseline artifacts: hit-rate
-# and goodput improve, p99 TTFT does not regress
-# (scripts/affinity_gate.py, perfguard-backed).
+# workload's determinism, and that workload replayed over live
+# cache-blind AND prefix-affinity 2 prefill x 2 decode in-proc fleets
+# (/metrics clean with every cache series live, bounded digests).
 #
 # Standalone face of the same coverage tier-1 carries (tests/cache is
 # a fast directory), sitting next to scripts/alerts.sh,
@@ -21,14 +18,8 @@
 #   scripts/cache_econ.sh -k digest     # pass-through pytest args
 set -eu
 cd "$(dirname "$0")/.."
-# JAX on CPU: the bench engine is a tiny random-weight model; the gate
+# JAX on CPU: the engines are tiny random-weight models; the gate
 # must never touch a real chip a colocated serving process owns
-env JAX_PLATFORMS=cpu python -m pytest \
+exec env JAX_PLATFORMS=cpu python -m pytest \
     tests/cache/ \
     -q -p no:cacheprovider -m "not slow" "$@"
-env JAX_PLATFORMS=cpu python scripts/cache_bench.py --smoke \
-    --out /tmp/BENCH_r16_cacheblind_smoke.json
-env JAX_PLATFORMS=cpu python scripts/cache_bench.py --smoke --affinity \
-    --out /tmp/BENCH_r19_affinity_smoke.json
-# the committed full-run artifacts carry the pre-registered win
-exec python scripts/affinity_gate.py

@@ -9,9 +9,9 @@
 # tiny-model e2e matrix (re-role mid-stream bit-identical to the
 # colocated oracle, controller-driven re-role on a live fleet with a
 # validate-clean /metrics render, seeded replica-kill convergence
-# without flapping, the two-tenant WFQ /metrics split), and finally
-# the diurnal trace-replay bench in --smoke mode (schema-valid curve
-# point, mid-flight metrics probe clean, at least one re-role).
+# without flapping, the two-tenant WFQ /metrics split, and a
+# prefill-pressure burst replayed open-loop against a live service
+# whose controller thread must re-role at least once).
 #
 # Standalone face of the same coverage tier-1 carries
 # (tests/controlplane + tests/core/test_wfq.py are fast), sitting next
@@ -23,11 +23,6 @@ set -eu
 cd "$(dirname "$0")/.."
 # JAX on CPU: the e2e kills replicas and flips roles on purpose; it
 # must never touch a real TPU chip a colocated serving process owns
-env JAX_PLATFORMS=cpu python -m pytest \
+exec env JAX_PLATFORMS=cpu python -m pytest \
     tests/controlplane/ tests/core/test_wfq.py \
     -q -p no:cacheprovider -m "not slow" "$@"
-# trace-replay e2e: the closed-loop diurnal bench, CI-speed — exits
-# nonzero unless the controller re-roles, the serving-curve point is
-# schema-valid, and the mid-flight /metrics probe validates clean
-exec env JAX_PLATFORMS=cpu python scripts/controlplane_bench.py \
-    --smoke --out /tmp/BENCH_r12_smoke.json

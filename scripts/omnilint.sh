@@ -18,7 +18,7 @@
 # pre-commit face.  Deliberate contract changes regenerate the baseline:
 #
 #   python -m vllm_omni_tpu.analysis --update-baseline \
-#       vllm_omni_tpu bench.py scripts
+#       vllm_omni_tpu scripts
 #
 # then commit the baseline.json diff for review like any code change.
 set -eu
@@ -37,4 +37,4 @@ case "$*" in
     *--rules*) ;;
     *) set -- --stale-audit "$@" ;;
 esac
-python -m vllm_omni_tpu.analysis "$@" vllm_omni_tpu bench.py scripts
+python -m vllm_omni_tpu.analysis "$@" vllm_omni_tpu scripts

@@ -17,7 +17,7 @@ cd "$(dirname "$0")/.."
 
 echo "== omnirace: static (OL7-OL9 self-lint) =="
 python -m vllm_omni_tpu.analysis --no-baseline --rules OL7,OL8,OL9 \
-    vllm_omni_tpu bench.py scripts
+    vllm_omni_tpu scripts
 
 echo "== omnirace: runtime (lock-order detector) =="
 exec env JAX_PLATFORMS=cpu OMNI_TPU_LOCK_CHECK=1 python -m pytest -q \

@@ -2,7 +2,7 @@
 
 from tests.analysis.util import lint, messages
 
-BENCH = "bench.py"
+BENCH = "vllm_omni_tpu/benchmarks/fixture.py"
 COLD = "vllm_omni_tpu/config/fixture.py"
 
 

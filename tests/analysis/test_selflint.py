@@ -6,7 +6,7 @@ If this test fails you either introduced a real OL1-OL11 violation
 (fix it or add a reasoned `# omnilint: disable=OLx - why`), or you
 deliberately changed a contract (regenerate the baseline with
 `python -m vllm_omni_tpu.analysis --update-baseline vllm_omni_tpu
-bench.py scripts` and commit the diff).
+scripts` and commit the diff).
 
 The full run (every family over every file, including the package-wide
 OL10/OL11 finalize pass) is computed once per test session and shared
@@ -25,7 +25,7 @@ from vllm_omni_tpu.analysis import (
 from vllm_omni_tpu.analysis.engine import REPO_ROOT
 from vllm_omni_tpu.analysis.manifest import validate_manifest
 
-LINT_TARGETS = ["vllm_omni_tpu", "bench.py", "scripts"]
+LINT_TARGETS = ["vllm_omni_tpu", "scripts"]
 
 _CACHE: dict = {}
 

@@ -112,3 +112,53 @@ def test_board_spans_and_debug_endpoint(tiny_model):
     ledgered = {e["request_id"] for e in board["regret_ledger"]}
     assert any(r.startswith("hot") for r in ledgered)
     assert board["pending_dispatches"] == 0
+
+
+@pytest.mark.parametrize("affinity", [False, True],
+                         ids=["cacheblind", "affinity"])
+def test_shared_prefix_replay_over_a_live_fleet(tiny_model, affinity):
+    """The shared-prefix workload replayed open-loop over a live 2
+    prefill x 2 decode service, under either dispatcher: the curve point
+    is schema-valid, every replica's digest stays under the node cap
+    whatever the traffic pushed, and the service's own /metrics render
+    validates clean with every cache-economics series live."""
+    from vllm_omni_tpu.disagg.service import DisaggService
+    from vllm_omni_tpu.loadgen import (
+        build_workload,
+        poisson_arrivals,
+        run_inproc,
+        shared_prefix_catalog,
+        summarize,
+        validate_curve_point,
+    )
+    from vllm_omni_tpu.metrics.prometheus import validate_exposition
+
+    params, cfg = tiny_model
+    trace = build_workload(
+        poisson_arrivals(20.0, 8, seed=0),
+        catalog=shared_prefix_catalog(n_tenants=4, prefix_len=16),
+        seed=0, vocab_size=60, id_prefix="cachereplay")
+    router = build_inproc_router(
+        params, cfg, EngineConfig(**{**BASE, "max_num_seqs": 2}), 2, 2,
+        affinity_routing=affinity)
+    service = DisaggService(router)
+    try:
+        records = run_inproc(service, trace, timeout_s=300.0)
+        point = summarize(records, offered_rps=20.0)
+        assert validate_curve_point(point) == []
+        assert point["errors"] == 0
+        assert point["completed"] == point["num_requests"], point
+        text = service.render_metrics()
+        expo = router.cache.exposition()
+    finally:
+        service.shutdown()
+    assert all(n <= DIGEST_MAX_NODES
+               for n in expo["digest_nodes"].values())
+    assert validate_exposition(text) == []
+    series = ["fleet_prefix_hit_tokens_total", "fleet_prefill_tokens_total",
+              "fleet_prefix_hit_rate",
+              "fleet_duplicate_prefill_tokens_total",
+              "fleet_duplicate_prefix_tokens", "cache_digest_nodes"]
+    if affinity:
+        series.append("router_affinity_dispatch_total")
+    assert [s for s in series if s not in text] == []

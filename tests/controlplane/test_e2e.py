@@ -262,3 +262,61 @@ def test_service_runs_controller_and_debug_endpoint(tiny_model):
     finally:
         service.shutdown()
     assert not service.engine_thread_alive or True  # joined above
+
+
+def test_service_controller_reroles_under_a_replayed_burst(tiny_model):
+    """The controller on its own thread beside a live service: a
+    prefill-heavy burst replayed open-loop (16 long prompts, 2 tokens
+    out, one prefill replica of three) drives at least one re-role, the
+    curve point is schema-valid with nothing lost, and the service's
+    /metrics render validates clean with the controlplane series live.
+
+    The pressure is arranged, not timed: the engine thread takes
+    requests in and applies the controller's actions but steps no
+    replica until a flip has landed, as an engine slower than the
+    arrivals would, so the queue the controller polls only grows and
+    the outcome does not depend on the machine's speed."""
+    import time
+
+    from vllm_omni_tpu.loadgen import (
+        LoadRequest,
+        run_inproc,
+        summarize,
+        validate_curve_point,
+    )
+
+    params, cfg = tiny_model
+    trace = [LoadRequest(
+        at_s=0.01 * i, request_id=f"burst-{i}", scenario="ingest",
+        tenant="default",
+        prompt_token_ids=[(i + j) % 60 + 1 for j in range(16)],
+        max_tokens=2) for i in range(16)]
+    router = _router(params, cfg, 1, 2)
+    cp = ControlPlane(router, ControlPlaneConfig(
+        poll_interval_s=0.01, hysteresis_ticks=1, cooldown_ticks=200,
+        band_high=1.5, saturation_gain=0.0))
+    step = router.step
+    give_up = time.monotonic() + 60.0  # a dead controller fails, not hangs
+
+    def held_step():
+        if cp.reroles < 1 and time.monotonic() < give_up:
+            time.sleep(0.001)
+            return
+        step()
+
+    router.step = held_step
+    service = DisaggService(router, controlplane=cp)
+    try:
+        records = run_inproc(service, trace, timeout_s=300.0)
+        point = summarize(records, offered_rps=100.0)
+        text = service.render_metrics()
+    finally:
+        service.shutdown()
+    assert validate_curve_point(point) == []
+    assert point["errors"] == 0
+    assert point["completed"] == point["num_requests"], point
+    assert cp.reroles >= 1, \
+        "queued prompts against 1 prefill replica must re-role"
+    assert validate_exposition(text) == []
+    assert "controlplane_replicas" in text
+    assert "controlplane_reroles_total" in text

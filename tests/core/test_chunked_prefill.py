@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.helpers import unified_step
 from vllm_omni_tpu.core.kv_cache_manager import KVCacheManager
 from vllm_omni_tpu.core.scheduler import ARScheduler, SchedulerConfig
 from vllm_omni_tpu.engine.llm_engine import EngineConfig, LLMEngine
@@ -74,31 +75,25 @@ def test_chunked_forward_matches_full_prefill():
 
     page = 4
     prompt = list(np.random.default_rng(0).integers(1, 100, size=13))
-    n = len(prompt)
 
     # full prefill oracle
     caches_a = init_kv_cache(cfg.num_layers, 16, page, cfg.num_kv_heads,
                              cfg.head_dim, jnp.float32)
-    toks = jnp.asarray([prompt], jnp.int32)
-    pos = jnp.arange(n)[None, :]
-    slots = jnp.arange(n)[None, :]
-    full_hidden, caches_a = tfm.forward_prefill(
-        params, cfg, toks, pos, caches_a, slots)
+    pages = [0, 1, 2, 3]  # cover 16 slots
+    (full_hidden,), caches_a = unified_step(
+        params, cfg, caches_a, [(prompt, 0, pages)], page)
 
-    # chunked: 6 + 7, second chunk via forward_prefill_chunked
+    # chunked: 6 + 7, the second chunk attends the first's cached KV
     caches_b = init_kv_cache(cfg.num_layers, 16, page, cfg.num_kv_heads,
                              cfg.head_dim, jnp.float32)
     c1 = 6
-    h1, caches_b = tfm.forward_prefill(
-        params, cfg, toks[:, :c1], pos[:, :c1], caches_b, slots[:, :c1])
-    n2 = n - c1
-    tables = jnp.arange(4)[None, :]  # pages 0..3 cover 16 slots
-    h2, caches_b = tfm.forward_prefill_chunked(
-        params, cfg, toks[:, c1:], pos[:, c1:], caches_b, slots[:, c1:],
-        tables, jnp.asarray([n], jnp.int32), jnp.asarray([c1], jnp.int32))
+    _, caches_b = unified_step(
+        params, cfg, caches_b, [(prompt[:c1], 0, pages)], page)
+    (h2,), caches_b = unified_step(
+        params, cfg, caches_b, [(prompt[c1:], c1, pages)], page)
 
     np.testing.assert_allclose(
-        np.asarray(h2[0]), np.asarray(full_hidden[0, c1:]),
+        np.asarray(h2), np.asarray(full_hidden[c1:]),
         atol=1e-4, rtol=1e-4)
     # caches identical too
     for (ka, va), (kb, vb) in zip(caches_a, caches_b):

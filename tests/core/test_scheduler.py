@@ -219,24 +219,6 @@ def test_abort():
     assert not s.has_unfinished
 
 
-def test_multi_step_window_retired():
-    """The multi-step window is retired (PR 11): the knob is accepted
-    as a no-op, every decode row is window 1 with exactly one slot —
-    no window-ahead page reservation survives."""
-    cfg = SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64,
-                          max_model_len=64, multi_step_decode=4)
-    s = _mk(cfg)
-    s.add_request(_req("a", n=8, max_tokens=6))
-    s.update_from_output(s.schedule(), {"a": 1})  # prefill, 1 token out
-
-    out = s.schedule()
-    d = out.decodes[0]
-    assert d.window == 1
-    assert len(d.slot_mapping) == 1
-    finished = s.update_from_output(out, {"a": 2})
-    assert not finished
-
-
 def test_spec_verify_in_flight_holds_request():
     """Async spec pipelining: while a k+1-candidate verify dispatch is
     in flight (num_inflight_tokens > 1) the request's next KV position
@@ -329,7 +311,7 @@ def test_restored_park_resumes_as_decode():
         "restored victim must not re-enter through the prefill path"
     assert [d.request.request_id for d in out3.decodes] == ["b"]
     d = out3.decodes[0]
-    assert d.num_new_tokens == 1 and d.window == 1
+    assert d.num_new_tokens == 1 and len(d.slot_mapping) == 1
     assert d.start_pos == victim.num_computed_tokens == 8
     assert kv.restored_tokens == 8
     # the resumed row continues its stream like any running decode

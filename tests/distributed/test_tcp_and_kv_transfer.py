@@ -205,15 +205,13 @@ def test_injected_kv_with_chunked_prefill():
     src.generate([prompt[:12]],
                  SamplingParams(temperature=0.0, max_tokens=1))
     # recompute oracle payload via a fresh forward (transfer-shaped)
+    from tests.helpers import unified_step
     from vllm_omni_tpu.ops.paged_attention import init_kv_cache
-    from vllm_omni_tpu.models.common import transformer as t2
 
     caches = init_kv_cache(cfg.num_layers, 16, 4, cfg.num_kv_heads,
                            cfg.head_dim, jnp.float32)
-    toks = jnp.asarray([prompt[:12]], jnp.int32)
-    posi = jnp.arange(12)[None, :]
-    slots = jnp.arange(12)[None, :]
-    _, caches = t2.forward_prefill(params, cfg, toks, posi, caches, slots)
+    _, caches = unified_step(params, cfg, caches,
+                             [(prompt[:12], 0, [0, 1, 2])], 4)
     payload = [
         (np.asarray(k.reshape(cfg.num_kv_heads, -1, cfg.head_dim)[:, :12]),
          np.asarray(v.reshape(cfg.num_kv_heads, -1, cfg.head_dim)[:, :12]))

@@ -50,13 +50,11 @@ def test_tp_async_pipelined_token_identical(tiny_model):
     """The async pipelined step under TP (on-device sampling inside the
     shard_map body; replicated logits sample the same token on every
     shard, so the device-resident feedback stays consistent without a
-    collective) must match single-device sync greedy exactly.  This is
-    the round-trip amortization that replaced the retired multi-step
-    scan window (PR 11); the knob rides along as an accepted no-op."""
+    collective) must match single-device sync greedy exactly."""
     params, cfg = tiny_model
     want = _greedy(_engine(params, cfg), PROMPTS, 8)
     eng = _engine(params, cfg, tensor_parallel_size=2,
-                  async_scheduling=True, multi_step_decode=4)
+                  async_scheduling=True)
     got = _greedy(eng, PROMPTS, 8)
     assert got == want
 

@@ -368,3 +368,36 @@ def test_engine_records_spans_for_traced_requests(tiny_model):
     assert all(s["trace_id"] == ctx["trace_id"] for s in spans)
     names = {s["name"] for s in spans}
     assert {"queue_wait", "prefill", "decode", "sampling"} <= names
+
+
+PROMPTS = [[1, 5, 9, 2, 7], [3, 3, 8], [11, 4, 6, 1, 2, 9, 5]]
+
+
+def test_warmup_precompiles_all_traffic_shapes(tiny_model):
+    """engine.warmup() => serving traffic hits zero new executables on
+    the unified/decode paths (a mid-traffic XLA compile stalls every
+    in-flight request for its whole duration).  The warmup surface is
+    the 1-D token-bucket line plus the decode buckets × {plain,
+    logprobs} — the (batch, seq) grid of the deleted split executor is
+    gone."""
+    params, cfg = tiny_model
+    eng = _engine(params, cfg)
+    n = eng.warmup(prefill_shapes=[
+        (len(PROMPTS), max(len(p) for p in PROMPTS))])
+    assert n > 0
+    compiles = eng.runner.compile_stats["compiles"]
+    sp = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
+    outs = eng.generate(PROMPTS, sp)
+    assert all(len(o.outputs[0].token_ids) == 12 for o in outs)
+    # identical prompts again: APC prefix hits resume mid-prompt
+    # through the unified continuation — same token buckets, still warm
+    outs2 = eng.generate(PROMPTS, sp)
+    assert eng.runner.compile_stats["compiles"] == compiles, \
+        "traffic compiled shapes warmup missed"
+    for a, b in zip(outs, outs2):
+        assert a.outputs[0].token_ids == b.outputs[0].token_ids
+    # warmup's dropped-slot writes must not have corrupted generation:
+    # a fresh un-warmed engine produces identical greedy tokens
+    base = _engine(params, cfg).generate(PROMPTS, sp)
+    for b, m in zip(base, outs):
+        assert m.outputs[0].token_ids == b.outputs[0].token_ids

@@ -1,6 +1,6 @@
 """Fast in-process end-to-end: the open-loop runner drives AsyncOmni
-and produces a schema-valid ``serving_curve`` record (the same shape
-bench.py's OMNI_BENCH_SERVING scenario writes into BENCH_*.json)."""
+and produces a schema-valid ``serving_curve`` record (the shape
+``loadgen.runner.validate_curve_point`` defines)."""
 
 import json
 

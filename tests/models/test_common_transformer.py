@@ -6,11 +6,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tests.helpers import unified_step
 from vllm_omni_tpu.models.common.transformer import (
     TransformerConfig,
     forward_decode,
     forward_hidden,
-    forward_prefill,
     init_params,
     logits_from_hidden,
 )
@@ -21,18 +21,18 @@ def test_prefill_matches_full_forward(rng):
     cfg = TransformerConfig.tiny()
     params = init_params(rng, cfg)
     tokens = jax.random.randint(rng, (2, 10), 0, cfg.vocab_size)
-    pos = jnp.broadcast_to(jnp.arange(10)[None], (2, 10))
     caches = init_kv_cache(
         cfg.num_layers, 16, 4, cfg.num_kv_heads, cfg.head_dim, jnp.float32
     )
     # seq0 -> pages 0..3, seq1 -> pages 8..11
-    slots = jnp.stack(
-        [jnp.arange(10), 8 * 4 + jnp.arange(10)]
-    )
-    h_pref, caches = forward_prefill(params, cfg, tokens, pos, caches, slots)
+    h_pref, caches = unified_step(
+        params, cfg, caches,
+        [(np.asarray(tokens[0]), 0, [0, 1, 2, 3]),
+         (np.asarray(tokens[1]), 0, [8, 9, 10, 11])], 4)
     h_full = forward_hidden(params, cfg, tokens)
     np.testing.assert_allclose(
-        np.asarray(h_pref), np.asarray(h_full), atol=1e-4, rtol=1e-4
+        np.asarray(jnp.stack(h_pref)), np.asarray(h_full),
+        atol=1e-4, rtol=1e-4
     )
 
 
@@ -43,7 +43,6 @@ def test_decode_continues_prefill(rng):
     params = init_params(rng, cfg)
     b, prompt_len = 2, 7
     tokens = jax.random.randint(rng, (b, prompt_len), 3, cfg.vocab_size)
-    pos = jnp.broadcast_to(jnp.arange(prompt_len)[None], (b, prompt_len))
     page = 4
     caches = init_kv_cache(
         cfg.num_layers, 32, page, cfg.num_kv_heads, cfg.head_dim, jnp.float32
@@ -58,11 +57,11 @@ def test_decode_continues_prefill(rng):
         p_i, off = idx // page, idx % page
         return int(block_tables[seq, p_i]) * page + off
 
-    slots = jnp.asarray(
-        [[slot_for(s, i) for i in range(prompt_len)] for s in range(b)]
-    )
-    h, caches = forward_prefill(params, cfg, tokens, pos, caches, slots)
-    logits = logits_from_hidden(params, cfg, h)[:, -1]
+    h, caches = unified_step(
+        params, cfg, caches,
+        [(np.asarray(tokens[s]), 0, np.asarray(block_tables[s]))
+         for s in range(b)], page)
+    logits = logits_from_hidden(params, cfg, jnp.stack(h))[:, -1]
     seqs = np.asarray(tokens)
 
     for step_i in range(4):

@@ -4,7 +4,7 @@ Reference semantics (qwen3_omni_moe_thinker.py:177-178): after decoder
 layer i (for i < n_deep), the multiscale visual features of level i are
 added to the residual stream at visual-token positions.  Here the
 processor ships a dense [n_deep, S, hidden] table (zeros at non-visual
-rows) and the prefill forwards add level i after layer i.
+rows) and the served forward adds level i after layer i.
 """
 
 import numpy as np
@@ -12,6 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from tests.helpers import unified_step
 from vllm_omni_tpu.models.common import transformer as tfm
 from vllm_omni_tpu.ops.paged_attention import init_kv_cache
 
@@ -25,47 +26,43 @@ def _setup(n_layers=3, seed=0):
     return cfg, params
 
 
+def _caches(cfg, num_pages, page):
+    return init_kv_cache(cfg.num_layers, num_pages, page, cfg.num_kv_heads,
+                         cfg.head_dim, jnp.float32)
+
+
 def test_zero_deepstack_is_identity():
     cfg, params = _setup()
     b, s, page = 2, 8, 8
-    caches = init_kv_cache(cfg.num_layers, 4, page, cfg.num_kv_heads,
-                           cfg.head_dim, jnp.float32)
-    toks = jnp.arange(b * s, dtype=jnp.int32).reshape(b, s) % 60
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    slots = (jnp.arange(b * s, dtype=jnp.int32).reshape(b, s))
-    base, _ = tfm.forward_prefill(params, cfg, toks, pos, caches, slots)
-    caches2 = init_kv_cache(cfg.num_layers, 4, page, cfg.num_kv_heads,
-                            cfg.head_dim, jnp.float32)
-    zeros = jnp.zeros((b, 2, s, cfg.hidden_size))
-    same, _ = tfm.forward_prefill(params, cfg, toks, pos, caches2, slots,
-                                  deepstack=zeros)
-    np.testing.assert_allclose(np.asarray(base), np.asarray(same),
-                               atol=1e-6)
+    toks = np.arange(b * s, dtype=np.int32).reshape(b, s) % 60
+    chunks = [(toks[i], 0, [i]) for i in range(b)]
+    base, _ = unified_step(params, cfg, _caches(cfg, 4, page), chunks, page)
+    zeros = [np.zeros((2, s, cfg.hidden_size), np.float32)] * b
+    same, _ = unified_step(params, cfg, _caches(cfg, 4, page), chunks, page,
+                           deepstack=zeros)
+    np.testing.assert_allclose(np.asarray(jnp.stack(base)),
+                               np.asarray(jnp.stack(same)), atol=1e-6)
 
 
 def test_injection_changes_only_causal_futures():
     """A deepstack perturbation at position p changes outputs at
     positions >= p (causal flow) and leaves positions < p untouched."""
     cfg, params = _setup()
-    b, s, page = 1, 8, 8
-    toks = jnp.arange(s, dtype=jnp.int32)[None] % 60
-    pos = jnp.arange(s, dtype=jnp.int32)[None]
-    slots = jnp.arange(s, dtype=jnp.int32)[None]
+    s, page = 8, 8
+    toks = np.arange(s, dtype=np.int32) % 60
 
     def run(deep):
-        caches = init_kv_cache(cfg.num_layers, 2, page, cfg.num_kv_heads,
-                               cfg.head_dim, jnp.float32)
-        h, _ = tfm.forward_prefill(params, cfg, toks, pos, caches, slots,
-                                   deepstack=deep)
+        (h,), _ = unified_step(params, cfg, _caches(cfg, 2, page),
+                               [(toks, 0, [0])], page, deepstack=[deep])
         return np.asarray(h)
 
     p = 4
-    deep = np.zeros((1, 2, s, cfg.hidden_size), np.float32)
-    base = run(jnp.asarray(deep))
-    deep[0, 0, p] = 1.0
-    pert = run(jnp.asarray(deep))
-    assert np.allclose(base[0, :p], pert[0, :p], atol=1e-6)
-    assert not np.allclose(base[0, p:], pert[0, p:], atol=1e-4)
+    deep = np.zeros((2, s, cfg.hidden_size), np.float32)
+    base = run(deep)
+    deep[0, p] = 1.0
+    pert = run(deep)
+    assert np.allclose(base[:p], pert[:p], atol=1e-6)
+    assert not np.allclose(base[p:], pert[p:], atol=1e-4)
 
 
 def test_chunked_prefill_matches_oneshot():
@@ -74,33 +71,25 @@ def test_chunked_prefill_matches_oneshot():
     chunk the same way."""
     cfg, params = _setup()
     s, page = 8, 4
-    toks = (jnp.arange(s, dtype=jnp.int32) % 60)[None]
-    pos = jnp.arange(s, dtype=jnp.int32)[None]
-    slots = jnp.arange(s, dtype=jnp.int32)[None]
+    toks = np.arange(s, dtype=np.int32) % 60
+    pages = list(range(s // page))
     rng = np.random.default_rng(0)
-    deep = rng.normal(size=(1, 2, s, cfg.hidden_size)).astype(np.float32)
+    deep = rng.normal(size=(2, s, cfg.hidden_size)).astype(np.float32)
 
-    caches = init_kv_cache(cfg.num_layers, 4, page, cfg.num_kv_heads,
-                           cfg.head_dim, jnp.float32)
-    full, _ = tfm.forward_prefill(params, cfg, toks, pos, caches, slots,
-                                  deepstack=jnp.asarray(deep))
+    (full,), _ = unified_step(params, cfg, _caches(cfg, 4, page),
+                              [(toks, 0, pages)], page, deepstack=[deep])
 
-    caches = init_kv_cache(cfg.num_layers, 4, page, cfg.num_kv_heads,
-                           cfg.head_dim, jnp.float32)
     half = s // 2
-    h1, caches = tfm.forward_prefill(
-        params, cfg, toks[:, :half], pos[:, :half], caches,
-        slots[:, :half], deepstack=jnp.asarray(deep[:, :, :half]))
-    tables = jnp.arange(s // page, dtype=jnp.int32)[None]
-    h2, _ = tfm.forward_prefill_chunked(
-        params, cfg, toks[:, half:], pos[:, half:], caches,
-        slots[:, half:], tables, jnp.asarray([s], jnp.int32),
-        jnp.asarray([half], jnp.int32),
-        deepstack=jnp.asarray(deep[:, :, half:]))
-    np.testing.assert_allclose(np.asarray(full[0, half:]),
-                               np.asarray(h2[0]), atol=1e-4)
-    np.testing.assert_allclose(np.asarray(full[0, :half]),
-                               np.asarray(h1[0]), atol=1e-5)
+    (h1,), caches = unified_step(
+        params, cfg, _caches(cfg, 4, page), [(toks[:half], 0, pages)], page,
+        deepstack=[deep[:, :half]])
+    (h2,), _ = unified_step(
+        params, cfg, caches, [(toks[half:], half, pages)], page,
+        deepstack=[deep[:, half:]])
+    np.testing.assert_allclose(np.asarray(full[half:]), np.asarray(h2),
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(full[:half]), np.asarray(h1),
+                               atol=1e-5)
 
 
 def test_engine_e2e_deepstack_conditions_output():

@@ -98,7 +98,6 @@ PROTOCOL_MODULES: tuple[str, ...] = (
 )
 
 BENCH_PATHS: tuple[str, ...] = (
-    "bench.py",
     "vllm_omni_tpu/benchmarks/",
     "vllm_omni_tpu/metrics/",
     "tests/benchmarks/",

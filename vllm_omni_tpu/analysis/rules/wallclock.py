@@ -7,7 +7,7 @@ it measures enqueue latency (microseconds) instead of execution
 with queue depth.  The fix is ``jax.block_until_ready(out)`` (or
 ``out.block_until_ready()``) before reading the second timestamp.
 
-Scope is the BENCH_PATHS manifest (bench.py, benchmarks/, metrics/).
+Scope is the BENCH_PATHS manifest (benchmarks/, metrics/, ...).
 The rule fires per function that (a) reads the clock at least twice —
 i.e. measures a duration, (b) dispatches jax work (a ``jnp.``/``jax.``
 call in the body), and (c) never syncs via ``block_until_ready``.

@@ -54,18 +54,12 @@ class SchedulerConfig:
     max_model_len: int = 4096
     # Chunked prefill: prompts longer than the token budget are split into
     # chunks; later chunks attend the cached KV of earlier ones
-    # (forward_prefill_chunked / the flash kernel's q_offsets path).
+    # (forward_unified: the ragged kernel reads the pages already written).
     enable_chunked_prefill: bool = False
     # speculative decoding: max draft tokens verified per decode step
     # (drafts come from the runner's MTP head via req.spec_draft_tokens)
     num_speculative_tokens: int = 0
     kv_transfer: Optional[KVTransferConfig] = None
-    # RETIRED (PR 11): the multi-step lax.scan window is gone — the
-    # async pipelined step is the round-trip amortization, and it works
-    # for mixed/sampled/spec batches where the scan could not.  The
-    # field is accepted so existing configs keep constructing; the
-    # scheduler always emits window 1.
-    multi_step_decode: int = 1
     # unified ragged batching: emit ONE token-budgeted mixed batch per
     # step — decodes claim the budget first, prefill chunks fill the
     # remainder (chunked prefill becomes the mechanism, not an opt-in
@@ -120,9 +114,6 @@ class ScheduledRequest:
     block_table: list[int]
     # position of the first new token (== num_computed_tokens at schedule)
     start_pos: int
-    # RETIRED (PR 11): the multi-step decode window is always 1 — kept
-    # only so stored/constructed ScheduledRequests keep their shape
-    window: int = 1
     # the sequence's state slot in a model with recurrent layers
     # (KVCacheManager.state_slot); -1 for every other model
     state_slot: int = -1
@@ -713,7 +704,6 @@ class ARScheduler:
                 out.decodes.append(ScheduledRequest(
                     request=req, num_new_tokens=1, slot_mapping=slots,
                     block_table=table, start_pos=req.num_computed_tokens,
-                    window=1,
                 ))
                 budget -= 1
                 self.waiting.pop(0)

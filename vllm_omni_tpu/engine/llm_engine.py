@@ -92,12 +92,6 @@ class EngineConfig:
     # rows of the unified dispatch; greedy requests verify by on-device
     # accept-mask, sampled requests by on-device rejection sampling
     num_speculative_tokens: int = 0
-    # RETIRED (PR 11): the multi-step lax.scan window is gone — the
-    # async pipelined step amortizes the host round trip instead, and
-    # it serves the batches the scan never could (mixed, sampled, spec,
-    # logprobs).  Accepted as a no-op so existing configs construct;
-    # values > 1 log a deprecation warning.
-    multi_step_decode: int = 1
     # unified ragged batching POLICY (the execution mechanism is always
     # on since PR 11 — every non-pure-decode step is ONE token-packed
     # dispatch, ops/ragged_paged_attention.py, and the split executor
@@ -132,8 +126,7 @@ class EngineConfig:
     # HBM-RESIDENT KV dtype (docs/performance.md): "int8" stores the
     # paged pool as int8 bytes + per-(head, page) absmax scales — the
     # attention kernels dequantize in-register during the page DMA
-    # pipeline, and the same HBM budget holds ~2x the pages (more
-    # concurrent sessions at fixed p99 TPOT, scripts/kv_quant_bench.py).
+    # pipeline, and the same HBM budget holds ~2x the pages.
     # "auto"/"bf16" keep the dense layout in ``dtype``
     kv_cache_dtype: str = "auto"  # "auto" | "bf16" | "int8"
     # HBM budget the page pool is sized from.  None derives it from
@@ -153,15 +146,15 @@ class EngineConfig:
     # the edge runs under the PR 3 retry policy + circuit breaker
     kv_offload_connector: Optional[str] = None
     kv_offload_connector_args: Optional[dict] = None
-    # pin the single-token decode family (sync, async dispatch,
-    # multi-step window) to the TOP batch bucket.  XLA fuses the
+    # pin the single-token decode family (sync and async dispatch)
+    # to the TOP batch bucket.  XLA fuses the
     # [B]-leading decode matmuls differently per bucket shape, so the
     # same request decoded next to 3 neighbours vs 7 can differ in the
     # last bf16 bit — enough to flip a greedy argmax when logits run
     # close.  With this on, a request's greedy stream is bit-stable
     # under co-batch churn (arrivals, preemptions, offload restores),
-    # which is what lets the kv_reuse bench compare an offloading
-    # engine against a never-preempted oracle token for token.  Costs
+    # which is what lets an offloading engine be compared with a
+    # never-preempted oracle token for token.  Costs
     # padded rows when the batch runs small; spec-decode verify and
     # the unified token-packed path keep their dynamic shapes.
     deterministic_decode: bool = False
@@ -368,11 +361,6 @@ class LLMEngine:
             wfq_scheduling=config.wfq_scheduling,
             wfq_quantum_tokens=config.wfq_quantum_tokens,
         )
-        if config.multi_step_decode > 1:
-            logger.warning(
-                "multi_step_decode=%d is retired (PR 11): the async "
-                "pipelined step is the round-trip amortization; the "
-                "knob is ignored", config.multi_step_decode)
         sched_cls = (GenerationScheduler if config.worker_type == "generation"
                      else ARScheduler)
         self.scheduler = sched_cls(sched_cfg, kv)
@@ -1726,8 +1714,8 @@ class LLMEngine:
                 st[0] = now
                 new -= 1  # the first token is TTFT, not an ITL
             if new > 0 and st[1]:
-                # a multi-step window emits its tokens in one host round
-                # trip: amortize the gap over them
+                # a speculative step emits several tokens in one host
+                # round trip: amortize the gap over them
                 sm.itl_ms.observe((now - st[1]) * 1e3 / new, n=new)
             st[1] = now
             st[2] = n_out

@@ -1,4 +1,5 @@
-"""CLI: ``python -m vllm_omni_tpu.entrypoints.cli serve|generate|bench``.
+"""CLI: ``python -m vllm_omni_tpu.entrypoints.cli <cmd>``, with ``<cmd>`` one
+of ``serve``, ``generate``, ``bench-serve``, ``serve-stage``.
 
 The TPU-native analogue of the reference's ``vllm serve <model> --omni``
 interception (reference: entrypoints/cli/main.py:10-17, OmniServeCommand
@@ -213,18 +214,6 @@ def cmd_generate(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import os
-    import runpy
-
-    # bench.py lives at the repo root, three levels above this module
-    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    bench_path = os.path.join(repo_root, "bench.py")
-    runpy.run_path(bench_path, run_name="__main__")
-    return 0
-
-
 def cmd_serve_stage(args) -> int:
     from vllm_omni_tpu.entrypoints.stage_proc import run_remote_stage
 
@@ -258,9 +247,6 @@ def main(argv=None) -> int:
     gen.add_argument("--sampling-params", default=None,
                      help='JSON, e.g. \'{"max_tokens": 32}\'')
     gen.set_defaults(fn=cmd_generate)
-
-    bench = sub.add_parser("bench", help="run the repo benchmark")
-    bench.set_defaults(fn=cmd_bench)
 
     bserve = sub.add_parser(
         "bench-serve",

@@ -155,8 +155,8 @@ def summarize(records: Sequence[RequestRecord], offered_rps: float,
     return point
 
 
-#: required keys of a serving_curve point (the BENCH_*.json contract —
-#: tests and the loadgen.sh gate validate artifacts against this)
+#: required keys of a serving_curve point (tests and the loadgen.sh
+#: gate validate what ``summarize`` returns against this)
 CURVE_POINT_KEYS = (
     "offered_rps", "duration_s", "num_requests", "completed", "shed",
     "expired", "errors", "attained_req_per_s", "attained_tok_per_s",

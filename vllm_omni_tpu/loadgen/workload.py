@@ -92,8 +92,8 @@ def shared_prefix_catalog(n_tenants: int = 4,
     weight tenant-pinned scenarios all opening with ONE common system
     prompt (``prefix_group`` shares the draw).  Under a cache-blind
     router the common prefix lands on every replica — the redundancy
-    `scripts/cache_bench.py` scores and prefix-affinity routing
-    (ROADMAP item 3) must reclaim.  Seed-deterministic like every
+    the CacheEconomics board scores (tests/cache/test_cache_e2e.py)
+    and prefix-affinity routing reclaims.  Seed-deterministic like every
     catalog: the prefix is drawn once from the workload seed."""
     if n_tenants < 1:
         raise ValueError("n_tenants must be positive")

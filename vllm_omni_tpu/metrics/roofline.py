@@ -1,8 +1,8 @@
 """Live roofline attribution: per-step achieved MFU / MBU, zero syncs.
 
 "The Anatomy of a Triton Attention Kernel" (PAPERS.md) makes the case
-for attributing achieved FLOPs and bytes per dispatch — which this repo
-only did OFFLINE in bench.py until now.  This module is the live face:
+for attributing achieved FLOPs and bytes per dispatch.  This module is
+the live face (the offline one is benchmark/trace.py, over device time):
 every engine step self-reports how close it ran to the hardware
 roofline, from quantities the step loop already holds on the host:
 

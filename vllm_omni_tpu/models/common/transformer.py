@@ -21,11 +21,9 @@ from vllm_omni_tpu.models.common import nn
 from vllm_omni_tpu.ops import (
     apply_partial_rope,
     apply_rope,
-    cache_shape,
     compute_mrope_freqs,
     compute_rope_freqs,
     flash_attention,
-    gather_pages,
     paged_attention,
     ragged_paged_attention,
     rms_norm,
@@ -621,14 +619,6 @@ def _stateful_recur(cache, slots, fresh, new_states, rule):
     return recur
 
 
-def _no_state_forward(cfg: TransformerConfig, name: str):
-    if cfg.num_state_layers:
-        raise NotImplementedError(
-            f"{name} has no recurrent-state cache: a model with Gated "
-            "DeltaNet layers runs forward_hidden (no cache), "
-            "forward_unified and forward_decode")
-
-
 def forward_hidden(
     params,
     cfg: TransformerConfig,
@@ -724,125 +714,6 @@ def logits_from_hidden(params, cfg: TransformerConfig, hidden: jax.Array):
     return logits
 
 
-def forward_prefill(
-    params,
-    cfg: TransformerConfig,
-    token_ids: jax.Array,  # [B, S] (right-padded)
-    positions: jax.Array,  # [B, S]
-    kv_caches: list,  # per-layer (k, v) paged caches
-    slot_mapping: jax.Array,  # [B, S] flat slots (-1 for padding)
-    inputs_embeds: Optional[jax.Array] = None,  # [B, S, embed_width]
-    embeds_mask: Optional[jax.Array] = None,  # [B, S] bool: row uses embeds
-    deepstack: Optional[jax.Array] = None,  # [B, n_deep, S, hidden]
-):
-    """Prefill: causal attention within the prompt, writing KV pages
-    (embeds-as-input handling: see ``_embed_input``).
-
-    ``deepstack`` carries multiscale visual features (zeros at non-visual
-    positions); level ``i`` is added to the residual stream after decoder
-    layer ``i`` (reference: Qwen3-Omni thinker deepstack injection,
-    qwen3_omni_moe_thinker.py:177-178).
-
-    Returns (hidden [B, S, hidden], new kv_caches).
-    """
-    _no_state_forward(cfg, "forward_prefill")
-    b, s = token_ids.shape
-    x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
-    cos, sin = _rope_tables(cfg, positions)
-    flat_slots = slot_mapping.reshape(-1)
-    new_caches = []
-    for i, (layer, (k_cache, v_cache)) in enumerate(
-            zip(params["layers"], kv_caches)):
-        def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
-            with jax.named_scope("omni.kv_write"):
-                k_cache, v_cache = write_kv_cache(
-                    k_cache, v_cache, k, v, flat_slots
-                )
-            new_caches.append((k_cache, v_cache))
-            with jax.named_scope("omni.attn"):
-                return flash_attention(
-                    q.reshape(b, s, -1, cfg.head_dim),
-                    k.reshape(b, s, -1, cfg.head_dim),
-                    v.reshape(b, s, -1, cfg.head_dim),
-                    causal=True,
-                )
-
-        x = _layer_step(layer, cfg, x, cos, sin, attend)
-        if deepstack is not None and i < deepstack.shape[1]:
-            with jax.named_scope("omni.embed"):
-                x = x + deepstack[:, i].astype(x.dtype)
-    return _final_norm(params, cfg, x), new_caches
-
-
-def forward_prefill_chunked(
-    params,
-    cfg: TransformerConfig,
-    token_ids: jax.Array,  # [B, S] chunk tokens (right-padded)
-    positions: jax.Array,  # [B, S] global positions
-    kv_caches: list,
-    slot_mapping: jax.Array,  # [B, S] flat slots (-1 for padding)
-    block_tables: jax.Array,  # [B, max_pages] page ids covering the context
-    context_lens: jax.Array,  # [B] prefix + chunk length
-    q_starts: jax.Array,  # [B] global position of the chunk's first token
-    inputs_embeds: Optional[jax.Array] = None,
-    embeds_mask: Optional[jax.Array] = None,
-    deepstack: Optional[jax.Array] = None,  # [B, n_deep, S, hidden]
-):
-    """Prefill continuation: a chunk attends the cached KV of earlier
-    chunks plus itself causally (chunked prefill — the capability the
-    reference inherits from vLLM's scheduler and the r1 scheduler left as
-    NotImplementedError).  ``deepstack`` rows cover THIS chunk's positions
-    (the caller slices the request-level table like prompt_embeds).
-
-    The chunk's KV is written to the paged cache first, then each layer
-    gathers the full context ``[B, ctx, Hkv, D]`` through ``block_tables``
-    and runs flash attention with per-sequence query offsets
-    (``q_starts``) so query i attends keys at positions <= q_starts+i.
-    Peak memory is O(B*ctx*Hkv*D) per layer — pages, never O(S²).
-
-    Returns (hidden [B, S, hidden], new kv_caches).
-    """
-    _no_state_forward(cfg, "forward_prefill_chunked")
-    b, s = token_ids.shape
-    hkv, _, page_size, d = cache_shape(kv_caches[0][0])
-    x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
-    cos, sin = _rope_tables(cfg, positions)
-    flat_slots = slot_mapping.reshape(-1)
-    ctx_width = block_tables.shape[1] * page_size
-    kv_mask = (jnp.arange(ctx_width)[None, :]
-               < context_lens[:, None]).astype(jnp.int32)
-    new_caches = []
-    for i, (layer, (k_cache, v_cache)) in enumerate(
-            zip(params["layers"], kv_caches)):
-        def attend(q, k, v, k_cache=k_cache, v_cache=v_cache):
-            with jax.named_scope("omni.kv_write"):
-                k_cache, v_cache = write_kv_cache(
-                    k_cache, v_cache, k, v, flat_slots
-                )
-            new_caches.append((k_cache, v_cache))
-            # gather context pages: [Hkv, B, P, page, D] -> [B, ctx, Hkv, D]
-            # (gather_pages dequantizes the int8 layout's pages); the
-            # gather is this path's way to attend a paged context, so it
-            # is billed to attention
-            with jax.named_scope("omni.attn"):
-                kg = jnp.transpose(
-                    gather_pages(k_cache, block_tables), (1, 2, 3, 0, 4)
-                ).reshape(b, ctx_width, hkv, d).astype(k.dtype)
-                vg = jnp.transpose(
-                    gather_pages(v_cache, block_tables), (1, 2, 3, 0, 4)
-                ).reshape(b, ctx_width, hkv, d).astype(v.dtype)
-                return flash_attention(
-                    q.reshape(b, s, -1, cfg.head_dim), kg, vg,
-                    causal=True, kv_mask=kv_mask, q_offsets=q_starts,
-                )
-
-        x = _layer_step(layer, cfg, x, cos, sin, attend)
-        if deepstack is not None and i < deepstack.shape[1]:
-            with jax.named_scope("omni.embed"):
-                x = x + deepstack[:, i].astype(x.dtype)
-    return _final_norm(params, cfg, x), new_caches
-
-
 def forward_unified(
     params,
     cfg: TransformerConfig,
@@ -871,9 +742,10 @@ def forward_unified(
     scattered onto the packed token axis (see ``_embed_input``);
     ``deepstack`` carries multiscale visual features per packed row
     (zeros at non-visual rows), level ``i`` added to the residual
-    stream after decoder layer ``i`` — the same contract as
-    ``forward_prefill``, so embeds/deepstack batches ride the unified
-    dispatch instead of a separately padded executable.
+    stream after decoder layer ``i`` (reference: Qwen3-Omni thinker
+    deepstack injection, qwen3_omni_moe_thinker.py:177-178), so
+    embeds/deepstack batches ride the unified dispatch instead of a
+    separately padded executable.
 
     A model with Gated DeltaNet layers passes ``HybridCaches`` as
     ``kv_caches`` (beside the pages, for each such layer the convolution's

@@ -9,7 +9,7 @@ speech-codec tokens; the MTP code predictor
 The thinker→talker handoff rides the engine's prompt_embeds path: the
 stage input processor packs thinker hidden states as prompt_embeds, and
 the transformer's optional ``embed_proj`` adapts thinker width → talker
-width (models/common/transformer.py forward_prefill).
+width (models/common/transformer.py _embed_input).
 """
 
 from __future__ import annotations
@@ -84,7 +84,7 @@ def load_talker(model_dir: str, dtype=jnp.bfloat16):
     Qwen loader.  On top of it ride two ResizeMLP projections from
     thinker width (transformers Qwen3OmniMoeTalkerResizeMLP):
     ``hidden_projection`` feeds the prompt-embeds path (wired as
-    ``embed_proj`` so forward_prefill applies it), ``text_projection``
+    ``embed_proj`` so ``_embed_input`` applies it), ``text_projection``
     is kept for the thinker-text conditioning stream.
 
     Returns (params, cfg, eos) — the model_factory contract; eos is the

@@ -26,7 +26,7 @@ Token-packed layout (the metadata contract, see docs/ragged_batching.md):
 - ``q_lens [S]`` — real (unaligned) query-token count per sequence;
   0 for padding rows of the metadata arrays.
 - ``seq_lens [S]`` — context length per sequence INCLUDING this chunk
-  (the ``context_lens`` convention of ``forward_prefill_chunked``).
+  (the ``context_lens`` convention of ``forward_decode``).
 - ``page_tables [S, max_pages]`` — KV page ids covering each context.
 - ``num_seqs`` — sequences actually present (rows past it are padding).
 

@@ -23,7 +23,7 @@ supports_pallas             ops (interpret-mode fallback for kernels)
 preferred_dtype             config/model.resolve_dtype ("auto" dtype)
 stage_device_env            spawned stage workers' pre-import env
 hbm_bytes / memory_stats    platforms/memory.py stage HBM budgeting
-peak_tflops_bf16            bench.py MFU denominators
+peak_tflops_bf16            metrics/roofline.py MFU denominators
 initialize                  once-per-process backend bring-up
 ==========================  =============================================
 """

@@ -206,9 +206,7 @@ class ARModelRunner:
         seed: Optional[int] = None,
         max_num_seqs: int = 64,
         mesh=None,  # 1-axis "tp" Mesh => tensor-parallel execution
-        multi_step_decode: int = 1,  # retired knob: accepted, ignored
         async_scheduling: bool = False,
-        unified_batching: bool = True,  # retired knob: always unified
         max_num_batched_tokens: int = 2048,  # sizes the token buckets
         deterministic_decode: bool = False,  # pin decode batches to one bucket
         kv_cache_dtype: str = "auto",  # auto | bf16 | int8 resident layout
