@@ -1,3 +1,5 @@
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,10 @@ from vllm_omni_tpu.ops import (
     write_kv_cache,
 )
 from vllm_omni_tpu.ops.paged_attention import init_kv_cache
+
+# the module, not the op of the same name that ``ops`` exports
+_pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+PAGE = 16
 
 
 def test_write_then_read_roundtrip(rng):
@@ -28,6 +34,204 @@ def test_write_then_read_roundtrip(rng):
     # negative slot (padding) is dropped
     kc2, _ = write_kv_cache(kc, vc, k_new[:1] * 7, v_new[:1], jnp.array([-1]))
     np.testing.assert_allclose(np.asarray(kc2), np.asarray(kc))
+
+
+def _row_by_row(cache, new, slots):
+    """The write's contract in plain numpy: row ``t`` lands at page
+    ``slot // page``, offset ``slot % page``; a negative slot is dropped."""
+    out = np.array(cache)
+    for t, s in enumerate(np.asarray(slots)):
+        if s >= 0:
+            out[:, s // PAGE, s % PAGE] = np.asarray(new)[t]
+    return out
+
+
+def _decode_batch(rs, pages):
+    """One row a sequence, each in a page of its own at any offset."""
+    return rs.permutation(pages)[:8] * PAGE + rs.integers(0, PAGE, 8)
+
+
+def _chunk_mid_page(rs, pages):
+    """A prefill chunk that starts mid-page and crosses two page
+    boundaries, its pages anywhere in the pool, then two decode rows."""
+    p = rs.permutation(pages)
+    chunk = (p[:3, None] * PAGE + np.arange(PAGE)).reshape(-1)[11:37]
+    return np.concatenate([chunk, p[3:5] * PAGE + [0, 15], [-1] * 4])
+
+
+def _dropped_among_real(rs, pages):
+    slots = _chunk_mid_page(rs, pages)
+    slots[[0, 5, 6, 17, 27]] = -1
+    return slots
+
+
+def _all_padding(rs, pages):
+    return np.full(16, -1)
+
+
+def _shuffled(rs, pages):
+    """Distinct slots in no order: a page's rows are not adjacent, and
+    more tokens than one block of the page-write kernel takes, so pages
+    are shared between blocks."""
+    slots = rs.permutation(pages * PAGE)[:150]
+    slots[::9] = -1
+    return slots
+
+
+STEPS = {f.__name__[1:]: f for f in (
+    _decode_batch, _chunk_mid_page, _dropped_among_real, _all_padding,
+    _shuffled)}
+# how a step's rows reach the pool: as served here (off the TPU the XLA
+# row scatter) and through the page-write kernel, interpreted
+WRITES = {"served": write_kv_cache, "pages_kernel": _pa._write_pages}
+
+
+def _pools_and_rows(hkv, d, pages, t, dtype, seed=0):
+    rs = np.random.default_rng(seed)
+    return [jnp.asarray(rs.standard_normal(shape), dtype)
+            for shape in [(hkv, pages, PAGE, d)] * 2 + [(t, hkv, d)] * 2]
+
+
+@pytest.mark.parametrize("write", sorted(WRITES))
+@pytest.mark.parametrize("step", sorted(STEPS))
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("hkv", [1, 2, 4])
+def test_write_equals_row_by_row_reference(hkv, d, step, write):
+    pages = 12
+    slots = STEPS[step](np.random.default_rng(5), pages).astype(np.int32)
+    kc, vc, kn, vn = _pools_and_rows(hkv, d, pages, len(slots),
+                                     jnp.bfloat16)
+    got = WRITES[write](kc, vc, kn, vn, jnp.asarray(slots))
+    for g, cache, new in zip(got, (kc, vc), (kn, vn)):
+        assert g.dtype == cache.dtype and g.shape == cache.shape
+        np.testing.assert_array_equal(
+            np.asarray(g, np.float32),
+            _row_by_row(cache, new, slots).astype(np.float32))
+
+
+@pytest.mark.parametrize("write", sorted(WRITES))
+def test_write_casts_rows_to_a_float32_pool(write):
+    """A float32 pool (the CPU engines' caches) takes bfloat16 rows
+    exactly; the kernel's page is then two (8, 128) tiles high."""
+    slots = _dropped_among_real(np.random.default_rng(6), 6).astype(np.int32)
+    kc, vc, kn, vn = _pools_and_rows(2, 128, 6, len(slots), jnp.float32)
+    kn, vn = kn.astype(jnp.bfloat16), vn.astype(jnp.bfloat16)
+    got = WRITES[write](kc, vc, kn, vn, jnp.asarray(slots))
+    for g, cache, new in zip(got, (kc, vc), (kn, vn)):
+        np.testing.assert_array_equal(
+            np.asarray(g), _row_by_row(cache, new.astype(jnp.float32), slots))
+
+
+def test_pages_kernel_keeps_the_later_row_of_a_slot_written_twice():
+    """Blocks run in order and a block lays its rows in token order, so
+    the kernel is the row-by-row loop even where the mapping repeats a
+    slot (an XLA scatter promises nothing there)."""
+    slots = np.array([33, 34, 35, -1, 34, 200, 33, 35], np.int32)
+    slots = np.concatenate([slots, np.full(140, -1), [34]]).astype(np.int32)
+    kc, vc, kn, vn = _pools_and_rows(2, 128, 16, len(slots), jnp.bfloat16)
+    got_k, _ = _pa._write_pages(kc, vc, kn, vn, jnp.asarray(slots))
+    np.testing.assert_array_equal(
+        np.asarray(got_k, np.float32),
+        _row_by_row(kc, kn, slots).astype(np.float32))
+
+
+@pytest.mark.parametrize("step", ["chunk_mid_page", "all_padding"])
+def test_write_under_jit_with_the_pool_donated(step):
+    """As a served step runs it: the pools donated to the jitted program.
+    The result is the undonated one, and where the backend honours the
+    donation the output is the input's buffer: the write is in place."""
+    pages = 12
+    slots = STEPS[step](np.random.default_rng(7), pages).astype(np.int32)
+    kc, vc, kn, vn = _pools_and_rows(4, 128, pages, len(slots), jnp.bfloat16)
+    want = [_row_by_row(c, n, slots).astype(np.float32)
+            for c, n in ((kc, kn), (vc, vn))]
+    where = [c.unsafe_buffer_pointer() for c in (kc, vc)]
+    step_fn = jax.jit(write_kv_cache, donate_argnums=(0, 1))
+    got = step_fn(kc, vc, kn, vn, jnp.asarray(slots))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32), w)
+    if kc.is_deleted() and vc.is_deleted():  # the donation was taken
+        assert [g.unsafe_buffer_pointer() for g in got] == where
+
+
+def test_write_plan_names_runs_pages_and_buffers():
+    """The page-write kernel's plan for one 16-token block: runs of
+    consecutive slots inside a page, each distinct page held once
+    however its runs are ordered, every run pointed at its page's
+    buffer."""
+    #        page 2: 3 rows | page 5, 1 | dropped | page 2 again | page 3
+    slots = jnp.asarray([37, 38, 39, 80, -1, -1, 32, 33, 47, 48, 49]
+                        + [-1] * 5, jnp.int32)
+    run_len, buf, held, n_pages = _pa._write_plan(slots, PAGE, 16)
+    assert list(run_len[:11]) == [3, 0, 0, 1, 0, 0, 2, 0, 1, 2, 0]
+    starts = [0, 3, 6, 8, 9]
+    assert [int(buf[i]) for i in starts] == [0, 1, 0, 0, 2]
+    assert list(n_pages) == [3] and list(held[:3]) == [2, 5, 3]
+
+
+def test_write_dispatch_names_the_geometry_it_cannot_tile():
+    """A page of 8 bfloat16 rows is half a packed tile: the page-write
+    kernel is refused there (and the row scatter serves), as for a head
+    width off the lanes."""
+    bf = jnp.zeros((2, 4, 16, 128), jnp.bfloat16)
+    assert _pa.write_tiling_reason(bf) == ""
+    assert _pa.write_tiling_reason(jnp.zeros((2, 4, 8, 128))) == ""
+    assert "page_size 8 % 16" in _pa.write_tiling_reason(
+        jnp.zeros((2, 4, 8, 128), jnp.bfloat16))
+    assert "head_dim 64" in _pa.write_tiling_reason(
+        jnp.zeros((2, 4, 16, 64), jnp.bfloat16))
+
+
+def test_write_dispatch_counts_every_buffer_against_vmem():
+    """A grid step's tokens follow the page's size, with the float32
+    rows and the double-buffered block counted beside the page buffers;
+    a page of which sixteen do not fit, and a step whose plans overrun
+    SMEM, are refused by name."""
+    cells = jnp.zeros((4, 4, 16, 128), jnp.bfloat16)
+    assert _pa._write_block(cells) == 128
+    assert _pa._write_block(jnp.zeros((2, 4, 16, 256), jnp.bfloat16)) == 128
+    wide = jnp.zeros((8, 2, 16, 256), jnp.float32)
+    assert _pa._write_block(wide) == 16
+    assert _pa.write_tiling_reason(wide, 2048) == ""
+    huge = jnp.zeros((8, 2, 128, 128), jnp.bfloat16)
+    assert "bytes of VMEM" in _pa.write_tiling_reason(huge, 2048)
+    assert _pa.write_tiling_reason(cells, 32768) == ""
+    assert "SMEM" in _pa.write_tiling_reason(cells, 65536)
+
+
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("page,kernel", [(16, True), (8, False)])
+def test_write_dispatch_on_a_platform_that_compiles_the_kernel(
+        page, kernel, d, monkeypatch):
+    """There a step over 256 rows a half goes to the kernel at either
+    head width; a pool the kernel cannot take (a page of 8 bfloat16 rows)
+    is written by the row scatter, and the fallback is counted."""
+    from vllm_omni_tpu.ops import _dispatch
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    monkeypatch.setattr(_dispatch, "native_fallbacks",
+                        _dispatch.native_fallbacks.copy())
+    taken = []
+    monkeypatch.setattr(
+        _pa, "_write_pages",
+        lambda kc, vc, *rest: taken.append(kc.shape) or (kc, vc))
+    rs = np.random.default_rng(9)
+    slots = rs.permutation(192)[:160].astype(np.int32)
+    slots[::7] = -1
+    kc = jnp.asarray(rs.standard_normal((2, 192 // page, page, d)),
+                     jnp.bfloat16)
+    kn = jnp.asarray(rs.standard_normal((160, 2, d)), jnp.bfloat16)
+    before = sum(_dispatch.native_fallbacks.values())
+    # the undecorated function: a jit cache would remember another mode
+    got_k, _ = write_kv_cache.__wrapped__(kc, kc, kn, kn, jnp.asarray(slots))
+    assert taken == ([kc.shape] if kernel else [])
+    assert sum(_dispatch.native_fallbacks.values()) - before == (not kernel)
+    if not kernel:
+        want = np.array(kc, np.float32).reshape(2, -1, d)
+        keep = slots >= 0
+        want[:, slots[keep]] = np.moveaxis(
+            np.asarray(kn, np.float32)[keep], 1, 0)
+        np.testing.assert_array_equal(
+            np.asarray(got_k, np.float32).reshape(2, -1, d), want)
 
 
 def _build_cache_from_dense(k_dense, v_dense, page_size, block_tables, ctx_lens):

@@ -14,6 +14,7 @@ import importlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -139,3 +140,83 @@ def test_sampler_keeps_its_selection_a_selection_on_v5e(one_chip):
         sds((b, 2), jnp.uint32)).compile().as_text()
     assert text.count('custom_call_target="TopK"') == 1
     assert len(re.findall(r" sort\(", text)) == 1
+
+
+# the pools of the benchmark's cells, a step's rows, and which write
+KV_WRITES = {
+    "thinker_decode64": dict(hkv=4, pool=8192, d=128, t=64),
+    "thinker_chunk2048": dict(hkv=4, pool=8192, d=128, t=2048),
+    "qwen3next_doc4096": dict(hkv=2, pool=32768, d=256, t=4096),
+    "tp4_shard_decode64": dict(hkv=1, pool=8192, d=128, t=64),
+    "one_row": dict(hkv=4, pool=8192, d=128, t=1),
+    # the edges ``write_tiling_reason`` draws: the longest step whose
+    # plans fit SMEM, and a page so large that a grid step takes 16 tokens
+    "longest_step_32768": dict(hkv=4, pool=8192, d=128, t=32768),
+    "block16_float32": dict(hkv=8, pool=1024, d=256, t=2048,
+                            dtype=jnp.float32),
+    # the XLA row scatter the int8 pool's rows and an untileable geometry
+    # take: its window is one [D] row, so it needs no relayout either
+    "row_scatter_chunk2048": dict(hkv=4, pool=8192, d=128, t=2048,
+                                  kernel=False),
+    "served_decode64": dict(hkv=4, pool=8192, d=128, t=64, kernel="served"),
+    "served_mixed128": dict(hkv=4, pool=8192, d=128, t=128,
+                            kernel="served"),
+    "served_qwen3next_doc4096": dict(hkv=2, pool=32768, d=256, t=4096,
+                                     kernel="served"),
+    "served_qwen3next_decode32": dict(hkv=2, pool=32768, d=256, t=32,
+                                      kernel="served"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KV_WRITES))
+def test_kv_write_compiles_in_place_for_v5e(name, one_chip, monkeypatch):
+    """A step's KV write at the cells' pools, the pools donated: the
+    compiled program holds no operation of a pool's size but the write
+    itself and no temporary of that size, and its outputs alias the
+    pools.  Until PR 31 the scatter ran with the kv head as its window
+    and XLA copied the whole pool (134 MB a half, 2.1 GB a step) into
+    the scatter's layout and back, 13 ms of every step on the chip;
+    this holds the shape of the program, the time comes from the chip
+    (scripts/kv_write_sweep.py)."""
+    import re
+
+    shape = KV_WRITES[name]
+    hkv, pool, d, t = (shape[k] for k in ("hkv", "pool", "d", "t"))
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    monkeypatch.setattr(pa, "interpret_flag", lambda: False)
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def rows_only(kc, vc, kn, vn, slots):
+        return (pa._scatter_rows(kc, kn, slots),
+                pa._scatter_rows(vc, vn, slots))
+
+    # "served": as ``write_kv_cache`` dispatches on a platform that
+    # compiles the kernel: the row scatter up to 256 rows a half
+    kernel = shape.get("kernel", True)
+    write = {True: pa._write_pages, False: rows_only}.get(
+        kernel, pa.write_kv_cache)
+    if kernel == "served":
+        from vllm_omni_tpu.ops import _dispatch
+        monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+        kernel = hkv * t > 256
+    dtype = jnp.dtype(shape.get("dtype", jnp.bfloat16))
+    cache = sds((hkv, pool, 16, d), dtype)
+    assert kernel is not True or pa.write_tiling_reason(cache, t) == ""
+    new = sds((t, hkv, d), jnp.bfloat16)
+    compiled = jax.jit(write, donate_argnums=(0, 1)).lower(
+        cache, cache, new, new, sds((t,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == kernel
+    pool_elems = hkv * pool * 16 * d
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|copy-start|transpose|convert)\(", text)
+        if np.prod([int(x) for x in m.group(1).split(",")]) >= pool_elems]
+    assert not moved, moved
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * pool_elems * dtype.itemsize
+    # (the step's own rows, K and V, laid out for the kernel and padded)
+    rows_bytes = 2 * (t + 128) * hkv * d * dtype.itemsize
+    assert memory.temp_size_in_bytes < (
+        pool_elems * dtype.itemsize // 16 + 2 * rows_bytes)

@@ -16,9 +16,32 @@ and the kernels dequantize in-register after the page DMA (the math
 stays f32; only HBM bytes shrink ~2x).
 
 Three ops:
-- ``write_kv_cache``  — slot-mapping scatter of new K/V into the paged cache
-  (the quantized branch grows per-page scales monotonically within a
-  page's tenancy and rescales the page's prior rows in-place)
+- ``write_kv_cache``  — a step's new K/V rows into the paged cache at
+  their flat slots, in the pool's own layout and in place (PR 31).  On
+  the TPU a Pallas kernel brings each touched page ``[Hkv, page, D]``
+  into VMEM once a block of tokens (128), lays the block's rows into it
+  and writes it back, the pools aliased from input to output
+  (``_write_pages``; a bfloat16 row is half a packed sublane, so a page,
+  not a row, is what a DMA moves); elsewhere, and for a step of at most
+  256 rows a half (a decode batch, whose executable is so spared the
+  kernel's trace at every set-up), one XLA scatter of ``[D]`` rows with
+  the head folded into the index (``_scatter_rows``).  Neither holds an
+  operation of the pool's size.  The scatter this replaced ran
+  on the pool viewed ``[Hkv, P*page, D]`` with the head as the leading
+  axis of its window: for that XLA gave the operand a layout of its own
+  and copied the whole pool in and out, 2.1 GB there and back in every
+  step of every cell (13 ms on a v5e).  At ``Hkv = 1`` (a four-chip
+  shard) the copies never existed, which is what showed that the
+  window's head axis, not the scatter, not the reshape and not donation,
+  was the cause; a window of one row needs none at any ``Hkv``, but XLA's
+  scatter costs about 75 ns an index whatever the row holds, dropped or
+  not, which at ``Hkv*T`` = 16 384 indices a half gives the gain back on
+  a 4096-row step (``scripts/kv_write_sweep.py``, PERF.md section 6).
+  Head widths 128 and 256 and 1, 2 and 4 kv heads are shapes the one
+  write reads, not paths of their own.
+  (The quantized branch grows per-page scales monotonically within a
+  page's tenancy and rescales the page's prior rows in-place; its rows
+  go through ``_scatter_rows``.)
 - ``paged_attention_ref`` — gather-based XLA fallback (also the test oracle)
 - ``paged_attention`` — Pallas decode kernel: per (seq, kv-head) grid cell,
   double-buffered page DMAs + online softmax over pages.
@@ -105,8 +128,8 @@ def _write_kv_quant(cache_half, x_new, slot_mapping):
     the scale grows monotonically, ``new = max(old, absmax(new)/127)``,
     and the page's prior int8 rows are rescaled onto the grown scale
     in-place (cost O(T * page_size), never O(cache)); (3) the new rows
-    quantize with the settled scale and scatter through the flat-slot
-    view exactly like the dense path (slot -1 drops)."""
+    quantize with the settled scale and scatter row by row like the
+    dense path off the TPU (``_scatter_rows``; slot -1 drops)."""
     data, scale = cache_half  # int8 [Hkv,P,ps,D], f32 [Hkv,P]
     hkv, p, ps, d = data.shape
     xn = jnp.moveaxis(x_new, 1, 0).astype(jnp.float32)  # [Hkv, T, D]
@@ -133,8 +156,274 @@ def _write_kv_quant(cache_half, x_new, slot_mapping):
     s_tok = jnp.maximum(new_scale[:, pages], SCALE_EPS)  # [Hkv, T]
     qn = jnp.clip(jnp.round(xn / s_tok[..., None]),
                   -QMAX, QMAX).astype(jnp.int8)
-    flat = data.reshape(hkv, p * ps, d).at[:, slots].set(qn, mode="drop")
-    return flat.reshape(data.shape), new_scale
+    data = _scatter_rows(data, jnp.moveaxis(qn, 0, 1), slot_mapping)
+    return data, new_scale
+
+
+def _scatter_rows(cache, new, slot_mapping):
+    """XLA row scatter, the head folded into the index: the pool viewed
+    ``[Hkv*P*page, D]`` (a bitcast) takes ``T*Hkv`` rows of ``[D]`` at
+    ``h*P*page + slot``.  A scatter whose window is one row leaves the
+    pool in its own layout; with the head as the window's leading axis
+    (``[Hkv, P*page, D].at[:, slots]``) XLA copies the whole pool into
+    the scatter's layout and back for ``Hkv > 1``.  About 75 ns an index
+    on a v5e (PERF.md, PR 31), whatever the row holds."""
+    hkv, p, ps, d = cache.shape
+    rows = new.astype(cache.dtype).reshape(-1, d)  # [T*Hkv, D] token-major
+    idx = (slot_mapping[:, None]
+           + jnp.arange(hkv, dtype=jnp.int32)[None] * (p * ps))
+    # negative slots would wrap Python-style: out of bounds, so dropped
+    idx = jnp.where(slot_mapping[:, None] < 0, hkv * p * ps, idx)
+    flat = cache.reshape(hkv * p * ps, d).at[idx.reshape(-1)].set(
+        rows, mode="drop")
+    return flat.reshape(cache.shape)
+
+
+#: rows of ``[D]`` a half (``Hkv*T``) up to which the XLA scatter writes
+#: a step on the TPU too.  A trade of step time for set-up time, by the
+#: sweep on a v5e (PERF.md, PR 31): a 64-row decode step's 16 writes take
+#: 1.15 ms through the scatter and 0.95 through the kernel (0.2 ms of a
+#: 50 ms step given up), and each executable spared the kernel's trace
+#: and lowering starts 0.15-0.25 s sooner at every warm set-up, 2-3 s
+#: over the 17 of 23 executables that are decode buckets (the kernel in
+#: every executable had read ``setup_s`` 10-11% up at a bound of 10).
+#: It counts rows, so the bucket at which the path turns differs with
+#: ``Hkv``: T = 64 at four kv heads, 256 on a one-head shard.
+_SCATTER_MAX_ROWS = 256
+#: VMEM all the page-write kernel's buffers may take together (compiled
+#: ahead of time for a v5e, 21 MB of them fit a kernel and 42 MB did not)
+_WRITE_VMEM_BYTES = 8 * 2 ** 20
+#: tokens a grid step of the page-write kernel takes at most (one page
+#: buffer a token: a decode batch touches a page a row)
+_WRITE_MAX_BLOCK = 128
+#: tokens a step up to which the kernel's prefetched plans (the slots
+#: and four ``[T]`` int32 tables) fit a v5e's 1 MB of SMEM: compiled
+#: ahead of time at this length, 3 KB over at twice it (a served step is
+#: bounded by ``max_num_batched_tokens``, 2048 in the cells)
+_WRITE_MAX_TOKENS = 32768
+
+
+def _write_block(cache) -> int:
+    """Tokens a grid step of the page-write kernel takes at this pool's
+    geometry: the most, in sixteens up to ``_WRITE_MAX_BLOCK``, whose
+    buffers fit ``_WRITE_VMEM_BYTES`` together; under 16, none do."""
+    hkv, _, ps, d = cache.shape
+    item = cache.dtype.itemsize
+    # a token of a block: a page buffer of K and of V, its row of the
+    # float32 ``rows``, its row of the double-buffered ``new`` block
+    per_token = 2 * hkv * d * (ps * item + 4 + 2 * item)
+    margins = 2 * hkv * d * 2 * ps * 4  # of ``rows``
+    fit = (_WRITE_VMEM_BYTES - margins) // per_token
+    return min(fit // 16 * 16, _WRITE_MAX_BLOCK)
+
+
+def _write_plan(slots, page_size: int, block: int):
+    """What the page-write kernel does in each ``block``-token grid
+    step, from the slot mapping alone (so one plan serves every layer of
+    a step: XLA computes it once).  ``[T]`` int32 each, a block's part at
+    ``[i*block, (i+1)*block)``: ``run_len`` (at a token that starts a run
+    of consecutive slots inside one page, the run's length; else 0),
+    ``buf`` (at a run's start, which page buffer holds its page: a page
+    is brought in, and written back, once a block however its rows are
+    ordered), ``held`` (the page in the block's r-th buffer); and
+    ``n_pages`` ``[T / block]``, the block's distinct pages."""
+    # lax primitives, not their jnp wrappers: every step executable
+    # traces this once, and set-up is counted (PERF.md, PR 31)
+    lax, i32 = jax.lax, jnp.int32
+    s = lax.reshape(slots, (slots.shape[0] // block, block))
+    shape, cube = s.shape, s.shape + (block,)
+
+    def const(x, shape=shape):
+        return lax.full(shape, x, i32)
+
+    def along(x, axis):  # [blocks, block] -> [blocks, i, j], x over ``axis``
+        return lax.broadcast_in_dim(x, cube, (0, axis))
+
+    idx = lax.broadcasted_iota(i32, shape, 1)
+    prev = lax.pad(s[:, :-1], i32(-2), ((0, 0, 0), (1, 0, 0)))
+    valid = lax.ge(s, const(0))
+    cont = lax.bitwise_and(
+        lax.bitwise_and(valid, lax.eq(s, lax.add(prev, const(1)))),
+        lax.ne(lax.rem(s, const(page_size)), const(0)))
+    start = lax.bitwise_and(valid, lax.bitwise_not(cont))
+    ends = lax.cummin(lax.select(cont, const(block), idx), axis=1,
+                      reverse=True)
+    ends = lax.pad(ends[:, 1:], i32(block), ((0, 0, 0), (0, 1, 0)))
+    run_len = lax.select(start, lax.sub(ends, idx), const(0))
+    # a page id where a run starts, elsewhere a value of the token's own
+    page = lax.select(start, lax.div(s, const(page_size)),
+                      lax.sub(const(-1), idx))
+    same = lax.eq(along(page, 1), along(page, 2))  # [blocks, i, j]
+    first = lax.argmax(lax.convert_element_type(same, i32), 1, i32)
+    fetch = lax.bitwise_and(start, lax.eq(first, idx))
+    fetch_i = lax.convert_element_type(fetch, i32)
+    rank = lax.sub(lax.cumsum(fetch_i, axis=1), const(1))
+    buf = lax.reduce_sum(
+        lax.select(lax.bitwise_and(same, along(fetch, 1)), along(rank, 1),
+                   const(0, cube)), (1,))
+    # buffer r holds the page of the r-th fetching token
+    held = lax.reduce_sum(
+        lax.select(lax.bitwise_and(along(fetch, 2),
+                                   lax.eq(along(rank, 2), along(idx, 1))),
+                   along(page, 2), const(0, cube)), (2,))
+    flat = (slots.shape[0],)
+    return (lax.reshape(run_len, flat), lax.reshape(buf, flat),
+            lax.reshape(held, flat), lax.reduce_sum(fetch_i, (1,)))
+
+
+def _write_pages_kernel(
+    # scalar prefetch (SMEM)
+    slots_ref,    # [T]
+    run_len_ref,  # [T]
+    buf_ref,      # [T]
+    held_ref,     # [T]
+    n_pages_ref,  # [T / block]
+    # inputs
+    new_ref,  # [2*Hkv, block, D] VMEM: the block's K rows, then its V rows
+    _k_in,    # the pools, aliased to the outputs: read through those
+    _v_in,
+    # outputs
+    k_hbm,  # [Hkv, P, page, D] ANY/HBM
+    v_hbm,
+    # scratch
+    pages,  # [block, 2*Hkv, page, D]: a distinct page's K half and V half
+    # [2*Hkv, D / 128, page + block + page, 128] f32: the block's rows
+    # between two margins, so that a page-high window never leaves them,
+    # in columns one vreg wide (Mosaic loads a window that starts off the
+    # sublane tiling only from a buffer 128 lanes wide)
+    rows,
+    sems,  # DMA [2]: pages in, pages out
+    *,
+    page_size: int,
+    block: int,
+):
+    # (lax primitives throughout, as in ``_write_plan``: set-up traces this)
+    lax = jax.lax
+    base = pl.program_id(0) * block
+    n_pages = n_pages_ref[pl.program_id(0)]
+    hkv = pages.shape[1] // 2
+
+    def move_pages(out: bool):
+        def start(r, carry):
+            page, buffer = held_ref[base + r], pages.at[r]
+            for hbm, half in ((k_hbm, buffer.at[:hkv]),
+                              (v_hbm, buffer.at[hkv:])):
+                pool = hbm.at[:, page]
+                pltpu.make_async_copy(
+                    *((half, pool) if out else (pool, half)),
+                    sems.at[int(out)]).start()
+            return carry
+
+        def wait(_, carry):
+            # a DMA semaphore counts bytes: any whole page buffer sizes
+            # the wait for one page's two halves
+            pltpu.make_async_copy(
+                pages.at[0], pages.at[0], sems.at[int(out)]).wait()
+            return carry
+
+        lax.fori_loop(0, n_pages, start, 0)
+        lax.fori_loop(0, n_pages, wait, 0)
+
+    @pl.when(n_pages > 0)
+    def _block():
+        move_pages(out=False)
+        lane_cols = [pl.ds(c, 128) for c in range(0, pages.shape[-1], 128)]
+        for c, lanes in enumerate(lane_cols):
+            rows[:, c, page_size:page_size + block, :] = (
+                lax.convert_element_type(new_ref[:, :, lanes], jnp.float32))
+        tile = (2 * hkv, page_size, 128)
+        row = lax.broadcasted_iota(jnp.int32, tile, 1)
+
+        def merge(j, carry):
+            n = run_len_ref[base + j]
+
+            @pl.when(n > 0)
+            def _():
+                # page row r takes token j + (r - off): a page-high
+                # window of the rows, masked to the run
+                off = lax.rem(slots_ref[base + j], page_size)
+                window = pl.ds(page_size + j - off, page_size)
+                mine = lax.bitwise_and(
+                    lax.ge(row, lax.broadcast(off, tile)),
+                    lax.lt(row, lax.broadcast(off + n, tile)))
+                b = buf_ref[base + j]
+                for c, lanes in enumerate(lane_cols):
+                    old = lax.convert_element_type(
+                        pages[b, :, :, lanes], jnp.float32)
+                    pages[b, :, :, lanes] = lax.convert_element_type(
+                        lax.select(mine, rows[:, c, window, :], old),
+                        pages.dtype)
+            return carry
+
+        lax.fori_loop(0, block, merge, 0)
+        move_pages(out=True)
+
+
+@jax.jit
+def _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping):
+    """The Pallas page write: each block of tokens brings the pages it
+    touches into VMEM (``[Hkv, page, D]`` of K and of V, once each), lays
+    its rows into them and writes them back, the pools aliased from input
+    to output.  Blocks run in order and a block's pages are back before
+    the next block reads, so a page that two blocks touch keeps both
+    blocks' rows and a slot written twice keeps the later row."""
+    hkv, _, ps, d = k_cache.shape
+    t = slot_mapping.shape[0]
+    block = min(_write_block(k_cache), -(-t // 16) * 16)
+    pad = -t % block
+    lax, dtype = jax.lax, k_cache.dtype
+    slots = lax.pad(lax.convert_element_type(slot_mapping, jnp.int32),
+                    jnp.int32(-1), ((0, pad, 0),))
+    new = lax.transpose(lax.pad(  # [T, 2*Hkv, D] -> [2*Hkv, T + pad, D]
+        lax.concatenate([lax.convert_element_type(k_new, dtype),
+                         lax.convert_element_type(v_new, dtype)], 1),
+        jnp.zeros((), dtype), ((0, pad, 0), (0, 0, 0), (0, 0, 0))),
+        (1, 0, 2))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=((t + pad) // block,),
+        in_specs=[pl.BlockSpec((2 * hkv, block, d), lambda i, *_: (0, i, 0),
+                               memory_space=pltpu.VMEM),
+                  pool_spec, pool_spec],
+        out_specs=[pool_spec, pool_spec],
+        scratch_shapes=[
+            pltpu.VMEM((block, 2 * hkv, ps, d), dtype),
+            pltpu.VMEM((2 * hkv, d // 128, block + 2 * ps, 128),
+                       jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return tuple(pl.pallas_call(
+        functools.partial(_write_pages_kernel, page_size=ps, block=block),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, dtype)],
+        # operands count the five prefetched plans: the pools are 6 and 7
+        input_output_aliases={6: 0, 7: 1},
+        interpret=interpret_flag(),
+    )(slots, *_write_plan(slots, ps, block), new, k_cache, v_cache))
+
+
+def write_tiling_reason(cache, tokens: int = 1) -> str:
+    """Why the page-write kernel cannot take a step of ``tokens`` rows
+    into this pool ("" when it can): the paged kernels' geometry; a page
+    that is whole packed tiles of the pool's dtype (16 rows of bfloat16,
+    8 of float32), because a page is the unit it moves; a page so large
+    (many kv heads, float32, a wide head) that sixteen tokens' buffers
+    overrun its VMEM; a step whose plans overrun SMEM."""
+    hkv, _, ps, d = cache.shape
+    rows = 32 // cache.dtype.itemsize
+    if paged_tiling_reason(d, ps):
+        return paged_tiling_reason(d, ps)
+    if ps % rows:
+        return f"page_size {ps} % {rows} != 0 ({cache.dtype.name})"
+    if _write_block(cache) < 16:
+        return (f"16 pages of [{2 * hkv}, {ps}, {d}] {cache.dtype.name} "
+                f"> {_WRITE_VMEM_BYTES} bytes of VMEM")
+    if tokens > _WRITE_MAX_TOKENS:
+        return f"{tokens} tokens a step > {_WRITE_MAX_TOKENS} (SMEM plans)"
+    return ""
 
 
 @jax.jit
@@ -145,27 +434,38 @@ def write_kv_cache(
     v_new: jax.Array,
     slot_mapping: jax.Array,  # [T] int32, flat slot = page*page_size + offset
 ):
-    """Scatter new KV into the paged cache at the given flat slots.
+    """Write new KV rows into the paged cache at the given flat slots, in
+    the pool's own layout and, where the caller donates the pool, in
+    place: no operation of the pool's size.
 
-    Padded tokens use slot -1: they scatter out of bounds, which XLA drops
-    (mode=drop), matching the CUDA kernel's ignore-negative-slot contract.
+    Padded tokens use slot -1 and are dropped, matching the CUDA kernel's
+    ignore-negative-slot contract.  On a platform that compiles Pallas
+    the touched pages go through VMEM (``_write_pages``); elsewhere, for
+    a geometry or a step the kernel cannot take (``write_tiling_reason``:
+    counted, never silent) and for a step of at most ``_SCATTER_MAX_ROWS``
+    rows a half (a decode batch: 0.2 ms of its step given up for 2-3 s of
+    every set-up, see the constant), one XLA scatter of ``[D]`` rows does
+    it (``_scatter_rows``).  The choice reads ``Hkv``, ``T``, ``D``, the
+    page and the dtype, and nothing else: head widths 128 and 256 and
+    1, 2 and 4 kv heads are shapes of one write.  Until PR 31 the scatter
+    ran on the pool viewed ``[Hkv, P*page, D]`` with the head as its
+    window, and XLA copied the whole pool into the scatter's layout and
+    back, 13 ms of every step for 2.1 GB of cache; at ``Hkv = 1`` it
+    never did, which is what showed that the head axis of the window was
+    the cause.
     The quantized (data, scale) layout dispatches on pytree structure —
     static under jit, so both layouts share one entry point.
     """
     if isinstance(k_cache, tuple):  # omnilint: disable=OL1 - pytree STRUCTURE branch (tuple vs array), static at trace time: jit specializes per layout by design
         return (_write_kv_quant(k_cache, k_new, slot_mapping),
                 _write_kv_quant(v_cache, v_new, slot_mapping))
-    hkv, p, ps, d = k_cache.shape
-    kc = k_cache.reshape(hkv, p * ps, d)
-    vc = v_cache.reshape(hkv, p * ps, d)
-    kn = jnp.moveaxis(k_new, 1, 0).astype(k_cache.dtype)  # [Hkv, T, D]
-    vn = jnp.moveaxis(v_new, 1, 0).astype(v_cache.dtype)
-    # Negative slots would wrap Python-style; push them out of bounds so
-    # mode="drop" discards them.
-    slots = jnp.where(slot_mapping < 0, p * ps, slot_mapping)
-    kc = kc.at[:, slots].set(kn, mode="drop")
-    vc = vc.at[:, slots].set(vn, mode="drop")
-    return kc.reshape(k_cache.shape), vc.reshape(v_cache.shape)
+    tokens, hkv, _ = k_new.shape
+    reason = write_tiling_reason(k_cache, tokens)
+    if tokens * hkv > _SCATTER_MAX_ROWS and auto_use_pallas(
+            "write_kv_cache", reason):
+        return _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping)
+    return (_scatter_rows(k_cache, k_new, slot_mapping),
+            _scatter_rows(v_cache, v_new, slot_mapping))
 
 
 def paged_attention_ref(
