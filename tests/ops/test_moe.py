@@ -171,3 +171,95 @@ def test_a_share_computes_only_the_pairs_of_its_own_experts(rng):
         moe_ops._grouped_mlp = real
     assert seen["sizes"].shape == (2,) and int(seen["sizes"].sum()) == local
     assert 0 < local < 64
+
+
+# ------------------------------------------- the router's scoring, as data
+def test_sigmoid_router_selects_on_the_bias_and_weighs_without_it():
+    """A hand-written case: four experts, top-2, identity router so that
+    the logits are the input.  Scores sigmoid(2, 1, 0.5, -3) = 0.881,
+    0.731, 0.622, 0.047.  Without a bias the picks are experts 0 and 1.
+    A bias of +0.2 on expert 2 lifts it over expert 1 (0.822 > 0.731) and
+    changes the PICK; the WEIGHTS are the unbiased scores of the picks,
+    0.881 and 0.622 over their sum, times the scaling factor."""
+    x = jnp.array([[2.0, 1.0, 0.5, -3.0]])
+    w = jnp.eye(4)
+    s = 1 / (1 + np.exp(-np.array([2.0, 1.0, 0.5, -3.0])))
+    idx, wts = moe_ops.router_topk(x, w, 2, scoring="sigmoid")
+    assert idx.tolist() == [[0, 1]]
+    np.testing.assert_allclose(wts[0], s[[0, 1]] / s[[0, 1]].sum(),
+                               rtol=1e-6)
+    bias = jnp.array([0.0, 0.0, 0.2, 0.0])
+    idx, wts = moe_ops.router_topk(x, w, 2, scoring="sigmoid", bias=bias,
+                                   scale=1.8)
+    assert idx.tolist() == [[0, 2]]
+    np.testing.assert_allclose(
+        wts[0], 1.8 * s[[0, 2]] / s[[0, 2]].sum(), rtol=1e-6)
+    # not the biased scores' weights, and not a softmax's
+    biased = (s + np.asarray(bias))[[0, 2]]
+    assert abs(float(wts[0, 1]) - 1.8 * biased[1] / biased.sum()) > 0.05
+    # a bias that changes no pick changes nothing
+    same = moe_ops.router_topk(x, w, 2, scoring="sigmoid",
+                               bias=jnp.array([0.0, 0.0, 0.05, 0.0]))
+    assert same[0].tolist() == [[0, 1]]
+    # without renormalisation the raw scores are the weights
+    _, raw = moe_ops.router_topk(x, w, 2, renormalize=False,
+                                 scoring="sigmoid")
+    np.testing.assert_allclose(raw[0], s[[0, 1]], rtol=1e-6)
+    with pytest.raises(ValueError, match="softmax or sigmoid"):
+        moe_ops.router_topk(x, w, 2, scoring="tanh")
+
+
+def test_the_default_router_is_the_softmax_it_was():
+    rs = np.random.default_rng(0)
+    x = jnp.asarray(rs.standard_normal((6, 16)), jnp.float32)
+    w = jnp.asarray(rs.standard_normal((16, 8)), jnp.float32)
+    idx, wts = moe_ops.router_topk(x, w, 3)
+    probs = jax.nn.softmax(x @ w, -1)
+    top_w, top_i = jax.lax.top_k(probs, 3)
+    assert idx.tolist() == top_i.tolist()
+    np.testing.assert_allclose(wts, top_w / top_w.sum(-1, keepdims=True),
+                               rtol=1e-6)
+
+
+def test_routed_moe_with_a_sigmoid_router_equals_the_dense_oracle():
+    """The grouped matmul under the sigmoid / bias / scale router against
+    every expert computed and masked by the same router's weights, and a
+    layer's MLP kind read from its weights (dense first, sparse after,
+    the shared expert added whole)."""
+    import dataclasses
+
+    from vllm_omni_tpu.models.common import transformer as tfm
+    from vllm_omni_tpu.models.glm4_moe_lite import tiny_config
+
+    cfg = tiny_config()
+    params = tfm.init_params(jax.random.PRNGKey(2), cfg, jnp.float32)
+    dense_layer, sparse_layer = params["layers"][0], params["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(3), (11, cfg.hidden_size))
+    routed = tfm._mlp(sparse_layer, cfg, x)
+    oracle = tfm._mlp(sparse_layer,
+                      dataclasses.replace(cfg, moe_dispatch="dense"), x)
+    np.testing.assert_allclose(routed, oracle, atol=2e-5)
+    # by hand: the picks of s + b, weighted by s, scaled, plus the shared
+    s = jax.nn.sigmoid(x @ sparse_layer["router"]["w"])
+    _, picks = jax.lax.top_k(s + sparse_layer["router"]["e_bias"], 2)
+    wts = jnp.take_along_axis(s, picks, -1)
+    wts = 1.8 * wts / wts.sum(-1, keepdims=True)
+
+    def expert(gate_up, down, h):
+        gate, up = jnp.split(h @ gate_up, 2, -1)
+        return (jax.nn.silu(gate) * up) @ down
+
+    want = expert(sparse_layer["shared_expert"]["gate_up"]["w"],
+                  sparse_layer["shared_expert"]["down"]["w"], x)
+    for t in range(x.shape[0]):
+        for j in range(2):
+            e = int(picks[t, j])
+            want = want.at[t].add(wts[t, j] * expert(
+                sparse_layer["experts"]["gate_up"][e],
+                sparse_layer["experts"]["down"][e], x[t]))
+    np.testing.assert_allclose(routed, want, atol=2e-5)
+    # the first layer holds no experts: a plain SwiGLU of 160
+    np.testing.assert_allclose(
+        tfm._mlp(dense_layer, cfg, x),
+        expert(dense_layer["gate_up"]["w"], dense_layer["down"]["w"], x),
+        atol=2e-5)

@@ -317,3 +317,116 @@ def test_auto_dispatch_counts_native_fallbacks(monkeypatch):
     assert not _dispatch.auto_use_pallas("paged_attention", reason)
     assert not _dispatch.auto_use_pallas("paged_attention", reason)
     assert _dispatch.native_fallbacks["paged_attention", reason] == 2
+
+
+# ------------------------------------------------ the shared (latent) layout
+def test_init_allocates_one_pool_for_the_shared_layout():
+    (pool, value), = init_kv_cache(1, 4, PAGE, 1, 640, jnp.bfloat16,
+                                   shared=True)
+    assert value is None and pool.shape == (1, 4, PAGE, 640)
+    with pytest.raises(ValueError, match="no int8 form"):
+        init_kv_cache(1, 4, PAGE, 1, 640, quantized=True, shared=True)
+
+
+@pytest.mark.parametrize("write", sorted(WRITES))
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_shared_pool_write_equals_row_by_row_reference(step, write):
+    """One pool of 640-lane rows (a latent cache's [c_kv | k_rope | pad])
+    through the same write: the value absent, one row a token."""
+    pages = 12
+    slots = STEPS[step](np.random.default_rng(5), pages).astype(np.int32)
+    pool, _, rows, _ = _pools_and_rows(1, 640, pages, len(slots),
+                                       jnp.bfloat16)
+    got = WRITES[write](pool, None, rows, None, jnp.asarray(slots))
+    assert len(got) == (2 if write == "served" else 1)
+    assert write != "served" or got[1] is None
+    assert got[0].dtype == pool.dtype and got[0].shape == pool.shape
+    np.testing.assert_array_equal(
+        np.asarray(got[0], np.float32),
+        _row_by_row(pool, rows, slots).astype(np.float32))
+
+
+def test_shared_pool_write_takes_the_kernel_on_a_platform_that_compiles_it(
+        monkeypatch):
+    """A chunk's 300 rows go through the page kernel at one kv head too
+    (its buffers counted for one pool: 128 tokens a block), a decode
+    batch's through the row scatter; neither is counted as a fallback."""
+    from vllm_omni_tpu.ops import _dispatch
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    monkeypatch.setattr(_dispatch, "native_fallbacks",
+                        _dispatch.native_fallbacks.copy())
+    monkeypatch.setattr(_pa, "interpret_flag", lambda: True)
+    calls = []
+    real = _pa._write_pages
+    monkeypatch.setattr(_pa, "_write_pages",
+                        lambda *a: calls.append(a[1]) or real(*a))
+    pool = jnp.zeros((1, 24, PAGE, 640), jnp.bfloat16)
+    assert _pa._write_block(pool, 1) == 128
+    assert _pa.write_tiling_reason(pool, 2048, 1) == ""
+    for t, kernel in ((300, True), (32, False)):
+        rs = np.random.default_rng(t)
+        slots = rs.permutation(24 * PAGE)[:t].astype(np.int32)
+        rows = jnp.asarray(rs.standard_normal((t, 1, 640)), jnp.bfloat16)
+        n = len(calls)
+        # not the jitted entry point: the patched kernel must be reached
+        got, none = _pa.write_kv_cache.__wrapped__(
+            pool, None, rows, None, jnp.asarray(slots))
+        assert none is None and (len(calls) > n) == kernel
+        assert calls[n:] == [None] * kernel
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            _row_by_row(pool, rows, slots).astype(np.float32))
+    assert not _dispatch.native_fallbacks
+
+
+def _latent_case(seed, b, ctx_lens, heads=20, width=640, v_dim=512):
+    """A shared pool filled for ``b`` sequences of the given contexts,
+    each row [576 numbers | zeros], and one absorbed query a head."""
+    rs = np.random.default_rng(seed)
+    pages_per = [-(-c // PAGE) for c in ctx_lens]
+    pool = np.zeros((1, 1 + sum(pages_per), PAGE, width), np.float32)
+    tables = np.zeros((b, max(pages_per) + 1), np.int32)
+    nxt = 1
+    for i, n in enumerate(pages_per):
+        tables[i, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pool[0, 1:, :, :576] = rs.standard_normal(pool[0, 1:, :, :576].shape)
+    q = rs.standard_normal((b, heads, width)).astype(np.float32)
+    q[..., 576:] = 0
+    return (jnp.asarray(q), jnp.asarray(pool), jnp.asarray(tables),
+            jnp.asarray(ctx_lens, jnp.int32), v_dim)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_shared_pool_decode_kernel_matches_the_reference(dtype, tol):
+    """The decode kernel over one pool of 640-lane rows whose first 512
+    lanes are the value, 20 query heads on the one kv head (padded to 24
+    rows inside): against the gather reference, and against the plain
+    definition with the value taken from the key."""
+    q, pool, tables, lens, v_dim = _latent_case(3, 3, [40, 1, 77])
+    q, pool = q.astype(dtype), pool.astype(dtype)
+    ref = paged_attention_ref(q, pool, None, tables, lens, 1 / 16, v_dim)
+    got = paged_attention(q, pool, None, tables, lens, scale=1 / 16,
+                          use_pallas=True, v_dim=v_dim)
+    assert got.shape == ref.shape == (3, 20, 512)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), atol=tol)
+    # the definition, for the second sequence's neighbour (77 keys)
+    keys = np.asarray(pool, np.float32)[0, np.asarray(tables[2])].reshape(
+        -1, 640)[:77]
+    s = np.asarray(q, np.float32)[2] @ keys.T / 16
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = (p / p.sum(-1, keepdims=True)) @ keys[:, :512]
+    np.testing.assert_allclose(np.asarray(ref, np.float32)[2], want,
+                               atol=tol)
+
+
+def test_shared_pool_dispatch_names_a_value_off_the_lanes(monkeypatch):
+    """The tiny models' latent width (64) is no lane tile: the ops say so
+    and take the reference, counted, on a platform that compiles them."""
+    assert _pa.shared_tiling_reason(None, 512) == ""
+    assert _pa.shared_tiling_reason((), 64) == ""  # not shared: no claim
+    assert "value width 64" in _pa.shared_tiling_reason(None, 64)
+    assert "head_dim 576" in _pa.paged_tiling_reason(576, 16)
+    assert _pa.paged_tiling_reason(640, 16) == ""

@@ -292,3 +292,88 @@ def test_wide_q_tokens_is_the_kernels_lookup(q_lens):
             assert wide_seq[b // nsub] == i
             tokens += min(TB, ql[i] - (b * TB - cu[i]))
     assert tokens == wide_q_tokens(q_lens, q_block)
+
+
+# ------------------------------------------------ the shared (latent) layout
+def _latent_pack(specs, heads=20, width=640, used=576, page=16, s_max=4,
+                 seed=0):
+    """A token-packed batch over ONE pool whose rows are key and value at
+    once ([``used`` numbers | zeros] of ``width`` lanes), one kv head."""
+    rng = np.random.default_rng(seed)
+    pages_per = [-(-c // page) for c, _ in specs]
+    pool = np.zeros((1, 1 + sum(pages_per), page, width), np.float32)
+    pool[0, 1:, :, :used] = rng.standard_normal(pool[0, 1:, :, :used].shape)
+    tables = np.zeros((s_max, max(pages_per) + 1), np.int32)
+    cu = np.zeros(s_max + 1, np.int32)
+    q_lens, seq_lens = np.zeros(s_max, np.int32), np.zeros(s_max, np.int32)
+    total, nxt = 0, 1
+    for i, ((ctx, qn), n) in enumerate(zip(specs, pages_per)):
+        tables[i, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+        cu[i], q_lens[i], seq_lens[i] = total, qn, ctx
+        total += align_to_block(qn, TB)
+    cu[len(specs):] = total
+    q = np.zeros((align_to_block(total, TB), heads, width), np.float32)
+    for i, (_, qn) in enumerate(specs):
+        q[cu[i]:cu[i] + qn, :, :used] = rng.standard_normal(
+            (qn, heads, used))
+    return (jnp.asarray(q), jnp.asarray(pool), jnp.asarray(tables),
+            jnp.asarray(cu), jnp.asarray(q_lens), jnp.asarray(seq_lens),
+            len(specs))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 3e-2)])
+def test_shared_pool_kernel_matches_the_reference(dtype, tol):
+    """The ragged kernel over a latent pool at the published shape (640
+    lanes of which the first 512 are the value, 20 query heads on one kv
+    head): two decode rows, a chunk over a cached prefix that crosses q
+    blocks and a page boundary, and a fresh short prompt, against the
+    reference and, for the chunk's last token, the plain definition."""
+    specs = [(45, 1), (130, 1), (100, 37), (9, 9)]
+    q, pool, tables, cu, q_lens, seq_lens, n = _latent_pack(specs)
+    q, pool = q.astype(dtype), pool.astype(dtype)
+    args = (q, pool, None, tables, cu, q_lens, seq_lens, n)
+    ref = ragged_paged_attention_ref(*args, scale=1 / 16, v_dim=512)
+    got = ragged_paged_attention(*args, scale=1 / 16, use_pallas=True,
+                                 v_dim=512)
+    assert got.shape == ref.shape == (q.shape[0], 20, 512)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), atol=tol)
+    row = int(cu[2]) + 36  # position 99 of the third sequence
+    keys = np.asarray(pool, np.float32)[0, np.asarray(tables[2])].reshape(
+        -1, 640)[:100]
+    s = np.asarray(q, np.float32)[row] @ keys.T / 16
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = (p / p.sum(-1, keepdims=True)) @ keys[:, :512]
+    np.testing.assert_allclose(np.asarray(ref, np.float32)[row], want,
+                               atol=tol)
+    # padding rows own their output too
+    assert not np.asarray(got, np.float32)[int(cu[0]) + 1:int(cu[1])].any()
+
+
+def test_shared_pool_decode_row_equals_the_decode_kernel():
+    """A decode row through the ragged kernel and through the decode
+    kernel: one pool, two kernels, the same numbers."""
+    from vllm_omni_tpu.ops import paged_attention
+
+    specs = [(61, 1), (17, 1)]
+    q, pool, tables, cu, q_lens, seq_lens, n = _latent_pack(specs, seed=4)
+    ragged = ragged_paged_attention(q, pool, None, tables, cu, q_lens,
+                                    seq_lens, n, scale=1 / 16,
+                                    use_pallas=True, v_dim=512)
+    rows = np.asarray(cu[:2])
+    decode = paged_attention(q[rows], pool, None, tables[:2], seq_lens[:2],
+                             scale=1 / 16, use_pallas=True, v_dim=512)
+    np.testing.assert_allclose(np.asarray(ragged)[rows],
+                               np.asarray(decode), atol=3e-5)
+
+
+def test_auto_blocks_count_one_buffer_for_a_shared_pool():
+    """At the latent shape (group 20, 640 lanes) the picker takes 16
+    tokens (320 rows) over 512 keys, with one KV buffer counted."""
+    from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
+
+    assert auto_ragged_blocks(head_dim=640, page_size=16, group=20,
+                              kv_itemsize=2, q_itemsize=2,
+                              kv_pools=1) == (16, 32, 2)

@@ -47,6 +47,11 @@ SHAPES = {
     # contexts to 33280 (the second shape the kernel is measured at)
     "head256_bf16": dict(t=4096, hkv=2, group=8, quantized=False, d=256,
                          pool=32768, s_max=32, max_pages=2080),
+    # GLM-4.7-Flash's latent cache: one shared pool whose 640-lane row
+    # ([c_kv 512 | k_rope 64 | 64 zeros]) is the key and, in its first
+    # 512 lanes, the value; 20 query heads on the one kv head
+    "latent_bf16": dict(t=4096, hkv=1, group=20, quantized=False, d=640,
+                        v_dim=512, pool=16384, s_max=32, max_pages=1104),
 }
 
 
@@ -65,22 +70,84 @@ def test_ragged_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
 
     data = sds((hkv, pool, page, d), jnp.int8 if quantized else jnp.bfloat16)
     cache = (data, sds((hkv, pool), jnp.float32)) if quantized else data
+    v_dim = shape.get("v_dim")  # the shared layout: no value pool
     q_block, ppb, slots = auto_ragged_blocks(
         head_dim=d, page_size=page, group=group,
         kv_itemsize=1 if quantized else 2, q_itemsize=2,
-        quantized=quantized)
+        quantized=quantized, kv_pools=1 if v_dim else 2)
 
     def step(q, k, v, tables, cu, q_lens, seq_lens, num_seqs):
         return rpa._ragged_attention(
             q, k, v, tables, cu, q_lens, seq_lens, num_seqs, None,
-            rpa.DEFAULT_TOKEN_BLOCK, True, q_block, ppb, slots)
+            rpa.DEFAULT_TOKEN_BLOCK, True, q_block, ppb, slots, v_dim)
 
     compiled = jax.jit(step).lower(
-        sds((t, hkv * group, d), jnp.bfloat16), cache, cache,
+        sds((t, hkv * group, d), jnp.bfloat16), cache,
+        None if v_dim else cache,
         sds((s_max, max_pages), jnp.int32), sds((s_max + 1,), jnp.int32),
         sds((s_max,), jnp.int32), sds((s_max,), jnp.int32),
         sds((1,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_decode_and_write_compile_for_v5e(one_chip, monkeypatch):
+    """The decode kernel and the page write at the latent cache's shapes
+    (one pool of 640-lane rows, the value its first 512 lanes, a group of
+    20 padded to 24 rows; a 2048-row step and a 32-row decode batch), and
+    no operation of the pool's size in either write."""
+    import re
+
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    from vllm_omni_tpu.ops import _dispatch
+    monkeypatch.setattr(pa, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = sds((1, 16384, 16, 640), jnp.bfloat16)
+    decode = jax.jit(lambda q, k, tables, lens: pa._paged_attention(
+        q, k, None, tables, lens, 1 / 16, True, 512)).lower(
+        sds((32, 20, 640), jnp.bfloat16), pool,
+        sds((32, 1104), jnp.int32), sds((32,), jnp.int32)).compile()
+    assert "tpu_custom_call" in decode.as_text()
+    assert decode.output_shardings is not None
+    for rows in (2048, 32):
+        text = jax.jit(
+            lambda k, new, slots: pa.write_kv_cache(k, None, new, None,
+                                                    slots)[0],
+            donate_argnums=0).lower(
+            pool, sds((rows, 1, 640), jnp.bfloat16),
+            sds((rows,), jnp.int32)).compile().as_text()
+        # the kernel for a chunk, the row scatter for a decode batch
+        assert ("tpu_custom_call" in text) == (rows > 256)
+        # nothing but the aliased operand and result is as large as the pool
+        copies = [ln for ln in text.splitlines()
+                  if re.search(r"= bf16\[1,16384,16,640\]\S* (copy|transpose)\(",
+                               ln)]
+        assert not copies, copies
+
+
+def test_glm_weights_are_made_an_expert_at_a_time(one_chip):
+    """The benchmark's GLM-4.7-Flash weights (the program's draw, the
+    routed experts then related to one another) are made in one jitted
+    call beside 10.3 GB of results: relating every layer's experts at once
+    in float32 took 8 GB of temporaries and did not load on the chip (my
+    chip run, PR 32); an expert at a time takes the largest drawn tensor's
+    0.8 GB."""
+    import functools
+
+    from benchmark import spec
+    from benchmark.archs import glm4_moe_lite as arch
+
+    config = spec.load_json(
+        spec.ROOT / "benchmark/configs/glm47flash-l8.json")
+    cfg = arch.program_config(config)
+    init = functools.partial(arch.init_weights, cfg=cfg, dtype=jnp.bfloat16)
+    memory = jax.jit(init).lower(jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=one_chip)).compile().memory_analysis()
+    assert 10.2e9 < memory.output_size_in_bytes < 10.5e9
+    assert memory.temp_size_in_bytes < 1.5e9
 
 
 def test_gated_delta_net_compiles_for_v5e(one_chip):

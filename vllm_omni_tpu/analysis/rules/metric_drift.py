@@ -83,6 +83,7 @@ def synthetic_engine_snapshot() -> dict:
         "kv_restore_seconds": hist,
         # the state cache of a model with recurrent layers
         "state_slots": hist,
+        "kv_pages_used": hist,
         "state": {"state_slot_allocs_total": 5, "state_resets_total": 1,
                   "prefix_hits_refused_total": 4},
         # serving-curve observability (docs/load_testing.md): tenant-

@@ -255,6 +255,7 @@ class LLMEngine:
             config = dataclasses.replace(config, async_scheduling=False,
                                          unified_batching=False)
         self._refuse_for_recurrent(model_cfg, config, draft_fn)
+        self._refuse_for_latent(model_cfg, config, draft_fn)
         self.config = config
         self.eos_token_id = eos_token_id
         # tiered KV offload: the cold-side store + break-even policy
@@ -280,8 +281,8 @@ class LLMEngine:
                 host_capacity_bytes=config.kv_host_tier_bytes,
                 remote=remote)
             kv_policy = OffloadPolicy.for_model(
-                model_cfg.num_kv_layers, model_cfg.num_kv_heads,
-                model_cfg.head_dim,
+                model_cfg.num_kv_layers, model_cfg.kv_layout.heads,
+                model_cfg.kv_layout.width,
                 jnp.dtype(config.dtype).itemsize,
                 mode=config.kv_offload_policy,
                 quant_mode=config.kv_offload_quant)
@@ -297,6 +298,7 @@ class LLMEngine:
         self._kv_quant = config.kv_cache_dtype == "int8"
         num_pages = config.num_pages
         self._kv_bytes_per_token: Optional[float] = None
+        self._kv_layout = self._kv_bytes_per_token_layer = None
         if isinstance(model_cfg, tfm.TransformerConfig) \
                 and config.worker_type == "ar":
             from vllm_omni_tpu.kvcache.quant import (
@@ -306,26 +308,36 @@ class LLMEngine:
             )
 
             itemsize = jnp.dtype(config.dtype).itemsize
+            # bytes follow the layout the runner allocates (per-head K and
+            # V pools, or a latent layer's one padded row a token)
+            layout = model_cfg.kv_layout
+            self._kv_layout = layout
             if self._kv_quant or config.kv_hbm_budget_bytes is not None:
                 budget = config.kv_hbm_budget_bytes
                 if budget is None:
                     budget = config.num_pages * model_cfg.num_kv_layers * \
-                        page_bytes(model_cfg.num_kv_heads,
-                                   config.page_size, model_cfg.head_dim,
-                                   quantized=False, itemsize=itemsize)
+                        page_bytes(layout.heads,
+                                   config.page_size, layout.width,
+                                   quantized=False, itemsize=itemsize,
+                                   pools=layout.pools)
                 num_pages = pages_for_budget(
-                    budget, model_cfg.num_kv_layers, model_cfg.num_kv_heads,
-                    config.page_size, model_cfg.head_dim,
-                    quantized=self._kv_quant, itemsize=itemsize)
+                    budget, model_cfg.num_kv_layers, layout.heads,
+                    config.page_size, layout.width,
+                    quantized=self._kv_quant, itemsize=itemsize,
+                    pools=layout.pools)
                 logger.info(
                     "kv_cache_dtype=%s: %d pages in a %.1f MiB HBM "
                     "budget (config asked %d at the dense layout)",
                     config.kv_cache_dtype, num_pages, budget / 2**20,
                     config.num_pages)
-            self._kv_bytes_per_token = bytes_per_token(
-                model_cfg.num_kv_layers, model_cfg.num_kv_heads,
-                config.page_size, model_cfg.head_dim,
-                quantized=self._kv_quant, itemsize=itemsize)
+            # a cached token's bytes in ONE layer, declared padding (and
+            # an int8 page's scales) included, and in all of them
+            self._kv_bytes_per_token_layer = bytes_per_token(
+                1, layout.heads, config.page_size, layout.width,
+                quantized=self._kv_quant, itemsize=itemsize,
+                pools=layout.pools)
+            self._kv_bytes_per_token = (
+                model_cfg.num_kv_layers * self._kv_bytes_per_token_layer)
         # prefix caching skips the forward for cached positions, so it
         # cannot coexist with collect_hidden (downstream stages need the
         # hidden row of EVERY prompt position) — thinker-style stages
@@ -536,6 +548,42 @@ class LLMEngine:
                 "a model with recurrent (linear-attention) layers keeps "
                 "per-sequence state beside its KV pages, which these "
                 "mechanisms do not carry yet: " + "; ".join(refused))
+
+    @staticmethod
+    def _refuse_for_latent(model_cfg, config: "EngineConfig",
+                           draft_fn) -> None:
+        """A model with multi-head latent attention keeps one shared pool
+        a layer, a row of latent and rotary key a token, where the other
+        models keep a K and a V pool of per-head rows (docs/kv_cache.md).
+        Pages stay pages (page tables, prefix hits, preemption and chunked
+        prefill carry it unchanged); whatever reads or writes a page's
+        CONTENTS by the (K, V) layout is refused here, by name, before
+        anything is built (ROADMAP, Reach)."""
+        layout = getattr(model_cfg, "kv_layout", None)
+        if layout is None or layout.kind != "latent":
+            return
+        refused = []
+        if config.kv_cache_dtype == "int8":
+            refused.append("kv_cache_dtype int8 (no quantized layout for "
+                           "the latent row)")
+        if config.kv_transfer is not None or config.engine_role != "colocated":
+            refused.append("kv_transfer (the inject_kv / extract_kv payload "
+                           "is (K, V) per head; engine_role "
+                           f"{config.engine_role!r})")
+        if config.kv_offload:
+            refused.append("kv_offload (parking / tiered offload extract "
+                           "the same payload)")
+        if config.tensor_parallel_size > 1:
+            refused.append("tensor_parallel_size > 1 (one latent kv head "
+                           "serves every query head: no sharded layout)")
+        if config.num_speculative_tokens > 0 or draft_fn is not None:
+            refused.append("speculative decoding (the verify path over the "
+                           "latent pool is not built)")
+        if refused:
+            raise ValueError(
+                "a model with multi-head latent attention keeps one latent "
+                "pool a layer, which these mechanisms do not carry yet: "
+                + "; ".join(refused))
 
     # ------------------------------------------------------------- warmup
     def warmup(self, prefill_shapes=(), progress_fn=None) -> int:
@@ -954,6 +1002,12 @@ class LLMEngine:
         kv = getattr(self.scheduler, "kv", None)  # duck-typed fakes
         state_rows = (len(sched_out.prefills) + len(sched_out.decodes)
                       if getattr(kv, "num_state_slots", 0) else 0)
+        if step_metrics is not None and kv is not None and (
+                sched_out.prefills or sched_out.decodes):
+            # pages held while the step ran (live tables and cached
+            # prefixes), every model: what fills the pool
+            step_metrics.kv_pages_used.observe(
+                kv.num_pages - kv.num_free_pages)
         if state_rows and step_metrics is not None:
             # slots in use while the step ran: a sequence that finished in
             # it has given its slot back by now, but was one of its rows
@@ -1117,6 +1171,15 @@ class LLMEngine:
                 str(jnp.dtype(self.config.dtype))),
             "bytes_per_token": self._kv_bytes_per_token,
         }
+        if self._kv_layout is not None:
+            # what a page holds: "kv" (a K and a V pool of per-head rows)
+            # or "latent" (one shared row a token)
+            layout = self._kv_layout
+            snap["kv"].update(
+                layout=layout.kind,
+                row_width=layout.width,
+                row_used=layout.used,
+                bytes_per_token_layer=self._kv_bytes_per_token_layer)
         snap["prefix_cache"] = self.prefix_cache_stats
         if getattr(kv, "num_state_slots", 0):
             # the second kind of cache (docs/kv_cache.md); the

@@ -9,8 +9,9 @@ ledger says *by what*:
 
 - **weights** — model parameters (static after load; the runner sums
   leaf ``nbytes`` once, a metadata walk with no device sync);
-- **kv_pages** — the paged KV cache arrays (static geometry: pages ×
-  page_size × layers × heads × head_dim × itemsize);
+- **kv_pages** — the paged KV cache arrays, summed from the arrays the
+  runner allocated (so by the layout: per-head K and V pools and, when
+  quantized, their scales; or a latent layer's one pool of padded rows);
 - **spec_buffers** — speculative-decode verify buffers when a draft
   head is attached (deterministic estimate from the config);
 - **workspace** — everything the components above can't name: compiled

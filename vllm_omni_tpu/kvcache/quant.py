@@ -190,30 +190,33 @@ def payload_wire_nbytes(payload) -> int:
 
 # --------------------------------------------------------- capacity math
 def page_bytes(num_kv_heads: int, page_size: int, head_dim: int,
-               quantized: bool, itemsize: int = 2) -> int:
-    """HBM bytes of ONE page (k + v halves) for ONE layer, including the
-    per-(head, page) scales on the quantized layout — the unit the page
-    pool is sized in and the ledger accounts."""
+               quantized: bool, itemsize: int = 2, pools: int = 2) -> int:
+    """HBM bytes of ONE page for ONE layer over its ``pools`` arrays (the
+    k and v halves; 1 for a latent layer's shared pool, whose ``head_dim``
+    is the row's width), including the per-(head, page) scales on the
+    quantized layout — the unit the page pool is sized in and the ledger
+    accounts."""
     if quantized:
-        return 2 * num_kv_heads * (page_size * head_dim + 4)
-    return 2 * num_kv_heads * page_size * head_dim * itemsize
+        return pools * num_kv_heads * (page_size * head_dim + 4)
+    return pools * num_kv_heads * page_size * head_dim * itemsize
 
 
 def bytes_per_token(num_layers: int, num_kv_heads: int, page_size: int,
                     head_dim: int, quantized: bool,
-                    itemsize: int = 2) -> float:
+                    itemsize: int = 2, pools: int = 2) -> float:
     """Amortized HBM bytes per cached token across all layers."""
     return num_layers * page_bytes(
-        num_kv_heads, page_size, head_dim, quantized, itemsize
+        num_kv_heads, page_size, head_dim, quantized, itemsize, pools
     ) / page_size
 
 
 def pages_for_budget(budget_bytes: int, num_layers: int,
                      num_kv_heads: int, page_size: int, head_dim: int,
-                     quantized: bool, itemsize: int = 2) -> int:
+                     quantized: bool, itemsize: int = 2,
+                     pools: int = 2) -> int:
     """Page-pool size that fits ``budget_bytes`` of HBM under the given
     layout; with int8 this lands >=1.8x the bf16 count for the same
     budget (the acceptance floor — exactly 2x minus the scale array)."""
     per_page = num_layers * page_bytes(
-        num_kv_heads, page_size, head_dim, quantized, itemsize)
+        num_kv_heads, page_size, head_dim, quantized, itemsize, pools)
     return max(1, int(budget_bytes) // per_page)

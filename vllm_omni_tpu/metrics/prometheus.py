@@ -63,6 +63,11 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "gauge", "KV cache pages allocated to live requests", ("stage",)),
     "kv_page_utilization": (
         "gauge", "Fraction of KV cache pages in use", ("stage",)),
+    # the JSON snapshot's ``kv_pages_used`` histogram (the gauge above
+    # has that name here)
+    "kv_pages_used_per_step": (
+        "histogram", "KV cache pages held, observed once a step",
+        ("stage",)),
     "prefix_cache_hits_total": (
         "counter", "Automatic-prefix-cache hits", ("stage",)),
     "prefix_cache_hit_tokens_total": (
@@ -568,6 +573,9 @@ def render_exposition(summary: dict, engine_snaps: dict,
             exp.sample("kv_pages_used", labels, kv.get("pages_used", 0))
             exp.sample("kv_page_utilization", labels,
                        kv.get("utilization", 0.0))
+        if "kv_pages_used" in snap:
+            exp.histogram("kv_pages_used_per_step", labels,
+                          snap["kv_pages_used"])
         pc = snap.get("prefix_cache")
         if pc and pc.get("enabled"):
             exp.sample("prefix_cache_hits_total", labels, pc.get("hits", 0))

@@ -89,11 +89,15 @@ class ModelGeometry:
         context and hold pages (``num_kv_layers``); a linear-attention
         layer's mixer is counted at an attention layer's parameters, a
         few per cent from its own (the benchmark's arch module counts
-        it exactly, benchmark/archs/)."""
+        it exactly, benchmark/archs/).  A latent-attention model's
+        projections and leading dense layers are counted likewise at an
+        attention layer's and a sparse layer's parameters; its cached
+        bytes are its layout's."""
         h = cfg.hidden_size
         kv_layers = getattr(cfg, "num_kv_layers", cfg.num_layers)
         q_dim = cfg.num_heads * cfg.head_dim
         kv_dim = cfg.num_kv_heads * cfg.head_dim
+        layout = cfg.kv_layout
         attn_params = h * q_dim + 2 * h * kv_dim + q_dim * h
         expert_params = experts = top_k = 0
         if getattr(cfg, "moe", False):
@@ -116,8 +120,11 @@ class ModelGeometry:
             weight_bytes=float(dtype_bytes * (
                 cfg.num_layers * (attn_params + fixed_mlp)
                 + h * cfg.vocab_size)),
-            kv_bytes_per_pos=float(2 * kv_layers * kv_dim
-                                   * dtype_bytes),
+            # what a cached position holds, by the layout (a K and a V
+            # row a kv head, or a latent layer's one row)
+            kv_bytes_per_pos=float(
+                kv_layers * layout.pools * layout.heads * layout.used
+                * dtype_bytes),
             expert_bytes=float(expert_params * dtype_bytes),
             num_experts=experts, experts_per_tok=top_k,
             num_layers=cfg.num_layers,

@@ -86,6 +86,11 @@ LATENCY_BUCKETS_MS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 TOKEN_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                  512.0, 1024.0, 2048.0, 4096.0, 8192.0)
 
+# KV pages held while a step ran (live tables and cached prefixes), up to
+# the pool's size
+KV_PAGE_BUCKETS = (64.0, 256.0, 1024.0, 2048.0, 4096.0, 8192.0, 12288.0,
+                   16384.0, 24576.0, 32768.0, 65536.0, 131072.0)
+
 # state slots in use (a count of sequences, up to max_num_seqs)
 STATE_SLOT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
 
@@ -354,6 +359,10 @@ class EngineStepMetrics:
         # second kind of cache a model with recurrent layers holds
         # (core/kv_cache_manager.py; never observed for other models)
         self.state_slots = Histogram(buckets=STATE_SLOT_BUCKETS)
+        # KV pages held, observed once a step that scheduled rows, for
+        # every model: how full the traffic keeps the pool (a latent
+        # cache's pages and per-head K/V pages count alike)
+        self.kv_pages_used = Histogram(buckets=KV_PAGE_BUCKETS)
         # SLO targets (None = unconfigured leg always passes) + the
         # per-tenant attainment/goodput ledger they gate
         self.slo_ttft_ms: Optional[float] = None
@@ -502,6 +511,7 @@ class EngineStepMetrics:
             "queue_wait_ms": self.queue_wait_ms.snapshot(),
             "intake_ms": self.intake_ms.snapshot(),
             "state_slots": self.state_slots.snapshot(),
+            "kv_pages_used": self.kv_pages_used.snapshot(),
             "saturation": dict(self.saturation),
             "slo": {
                 "targets": {"ttft_ms": self.slo_ttft_ms,

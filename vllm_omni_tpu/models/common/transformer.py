@@ -11,6 +11,7 @@ for the continuous-batching engine.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
@@ -21,6 +22,7 @@ from vllm_omni_tpu.models.common import nn
 from vllm_omni_tpu.ops import (
     apply_partial_rope,
     apply_rope,
+    apply_rope_ref,
     compute_mrope_freqs,
     compute_rope_freqs,
     flash_attention,
@@ -55,6 +57,9 @@ class TransformerConfig:
     # gate weights; reference backbone models/qwen3_omni/qwen3_moe.py).
     # Expert weights are stacked on a leading E axis — shard it over the
     # mesh "ep" axis and GSPMD partitions the expert einsums (EP).
+    # ``moe`` says that the stack HAS sparse layers: every layer from
+    # ``first_k_dense_replace`` on.  What a layer's MLP is, the forward
+    # reads from its weights (``"experts" in layer``), never from here
     moe: bool = False
     num_experts: int = 8
     num_experts_per_tok: int = 2
@@ -62,10 +67,26 @@ class TransformerConfig:
     # norm_topk_prob: Qwen3-MoE renormalizes the kept router weights;
     # the Qwen3-Omni talker keeps raw softmax mass (False)
     moe_renormalize: bool = True
-    # Qwen2-MoE-style always-on shared expert beside the routed ones,
-    # combined through a learned sigmoid gate (the Qwen3-Omni talker LM,
-    # transformers Qwen3OmniMoeTalkerTextSparseMoeBlock); 0 => none
+    # always-on shared expert beside the routed ones, of this width (for
+    # several shared experts, the sum of theirs); 0 => none.  With
+    # ``shared_expert_gate`` it is combined through a learned per-token
+    # sigmoid gate (Qwen2-MoE style: the Qwen3-Omni talker LM,
+    # transformers Qwen3OmniMoeTalkerTextSparseMoeBlock; Qwen3-Next);
+    # without, it is added whole (the DeepSeek-V3 family, GLM-4.7-Flash).
+    # The forward applies the gate where ``shared_gate`` is in the layer
     shared_expert_size: int = 0
+    shared_expert_gate: bool = True
+    # leading layers whose MLP is a dense SwiGLU of ``intermediate_size``
+    # in a stack whose other layers are sparse (first_k_dense_replace)
+    first_k_dense_replace: int = 0
+    # the router's scoring, as ``ops.moe.router_topk`` takes it: "softmax"
+    # over the experts, or independent "sigmoid" scores (noaux_tc), with
+    # ``router_bias`` a learned correction (``e_score_correction_bias``)
+    # added for the selection alone, and the kept weights multiplied by
+    # ``routed_scaling_factor`` after their renormalisation
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
     # "routed" (grouped-matmul top-k dispatch; EP over the mesh "ep" axis
     # when ops.moe.set_ep_mesh was called) | "dense" (oracle: all experts
     # compute all tokens)
@@ -104,6 +125,21 @@ class TransformerConfig:
     # num_experts; picks that live elsewhere add nothing here
     router_experts: int = 0
     expert_offset: int = 0
+    # ---- multi-head latent attention (kv_lora_rank > 0: every attention
+    # layer; DeepSeek-V2's MLA as GLM-4.7-Flash has it).  Queries come
+    # through a low-rank pair (q_lora_rank) as [q_nope | q_rope] a head;
+    # keys and values through one latent row a token, [c_kv | k_rope] of
+    # kv_lora_rank + qk_rope_head_dim numbers, which is ALL the cache
+    # holds (``kv_layout``); ``kv_b`` expands a latent row to a head's
+    # [k_nope | v].  Served absorbed: kv_b's two halves are applied to the
+    # query and to the attention output instead of to every cached row
+    # (``_mla_mixer``).  ``head_dim`` is then qk_nope + qk_rope (the
+    # softmax scale), ``num_kv_heads`` the published count, unused
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # keep the residual stream in float32 between layers: the mixers' and
     # MLPs' out projections leave their matmuls in float32 and are added
     # unrounded; norm outputs are cast to the weights' dtype, so every
@@ -136,7 +172,30 @@ class TransformerConfig:
 
     @property
     def rotary_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_rope_head_dim
         return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def kv_layout(self) -> "KVLayout":
+        """What one attention layer's paged cache holds a token."""
+        if self.kv_lora_rank:
+            row = self.kv_lora_rank + self.qk_rope_head_dim
+            return KVLayout("latent", 1, -(-row // 128) * 128, 1, row)
+        return KVLayout("kv", self.num_kv_heads, self.head_dim, 2,
+                        self.head_dim)
+
+    @property
+    def attn_kwargs(self) -> dict:
+        """What the paged attention ops are told beyond their operands:
+        nothing for per-head K and V pages (the scale is the head's); for
+        a latent pool the scale of the un-absorbed head and the lanes of
+        a row that are the value."""
+        if not self.kv_lora_rank:
+            return {}
+        return {"scale": (self.qk_nope_head_dim
+                          + self.qk_rope_head_dim) ** -0.5,
+                "v_dim": self.kv_lora_rank}
 
     @property
     def linear_conv_dim(self) -> int:
@@ -177,6 +236,18 @@ class TransformerConfig:
         )
 
 
+class KVLayout(NamedTuple):
+    """The paged cache of one attention layer, as ``init_kv_cache``
+    allocates it and the engine accounts it (docs/kv_cache.md): ``pools``
+    arrays of ``[heads, pages, page_size, width]``."""
+
+    kind: str    # "kv": a K and a V pool of per-head rows | "latent"
+    heads: int   # kv heads a pool holds
+    width: int   # lanes a row takes, declared padding included
+    pools: int   # 2 (K, V) | 1 (the row is key and value at once)
+    used: int    # lanes of a row that hold numbers (width - padding)
+
+
 def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
     keys = jax.random.split(key, cfg.num_layers + 3)
     params = {
@@ -210,6 +281,11 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
         }
         if kind == "linear_attention":
             layer["linear_attn"] = _gdn_init(k, cfg, dtype)
+        elif cfg.kv_lora_rank:
+            layer["mla"] = _mla_init(k, cfg, dtype)
+            layer["o_proj"] = nn.linear_init(
+                k[3], cfg.num_heads * cfg.v_head_dim, cfg.hidden_size,
+                bias=False, dtype=dtype)
         else:
             # gated attention doubles q_proj: a head's columns are [q | gate]
             q_out = q_dim * (2 if cfg.attn_output_gate else 1)
@@ -219,7 +295,7 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
                 "v_proj": nn.linear_init(k[2], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
                 "o_proj": nn.linear_init(k[3], q_dim, cfg.hidden_size, bias=False, dtype=dtype),
             })
-        if cfg.moe:
+        if cfg.moe and i >= cfg.first_k_dense_replace:
             e = cfg.num_experts
             inter = cfg.moe_intermediate_size or cfg.intermediate_size
             scale_in = 1.0 / (cfg.hidden_size ** 0.5)
@@ -229,6 +305,15 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
                 k[5], cfg.hidden_size, cfg.router_experts or e, bias=False,
                 dtype=dtype
             )
+            if cfg.router_bias:
+                # the learned correction of the selection; float32 as the
+                # checkpoint keeps it.  The published initialiser writes
+                # zeros: a little noise here keeps "selected on s + b,
+                # weighted by s" visible to every comparison
+                layer["router"]["e_bias"] = jax.random.uniform(
+                    jax.random.fold_in(k[5], 1),
+                    (cfg.router_experts or e,), jnp.float32,
+                    minval=-0.05, maxval=0.05)
             # stacked expert weights: leading E axis is the EP shard axis
             layer["experts"] = {
                 "gate_up": jax.random.uniform(
@@ -250,8 +335,9 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
                         ks2, cfg.shared_expert_size, cfg.hidden_size,
                         bias=False, dtype=dtype),
                 }
-                layer["shared_gate"] = nn.linear_init(
-                    ks3, cfg.hidden_size, 1, bias=False, dtype=dtype)
+                if cfg.shared_expert_gate:
+                    layer["shared_gate"] = nn.linear_init(
+                        ks3, cfg.hidden_size, 1, bias=False, dtype=dtype)
         else:
             layer["gate_up"] = nn.linear_init(
                 k[4], cfg.hidden_size, 2 * cfg.intermediate_size, bias=False, dtype=dtype
@@ -259,11 +345,38 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
             layer["down"] = nn.linear_init(
                 k[5], cfg.intermediate_size, cfg.hidden_size, bias=False, dtype=dtype
             )
-        if cfg.qk_norm and kind == "full_attention":
+        if cfg.qk_norm and kind == "full_attention" and not cfg.kv_lora_rank:
             layer["q_norm"] = norm_init(kn[2], cfg.head_dim)
             layer["k_norm"] = norm_init(kn[3], cfg.head_dim)
         params["layers"].append(layer)
     return params
+
+
+def _mla_init(k, cfg: TransformerConfig, dtype):
+    """One latent-attention mixer's weights beside ``o_proj``, under the
+    checkpoint's names without their suffixes (``q_a_proj``,
+    ``q_a_layernorm``, ``q_b_proj``, ``kv_a_proj_with_mqa``,
+    ``kv_a_layernorm``, ``kv_b_proj``).  ``kv_b`` stays one matrix
+    [kv_lora_rank, H * (qk_nope + v)], a head's columns [k_nope | v]; the
+    forward cuts it into the two halves it absorbs."""
+    if not cfg.q_lora_rank:
+        raise ValueError("latent attention without q_lora_rank (a plain "
+                         "q_proj) is not built")
+    h, r = cfg.hidden_size, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return {
+        "q_a": nn.linear_init(k[0], h, cfg.q_lora_rank, bias=False,
+                              dtype=dtype),
+        "q_a_norm": nn.rmsnorm_init(cfg.q_lora_rank, dtype),
+        "q_b": nn.linear_init(k[1], cfg.q_lora_rank, cfg.num_heads * qk,
+                              bias=False, dtype=dtype),
+        "kv_a": nn.linear_init(k[2], h, r + cfg.qk_rope_head_dim,
+                               bias=False, dtype=dtype),
+        "kv_a_norm": nn.rmsnorm_init(r, dtype),
+        "kv_b": nn.linear_init(
+            k[5], r, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+            bias=False, dtype=dtype),
+    }
 
 
 def _gdn_init(k, cfg: TransformerConfig, dtype):
@@ -338,24 +451,38 @@ def _moe_mlp_dense(layer, cfg: TransformerConfig, x):
     mask.  Kept as the numerics oracle for the routed path (and the
     GSPMD fallback when neither routing mode applies); a k/E FLOP waste
     at real geometries (VERDICT r1 weak#4)."""
+    from vllm_omni_tpu.ops import moe as moe_ops
+
     t = x.shape[0]
-    router_logits = x @ layer["router"]["w"]  # [T, E]
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    topk_w, topk_idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    if cfg.moe_renormalize:
-        topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
+    topk_idx, topk_w = moe_ops.router_topk(
+        x, layer["router"]["w"], cfg.num_experts_per_tok,
+        cfg.moe_renormalize, **_router_kwargs(layer, cfg))
     # [T, E] combine weights (zero for non-selected experts)
-    combine = jnp.zeros_like(probs).at[
+    combine = jnp.zeros((t, layer["router"]["w"].shape[-1]),
+                        jnp.float32).at[
         jnp.arange(t)[:, None], topk_idx
     ].set(topk_w)
     held = layer["experts"]["gate_up"].shape[0]
-    if held != probs.shape[-1]:  # an expert share: the held columns only
+    if held != combine.shape[-1]:  # an expert share: the held columns only
         combine = jax.lax.dynamic_slice_in_dim(
             combine, cfg.expert_offset, held, axis=1)
     h = jnp.einsum("th,ehf->etf", x, layer["experts"]["gate_up"])
     h = silu_mul(h)
     y = jnp.einsum("etf,efh->eth", h, layer["experts"]["down"])
     return jnp.einsum("eth,te->th", y, combine.astype(x.dtype))
+
+
+def _router_kwargs(layer, cfg: TransformerConfig) -> dict:
+    """The router's scoring as ``ops.moe.router_topk`` takes it; {} for
+    the plain softmax router.  A sigmoid router's logits stay in float32,
+    as the published implementation computes them."""
+    if cfg.router_scoring == "softmax" and "e_bias" not in layer["router"] \
+            and cfg.routed_scaling_factor == 1.0:
+        return {}
+    return {"scoring": cfg.router_scoring,
+            "bias": layer["router"].get("e_bias"),
+            "scale": cfg.routed_scaling_factor,
+            "float32_logits": cfg.router_scoring == "sigmoid"}
 
 
 def _moe_mlp(layer, cfg: TransformerConfig, x):
@@ -371,6 +498,14 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
         from vllm_omni_tpu.ops import moe as moe_ops
 
         mesh = moe_ops.ep_mesh()
+        router = _router_kwargs(layer, cfg)
+        shared_out = layer["router"]["w"].shape[-1] != \
+            layer["experts"]["gate_up"].shape[0]
+        if router and (mesh is not None or shared_out):
+            raise NotImplementedError(
+                "a sigmoid / correction-bias / scaled router is routed on "
+                "one shard only: the expert-parallel and expert-share "
+                "dispatches take the plain softmax router")
         if mesh is not None:
             ep_fn = (moe_ops.routed_moe_ep_a2a
                      if cfg.moe_dispatch == "a2a"
@@ -380,8 +515,7 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
                 layer["experts"]["down"], cfg.num_experts_per_tok, mesh,
                 renormalize=cfg.moe_renormalize,
             )
-        elif layer["router"]["w"].shape[-1] != \
-                layer["experts"]["gate_up"].shape[0]:
+        elif shared_out:
             # the chip's share of an expert-parallel deployment: route
             # over the router's whole width, compute what the experts
             # held here give, no exchange
@@ -394,21 +528,24 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
             out = moe_ops.routed_moe(
                 x, layer["router"]["w"], layer["experts"]["gate_up"],
                 layer["experts"]["down"], cfg.num_experts_per_tok,
-                renormalize=cfg.moe_renormalize,
+                renormalize=cfg.moe_renormalize, **router,
             )
     if "shared_expert" in layer:
-        # always-on shared expert, sigmoid-gated per token
+        # always-on shared expert: sigmoid-gated per token where the
+        # layer has the gate, added whole where it has none
         se = nn.linear(layer["shared_expert"]["down"],
                        silu_mul(nn.linear(layer["shared_expert"]["gate_up"],
                                           x)))
-        gate = jax.nn.sigmoid(
-            nn.linear(layer["shared_gate"], x).astype(jnp.float32))
-        out = out + (gate.astype(se.dtype) * se)
+        if "shared_gate" in layer:
+            gate = jax.nn.sigmoid(
+                nn.linear(layer["shared_gate"], x).astype(jnp.float32))
+            se = gate.astype(se.dtype) * se
+        out = out + se
     return out.reshape(*lead, out.shape[-1])
 
 
 def _mlp(layer, cfg: TransformerConfig, x):
-    if cfg.moe:
+    if "experts" in layer:
         return _moe_mlp(layer, cfg, x)
     return nn.linear(layer["down"], silu_mul(nn.linear(layer["gate_up"], x)))
 
@@ -515,6 +652,68 @@ def _gdn_mixer(la, cfg: TransformerConfig, h, recur):
         return _out_proj(cfg, o.reshape(t, -1), la["out_proj"]["w"])
 
 
+def _join_lanes(parts, width: int):
+    """``parts`` side by side on the last axis, zeros after them up to
+    ``width``.  A leading part that is whole 128-lane tiles is joined as
+    it lies (an aligned ``concatenate``); what follows it is put together
+    in blocks as wide as the pieces' common divisor, on the axis before
+    the last, then reshaped: a ``concatenate`` of a 64-wide piece and
+    another on the last axis is the unaligned join that aborts the XLA
+    TPU compiler (``ops/rope.py apply_rope_ref``), and cutting the
+    512-wide latent into 64-lane blocks as well cost a relayout of it
+    (4% of the cell's device time, PERF.md, PR 32)."""
+    if len(parts) > 1 and parts[0].shape[-1] % 128 == 0 \
+            and (width - parts[0].shape[-1]) % 128 == 0:
+        tail = _join_lanes(parts[1:], width - parts[0].shape[-1])
+        return jnp.concatenate([parts[0], tail], axis=-1)
+    g = math.gcd(width, *(p.shape[-1] for p in parts))
+    lead = parts[0].shape[:-1]
+    blocks = [p.reshape(*lead, p.shape[-1] // g, g) for p in parts]
+    pad = width - sum(p.shape[-1] for p in parts)
+    if pad:
+        blocks.append(jnp.zeros((*lead, pad // g, g), parts[0].dtype))
+    return jnp.concatenate(blocks, axis=-2).reshape(*lead, width)
+
+
+def _mla_mixer(layer, cfg: TransformerConfig, h, cos, sin, attend):
+    """Multi-head latent attention on normed rows h [T, hidden], in its
+    absorbed form: ``kv_b`` cut a head into ``W_UK`` [r, nope] and
+    ``W_UV`` [r, v], the query is taken into the latent space (``q_lat =
+    q_nope W_UK^T``), attention runs over the cached rows themselves —
+    ONE kv head whose key is ``[c_kv | k_rope]`` and whose value is the
+    key's first ``r`` lanes, every query head on it — and the output
+    comes back through ``W_UV``.  The same numbers as expanding every
+    cached row to per-head keys and values
+    (``q_nope . (c W_UK) = (q_nope W_UK^T) . c``), without a product of
+    the context's size.  ``attend(q [T, H, W], row [T, 1, W], None)``
+    writes the step's rows and attends (the ``attend`` of the forward,
+    its value operand absent) and returns [T, H, r].  Every product with
+    ``kv_b`` runs under ``omni.mla_up``."""
+    m = layer["mla"]
+    t = h.shape[0]
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    width = cfg.kv_layout.width
+    with jax.named_scope("omni.proj"):
+        c_q = _rms(cfg, nn.linear(m["q_a"], h), m["q_a_norm"]).astype(h.dtype)
+        q = nn.linear(m["q_b"], c_q).reshape(t, -1, dn + cfg.qk_rope_head_dim)
+        kv = nn.linear(m["kv_a"], h)
+        c_kv = _rms(cfg, kv[:, :r], m["kv_a_norm"]).astype(h.dtype)
+        # rotate-half over all of the rotary part; the one rotary key is
+        # every head's (elementwise work XLA fuses: no kernel at width 64)
+        q_rope = apply_rope_ref(q[..., dn:], cos, sin)
+        k_rope = apply_rope_ref(kv[:, None, r:], cos, sin)
+        row = _join_lanes([c_kv[:, None, :], k_rope], width)
+    w_kvb = m["kv_b"]["w"].reshape(r, -1, dn + cfg.v_head_dim)
+    with jax.named_scope("omni.mla_up"):
+        q_lat = jnp.einsum("thn,rhn->thr", q[..., :dn], w_kvb[..., :dn])
+        q_full = _join_lanes([q_lat, q_rope], width)
+    o_lat = attend(q_full, row, None).reshape(t, -1, r)
+    with jax.named_scope("omni.mla_up"):
+        o = jnp.einsum("thr,rhv->thv", o_lat, w_kvb[..., dn:])
+    with jax.named_scope("omni.proj"):
+        return _out_proj(cfg, o.reshape(t, -1), layer["o_proj"]["w"])
+
+
 def _out_proj(cfg: TransformerConfig, o, w):
     """A mixer's output projection, in the residual stream's precision."""
     if cfg.residual_in_fp32:
@@ -527,9 +726,13 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
     residual.  In a softmax-attention layer the mixer is qkv+rope →
     ``attend(q, k, v)``, which supplies the attention variant (dense
     causal / cached-context chunked / paged decode) and returns o with
-    leading dims matching ``x``'s; in a Gated DeltaNet layer
-    (``"linear_attn"`` in the layer's weights) ``attend`` is the ``recur``
-    of ``_gdn_mixer``.  One body serves every forward so the variants
+    leading dims matching ``x``'s; in a latent-attention layer (``"mla"``
+    in the layer's weights) the same ``attend`` gets the absorbed query,
+    the latent row as the key and no value (``_mla_mixer``); in a Gated
+    DeltaNet layer (``"linear_attn"``) ``attend`` is the ``recur`` of
+    ``_gdn_mixer``.  The MLP is the sparse one where the layer holds
+    ``experts``, a dense SwiGLU where it does not.  One body serves every
+    forward so the variants
     cannot silently diverge."""
     b = x.shape[:-1]
     act = layer["input_norm"]["w"].dtype
@@ -540,6 +743,9 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
     if "linear_attn" in layer:
         o = _gdn_mixer(layer["linear_attn"], cfg,
                        h.reshape(-1, h.shape[-1]), attend).reshape(*b, -1)
+    elif "mla" in layer:
+        o = _mla_mixer(layer, cfg, h.reshape(-1, h.shape[-1]), cos, sin,
+                       attend).reshape(*b, -1)
     else:
         with jax.named_scope("omni.proj"):
             q, k, v, gate = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
@@ -559,7 +765,7 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
             o = jax.lax.psum(o, cfg.tp_axis)
         x = x + o
         h = _rms(cfg, x, layer["post_norm"]).astype(act)
-    with jax.named_scope("omni.moe" if cfg.moe else "omni.mlp"):
+    with jax.named_scope("omni.moe" if "experts" in layer else "omni.mlp"):
         y = _mlp(layer, cfg, h)
         if cfg.tp_axis is not None:
             y = jax.lax.psum(y, cfg.tp_axis)
@@ -577,7 +783,9 @@ class HybridCaches(NamedTuple):
 
 def _layer_caches(params, kv_caches, state_caches):
     """(layer, its cache) down the stack: the (k, v) pages of a
-    softmax-attention layer, the (conv, recurrent) state slots of a Gated
+    softmax-attention layer ((latent pool, None) of a latent-attention
+    layer: the write and the kernels take the absent value as "read it
+    from the key's row"), the (conv, recurrent) state slots of a Gated
     DeltaNet layer.  ``kv_caches`` holds one entry for each attention
     layer only, ``state_caches`` one for each GDN layer."""
     kv, st = iter(kv_caches), iter(state_caches or ())
@@ -658,6 +866,16 @@ def forward_hidden(
 
     def attend(q, k, v):
         with jax.named_scope("omni.attn"):
+            if v is None:
+                # a latent layer, absorbed: one kv head whose row is key
+                # and value; the latent part of the output is kept
+                o = flash_attention(
+                    q.reshape(b, s, -1, q.shape[-1]),
+                    k.reshape(b, s, 1, k.shape[-1]),
+                    k.reshape(b, s, 1, k.shape[-1]),
+                    causal=True, kv_mask=attn_mask,
+                    scale=cfg.attn_kwargs["scale"])
+                return o[..., :cfg.kv_lora_rank]
             return flash_attention(
                 q.reshape(b, s, -1, cfg.head_dim),
                 k.reshape(b, s, -1, cfg.head_dim),
@@ -777,7 +995,7 @@ def forward_unified(
             with jax.named_scope("omni.attn"):
                 return ragged_paged_attention(
                     q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
-                    seq_lens, num_seqs,
+                    seq_lens, num_seqs, **cfg.attn_kwargs,
                 )
 
         # a sequence starts where its context is this step's tokens alone
@@ -829,7 +1047,8 @@ def forward_decode(
             new_caches.append((k_cache, v_cache))
             with jax.named_scope("omni.attn"):
                 return paged_attention(
-                    q, k_cache, v_cache, block_tables, context_lens
+                    q, k_cache, v_cache, block_tables, context_lens,
+                    **cfg.attn_kwargs,
                 )
 
         recur = _stateful_recur(

@@ -168,6 +168,11 @@ _AR_MODELS: dict[str, _Entry] = {
     "Qwen3NextForCausalLM": _Entry(
         "vllm_omni_tpu.models.qwen3_next", "real_factory"
     ),
+    # multi-head latent attention over a latent paged cache, a leading
+    # dense layer, sigmoid-routed experts (GLM-4.7-Flash; docs/kv_cache.md)
+    "Glm4MoeLiteForCausalLM": _Entry(
+        "vllm_omni_tpu.models.glm4_moe_lite", "real_factory"
+    ),
 }
 
 

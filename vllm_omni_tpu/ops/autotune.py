@@ -107,6 +107,7 @@ def auto_ragged_blocks(
     q_itemsize: int = 4,
     vmem_cap_bytes: int = RAGGED_VMEM_CAP,
     quantized: bool = False,
+    kv_pools: int = 2,
 ) -> tuple[int, int, int]:
     """(q_block, pages_per_block, dma_slots) for the ragged
     paged-attention kernel.
@@ -155,7 +156,8 @@ def auto_ragged_blocks(
         # f32 score block and its live temporaries (mask, p, p in the
         # matmul dtype)
         need += 4 * rows * kvb * 4
-        need += 2 * slots * kvb * head_dim * kv_itemsize
+        # K and V buffers, or the one buffer of a shared pool
+        need += kv_pools * slots * kvb * head_dim * kv_itemsize
         if quantized:
             # the float staging copy of the block being consumed (k, v)
             need += 2 * kvb * head_dim * 4

@@ -55,23 +55,42 @@ def ep_mesh():
 
 
 def router_topk(x, router_w, num_experts_per_tok: int,
-                renormalize: bool = True, float32_logits: bool = False):
-    """Softmax router -> top-k (idx [T,k], weights [T,k]).
+                renormalize: bool = True, float32_logits: bool = False,
+                scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """Router -> top-k (idx [T,k], weights [T,k]).
 
-    ``renormalize`` divides the kept weights by their sum (Qwen3-MoE
-    norm_topk_prob=True); the Qwen3-Omni talker keeps the raw softmax
-    mass (norm_topk_prob=False).  ``float32_logits`` leaves the router's
-    matmul in float32 instead of rounding its output to the activations'
-    dtype first: the k-th and (k+1)-th of 512 logits lie a few bfloat16
-    steps apart, and a rounding that swaps them swaps an expert."""
+    ``scoring`` is how a logit becomes an expert's score: ``"softmax"``
+    over the router's width (Qwen3-MoE) or an independent ``"sigmoid"``
+    (the ``noaux_tc`` routers).  ``bias`` ([E] float32, or None) is a
+    learned correction added to the scores for the SELECTION only: the
+    k largest of ``score + bias`` are picked, and their weights are the
+    unbiased scores.  ``renormalize`` divides the kept weights by their
+    sum (norm_topk_prob=True; the Qwen3-Omni talker keeps the raw softmax
+    mass), and ``scale`` multiplies them after that (routed_scaling_factor).
+    ``float32_logits`` leaves the router's matmul in float32 instead of
+    rounding its output to the activations' dtype first: the k-th and
+    (k+1)-th of 512 logits lie a few bfloat16 steps apart, and a rounding
+    that swaps them swaps an expert."""
     if float32_logits:
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
     else:
         logits = x @ router_w  # [T, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topk_w, topk_idx = jax.lax.top_k(probs, num_experts_per_tok)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    else:
+        raise ValueError(f"router scoring {scoring!r}: softmax or sigmoid")
+    if bias is None:
+        topk_w, topk_idx = jax.lax.top_k(scores, num_experts_per_tok)
+    else:
+        _, topk_idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                    num_experts_per_tok)
+        topk_w = jnp.take_along_axis(scores, topk_idx, axis=-1)
     if renormalize:
         topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
+    if scale != 1.0:
+        topk_w = topk_w * scale
     return topk_idx, topk_w
 
 
@@ -89,13 +108,14 @@ def routed_moe(
     down: jax.Array,       # [E, inter, hidden]
     num_experts_per_tok: int,
     renormalize: bool = True,
+    **router,  # scoring / bias / scale / float32_logits of ``router_topk``
 ) -> jax.Array:
     """Top-k routed MoE on one shard: sort pairs by expert, grouped
     matmul, weighted scatter-add."""
     t, hidden = x.shape
     e = gate_up.shape[0]
     k = num_experts_per_tok
-    topk_idx, topk_w = router_topk(x, router_w, k, renormalize)
+    topk_idx, topk_w = router_topk(x, router_w, k, renormalize, **router)
 
     flat_e = topk_idx.reshape(-1)                    # [T*k]
     flat_w = topk_w.reshape(-1)                      # [T*k]

@@ -15,6 +15,17 @@ jit, so every op here branches statically on ``isinstance(half, tuple)``
 and the kernels dequantize in-register after the page DMA (the math
 stays f32; only HBM bytes shrink ~2x).
 
+Shared layout (a latent cache, ``v_cache is None``): a layer holds ONE
+pool ``[1, P, page_size, W]`` whose row is at once the key (all ``W``
+lanes) and the value (its first ``v_dim`` lanes) of the one kv head every
+query head attends: multi-head latent attention in its absorbed form
+(``W`` = the latent row and the shared rotary key, padded to a multiple of
+128 lanes with zeros that the query's zeros meet).  Every op here takes it
+as the same (k, v) pair with ``v`` absent: the write moves one pool's
+pages, the kernels bring a page in once and read both operands from that
+tile (a second copy of the page would double the bytes of a memory-bound
+kernel).  The row's width and ``v_dim`` are shapes the ops read.
+
 Three ops:
 - ``write_kv_cache``  — a step's new K/V rows into the paged cache at
   their flat slots, in the pool's own layout and in place (PR 31).  On
@@ -72,14 +83,21 @@ def init_kv_cache(
     head_dim: int,
     dtype=jnp.bfloat16,
     quantized: bool = False,
+    shared: bool = False,
 ):
-    """Allocate per-layer (k, v) caches.
+    """Allocate per-layer (k, v) caches; ``shared`` allocates the one-pool
+    layout ``(pool, None)`` whose rows are key and value at once
+    (``head_dim`` is then the row's whole width).
 
     ``quantized`` allocates the int8 layout: each half is
     ``(data int8, scale f32 [Hkv, P])``; zero scales mean "never
     written" and dequantize to the same zeros the bf16 pool starts
     with."""
     shape = (num_kv_heads, num_pages, page_size, head_dim)
+    if shared:
+        if quantized:
+            raise ValueError("the shared (latent) layout has no int8 form")
+        return [(jnp.zeros(shape, dtype), None) for _ in range(num_layers)]
     if quantized:
         return [
             ((jnp.zeros(shape, jnp.int8),
@@ -203,16 +221,17 @@ _WRITE_MAX_BLOCK = 128
 _WRITE_MAX_TOKENS = 32768
 
 
-def _write_block(cache) -> int:
+def _write_block(cache, pools: int = 2) -> int:
     """Tokens a grid step of the page-write kernel takes at this pool's
-    geometry: the most, in sixteens up to ``_WRITE_MAX_BLOCK``, whose
+    geometry (``pools`` of them a layer: K and V, or the one shared
+    pool): the most, in sixteens up to ``_WRITE_MAX_BLOCK``, whose
     buffers fit ``_WRITE_VMEM_BYTES`` together; under 16, none do."""
     hkv, _, ps, d = cache.shape
     item = cache.dtype.itemsize
-    # a token of a block: a page buffer of K and of V, its row of the
+    # a token of a block: a page buffer of each pool, its row of the
     # float32 ``rows``, its row of the double-buffered ``new`` block
-    per_token = 2 * hkv * d * (ps * item + 4 + 2 * item)
-    margins = 2 * hkv * d * 2 * ps * 4  # of ``rows``
+    per_token = pools * hkv * d * (ps * item + 4 + 2 * item)
+    margins = pools * hkv * d * 2 * ps * 4  # of ``rows``
     fit = (_WRITE_VMEM_BYTES - margins) // per_token
     return min(fit // 16 * 16, _WRITE_MAX_BLOCK)
 
@@ -279,44 +298,42 @@ def _write_pages_kernel(
     held_ref,     # [T]
     n_pages_ref,  # [T / block]
     # inputs
-    new_ref,  # [2*Hkv, block, D] VMEM: the block's K rows, then its V rows
-    _k_in,    # the pools, aliased to the outputs: read through those
-    _v_in,
-    # outputs
-    k_hbm,  # [Hkv, P, page, D] ANY/HBM
-    v_hbm,
-    # scratch
-    pages,  # [block, 2*Hkv, page, D]: a distinct page's K half and V half
-    # [2*Hkv, D / 128, page + block + page, 128] f32: the block's rows
-    # between two margins, so that a page-high window never leaves them,
-    # in columns one vreg wide (Mosaic loads a window that starts off the
-    # sublane tiling only from a buffer 128 lanes wide)
-    rows,
-    sems,  # DMA [2]: pages in, pages out
-    *,
+    new_ref,  # [pools*Hkv, block, D] VMEM: the block's rows, pool by pool
+    # then the ``pools`` pools (aliased to the outputs: read through
+    # those), the outputs [Hkv, P, page, D] ANY/HBM (K and V, or the one
+    # shared pool), and scratch:
+    # pages [block, pools*Hkv, page, D]: a distinct page's part of each pool
+    # rows [pools*Hkv, D / 128, page + block + page, 128] f32: the block's
+    #   rows between two margins, so that a page-high window never leaves
+    #   them, in columns one vreg wide (Mosaic loads a window that starts
+    #   off the sublane tiling only from a buffer 128 lanes wide)
+    # sems DMA [2]: pages in, pages out
+    *refs,
     page_size: int,
     block: int,
+    pools: int,
 ):
     # (lax primitives throughout, as in ``_write_plan``: set-up traces this)
     lax = jax.lax
+    pools_hbm = refs[pools:2 * pools]
+    pages, rows, sems = refs[2 * pools:]
     base = pl.program_id(0) * block
     n_pages = n_pages_ref[pl.program_id(0)]
-    hkv = pages.shape[1] // 2
+    hkv = pages.shape[1] // pools
 
     def move_pages(out: bool):
         def start(r, carry):
             page, buffer = held_ref[base + r], pages.at[r]
-            for hbm, half in ((k_hbm, buffer.at[:hkv]),
-                              (v_hbm, buffer.at[hkv:])):
-                pool = hbm.at[:, page]
+            for i, hbm in enumerate(pools_hbm):
+                pool, part = hbm.at[:, page], buffer.at[i * hkv:(i + 1) * hkv]
                 pltpu.make_async_copy(
-                    *((half, pool) if out else (pool, half)),
+                    *((part, pool) if out else (pool, part)),
                     sems.at[int(out)]).start()
             return carry
 
         def wait(_, carry):
             # a DMA semaphore counts bytes: any whole page buffer sizes
-            # the wait for one page's two halves
+            # the wait for one page's part of every pool
             pltpu.make_async_copy(
                 pages.at[0], pages.at[0], sems.at[int(out)]).wait()
             return carry
@@ -331,7 +348,7 @@ def _write_pages_kernel(
         for c, lanes in enumerate(lane_cols):
             rows[:, c, page_size:page_size + block, :] = (
                 lax.convert_element_type(new_ref[:, :, lanes], jnp.float32))
-        tile = (2 * hkv, page_size, 128)
+        tile = (pools * hkv, page_size, 128)
         row = lax.broadcasted_iota(jnp.int32, tile, 1)
 
         def merge(j, carry):
@@ -362,50 +379,56 @@ def _write_pages_kernel(
 @jax.jit
 def _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping):
     """The Pallas page write: each block of tokens brings the pages it
-    touches into VMEM (``[Hkv, page, D]`` of K and of V, once each), lays
-    its rows into them and writes them back, the pools aliased from input
-    to output.  Blocks run in order and a block's pages are back before
-    the next block reads, so a page that two blocks touch keeps both
-    blocks' rows and a slot written twice keeps the later row."""
-    hkv, _, ps, d = k_cache.shape
+    touches into VMEM (``[Hkv, page, D]`` of every pool of the layer: K
+    and V, or the one shared pool where ``v_cache`` is None; once each),
+    lays its rows into them and writes them back, the pools aliased from
+    input to output.  Blocks run in order and a block's pages are back
+    before the next block reads, so a page that two blocks touch keeps
+    both blocks' rows and a slot written twice keeps the later row.
+    Returns the pools it was given, as a tuple."""
+    caches = (k_cache,) if v_cache is None else (k_cache, v_cache)
+    news = (k_new,) if v_cache is None else (k_new, v_new)
+    pools = len(caches)
+    hkv, _, ps, d = caches[0].shape
     t = slot_mapping.shape[0]
-    block = min(_write_block(k_cache), -(-t // 16) * 16)
+    block = min(_write_block(caches[0], pools), -(-t // 16) * 16)
     pad = -t % block
-    lax, dtype = jax.lax, k_cache.dtype
+    lax, dtype = jax.lax, caches[0].dtype
     slots = lax.pad(lax.convert_element_type(slot_mapping, jnp.int32),
                     jnp.int32(-1), ((0, pad, 0),))
-    new = lax.transpose(lax.pad(  # [T, 2*Hkv, D] -> [2*Hkv, T + pad, D]
-        lax.concatenate([lax.convert_element_type(k_new, dtype),
-                         lax.convert_element_type(v_new, dtype)], 1),
+    new = lax.transpose(lax.pad(  # [T, pools*Hkv, D] -> [.., T + pad, D]
+        lax.concatenate([lax.convert_element_type(n, dtype) for n in news],
+                        1),
         jnp.zeros((), dtype), ((0, pad, 0), (0, 0, 0), (0, 0, 0))),
         (1, 0, 2))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=((t + pad) // block,),
-        in_specs=[pl.BlockSpec((2 * hkv, block, d), lambda i, *_: (0, i, 0),
-                               memory_space=pltpu.VMEM),
-                  pool_spec, pool_spec],
-        out_specs=[pool_spec, pool_spec],
+        in_specs=[pl.BlockSpec((pools * hkv, block, d),
+                               lambda i, *_: (0, i, 0),
+                               memory_space=pltpu.VMEM)]
+        + [pool_spec] * pools,
+        out_specs=[pool_spec] * pools,
         scratch_shapes=[
-            pltpu.VMEM((block, 2 * hkv, ps, d), dtype),
-            pltpu.VMEM((2 * hkv, d // 128, block + 2 * ps, 128),
+            pltpu.VMEM((block, pools * hkv, ps, d), dtype),
+            pltpu.VMEM((pools * hkv, d // 128, block + 2 * ps, 128),
                        jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     return tuple(pl.pallas_call(
-        functools.partial(_write_pages_kernel, page_size=ps, block=block),
+        functools.partial(_write_pages_kernel, page_size=ps, block=block,
+                          pools=pools),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, dtype),
-                   jax.ShapeDtypeStruct(v_cache.shape, dtype)],
-        # operands count the five prefetched plans: the pools are 6 and 7
-        input_output_aliases={6: 0, 7: 1},
+        out_shape=[jax.ShapeDtypeStruct(c.shape, dtype) for c in caches],
+        # operands count the five prefetched plans: the pools start at 6
+        input_output_aliases={6 + i: i for i in range(pools)},
         interpret=interpret_flag(),
-    )(slots, *_write_plan(slots, ps, block), new, k_cache, v_cache))
+    )(slots, *_write_plan(slots, ps, block), new, *caches))
 
 
-def write_tiling_reason(cache, tokens: int = 1) -> str:
+def write_tiling_reason(cache, tokens: int = 1, pools: int = 2) -> str:
     """Why the page-write kernel cannot take a step of ``tokens`` rows
     into this pool ("" when it can): the paged kernels' geometry; a page
     that is whole packed tiles of the pool's dtype (16 rows of bfloat16,
@@ -418,8 +441,8 @@ def write_tiling_reason(cache, tokens: int = 1) -> str:
         return paged_tiling_reason(d, ps)
     if ps % rows:
         return f"page_size {ps} % {rows} != 0 ({cache.dtype.name})"
-    if _write_block(cache) < 16:
-        return (f"16 pages of [{2 * hkv}, {ps}, {d}] {cache.dtype.name} "
+    if _write_block(cache, pools) < 16:
+        return (f"16 pages of [{pools * hkv}, {ps}, {d}] {cache.dtype.name} "
                 f"> {_WRITE_VMEM_BYTES} bytes of VMEM")
     if tokens > _WRITE_MAX_TOKENS:
         return f"{tokens} tokens a step > {_WRITE_MAX_TOKENS} (SMEM plans)"
@@ -429,9 +452,9 @@ def write_tiling_reason(cache, tokens: int = 1) -> str:
 @jax.jit
 def write_kv_cache(
     k_cache,  # [Hkv, P, page, D] or quantized (data, scale) tuple
-    v_cache,
+    v_cache,  # None: the shared (latent) layout, one pool a layer
     k_new: jax.Array,  # [T, Hkv, D]
-    v_new: jax.Array,
+    v_new: Optional[jax.Array],
     slot_mapping: jax.Array,  # [T] int32, flat slot = page*page_size + offset
 ):
     """Write new KV rows into the paged cache at the given flat slots, in
@@ -446,8 +469,9 @@ def write_kv_cache(
     rows a half (a decode batch: 0.2 ms of its step given up for 2-3 s of
     every set-up, see the constant), one XLA scatter of ``[D]`` rows does
     it (``_scatter_rows``).  The choice reads ``Hkv``, ``T``, ``D``, the
-    page and the dtype, and nothing else: head widths 128 and 256 and
-    1, 2 and 4 kv heads are shapes of one write.  Until PR 31 the scatter
+    page, the dtype and whether the layer holds two pools or the one
+    shared pool, and nothing else: head widths 128, 256 and 640 and 1, 2
+    and 4 kv heads are shapes of one write.  Until PR 31 the scatter
     ran on the pool viewed ``[Hkv, P*page, D]`` with the head as its
     window, and XLA copied the whole pool into the scatter's layout and
     back, 13 ms of every step for 2.1 GB of cache; at ``Hkv = 1`` it
@@ -459,22 +483,29 @@ def write_kv_cache(
     if isinstance(k_cache, tuple):  # omnilint: disable=OL1 - pytree STRUCTURE branch (tuple vs array), static at trace time: jit specializes per layout by design
         return (_write_kv_quant(k_cache, k_new, slot_mapping),
                 _write_kv_quant(v_cache, v_new, slot_mapping))
+    # the shared (latent) layout is the same write over one pool
+    shared = v_cache is None
     tokens, hkv, _ = k_new.shape
-    reason = write_tiling_reason(k_cache, tokens)
+    reason = write_tiling_reason(k_cache, tokens, 1 if shared else 2)
     if tokens * hkv > _SCATTER_MAX_ROWS and auto_use_pallas(
             "write_kv_cache", reason):
-        return _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping)
-    return (_scatter_rows(k_cache, k_new, slot_mapping),
-            _scatter_rows(v_cache, v_new, slot_mapping))
+        out = _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping)
+    elif shared:
+        out = (_scatter_rows(k_cache, k_new, slot_mapping),)
+    else:
+        out = (_scatter_rows(k_cache, k_new, slot_mapping),
+               _scatter_rows(v_cache, v_new, slot_mapping))
+    return (out[0], None) if shared else out
 
 
 def paged_attention_ref(
     q: jax.Array,  # [B, H, D] (one decode token per sequence)
     k_cache,  # [Hkv, P, page, D] or quantized (data, scale) tuple
-    v_cache,
+    v_cache,  # None: the value is the key row's first ``v_dim`` lanes
     block_tables: jax.Array,  # [B, max_pages] int32 page ids
     context_lens: jax.Array,  # [B] int32
     scale: Optional[float] = None,
+    v_dim: Optional[int] = None,
 ):
     b, h, d = q.shape
     hkv, _, page, _ = cache_shape(k_cache)
@@ -486,9 +517,12 @@ def paged_attention_ref(
     kg = jnp.moveaxis(gather_pages(k_cache, block_tables), 0, 1).reshape(
         b, hkv, max_pages * page, d
     )
-    vg = jnp.moveaxis(gather_pages(v_cache, block_tables), 0, 1).reshape(
-        b, hkv, max_pages * page, d
-    )
+    if v_cache is None:
+        vg = kg[..., :v_dim]
+    else:
+        vg = jnp.moveaxis(gather_pages(v_cache, block_tables), 0, 1).reshape(
+            b, hkv, max_pages * page, d
+        )
     qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
     s = jnp.einsum("bngd,bnld->bngl", qg, kg.astype(jnp.float32)) * scale
     pos = jnp.arange(max_pages * page)[None, None, None, :]
@@ -496,7 +530,7 @@ def paged_attention_ref(
     s = jnp.where(mask, s, _NEG_INF)
     p_ = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bngl,bnld->bngd", p_, vg.astype(jnp.float32))
-    return o.reshape(b, h, d).astype(q.dtype)
+    return o.reshape(b, h, vg.shape[-1]).astype(q.dtype)
 
 
 def _paged_decode_kernel(
@@ -506,21 +540,26 @@ def _paged_decode_kernel(
     # inputs
     q_ref,  # [1, 1, group_p, D] VMEM
     k_hbm,  # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
-    v_hbm,
+    # v_hbm likewise, unless the layout is shared (the value is then the
+    # key tile's first Dv lanes: one page copy an iteration, not two);
     # quantized only: k_sc_ref/v_sc_ref [1, max_pages] SMEM — this
     # sequence's per-page scales in block-table order — then outputs
-    # o_ref [1, 1, group_p, D] and scratch
-    # k_buf/v_buf [2, page, D], sems [2, 2], acc_scr [group_p, D]
+    # o_ref [1, 1, group_p, Dv] and scratch
+    # k_buf/v_buf [2, page, D] (no v_buf when shared), sems [2, 2],
+    # acc_scr [group_p, Dv]
     *refs,
     page_size: int,
     scale: float,
     quantized: bool,
+    shared: bool,
 ):
-    if quantized:
-        k_sc_ref, v_sc_ref, o_ref, k_buf, v_buf, sems, acc_scr = refs
-    else:
-        o_ref, k_buf, v_buf, sems, acc_scr = refs
-        k_sc_ref = v_sc_ref = None
+    refs = list(refs)
+    v_hbm = None if shared else refs.pop(0)
+    k_sc_ref, v_sc_ref = (refs.pop(0), refs.pop(0)) if quantized \
+        else (None, None)
+    o_ref, k_buf = refs.pop(0), refs.pop(0)
+    v_buf = None if shared else refs.pop(0)
+    sems, acc_scr = refs
     b = pl.program_id(0)
     kvh = pl.program_id(1)
     ctx_len = context_lens_ref[b]
@@ -528,14 +567,12 @@ def _paged_decode_kernel(
 
     def page_dma(slot, p_idx):
         page_id = block_tables_ref[b, p_idx]
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[kvh, page_id], k_buf.at[slot], sems.at[slot, 0]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[kvh, page_id], v_buf.at[slot], sems.at[slot, 1]
-            ),
-        )
+        dmas = (pltpu.make_async_copy(
+            k_hbm.at[kvh, page_id], k_buf.at[slot], sems.at[slot, 0]),)
+        if not shared:
+            dmas += (pltpu.make_async_copy(
+                v_hbm.at[kvh, page_id], v_buf.at[slot], sems.at[slot, 1]),)
+        return dmas
 
     @pl.when(num_pages > 0)
     def _run():
@@ -557,7 +594,8 @@ def _paged_decode_kernel(
 
             q = q_ref[0, 0].astype(jnp.float32)
             k = k_buf[slot].astype(jnp.float32)
-            v = v_buf[slot].astype(jnp.float32)
+            v = (k[:, :acc_scr.shape[-1]] if shared
+                 else v_buf[slot].astype(jnp.float32))
             if quantized:
                 # dequantize in-register: one f32 scale per (head, page),
                 # a scalar read at the page's table position
@@ -596,16 +634,27 @@ def _paged_decode_kernel(
 
 def paged_tiling_reason(head_dim: int, page_size: int) -> str:
     """Why Mosaic cannot tile the paged kernels' (page_size, head_dim)
-    page buffers at this geometry ("" when it can): the lane dim must be
-    a multiple of 128 and the sublane dim of 8, in either layout (bf16
-    and int8 page tiles both compiled and matched the reference at page
-    16 on a v5e, CHANGES.md PR 21).  The engine refuses such a geometry
-    at construction on a platform that compiles the kernels; the ops
-    count it (``_dispatch.auto_use_pallas``)."""
+    page buffers at this geometry ("" when it can): the lane dim (a
+    head's width, or the whole row of a shared pool: 128, 256 and 640 are
+    served) must be a multiple of 128 and the sublane dim of 8, in either
+    layout (bf16 and int8 page tiles both compiled and matched the
+    reference at page 16 on a v5e, CHANGES.md PR 21).  The engine refuses
+    such a geometry at construction on a platform that compiles the
+    kernels; the ops count it (``_dispatch.auto_use_pallas``)."""
     if head_dim % 128:
-        return f"head_dim {head_dim} % 128 != 0"
+        return (f"head_dim {head_dim} % 128 != 0 (a head's width, or a "
+                "shared pool's row, takes whole 128-lane tiles)")
     if page_size % 8:
         return f"page_size {page_size} % 8 != 0"
+    return ""
+
+
+def shared_tiling_reason(v_cache, v_dim) -> str:
+    """Why the kernels cannot read the value out of a shared pool's key
+    tile ("" when they can, or when the layout is not shared): the value
+    must end on a lane tile."""
+    if v_cache is None and v_dim % 128:
+        return f"value width {v_dim} % 128 != 0"
     return ""
 
 
@@ -630,63 +679,66 @@ def _page_scale_spec(max_pages: int, head_seq):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "use_pallas"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "use_pallas", "v_dim"))
 def _paged_attention(
-    q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas
+    q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
+    v_dim=None,
 ):
     b, h, d = q.shape
     quantized = isinstance(k_cache, tuple)
+    shared = v_cache is None
     k_data, k_scale = k_cache if quantized else (k_cache, None)
     v_data, v_scale = v_cache if quantized else (v_cache, None)
     hkv, _, page_size, _ = k_data.shape
+    dv = v_dim if shared else d
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if not use_pallas:
         return paged_attention_ref(
-            q, k_cache, v_cache, block_tables, context_lens, scale
+            q, k_cache, v_cache, block_tables, context_lens, scale, v_dim
         )
     group = h // hkv
-    group_p = max(8, group)  # sublane-align the per-kv-head q group
+    # sublane-align the per-kv-head q group (8 rows of float32): 20 query
+    # heads on one latent kv head take 24
+    group_p = max(8, -(-group // 8) * 8)
     qx = q.reshape(b, hkv, group, d)
     if group_p != group:
         qx = jnp.pad(qx, ((0, 0), (0, 0), (0, group_p - group), (0, 0)))
     max_pages = block_tables.shape[1]
 
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
         pl.BlockSpec(
             (1, 1, group_p, d),
             lambda b_, h_, *_: (b_, h_, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
+    ] + [pool_spec] * (1 if shared else 2)
     operands = [
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),
         qx,
         k_data,
-        v_data,
-    ]
+    ] + ([] if shared else [v_data])
     if quantized:
         sc_spec = _page_scale_spec(max_pages, lambda b_, h_, *_: (h_, b_))
         in_specs += [sc_spec, sc_spec]
         operands += [_table_order_scales(k_scale, block_tables),
                      _table_order_scales(v_scale, block_tables)]
+    page_buf = pltpu.VMEM((2, page_size, d), k_data.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, group_p, d),
+            (1, 1, group_p, dv),
             lambda b_, h_, *_: (b_, h_, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, d), k_data.dtype),
-            pltpu.VMEM((2, page_size, d), v_data.dtype),
+        scratch_shapes=[page_buf] * (1 if shared else 2) + [
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((group_p, d), jnp.float32),
+            pltpu.VMEM((group_p, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -695,12 +747,13 @@ def _paged_attention(
             page_size=page_size,
             scale=scale,
             quantized=quantized,
+            shared=shared,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group_p, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group_p, dv), q.dtype),
         interpret=interpret_flag(),
     )(*operands)
-    return out[:, :, :group].reshape(b, h, d)
+    return out[:, :, :group].reshape(b, h, dv)
 
 
 def paged_attention(
@@ -711,14 +764,19 @@ def paged_attention(
     context_lens: jax.Array,
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
+    v_dim: Optional[int] = None,
 ):
     """Single-token-per-sequence paged decode attention.  An explicit
     ``use_pallas=True`` is honored as-is (kernel tests rely on it;
-    unsupported shapes then fail loudly at compile)."""
+    unsupported shapes then fail loudly at compile).  ``v_cache=None`` is
+    the shared layout: the value is the first ``v_dim`` lanes of the key
+    row, read from the key's tile, and the output is ``[B, H, v_dim]``."""
     if use_pallas is None:
         use_pallas = auto_use_pallas(
             "paged_attention",
-            paged_tiling_reason(q.shape[-1], cache_shape(k_cache)[2]))
+            paged_tiling_reason(q.shape[-1], cache_shape(k_cache)[2])
+            or shared_tiling_reason(v_cache, v_dim))
     return _paged_attention(
-        q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas
+        q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
+        v_dim,
     )

@@ -28,6 +28,10 @@ Token-packed layout (the metadata contract, see docs/ragged_batching.md):
 - ``seq_lens [S]`` — context length per sequence INCLUDING this chunk
   (the ``context_lens`` convention of ``forward_decode``).
 - ``page_tables [S, max_pages]`` — KV page ids covering each context.
+- ``v_cache=None`` — the shared layout of ``ops/paged_attention.py`` (a
+  latent cache): one pool whose row is the key and, in its first
+  ``v_dim`` lanes, the value; the kernel brings a KV block in once and
+  reads both from it, and the output is ``[T, H, v_dim]``.
 - ``num_seqs`` — sequences actually present (rows past it are padding).
 
 Causality is per-token global positions: query ``j`` of sequence ``i``
@@ -74,6 +78,7 @@ from vllm_omni_tpu.ops.paged_attention import (
     cache_shape,
     gather_pages,
     paged_tiling_reason,
+    shared_tiling_reason,
 )
 
 _NEG_INF = -1e30
@@ -111,6 +116,7 @@ def ragged_paged_attention_ref(
     seq_lens: jax.Array,     # [S] int32 (context incl. this chunk)
     num_seqs,                # int | [] | [1]
     scale: Optional[float] = None,
+    v_dim: Optional[int] = None,
 ):
     """Pure-XLA reference with identical semantics (fp32 softmax).
 
@@ -145,9 +151,12 @@ def ragged_paged_attention_ref(
     kg = jnp.transpose(
         gather_pages(k_cache, page_tables), (1, 2, 3, 0, 4)
     ).reshape(s_max, max_ctx, hkv, d)[seq_of]
-    vg = jnp.transpose(
-        gather_pages(v_cache, page_tables), (1, 2, 3, 0, 4)
-    ).reshape(s_max, max_ctx, hkv, d)[seq_of]
+    if v_cache is None:  # shared: the key row's first v_dim lanes
+        vg = kg[..., :v_dim]
+    else:
+        vg = jnp.transpose(
+            gather_pages(v_cache, page_tables), (1, 2, 3, 0, 4)
+        ).reshape(s_max, max_ctx, hkv, d)[seq_of]
     qg = q.reshape(t, hkv, group, d).astype(jnp.float32)
     s = jnp.einsum("thgd,tlhd->thgl", qg, kg.astype(jnp.float32)) * scale
     k_pos = jnp.arange(max_ctx)
@@ -162,7 +171,7 @@ def ragged_paged_attention_ref(
     l = jnp.sum(p, axis=-1, keepdims=True)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o = jnp.einsum("thgl,tlhd->thgd", p / l_safe, vg.astype(jnp.float32))
-    return o.reshape(t, h, d).astype(q.dtype)
+    return o.reshape(t, h, vg.shape[-1]).astype(q.dtype)
 
 
 def wide_q_tokens(q_lens, q_block: int,
@@ -229,12 +238,13 @@ def _ragged_kernel(
     # inputs
     q_ref,         # [nsub, token_block * group, D] VMEM: one q block
     k_hbm,         # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
-    v_hbm,
+    # v_hbm likewise, unless the layout is shared (the value is then the
+    # first Dv lanes of the key block: one copy of a page, not two);
     # quantized only: k_sc_ref/v_sc_ref [nsub, max_pages] SMEM — per
     # sub-block, its sequence's per-page scales in page-table order —
-    # then outputs o_ref [nsub, token_block * group, D] and scratch
-    # k_buf/v_buf [dma_slots, ppb * page, D], sems [dma_slots, 2],
-    # acc_scr [nsub * token_block * group, D]
+    # then outputs o_ref [nsub, token_block * group, Dv] and scratch
+    # k_buf/v_buf [dma_slots, ppb * page, D] (no v_buf when shared),
+    # sems [dma_slots, 2], acc_scr [nsub * token_block * group, Dv]
     *refs,
     page_size: int,
     token_block: int,
@@ -244,19 +254,25 @@ def _ragged_kernel(
     ppb: int,
     dma_slots: int,
     quantized: bool,
+    shared: bool,
 ):
-    if quantized:
-        k_sc_ref, v_sc_ref, o_ref, k_buf, v_buf, sems, acc_scr = refs
-    else:
-        o_ref, k_buf, v_buf, sems, acc_scr = refs
-        k_sc_ref = v_sc_ref = None
+    refs = list(refs)
+    v_hbm = None if shared else refs.pop(0)
+    k_sc_ref, v_sc_ref = (refs.pop(0), refs.pop(0)) if quantized \
+        else (None, None)
+    o_ref, k_buf = refs.pop(0), refs.pop(0)
+    v_buf = None if shared else refs.pop(0)
+    sems, acc_scr = refs
+    # the pools a KV block is copied from, each into its buffer
+    halves = ((k_hbm, k_buf),) if shared else ((k_hbm, k_buf),
+                                               (v_hbm, v_buf))
     kvh = pl.program_id(0)
     j = pl.program_id(1)   # GLOBAL q block of nsub sub-blocks: the grid
     #                        is (Hkv, NBW), no per-sequence dimension
     sub_rows = token_block * group
     kvb = ppb * page_size  # keys per KV block (one loop iteration)
     unroll = math.gcd(ppb, _PAGE_COPY_UNROLL)
-    d = q_ref.shape[-1]
+    d = o_ref.shape[-1]  # the value's width (the key's, unless shared)
 
     def attend(i, tok0, sub, ntok, q, write):
         """Online softmax of one q tile — ``ntok`` packed tokens of
@@ -287,12 +303,10 @@ def _ragged_kernel(
                     page_id = tables_ref[i, page_idx(blk, p)]
                     dst = pl.ds(pl.multiple_of(p * page_size, page_size),
                                 page_size)
-                    pltpu.make_async_copy(
-                        k_hbm.at[kvh, page_id], k_buf.at[slot, dst],
-                        sems.at[slot, 0]).start()
-                    pltpu.make_async_copy(
-                        v_hbm.at[kvh, page_id], v_buf.at[slot, dst],
-                        sems.at[slot, 1]).start()
+                    for half, (hbm, buf) in enumerate(halves):
+                        pltpu.make_async_copy(
+                            hbm.at[kvh, page_id], buf.at[slot, dst],
+                            sems.at[slot, half]).start()
                 return carry
 
             jax.lax.fori_loop(0, ppb // unroll, copy_pages, 0)
@@ -300,7 +314,7 @@ def _ragged_kernel(
         def wait_block(slot):
             # one wait per half for the block's ppb page copies: a DMA
             # semaphore counts bytes, and the descriptor only sizes it
-            for buf, half in ((k_buf, 0), (v_buf, 1)):
+            for half, (_, buf) in enumerate(halves):
                 pltpu.make_async_copy(
                     buf.at[slot], buf.at[slot], sems.at[slot, half]
                 ).wait()
@@ -353,7 +367,7 @@ def _ragged_kernel(
 
                 wait_block(slot)
                 k = k_buf[slot]
-                v = v_buf[slot]
+                v = k[:, :d] if shared else v_buf[slot]
                 if k.dtype != q.dtype:
                     # int8 values are exact in any float dtype: the
                     # page's (head, page) scale multiplies its score and
@@ -414,8 +428,8 @@ def _ragged_kernel(
             o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
 
         attend(w, j * (nsub * token_block) - cu_ref[w], 0,
-               nsub * token_block, q_ref[...].reshape(nsub * sub_rows, d),
-               write)
+               nsub * token_block,
+               q_ref[...].reshape(nsub * sub_rows, q_ref.shape[-1]), write)
 
     @pl.when(w < 0)
     def _narrow():
@@ -449,14 +463,16 @@ def _ragged_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "token_block", "use_pallas", "q_block",
-                     "pages_per_block", "dma_slots"))
+                     "pages_per_block", "dma_slots", "v_dim"))
 def _ragged_attention(
     q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
     num_seqs, scale, token_block, use_pallas, q_block, pages_per_block,
-    dma_slots,
+    dma_slots, v_dim=None,
 ):
     t, h, d = q.shape
     quantized = isinstance(k_cache, tuple)
+    shared = v_cache is None
+    dv = v_dim if shared else d
     k_data, k_scale = k_cache if quantized else (k_cache, None)
     v_data, v_scale = v_cache if quantized else (v_cache, None)
     hkv, _, page_size, _ = k_data.shape
@@ -465,7 +481,7 @@ def _ragged_attention(
     if not use_pallas:
         return ragged_paged_attention_ref(
             q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
-            seq_lens, num_seqs, scale,
+            seq_lens, num_seqs, scale, v_dim,
         )
     if t % token_block or q_block % token_block:
         raise ValueError(
@@ -488,16 +504,13 @@ def _ragged_attention(
     wide_seq, sub_seq = _block_owners(
         cu_q_lens, q_lens, num_seqs, nbw, nsub, token_block)
 
-    def q_spec():
-        return pl.BlockSpec((None, None, nsub, sub_rows, d),
+    def q_spec(width):
+        return pl.BlockSpec((None, None, nsub, sub_rows, width),
                             lambda kvh, j, *_: (kvh, j, 0, 0, 0),
                             memory_space=pltpu.VMEM)
 
-    in_specs = [
-        q_spec(),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
+    in_specs = [q_spec(d)] + [pl.BlockSpec(memory_space=pl.ANY)] * (
+        1 if shared else 2)
     operands = [
         wide_seq,
         sub_seq,
@@ -507,8 +520,7 @@ def _ragged_attention(
         page_tables.astype(jnp.int32),
         qx,
         k_data,
-        v_data,
-    ]
+    ] + ([] if shared else [v_data])
     if quantized:
         # per SUB-BLOCK, its sequence's scales in page-table order (a
         # q block may hold several sequences): [Hkv, NBW, nsub,
@@ -526,12 +538,12 @@ def _ragged_attention(
         num_scalar_prefetch=6,
         grid=(hkv, nbw),
         in_specs=in_specs,
-        out_specs=q_spec(),
+        out_specs=q_spec(dv),
         scratch_shapes=[
-            pltpu.VMEM((dma_slots, ppb * page_size, d), k_data.dtype),
-            pltpu.VMEM((dma_slots, ppb * page_size, d), v_data.dtype),
+            pltpu.VMEM((dma_slots, ppb * page_size, d), k_data.dtype)
+        ] * (1 if shared else 2) + [
             pltpu.SemaphoreType.DMA((dma_slots, 2)),
-            pltpu.VMEM((nsub * sub_rows, d), jnp.float32),
+            pltpu.VMEM((nsub * sub_rows, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -545,14 +557,15 @@ def _ragged_attention(
             ppb=ppb,
             dma_slots=dma_slots,
             quantized=quantized,
+            shared=shared,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qx.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qx.shape[:-1] + (dv,), q.dtype),
         interpret=interpret_flag(),
     )(*operands)
-    # [Hkv, NBW, nsub, tb*group, D] -> [T, H, D]
-    out = out.reshape(hkv, t_pad, group, d)[:, :t]
-    return jnp.transpose(out, (1, 0, 2, 3)).reshape(t, h, d)
+    # [Hkv, NBW, nsub, tb*group, Dv] -> [T, H, Dv]
+    out = out.reshape(hkv, t_pad, group, dv)[:, :t]
+    return jnp.transpose(out, (1, 0, 2, 3)).reshape(t, h, dv)
 
 
 def ragged_paged_attention(
@@ -570,6 +583,7 @@ def ragged_paged_attention(
     q_block: Optional[int] = None,
     pages_per_block: Optional[int] = None,
     dma_slots: Optional[int] = None,
+    v_dim: Optional[int] = None,
 ):
     """Mixed prefill+decode paged attention over a token-packed batch.
 
@@ -585,7 +599,8 @@ def ragged_paged_attention(
     quantized = cache_is_quantized(k_cache)
     k_data = cache_data(k_cache)
     if use_pallas is None:
-        reason = paged_tiling_reason(q.shape[-1], k_data.shape[2])
+        reason = (paged_tiling_reason(q.shape[-1], k_data.shape[2])
+                  or shared_tiling_reason(v_cache, v_dim))
         if not reason and q.shape[0] % token_block:
             reason = (f"packed length {q.shape[0]} % token_block "
                       f"{token_block} != 0")
@@ -596,7 +611,7 @@ def ragged_paged_attention(
             group=q.shape[1] // k_data.shape[0],
             kv_itemsize=k_data.dtype.itemsize,
             q_itemsize=q.dtype.itemsize,
-            quantized=quantized)
+            quantized=quantized, kv_pools=1 if v_cache is None else 2)
         q_block, pages_per_block, dma_slots = (
             a if x is None else x
             for x, a in zip((q_block, pages_per_block, dma_slots), auto))
@@ -604,5 +619,5 @@ def ragged_paged_attention(
     return _ragged_attention(
         q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
         num_seqs, scale, token_block, use_pallas, q_block,
-        pages_per_block, dma_slots,
+        pages_per_block, dma_slots, v_dim,
     )

@@ -58,6 +58,7 @@ from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
 from vllm_omni_tpu.ops.paged_attention import (
     init_kv_cache,
     paged_tiling_reason,
+    shared_tiling_reason,
     write_kv_cache,
 )
 from vllm_omni_tpu.ops.ragged_paged_attention import (
@@ -219,6 +220,22 @@ class ARModelRunner:
         # of cache: pages for its attention layers and one state slot a
         # sequence for the others (docs/kv_cache.md)
         self._hybrid = cfg.num_state_layers > 0
+        # what an attention layer's pages hold (transformer.KVLayout):
+        # a K and a V pool of per-head rows, or the one latent pool of a
+        # model with multi-head latent attention, whose row is key and
+        # value at once (docs/kv_cache.md)
+        layout = cfg.kv_layout
+        self.kv_layout = layout
+        self._latent = layout.kind == "latent"
+        if self._latent and kv_cache_dtype == "int8":
+            raise ValueError(
+                "kv_cache_dtype int8: the latent cache has no quantized "
+                "layout (one absmax scale a page would span the latent "
+                "and the rotary key alike)")
+        if self._latent and mesh is not None:
+            raise ValueError(
+                "tensor parallelism: the latent cache is one kv head that "
+                "every query head reads, and has no sharded layout yet")
         if self._hybrid and kv_cache_dtype == "int8":
             raise ValueError(
                 "kv_cache_dtype int8: a model with recurrent layers keeps "
@@ -239,13 +256,16 @@ class ARModelRunner:
         # the geometry is known here: on a platform that compiles the
         # paged kernels, one they cannot tile is refused now rather than
         # served through the O(T * max_ctx) gather reference
-        reason = paged_tiling_reason(cfg.head_dim, page_size)
+        reason = paged_tiling_reason(layout.width, page_size)
+        if self._latent and not reason:
+            reason = shared_tiling_reason(None, cfg.kv_lora_rank)
         if reason and _dispatch.pallas_mode() == "native":
             raise ValueError(
                 f"the paged attention kernels cannot tile this geometry "
                 f"({reason}; kv_cache_dtype={self.kv_cache_dtype}): pick "
                 "a page_size that is a multiple of 8 and a model whose "
-                "head_dim is a multiple of 128")
+                "cache rows are whole 128-lane tiles (a head_dim of 128 "
+                "or 256, or a latent row padded to 640, are served)")
         self.async_scheduling = bool(async_scheduling)
         self.deterministic_decode = bool(deterministic_decode)
         self.mesh = mesh
@@ -288,12 +308,12 @@ class ARModelRunner:
         # dispatch.
         self.q_block, self._pages_per_block, self._dma_slots = (
             auto_ragged_blocks(
-                head_dim=cfg.head_dim, page_size=page_size,
-                group=max(cfg.num_heads // max(cfg.num_kv_heads, 1), 1),
+                head_dim=layout.width, page_size=page_size,
+                group=max(cfg.num_heads // max(layout.heads, 1), 1),
                 kv_itemsize=(1 if self._kv_quant
                              else jnp.dtype(dtype).itemsize),
                 q_itemsize=jnp.dtype(dtype).itemsize,
-                quantized=self._kv_quant))
+                quantized=self._kv_quant, kv_pools=layout.pools))
         self._token_block = DEFAULT_TOKEN_BLOCK
         # unified ragged batching pads to TOKEN-count buckets: a 1-D
         # bucket line replacing the (batch, seq) grid of the split path.
@@ -330,10 +350,12 @@ class ARModelRunner:
         self.compile_stats = {"compiles": 0, "cache_hits": 0,
                               "compile_s": 0.0, "in_flight": 0}
         self._jit_seen: set[tuple] = set()
-        # one (k, v) for each softmax-attention layer only
+        # one (k, v) for each softmax-attention layer only; (pool, None)
+        # where the layout is the shared latent one
         pages = init_kv_cache(
-            cfg.num_kv_layers, num_pages, page_size, cfg.num_kv_heads,
-            cfg.head_dim, dtype, quantized=self._kv_quant,
+            cfg.num_kv_layers, num_pages, page_size, layout.heads,
+            layout.width, dtype, quantized=self._kv_quant,
+            shared=self._latent,
         )
         # and, for each recurrent layer, the convolution's last inputs and
         # the rule's state, both float32 (the rule's as the published
@@ -607,6 +629,10 @@ class ARModelRunner:
         executable's input shapes."""
         import inspect
 
+        if self._latent:
+            raise ValueError(
+                "speculative decoding: the verify rows' path over a "
+                "latent cache is not built")
         if self._hybrid:
             raise ValueError(
                 "speculative decoding: a rejected draft cannot roll a "
@@ -750,10 +776,10 @@ class ARModelRunner:
 
         logger.info(
             "ragged blocks: token_block=%d q_block=%d pages_per_block=%d "
-            "dma_slots=%d (head_dim=%d page_size=%d kv_cache_dtype=%s) "
+            "dma_slots=%d (row width=%d page_size=%d kv_cache_dtype=%s) "
             "— ops/autotune.py picks per layout", self._token_block,
             self.q_block, self._pages_per_block, self._dma_slots,
-            self.cfg.head_dim, self.page_size, self.kv_cache_dtype)
+            self.kv_layout.width, self.page_size, self.kv_cache_dtype)
 
         def pos_shape(b):
             return (b, 3) if self.use_mrope else (b,)
@@ -1562,6 +1588,12 @@ class ARModelRunner:
 
     # -------------------------------------------------------- kv extraction
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self._latent:
+            raise NotImplementedError(
+                f"{what}: a latent cache holds one shared pool a layer, "
+                "which the (K, V) page payload does not describe (KV "
+                "transfer, parking and offload are refused for it at "
+                "engine construction)")
         if self._hybrid:
             raise NotImplementedError(
                 f"{what}: a model with recurrent layers keeps part of a "
