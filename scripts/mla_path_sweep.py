@@ -17,12 +17,12 @@ context's latent rows, to the per-head output ``[T, 20, 256]``:
   of the context's size), the shared rotary key laid beside each head's
   ``k_nope``, and the dense flash kernel over them with the chunk's offset.
 
-A decode case is 24 rows at 8k of context a row, absorbed, through the
-decode kernel (a pure decode step's path) and through the ragged kernel
-(the decode rows of a mixed step).  Timed by the host's clock around
-``block_until_ready`` (median of ``--reps`` calls); each form's output is
-checked against the other's.  ``--tiny`` runs a small version anywhere: a
-rehearsal of the script, never a speed.
+Timed by the host's clock around ``block_until_ready`` (median of
+``--reps`` calls); each form's output is checked against the other's.
+``--tiny`` runs a small version anywhere: a rehearsal of the script, never
+a speed.  (The decode rows of this model, 24 at 8k of context, are a shape
+of ``scripts/decode_attention_sweep.py`` since PR 33, when the decode
+kernel this script also timed went.)
 """
 
 import argparse
@@ -37,11 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from vllm_omni_tpu.models.common.transformer import _join_lanes
-from vllm_omni_tpu.ops import (
-    flash_attention,
-    paged_attention,
-    ragged_paged_attention,
-)
+from vllm_omni_tpu.ops import flash_attention, ragged_paged_attention
 
 PAGE = 16
 HEADS, NOPE, ROPE, V, RANK, WIDTH = 20, 192, 64, 256, 512, 640
@@ -74,20 +70,6 @@ def expanded(q, w_kvb, pool, tables, cu, q_lens, seq_lens, ctx: int):
     return o[0]
 
 
-def decode_absorbed(q_full, pool, tables, lens, kernel: str):
-    """24 absorbed query rows over their contexts, by either kernel."""
-    if kernel == "decode":
-        return paged_attention(q_full, pool, None, tables, lens,
-                               scale=SCALE, v_dim=RANK)
-    b = q_full.shape[0]
-    packed = jnp.zeros((b * 8,) + q_full.shape[1:], q_full.dtype)
-    packed = packed.at[::8].set(q_full)
-    out = ragged_paged_attention(
-        packed, pool, None, tables, jnp.arange(b + 1, dtype=jnp.int32) * 8,
-        jnp.ones((b,), jnp.int32), lens, b, scale=SCALE, v_dim=RANK)
-    return out[::8]
-
-
 def timed(fn, args, reps: int) -> float:
     jax.block_until_ready(fn(*args))
     times = []
@@ -103,12 +85,11 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args()
-    chunk, contexts, rows, dec_ctx = (
-        (64, (64, 256), 4, 160) if args.tiny
-        else (2048, (2048, 8192, 16384), 24, 8192))
+    chunk, contexts = ((64, (64, 256)) if args.tiny
+                       else (2048, (2048, 8192, 16384)))
     dtype = jnp.float32 if args.tiny else jnp.bfloat16
     rs = np.random.default_rng(0)
-    pages = max(max(contexts), rows * dec_ctx) // PAGE + 1
+    pages = max(contexts) // PAGE + 1
     pool = np.zeros((1, pages, PAGE, WIDTH), np.float32)
     pool[..., :RANK + ROPE] = rs.standard_normal(
         (1, pages, PAGE, RANK + ROPE)) * 0.5
@@ -139,26 +120,6 @@ def main() -> None:
             case[f"{k}_ms"] = timed(f, (q, w_kvb, pool, *meta), args.reps)
         out["cases"].append(case)
         print(json.dumps(case), flush=True)
-    q_full = np.zeros((rows, HEADS, WIDTH), np.float32)
-    q_full[..., :RANK + ROPE] = rs.standard_normal(
-        (rows, HEADS, RANK + ROPE))
-    q_full = jnp.asarray(q_full, dtype)
-    tables = jnp.asarray((1 + rs.permutation(pages - 1))[
-        :rows * (dec_ctx // PAGE)].reshape(rows, -1), jnp.int32)
-    lens = jnp.full((rows,), dec_ctx, jnp.int32)
-    decode = jax.jit(decode_absorbed, static_argnames="kernel")
-    forms = {k: functools.partial(decode, kernel=k)
-             for k in ("decode", "ragged")}
-    got = {k: np.asarray(f(q_full, pool, tables, lens), np.float32)
-           for k, f in forms.items()}
-    case = {"decode_rows": rows, "context": dec_ctx,
-            "max_abs_diff": float(np.abs(got["decode"]
-                                         - got["ragged"]).max())}
-    for k, f in forms.items():
-        case[f"{k}_kernel_ms"] = timed(f, (q_full, pool, tables, lens),
-                                       args.reps)
-    out["cases"].append(case)
-    print(json.dumps(case), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/mla_path_sweep.json", "w") as f:
         json.dump(out, f, indent=1)

@@ -118,6 +118,9 @@ def main():
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=26)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--auto-only", action="store_true",
+                    help="time the picker's tile alone: the control of a "
+                         "PR that changes the kernel, parent beside change")
     ap.add_argument("--out", default="chiprun_out/ragged_sweep.json")
     ns = ap.parse_args()
     dev = jax.devices()[0]
@@ -136,7 +139,9 @@ def main():
         kv_itemsize=jnp.dtype(geo["dtype"]).itemsize,
         q_itemsize=jnp.dtype(geo["dtype"]).itemsize)
     print(f"auto_ragged_blocks -> {auto}")
-    if auto not in cands:
+    if ns.auto_only:
+        cands = [auto]
+    elif auto not in cands:
         cands = cands + [auto]
     rows = []
     for case in cases:

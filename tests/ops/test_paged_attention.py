@@ -298,6 +298,69 @@ def test_paged_decode_empty_context(rng):
     np.testing.assert_allclose(np.asarray(out), 0.0)
 
 
+# the decode entry: one token a segment through the ragged kernel's KV
+# blocks, each row's first block started by the row before it
+DECODE_POOLS = {
+    # the thinker: 8 query heads a kv head, two pools
+    "group8_w128": dict(hkv=2, group=8, d=128),
+    # GLM's latent cache: 20 heads on one shared pool of 640-lane rows,
+    # the value its first 512 lanes (24 rows a tile inside)
+    "group20_shared_w640": dict(hkv=1, group=20, d=640, v_dim=512),
+    # Qwen3-Next's gated attention
+    "w256_2kv": dict(hkv=2, group=8, d=256),
+    "int8": dict(hkv=2, group=8, d=128, quantized=True),
+}
+BLOCK = 32 * PAGE  # keys a KV block (16 pages at float32 rows of 256+)
+DECODE_CONTEXTS = {
+    # the edges of a page and of a KV block: one key, one page, one
+    # block exactly (of 16 pages, of 32), a page more
+    "edges": [1, PAGE, BLOCK // 2, BLOCK, BLOCK + PAGE],
+    # nothing may be started for, or waited by, a row without context:
+    # one between two real rows, one as the last row
+    "empty_rows": [40, 0, 77, 0],
+    "one_row": [100],
+    # a row more than a grid cell's sixteen
+    "seventeen_rows": [7 + 11 * i for i in range(17)],
+}
+
+
+@pytest.mark.parametrize("contexts", sorted(DECODE_CONTEXTS))
+@pytest.mark.parametrize("pool", sorted(DECODE_POOLS))
+def test_decode_entry_matches_the_reference(pool, contexts):
+    geo, lens = DECODE_POOLS[pool], DECODE_CONTEXTS[contexts]
+    hkv, group, d = geo["hkv"], geo["group"], geo["d"]
+    v_dim, quantized = geo.get("v_dim"), geo.get("quantized", False)
+    b, rs = len(lens), np.random.default_rng(len(pool) + len(contexts))
+    pages_per = [-(-n // PAGE) for n in lens]
+    tables = np.zeros((b, max(pages_per) + 1), np.int32)
+    ids = 1 + rs.permutation(sum(pages_per))  # scattered, page 0 unused
+    used = 0
+    for i, n in enumerate(pages_per):
+        tables[i, :n] = ids[used: used + n]
+        used += n
+
+    def half():
+        x = rs.standard_normal((hkv, used + 1, PAGE, d)).astype(np.float32)
+        if not quantized:
+            return jnp.asarray(x)
+        scale = np.abs(x).max(axis=(2, 3)) / 127
+        return (jnp.asarray(np.round(x / scale[..., None, None]), jnp.int8),
+                jnp.asarray(scale))
+
+    k, v = half(), None if v_dim else half()
+    q = jnp.asarray(rs.standard_normal((b, hkv * group, d)), jnp.float32)
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+    got = np.asarray(paged_attention(q, k, v, tables, lens,
+                                     use_pallas=True, v_dim=v_dim))
+    want = np.asarray(paged_attention_ref(q, k, v, tables, lens,
+                                          v_dim=v_dim))
+    assert got.shape == want.shape == (b, hkv * group, v_dim or d)
+    real = np.asarray(lens) > 0
+    # a float32 pool is computed in float32 (int8 rows are exact in it)
+    np.testing.assert_allclose(got[real], want[real], atol=2e-5)
+    assert np.all(got[~real] == 0.0)
+
+
 def test_auto_dispatch_counts_native_fallbacks(monkeypatch):
     """On a platform that compiles the kernels, a shape that misses a
     tiling gate is counted (and takes the XLA path); on the interpreted
@@ -400,7 +463,7 @@ def _latent_case(seed, b, ctx_lens, heads=20, width=640, v_dim=512):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 2e-2)])
 def test_shared_pool_decode_kernel_matches_the_reference(dtype, tol):
-    """The decode kernel over one pool of 640-lane rows whose first 512
+    """The decode entry over one pool of 640-lane rows whose first 512
     lanes are the value, 20 query heads on the one kv head (padded to 24
     rows inside): against the gather reference, and against the plain
     definition with the value taken from the key."""

@@ -157,6 +157,37 @@ def test_matches_dense_oracle(name, use_pallas):
     assert np.all(np.asarray(got)[~mask] == 0.0)
 
 
+@pytest.mark.parametrize("dma_slots", [2, 3])
+def test_mixed_step_with_a_padding_sub_block_between(dma_slots):
+    """A mixed step as the runner packs it, and one thing more: decode
+    rows first (contexts of one to four 8-key KV blocks, so every
+    narrow tile hands its pipeline on to the next, within a q block and
+    across two), then a sub-block nobody owns, then a chunk (narrow at
+    its head, one wide tile, narrow at its tail).  The tile before the
+    padding must start nothing for it, and the chunk's first tile, which
+    nothing started, must prime itself."""
+    specs = [(9, 1), (30, 1), (1, 1), (17, 1), (25, 1), (60, 37)]
+    h, hkv, d, page = 4, 2, 32, 4
+    (q, kc, vc, tables, cu, q_lens, seq_lens, n, dense) = _pack(
+        specs, h, hkv, d, page, s_max=8, max_pages=16, seed=5)
+    # open an 8-row hole before the chunk
+    chunk = len(specs) - 1
+    lo = int(cu[chunk])
+    q = jnp.concatenate([q[:lo], jnp.zeros((TB, h, d), q.dtype), q[lo:]])
+    cu = cu.at[chunk:].add(TB)
+    got = ragged_paged_attention(
+        q, kc, vc, tables, cu, q_lens, seq_lens, n, use_pallas=True,
+        q_block=16, pages_per_block=2, dma_slots=dma_slots)
+    want = _oracle(q, cu, q_lens, seq_lens, dense, h, d)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
+                               atol=2e-5)
+    ref = ragged_paged_attention_ref(
+        q, kc, vc, tables, cu, q_lens, seq_lens, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert np.all(np.asarray(got)[lo: lo + TB] == 0.0)
+
+
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_gqa_group_wider(use_pallas):
     """H == 4 * Hkv: every kv head serves 4 query heads in one block."""
@@ -212,8 +243,8 @@ def test_kernel_matches_ref_exactly_shaped():
 
 
 def test_decode_row_equals_paged_attention():
-    """A 1-token ragged row reproduces the dedicated decode kernel's
-    semantics (same cache, same tables)."""
+    """A 1-token ragged row reproduces the decode entry's semantics
+    (same cache, same tables)."""
     from vllm_omni_tpu.ops import paged_attention_ref
 
     h, hkv, d, page = 4, 2, 32, 4
@@ -353,8 +384,9 @@ def test_shared_pool_kernel_matches_the_reference(dtype, tol):
 
 
 def test_shared_pool_decode_row_equals_the_decode_kernel():
-    """A decode row through the ragged kernel and through the decode
-    kernel: one pool, two kernels, the same numbers."""
+    """A decode row as a mixed step packs it (one token in an 8-row
+    segment) and through the decode entry (one token a segment): one
+    pool, one kernel, two layouts, the same numbers."""
     from vllm_omni_tpu.ops import paged_attention
 
     specs = [(61, 1), (17, 1)]

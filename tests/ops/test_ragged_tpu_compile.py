@@ -90,11 +90,112 @@ def test_ragged_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_latent_decode_and_write_compile_for_v5e(one_chip, monkeypatch):
-    """The decode kernel and the page write at the latent cache's shapes
-    (one pool of 640-lane rows, the value its first 512 lanes, a group of
-    20 padded to 24 rows; a 2048-row step and a 32-row decode batch), and
-    no operation of the pool's size in either write."""
+# the decode entry (``paged_attention``: one token a segment through the
+# ragged kernel) at the decode buckets the cells' configurations run
+DECODES = {
+    "thinker_bf16": dict(b=64, hkv=4, group=8),
+    "thinker_8rows_bf16": dict(b=8, hkv=4, group=8),
+    "thinker_int8": dict(b=64, hkv=4, group=8, quantized=True),
+    "tp4_shard_bf16": dict(b=64, hkv=1, group=8),
+    "group1_bf16": dict(b=64, hkv=4, group=1),
+    "head256_bf16": dict(b=32, hkv=2, group=8, d=256, pool=32768,
+                         max_pages=2080),
+    "latent_bf16": dict(b=32, hkv=1, group=20, d=640, v_dim=512,
+                        pool=16384, max_pages=1104),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODES))
+def test_decode_entry_compiles_for_v5e(name, one_chip, monkeypatch):
+    """``paged_attention`` as ``forward_decode`` calls it (auto dispatch
+    on a platform that compiles the kernels): one attention custom call,
+    and nothing of the pool's size beside it."""
+    import re
+
+    from vllm_omni_tpu.ops import _dispatch
+    shape = DECODES[name]
+    b, hkv, group = (shape[k] for k in ("b", "hkv", "group"))
+    d, pool, max_pages = (shape.get(k, v) for k, v in (
+        ("d", 128), ("pool", 8192), ("max_pages", 528)))
+    quantized, v_dim = shape.get("quantized", False), shape.get("v_dim")
+    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    data = sds((hkv, pool, 16, d), jnp.int8 if quantized else jnp.bfloat16)
+    cache = (data, sds((hkv, pool), jnp.float32)) if quantized else data
+    text = jax.jit(lambda q, k, v, tables, lens: rpa.paged_attention(
+        q, k, v, tables, lens, v_dim=v_dim)).lower(
+        sds((b, hkv * group, d), jnp.bfloat16), cache,
+        None if v_dim else cache, sds((b, max_pages), jnp.int32),
+        sds((b,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "_ragged_attention" in text  # what ``dev_attn_share_pct`` finds
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|transpose|convert|gather)\(", text)
+        if np.prod([int(x) for x in m.group(1).split(",")])
+        >= hkv * pool * 16 * d]
+    assert not moved, moved
+
+
+def test_decode_step_holds_one_attention_call_a_layer(one_chip, monkeypatch):
+    """A whole pure-decode step of the thinker at its published widths
+    (two layers, 64 rows, the cell's pools donated) compiled for a v5e:
+    one ``_ragged_attention`` custom call a layer (the name the
+    benchmark's ``dev_attn_share_pct`` looks for), and no operation of a
+    pool's size."""
+    import functools
+    import re
+
+    from benchmark import spec
+    from benchmark.archs import qwen3_moe as arch
+    from vllm_omni_tpu.models.common import transformer
+    from vllm_omni_tpu.ops import _dispatch
+
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
+    monkeypatch.setattr(pa, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    layers, b = 2, 64
+    config = dict(spec.load_json(
+        spec.ROOT / "benchmark/configs/thinker-l8.json"),
+        num_hidden_layers=layers)
+    config.pop("program_config", None)  # the constant has the full depth
+    cfg = arch.program_config(config)
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(functools.partial(arch.init_weights, cfg=cfg,
+                                         dtype=jnp.bfloat16),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    pool = sds((4, 8192, 16, 128), jnp.bfloat16)
+    text = jax.jit(
+        lambda p, tok, pos, kv, slots, tables, lens:
+        transformer.forward_decode(p, cfg, tok, pos, kv, slots, tables, lens),
+        donate_argnums=3).lower(
+        params, sds((b,), jnp.int32), sds((b, 3), jnp.int32),
+        [(pool, pool)] * layers, sds((b,), jnp.int32),
+        sds((b, 528), jnp.int32), sds((b,), jnp.int32)).compile().as_text()
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*custom-call\([^\n]*"
+                       r"tpu_custom_call", text)
+    assert sum("attention" in name for name in calls) == layers
+    assert calls.count("_ragged_attention") == layers
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|copy-start|transpose|convert|gather)\(",
+        text) if np.prod([int(x) for x in m.group(1).split(",")])
+        >= 4 * 8192 * 16 * 128]
+    assert not moved, moved
+
+
+def test_latent_write_compiles_for_v5e(one_chip, monkeypatch):
+    """The page write at the latent cache's shapes (one pool of 640-lane
+    rows; a 2048-row step and a 32-row decode batch), and no operation of
+    the pool's size in either."""
     import re
 
     pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
@@ -106,12 +207,6 @@ def test_latent_decode_and_write_compile_for_v5e(one_chip, monkeypatch):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     pool = sds((1, 16384, 16, 640), jnp.bfloat16)
-    decode = jax.jit(lambda q, k, tables, lens: pa._paged_attention(
-        q, k, None, tables, lens, 1 / 16, True, 512)).lower(
-        sds((32, 20, 640), jnp.bfloat16), pool,
-        sds((32, 1104), jnp.int32), sds((32,), jnp.int32)).compile()
-    assert "tpu_custom_call" in decode.as_text()
-    assert decode.output_shardings is not None
     for rows in (2048, 32):
         text = jax.jit(
             lambda k, new, slots: pa.write_kv_cache(k, None, new, None,

@@ -22,11 +22,11 @@ from vllm_omni_tpu.ops.paged_attention import (
     cache_is_quantized,
     cache_shape,
     gather_pages,
-    paged_attention,
     paged_attention_ref,
     write_kv_cache,
 )
 from vllm_omni_tpu.ops.ragged_paged_attention import (
+    paged_attention,
     ragged_paged_attention,
     ragged_paged_attention_ref,
 )

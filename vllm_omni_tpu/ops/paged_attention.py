@@ -4,7 +4,7 @@ GPUModelRunner's attention metadata, worker/gpu_ar_model_runner.py:243-255).
 
 Cache layout (TPU-first): ``[Hkv, num_pages, page_size, D]`` — fixing the
 head and page indices yields a *contiguous* (page_size, D) tile, so the
-decode kernel's HBM→VMEM page DMAs are dense (the CUDA layout
+attention kernel's HBM→VMEM page DMAs are dense (the CUDA layout
 [pages, page_size, Hkv, D] would stride every row on TPU).
 
 Quantized layout (``--kv-cache-dtype int8``): each cache half becomes a
@@ -26,7 +26,8 @@ pages, the kernels bring a page in once and read both operands from that
 tile (a second copy of the page would double the bytes of a memory-bound
 kernel).  The row's width and ``v_dim`` are shapes the ops read.
 
-Three ops:
+The ops here (the decode entry ``paged_attention`` lives beside the one
+kernel that reads the pages, ``ops/ragged_paged_attention.py``):
 - ``write_kv_cache``  — a step's new K/V rows into the paged cache at
   their flat slots, in the pool's own layout and in place (PR 31).  On
   the TPU a Pallas kernel brings each touched page ``[Hkv, page, D]``
@@ -53,9 +54,8 @@ Three ops:
   (The quantized branch grows per-page scales monotonically within a
   page's tenancy and rescales the page's prior rows in-place; its rows
   go through ``_scatter_rows``.)
-- ``paged_attention_ref`` — gather-based XLA fallback (also the test oracle)
-- ``paged_attention`` — Pallas decode kernel: per (seq, kv-head) grid cell,
-  double-buffered page DMAs + online softmax over pages.
+- ``paged_attention_ref`` — gather-based XLA fallback of the decode entry
+  (also the test oracle)
 """
 
 from __future__ import annotations
@@ -533,105 +533,6 @@ def paged_attention_ref(
     return o.reshape(b, h, vg.shape[-1]).astype(q.dtype)
 
 
-def _paged_decode_kernel(
-    # scalar prefetch
-    block_tables_ref,  # [B, max_pages] (SMEM)
-    context_lens_ref,  # [B] (SMEM)
-    # inputs
-    q_ref,  # [1, 1, group_p, D] VMEM
-    k_hbm,  # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
-    # v_hbm likewise, unless the layout is shared (the value is then the
-    # key tile's first Dv lanes: one page copy an iteration, not two);
-    # quantized only: k_sc_ref/v_sc_ref [1, max_pages] SMEM — this
-    # sequence's per-page scales in block-table order — then outputs
-    # o_ref [1, 1, group_p, Dv] and scratch
-    # k_buf/v_buf [2, page, D] (no v_buf when shared), sems [2, 2],
-    # acc_scr [group_p, Dv]
-    *refs,
-    page_size: int,
-    scale: float,
-    quantized: bool,
-    shared: bool,
-):
-    refs = list(refs)
-    v_hbm = None if shared else refs.pop(0)
-    k_sc_ref, v_sc_ref = (refs.pop(0), refs.pop(0)) if quantized \
-        else (None, None)
-    o_ref, k_buf = refs.pop(0), refs.pop(0)
-    v_buf = None if shared else refs.pop(0)
-    sems, acc_scr = refs
-    b = pl.program_id(0)
-    kvh = pl.program_id(1)
-    ctx_len = context_lens_ref[b]
-    num_pages = jax.lax.div(ctx_len + page_size - 1, page_size)
-
-    def page_dma(slot, p_idx):
-        page_id = block_tables_ref[b, p_idx]
-        dmas = (pltpu.make_async_copy(
-            k_hbm.at[kvh, page_id], k_buf.at[slot], sems.at[slot, 0]),)
-        if not shared:
-            dmas += (pltpu.make_async_copy(
-                v_hbm.at[kvh, page_id], v_buf.at[slot], sems.at[slot, 1]),)
-        return dmas
-
-    @pl.when(num_pages > 0)
-    def _run():
-        for dma in page_dma(0, 0):
-            dma.start()
-
-        def body(p_idx, carry):
-            m_prev, l_prev, _ = carry  # acc lives in scratch
-            slot = jax.lax.rem(p_idx, 2)
-            nxt = jax.lax.rem(p_idx + 1, 2)
-
-            @pl.when(p_idx + 1 < num_pages)
-            def _prefetch():
-                for dma in page_dma(nxt, p_idx + 1):
-                    dma.start()
-
-            for dma in page_dma(slot, p_idx):
-                dma.wait()
-
-            q = q_ref[0, 0].astype(jnp.float32)
-            k = k_buf[slot].astype(jnp.float32)
-            v = (k[:, :acc_scr.shape[-1]] if shared
-                 else v_buf[slot].astype(jnp.float32))
-            if quantized:
-                # dequantize in-register: one f32 scale per (head, page),
-                # a scalar read at the page's table position
-                k = k * k_sc_ref[0, p_idx]
-                v = v * v_sc_ref[0, p_idx]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-            pos = p_idx * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
-            )
-            s = jnp.where(pos < ctx_len, s, _NEG_INF)
-
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-                p, v, preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, 0
-
-        group_p = q_ref.shape[2]
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m0 = jnp.full((group_p, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((group_p, 1), jnp.float32)
-        m_fin, l_fin, _ = jax.lax.fori_loop(
-            0, num_pages, body, (m0, l0, 0)
-        )
-        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-
-    @pl.when(num_pages == 0)
-    def _empty():
-        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
-
-
 def paged_tiling_reason(head_dim: int, page_size: int) -> str:
     """Why Mosaic cannot tile the paged kernels' (page_size, head_dim)
     page buffers at this geometry ("" when it can): the lane dim (a
@@ -656,127 +557,3 @@ def shared_tiling_reason(v_cache, v_dim) -> str:
     if v_cache is None and v_dim % 128:
         return f"value width {v_dim} % 128 != 0"
     return ""
-
-
-def _table_order_scales(scale, page_tables):
-    """[Hkv, P] per-page scales -> [Hkv, S, 1, max_pages] in each
-    sequence's page-table order.  Mosaic can neither tile a (1, P) block
-    of an [Hkv, P] array, nor read a VMEM scalar at a dynamic lane
-    index, nor broadcast a (1, 1) vector over a page tile — so the few
-    scales one sequence needs travel as an SMEM block and the kernels
-    read them as true scalars."""
-    return scale[:, page_tables][:, :, None, :]
-
-
-def _page_scale_spec(max_pages: int, head_seq):
-    """SMEM block over ``_table_order_scales`` output: the (1, max_pages)
-    row of the (kv-head, sequence) pair ``head_seq(*grid_idx,
-    *prefetch_refs)`` names."""
-    return pl.BlockSpec(
-        (None, None, 1, max_pages),
-        lambda *a: (*head_seq(*a), 0, 0),
-        memory_space=pltpu.SMEM,
-    )
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "use_pallas", "v_dim"))
-def _paged_attention(
-    q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
-    v_dim=None,
-):
-    b, h, d = q.shape
-    quantized = isinstance(k_cache, tuple)
-    shared = v_cache is None
-    k_data, k_scale = k_cache if quantized else (k_cache, None)
-    v_data, v_scale = v_cache if quantized else (v_cache, None)
-    hkv, _, page_size, _ = k_data.shape
-    dv = v_dim if shared else d
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    if not use_pallas:
-        return paged_attention_ref(
-            q, k_cache, v_cache, block_tables, context_lens, scale, v_dim
-        )
-    group = h // hkv
-    # sublane-align the per-kv-head q group (8 rows of float32): 20 query
-    # heads on one latent kv head take 24
-    group_p = max(8, -(-group // 8) * 8)
-    qx = q.reshape(b, hkv, group, d)
-    if group_p != group:
-        qx = jnp.pad(qx, ((0, 0), (0, 0), (0, group_p - group), (0, 0)))
-    max_pages = block_tables.shape[1]
-
-    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, group_p, d),
-            lambda b_, h_, *_: (b_, h_, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-    ] + [pool_spec] * (1 if shared else 2)
-    operands = [
-        block_tables.astype(jnp.int32),
-        context_lens.astype(jnp.int32),
-        qx,
-        k_data,
-    ] + ([] if shared else [v_data])
-    if quantized:
-        sc_spec = _page_scale_spec(max_pages, lambda b_, h_, *_: (h_, b_))
-        in_specs += [sc_spec, sc_spec]
-        operands += [_table_order_scales(k_scale, block_tables),
-                     _table_order_scales(v_scale, block_tables)]
-    page_buf = pltpu.VMEM((2, page_size, d), k_data.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, group_p, dv),
-            lambda b_, h_, *_: (b_, h_, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[page_buf] * (1 if shared else 2) + [
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((group_p, dv), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel,
-            page_size=page_size,
-            scale=scale,
-            quantized=quantized,
-            shared=shared,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group_p, dv), q.dtype),
-        interpret=interpret_flag(),
-    )(*operands)
-    return out[:, :, :group].reshape(b, h, dv)
-
-
-def paged_attention(
-    q: jax.Array,
-    k_cache,
-    v_cache,
-    block_tables: jax.Array,
-    context_lens: jax.Array,
-    scale: Optional[float] = None,
-    use_pallas: Optional[bool] = None,
-    v_dim: Optional[int] = None,
-):
-    """Single-token-per-sequence paged decode attention.  An explicit
-    ``use_pallas=True`` is honored as-is (kernel tests rely on it;
-    unsupported shapes then fail loudly at compile).  ``v_cache=None`` is
-    the shared layout: the value is the first ``v_dim`` lanes of the key
-    row, read from the key's tile, and the output is ``[B, H, v_dim]``."""
-    if use_pallas is None:
-        use_pallas = auto_use_pallas(
-            "paged_attention",
-            paged_tiling_reason(q.shape[-1], cache_shape(k_cache)[2])
-            or shared_tiling_reason(v_cache, v_dim))
-    return _paged_attention(
-        q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
-        v_dim,
-    )

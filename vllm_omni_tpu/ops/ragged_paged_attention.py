@@ -1,12 +1,15 @@
-"""Ragged paged attention — one token-packed kernel for prefill + decode.
+"""Ragged paged attention — one token-packed kernel for prefill + decode,
+and the one block loop over paged KV the package has.
 
-The unified-batching counterpart of ``ops/paged_attention.py`` (single
-token per sequence) and the chunked path of ``ops/attention.py`` (padded
-[B, S] chunks): variable-length prefill chunks and 1-token decode rows
-ride the SAME token-packed launch, so a mixed engine step is ONE device
-dispatch instead of the fresh/chunk/decode triple ("Ragged Paged
-Attention: A High-Performance and Flexible LLM Inference Kernel for
-TPU", PAPERS.md).
+The unified-batching counterpart of the chunked path of
+``ops/attention.py`` (padded [B, S] chunks): variable-length prefill
+chunks and 1-token decode rows ride the SAME token-packed launch, so a
+mixed engine step is ONE device dispatch instead of the
+fresh/chunk/decode triple ("Ragged Paged Attention: A High-Performance
+and Flexible LLM Inference Kernel for TPU", PAPERS.md).  A pure decode
+step enters the same kernel through ``paged_attention`` below: one
+token a segment, ``token_block = 1`` (until PR 33 a kernel of its own
+that took one page an iteration).
 
 Token-packed layout (the metadata contract, see docs/ragged_batching.md):
 
@@ -53,7 +56,11 @@ rows exclusively.  A tile online-softmaxes over KV blocks of
 double-buffered VMEM buffer, one wait, one ``rows x D x keys`` product
 and one back, in the stored dtype with float32 accumulation — and its
 loop ends at the tile's last causal page; only the blocks crossing the
-diagonal are masked.  ``auto_ragged_blocks`` (ops/autotune.py) derives
+diagonal are masked.  The sub-block tiles are short (a decode row's
+context is a KV block or a few), so each starts the first KV block of
+the tile after it while it still computes, across grid cells too: no
+such tile waits for a copy it has only just asked for, but the first.
+``auto_ragged_blocks`` (ops/autotune.py) derives
 (q_block, pages_per_block, dma_slots) from group, head dim, page size
 and cache dtype; ``ragged_paged_attention_ref`` is the XLA fallback /
 test oracle.
@@ -77,6 +84,7 @@ from vllm_omni_tpu.ops.paged_attention import (
     cache_is_quantized,
     cache_shape,
     gather_pages,
+    paged_attention_ref,
     paged_tiling_reason,
     shared_tiling_reason,
 )
@@ -244,7 +252,8 @@ def _ragged_kernel(
     # sub-block, its sequence's per-page scales in page-table order —
     # then outputs o_ref [nsub, token_block * group, Dv] and scratch
     # k_buf/v_buf [dma_slots, ppb * page, D] (no v_buf when shared),
-    # sems [dma_slots, 2], acc_scr [nsub * token_block * group, Dv]
+    # sems [dma_slots, 2], acc_scr [nsub * token_block * group, Dv],
+    # pipe [2] SMEM: what one narrow tile leaves the next (see _narrow)
     *refs,
     page_size: int,
     token_block: int,
@@ -255,6 +264,7 @@ def _ragged_kernel(
     dma_slots: int,
     quantized: bool,
     shared: bool,
+    wide: bool,
 ):
     refs = list(refs)
     v_hbm = None if shared else refs.pop(0)
@@ -262,62 +272,91 @@ def _ragged_kernel(
         else (None, None)
     o_ref, k_buf = refs.pop(0), refs.pop(0)
     v_buf = None if shared else refs.pop(0)
-    sems, acc_scr = refs
+    sems, acc_scr, pipe = refs
     # the pools a KV block is copied from, each into its buffer
     halves = ((k_hbm, k_buf),) if shared else ((k_hbm, k_buf),
                                                (v_hbm, v_buf))
     kvh = pl.program_id(0)
     j = pl.program_id(1)   # GLOBAL q block of nsub sub-blocks: the grid
-    #                        is (Hkv, NBW), no per-sequence dimension
+    #                        is (Hkv, NBW), run in order (_narrow needs it)
+    nbw = pl.num_programs(1)
     sub_rows = token_block * group
     kvb = ppb * page_size  # keys per KV block (one loop iteration)
     unroll = math.gcd(ppb, _PAGE_COPY_UNROLL)
     d = o_ref.shape[-1]  # the value's width (the key's, unless shared)
 
-    def attend(i, tok0, sub, ntok, q, write):
+    def extent(i, tok0, ntok):
+        """(last page, KV blocks) of the tile of ``ntok`` packed tokens
+        of sequence ``i`` from its chunk token ``tok0``."""
+        q_len = qlen_ref[i]
+        # the loop ends at the last causal key of the tile's last real
+        # token, not at the context's end: a chunk's early tiles never
+        # fetch the pages above their diagonal
+        kv_end = slen_ref[i] - q_len + jnp.minimum(tok0 + ntok, q_len)
+        return (jax.lax.div(kv_end + page_size - 1, page_size) - 1,
+                jax.lax.div(kv_end + kvb - 1, kvb))
+
+    def page_idx(last_page, blk, p):
+        # the ragged last block refetches the last page into its spare
+        # slots: every slot holds real cache rows (finite, masked by
+        # position), and the loop body needs no branch
+        return jnp.minimum(blk * ppb + p, last_page)
+
+    def start_block(head, i, last_page, slot, blk):
+        def copy_pages(g, carry):
+            for u in range(unroll):
+                p = g * unroll + u
+                page_id = tables_ref[i, page_idx(last_page, blk, p)]
+                dst = pl.ds(pl.multiple_of(p * page_size, page_size),
+                            page_size)
+                for half, (hbm, buf) in enumerate(halves):
+                    pltpu.make_async_copy(
+                        hbm.at[head, page_id], buf.at[slot, dst],
+                        sems.at[slot, half]).start()
+            return carry
+
+        jax.lax.fori_loop(0, ppb // unroll, copy_pages, 0)
+
+    def wait_block(slot):
+        # one wait per half for the block's ppb page copies: a DMA
+        # semaphore counts bytes, and the descriptor only sizes it
+        for half, (_, buf) in enumerate(halves):
+            pltpu.make_async_copy(
+                buf.at[slot], buf.at[slot], sems.at[slot, half]
+            ).wait()
+
+    def attend(i, tok0, sub, ntok, q, write, base=0, primed=None,
+               hand_off=None):
         """Online softmax of one q tile — ``ntok`` packed tokens of
         sequence ``i`` from its chunk token ``tok0`` (rows pack
         ``group`` query heads per token, token-major) — over KV blocks
         of ``ppb`` pages; ``write`` takes the f32 [ntok * group, D]
-        result.  ``sub`` names the tile's row of the scale blocks."""
+        result.  ``sub`` names the tile's row of the scale blocks.
+        Block ``b`` lives in DMA slot ``(base + b) % dma_slots``; where
+        ``primed`` holds, block 0 is already on its way there (the tile
+        before started it), and ``hand_off(slot)`` is called once, when
+        the pipeline would reach past this tile's last block, with the
+        slot that block would take: the next tile's to start in."""
         rows = ntok * group
         q_len = qlen_ref[i]
         ctx_len = slen_ref[i]
-        # the loop ends at the last causal key of the tile's last real
-        # token, not at the context's end: a chunk's early tiles never
-        # fetch the pages above their diagonal
-        kv_end = ctx_len - q_len + jnp.minimum(tok0 + ntok, q_len)
-        last_page = jax.lax.div(kv_end + page_size - 1, page_size) - 1
-        n_blk = jax.lax.div(kv_end + kvb - 1, kvb)
+        last_page, n_blk = extent(i, tok0, ntok)
 
-        def page_idx(blk, p):
-            # the ragged last block refetches the last page into its
-            # spare slots: every slot holds real cache rows (finite,
-            # masked by position), and the loop body needs no branch
-            return jnp.minimum(blk * ppb + p, last_page)
+        def slot_of(blk):
+            if isinstance(base, int) and isinstance(blk, int):
+                return (base + blk) % dma_slots
+            return jax.lax.rem(base + blk, dma_slots)
 
-        def start_block(slot, blk):
-            def copy_pages(g, carry):
-                for u in range(unroll):
-                    p = g * unroll + u
-                    page_id = tables_ref[i, page_idx(blk, p)]
-                    dst = pl.ds(pl.multiple_of(p * page_size, page_size),
-                                page_size)
-                    for half, (hbm, buf) in enumerate(halves):
-                        pltpu.make_async_copy(
-                            hbm.at[kvh, page_id], buf.at[slot, dst],
-                            sems.at[slot, half]).start()
-                return carry
+        def fetch_ahead(blk):
+            # block ``blk`` of this tile, or what follows its last one
+            @pl.when(blk < n_blk)
+            def _own():
+                start_block(kvh, i, last_page, slot_of(blk), blk)
 
-            jax.lax.fori_loop(0, ppb // unroll, copy_pages, 0)
-
-        def wait_block(slot):
-            # one wait per half for the block's ppb page copies: a DMA
-            # semaphore counts bytes, and the descriptor only sizes it
-            for half, (_, buf) in enumerate(halves):
-                pltpu.make_async_copy(
-                    buf.at[slot], buf.at[slot], sems.at[slot, half]
-                ).wait()
+            if hand_off is not None:
+                @pl.when(blk == n_blk)
+                def _next():
+                    hand_off(slot_of(blk))
 
         def page_scales(sc_ref, blk):
             # [1, kvb] per-key scale of the block's pages (int8 layout):
@@ -328,18 +367,22 @@ def _ragged_kernel(
             out = jnp.zeros((1, kvb), jnp.float32)
             for p in range(ppb):
                 out = jnp.where(col_page == p,
-                                sc_ref[sub, page_idx(blk, p)], out)
+                                sc_ref[sub, page_idx(last_page, blk, p)],
+                                out)
             return out
 
         @pl.when(n_blk > 0)
         def _run():
             # prime the pipeline: up to ``dma_slots - 1`` KV blocks in
             # flight before the loop body consumes block 0
-            start_block(0, 0)
+            if primed is None:
+                start_block(kvh, i, last_page, slot_of(0), 0)
+            else:
+                @pl.when(jnp.logical_not(primed))
+                def _cold():
+                    start_block(kvh, i, last_page, slot_of(0), 0)
             for ahead in range(1, dma_slots - 1):
-                @pl.when(ahead < n_blk)
-                def _prime(ahead=ahead):
-                    start_block(ahead, ahead)
+                fetch_ahead(ahead)
 
             # global position per q row (ascending); rows past the
             # segment's real tokens (alignment tail) are zeroed at the
@@ -356,15 +399,10 @@ def _ragged_kernel(
 
             def body(masked, blk, carry):
                 m_prev, l_prev = carry  # acc lives in scratch
-                slot = jax.lax.rem(blk, dma_slots)
+                slot = slot_of(blk)
                 # keep the pipeline ``dma_slots - 1`` blocks deep: the
                 # slot being refilled is the one consumed longest ago
-                pre = blk + dma_slots - 1
-
-                @pl.when(pre < n_blk)
-                def _prefetch():
-                    start_block(jax.lax.rem(pre, dma_slots), pre)
-
+                fetch_ahead(blk + dma_slots - 1)
                 wait_block(slot)
                 k = k_buf[slot]
                 v = k[:, :d] if shared else v_buf[slot]
@@ -418,10 +456,11 @@ def _ragged_kernel(
             # the defensive empty-context case owns its rows too
             write(jnp.zeros((rows, d), jnp.float32))
 
-    w = wide_seq_ref[j]
+    @pl.when((kvh == 0) & (j == 0))
+    def _first_cell():
+        pipe[0] = 0  # scratch starts undefined: nothing is in flight
 
-    @pl.when(w >= 0)
-    def _wide():
+    def _wide(w):
         # the whole block inside one sequence's segment (nearly every
         # block of a prefill chunk): all its rows attend as ONE tile
         def write(out):
@@ -431,23 +470,59 @@ def _ragged_kernel(
                nsub * token_block,
                q_ref[...].reshape(nsub * sub_rows, q_ref.shape[-1]), write)
 
-    @pl.when(w < 0)
     def _narrow():
         # several sequences (decode rows, a segment's head or tail) or
-        # padding: each sub-block against its own sequence's pages
+        # padding: each sub-block against its own sequence's pages.
+        # Such tiles are short (a decode row's whole context is a block
+        # or a few), so a tile that primed its own pipeline would spend
+        # most of its time waiting for its first block: each instead
+        # starts the FIRST block of the tile after it, in grid order
+        # (sub-block, q block, kv head; across grid cells too: scratch
+        # outlives a cell), as the block that follows its own last one,
+        # and says so in ``pipe`` ([0]: block 0 of the coming tile is in
+        # flight, [1]: in which slot; that tile then counts its slots
+        # from there).  The slot is the one its own pipeline would fill
+        # next, so no wait of this tile counts the copy's bytes.
+        cells = nbw * nsub  # sub-blocks a kv head
+
+        def tile_of(at):
+            """(sequence or -1, its chunk token) of sub-block ``at`` of
+            a kv head, -1 too where its q block attends as one tile."""
+            i = sub_seq_ref[at]
+            if wide:
+                i = jnp.where(wide_seq_ref[jax.lax.div(at, nsub)] < 0, i, -1)
+            return i, at * token_block - cu_ref[jnp.maximum(i, 0)]
+
         def sub_block(sub, carry):
-            i = sub_seq_ref[j * nsub + sub]
+            at = j * nsub + sub
+            i, tok0 = tile_of(at)
             # clamp for SMEM reads; the tile below runs only when active
             i_safe = jnp.maximum(i, 0)
 
             def write(out):
                 o_ref[sub] = out.astype(o_ref.dtype)
 
+            def hand_off(slot):
+                last = (kvh == pl.num_programs(0) - 1) & (at == cells - 1)
+                nxt = jnp.where(at == cells - 1, 0, at + 1)
+                head = jnp.where(at == cells - 1, kvh + 1, kvh)
+                i_nxt, tok0_nxt = tile_of(nxt)
+                i_nxt_safe = jnp.maximum(i_nxt, 0)
+                last_page, n_blk = extent(i_nxt_safe, tok0_nxt, token_block)
+
+                @pl.when(jnp.logical_not(last) & (i_nxt >= 0) & (n_blk > 0))
+                def _start():
+                    start_block(head, i_nxt_safe, last_page, slot, 0)
+                    pipe[0] = 1
+                    pipe[1] = slot
+
             @pl.when(i >= 0)
             def _tile():
-                attend(i_safe,
-                       (j * nsub + sub) * token_block - cu_ref[i_safe],
-                       sub, token_block, q_ref[sub], write)
+                primed = pipe[0] == 1
+                base = jnp.where(primed, pipe[1], 0)
+                pipe[0] = 0
+                attend(i_safe, tok0, sub, token_block, q_ref[sub], write,
+                       base, primed, hand_off)
 
             @pl.when(i < 0)
             def _padding():
@@ -459,16 +534,27 @@ def _ragged_kernel(
 
         jax.lax.fori_loop(0, nsub, sub_block, 0)
 
+    if wide:
+        w = wide_seq_ref[j]
+        pl.when(w >= 0)(functools.partial(_wide, w))
+        pl.when(w < 0)(_narrow)
+    else:
+        _narrow()
+
 
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "token_block", "use_pallas", "q_block",
-                     "pages_per_block", "dma_slots", "v_dim"))
+                     "pages_per_block", "dma_slots", "v_dim", "max_q_len"))
 def _ragged_attention(
     q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
     num_seqs, scale, token_block, use_pallas, q_block, pages_per_block,
-    dma_slots, v_dim=None,
+    dma_slots, v_dim=None, max_q_len=None,
 ):
+    """``max_q_len``: the longest segment the caller can hand in, where
+    it knows (the decode entry: 1).  At ``token_block`` or under, no
+    sequence spans two sub-blocks, so every q block goes sub-block by
+    sub-block and the one-tile path is not traced into the kernel."""
     t, h, d = q.shape
     quantized = isinstance(k_cache, tuple)
     shared = v_cache is None
@@ -490,6 +576,7 @@ def _ragged_attention(
     group = h // hkv
     nsub = q_block // token_block
     sub_rows = token_block * group
+    wide = max_q_len is None or max_q_len > token_block
     nbw = -(-t // q_block)
     t_pad = nbw * q_block
     max_pages = page_tables.shape[1]
@@ -543,7 +630,8 @@ def _ragged_attention(
             pltpu.VMEM((dma_slots, ppb * page_size, d), k_data.dtype)
         ] * (1 if shared else 2) + [
             pltpu.SemaphoreType.DMA((dma_slots, 2)),
-            pltpu.VMEM((nsub * sub_rows, dv), jnp.float32),
+            pltpu.VMEM(((nsub if wide else 1) * sub_rows, dv), jnp.float32),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
@@ -558,6 +646,7 @@ def _ragged_attention(
             dma_slots=dma_slots,
             quantized=quantized,
             shared=shared,
+            wide=wide,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qx.shape[:-1] + (dv,), q.dtype),
@@ -621,3 +710,83 @@ def ragged_paged_attention(
         num_seqs, scale, token_block, use_pallas, q_block,
         pages_per_block, dma_slots, v_dim,
     )
+
+
+#: decode rows a grid cell of the decode entry holds.  A cell is only the
+#: unit in which queries come in and outputs go out (the KV pipeline
+#: runs on from one cell's last row into the next cell's first), so it
+#: is kept small: the int8 layout's per-row scale tables are SMEM blocks
+#: a cell, two of ``[rows, max_pages]`` float32.
+DECODE_ROWS_A_CELL = 16
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "use_pallas", "v_dim", "q_block",
+                     "pages_per_block", "dma_slots"))
+def _paged_attention(
+    q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
+    v_dim, q_block, pages_per_block, dma_slots,
+):
+    b, h, d = q.shape
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    if not use_pallas:
+        return paged_attention_ref(
+            q, k_cache, v_cache, block_tables, context_lens, scale, v_dim)
+    hkv = cache_shape(k_cache)[0]
+    group = h // hkv
+    # a row's query heads of one kv head are a tile's rows: whole
+    # sublane tiles of them (20 heads on one latent kv head take 24)
+    group_p = -(-group // 8) * 8
+    qx = jnp.pad(q.reshape(b, hkv, group, d),
+                 ((0, 0), (0, 0), (0, group_p - group), (0, 0)))
+    lens = context_lens.astype(jnp.int32)
+    out = _ragged_attention(
+        qx.reshape(b, hkv * group_p, d), k_cache, v_cache, block_tables,
+        jnp.arange(b + 1, dtype=jnp.int32), (lens > 0).astype(jnp.int32),
+        lens, jnp.asarray(b, jnp.int32), scale, 1, True, q_block,
+        pages_per_block, dma_slots, v_dim, max_q_len=1)
+    return out.reshape(b, hkv, group_p, -1)[:, :, :group].reshape(b, h, -1)
+
+
+def paged_attention(
+    q: jax.Array,            # [B, H, D]: one decode token a sequence
+    k_cache,                 # [Hkv, P, page, D] or quantized tuple
+    v_cache,
+    block_tables: jax.Array,  # [B, max_pages]
+    context_lens: jax.Array,  # [B] (including the new token)
+    scale: Optional[float] = None,
+    use_pallas: Optional[bool] = None,
+    v_dim: Optional[int] = None,
+):
+    """Single-token-per-sequence paged decode attention: the decode entry
+    of the ragged kernel.  The batch is laid out, inside the jit, as
+    ``B`` segments of ONE token with ``token_block = 1``: sequence ``i``
+    is packed row ``i``, its query heads of a kv head (padded to whole
+    sublane tiles) are a tile's rows, and the tile walks its context in
+    the kernel's KV blocks with the next row's first block in flight
+    (``_ragged_kernel``'s narrow path; a row of context 0 is a padding
+    row and reads zeros).  Callers keep ``B`` rows: nothing is packed
+    eightfold.  An explicit ``use_pallas=True`` is honored as-is (kernel
+    tests rely on it; unsupported shapes then fail loudly at compile).
+    ``v_cache=None`` is the shared layout: the value is the first
+    ``v_dim`` lanes of the key row, read from the key's tile, and the
+    output is ``[B, H, v_dim]``."""
+    k_data = cache_data(k_cache)
+    if use_pallas is None:
+        use_pallas = auto_use_pallas(
+            "paged_attention",
+            paged_tiling_reason(q.shape[-1], k_data.shape[2])
+            or shared_tiling_reason(v_cache, v_dim))
+    group = q.shape[1] // k_data.shape[0]
+    _, pages_per_block, dma_slots = auto_ragged_blocks(
+        head_dim=q.shape[-1], page_size=k_data.shape[2],
+        group=-(-group // 8) * 8, kv_itemsize=k_data.dtype.itemsize,
+        q_itemsize=q.dtype.itemsize,
+        quantized=cache_is_quantized(k_cache),
+        kv_pools=1 if v_cache is None else 2)
+    return _paged_attention(
+        q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
+        v_dim, min(q.shape[0], DECODE_ROWS_A_CELL), pages_per_block,
+        dma_slots)
