@@ -263,3 +263,67 @@ def test_routed_moe_with_a_sigmoid_router_equals_the_dense_oracle():
         tfm._mlp(dense_layer, cfg, x),
         expert(dense_layer["gate_up"]["w"], dense_layer["down"]["w"], x),
         atol=2e-5)
+
+
+# ---- a share and an ep shard under a sigmoid router (ISSUE 34: the
+# refusal of PR 32 is gone; these hold what replaced it)
+def _sigmoid_layer(rng):
+    layer = _shared_expert_layer(rng)
+    del layer["shared_gate"]  # added whole, as the noaux_tc models do
+    layer["router"]["e_bias"] = jax.random.uniform(
+        jax.random.PRNGKey(11), (8,), jnp.float32, minval=-0.2, maxval=0.2)
+    return layer
+
+
+_SIGMOID = dict(moe=True, num_experts=8, num_experts_per_tok=2,
+                shared_expert_size=8, shared_expert_gate=False,
+                router_scoring="sigmoid", router_bias=True,
+                routed_scaling_factor=1.5)
+
+
+@pytest.mark.parametrize("dispatch", ["routed", "dense"])
+def test_the_shares_of_a_sigmoid_router_add_up_to_the_layer(rng, dispatch):
+    """The share test of the model-configs guide under the sigmoid router
+    with its correction bias and a scaling factor: the routed parts four
+    chips give (two experts each of an 8-wide router: selected on s + b,
+    weighted by s renormalised over both picks wherever they live, times
+    1.5) and the shared expert counted once equal the uncut layer."""
+    layer = _sigmoid_layer(rng)
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, 16))
+    whole_cfg = tfm.TransformerConfig(**_SIGMOID, moe_dispatch=dispatch)
+    whole = tfm._moe_mlp(layer, whole_cfg, x)
+    routed_only = {k: v for k, v in layer.items()
+                   if not k.startswith("shared")}
+    parts = []
+    for offset in (0, 2, 4, 6):
+        share = dict(routed_only, experts={
+            k: v[offset:offset + 2] for k, v in layer["experts"].items()})
+        cfg = dataclasses.replace(whole_cfg, num_experts=2, router_experts=8,
+                                  expert_offset=offset)
+        parts.append(tfm._moe_mlp(share, cfg, x))
+    shared_once = whole - tfm._moe_mlp(routed_only, whole_cfg, x)
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared_once),
+                               np.asarray(whole), atol=2e-6, rtol=1e-5)
+    assert float(jnp.abs(parts[0] + shared_once - whole).max()) > 1e-3
+    # the bias selects: without it another pair of experts answers
+    no_bias = dict(layer, router={"w": layer["router"]["w"]})
+    plain = tfm._moe_mlp(no_bias, dataclasses.replace(
+        whole_cfg, router_bias=False), x)
+    assert float(jnp.abs(plain - whole).max()) > 1e-3
+
+
+def test_the_ep_shards_route_as_the_sigmoid_router_does(rng, devices8):
+    """The psum expert-parallel dispatch under the same router equals the
+    one-shard layer; the all-to-all dispatch says that it does not take
+    one."""
+    layer = _sigmoid_layer(rng)
+    x = jax.random.normal(jax.random.PRNGKey(6), (16, 16))
+    cfg = tfm.TransformerConfig(**_SIGMOID)
+    want = tfm._moe_mlp(layer, cfg, x)
+    moe_ops.set_ep_mesh(build_mesh(
+        MeshConfig(expert_parallel_size=4, data_parallel_size=2), devices8))
+    got = tfm._moe_mlp(layer, cfg, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=1e-5)
+    with pytest.raises(NotImplementedError, match="all-to-all"):
+        tfm._moe_mlp(layer, dataclasses.replace(cfg, moe_dispatch="a2a"), x)

@@ -409,3 +409,175 @@ def test_auto_blocks_count_one_buffer_for_a_shared_pool():
     assert auto_ragged_blocks(head_dim=640, page_size=16, group=20,
                               kv_itemsize=2, q_itemsize=2,
                               kv_pools=1) == (16, 32, 2)
+
+
+# ------------------------------------- a window and a selection (ISSUE 34)
+def _plain(q_row, keys, values, allowed, scale):
+    """The definition, for one packed row: q_row [H, D], keys [L, D]."""
+    s = np.where(allowed[None, :], q_row @ keys.T * scale, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return (p / p.sum(-1, keepdims=True)) @ values
+
+
+def _positions(cu, q_lens, seq_lens, n, t):
+    """Global position of each packed row (-1 for padding rows)."""
+    pos = np.full(t, -1)
+    for i in range(n):
+        for j in range(int(q_lens[i])):
+            pos[int(cu[i]) + j] = int(seq_lens[i]) - int(q_lens[i]) + j
+    return pos
+
+
+def _random_keep(pos, reach, seed=0):
+    """A selection as the indexer gives one: a random third of the keys
+    at or below each row's position, the row's own key among them."""
+    rng = np.random.default_rng(seed)
+    cols = np.arange(reach)[None, :]
+    keep = (rng.random((len(pos), reach)) < 0.3) | (cols == pos[:, None])
+    return keep & (cols <= pos[:, None])
+
+
+#: the layouts the window and the selection are served over, tiny tiles
+#: (pages_per_block 2 = 32 keys a KV block, so a 21-key window and a
+#: 200-token context cross several): per-head K and V at GQA, the latent
+#: pool of the full layers (640 lanes, value the first 512) and of the
+#: sliding ones (1 152 lanes, value the first 1 024)
+_LAYOUTS = {
+    "gqa": dict(latent=None, h=8, hkv=2, d=128),
+    "latent640": dict(latent=dict(heads=16, width=640, used=576), v_dim=512),
+    "latent1152": dict(latent=dict(heads=8, width=1152, used=1088),
+                       v_dim=1024),
+}
+_MIXED = [(70, 1), (5, 1), (200, 40), (19, 19)]
+
+
+def _layout_batch(layout, specs, seed=0):
+    """(q, k_cache, v_cache, tables, cu, q_lens, seq_lens, n, kwargs)."""
+    if layout["latent"] is None:
+        q, kc, vc, tables, cu, q_lens, seq_lens, n, _ = _pack(
+            specs, layout["h"], layout["hkv"], layout["d"], 16, 4, 14,
+            seed=seed)
+        return q, kc, vc, tables, cu, q_lens, seq_lens, n, {"scale": 0.1}
+    q, pool, tables, cu, q_lens, seq_lens, n = _latent_pack(
+        specs, **layout["latent"], seed=seed)
+    return (q, pool, None, tables, cu, q_lens, seq_lens, n,
+            {"scale": 1 / 16, "v_dim": layout["v_dim"]})
+
+
+@pytest.mark.parametrize("bound", ["window", "keep"])
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_a_bounded_mixed_step_matches_the_reference_and_the_definition(
+        name, bound):
+    """A mixed step (two decode rows, a 40-token chunk ending a 200-token
+    context, a fresh prompt) under ``window=21`` or under a random
+    selection: the kernel against the reference on every row, and the
+    reference against the plain definition on the chunk's last row."""
+    q, kc, vc, tables, cu, q_lens, seq_lens, n, kw = _layout_batch(
+        _LAYOUTS[name], _MIXED)
+    pos = _positions(cu, q_lens, seq_lens, n, q.shape[0])
+    reach = tables.shape[1] * 16
+    if bound == "window":
+        kw["window"] = 21
+        allowed = (np.arange(reach)[None, :] <= pos[:, None]) & (
+            np.arange(reach)[None, :] > pos[:, None] - 21)
+    else:
+        allowed = _random_keep(pos, reach)
+        kw["keep"] = jnp.asarray(allowed)
+    args = (q, kc, vc, tables, cu, q_lens, seq_lens, n)
+    ref = ragged_paged_attention_ref(*args, **kw)
+    got = ragged_paged_attention(*args, use_pallas=True, pages_per_block=2,
+                                 q_block=16, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=3e-5)
+    row = int(cu[2]) + 39
+    if vc is None:
+        keys = np.asarray(kc)[0, np.asarray(tables[2])].reshape(
+            reach, -1)
+        want = _plain(np.asarray(q)[row], keys, keys[:, :kw["v_dim"]],
+                      allowed[row], kw["scale"])
+        np.testing.assert_allclose(np.asarray(ref)[row], want, atol=3e-5)
+    assert not np.asarray(got)[int(cu[0]) + 1:int(cu[1])].any()
+
+
+@pytest.mark.parametrize("bound", ["window", "keep"])
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_a_bounded_decode_row_through_the_decode_entry(name, bound):
+    """The same bounds through ``paged_attention`` (one token a segment),
+    against ``paged_attention_ref`` and against the mixed step's packing
+    of the same rows; a padding row of context 0 among them."""
+    from vllm_omni_tpu.ops import paged_attention, paged_attention_ref
+
+    q, kc, vc, tables, cu, q_lens, seq_lens, n, kw = _layout_batch(
+        _LAYOUTS[name], [(70, 1), (5, 1), (130, 1)], seed=3)
+    rows = np.asarray(cu[:3])
+    reach = tables.shape[1] * 16
+    pos = np.asarray(seq_lens[:3]) - 1
+    if bound == "window":
+        kw["window"] = 21
+        packed = dict(kw)
+    else:
+        keep = _random_keep(pos, reach, seed=5)
+        kw["keep"] = jnp.asarray(keep)
+        full = np.zeros((q.shape[0], reach), bool)
+        full[rows] = keep
+        packed = dict(kw, keep=jnp.asarray(full))
+    ctx = jnp.concatenate([seq_lens[:3], jnp.zeros((1,), jnp.int32)])
+    qd = jnp.concatenate([q[rows], q[rows[:1]]])
+    td = jnp.concatenate([tables[:3], tables[:1]])
+    if "keep" in kw:
+        kw["keep"] = jnp.concatenate(
+            [kw["keep"], jnp.zeros((1, reach), bool)])
+    got = paged_attention(qd, kc, vc, td, ctx, use_pallas=True, **kw)
+    ref = paged_attention_ref(qd, kc, vc, td, ctx, **kw)
+    np.testing.assert_allclose(np.asarray(got)[:3], np.asarray(ref)[:3],
+                               atol=3e-5)
+    assert not np.asarray(got)[3].any()
+    mixed = ragged_paged_attention(q, kc, vc, tables, cu, q_lens, seq_lens,
+                                   n, use_pallas=True, **packed)
+    np.testing.assert_allclose(np.asarray(mixed)[rows],
+                               np.asarray(got)[:3], atol=3e-5)
+
+
+def test_a_window_walks_only_the_blocks_it_reaches():
+    """Keys below the window may lie in pages that were given back: the
+    table then points at page 0, and whatever finite rows that page holds
+    (here 1e3 everywhere) reach no row, in the kernel or in the
+    reference."""
+    layout = _LAYOUTS["latent1152"]
+    q, pool, _, tables, cu, q_lens, seq_lens, n, kw = _layout_batch(
+        layout, [(200, 1), (200, 40)], seed=7)
+    kw["window"] = 21
+    want = ragged_paged_attention(q, pool, None, tables, cu, q_lens,
+                                  seq_lens, n, use_pallas=True,
+                                  pages_per_block=2, **kw)
+    # the pages wholly below position 200 - 40 - 21 + 1 go back
+    freed = (200 - 40 - 21 + 1) // 16
+    tables = tables.at[:, :freed].set(0)
+    pool = pool.at[0, 0].set(1e3)
+    for use_pallas in (True, False):
+        got = ragged_paged_attention(q, pool, None, tables, cu, q_lens,
+                                     seq_lens, n, use_pallas=use_pallas,
+                                     pages_per_block=2, **kw)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=3e-5)
+
+
+def test_no_window_and_no_selection_trace_to_the_kernel_that_was():
+    """``window=None`` and ``keep=None`` leave the kernel's program as it
+    was: the same jaxpr as a call that never names them, one fewer operand
+    and semaphore than a call with a selection."""
+    import jax
+
+    q, pool, _, tables, cu, q_lens, seq_lens, n, kw = _layout_batch(
+        _LAYOUTS["latent640"], _MIXED)
+
+    def call(**extra):
+        return str(jax.make_jaxpr(lambda q, pool: ragged_paged_attention(
+            q, pool, None, tables, cu, q_lens, seq_lens, n, use_pallas=True,
+            **kw, **extra))(q, pool))
+
+    plain = call()
+    assert call(window=None, keep=None) == plain
+    keep = jnp.ones((q.shape[0], tables.shape[1] * 16), bool)
+    assert call(keep=keep) != plain and call(window=21) != plain
+    assert "has_keep" not in plain and "window" not in plain

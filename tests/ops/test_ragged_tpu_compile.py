@@ -382,3 +382,291 @@ def test_kv_write_compiles_in_place_for_v5e(name, one_chip, monkeypatch):
     rows_bytes = 2 * (t + 128) * hkv * d * dtype.itemsize
     assert memory.temp_size_in_bytes < (
         pool_elems * dtype.itemsize // 16 + 2 * rows_bytes)
+
+
+# ------------------------- a window and a selection (ISSUE 34), and what
+# they leave alone
+BOUNDED = {
+    # dots3-note-prev's sliding layers: 64 heads on a 1 152-lane row whose
+    # first 1 024 lanes are the value, window 513, the window kind's pool
+    "window": dict(group=64, d=1152, v_dim=1024, pool=5155, window=513),
+    # its full layers: 128 heads on GLM's 640-lane row, a selection
+    "keep": dict(group=128, d=640, v_dim=512, pool=16384, keep=True),
+}
+
+
+@pytest.mark.parametrize("entry", ["mixed", "decode"])
+@pytest.mark.parametrize("name", sorted(BOUNDED))
+def test_bounded_attention_compiles_for_v5e(name, entry, one_chip,
+                                            monkeypatch):
+    """The kernel with ``window=513`` and with a ``keep`` operand at the
+    model's shapes (a 2 048-token mixed step and the 32-row decode entry,
+    contexts to 17 664): one custom call, which asks Mosaic for the VMEM
+    its 1 024-row tile needs, and whole 512-key blocks."""
+    from vllm_omni_tpu.ops import _dispatch
+    from vllm_omni_tpu.ops.autotune import ragged_vmem_limit
+    shape = BOUNDED[name]
+    group, d, v_dim, pool = (shape[k] for k in ("group", "d", "v_dim",
+                                                "pool"))
+    s_max, max_pages, t = 32, 1104, 2048
+    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    kw = dict(scale=0.1, v_dim=v_dim, window=shape.get("window"))
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert auto_ragged_blocks(head_dim=d, page_size=16, group=group,
+                              kv_itemsize=2, q_itemsize=2,
+                              kv_pools=1) == (8, 32, 2)
+    assert ragged_vmem_limit(d, 16, group, 2, 2, False, 1) > 8 * 2 ** 20
+    cache = sds((1, pool, 16, d), jnp.bfloat16)
+    rows = t if entry == "mixed" else s_max
+    keep = (sds((rows, max_pages * 16), jnp.bool_),) if shape.get("keep") \
+        else ()
+    if entry == "mixed":
+        def step(q, k, tables, cu, q_lens, seq_lens, num_seqs, *keep):
+            return rpa.ragged_paged_attention(
+                q, k, None, tables, cu, q_lens, seq_lens, num_seqs,
+                keep=keep[0] if keep else None, **kw)
+        args = (sds((t, group, d), jnp.bfloat16), cache,
+                sds((s_max, max_pages), jnp.int32),
+                sds((s_max + 1,), jnp.int32), sds((s_max,), jnp.int32),
+                sds((s_max,), jnp.int32), sds((1,), jnp.int32))
+    else:
+        def step(q, k, tables, lens, *keep):
+            return rpa.paged_attention(q, k, None, tables, lens,
+                                       keep=keep[0] if keep else None, **kw)
+        args = (sds((s_max, group, d), jnp.bfloat16), cache,
+                sds((s_max, max_pages), jnp.int32), sds((s_max,), jnp.int32))
+    text = jax.jit(step).lower(*args, *keep).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_indexer_compiles_for_v5e(one_chip, monkeypatch):
+    """The indexer's score kernel (64 heads x 128 over the gathered index
+    keys) and the selection by counting passes at the model's shapes, for
+    a 2 048-token step and for 32 decode rows: one custom call each, and
+    no sort of ``[T, L]``."""
+    ix = importlib.import_module("vllm_omni_tpu.ops.indexer")
+    from vllm_omni_tpu.ops import _dispatch
+    monkeypatch.setattr(ix, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pool = sds((1, 16384, 16, 128), jnp.bfloat16)
+    mixed = jax.jit(lambda q, w, p, tables, cu, q_lens, seq_lens, n:
+                    ix.topk_keep(ix.indexer_scores(
+                        q, w, p, tables, cu, q_lens, seq_lens, n), 2048)
+                    ).lower(
+        sds((2048, 64, 128), jnp.bfloat16), sds((2048, 64), jnp.float32),
+        pool, sds((32, 1104), jnp.int32), sds((33,), jnp.int32),
+        sds((32,), jnp.int32), sds((32,), jnp.int32),
+        sds((1,), jnp.int32)).compile()
+    decode = jax.jit(lambda q, w, p, tables, lens: ix.topk_keep(
+        ix.indexer_scores_decode(q, w, p, tables, lens), 2048)).lower(
+        sds((32, 64, 128), jnp.bfloat16), sds((32, 64), jnp.float32), pool,
+        sds((32, 1104), jnp.int32), sds((32,), jnp.int32)).compile()
+    for compiled in (mixed, decode):
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert " sort(" not in text
+    # the step's scores, their unsigned image and the tie ranks, a block
+    # of rows at a time: well under a GB beside the weights
+    assert mixed.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+@pytest.mark.parametrize("max_model_len", [2048, 17664])
+def test_tables_within_index_topk_skip_the_selection_on_v5e(
+        max_model_len, one_chip, monkeypatch):
+    """A 2 048-token mixed step of dots3-note-prev's leading full layer and
+    its first sliding one at their published widths, compiled for a v5e
+    under the tables of two deployments: where ``max_model_len`` is at most
+    ``index_topk`` no row can see more keys than the selection keeps, and
+    the step holds the index key's write but neither the indexer's score
+    kernel nor a ``keep`` operand (the 128-head walk alone); past it, the
+    score kernel is there once a full layer.  Both fit beside the weights
+    (the branch that skips never ran to its end on the chip in PR 34's
+    first calls, for a reason that was not the compiler's)."""
+    import functools
+    import re
+
+    from benchmark import spec
+    from benchmark.archs import dots3_note as arch
+    from vllm_omni_tpu.models.common import transformer
+    from vllm_omni_tpu.ops import _dispatch
+
+    ix = importlib.import_module("vllm_omni_tpu.ops.indexer")
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    for module in (rpa, pa, ix):
+        monkeypatch.setattr(module, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    config = spec.load_json(
+        spec.ROOT / "benchmark/configs/dots3note-l5-ep8.json")
+    config.update(num_hidden_layers=3,
+                  layer_types=config["layer_types"][:3],
+                  stage_layers={"dense_full": 1, "sparse_full": 1,
+                                "sparse_sliding": 1})
+    cfg = arch.program_config(config)
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(functools.partial(arch.init_weights, cfg=cfg,
+                                         dtype=jnp.bfloat16),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    t, s_max, pages = 2048, 32, max_model_len // 16
+    full = (sds((1, 16384, 16, 640), jnp.bfloat16), None,
+            sds((1, 16384, 16, 128), jnp.bfloat16))
+    slide = (sds((1, 5155, 16, 1152), jnp.bfloat16), None)
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda p, tok, pos, kv, slots, tables, lens, cu, q_lens, n, wslots,
+        wtables: transformer.forward_unified(
+            p, cfg, tok, pos, kv, slots, tables, lens, cu, q_lens, n,
+            window_pages=(wslots, wtables)),
+        donate_argnums=3).lower(
+        params, sds((t,), i32), sds((t,), i32), [full, full, slide],
+        sds((t,), i32), sds((s_max, pages), i32), sds((s_max,), i32),
+        sds((s_max + 1,), i32), sds((s_max,), i32), sds((1,), i32),
+        sds((t,), i32), sds((s_max, pages), i32)).compile()
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*custom-call\([^\n]*"
+                       r"tpu_custom_call", compiled.as_text())
+    assert sum("attention" in name for name in calls) == 3
+    assert sum("indexer" in name for name in calls) == (
+        2 if max_model_len > cfg.index_topk else 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+#: sha256[:16] of ``str(jax.make_jaxpr(...))`` of the decode entry and of a
+#: 2 048-token mixed step at the thinker's and at GLM-4.7-Flash's shapes,
+#: taken on the parent of PR 34 (628fc51): the programs a window and a
+#: selection must leave as they were.  (A jaxpr, not the compiled text:
+#: that holds the kernel's source lines, which move with every edit of
+#: its file.  A PR that means to change the kernel for these models
+#: replaces the digests and says so.)
+UNBOUNDED = {
+    "thinker": (dict(b=64, hkv=4, group=8, d=128, v_dim=None, pool=8192,
+                     max_pages=528),
+                ("2db6b231b2b7263c", "8b65954dda1a930e")),
+    "glm": (dict(b=32, hkv=1, group=20, d=640, v_dim=512, pool=16384,
+                 max_pages=1104),
+            ("4ae25e1a13cb3f9b", "3256837ed86428cb")),
+}
+
+
+@pytest.mark.parametrize("name", sorted(UNBOUNDED))
+def test_without_a_window_or_a_selection_the_kernel_is_the_parents(
+        name, monkeypatch):
+    import hashlib
+
+    shape, want = UNBOUNDED[name]
+    b, hkv, group, d, v_dim, pool, max_pages = (
+        shape[k] for k in ("b", "hkv", "group", "d", "v_dim", "pool",
+                           "max_pages"))
+    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
+
+    def sds(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    cache = sds((hkv, pool, 16, d), jnp.bfloat16)
+    value = None if v_dim else cache
+    decode = jax.make_jaxpr(lambda q, k, v, tables, lens: rpa.paged_attention(
+        q, k, v, tables, lens, use_pallas=True, v_dim=v_dim))(
+        sds((b, hkv * group, d), jnp.bfloat16), cache, value,
+        sds((b, max_pages), jnp.int32), sds((b,), jnp.int32))
+    mixed = jax.make_jaxpr(
+        lambda q, k, v, tables, cu, q_lens, seq_lens:
+        rpa.ragged_paged_attention(q, k, v, tables, cu, q_lens, seq_lens, 3,
+                                   use_pallas=True, v_dim=v_dim))(
+        sds((2048, hkv * group, d), jnp.bfloat16), cache, value,
+        sds((32, max_pages), jnp.int32), sds((33,), jnp.int32),
+        sds((32,), jnp.int32), sds((32,), jnp.int32))
+    got = tuple(hashlib.sha256(str(x).encode()).hexdigest()[:16]
+                for x in (decode, mixed))
+    assert got == want
+
+
+#: the same of the WHOLE decode step and of a 32-token mixed step at the
+#: tiny widths of the three families the benchmark served before PR 34
+#: (grouped-query attention with experts, GLM's latent attention,
+#: Qwen3-Next's hybrid stack), taken on 628fc51 as these tests trace them:
+#: layer kinds as data, a ``LatentKind`` a kind, the rescale, the head
+#: gate, the indexer and the second table leave their programs as they were
+STEPS = {
+    "gqa": ("bd6e7b7073a712b8", "d46c6428770d8a51"),
+    "glm": ("7777300f343087c0", "5eaccde9b5b5e930"),
+    "next": ("a34b365d82d480ce", "4c3391e96f4d6c84"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STEPS))
+def test_without_a_window_or_a_selection_the_steps_are_the_parents(name):
+    import functools
+    import hashlib
+
+    from vllm_omni_tpu.models.common import transformer as tfm
+    from vllm_omni_tpu.models.glm4_moe_lite import tiny_config as glm_tiny
+    from vllm_omni_tpu.models.qwen3_next import tiny_config as next_tiny
+
+    cfg = {"gqa": tfm.TransformerConfig.tiny_moe, "glm": glm_tiny,
+           "next": next_tiny}[name]()
+    i32, f32 = jnp.int32, jnp.float32
+
+    def sds(dims, dtype=i32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    params = jax.eval_shape(functools.partial(
+        tfm.init_params, cfg=cfg, dtype=f32), jax.random.PRNGKey(0))
+    lay = cfg.kv_layout
+    pool = sds((lay.heads, 32, 16, lay.width), f32)
+    kv = [(pool, None if lay.pools == 1 else pool)] * cfg.num_kv_layers
+    extra = {}
+    if cfg.num_state_layers:
+        state = (sds((4, cfg.linear_conv_kernel_dim - 1,
+                      cfg.linear_conv_dim), f32),
+                 sds((4, cfg.linear_num_value_heads,
+                      cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                     f32))
+        kv = tfm.HybridCaches(kv, [state] * cfg.num_state_layers)
+        extra = {"state_slots": sds((4,))}
+    decode = jax.make_jaxpr(
+        lambda p, kv, tok, pos, slots, tables, ctx, **e: tfm.forward_decode(
+            p, cfg, tok, pos, kv, slots, tables, ctx, **e))(
+        params, kv, sds((4,)), sds((4,)), sds((4,)), sds((4, 8)), sds((4,)),
+        **extra)
+    mixed = jax.make_jaxpr(
+        lambda p, kv, tok, pos, slots, tables, lens, cu, q_lens, n, **e:
+        tfm.forward_unified(p, cfg, tok, pos, kv, slots, tables, lens, cu,
+                            q_lens, n, **e))(
+        params, kv, sds((32,)), sds((32,)), sds((32,)), sds((4, 8)),
+        sds((4,)), sds((5,)), sds((4,)), sds((1,)), **extra)
+    got = tuple(hashlib.sha256(str(x).encode()).hexdigest()[:16]
+                for x in (decode, mixed))
+    assert got == STEPS[name]
+
+
+def test_dots3_weights_are_made_an_expert_at_a_time(one_chip):
+    """The benchmark's dots3-note-prev weights (the program's draw, the
+    routed experts related an expert at a time, the latent norms' weights
+    at their power) are made in one jitted call beside 10.9 GB of results
+    with under 1.5 GB of temporaries: scaling every layer's experts after
+    the draw took a second copy of them and did not load on the chip (my
+    chip run, PR 34: the set-up then hung)."""
+    import functools
+
+    from benchmark import spec
+    from benchmark.archs import dots3_note as arch
+
+    config = spec.load_json(
+        spec.ROOT / "benchmark/configs/dots3note-l5-ep8.json")
+    cfg = arch.program_config(config)
+    init = functools.partial(arch.init_weights, cfg=cfg, dtype=jnp.bfloat16)
+    memory = jax.jit(init).lower(jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=one_chip)).compile().memory_analysis()
+    assert 10.8e9 < memory.output_size_in_bytes < 11.0e9
+    assert memory.temp_size_in_bytes < 1.5e9

@@ -52,6 +52,11 @@ logger = init_logger(__name__)
 _REQUEST_HIT_CAP = 1024
 
 
+#: page 0 of the window kind's pool: what a table entry points at once its
+#: page slid out of reach; never handed out, never written
+NULL_WINDOW_PAGE = 0
+
+
 def park_key(request_id: str) -> str:
     """Tier-store key of a preempted request's parked KV run."""
     return f"park/{request_id}"
@@ -95,7 +100,8 @@ class KVCacheManager:
                  policy: Optional[OffloadPolicy] = None,
                  cache_dtype: Optional[str] = None,
                  bytes_per_token: Optional[float] = None,
-                 num_state_slots: int = 0):
+                 num_state_slots: int = 0,
+                 window: int = 0, num_window_pages: int = 0):
         if num_pages < 1 or page_size < 1:
             raise ValueError("num_pages and page_size must be positive")
         self.num_pages = num_pages
@@ -114,6 +120,27 @@ class KVCacheManager:
         # prefix hits found in the index and NOT handed out: pages hold
         # no recurrent state, so a recurrent model recomputes them
         self.prefix_hits_refused = 0
+        # ---- the second kind of PAGE (docs/kv_cache.md, "Two kinds of
+        # page"): a model with sliding-window layers keeps their rows in a
+        # pool of its own, and a sequence holds a second table over it.
+        # A query at position t reads keys t - window < s <= t there, so
+        # a page that lies wholly below ``computed - window + 1`` is given
+        # back when the sequence next grows (``_slide``) and its table
+        # entry points at ``NULL_WINDOW_PAGE``, page 0 of that pool, which
+        # is never handed out: the kernels may still copy it (a KV block
+        # starts below the window's first key) and mask what it holds.
+        # window 0 => the model has no such layer and nothing below applies
+        if window and num_window_pages < 2:
+            raise ValueError("a window needs its null page and one more")
+        self.window = window
+        self.num_window_pages = num_window_pages
+        self._wfree: list[int] = list(range(1, num_window_pages))[::-1]
+        self._wtables: dict[str, list[int]] = {}
+        self.window_pages_freed = 0
+        self.window_pages_high_water = 0
+        # hits refused because a freed window page no longer holds the
+        # rows at the prefix's boundary (counted once a request)
+        self.window_prefix_hits_refused = 0
         # resident KV layout metadata for /debug/kv (informational —
         # the allocator is layout-agnostic): the pool dtype label
         # ("int8" / "bfloat16" / None when the engine didn't say) and
@@ -213,7 +240,53 @@ class KVCacheManager:
     def can_allocate(self, request: Request, num_new_tokens: int) -> bool:
         have = len(self._tables.get(request.request_id, ()))
         need = self.pages_needed(request.num_computed_tokens + num_new_tokens)
+        if self.window and (
+                need - len(self._wtables.get(request.request_id, ()))
+                > len(self._wfree) + self._slidable(request)):
+            return False
         return need - have <= self.num_free_pages
+
+    # ------------------------------------------------- the window's pages
+    @property
+    def window_pages_in_use(self) -> int:
+        return self.num_window_pages - 1 - len(self._wfree) \
+            if self.window else 0
+
+    def _slid_out(self, request: Request) -> int:
+        """Leading window pages the request's next query cannot reach: it
+        sits at ``num_computed_tokens`` and reads down to ``window - 1``
+        keys below itself."""
+        return max(request.num_computed_tokens - self.window + 1,
+                   0) // self.page_size
+
+    def _slidable(self, request: Request) -> int:
+        table = self._wtables.get(request.request_id, ())
+        return sum(1 for p in table[:self._slid_out(request)]
+                   if p != NULL_WINDOW_PAGE)
+
+    def _slide(self, request: Request) -> None:
+        """Give back the window pages that slid out of the request's
+        reach; their table entries point at the null page."""
+        table = self._wtables.get(request.request_id)
+        if not table:
+            return
+        for i in range(min(self._slid_out(request), len(table))):
+            if table[i] != NULL_WINDOW_PAGE:
+                self._wfree.append(table[i])
+                table[i] = NULL_WINDOW_PAGE
+                self.window_pages_freed += 1
+
+    def window_table(self, request_id: str) -> list[int]:
+        return list(self._wtables.get(request_id, ()))
+
+    def window_slot_mapping(self, request_id: str, start: int,
+                            num_new_tokens: int) -> list[int]:
+        """Flat slots in the window kind's pool for the tokens at
+        ``start .. start + num_new_tokens``."""
+        table = self._wtables[request_id]
+        return [table[pos // self.page_size] * self.page_size
+                + pos % self.page_size
+                for pos in range(start, start + num_new_tokens)]
 
     @property
     def offload_enabled(self) -> bool:
@@ -277,6 +350,16 @@ class KVCacheManager:
                 "by_request": dict(sorted(self._state_slots.items())),
                 "allocs": self.state_slot_allocs,
                 "prefix_hits_refused": self.prefix_hits_refused,
+            },
+            # the second kind of page: a sequence's table over the
+            # sliding-window layers' pool (window 0 => the model has none)
+            "window_pages": {
+                "window": self.window,
+                "total": self.num_window_pages,
+                "in_use": self.window_pages_in_use,
+                "high_water": self.window_pages_high_water,
+                "freed": self.window_pages_freed,
+                "prefix_hits_refused": self.window_prefix_hits_refused,
             },
             "pending_moves": {
                 "offloads": len(self.pending_offloads),
@@ -373,6 +456,15 @@ class KVCacheManager:
             request._apc_keys = keys
         nodes = self.index.match(keys=keys)
         if not nodes:
+            return 0
+        if self.window:
+            # a hit hands out the full kind's pages; the rows the sliding
+            # layers need at the prefix's boundary lay in window pages
+            # that were given back.  Refused (and counted, once a
+            # request) until window snapshots exist (ROADMAP)
+            if not getattr(request, "_apc_refused", False):
+                request._apc_refused = True
+                self.window_prefix_hits_refused += 1
             return 0
         if self.num_state_slots:
             # a hit hands out pages, and pages hold no recurrent state:
@@ -533,6 +625,8 @@ class KVCacheManager:
                     ok = False
                     break
                 table.append(page)
+        if ok and self.window:
+            ok = self._grow_window(request, need)
         if not ok:
             self._free.extend(table[base:])
             del table[base:]
@@ -545,6 +639,24 @@ class KVCacheManager:
             self._state_slots[rid] = self._free_slots.pop()
             self.state_slot_allocs += 1
         return list(table)
+
+    def _grow_window(self, request: Request, need: int) -> bool:
+        """The window kind's half of ``allocate``: give back what slid out
+        of reach, then grow the second table to ``need`` pages (every new
+        page holds tokens this step writes).  False, with nothing grown,
+        where the pool cannot."""
+        self._slide(request)
+        wtable = self._wtables.setdefault(request.request_id, [])
+        grow = need - len(wtable)
+        if grow > len(self._wfree):
+            if not wtable:
+                del self._wtables[request.request_id]
+            return False
+        for _ in range(max(grow, 0)):
+            wtable.append(self._wfree.pop())
+        self.window_pages_high_water = max(self.window_pages_high_water,
+                                           self.window_pages_in_use)
+        return True
 
     def adopt_streamed(self, request: Request, n_tokens: int
                        ) -> Optional[list[int]]:
@@ -606,6 +718,9 @@ class KVCacheManager:
         slot = self._state_slots.pop(request.request_id, None)
         if slot is not None:
             self._free_slots.append(slot)
+        self._wfree.extend(
+            p for p in self._wtables.pop(request.request_id, ())
+            if p != NULL_WINDOW_PAGE)
         for node in self._adopted.pop(request.request_id, ()):
             self.index.release(node)
         owned = set(self.index._by_page)

@@ -117,6 +117,11 @@ class ScheduledRequest:
     # the sequence's state slot in a model with recurrent layers
     # (KVCacheManager.state_slot); -1 for every other model
     state_slot: int = -1
+    # the sequence's table over the window kind of page and the step's
+    # slots there, in a model with sliding-window layers
+    # (KVCacheManager.window_table); None for every other model
+    window_table: Optional[list[int]] = None
+    window_slots: Optional[list[int]] = None
 
     @property
     def is_prefill(self) -> bool:
@@ -735,6 +740,14 @@ class ARScheduler:
             # every scheduled row of a recurrent model has one by now
             for sc in out.prefills + out.decodes:
                 sc.state_slot = self.kv.state_slot(sc.request.request_id)
+        if getattr(self.kv, "window", 0):
+            # the second table and its slots, for the sliding-window
+            # layers' kind of page (grown with the first in allocate())
+            for sc in out.prefills + out.decodes:
+                rid = sc.request.request_id
+                sc.window_table = self.kv.window_table(rid)
+                sc.window_slots = self.kv.window_slot_mapping(
+                    rid, sc.start_pos, sc.num_new_tokens)
         return out
 
     def _preempt(self, req: Request) -> None:

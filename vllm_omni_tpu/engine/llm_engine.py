@@ -33,7 +33,10 @@ from vllm_omni_tpu.introspection import (
     register_engine,
 )
 from vllm_omni_tpu.logger import init_logger
-from vllm_omni_tpu.metrics.roofline import ctx_positions
+from vllm_omni_tpu.metrics.roofline import (
+    capped_ctx_positions,
+    ctx_positions,
+)
 from vllm_omni_tpu.metrics.stats import EngineStepMetrics
 from vllm_omni_tpu.resilience.faults import fault_point
 from vllm_omni_tpu.tracing import get_recorder
@@ -255,6 +258,7 @@ class LLMEngine:
             config = dataclasses.replace(config, async_scheduling=False,
                                          unified_batching=False)
         self._refuse_for_recurrent(model_cfg, config, draft_fn)
+        self._refuse_for_window(model_cfg, config, draft_fn)
         self._refuse_for_latent(model_cfg, config, draft_fn)
         self.config = config
         self.eos_token_id = eos_token_id
@@ -299,6 +303,10 @@ class LLMEngine:
         num_pages = config.num_pages
         self._kv_bytes_per_token: Optional[float] = None
         self._kv_layout = self._kv_bytes_per_token_layer = None
+        # a window, a selection and a layout a kind of layer: only a
+        # model that has them sets these (below)
+        self._kv_kinds = None
+        self._window = self._index_topk = num_window_pages = 0
         if isinstance(model_cfg, tfm.TransformerConfig) \
                 and config.worker_type == "ar":
             from vllm_omni_tpu.kvcache.quant import (
@@ -338,6 +346,22 @@ class LLMEngine:
                 pools=layout.pools)
             self._kv_bytes_per_token = (
                 model_cfg.num_kv_layers * self._kv_bytes_per_token_layer)
+            # a model whose attention layers are of two kinds (or keep an
+            # index key beside the row) is accounted a kind: the full
+            # kind's row and index key on ``num_pages``, the sliding
+            # kind's row on a pool sized from what the window can hold
+            self._window = (model_cfg.sliding_window
+                            if model_cfg.num_window_layers else 0)
+            self._index_topk = model_cfg.index_topk
+            if self._window or model_cfg.index_topk:
+                self._kv_kinds = self._kinds_of(model_cfg, config,
+                                                num_pages, itemsize)
+                self._kv_bytes_per_token = sum(
+                    k["layers"] * k["bytes_per_token_layer"]
+                    for k in self._kv_kinds.values())
+            if self._window:
+                num_window_pages = self._kv_kinds["sliding_attention"][
+                    "pages_total"]
         # prefix caching skips the forward for cached positions, so it
         # cannot coexist with collect_hidden (downstream stages need the
         # hidden row of EVERY prompt position) — thinker-style stages
@@ -357,7 +381,11 @@ class LLMEngine:
                             num_state_slots=(
                                 max(config.max_num_seqs, 1)
                                 if getattr(model_cfg, "num_state_layers", 0)
-                                else 0))
+                                else 0),
+                            # and a second table a sequence over the
+                            # window kind of page, for sliding layers
+                            window=self._window,
+                            num_window_pages=num_window_pages)
         sched_cfg = SchedulerConfig(
             max_num_seqs=config.max_num_seqs,
             max_num_batched_tokens=config.max_num_batched_tokens,
@@ -418,6 +446,7 @@ class LLMEngine:
                 async_scheduling=config.async_scheduling,
                 max_num_batched_tokens=config.max_num_batched_tokens,
                 deterministic_decode=config.deterministic_decode,
+                num_window_pages=num_window_pages,
             )
         if (draft_fn is not None and config.num_speculative_tokens > 0
                 and hasattr(self.runner, "set_draft_fn")):
@@ -548,6 +577,72 @@ class LLMEngine:
                 "a model with recurrent (linear-attention) layers keeps "
                 "per-sequence state beside its KV pages, which these "
                 "mechanisms do not carry yet: " + "; ".join(refused))
+
+    @staticmethod
+    def _kinds_of(model_cfg, config: "EngineConfig", num_pages: int,
+                  itemsize: int) -> dict:
+        """{layer kind: layers, row widths, bytes a cached token a layer,
+        pages} of a model whose attention layers are of two kinds or keep
+        an index key beside the row (``kv.kinds`` of the snapshot)."""
+        kinds = {}
+        page = config.page_size
+        for kind in ("full_attention", "sliding_attention"):
+            layers = model_cfg.layer_types.count(kind)
+            if not layers:
+                continue
+            layout = model_cfg.kv_layout_of(kind)
+            index = (model_cfg.index_head_dim
+                     if kind == "full_attention" and model_cfg.index_topk
+                     else 0)
+            pages = num_pages
+            if kind == "sliding_attention":
+                # what max_num_seqs sequences can hold of a window and a
+                # step's tokens, a page of slack each, and the null page
+                seqs = max(config.max_num_seqs, 1)
+                pages = -(-seqs * (model_cfg.sliding_window
+                                   + config.max_num_batched_tokens)
+                          // page) + seqs + 1
+            kinds[kind] = {
+                "layers": layers, "row_width": layout.width,
+                "row_used": layout.used, "index_width": index,
+                "bytes_per_token_layer": float(
+                    itemsize * layout.pools * layout.heads
+                    * (layout.width + index)),
+                "pages_total": pages}
+        return kinds
+
+    @staticmethod
+    def _refuse_for_window(model_cfg, config: "EngineConfig",
+                           draft_fn) -> None:
+        """A model with sliding-window layers gives a page of the window
+        kind back once it slid out of reach (docs/kv_cache.md, "Two kinds
+        of page"), so whatever needs a sequence's whole past in its pages,
+        or rolls a sequence back, is refused here by name; prefix hits are
+        refused one by one in ``KVCacheManager.match_prefix`` and
+        preemption recomputes from position 0."""
+        if not getattr(model_cfg, "num_window_layers", 0):
+            return
+        refused = []
+        if config.kv_offload:
+            refused.append("kv_offload (a parked run would lack the window "
+                           "pages that were given back)")
+        if config.kv_transfer is not None or config.engine_role != "colocated":
+            refused.append("kv_transfer (the payload carries one kind of "
+                           f"page; engine_role {config.engine_role!r})")
+        if config.num_speculative_tokens > 0 or draft_fn is not None:
+            refused.append("speculative decoding (a rejected draft rolls "
+                           "back below pages already given back)")
+        if config.kv_cache_dtype == "int8":
+            refused.append("kv_cache_dtype int8 (no quantized layout for "
+                           "the window kind's rows)")
+        if config.tensor_parallel_size > 1:
+            refused.append("tensor_parallel_size > 1 (no sharded layout "
+                           "for the window kind's pool)")
+        if refused:
+            raise ValueError(
+                "a model with sliding-window layers keeps two kinds of "
+                "page, which these mechanisms do not carry yet: "
+                + "; ".join(refused))
 
     @staticmethod
     def _refuse_for_latent(model_cfg, config: "EngineConfig",
@@ -1008,6 +1103,28 @@ class LLMEngine:
             # prefixes), every model: what fills the pool
             step_metrics.kv_pages_used.observe(
                 kv.num_pages - kv.num_free_pages)
+        # keys the step's new tokens attend where attention is bounded:
+        # the best ``index_topk`` on the full layers of a model with
+        # learned sparse attention, the last ``sliding_window`` on its
+        # sliding layers (what their rooflines count; absent otherwise)
+        capped = {}
+        for name, cap in (("kept", getattr(self, "_index_topk", 0)),
+                          ("window", getattr(self, "_window", 0))):
+            if cap:
+                for phase, scs in (("prefill", sched_out.prefills),
+                                   ("decode", sched_out.decodes)):
+                    capped[f"{phase}_ctx_{name}"] = int(sum(
+                        capped_ctx_positions(s.start_pos, s.num_new_tokens,
+                                             cap) for s in scs))
+        if step_metrics is not None and prefill_ctx + decode_ctx > 0:
+            if "decode_ctx_kept" in capped:
+                step_metrics.sparse_keep_pct.observe(
+                    100.0 * (capped["prefill_ctx_kept"]
+                             + capped["decode_ctx_kept"])
+                    / (prefill_ctx + decode_ctx))
+            if getattr(kv, "window", 0):
+                step_metrics.kv_window_pages_used.observe(
+                    kv.window_pages_in_use)
         if state_rows and step_metrics is not None:
             # slots in use while the step ran: a sequence that finished in
             # it has given its slot back by now, but was one of its rows
@@ -1049,6 +1166,7 @@ class LLMEngine:
                                   for s in sched_out.prefills),
             "prefill_ctx": int(prefill_ctx),
             "decode_ctx": int(decode_ctx),
+            **capped,
             "wide_q_tokens": wide,
             "sampler_path": ("full_sort" if full_sort
                              else "bounded" if bounded else None),
@@ -1180,6 +1298,20 @@ class LLMEngine:
                 row_width=layout.width,
                 row_used=layout.used,
                 bytes_per_token_layer=self._kv_bytes_per_token_layer)
+        if self._kv_kinds:
+            # the layout a kind of layer: rows, index keys, bytes, pages
+            snap["kv"]["kinds"] = self._kv_kinds
+        if getattr(kv, "window", 0):
+            # the second kind of page; the ``kv_window_pages_used``
+            # histogram rides step_metrics' snapshot
+            snap["window"] = {
+                "window": kv.window,
+                "pages_total": kv.num_window_pages,
+                "pages_in_use": kv.window_pages_in_use,
+                "pages_high_water": kv.window_pages_high_water,
+                "pages_freed_total": kv.window_pages_freed,
+                "prefix_hits_refused_total": kv.window_prefix_hits_refused,
+            }
         snap["prefix_cache"] = self.prefix_cache_stats
         if getattr(kv, "num_state_slots", 0):
             # the second kind of cache (docs/kv_cache.md); the

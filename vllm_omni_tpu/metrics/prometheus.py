@@ -73,6 +73,23 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     "prefix_cache_hit_tokens_total": (
         "counter", "Prompt tokens served from the prefix cache",
         ("stage",)),
+    # ---- the window kind of page of models with sliding-window layers,
+    # and the selection of models with learned sparse attention
+    # (docs/kv_cache.md, "Two kinds of page")
+    "kv_window_pages_used": (
+        "histogram",
+        "Pages of the window kind held, observed once a step", ("stage",)),
+    "sparse_keep_pct": (
+        "histogram",
+        "Share of the visible keys a step's rows attend on sparse layers",
+        ("stage",)),
+    "window_prefix_hits_refused_total": (
+        "counter",
+        "Prefix-cache hits not handed out because freed window pages "
+        "no longer hold the boundary's rows", ("stage",)),
+    "window_pages_freed_total": (
+        "counter", "Window pages given back after sliding out of reach",
+        ("stage",)),
     # ---- the state cache of models with recurrent layers
     # (docs/kv_cache.md): one slot a sequence beside its pages
     "state_slots": (
@@ -587,6 +604,17 @@ def render_exposition(summary: dict, engine_snaps: dict,
             for name in ("state_slot_allocs_total", "state_resets_total",
                          "prefix_hits_refused_total"):
                 exp.sample(name, labels, state.get(name, 0))
+        window = snap.get("window")
+        if window:
+            exp.histogram("kv_window_pages_used", labels,
+                          snap["kv_window_pages_used"])
+            exp.sample("window_prefix_hits_refused_total", labels,
+                       window.get("prefix_hits_refused_total", 0))
+            exp.sample("window_pages_freed_total", labels,
+                       window.get("pages_freed_total", 0))
+        if snap.get("sparse_keep_pct", {}).get("count"):
+            exp.histogram("sparse_keep_pct", labels,
+                          snap["sparse_keep_pct"])
         tiers = snap.get("kv_tiers")
         if tiers:
             exp.sample("kv_prefix_hit_tokens_total", labels,

@@ -181,6 +181,15 @@ def ctx_positions(start_pos: int, num_new: int) -> float:
     return n * max(int(start_pos), 0) + n * (n + 1) / 2.0
 
 
+def capped_ctx_positions(start_pos: int, num_new: int, cap: int) -> float:
+    """``ctx_positions`` where a token attends at most ``cap`` keys (a
+    sliding window, or a learned selection of the ``cap`` best): token i
+    attends ``min(start_pos + i + 1, cap)`` positions."""
+    n, start = max(int(num_new), 0), max(int(start_pos), 0)
+    under = min(max(cap - start, 0), n)  # tokens that still see < cap keys
+    return ctx_positions(start, under) + (n - under) * float(cap)
+
+
 class RooflineTracker:
     """Rolling per-step MFU/MBU window for one engine.
 

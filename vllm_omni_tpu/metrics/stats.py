@@ -92,6 +92,8 @@ KV_PAGE_BUCKETS = (64.0, 256.0, 1024.0, 2048.0, 4096.0, 8192.0, 12288.0,
                    16384.0, 24576.0, 32768.0, 65536.0, 131072.0)
 
 # state slots in use (a count of sequences, up to max_num_seqs)
+PERCENT_BUCKETS = (1.0, 2.0, 5.0, 10.0, 20.0, 30.0, 40.0, 50.0, 60.0,
+                   70.0, 80.0, 90.0, 100.0)
 STATE_SLOT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
 
 # KV tier-restore latency buckets, in SECONDS: sub-ms for small
@@ -363,6 +365,13 @@ class EngineStepMetrics:
         # every model: how full the traffic keeps the pool (a latent
         # cache's pages and per-head K/V pages count alike)
         self.kv_pages_used = Histogram(buckets=KV_PAGE_BUCKETS)
+        # pages of the window kind held, once such a step, by a model
+        # with sliding-window layers (never observed for other models)
+        self.kv_window_pages_used = Histogram(buckets=KV_PAGE_BUCKETS)
+        # of the keys a step's rows see on the full layers of a model with
+        # learned sparse attention, the share they attend:
+        # 100 x sum min(ctx, top_k) / sum ctx over the step's new tokens
+        self.sparse_keep_pct = Histogram(buckets=PERCENT_BUCKETS)
         # SLO targets (None = unconfigured leg always passes) + the
         # per-tenant attainment/goodput ledger they gate
         self.slo_ttft_ms: Optional[float] = None
@@ -512,6 +521,8 @@ class EngineStepMetrics:
             "intake_ms": self.intake_ms.snapshot(),
             "state_slots": self.state_slots.snapshot(),
             "kv_pages_used": self.kv_pages_used.snapshot(),
+            "kv_window_pages_used": self.kv_window_pages_used.snapshot(),
+            "sparse_keep_pct": self.sparse_keep_pct.snapshot(),
             "saturation": dict(self.saturation),
             "slo": {
                 "targets": {"ttft_ms": self.slo_ttft_ms,

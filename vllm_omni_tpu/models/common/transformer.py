@@ -33,6 +33,32 @@ from vllm_omni_tpu.ops import (
     write_kv_cache,
 )
 from vllm_omni_tpu.ops import gated_delta_rule as gdr
+from vllm_omni_tpu.ops import indexer as indexer_ops
+
+
+@dataclass(frozen=True)
+class LatentKind:
+    """One kind of latent-attention layer: its head count, its ranks, a
+    head's widths and its rotary base.  A model whose layers are of two
+    kinds (full and sliding-window) has two of these; the full kind's is
+    read from ``TransformerConfig``'s own fields (``latent_kind``)."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+
+    @property
+    def row(self) -> int:
+        """Numbers a cached token holds: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def scale(self) -> float:
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
 
 
 @dataclass(frozen=True)
@@ -152,9 +178,34 @@ class TransformerConfig:
     # reference, against the benchmark's limit of 0.15; with this,
     # 0.03-0.13 (PERF.md, PR 28)
     residual_in_fp32: bool = False
+    # ---- layers of several kinds, as data: one of "full_attention",
+    # "sliding_attention", "linear_attention" a layer (the published
+    # ``layer_types``); None => derived from ``full_attention_interval``
+    layer_kinds: Optional[tuple[str, ...]] = None
+    # a "sliding_attention" layer is latent attention with widths of its
+    # own (``sliding``) over the last ``sliding_window`` keys, the token
+    # itself among them; its rows live in pages of a second kind that are
+    # given back once they slide out of reach (docs/kv_cache.md)
+    sliding: Optional[LatentKind] = None
+    sliding_window: int = 0
+    # learned sparse attention on the full layers (index_topk > 0): an
+    # indexer of ``index_n_heads`` heads of ``index_head_dim`` scores every
+    # cached token (one index key a token, in a pool beside the latent
+    # rows) and the ``index_topk`` best are attended (ops/indexer.py)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # the latents are multiplied by sqrt(hidden / rank) after their norms
+    # (scale-corrected latent attention)
+    mla_lora_rescale: bool = False
+    # one sigmoid gate a head on the attention output, from the layer's
+    # normed input (``attn_output_gate`` is the element-wise one)
+    attn_head_gate: bool = False
 
     @property
     def layer_types(self) -> tuple[str, ...]:
+        if self.layer_kinds is not None:
+            return self.layer_kinds
         n = self.full_attention_interval
         return tuple(
             "linear_attention" if n and (i + 1) % n else "full_attention"
@@ -163,12 +214,26 @@ class TransformerConfig:
     @property
     def num_kv_layers(self) -> int:
         """Layers that hold KV pages (the softmax-attention ones)."""
-        return self.layer_types.count("full_attention")
+        return self.num_layers - self.num_state_layers
+
+    @property
+    def num_window_layers(self) -> int:
+        """Of those, the ones whose pages are of the window kind."""
+        return self.layer_types.count("sliding_attention")
 
     @property
     def num_state_layers(self) -> int:
         """Layers that hold a per-sequence recurrent state."""
-        return self.num_layers - self.num_kv_layers
+        return self.layer_types.count("linear_attention")
+
+    def latent_kind(self, kind: str = "full_attention") -> LatentKind:
+        """The latent widths of a kind of layer."""
+        if kind == "sliding_attention":
+            return self.sliding
+        return LatentKind(self.num_heads, self.q_lora_rank,
+                          self.kv_lora_rank, self.qk_nope_head_dim,
+                          self.qk_rope_head_dim, self.v_head_dim,
+                          self.rope_theta)
 
     @property
     def rotary_dim(self) -> int:
@@ -176,26 +241,37 @@ class TransformerConfig:
             return self.qk_rope_head_dim
         return int(self.head_dim * self.partial_rotary_factor)
 
-    @property
-    def kv_layout(self) -> "KVLayout":
-        """What one attention layer's paged cache holds a token."""
+    def kv_layout_of(self, kind: str = "full_attention") -> "KVLayout":
+        """What the paged cache of one attention layer of this kind holds
+        a token."""
         if self.kv_lora_rank:
-            row = self.kv_lora_rank + self.qk_rope_head_dim
+            row = self.latent_kind(kind).row
             return KVLayout("latent", 1, -(-row // 128) * 128, 1, row)
         return KVLayout("kv", self.num_kv_heads, self.head_dim, 2,
                         self.head_dim)
 
     @property
-    def attn_kwargs(self) -> dict:
+    def kv_layout(self) -> "KVLayout":
+        """The full kind's (the only kind, but for a model with
+        sliding-window layers)."""
+        return self.kv_layout_of("full_attention")
+
+    def attn_kwargs_of(self, kind: str = "full_attention") -> dict:
         """What the paged attention ops are told beyond their operands:
         nothing for per-head K and V pages (the scale is the head's); for
         a latent pool the scale of the un-absorbed head and the lanes of
-        a row that are the value."""
+        a row that are the value; for a sliding-window layer its window."""
         if not self.kv_lora_rank:
             return {}
-        return {"scale": (self.qk_nope_head_dim
-                          + self.qk_rope_head_dim) ** -0.5,
-                "v_dim": self.kv_lora_rank}
+        lk = self.latent_kind(kind)
+        out = {"scale": lk.scale, "v_dim": lk.kv_lora_rank}
+        if kind == "sliding_attention":
+            out["window"] = self.sliding_window
+        return out
+
+    @property
+    def attn_kwargs(self) -> dict:
+        return self.attn_kwargs_of("full_attention")
 
     @property
     def linear_conv_dim(self) -> int:
@@ -282,10 +358,18 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
         if kind == "linear_attention":
             layer["linear_attn"] = _gdn_init(k, cfg, dtype)
         elif cfg.kv_lora_rank:
-            layer["mla"] = _mla_init(k, cfg, dtype)
+            lk = cfg.latent_kind(kind)
+            layer["mla"] = _mla_init(k, cfg, lk, dtype)
             layer["o_proj"] = nn.linear_init(
-                k[3], cfg.num_heads * cfg.v_head_dim, cfg.hidden_size,
+                k[3], lk.num_heads * lk.v_head_dim, cfg.hidden_size,
                 bias=False, dtype=dtype)
+            if cfg.attn_head_gate:
+                layer["attn_gate"] = nn.linear_init(
+                    jax.random.fold_in(k[3], 1), cfg.hidden_size,
+                    lk.num_heads, bias=False, dtype=dtype)
+            if cfg.index_topk and kind == "full_attention":
+                layer["indexer"] = _indexer_init(
+                    jax.random.fold_in(k[3], 2), cfg, lk, dtype)
         else:
             # gated attention doubles q_proj: a head's columns are [q | gate]
             q_out = q_dim * (2 if cfg.attn_output_gate else 1)
@@ -352,30 +436,54 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
     return params
 
 
-def _mla_init(k, cfg: TransformerConfig, dtype):
-    """One latent-attention mixer's weights beside ``o_proj``, under the
-    checkpoint's names without their suffixes (``q_a_proj``,
-    ``q_a_layernorm``, ``q_b_proj``, ``kv_a_proj_with_mqa``,
-    ``kv_a_layernorm``, ``kv_b_proj``).  ``kv_b`` stays one matrix
-    [kv_lora_rank, H * (qk_nope + v)], a head's columns [k_nope | v]; the
-    forward cuts it into the two halves it absorbs."""
-    if not cfg.q_lora_rank:
+def _mla_init(k, cfg: TransformerConfig, lk: LatentKind, dtype):
+    """One latent-attention mixer's weights beside ``o_proj``, at the
+    widths of the layer's kind, under the checkpoint's names without their
+    suffixes (``q_a_proj``, ``q_a_layernorm``, ``q_b_proj``,
+    ``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``).  ``kv_b``
+    stays one matrix [kv_lora_rank, H * (qk_nope + v)], a head's columns
+    [k_nope | v]; the forward cuts it into the two halves it absorbs."""
+    if not lk.q_lora_rank:
         raise ValueError("latent attention without q_lora_rank (a plain "
                          "q_proj) is not built")
-    h, r = cfg.hidden_size, cfg.kv_lora_rank
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    h, r = cfg.hidden_size, lk.kv_lora_rank
+    qk = lk.qk_nope_head_dim + lk.qk_rope_head_dim
     return {
-        "q_a": nn.linear_init(k[0], h, cfg.q_lora_rank, bias=False,
+        "q_a": nn.linear_init(k[0], h, lk.q_lora_rank, bias=False,
                               dtype=dtype),
-        "q_a_norm": nn.rmsnorm_init(cfg.q_lora_rank, dtype),
-        "q_b": nn.linear_init(k[1], cfg.q_lora_rank, cfg.num_heads * qk,
+        "q_a_norm": nn.rmsnorm_init(lk.q_lora_rank, dtype),
+        "q_b": nn.linear_init(k[1], lk.q_lora_rank, lk.num_heads * qk,
                               bias=False, dtype=dtype),
-        "kv_a": nn.linear_init(k[2], h, r + cfg.qk_rope_head_dim,
+        "kv_a": nn.linear_init(k[2], h, r + lk.qk_rope_head_dim,
                                bias=False, dtype=dtype),
         "kv_a_norm": nn.rmsnorm_init(r, dtype),
         "kv_b": nn.linear_init(
-            k[5], r, cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+            k[5], r, lk.num_heads * (lk.qk_nope_head_dim + lk.v_head_dim),
             bias=False, dtype=dtype),
+    }
+
+
+def _indexer_init(key, cfg: TransformerConfig, lk: LatentKind, dtype):
+    """The indexer of a full layer (``wq_b``, ``wk``, ``k_norm``,
+    ``weights_proj`` of the DeepSeek-V3.2 checkpoint): queries from the
+    layer's query latent, one key a token from its normed input through a
+    LayerNorm (weight and bias), a weight a head from the same input."""
+    kq, kk, kw, kb = jax.random.split(key, 4)
+    n, d = cfg.index_n_heads, cfg.index_head_dim
+    return {
+        "wq": nn.linear_init(kq, lk.q_lora_rank, n * d, bias=False,
+                             dtype=dtype),
+        "wk": nn.linear_init(kk, cfg.hidden_size, d, bias=False,
+                             dtype=dtype),
+        # the published initialiser writes ones and zeros: a little noise
+        # keeps weight and bias visible to every comparison
+        "k_norm": {"w": (1.0 + jax.random.uniform(
+            kb, (d,), jnp.float32, minval=-0.1, maxval=0.1)).astype(dtype),
+                   "b": jax.random.uniform(
+            jax.random.fold_in(kb, 1), (d,), jnp.float32, minval=-0.1,
+            maxval=0.1).astype(dtype)},
+        "w": nn.linear_init(kw, cfg.hidden_size, n, bias=False,
+                            dtype=dtype),
     }
 
 
@@ -501,19 +609,25 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
         router = _router_kwargs(layer, cfg)
         shared_out = layer["router"]["w"].shape[-1] != \
             layer["experts"]["gate_up"].shape[0]
-        if router and (mesh is not None or shared_out):
-            raise NotImplementedError(
-                "a sigmoid / correction-bias / scaled router is routed on "
-                "one shard only: the expert-parallel and expert-share "
-                "dispatches take the plain softmax router")
-        if mesh is not None:
-            ep_fn = (moe_ops.routed_moe_ep_a2a
-                     if cfg.moe_dispatch == "a2a"
-                     else moe_ops.routed_moe_ep)
-            out = ep_fn(
+        if mesh is not None and cfg.moe_dispatch == "a2a":
+            if router:
+                raise NotImplementedError(
+                    "the all-to-all expert-parallel dispatch takes the "
+                    "plain softmax router; a sigmoid / correction-bias / "
+                    "scaled router rides the psum dispatch (moe_dispatch "
+                    "'routed') or an expert share")
+            out = moe_ops.routed_moe_ep_a2a(
                 x, layer["router"]["w"], layer["experts"]["gate_up"],
                 layer["experts"]["down"], cfg.num_experts_per_tok, mesh,
                 renormalize=cfg.moe_renormalize,
+            )
+        elif mesh is not None:
+            # the shards route as routed_moe does (the activations'
+            # dtype) unless the router says otherwise
+            out = moe_ops.routed_moe_ep(
+                x, layer["router"]["w"], layer["experts"]["gate_up"],
+                layer["experts"]["down"], cfg.num_experts_per_tok, mesh,
+                renormalize=cfg.moe_renormalize, **router,
             )
         elif shared_out:
             # the chip's share of an expert-parallel deployment: route
@@ -523,6 +637,7 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
                 x, layer["router"]["w"], layer["experts"]["gate_up"],
                 layer["experts"]["down"], cfg.num_experts_per_tok,
                 cfg.expert_offset, renormalize=cfg.moe_renormalize,
+                **router,
             )
         else:
             out = moe_ops.routed_moe(
@@ -551,13 +666,19 @@ def _mlp(layer, cfg: TransformerConfig, x):
 
 
 @jax.named_scope("omni.proj")
-def _rope_tables(cfg: TransformerConfig, positions):
+def _rope_tables(cfg: TransformerConfig, positions,
+                 kind: Optional[LatentKind] = None):
     """cos/sin tables from positions, 1-D or multimodal 3-D.
 
     1-D rope: positions [B, S] (prefill) or [B] (decode).  MRoPE
     (cfg.mrope_sections set): [B, 3, S] or [B, 3] — the three streams are
     flattened batch-major to match the activations' [B*S] layout.
+    ``kind``: the tables of a kind of latent layer that has a rotary
+    width and base of its own.
     """
+    if kind is not None:
+        return compute_rope_freqs(
+            positions.reshape(-1), kind.qk_rope_head_dim, kind.rope_theta)
     if cfg.mrope_sections is None:
         return compute_rope_freqs(
             positions.reshape(-1), cfg.rotary_dim, cfg.rope_theta
@@ -675,7 +796,42 @@ def _join_lanes(parts, width: int):
     return jnp.concatenate(blocks, axis=-2).reshape(*lead, width)
 
 
-def _mla_mixer(layer, cfg: TransformerConfig, h, cos, sin, attend):
+def _kind_ropes(cfg: TransformerConfig, positions, mrope_positions=None):
+    """{layer kind: (cos, sin)} for the kinds the stack has: one entry for
+    every model but one whose sliding-window layers rotate at a base of
+    their own."""
+    first = _rope_tables(cfg, positions if mrope_positions is None
+                         else mrope_positions)
+    ropes = {kind: first for kind in set(cfg.layer_types)}
+    if cfg.sliding is not None:
+        ropes["sliding_attention"] = _rope_tables(cfg, positions,
+                                                  cfg.sliding)
+    return ropes
+
+
+def _indexer_inputs(ix, cfg: TransformerConfig, h, c_q, cos, sin):
+    """The indexer's operands for normed rows h [T, hidden] and their
+    query latent: (q [T, Hi, Di], key [T, Di], w [T, Hi] float32).  The
+    first rotary-width dims of a query head and of the key are rotated at
+    the token's position; the weights carry both scales."""
+    t = h.shape[0]
+    n, d, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    q = nn.linear(ix["wq"], c_q).reshape(t, n, d)
+    q = _join_lanes([apply_rope_ref(q[..., :dr], cos, sin), q[..., dr:]], d)
+    k = nn.linear(ix["wk"], h).astype(jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = (k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                           + cfg.rms_eps)
+         * ix["k_norm"]["w"].astype(jnp.float32)
+         + ix["k_norm"]["b"].astype(jnp.float32)).astype(h.dtype)
+    k = _join_lanes(
+        [apply_rope_ref(k[:, None, :dr], cos, sin), k[:, None, dr:]], d)
+    w = nn.linear(ix["w"], h).astype(jnp.float32) * (n ** -0.5 * d ** -0.5)
+    return q, k[:, 0], w
+
+
+def _mla_mixer(layer, cfg: TransformerConfig, lk: LatentKind, h, cos, sin,
+               attend):
     """Multi-head latent attention on normed rows h [T, hidden], in its
     absorbed form: ``kv_b`` cut a head into ``W_UK`` [r, nope] and
     ``W_UV`` [r, v], the query is taken into the latent space (``q_lat =
@@ -688,29 +844,54 @@ def _mla_mixer(layer, cfg: TransformerConfig, h, cos, sin, attend):
     the context's size.  ``attend(q [T, H, W], row [T, 1, W], None)``
     writes the step's rows and attends (the ``attend`` of the forward,
     its value operand absent) and returns [T, H, r].  Every product with
-    ``kv_b`` runs under ``omni.mla_up``."""
+    ``kv_b`` runs under ``omni.mla_up``.
+
+    ``lk`` holds the widths of the layer's kind (a model may have two).
+    What a layer has beyond that it says by its weights: an ``indexer``
+    (learned sparse attention: its operands go to ``attend`` as
+    ``index=``, which writes the index key beside the row and attends the
+    selected keys only), an ``attn_gate`` (one sigmoid gate a head on the
+    output); ``cfg.mla_lora_rescale`` multiplies both latents by
+    sqrt(hidden / rank) after their norms."""
     m = layer["mla"]
     t = h.shape[0]
-    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    width = cfg.kv_layout.width
+    dn, r = lk.qk_nope_head_dim, lk.kv_lora_rank
+    width = -(-lk.row // 128) * 128
+
+    def latent(x, norm, rank):
+        x = _rms(cfg, x, norm)
+        if cfg.mla_lora_rescale:
+            x = x.astype(jnp.float32) * (cfg.hidden_size / rank) ** 0.5
+        return x.astype(h.dtype)
+
     with jax.named_scope("omni.proj"):
-        c_q = _rms(cfg, nn.linear(m["q_a"], h), m["q_a_norm"]).astype(h.dtype)
-        q = nn.linear(m["q_b"], c_q).reshape(t, -1, dn + cfg.qk_rope_head_dim)
+        c_q = latent(nn.linear(m["q_a"], h), m["q_a_norm"], lk.q_lora_rank)
+        q = nn.linear(m["q_b"], c_q).reshape(t, -1, dn + lk.qk_rope_head_dim)
         kv = nn.linear(m["kv_a"], h)
-        c_kv = _rms(cfg, kv[:, :r], m["kv_a_norm"]).astype(h.dtype)
+        c_kv = latent(kv[:, :r], m["kv_a_norm"], r)
         # rotate-half over all of the rotary part; the one rotary key is
         # every head's (elementwise work XLA fuses: no kernel at width 64)
         q_rope = apply_rope_ref(q[..., dn:], cos, sin)
         k_rope = apply_rope_ref(kv[:, None, r:], cos, sin)
         row = _join_lanes([c_kv[:, None, :], k_rope], width)
-    w_kvb = m["kv_b"]["w"].reshape(r, -1, dn + cfg.v_head_dim)
+    w_kvb = m["kv_b"]["w"].reshape(r, -1, dn + lk.v_head_dim)
     with jax.named_scope("omni.mla_up"):
         q_lat = jnp.einsum("thn,rhn->thr", q[..., :dn], w_kvb[..., :dn])
         q_full = _join_lanes([q_lat, q_rope], width)
-    o_lat = attend(q_full, row, None).reshape(t, -1, r)
+    if "indexer" in layer:
+        with jax.named_scope("omni.indexer"):
+            index = _indexer_inputs(layer["indexer"], cfg, h, c_q, cos, sin)
+        o_lat = attend(q_full, row, None, index=index)
+    else:
+        o_lat = attend(q_full, row, None)
+    o_lat = o_lat.reshape(t, -1, r)
     with jax.named_scope("omni.mla_up"):
         o = jnp.einsum("thr,rhv->thv", o_lat, w_kvb[..., dn:])
     with jax.named_scope("omni.proj"):
+        if "attn_gate" in layer:
+            gate = jax.nn.sigmoid(
+                nn.linear(layer["attn_gate"], h).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         return _out_proj(cfg, o.reshape(t, -1), layer["o_proj"]["w"])
 
 
@@ -721,7 +902,8 @@ def _out_proj(cfg: TransformerConfig, o, w):
     return o @ w
 
 
-def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
+def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
+                kind: str = "full_attention"):
     """One transformer block: norm → mixer → residual → norm → MLP →
     residual.  In a softmax-attention layer the mixer is qkv+rope →
     ``attend(q, k, v)``, which supplies the attention variant (dense
@@ -744,7 +926,8 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend):
         o = _gdn_mixer(layer["linear_attn"], cfg,
                        h.reshape(-1, h.shape[-1]), attend).reshape(*b, -1)
     elif "mla" in layer:
-        o = _mla_mixer(layer, cfg, h.reshape(-1, h.shape[-1]), cos, sin,
+        o = _mla_mixer(layer, cfg, cfg.latent_kind(kind),
+                       h.reshape(-1, h.shape[-1]), cos, sin,
                        attend).reshape(*b, -1)
     else:
         with jax.named_scope("omni.proj"):
@@ -857,6 +1040,11 @@ def forward_hidden(
     (pipeline_flux2_klein.py:247-302).  When set, the concatenation is
     returned instead of the final hidden states.
     """
+    if cfg.sliding is not None or cfg.index_topk:
+        raise NotImplementedError(
+            "the cache-less full-sequence forward has no window and no "
+            "learned selection: a model with sliding-window or sparse "
+            "layers is served through forward_unified / forward_decode")
     b, s = token_ids.shape
     x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
     if positions is None:
@@ -932,6 +1120,44 @@ def logits_from_hidden(params, cfg: TransformerConfig, hidden: jax.Array):
     return logits
 
 
+def _paged_attend(cfg: TransformerConfig, kind: str, cache, new_caches,
+                  slots, tables, attention, scores):
+    """The ``attend`` of a forward over paged caches for one layer of
+    ``kind``: write the step's rows at ``slots`` into the layer's pools,
+    then ``attention(q, k_cache, v_cache, tables, **kwargs)``.  A layer's
+    cache is ``(k, v)``, ``(pool, None)`` for a latent layer, and ``(pool,
+    None, index_pool)`` for one with an indexer: its index key is written
+    at the same slots, and where the tables reach past ``index_topk`` keys
+    ``scores(q, w, index_pool, tables)`` and the selection by rank give the
+    attention its ``keep`` (a context that short keeps every key, by the
+    shapes of the call)."""
+    sliding = kind == "sliding_attention"
+    kwargs = cfg.attn_kwargs_of(kind)
+
+    def attend(q, k, v, index=None):
+        k_cache, v_cache = cache[:2]
+        with jax.named_scope("omni.kv_write"):
+            k_cache, v_cache = write_kv_cache(k_cache, v_cache, k, v, slots)
+            if index is not None:
+                index_pool, _ = write_kv_cache(
+                    cache[2], None, index[1][:, None, :], None, slots)
+        keep = {}
+        if index is None:
+            new_caches.append((k_cache, v_cache))
+        else:
+            new_caches.append((k_cache, v_cache, index_pool))
+            if tables.shape[1] * k_cache.shape[2] > cfg.index_topk:
+                with jax.named_scope("omni.indexer"):
+                    keep["keep"] = indexer_ops.topk_keep(
+                        scores(index[0], index[2], index_pool, tables),
+                        cfg.index_topk)
+        with jax.named_scope(
+                "omni.attn_window" if sliding else "omni.attn"):
+            return attention(q, k_cache, v_cache, tables, **kwargs, **keep)
+
+    return attend
+
+
 def forward_unified(
     params,
     cfg: TransformerConfig,
@@ -948,6 +1174,7 @@ def forward_unified(
     embeds_mask: Optional[jax.Array] = None,    # [T] True=row uses embeds
     deepstack: Optional[jax.Array] = None,      # [n_deep, T, hidden]
     state_slots: Optional[jax.Array] = None,    # [S] slot of each sequence
+    window_pages: Optional[tuple] = None,       # (slots [T], tables [S, n])
 ):
     """Unified ragged mixed-batch forward: prefill chunks and 1-token
     decode rows share ONE token-packed execution (ops/
@@ -972,31 +1199,37 @@ def forward_unified(
     chunk starts the sequence) and write it at the chunk's end, through
     the chunked form of the rule.
 
+    A model with sliding-window layers passes ``window_pages``: the slot
+    mapping and the page tables of the window kind of page, which its
+    sliding layers write and read in place of ``slot_mapping`` and
+    ``page_tables`` (docs/kv_cache.md, "Two kinds of page").
+
     Returns (hidden [T, hidden], new kv_caches of the type passed).
     """
     hybrid = isinstance(kv_caches, HybridCaches)
     kv_caches, state_caches = kv_caches if hybrid else (kv_caches, None)
     x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
-    if cfg.mrope_sections is None:
-        cos, sin = _rope_tables(cfg, positions)
-    else:
-        # [3, T] -> the [B, 3, S] convention with B=1
-        cos, sin = _rope_tables(cfg, positions[None])
+    # [3, T] -> the [B, 3, S] convention with B=1
+    ropes = _kind_ropes(cfg, positions, None if cfg.mrope_sections is None
+                        else positions[None])
     new_caches, new_states = [], []
+
+    def attention(q, k_cache, v_cache, tables, **kwargs):
+        return ragged_paged_attention(
+            q, k_cache, v_cache, tables, cu_q_lens, q_lens, seq_lens,
+            num_seqs, **kwargs)
+
+    def scores(q, w, pool, tables):
+        return indexer_ops.indexer_scores(
+            q, w, pool, tables, cu_q_lens, q_lens, seq_lens, num_seqs)
+
     for i, (layer, cache) in enumerate(
             _layer_caches(params, kv_caches, state_caches)):
-        def attend(q, k, v, cache=cache):
-            k_cache, v_cache = cache
-            with jax.named_scope("omni.kv_write"):
-                k_cache, v_cache = write_kv_cache(
-                    k_cache, v_cache, k, v, slot_mapping
-                )
-            new_caches.append((k_cache, v_cache))
-            with jax.named_scope("omni.attn"):
-                return ragged_paged_attention(
-                    q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
-                    seq_lens, num_seqs, **cfg.attn_kwargs,
-                )
+        kind = cfg.layer_types[i]
+        attend = _paged_attend(
+            cfg, kind, cache, new_caches,
+            *(window_pages if kind == "sliding_attention"
+              else (slot_mapping, page_tables)), attention, scores)
 
         # a sequence starts where its context is this step's tokens alone
         recur = _stateful_recur(
@@ -1004,8 +1237,8 @@ def forward_unified(
             lambda *a: gdr.gated_delta_net_ragged(
                 *a, cu_q_lens, q_lens, cfg.gdn_heads))
 
-        x = _layer_step(layer, cfg, x, cos, sin,
-                        recur if "linear_attn" in layer else attend)
+        x = _layer_step(layer, cfg, x, *ropes[kind],
+                        recur if "linear_attn" in layer else attend, kind)
         if deepstack is not None and i < deepstack.shape[0]:
             with jax.named_scope("omni.embed"):
                 x = x + deepstack[i].astype(x.dtype)
@@ -1024,6 +1257,7 @@ def forward_decode(
     block_tables: jax.Array,  # [B, max_pages]
     context_lens: jax.Array,  # [B] (including the new token)
     state_slots: Optional[jax.Array] = None,  # [B] slot of each row
+    window_pages: Optional[tuple] = None,     # (slots [B], tables [B, n])
 ):
     """One decode step over a batch of sequences with paged attention;
     the Gated DeltaNet layers of a hybrid model update each row's state
@@ -1035,28 +1269,31 @@ def forward_decode(
     hybrid = isinstance(kv_caches, HybridCaches)
     kv_caches, state_caches = kv_caches if hybrid else (kv_caches, None)
     x = _embed_input(params, token_ids, None, None)  # [B, hidden]
-    cos, sin = _rope_tables(cfg, positions)
+    ropes = _kind_ropes(cfg, positions)
     new_caches, new_states = [], []
-    for layer, cache in _layer_caches(params, kv_caches, state_caches):
-        def attend(q, k, v, cache=cache):
-            k_cache, v_cache = cache
-            with jax.named_scope("omni.kv_write"):
-                k_cache, v_cache = write_kv_cache(
-                    k_cache, v_cache, k, v, slot_mapping
-                )
-            new_caches.append((k_cache, v_cache))
-            with jax.named_scope("omni.attn"):
-                return paged_attention(
-                    q, k_cache, v_cache, block_tables, context_lens,
-                    **cfg.attn_kwargs,
-                )
+
+    def attention(q, k_cache, v_cache, tables, **kwargs):
+        return paged_attention(q, k_cache, v_cache, tables, context_lens,
+                               **kwargs)
+
+    def scores(q, w, pool, tables):
+        return indexer_ops.indexer_scores_decode(q, w, pool, tables,
+                                                 context_lens)
+
+    for i, (layer, cache) in enumerate(
+            _layer_caches(params, kv_caches, state_caches)):
+        kind = cfg.layer_types[i]
+        attend = _paged_attend(
+            cfg, kind, cache, new_caches,
+            *(window_pages if kind == "sliding_attention"
+              else (slot_mapping, block_tables)), attention, scores)
 
         recur = _stateful_recur(
             cache, state_slots, context_lens <= 1, new_states,
             lambda *a: gdr.gated_delta_net_decode(*a, cfg.gdn_heads))
 
-        x = _layer_step(layer, cfg, x, cos, sin,
-                        recur if "linear_attn" in layer else attend)
+        x = _layer_step(layer, cfg, x, *ropes[kind],
+                        recur if "linear_attn" in layer else attend, kind)
     if hybrid:
         new_caches = HybridCaches(new_caches, new_states)
     return _final_norm(params, cfg, x), new_caches

@@ -173,6 +173,12 @@ _AR_MODELS: dict[str, _Entry] = {
     "Glm4MoeLiteForCausalLM": _Entry(
         "vllm_omni_tpu.models.glm4_moe_lite", "real_factory"
     ),
+    # latent attention of two kinds (a learned selection on the full
+    # layers, a window on the others) over two kinds of page
+    # (dots3-note-prev; docs/kv_cache.md)
+    "Dots3NoteForCausalLM": _Entry(
+        "vllm_omni_tpu.models.dots3_note", "real_factory"
+    ),
 }
 
 

@@ -98,6 +98,50 @@ RAGGED_MAX_PAGES_PER_BLOCK = 32
 RAGGED_MAX_Q_BLOCK = 128
 
 
+#: the most VMEM the ragged kernel asks Mosaic for, where its smallest
+#: tile does not fit ``RAGGED_VMEM_CAP`` (a v5e has 128 MiB; Mosaic's own
+#: limit is 16 MiB until a kernel asks for more)
+RAGGED_VMEM_MAX = 64 * 1024 * 1024
+
+
+def _ragged_need(bq, ppb, slots, head_dim, page_size, group, kv_itemsize,
+                 q_itemsize, quantized, kv_pools) -> int:
+    """Bytes of VMEM the ragged kernel's buffers take at these blocks."""
+    rows, kvb = bq * group, ppb * page_size
+    # q block and output block (double-buffered by the grid
+    # pipeline) + f32 accumulator
+    need = rows * head_dim * (4 * q_itemsize + 4)
+    # f32 score block and its live temporaries (mask, p, p in the
+    # matmul dtype)
+    need += 4 * rows * kvb * 4
+    # K and V buffers, or the one buffer of a shared pool
+    need += kv_pools * slots * kvb * head_dim * kv_itemsize
+    if quantized:
+        # the float staging copy of the block being consumed (k, v)
+        need += 2 * kvb * head_dim * 4
+    return need
+
+
+def ragged_vmem_limit(head_dim: int, page_size: int, group: int = 1,
+                      kv_itemsize: int = 2, q_itemsize: int = 4,
+                      quantized: bool = False, kv_pools: int = 2):
+    """None where the kernel's smallest q tile (the packer's 8 tokens x
+    ``group`` heads) fits ``RAGGED_VMEM_CAP`` with whole KV blocks, as at
+    every geometry served before PR 34: the kernel then asks Mosaic for
+    nothing.  Else the bytes it asks for (``vmem_limit_bytes``) and
+    ``auto_ragged_blocks`` budgets with: 128 latent heads on one kv head
+    are 1024 rows of 640 lanes a tile, which at the cap left one page an
+    iteration."""
+    ppb = max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
+                     RAGGED_KV_BLOCK // page_size))
+    need = _ragged_need(8, ppb, 2, head_dim, page_size, max(group, 1),
+                        kv_itemsize, q_itemsize, quantized, kv_pools)
+    if need <= RAGGED_VMEM_CAP:
+        return None
+    # half as much again: Mosaic's own temporaries beside the model's
+    return min(need + need // 2, RAGGED_VMEM_MAX)
+
+
 @functools.lru_cache(maxsize=64)
 def auto_ragged_blocks(
     head_dim: int,
@@ -147,21 +191,17 @@ def auto_ragged_blocks(
     ppb = max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
                      RAGGED_KV_BLOCK // page_size))
     slots = 2
+    if vmem_cap_bytes == RAGGED_VMEM_CAP:
+        # a geometry whose smallest tile overruns the cap budgets with
+        # what the kernel then asks Mosaic for (``ragged_vmem_limit``)
+        vmem_cap_bytes = ragged_vmem_limit(
+            head_dim, page_size, group, kv_itemsize, q_itemsize, quantized,
+            kv_pools) or vmem_cap_bytes
 
     def fits(bq, ppb):
-        rows, kvb = bq * group, ppb * page_size
-        # q block and output block (double-buffered by the grid
-        # pipeline) + f32 accumulator
-        need = rows * head_dim * (4 * q_itemsize + 4)
-        # f32 score block and its live temporaries (mask, p, p in the
-        # matmul dtype)
-        need += 4 * rows * kvb * 4
-        # K and V buffers, or the one buffer of a shared pool
-        need += kv_pools * slots * kvb * head_dim * kv_itemsize
-        if quantized:
-            # the float staging copy of the block being consumed (k, v)
-            need += 2 * kvb * head_dim * 4
-        return need <= vmem_cap_bytes
+        return _ragged_need(bq, ppb, slots, head_dim, page_size, group,
+                            kv_itemsize, q_itemsize, quantized,
+                            kv_pools) <= vmem_cap_bytes
 
     while not fits(bq, ppb):
         if ppb > 1:

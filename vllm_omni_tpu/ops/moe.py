@@ -131,7 +131,7 @@ def routed_moe(
 
 def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
                      renormalize: bool = True,
-                     float32_logits: bool = True) -> jax.Array:
+                     float32_logits: bool = True, **router) -> jax.Array:
     """What the experts held here give: ``gate_up`` / ``down`` stack the
     experts ``expert_offset .. expert_offset + E_held`` of a router that
     is ``router_w.shape[-1]`` wide.  Every token is routed over the whole
@@ -142,10 +142,13 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     where the grouped matmul computes no row, and are masked out of the
     sum.  This is one chip's share of an expert-parallel deployment (the
     model-configs cut) and, followed by a psum, the body of every ``ep``
-    shard."""
+    shard.  ``router`` is ``router_topk``'s scoring, bias and scale: the
+    share of a sigmoid router with its correction bias routes as the
+    whole model does."""
     e_held = gate_up.shape[0]
-    topk_idx, topk_w = router_topk(x, router_w, k, renormalize,
-                                   float32_logits=float32_logits)
+    topk_idx, topk_w = router_topk(
+        x, router_w, k, renormalize,
+        **{"float32_logits": float32_logits, **router})
     flat_e = topk_idx.reshape(-1) - expert_offset
     mine = (flat_e >= 0) & (flat_e < e_held)
     local_e = jnp.where(mine, flat_e, e_held)
@@ -162,13 +165,13 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
 
 
 def _routed_moe_ep_shard(x, router_w, gate_up, down, k: int,
-                         renormalize: bool = True):
+                         renormalize: bool = True, **router):
     """Per-ep-shard body: full token set, local expert slab; the psum over
     ep sums exactly one live contribution per pair."""
     lo = jax.lax.axis_index("ep") * gate_up.shape[0]
     # the ep shards keep the routing of routed_moe, their one-chip twin
     out = routed_moe_share(x, router_w, gate_up, down, k, lo, renormalize,
-                           float32_logits=False)
+                           **{"float32_logits": False, **router})
     return jax.lax.psum(out, "ep")
 
 
@@ -276,25 +279,29 @@ def routed_moe_ep_a2a(x, router_w, gate_up, down,
 
 
 def routed_moe_ep(x, router_w, gate_up, down, num_experts_per_tok: int,
-                  mesh, renormalize: bool = True) -> jax.Array:
+                  mesh, renormalize: bool = True, bias=None,
+                  **router) -> jax.Array:
     """Expert-parallel routed MoE: experts sharded over the ``ep`` mesh
     axis; tokens stay sharded over ``dp`` (replicated only over ep —
     each dp rank computes its own token slice, each ep shard its local
-    experts, one psum over ep combines)."""
+    experts, one psum over ep combines).  ``bias`` and ``router`` as
+    ``router_topk`` takes them (the bias replicated on every shard)."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     ax = dict(zip(mesh.axis_names, mesh.devices.shape))
     dp = ax.get("dp", 1)
     tok_spec = P("dp") if x.shape[0] % max(dp, 1) == 0 else P()
+    extra = () if bias is None else (bias,)
     fn = shard_map(
-        lambda xx, rw, gu, dn: _routed_moe_ep_shard(
-            xx, rw, gu, dn, num_experts_per_tok, renormalize),
+        lambda xx, rw, gu, dn, *b: _routed_moe_ep_shard(
+            xx, rw, gu, dn, num_experts_per_tok, renormalize,
+            **router, **({"bias": b[0]} if b else {})),
         mesh=mesh,
-        in_specs=(tok_spec, P(), P("ep"), P("ep")),
+        in_specs=(tok_spec, P(), P("ep"), P("ep")) + (P(),) * len(extra),
         out_specs=tok_spec,
     )
-    return fn(x, router_w, gate_up, down)
+    return fn(x, router_w, gate_up, down, *extra)
 
 
 # ------------------------------------------------------------------ EPLB

@@ -506,7 +506,11 @@ def paged_attention_ref(
     context_lens: jax.Array,  # [B] int32
     scale: Optional[float] = None,
     v_dim: Optional[int] = None,
+    window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,  # [B, >= max_pages * page] bool
 ):
+    """``window``: the row's token (at ``context_lens - 1``) admits the
+    last ``window`` keys only; ``keep``: and only those it marks."""
     b, h, d = q.shape
     hkv, _, page, _ = cache_shape(k_cache)
     group = h // hkv
@@ -527,6 +531,10 @@ def paged_attention_ref(
     s = jnp.einsum("bngd,bnld->bngl", qg, kg.astype(jnp.float32)) * scale
     pos = jnp.arange(max_pages * page)[None, None, None, :]
     mask = pos < context_lens[:, None, None, None]
+    if window is not None:
+        mask &= pos >= (context_lens - window)[:, None, None, None]
+    if keep is not None:
+        mask &= keep[:, None, None, :max_pages * page]
     s = jnp.where(mask, s, _NEG_INF)
     p_ = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bngl,bnld->bngd", p_, vg.astype(jnp.float32))

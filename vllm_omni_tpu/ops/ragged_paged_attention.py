@@ -78,7 +78,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
-from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
+from vllm_omni_tpu.ops.autotune import auto_ragged_blocks, ragged_vmem_limit
 from vllm_omni_tpu.ops.paged_attention import (
     cache_data,
     cache_is_quantized,
@@ -125,6 +125,8 @@ def ragged_paged_attention_ref(
     num_seqs,                # int | [] | [1]
     scale: Optional[float] = None,
     v_dim: Optional[int] = None,
+    window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,  # [T, >= max_ctx] bool
 ):
     """Pure-XLA reference with identical semantics (fp32 softmax).
 
@@ -173,6 +175,10 @@ def ragged_paged_attention_ref(
         & (k_pos[None, :] <= q_pos[:, None])
         & valid[:, None]
     )
+    if window is not None:
+        mask &= k_pos[None, :] > q_pos[:, None] - window
+    if keep is not None:
+        mask &= keep[:, :max_ctx]
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(mask[:, None, None, :], jnp.exp(s - m), 0.0)
@@ -253,7 +259,15 @@ def _ragged_kernel(
     # then outputs o_ref [nsub, token_block * group, Dv] and scratch
     # k_buf/v_buf [dma_slots, ppb * page, D] (no v_buf when shared),
     # sems [dma_slots, 2], acc_scr [nsub * token_block * group, Dv],
-    # pipe [2] SMEM: what one narrow tile leaves the next (see _narrow)
+    # pipe [2] SMEM: what one narrow tile leaves the next (see _narrow).
+    # With a selection (``has_keep``): keep_hbm [T / token_block,
+    # token_block * keep_rep, L] f32 ANY/HBM after the pools (0 where a
+    # token admits a key, -1e30 where not; a sub-block's rows are one
+    # slab; a row a token where the group is whole sublane tiles, else
+    # one a (token, head) as the scores lie) and keep_buf [dma_slots,
+    # nsub | 1, token_block * keep_rep, ppb * page] after the KV buffers:
+    # a KV block's columns come in with the block; sems then
+    # [dma_slots, 3]
     *refs,
     page_size: int,
     token_block: int,
@@ -265,14 +279,23 @@ def _ragged_kernel(
     quantized: bool,
     shared: bool,
     wide: bool,
+    window: Optional[int] = None,
+    has_keep: bool = False,
+    keep_rep: int = 1,
 ):
     refs = list(refs)
     v_hbm = None if shared else refs.pop(0)
+    keep_hbm = refs.pop(0) if has_keep else None
     k_sc_ref, v_sc_ref = (refs.pop(0), refs.pop(0)) if quantized \
         else (None, None)
     o_ref, k_buf = refs.pop(0), refs.pop(0)
     v_buf = None if shared else refs.pop(0)
+    keep_buf = refs.pop(0) if has_keep else None
     sems, acc_scr, pipe = refs
+    # a window or a selection can hide a whole KV block from a row, so
+    # every block is masked and a hidden key's probability is set to 0
+    # (without either, block 0 holds key 0, which every row sees)
+    bounded = window is not None or has_keep
     # the pools a KV block is copied from, each into its buffer
     halves = ((k_hbm, k_buf),) if shared else ((k_hbm, k_buf),
                                                (v_hbm, v_buf))
@@ -286,15 +309,22 @@ def _ragged_kernel(
     d = o_ref.shape[-1]  # the value's width (the key's, unless shared)
 
     def extent(i, tok0, ntok):
-        """(last page, KV blocks) of the tile of ``ntok`` packed tokens
-        of sequence ``i`` from its chunk token ``tok0``."""
+        """(last page, end of the KV blocks, first KV block) of the tile
+        of ``ntok`` packed tokens of sequence ``i`` from its chunk token
+        ``tok0``."""
         q_len = qlen_ref[i]
         # the loop ends at the last causal key of the tile's last real
         # token, not at the context's end: a chunk's early tiles never
         # fetch the pages above their diagonal
         kv_end = slen_ref[i] - q_len + jnp.minimum(tok0 + ntok, q_len)
+        first = 0
+        if window is not None:
+            # and starts at the block that holds the lowest key the
+            # tile's FIRST token admits
+            first = jax.lax.div(jnp.maximum(
+                slen_ref[i] - q_len + tok0 - (window - 1), 0), kvb)
         return (jax.lax.div(kv_end + page_size - 1, page_size) - 1,
-                jax.lax.div(kv_end + kvb - 1, kvb))
+                jax.lax.div(kv_end + kvb - 1, kvb), first)
 
     def page_idx(last_page, blk, p):
         # the ragged last block refetches the last page into its spare
@@ -302,7 +332,17 @@ def _ragged_kernel(
         # position), and the loop body needs no branch
         return jnp.minimum(blk * ppb + p, last_page)
 
-    def start_block(head, i, last_page, slot, blk):
+    def start_block(head, i, last_page, slot, blk, sub0, subs):
+        """Start the copies of KV block ``blk`` of sequence ``i`` into
+        ``slot``; with a selection, also the block's columns of the
+        tile's ``subs`` sub-blocks from sub-block ``sub0`` on."""
+        if has_keep:
+            pltpu.make_async_copy(
+                keep_hbm.at[pl.ds(sub0, subs), :,
+                            pl.ds(pl.multiple_of(blk * kvb, kvb), kvb)],
+                keep_buf.at[slot, pl.ds(0, subs)],
+                sems.at[slot, 2]).start()
+
         def copy_pages(g, carry):
             for u in range(unroll):
                 p = g * unroll + u
@@ -317,15 +357,18 @@ def _ragged_kernel(
 
         jax.lax.fori_loop(0, ppb // unroll, copy_pages, 0)
 
-    def wait_block(slot):
+    def wait_block(slot, subs):
         # one wait per half for the block's ppb page copies: a DMA
         # semaphore counts bytes, and the descriptor only sizes it
         for half, (_, buf) in enumerate(halves):
             pltpu.make_async_copy(
                 buf.at[slot], buf.at[slot], sems.at[slot, half]
             ).wait()
+        if has_keep:
+            part = keep_buf.at[slot, pl.ds(0, subs)]
+            pltpu.make_async_copy(part, part, sems.at[slot, 2]).wait()
 
-    def attend(i, tok0, sub, ntok, q, write, base=0, primed=None,
+    def attend(i, tok0, sub, ntok, q, write, sub0, base=0, primed=None,
                hand_off=None):
         """Online softmax of one q tile — ``ntok`` packed tokens of
         sequence ``i`` from its chunk token ``tok0`` (rows pack
@@ -336,13 +379,20 @@ def _ragged_kernel(
         ``primed`` holds, block 0 is already on its way there (the tile
         before started it), and ``hand_off(slot)`` is called once, when
         the pipeline would reach past this tile's last block, with the
-        slot that block would take: the next tile's to start in."""
+        slot that block would take: the next tile's to start in.
+        ``sub0`` is the tile's first sub-block among the packed ones
+        (where its rows of the selection lie).  Under a window the
+        tile's first block is ``blk0``, not 0, and slots count from
+        there."""
         rows = ntok * group
+        subs = ntok // token_block
         q_len = qlen_ref[i]
         ctx_len = slen_ref[i]
-        last_page, n_blk = extent(i, tok0, ntok)
+        last_page, n_blk, blk0 = extent(i, tok0, ntok)
 
         def slot_of(blk):
+            if window is not None:
+                blk = blk - blk0
             if isinstance(base, int) and isinstance(blk, int):
                 return (base + blk) % dma_slots
             return jax.lax.rem(base + blk, dma_slots)
@@ -351,7 +401,8 @@ def _ragged_kernel(
             # block ``blk`` of this tile, or what follows its last one
             @pl.when(blk < n_blk)
             def _own():
-                start_block(kvh, i, last_page, slot_of(blk), blk)
+                start_block(kvh, i, last_page, slot_of(blk), blk, sub0,
+                            subs)
 
             if hand_off is not None:
                 @pl.when(blk == n_blk)
@@ -376,13 +427,15 @@ def _ragged_kernel(
             # prime the pipeline: up to ``dma_slots - 1`` KV blocks in
             # flight before the loop body consumes block 0
             if primed is None:
-                start_block(kvh, i, last_page, slot_of(0), 0)
+                start_block(kvh, i, last_page, slot_of(blk0), blk0, sub0,
+                            subs)
             else:
                 @pl.when(jnp.logical_not(primed))
                 def _cold():
-                    start_block(kvh, i, last_page, slot_of(0), 0)
+                    start_block(kvh, i, last_page, slot_of(blk0), blk0,
+                                sub0, subs)
             for ahead in range(1, dma_slots - 1):
-                fetch_ahead(ahead)
+                fetch_ahead(blk0 + ahead)
 
             # global position per q row (ascending); rows past the
             # segment's real tokens (alignment tail) are zeroed at the
@@ -393,7 +446,7 @@ def _ragged_kernel(
             # KV blocks wholly at or below the FIRST row's position are
             # visible to every row: they run without the mask, which
             # only the diagonal blocks need
-            n_full = jnp.minimum(
+            n_full = blk0 if bounded else jnp.minimum(
                 jax.lax.div(ctx_len - q_len + tok0 + 1, kvb), n_blk)
             acc = acc_scr.at[pl.ds(0, rows)]
 
@@ -403,7 +456,7 @@ def _ragged_kernel(
                 # keep the pipeline ``dma_slots - 1`` blocks deep: the
                 # slot being refilled is the one consumed longest ago
                 fetch_ahead(blk + dma_slots - 1)
-                wait_block(slot)
+                wait_block(slot, subs)
                 k = k_buf[slot]
                 v = k[:, :d] if shared else v_buf[slot]
                 if k.dtype != q.dtype:
@@ -419,10 +472,23 @@ def _ragged_kernel(
                     preferred_element_type=jnp.float32) * scale
                 if quantized:
                     s = s * page_scales(k_sc_ref, blk)
-                if masked:
+                if has_keep:
+                    # the selection holds causality too (a token keeps
+                    # no key above itself): its 0 / -1e30 is added, a
+                    # token's row to each of its ``group`` heads
+                    keep = keep_buf[slot, pl.ds(0, subs)]
+                    if keep_rep == 1:
+                        s = (s.reshape(ntok, group, kvb)
+                             + keep.reshape(ntok, 1, kvb)).reshape(rows, kvb)
+                    else:
+                        s = s + keep.reshape(rows, kvb)
+                elif masked:
                     k_pos = blk * kvb + jax.lax.broadcasted_iota(
                         jnp.int32, s.shape, 1)
-                    s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+                    ok = k_pos <= q_pos
+                    if window is not None:
+                        ok &= k_pos > q_pos - window
+                    s = jnp.where(ok, s, _NEG_INF)
                 m_new = jnp.maximum(
                     m_prev, jnp.max(s, axis=-1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
@@ -430,6 +496,11 @@ def _ragged_kernel(
                 # holds key 0, which every real row sees, so m_new is a
                 # real score from the first iteration on
                 p = jnp.exp(s - m_new)
+                if bounded:
+                    # not so under a window or a selection: a row that
+                    # sees nothing of this block (nor of those before)
+                    # has m_new = -1e30 and would count its keys as 1
+                    p = jnp.where(s > 0.5 * _NEG_INF, p, 0.0)
                 l_new = l_prev * alpha + jnp.sum(p, axis=-1,
                                                  keepdims=True)
                 if quantized:
@@ -445,8 +516,10 @@ def _ragged_kernel(
             # never count; they keep the discarded alignment-tail rows
             # away from 0 / 0
             l0 = jnp.ones((rows, 1), jnp.float32)
-            carry = jax.lax.fori_loop(
-                0, n_full, functools.partial(body, False), (m0, l0))
+            carry = (m0, l0)
+            if not bounded:
+                carry = jax.lax.fori_loop(
+                    0, n_full, functools.partial(body, False), carry)
             _, l_fin = jax.lax.fori_loop(
                 n_full, n_blk, functools.partial(body, True), carry)
             write(jnp.where(row_tok < q_len, acc[...] / l_fin, 0.0))
@@ -468,7 +541,8 @@ def _ragged_kernel(
 
         attend(w, j * (nsub * token_block) - cu_ref[w], 0,
                nsub * token_block,
-               q_ref[...].reshape(nsub * sub_rows, q_ref.shape[-1]), write)
+               q_ref[...].reshape(nsub * sub_rows, q_ref.shape[-1]), write,
+               j * nsub if has_keep else 0)
 
     def _narrow():
         # several sequences (decode rows, a segment's head or tail) or
@@ -508,11 +582,13 @@ def _ragged_kernel(
                 head = jnp.where(at == cells - 1, kvh + 1, kvh)
                 i_nxt, tok0_nxt = tile_of(nxt)
                 i_nxt_safe = jnp.maximum(i_nxt, 0)
-                last_page, n_blk = extent(i_nxt_safe, tok0_nxt, token_block)
+                last_page, n_blk, first = extent(i_nxt_safe, tok0_nxt,
+                                                 token_block)
 
                 @pl.when(jnp.logical_not(last) & (i_nxt >= 0) & (n_blk > 0))
                 def _start():
-                    start_block(head, i_nxt_safe, last_page, slot, 0)
+                    start_block(head, i_nxt_safe, last_page, slot, first,
+                                nxt, 1)
                     pipe[0] = 1
                     pipe[1] = slot
 
@@ -522,7 +598,7 @@ def _ragged_kernel(
                 base = jnp.where(primed, pipe[1], 0)
                 pipe[0] = 0
                 attend(i_safe, tok0, sub, token_block, q_ref[sub], write,
-                       base, primed, hand_off)
+                       at, base, primed, hand_off)
 
             @pl.when(i < 0)
             def _padding():
@@ -545,16 +621,20 @@ def _ragged_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "token_block", "use_pallas", "q_block",
-                     "pages_per_block", "dma_slots", "v_dim", "max_q_len"))
+                     "pages_per_block", "dma_slots", "v_dim", "max_q_len",
+                     "window"))
 def _ragged_attention(
     q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
     num_seqs, scale, token_block, use_pallas, q_block, pages_per_block,
-    dma_slots, v_dim=None, max_q_len=None,
+    dma_slots, v_dim=None, max_q_len=None, window=None, keep=None,
 ):
     """``max_q_len``: the longest segment the caller can hand in, where
     it knows (the decode entry: 1).  At ``token_block`` or under, no
     sequence spans two sub-blocks, so every q block goes sub-block by
-    sub-block and the one-tile path is not traced into the kernel."""
+    sub-block and the one-tile path is not traced into the kernel.
+    ``window`` (static) and ``keep`` ([T, L] bool, L at least the page
+    tables' reach) as ``ragged_paged_attention`` has them; without
+    either the kernel traces as it did before they existed."""
     t, h, d = q.shape
     quantized = isinstance(k_cache, tuple)
     shared = v_cache is None
@@ -567,7 +647,7 @@ def _ragged_attention(
     if not use_pallas:
         return ragged_paged_attention_ref(
             q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
-            seq_lens, num_seqs, scale, v_dim,
+            seq_lens, num_seqs, scale, v_dim, window, keep,
         )
     if t % token_block or q_block % token_block:
         raise ValueError(
@@ -608,6 +688,27 @@ def _ragged_attention(
         qx,
         k_data,
     ] + ([] if shared else [v_data])
+    keep_scratch = []
+    if keep is not None:
+        # 0 where the token admits the key, -1e30 where not or past what
+        # the caller gave; a sub-block's rows one slab, L whole KV blocks
+        # past the tables' reach.  A token's row serves its whole group
+        # where that is whole sublane tiles (the kernel broadcasts it);
+        # a narrower group gets a row a (token, head), as the scores lie
+        keep_rep = 1 if group % 8 == 0 else group
+        kvb = ppb * page_size
+        reach = -(-max_pages * page_size // kvb) * kvb
+        bias = jnp.where(keep[:, :reach], 0.0, _NEG_INF).astype(jnp.float32)
+        bias = jnp.pad(bias, ((0, t_pad - t), (0, reach - bias.shape[1])),
+                       constant_values=_NEG_INF)
+        if keep_rep > 1:
+            bias = jnp.repeat(bias, keep_rep, axis=0)
+        operands.append(bias.reshape(t_pad // token_block,
+                                     token_block * keep_rep, reach))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        keep_scratch = [pltpu.VMEM(
+            (dma_slots, nsub if wide else 1, token_block * keep_rep, kvb),
+            jnp.float32)]
     if quantized:
         # per SUB-BLOCK, its sequence's scales in page-table order (a
         # q block may hold several sequences): [Hkv, NBW, nsub,
@@ -628,12 +729,17 @@ def _ragged_attention(
         out_specs=q_spec(dv),
         scratch_shapes=[
             pltpu.VMEM((dma_slots, ppb * page_size, d), k_data.dtype)
-        ] * (1 if shared else 2) + [
-            pltpu.SemaphoreType.DMA((dma_slots, 2)),
+        ] * (1 if shared else 2) + keep_scratch + [
+            pltpu.SemaphoreType.DMA((dma_slots, 3 if keep_scratch else 2)),
             pltpu.VMEM(((nsub if wide else 1) * sub_rows, dv), jnp.float32),
             pltpu.SMEM((2,), jnp.int32),
         ],
     )
+    # None at every geometry whose smallest tile fits the picker's cap
+    vmem_limit = ragged_vmem_limit(
+        d, page_size, -(-group // 8) * 8 if token_block == 1 else group,
+        k_data.dtype.itemsize, q.dtype.itemsize, quantized,
+        1 if shared else 2)
     out = pl.pallas_call(
         functools.partial(
             _ragged_kernel,
@@ -647,10 +753,16 @@ def _ragged_attention(
             quantized=quantized,
             shared=shared,
             wide=wide,
+            **({} if window is None else {"window": window}),
+            **({"has_keep": True, "keep_rep": keep_rep}
+               if keep_scratch else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qx.shape[:-1] + (dv,), q.dtype),
         interpret=interpret_flag(),
+        **({} if vmem_limit is None else {
+            "compiler_params": pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit)}),
     )(*operands)
     # [Hkv, NBW, nsub, tb*group, Dv] -> [T, H, Dv]
     out = out.reshape(hkv, t_pad, group, dv)[:, :t]
@@ -673,6 +785,8 @@ def ragged_paged_attention(
     pages_per_block: Optional[int] = None,
     dma_slots: Optional[int] = None,
     v_dim: Optional[int] = None,
+    window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,
 ):
     """Mixed prefill+decode paged attention over a token-packed batch.
 
@@ -684,7 +798,17 @@ def ragged_paged_attention(
     fails loudly if unsupported.  ``q_block`` (tokens a grid cell),
     ``pages_per_block`` (pages a loop iteration) and ``dma_slots`` (KV
     blocks in the DMA pipeline) default to the per-shape
-    ``auto_ragged_blocks`` choice."""
+    ``auto_ragged_blocks`` choice.
+
+    ``window`` (a Python int): the query at position ``t`` admits key
+    ``s`` iff ``t - window < s <= t``; a tile's walk then starts at the
+    KV block that holds its first token's lowest key, so a decode row
+    reads ``window`` keys' blocks at any context.  ``keep`` ``[T, L]``
+    bool (``L`` at least ``max_pages * page_size``): packed row ``t``
+    admits key ``s`` iff ``keep[t, s]`` (a selection; it holds causality
+    itself: no key above the token is kept); its columns come into VMEM
+    with each KV block.  Neither changes what the kernel is without
+    them."""
     quantized = cache_is_quantized(k_cache)
     k_data = cache_data(k_cache)
     if use_pallas is None:
@@ -708,7 +832,7 @@ def ragged_paged_attention(
     return _ragged_attention(
         q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
         num_seqs, scale, token_block, use_pallas, q_block,
-        pages_per_block, dma_slots, v_dim,
+        pages_per_block, dma_slots, v_dim, window=window, keep=keep,
     )
 
 
@@ -723,17 +847,18 @@ DECODE_ROWS_A_CELL = 16
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "use_pallas", "v_dim", "q_block",
-                     "pages_per_block", "dma_slots"))
+                     "pages_per_block", "dma_slots", "window"))
 def _paged_attention(
     q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
-    v_dim, q_block, pages_per_block, dma_slots,
+    v_dim, q_block, pages_per_block, dma_slots, window=None, keep=None,
 ):
     b, h, d = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if not use_pallas:
         return paged_attention_ref(
-            q, k_cache, v_cache, block_tables, context_lens, scale, v_dim)
+            q, k_cache, v_cache, block_tables, context_lens, scale, v_dim,
+            window, keep)
     hkv = cache_shape(k_cache)[0]
     group = h // hkv
     # a row's query heads of one kv head are a tile's rows: whole
@@ -746,7 +871,8 @@ def _paged_attention(
         qx.reshape(b, hkv * group_p, d), k_cache, v_cache, block_tables,
         jnp.arange(b + 1, dtype=jnp.int32), (lens > 0).astype(jnp.int32),
         lens, jnp.asarray(b, jnp.int32), scale, 1, True, q_block,
-        pages_per_block, dma_slots, v_dim, max_q_len=1)
+        pages_per_block, dma_slots, v_dim, max_q_len=1, window=window,
+        keep=keep)
     return out.reshape(b, hkv, group_p, -1)[:, :, :group].reshape(b, h, -1)
 
 
@@ -759,6 +885,8 @@ def paged_attention(
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
     v_dim: Optional[int] = None,
+    window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,  # [B, L] bool
 ):
     """Single-token-per-sequence paged decode attention: the decode entry
     of the ragged kernel.  The batch is laid out, inside the jit, as
@@ -772,7 +900,9 @@ def paged_attention(
     tests rely on it; unsupported shapes then fail loudly at compile).
     ``v_cache=None`` is the shared layout: the value is the first
     ``v_dim`` lanes of the key row, read from the key's tile, and the
-    output is ``[B, H, v_dim]``."""
+    output is ``[B, H, v_dim]``.  ``window`` and ``keep`` as
+    ``ragged_paged_attention`` has them (the row's token sits at
+    ``context_lens - 1``)."""
     k_data = cache_data(k_cache)
     if use_pallas is None:
         use_pallas = auto_use_pallas(
@@ -789,4 +919,4 @@ def paged_attention(
     return _paged_attention(
         q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
         v_dim, min(q.shape[0], DECODE_ROWS_A_CELL), pages_per_block,
-        dma_slots)
+        dma_slots, window=window, keep=keep)

@@ -211,6 +211,7 @@ class ARModelRunner:
         max_num_batched_tokens: int = 2048,  # sizes the token buckets
         deterministic_decode: bool = False,  # pin decode batches to one bucket
         kv_cache_dtype: str = "auto",  # auto | bf16 | int8 resident layout
+        num_window_pages: int = 0,  # the window kind's pool (null page incl.)
     ):
         if kv_cache_dtype not in ("auto", "bf16", "int8"):
             raise ValueError(
@@ -227,6 +228,16 @@ class ARModelRunner:
         layout = cfg.kv_layout
         self.kv_layout = layout
         self._latent = layout.kind == "latent"
+        # a model with sliding-window layers holds two kinds of PAGE: its
+        # sliding layers' rows live in a pool of their own, under a second
+        # table a sequence (docs/kv_cache.md, "Two kinds of page")
+        self._window = cfg.sliding_window if cfg.num_window_layers else 0
+        if self._window and (self._hybrid or num_window_pages < 2):
+            raise ValueError(
+                "sliding-window layers need num_window_pages (the engine "
+                "sizes it from max_num_seqs x (window + "
+                "max_num_batched_tokens)), and are not built beside "
+                "recurrent layers")
         if self._latent and kv_cache_dtype == "int8":
             raise ValueError(
                 "kv_cache_dtype int8: the latent cache has no quantized "
@@ -259,6 +270,10 @@ class ARModelRunner:
         reason = paged_tiling_reason(layout.width, page_size)
         if self._latent and not reason:
             reason = shared_tiling_reason(None, cfg.kv_lora_rank)
+        if self._window and not reason:
+            reason = (paged_tiling_reason(
+                cfg.kv_layout_of("sliding_attention").width, page_size)
+                or shared_tiling_reason(None, cfg.sliding.kv_lora_rank))
         if reason and _dispatch.pallas_mode() == "native":
             raise ValueError(
                 f"the paged attention kernels cannot tile this geometry "
@@ -357,6 +372,22 @@ class ARModelRunner:
             layout.width, dtype, quantized=self._kv_quant,
             shared=self._latent,
         )
+        if self._window or cfg.index_topk:
+            # pools by the layer's kind, in layer order: a sliding layer's
+            # over the window kind's pages at its own row width; a full
+            # layer with an indexer holds, on the same pages as its rows,
+            # the index keys' pool as a third entry
+            kinds = [k for k in cfg.layer_types if k != "linear_attention"]
+            wide = cfg.kv_layout_of("sliding_attention")
+            for i, kind in enumerate(kinds):
+                if kind == "sliding_attention":
+                    pages[i] = init_kv_cache(
+                        1, num_window_pages, page_size, wide.heads,
+                        wide.width, dtype, shared=True)[0]
+                elif cfg.index_topk:
+                    pages[i] += init_kv_cache(
+                        1, num_pages, page_size, 1, cfg.index_head_dim,
+                        dtype, shared=True)[0][:1]
         # and, for each recurrent layer, the convolution's last inputs and
         # the rule's state, both float32 (the rule's as the published
         # implementation keeps it; the convolution's because its inputs
@@ -424,10 +455,20 @@ class ARModelRunner:
         cfg_ = cfg
         collect_ = collect_hidden
 
+        hybrid_, window_ = self._hybrid, bool(self._window)
+
+        def extra_kwargs(extra):
+            # what rides next to the page tables (``_extra_operands``)
+            if hybrid_:
+                return {"state_slots": extra[0]}
+            if window_:
+                return {"window_pages": tuple(extra)}
+            return {}
+
         def _decode_core(params, token_ids, kv_caches, positions,
                          slot_mapping, block_tables, context_lens,
                          temperature, top_k, top_p, keys,
-                         state_slots=None, *, want_lp: bool):
+                         *extra, want_lp: bool):
             # the [B]-row pure-decode step: forward + ON-DEVICE sampling
             # (the hoist that enables the async pipelined engine step —
             # sampled tokens stay device-resident and feed the NEXT
@@ -440,7 +481,7 @@ class ARModelRunner:
             hidden, new_caches = tfm.forward_decode(
                 params, cfg_, token_ids, positions, kv_caches,
                 slot_mapping, block_tables, context_lens,
-                state_slots=state_slots,
+                **extra_kwargs(extra),
             )
             logits = tfm.logits_from_hidden(params, cfg_, hidden)
             toks, path = sample_tokens(logits, temperature, top_k, top_p,
@@ -464,7 +505,7 @@ class ARModelRunner:
                           slot_mapping, page_tables, seq_lens, cu_q_lens,
                           q_lens, num_seqs, verify_idx, n_cand, drafts,
                           temperature, top_k, top_p, keys,
-                          state_slots=None, inputs_embeds=None,
+                          *extra, inputs_embeds=None,
                           embeds_mask=None, deepstack=None):
             # ONE executable for every non-pure-decode step: the
             # token-packed ragged forward serves prefill chunks,
@@ -480,7 +521,7 @@ class ARModelRunner:
                 slot_mapping, page_tables, seq_lens, cu_q_lens, q_lens,
                 num_seqs, inputs_embeds=inputs_embeds,
                 embeds_mask=embeds_mask, deepstack=deepstack,
-                state_slots=state_slots,
+                **extra_kwargs(extra),
             )
             with jax.named_scope("omni.lm_head"):
                 cand_hidden = hidden[verify_idx]      # [S, V, H]
@@ -708,9 +749,23 @@ class ARModelRunner:
     def state_caches(self, value) -> None:
         self.kv_caches = self.kv_caches._replace(state=list(value))
 
-    def _slot_column(self, scheds, width: int) -> tuple:
-        """The state-slot operand of a step, next to the page tables:
-        ``()`` for a model without recurrent layers."""
+    def _slot_column(self, scheds, width: int, cu=None,
+                     t_pad: Optional[int] = None) -> tuple:
+        """What a step carries next to its page tables: the state-slot
+        column of a model with recurrent layers; the window kind's slots
+        (``[t_pad]``, at the packed offsets ``cu``; a row a sequence
+        where ``t_pad`` is None) and page tables of a model with
+        sliding-window layers; ``()`` for every other."""
+        if self._window:
+            slots = np.full((width if t_pad is None else t_pad,), -1,
+                            np.int32)
+            tables = np.zeros((width, self.max_pages_per_seq), np.int32)
+            for i, sc in enumerate(scheds):
+                lo = i if cu is None else int(cu[i])
+                slots[lo: lo + len(sc.window_slots)] = sc.window_slots
+                t = sc.window_table[: self.max_pages_per_seq]
+                tables[i, : len(t)] = t
+            return (slots, tables)
         if not self._hybrid:
             return ()
         col = np.full((width,), self.num_state_slots, np.int32)
@@ -844,7 +899,8 @@ class ARModelRunner:
                     jnp.ones((s_max,), jnp.int32),
                     jnp.zeros((s_max, v - 1), jnp.int32),
                     t.temperature, t.top_k, t.top_p, t.keys,
-                    *map(jnp.asarray, self._slot_column((), s_max))))
+                    *map(jnp.asarray, self._slot_column(
+                        (), s_max, t_pad=t_pad))))
         return built
 
     # ---------------------------------------------------------------- step
@@ -1132,7 +1188,8 @@ class ARModelRunner:
                 tensors.keys,
                 # the slot column of a model with recurrent layers
                 *map(jnp.asarray, self._slot_column(
-                    scheds, self._batch_buckets[-1])))
+                    scheds, self._batch_buckets[-1], asm.cu_q_lens,
+                    asm.t_pad)))
         outs, self.kv_caches = self._run_jit(
             "unified",
             # the deepstack LEVEL COUNT is part of the operand shape —
