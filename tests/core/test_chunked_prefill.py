@@ -264,3 +264,186 @@ def test_intake_accepts_long_prompt_when_chunked():
     req = _mk_req("long", 100)
     sched.add_request(req)
     assert req.status is RequestStatus.WAITING
+
+
+# ------------------------------------------- chunks sized in packed rows
+def _contract(max_model_len, token_block=8):
+    """The packing contract an engine would hand over: the runner's
+    token block and its bucket line (powers of two up to the cap)."""
+    from vllm_omni_tpu.core.scheduler import PackingContract
+    from vllm_omni_tpu.worker.model_runner import _make_buckets
+
+    return PackingContract(token_block, _make_buckets(16, max_model_len))
+
+
+def _packed_sched(n_decodes, *, max_num_seqs, budget, max_model_len,
+                  k=0, chunked=True):
+    """A scheduler under a packing contract with ``n_decodes`` requests
+    already decoding (verify rows of 1 + k where ``k``)."""
+    kv = KVCacheManager(num_pages=4096, page_size=16)
+    sched = ARScheduler(SchedulerConfig(
+        max_num_seqs=max_num_seqs, max_num_batched_tokens=budget,
+        max_model_len=max_model_len, enable_chunked_prefill=chunked,
+        num_speculative_tokens=k), kv)
+    sched.packing = _contract(max_model_len)
+    decoders = [_mk_req(f"d{i}", 3, max_tokens=10_000)
+                for i in range(n_decodes)]
+    for r in decoders:
+        sched.add_request(r)
+    while any(not r.output_token_ids for r in decoders):
+        out = sched.schedule()
+        sched.update_from_output(out, {
+            s.request.request_id: 1 for s in out.decodes + out.prefills
+            if not s.is_prefill or s.samples_final})
+    for r in decoders:
+        r.spec_draft_tokens = [2] * k
+    return sched
+
+
+def _step_rows(sched, out):
+    """Packed rows of a step, as ARModelRunner._assemble_unified counts."""
+    return sum(sched.packing.rows(s.num_new_tokens)
+               for s in out.decodes + out.prefills)
+
+
+@pytest.mark.parametrize("n", [0, 1, 9, 24, 31, 62, 63])
+def test_chunk_fills_the_bucket_the_step_needs(n):
+    """The benchmark's limits (64 seats, 2048 tokens a step): a long
+    prompt beside ``n`` decode rows never opens the 4096-row bucket, and
+    its chunks cover it once, in order."""
+    sched = _packed_sched(n, max_num_seqs=64, budget=2048,
+                          max_model_len=8448)
+    doc = _mk_req("doc", 5000)
+    sched.add_request(doc)
+    covered, trimmed, by_tokens = [], 0, 0
+    while doc.num_computed_tokens < 5000:
+        out = sched.schedule()
+        assert len(out.decodes) == n, "a decode row lost its seat"
+        assert len(out.prefills) == 1
+        chunk = out.prefills[0]
+        assert _step_rows(sched, out) <= 2048
+        assert not out.bucket_raised
+        assert chunk.start_pos == (covered[-1][1] if covered else 0)
+        covered.append((chunk.start_pos,
+                        chunk.start_pos + chunk.num_new_tokens))
+        trimmed += out.chunk_tokens_trimmed
+        # what the token budget alone would have given the chunk
+        by_tokens += min(5000 - chunk.start_pos, 2048 - n)
+        if covered[-1][1] < 5000:
+            # a whole chunk takes every row the decode rows leave
+            assert chunk.num_new_tokens == 2048 - 8 * n
+        sampled = {s.request.request_id: 1 for s in out.decodes}
+        if covered[-1][1] == 5000:
+            sampled["doc"] = 1
+        sched.update_from_output(out, sampled)
+    assert covered[-1][1] == 5000
+    # 7 tokens a decode row and whole chunk: what fitting the bucket costs
+    assert trimmed == by_tokens - 5000
+    assert trimmed >= 7 * n * (len(covered) - 2)
+
+
+@pytest.mark.parametrize("n", [9, 12, 15])
+def test_decode_rows_past_the_budget_keep_their_seats(n):
+    """8 x decodes > max_num_batched_tokens: every decode row is still
+    scheduled, the chunk gets at least a token block, and the step runs
+    in the bucket the decode rows alone set."""
+    sched = _packed_sched(n, max_num_seqs=16, budget=64, max_model_len=512)
+    sched.add_request(_mk_req("doc", 300))
+    out = sched.schedule()
+    assert len(out.decodes) == n and len(out.prefills) == 1
+    assert out.prefills[0].num_new_tokens >= 8
+    rows = _step_rows(sched, out)
+    assert sched.packing.bucket(rows) == sched.packing.bucket(8 * n + 8)
+    assert sched.packing.bucket(8 * n + 8) > sched.packing.bucket(64)
+    assert out.bucket_raised
+
+
+def test_verify_row_counts_at_its_aligned_size():
+    """A verify row of 1 + k = 9 tokens holds two token blocks: three of
+    them leave a 64-row step 16 rows, though its budget has 37 tokens."""
+    sched = _packed_sched(3, max_num_seqs=8, budget=64, max_model_len=512,
+                          k=8)
+    sched.add_request(_mk_req("doc", 300))
+    out = sched.schedule()
+    assert [s.num_new_tokens for s in out.decodes] == [9, 9, 9]
+    assert out.prefills[0].num_new_tokens == 16
+    assert out.chunk_tokens_trimmed == 37 - 16
+    assert _step_rows(sched, out) == 64
+
+
+def test_chunk_ahead_of_a_decode_row_leaves_its_segment_free():
+    """Classic order (no decodes-first sort): a chunking request that
+    stands before the decode rows in the running list still leaves them
+    their segments, and their tokens of the budget."""
+    sched = _packed_sched(0, max_num_seqs=8, budget=64, max_model_len=512)
+    doc = _mk_req("doc", 300)
+    sched.add_request(doc)
+    out = sched.schedule()
+    sched.update_from_output(out, {})
+    late = [_mk_req(f"l{i}", 3, max_tokens=100) for i in range(2)]
+    for r in late:
+        sched.add_request(r)
+    # the doc's second chunk takes the budget: the late prompts wait
+    out = sched.schedule()
+    sched.update_from_output(out, {})
+    assert [s.request.request_id for s in out.prefills] == ["doc"]
+    # give them a step of their own by hand, behind the doc in running
+    for r in late:
+        sched.waiting.remove(r)
+        r.status = RequestStatus.RUNNING
+        sched.kv.allocate(r, 3)
+        r.num_computed_tokens = 3
+        r.output_token_ids.append(1)
+        sched.running.append(r)
+    assert sched.running[0] is doc
+    out = sched.schedule()
+    assert len(out.decodes) == 2
+    assert out.prefills[0].num_new_tokens == 64 - 2 * 8
+    assert _step_rows(sched, out) == 64
+
+
+def test_whole_prompt_waits_for_a_segment_that_fits():
+    """Chunked prefill off: a prompt is admitted whole only if its
+    segment fits the step's bucket beside the decode rows, else it waits
+    a step as it does when the token budget is short."""
+    sched = _packed_sched(2, max_num_seqs=8, budget=64, max_model_len=512,
+                          chunked=False)
+    # 2 decode rows hold 16 rows: 48 left.  41 tokens fit the token
+    # budget (62) and the bucket (48 rows)
+    sched.add_request(_mk_req("fits", 41))
+    sched.add_request(_mk_req("waits", 9))  # 16 rows: past the bucket
+    out = sched.schedule()
+    assert [s.request.request_id for s in out.prefills] == ["fits"]
+    assert _step_rows(sched, out) == 64
+    assert out.chunk_tokens_trimmed == 0  # deferred, not trimmed
+    sched.update_from_output(
+        out, {s.request.request_id: 1
+              for s in out.decodes + out.prefills})
+    out = sched.schedule()
+    assert [s.request.request_id for s in out.prefills] == ["waits"]
+
+
+def test_scheduler_without_a_runner_sizes_by_tokens():
+    """No contract (every scheduler these tests build bare): a chunk is
+    the token budget the decode rows leave, as before."""
+    sched = _packed_sched(9, max_num_seqs=16, budget=64, max_model_len=512)
+    sched.packing = None
+    sched.add_request(_mk_req("doc", 300))
+    out = sched.schedule()
+    assert out.prefills[0].num_new_tokens == 64 - 9
+    assert (out.chunk_tokens_trimmed, out.bucket_raised) == (0, False)
+
+
+def test_runner_reach_and_scheduler_cap_are_one_rule():
+    """The runner's warm-up reach is the scheduler's cap at a full house
+    (core/scheduler.py packed_rows_needed): the benchmark's limits reach
+    2048 rows and no further; seats past budget / 8 raise both."""
+    from vllm_omni_tpu.core.scheduler import packed_rows_needed
+
+    assert packed_rows_needed(2048, 63 * 8, 8) == 2048
+    assert packed_rows_needed(2048, 31 * 8, 8) == 2048
+    assert packed_rows_needed(2048, 300 * 8, 8) == 2408
+    c = _contract(8448)
+    assert c.step_cap(2048, 63 * 8) == 2048
+    assert c.step_cap(2048, 300 * 8) == 4096
+    assert c.step_cap(64, 0) == 64

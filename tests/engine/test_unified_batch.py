@@ -406,17 +406,140 @@ def test_metrics_snapshot_and_exposition(tiny_model):
         assert needle in text, needle
 
 
-@pytest.mark.slow  # fast sibling: test_warmup_precompiles_all_traffic_
-# shapes warms the same 1-D token-bucket line (the split executor's
-# grid is gone, so the compiled surface no longer depends on the flag)
+# ------------------------------------- chunks sized in packed rows
+def _spy_buckets(eng):
+    """Record (t_pad, packed rows) of every unified step assembled."""
+    seen = []
+    orig = eng.runner._assemble_unified
+
+    def spy(scheds, spec_rows):
+        asm = orig(scheds, spec_rows)
+        seen.append((asm.t_pad, asm.total))
+        return asm
+
+    eng.runner._assemble_unified = spy
+    return seen
+
+
+DOC = [(i * 7) % 61 + 1 for i in range(90)]
+LONG = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
+
+
+def _doc_beside_decodes(eng, n):
+    """``n`` short requests reach decode, then a 90-token prompt
+    arrives: every step until it is computed carries one of its chunks
+    beside ``n`` decode rows."""
+    outs = {}
+    for i in range(n):
+        eng.add_request(PROMPTS[i], LONG, request_id=f"d{i}")
+    for _ in range(2):
+        eng.step()
+    eng.add_request(DOC, GREEDY, request_id="doc")
+    while eng.has_unfinished_requests:
+        for o in eng.step():
+            outs[o.request_id] = o.outputs[0].token_ids
+    return outs
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 3])
+def test_chunked_step_packs_into_the_budget_bucket(tiny_model, n):
+    """4 seats, 32 tokens a step: 3 decode rows and a chunk's block are
+    32 rows, so no step with a chunk runs past the 32-row bucket, and
+    the tokens are those of an engine that never chunks."""
+    params, cfg = tiny_model
+    kw = dict(max_num_seqs=4, max_model_len=128)
+    eng = _engine(params, cfg, enable_chunked_prefill=True, **kw)
+    seen = _spy_buckets(eng)
+    got = _doc_beside_decodes(eng, n)
+    assert seen and max(t for t, _ in seen) <= 32, seen
+    # a whole chunk takes every row the decode rows' blocks leave
+    assert (32, 32) in seen
+    whole = _doc_beside_decodes(
+        _engine(params, cfg, max_num_batched_tokens=128, **kw), n)
+    assert got == whole
+    pad = eng.metrics_snapshot()["padding"]
+    assert pad["bucket_raised_steps_total"] == 0
+    assert (pad["chunk_tokens_trimmed_total"] > 0) == (n > 0)
+
+
+def test_decode_rows_alone_raise_the_bucket(tiny_model):
+    """8 seats, 16 tokens a step: 5 decode rows hold 40 rows, past the
+    budget's bucket.  None loses its seat, the chunk gets the rows left
+    of the bucket they opened (64), and the tokens are unchanged."""
+    params, cfg = tiny_model
+    kw = dict(max_num_seqs=8, max_model_len=128)
+    eng = _engine(params, cfg, enable_chunked_prefill=True,
+                  max_num_batched_tokens=16, **kw)
+    seen = _spy_buckets(eng)
+    rec = _spy_execute(eng)
+    got = _doc_beside_decodes(eng, 5)
+    doc_steps = [r for r in rec if r[0] == 1 and r[1]]
+    assert len(doc_steps) > 2 and all(r[1] == 5 for r in doc_steps), rec
+    assert max(t for t, _ in seen) == 64
+    whole = _doc_beside_decodes(
+        _engine(params, cfg, max_num_batched_tokens=128, **kw), 5)
+    assert got == whole
+    pad = eng.metrics_snapshot()["padding"]
+    assert pad["bucket_raised_steps_total"] > 0
+
+
+def test_trimmed_chunks_are_counted_and_recorded(tiny_model):
+    """A chunked run beside decode rows moves
+    ``padding.chunk_tokens_trimmed_total`` (the sum of the flight
+    records' ``chunk_tokens_trimmed``) and leaves
+    ``padding.bucket_raised_steps_total`` at 0."""
+    params, cfg = tiny_model
+    eng = _engine(params, cfg, enable_chunked_prefill=True,
+                  max_num_seqs=4, max_model_len=128)
+    _doc_beside_decodes(eng, 3)
+    recs = eng.flight.snapshot()["records"]
+    assert all(isinstance(r["chunk_tokens_trimmed"], int)
+               and r["bucket_raised"] is False for r in recs)
+    trimmed = sum(r["chunk_tokens_trimmed"] for r in recs)
+    # 32 rows less 3 decode rows' blocks is 8; the budget had left 29
+    assert max(r["chunk_tokens_trimmed"] for r in recs) == 21
+    pad = eng.metrics_snapshot()["padding"]
+    assert pad["chunk_tokens_trimmed_total"] == trimmed > 0
+    assert pad["bucket_raised_steps_total"] == 0
+
+
+def _warmed_token_buckets(eng):
+    return sorted(k[1] for k in eng.runner._jit_seen if k[0] == "unified")
+
+
+def test_warmup_walks_what_the_scheduler_can_emit(tiny_model):
+    """Warm-up compiles the token buckets a budgeted step can reach (4
+    seats, 32 tokens: 16 and 32 of a line that runs to 128) and a
+    chunked run beside decode rows then compiles nothing."""
+    params, cfg = tiny_model
+    eng = _engine(params, cfg, enable_chunked_prefill=True,
+                  max_num_seqs=4, max_model_len=128, warmup=True)
+    assert eng.runner._token_buckets == (16, 32, 64, 128)
+    assert _warmed_token_buckets(eng) == [16, 32]
+    compiles = eng.runner.compile_stats["compiles"]
+    seen = _spy_buckets(eng)
+    _doc_beside_decodes(eng, 3)
+    assert {t for t, _ in seen} <= {16, 32}
+    assert eng.runner.compile_stats["compiles"] == compiles
+
+
+@pytest.mark.slow  # fast siblings: test_warmup_walks_what_the_scheduler_
+# can_emit above; test_warmup_precompiles_all_traffic_shapes warms the
+# same 1-D token-bucket line (the split executor's grid is gone, so the
+# compiled surface no longer depends on the flag)
 def test_warmup_precompiles_token_buckets(tiny_model):
-    """Unified warmup walks the 1-D token-bucket line; traffic at any
-    packed size then hits the shape cache (no mid-traffic compiles)."""
+    """Unified warmup walks the 1-D token-bucket line as far as a
+    budgeted step reaches; traffic at any packed size then hits the
+    shape cache (no mid-traffic compiles)."""
     params, cfg = tiny_model
     eng = _engine(params, cfg, unified_batching=True, warmup=True)
     compiles_after_warmup = eng.runner.compile_stats["compiles"]
-    assert compiles_after_warmup >= len(eng.runner._token_buckets)
+    # 8 seats of one block are 64 rows, past the 32-token budget: the
+    # reachable prefix of (16, 32, 64, 128) is its first three
+    assert _warmed_token_buckets(eng) == [16, 32, 64]
+    assert compiles_after_warmup >= 3
     _run_staggered(eng)
+    _doc_beside_decodes(eng, 5)
     assert eng.runner.compile_stats["compiles"] == compiles_after_warmup
 
 

@@ -203,7 +203,8 @@ def test_engine_appends_step_records(stepped_engine):
     for key in ("path", "unified", "fallback", "prefills", "decodes",
                 "spec_rows", "verify_tokens", "new_tokens",
                 "prefill_tokens", "prefill_ctx", "decode_ctx",
-                "wide_q_tokens", "sampler_path", "waiting", "running",
+                "wide_q_tokens", "chunk_tokens_trimmed", "bucket_raised",
+                "sampler_path", "waiting", "running",
                 "host_ms", "device_ms", "kv_offloads", "kv_restores",
                 "slot", "compiles", "requests", "seq", "ts"):
         assert key in r, f"record missing {key}"

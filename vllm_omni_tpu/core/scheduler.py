@@ -20,6 +20,7 @@ model runner buckets and pads into device arrays.
 
 from __future__ import annotations
 
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Optional
@@ -47,8 +48,55 @@ class KVTransferConfig:
     special_token_id: Optional[int] = None
 
 
+def packed_rows_needed(max_num_batched_tokens: int, decode_rows: int,
+                       token_block: int) -> int:
+    """Packed rows a budgeted step must have room for: the token budget,
+    or the step's decode rows and one token block beside them where
+    those alone pass it.  The one rule behind the scheduler's chunk cap
+    (``PackingContract.step_cap``) and the runner's warm-up reach."""
+    return max(max_num_batched_tokens, decode_rows + token_block)
+
+
+@dataclass(frozen=True)
+class PackingContract:
+    """How the runner packs a step onto the token axis: every sequence
+    takes a segment of whole token blocks, and the step runs in the
+    first bucket of the line that holds its segments.  The engine hands
+    the runner's to the scheduler, which then sizes a prefill chunk in
+    packed rows; a scheduler without one sizes it by the token budget
+    alone."""
+
+    token_block: int
+    token_buckets: tuple[int, ...]
+
+    def rows(self, n_new: int) -> int:
+        """Packed rows of a segment of ``n_new`` tokens."""
+        return -(-n_new // self.token_block) * self.token_block
+
+    def bucket(self, rows: int) -> int:
+        for b in self.token_buckets:
+            if rows <= b:
+                return b
+        return self.token_buckets[-1]
+
+    def step_cap(self, max_num_batched_tokens: int, decode_rows: int) -> int:
+        """The bucket a step runs in anyway: the token budget's, or the
+        one its decode rows open with one token block left for a chunk.
+        Prefill chunks fill it and never open the next."""
+        return self.bucket(packed_rows_needed(
+            max_num_batched_tokens, decode_rows, self.token_block))
+
+
 @dataclass
 class SchedulerConfig:
+    """``max_num_batched_tokens`` is a step's budget of NEW tokens: a
+    decode row spends one (a verify row its candidates), prefill chunks
+    the rest.  Under an engine a chunk is additionally held to the
+    packed-row bucket the step already needs (``PackingContract``): the
+    runner gives every sequence a whole token block, so a chunk sized by
+    tokens alone would push a full step one row past its bucket and
+    double the rows every dense layer computes."""
+
     max_num_seqs: int = 8
     max_num_batched_tokens: int = 2048
     max_model_len: int = 4096
@@ -162,6 +210,11 @@ class SchedulerOutput:
     # matches — a preempt-and-readmit while the step was in flight
     # bumps the generation, discarding the stale token.
     async_sampled: dict[str, int] = field(default_factory=dict)
+    # tokens this step's chunks gave up to fit the step's bucket, and
+    # whether its decode rows alone opened a bucket above the token
+    # budget's (PackingContract.step_cap; docs/observability.md)
+    chunk_tokens_trimmed: int = 0
+    bucket_raised: bool = False
 
     @property
     def num_scheduled(self) -> int:
@@ -208,6 +261,10 @@ class ARScheduler:
         # set once any admitted request carries a deadline, so the
         # per-step expiry sweep stays free for deadline-less serving
         self._deadlines_possible = False
+        # the runner's packing contract, handed over by the engine; None
+        # (a scheduler built without a runner) sizes chunks by the token
+        # budget alone
+        self.packing: Optional[PackingContract] = None
 
     # ------------------------------------------------------------- intake
     def add_request(self, request: Request, injected_len: int = 0) -> None:
@@ -504,15 +561,17 @@ class ARScheduler:
             # relative arrival order preserved within each class).  This
             # also points the preemption policy (_preempt_for walks the
             # snapshot tail) at chunking requests before decoding ones.
-            def _needs_chunk(r: Request) -> bool:
-                remaining = (r.num_tokens + r.num_inflight_tokens
-                             - r.num_computed_tokens)
-                return (remaining > 1 or r.awaiting_chunks
-                        or (r.prompt_embeds is not None
-                            and r.num_computed_tokens
-                            < r.num_prompt_tokens))
-
-            snapshot.sort(key=_needs_chunk)
+            snapshot.sort(key=self._needs_chunk)
+        # packed rows left for prefill chunks in the bucket the step
+        # runs in anyway; without a runner's contract a segment counts
+        # no rows and the token budget alone sizes a chunk
+        pack = self.packing
+        room, rows = sys.maxsize, (lambda n_new: 0)
+        if pack is not None:
+            decode_rows = self._decode_rows_ahead(snapshot)
+            cap = pack.step_cap(self.config.max_num_batched_tokens,
+                                decode_rows)
+            room, rows = cap - decode_rows, pack.rows
         for i, req in enumerate(snapshot):
             if req.status is not RequestStatus.RUNNING:
                 continue  # preempted earlier in this very loop
@@ -558,7 +617,12 @@ class ARScheduler:
                 # resumed request chunks through its generated suffix too
                 # instead of crawling it one decode step at a time):
                 # schedule the next chunk rather than a decode token
-                chunk = min(remaining, budget)
+                if room <= 0:
+                    # an earlier chunk filled the bucket: next step
+                    still_running.append(req)
+                    continue
+                want = min(remaining, budget)
+                chunk = min(want, room)
                 if not self.kv.can_allocate(req, chunk):
                     out.preempted.extend(
                         self._preempt_for(req, snapshot[i + 1:], chunk)
@@ -578,6 +642,8 @@ class ARScheduler:
                     block_table=table, start_pos=req.num_computed_tokens,
                 ))
                 budget -= chunk
+                room -= rows(chunk)
+                out.chunk_tokens_trimmed += want - chunk
                 still_running.append(req)
                 continue
             if not self.kv.can_allocate(req, 1):
@@ -632,7 +698,8 @@ class ARScheduler:
         # 2. admit waiting requests (chunked prefill under the budget).
         # num_tokens (not num_prompt_tokens): a preempted request resumes by
         # recomputing KV for its prompt *and* its already-generated tokens.
-        while self.waiting and budget > 0 and len(self.running) < self.config.max_num_seqs:
+        while (self.waiting and budget > 0 and room > 0
+               and len(self.running) < self.config.max_num_seqs):
             req = self.waiting[0]
             if (self.config.kv_offload
                     and req.num_computed_tokens == 0
@@ -711,16 +778,20 @@ class ARScheduler:
                     block_table=table, start_pos=req.num_computed_tokens,
                 ))
                 budget -= 1
+                room -= rows(1)
                 self.waiting.pop(0)
                 req.status = RequestStatus.RUNNING
                 self.running.append(req)
                 continue
             if self.config.chunking_enabled:
-                chunk = min(remaining, budget)
-            elif remaining > budget:
-                break  # whole prompt must fit this step's budget
+                want = min(remaining, budget)
+                chunk = min(want, room)
+            elif remaining > budget or rows(remaining) > room:
+                # the whole prompt must fit this step's budget, and its
+                # segment the step's bucket
+                break
             else:
-                chunk = remaining
+                want = chunk = remaining
             if chunk <= 0 or not self.kv.can_allocate(req, chunk):
                 break
             table = self.kv.allocate(req, chunk)
@@ -732,9 +803,14 @@ class ARScheduler:
                 block_table=table, start_pos=req.num_computed_tokens,
             ))
             budget -= chunk
+            room -= rows(chunk)
+            out.chunk_tokens_trimmed += want - chunk
             self.waiting.pop(0)
             req.status = RequestStatus.RUNNING
             self.running.append(req)
+        if pack is not None and out.prefills:
+            out.bucket_raised = cap > pack.bucket(
+                self.config.max_num_batched_tokens)
         if self.kv.num_state_slots:
             # the slot column: taken with the sequence's first page, so
             # every scheduled row of a recurrent model has one by now
@@ -749,6 +825,34 @@ class ARScheduler:
                 sc.window_slots = self.kv.window_slot_mapping(
                     rid, sc.start_pos, sc.num_new_tokens)
         return out
+
+    @staticmethod
+    def _needs_chunk(req: Request) -> bool:
+        """The request's next row is a prefill chunk, not a decode row
+        (the running loop's own test, before any is scheduled)."""
+        remaining = (req.num_tokens + req.num_inflight_tokens
+                     - req.num_computed_tokens)
+        return (remaining > 1 or req.awaiting_chunks
+                or (req.prompt_embeds is not None
+                    and req.num_computed_tokens < req.num_prompt_tokens))
+
+    def _decode_rows_ahead(self, snapshot: list[Request]) -> int:
+        """Packed rows this step's decode rows will hold, wherever they
+        stand in the running order: a row costs a token block (a verify
+        row its candidates' blocks).  Counted before the loop, so that a
+        chunk scheduled ahead of a decode row leaves its segment free;
+        a row that then degrades or is preempted was counted too, which
+        costs its chunk a few tokens and never a bucket."""
+        k = self.config.num_speculative_tokens
+        rows = 0
+        for req in snapshot:
+            if (req.num_inflight_tokens > 1 or self._needs_chunk(req)
+                    or req.num_tokens + req.num_inflight_tokens
+                    <= req.num_computed_tokens):
+                continue  # held a step, a chunk, or idle
+            drafts = min(len(req.spec_draft_tokens or ()), k)
+            rows += self.packing.rows(1 + drafts)
+        return rows
 
     def _preempt(self, req: Request) -> None:
         """Preemption: free pages, reset progress, back to waiting.

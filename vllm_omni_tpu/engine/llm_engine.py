@@ -453,6 +453,12 @@ class LLMEngine:
             self.runner.set_draft_fn(
                 draft_fn, config.num_speculative_tokens
             )
+        if sched_cls is ARScheduler:
+            # the runner's packing contract (token block, bucket line):
+            # the scheduler sizes a prefill chunk against the bucket its
+            # step already needs.  The one-shot generation scheduler
+            # ignores the budget and takes none.
+            self.scheduler.packing = self.runner.packing
         # connector hook: called with (request, kv_payload) when a
         # cross-stage KV extraction completes (OmniKVTransferManager put)
         self.kv_transfer_sink: Optional[Callable] = None
@@ -1077,9 +1083,16 @@ class LLMEngine:
              for s in sched_out.decodes + sched_out.prefills],
             getattr(self.runner, "q_block", DEFAULT_TOKEN_BLOCK),
         ) if unified else 0
+        # what the step's chunks gave up to fit its bucket, and whether
+        # its decode rows alone had opened a bucket above the budget's
+        # (core/scheduler.py PackingContract)
+        trimmed = getattr(sched_out, "chunk_tokens_trimmed", 0)
+        raised = bool(getattr(sched_out, "bucket_raised", False))
         step_metrics = getattr(self, "step_metrics", None)  # fakes
         if step_metrics is not None:
             step_metrics.wide_q_tokens_total += wide
+            step_metrics.chunk_tokens_trimmed_total += trimmed
+            step_metrics.bucket_raised_steps_total += raised
         # dispatches retired since the last record whose sampler applied
         # a top-k / top-p filter, by the way it found its thresholds (this
         # step's own on the sync path, its predecessor's when pipelined)
@@ -1168,6 +1181,8 @@ class LLMEngine:
             "decode_ctx": int(decode_ctx),
             **capped,
             "wide_q_tokens": wide,
+            "chunk_tokens_trimmed": trimmed,
+            "bucket_raised": raised,
             "sampler_path": ("full_sort" if full_sort
                              else "bounded" if bounded else None),
             "state_rows": state_rows,

@@ -399,6 +399,12 @@ class EngineStepMetrics:
         # new tokens the ragged kernel attended as whole q-block tiles
         # (LLMEngine._record_step; ops/ragged_paged_attention.py)
         self.wide_q_tokens_total = 0
+        # tokens prefill chunks gave up to fit the bucket their step
+        # already needed, and steps whose decode rows alone opened a
+        # bucket above the token budget's (core/scheduler.py
+        # PackingContract.step_cap)
+        self.chunk_tokens_trimmed_total = 0
+        self.bucket_raised_steps_total = 0
         # steps whose sampler applied a top-k / top-p filter, by where
         # its thresholds came from: the bounded set of candidates, or a
         # sort of the whole vocabulary (sample/sampler.py)
@@ -539,6 +545,10 @@ class EngineStepMetrics:
                 "useful_tokens_total": self.useful_tokens_total,
                 "padded_tokens_total": self.padded_tokens_total,
                 "wide_q_tokens_total": self.wide_q_tokens_total,
+                "chunk_tokens_trimmed_total":
+                    self.chunk_tokens_trimmed_total,
+                "bucket_raised_steps_total":
+                    self.bucket_raised_steps_total,
                 "efficiency": round(self.padding_efficiency, 4),
             },
             "sampler": {

@@ -45,7 +45,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from vllm_omni_tpu.core.scheduler import ScheduledRequest, SchedulerOutput
+from vllm_omni_tpu.core.scheduler import (
+    PackingContract,
+    ScheduledRequest,
+    SchedulerOutput,
+    packed_rows_needed,
+)
 from vllm_omni_tpu.kvcache.quant import (
     dequantize_payload,
     is_quant_payload,
@@ -330,24 +335,8 @@ class ARModelRunner:
                 q_itemsize=jnp.dtype(dtype).itemsize,
                 quantized=self._kv_quant, kv_pools=layout.pools))
         self._token_block = DEFAULT_TOKEN_BLOCK
-        # unified ragged batching pads to TOKEN-count buckets: a 1-D
-        # bucket line replacing the (batch, seq) grid of the split path.
-        # Worst packed size under the AR scheduler = the step token
-        # budget plus per-sequence q-block alignment; the one-shot
-        # generation scheduler ignores the token budget, so the line
-        # extends to max_model_len for CAPACITY — but warmup only walks
-        # the budget-reachable prefix (the AR scheduler can never emit
-        # the larger buckets, and each compile costs tens of seconds; a
-        # generation deployment takes the one-time first-hit compile
-        # instead)
-        budget_cap = align_to_block(
-            max_num_batched_tokens
-            + max(max_num_seqs, 1) * (align_to_block(1) - 1),
-            self._token_block)
-        t_cap = align_to_block(max(budget_cap, max_model_len),
-                               self._token_block)
-        self._token_buckets = _make_buckets(16, max(t_cap, 16))
-        self._warm_token_cap = max(budget_cap, 16)
+        self._max_num_batched_tokens = max_num_batched_tokens
+        self._max_model_len = max_model_len
         self.collect_hidden = collect_hidden
         # --- telemetry (metrics/stats.py pulls these per step) ---
         # device dispatches: one jitted-executable launch each; tests
@@ -640,6 +629,7 @@ class ARModelRunner:
         # last_token [M], positions [M]) -> [M, k] proposals
         self.draft_fn = None
         self.num_draft_tokens = 0
+        self._lay_token_line()
         self.spec_stats = {"verify_steps": 0, "proposed": 0, "accepted": 0}
         # dispatches retired since the engine last looked, by the way the
         # sampler's filter went (indexed by sample/sampler.py PATH_*; read
@@ -661,6 +651,36 @@ class ARModelRunner:
         the same executable."""
         return 1 + self.num_draft_tokens
 
+    def _lay_token_line(self) -> None:
+        """The token-bucket line of the unified executable, how far up
+        it a budgeted step can reach, and the packing contract the
+        engine hands the scheduler (core/scheduler.py PackingContract).
+
+        Unified ragged batching pads to TOKEN-count buckets: a 1-D line
+        replacing the (batch, seq) grid of the split path.  The AR
+        scheduler holds a prefill chunk to the bucket its step already
+        needs, so the most rows a step packs follow from the rule that
+        sizes the chunk (``packed_rows_needed``): the token budget, or a
+        chunk's block beside every other seat's decode row, or every
+        seat a decode row (a verify row of 1 + k candidates where a
+        draft head is installed: laid again by ``set_draft_fn``).  The
+        one-shot generation scheduler ignores the token budget, so the
+        line extends to max_model_len for CAPACITY, but warmup only
+        walks the prefix a budgeted step reaches: each compile costs
+        tens of seconds, and a generation deployment takes the one-time
+        first-hit compile instead."""
+        tb = self._token_block
+        seats = self._batch_buckets[-1]
+        row = align_to_block(self._spec_v, tb)
+        reach = max(
+            packed_rows_needed(self._max_num_batched_tokens,
+                               (seats - 1) * row, tb),
+            seats * row)
+        t_cap = align_to_block(max(reach, self._max_model_len, 16), tb)
+        self._token_buckets = _make_buckets(16, t_cap)
+        self._warm_token_cap = max(reach, 16)
+        self.packing = PackingContract(tb, self._token_buckets)
+
     def set_draft_fn(self, draft_fn, num_draft_tokens: int) -> None:
         """Install the MTP draft head (talker spec decode, reference:
         gpu_ar_model_runner.py:466-497 EAGLE propose).  A draft_fn taking
@@ -680,6 +700,7 @@ class ARModelRunner:
                 "recurrent state back, and this model has recurrent layers")
         self.draft_fn = draft_fn
         self.num_draft_tokens = num_draft_tokens
+        self._lay_token_line()
         # memory-ledger estimate of the verify-path buffers: the widest
         # batch's (k+1)-row logits at float32 (deterministic — the
         # ledger's CPU fallback must not depend on allocator probes)
