@@ -56,6 +56,14 @@ def synthetic_engine_snapshot() -> dict:
         "step_ms": hist, "host_ms": hist, "device_ms": hist,
         "overlap": {"ratio": 0.75, "host_ms_total": 40.0,
                     "overlapped_host_ms_total": 30.0},
+        # the host's phases, stalls and collections
+        # (introspection/phase_clock.py)
+        "phases": {"clock_ms": 5000.0,
+                   "by_name": {"omni.step": {"ms": 12.5, "n": 7},
+                               "omni.assemble.pack": {"ms": 3.25, "n": 7}},
+                   "busy_waits": 4, "busy_wait_ms": 8.5},
+        "stalls": {"total": 1, "ms_total": 2100.0, "records": []},
+        "gc": {"ms_total": 3.5, "collections": {"0": 9, "1": 1, "2": 0}},
         "batched_tokens": hist,
         "padding": {"useful_tokens_total": 42, "padded_tokens_total": 64,
                     "efficiency": 0.6563},

@@ -30,6 +30,7 @@ from vllm_omni_tpu.kvcache.quant import (
 from vllm_omni_tpu.introspection import (
     DeviceMemoryLedger,
     FlightRecorder,
+    PhaseClock,
     register_engine,
 )
 from vllm_omni_tpu.logger import init_logger
@@ -57,10 +58,6 @@ from vllm_omni_tpu.request import Request, RequestStatus
 from vllm_omni_tpu.sample.sampler import PATH_BOUNDED, PATH_FULL_SORT
 from vllm_omni_tpu.sampling_params import SamplingParams
 from vllm_omni_tpu.worker.model_runner import ARModelRunner
-
-# a host phase of the step in the profiler's trace (the runner adds
-# omni.assemble / omni.dispatch / omni.readback around its own halves)
-_phase = jax.profiler.TraceAnnotation
 
 logger = init_logger(__name__)
 
@@ -448,6 +445,10 @@ class LLMEngine:
                 deterministic_decode=config.deterministic_decode,
                 num_window_pages=num_window_pages,
             )
+        # the host's phases on the host's clock (introspection/
+        # phase_clock.py): one clock for the engine thread, which the
+        # runner's halves of a step and the orchestrator's loop charge too
+        self.phases = self.runner.phases = PhaseClock()
         if (draft_fn is not None and config.num_speculative_tokens > 0
                 and hasattr(self.runner, "set_draft_fn")):
             self.runner.set_draft_fn(
@@ -1042,7 +1043,9 @@ class LLMEngine:
     def _record_step(self, path: str, sched_out: SchedulerOutput,
                      scheduled, new_tokens: int, host_ms: float,
                      device_ms: float,
-                     fallback: Optional[str] = None) -> None:
+                     fallback: Optional[str] = None,
+                     phases_ms: Optional[dict] = None,
+                     cpu_ms: Optional[float] = None) -> None:
         """Append one flight-recorder record.  Every field is a host
         int/str the step already computed — NO device syncs here (the
         recorder path is omnilint OL2 HOT_PATHS scoped)."""
@@ -1188,8 +1191,17 @@ class LLMEngine:
             "state_rows": state_rows,
             "waiting": len(self.scheduler.waiting),
             "running": len(self.scheduler.running),
+            # the step's number, as its ``omni.step`` annotation carries
+            # it (``step_num``): a record and its annotation join exactly
+            "step": getattr(self, "_steps_completed", None),
+            # host_ms + device_ms is the step; device_ms is what the
+            # engine thread waited on the device, cpu_ms what it spent
+            # on a CPU, phases_ms the step by phase (exclusive; they sum
+            # to the step): introspection/phase_clock.py
             "host_ms": round(host_ms, 3),
             "device_ms": round(device_ms, 3),
+            "cpu_ms": None if cpu_ms is None else round(cpu_ms, 3),
+            "phases_ms": phases_ms or {},
             "kv_offloads": offloads,
             "kv_restores": restores,
             "slot": {"occupied": inflight is not None,
@@ -1357,6 +1369,10 @@ class LLMEngine:
         compile_stats = getattr(self.runner, "compile_stats", None)
         if compile_stats is not None:
             snap["compile"] = dict(compile_stats)
+        # the host's phases, stalls and collections, lifetime totals
+        # (engine_host_phase_ms_total{phase}, engine_stalls_total,
+        # engine_stall_ms_total on /metrics)
+        snap.update(self.phases.snapshot())
         if self.roofline is not None:
             # rolling-window MFU/MBU (engine_step_mfu /
             # engine_step_mbu{phase}); the per-step series rides the
@@ -1378,10 +1394,11 @@ class LLMEngine:
     def step(self) -> list[OmniRequestOutput]:
         """One engine step.  The step and its host phases (``omni.*``,
         docs/observability.md) are annotations in the profiler's own
-        trace, where they share a clock with the device's operations;
-        with no profiler session each is a flag test."""
-        with jax.profiler.StepTraceAnnotation(
-                "omni.step", step_num=self._steps_completed):
+        trace, where they share a clock with the device's operations
+        (with no profiler session each is a flag test), and phases of
+        the engine's own clock, which keeps their time on every step."""
+        with self.phases.phase("omni.step",
+                               step_num=self._steps_completed):
             return self._step()
 
     def _step(self) -> list[OmniRequestOutput]:
@@ -1390,11 +1407,11 @@ class LLMEngine:
         # fail_step raises into the caller) — one dict lookup when no
         # fault plan is installed
         fault_point("step")
-        t_step0 = time.perf_counter()
+        self.phases.mark()
         # deadline sweep BEFORE scheduling: expired requests become
         # deadline_exceeded outputs this very step instead of consuming
         # another forward (resilience/deadline.py)
-        with _phase("omni.schedule"):
+        with self.phases.phase("omni.schedule"):
             self.scheduler.expire_deadlines()
             # surface intake-rejected requests as errored outputs instead
             # of silently dropping them
@@ -1413,11 +1430,11 @@ class LLMEngine:
         errored = [OmniRequestOutput.from_pipeline(r)
                    for r in errored_reqs]
         if self.config.async_scheduling:
-            outs = errored + self._step_async(t_step0)
+            outs = errored + self._step_async()
         else:
-            with _phase("omni.schedule"):
+            with self.phases.phase("omni.schedule"):
                 sched_out = self.scheduler.schedule()
-            outs = errored + self._run_scheduled(sched_out, t_step0)
+            outs = errored + self._run_scheduled(sched_out)
         # counted at COMPLETION: a step wedged mid-flight never
         # advances the watchdog's progress signal
         self._steps_completed += 1
@@ -1428,7 +1445,7 @@ class LLMEngine:
         self.async_fallback[reason] = self.async_fallback.get(
             reason, 0) + 1
 
-    def _step_async(self, t_step0: float) -> list[OmniRequestOutput]:
+    def _step_async(self) -> list[OmniRequestOutput]:
         """Two-slot pipelined step: dispatch step N BEFORE retiring step
         N-1 — the device starts computing N while the host does N-1's
         token readback, stop checks, and bookkeeping, plus (on the next
@@ -1440,7 +1457,7 @@ class LLMEngine:
         synchronous path, counted per reason in ``async_fallback``."""
         ready, reason = self._pipeline_ready()
         if ready:
-            with _phase("omni.schedule"):
+            with self.phases.phase("omni.schedule"):
                 sched_out = self.scheduler.schedule()
                 self.step_metrics.on_schedule(
                     waiting=len(self.scheduler.waiting),
@@ -1451,17 +1468,16 @@ class LLMEngine:
                 # the in-flight retire (e.g. a spec verify's accept
                 # count pins the request's next KV position) — retire
                 # now; the freed knowledge schedules next step
-                outs, _ = self._drain_pipeline()
-                return outs
+                return self._drain_pipeline()
             if self._pipeline_eligible(sched_out):
-                return self._step_pipelined(sched_out, t_step0)
+                return self._step_pipelined(sched_out)
             # scheduled but not dispatchable (e.g. page pressure
             # preempted the whole batch, or the reshaped batch fell off
             # the unified fast path): drain the pipeline, drop requests
             # the retire just finished from the stale schedule, and run
             # the remainder synchronously
             self._note_fallback("reshaped")
-            outs, drain_wait = self._drain_pipeline()
+            outs = self._drain_pipeline()
             drop = lambda ss: [  # noqa: E731
                 s for s in ss
                 if not s.request.is_finished
@@ -1470,19 +1486,16 @@ class LLMEngine:
             sched_out.decodes = drop(sched_out.decodes)
             sched_out.prefills = drop(sched_out.prefills)
             return outs + self._run_scheduled(
-                sched_out, t_step0, skip_on_schedule=True,
-                drained_wait_s=drain_wait, fallback="reshaped")
+                sched_out, skip_on_schedule=True, fallback="reshaped")
         # fallback step (prefills / spec / logprobs / streaming / ...):
         # retire FIRST so scheduling sees post-retire state and decode
         # inputs are host-visible for the synchronous runner
         if reason is not None:
             self._note_fallback(reason)
-        outs, drain_wait = self._drain_pipeline()
-        with _phase("omni.schedule"):
+        outs = self._drain_pipeline()
+        with self.phases.phase("omni.schedule"):
             sched_out = self.scheduler.schedule()
-        return outs + self._run_scheduled(sched_out, t_step0,
-                                          drained_wait_s=drain_wait,
-                                          fallback=reason)
+        return outs + self._run_scheduled(sched_out, fallback=reason)
 
     def _pipeline_ready(self) -> "tuple[bool, Optional[str]]":
         """Cheap pre-schedule check: can the NEXT step be dispatched
@@ -1540,15 +1553,14 @@ class LLMEngine:
             return False
         return True
 
-    def _step_pipelined(self, sched_out: SchedulerOutput,
-                        t_step0: float) -> list[OmniRequestOutput]:
-        rec = get_recorder()
+    def _step_pipelined(self, sched_out: SchedulerOutput
+                        ) -> list[OmniRequestOutput]:
         prev = self._inflight
         scheduled = sched_out.prefills + sched_out.decodes
-        with _phase("omni.record"):
+        with self.phases.phase("omni.record"):
             self._note_first_scheduled(scheduled)
             self._observe_saturation(sched_out)
-        t_d0, w_d0 = time.perf_counter(), time.time()
+        t_d0 = self.phases.last_ns
         u0, p0 = self._padding_totals()
         if self.runner._plain_decode_only(sched_out):
             handle = self.runner.dispatch_decode(
@@ -1560,49 +1572,83 @@ class LLMEngine:
             # logprobs, and embeds batches pipeline too
             handle = self.runner.dispatch_unified(
                 sched_out, prev.handle if prev is not None else None)
-        with _phase("omni.record"):
+        with self.phases.phase("omni.record"):
             # schedule-ahead accounting: the dispatched rows' tokens are
             # now in flight; the next schedule() counts them without seeing
             # their values
             self.scheduler.note_async_dispatch(sched_out)
             self._observe_padding(u0, p0)
-            dur_disp = time.perf_counter() - t_d0
-            for s in scheduled:
-                rec.record(s.request.additional_information.get("trace"),
-                           "dispatch", w_d0, dur_disp,
-                           stage_id=self.stage_id,
-                           args={"batch": len(scheduled)},
-                           **self.span_tags)
+            self._record_spans(self._traced_rows(scheduled), "dispatch",
+                               t_d0, lambda s: {"batch": len(scheduled)})
         self._inflight = _InflightStep(sched_out=sched_out, handle=handle)
         outs: list[OmniRequestOutput] = []
         new_total = 0
-        wait_s = 0.0
         if prev is not None:
             # step N-1's host work, overlapped with step N's compute
-            outs, new_total, wait_s = self._retire_step(prev)
+            outs, new_total = self._retire_step(prev)
             if not self.scheduler.has_unfinished:
                 # the step just dispatched is pure overshoot (every
                 # request finished at this retire): drain it now instead
                 # of dangling device buffers + finished requests until
                 # the next traffic burst
-                extra, drain_wait = self._drain_pipeline()
-                outs += extra
-                wait_s += drain_wait
-        with _phase("omni.record"):
-            total_s = time.perf_counter() - t_step0
-            host_ms = max(total_s - wait_s, 0.0) * 1e3
+                outs += self._drain_pipeline()
+        with self.phases.phase("omni.record"):
             # with a predecessor in flight, schedule+dispatch overlapped ITS
             # compute and the retire's post-wait work overlaps the step just
             # dispatched — the only unoverlapped host time is the wait
-            self.step_metrics.on_step(
-                step_ms=total_s * 1e3, new_tokens=new_total,
-                prefill_tokens=_prompt_tokens_computed(sched_out),
-                host_ms=host_ms, device_ms=wait_s * 1e3,
-                overlapped_host_ms=host_ms if prev is not None else 0.0,
-            )
-            self._record_step("pipelined", sched_out, scheduled, new_total,
-                              host_ms=host_ms, device_ms=wait_s * 1e3)
+            self._close_step("pipelined", sched_out, scheduled, new_total,
+                             overlapped=prev is not None)
             return outs
+
+    @staticmethod
+    def _traced_rows(scheds) -> list:
+        """The rows of a step whose request carries a trace context, each
+        with it.  Walked once a step; the loops that record a journey
+        span a row are entered only for these (``rec.record(None, ..)``
+        returns at once, but at 64 rows a step the calls alone cost tens
+        of microseconds)."""
+        return [(s, ctx) for s in scheds
+                if (ctx := s.request.additional_information.get("trace"))]
+
+    def _record_spans(self, traced: list, name: str, t0_ns: int,
+                      args_of) -> None:
+        """One journey span a traced row, from the clock's reading
+        ``t0_ns`` to its last stamp (the entry of the phase the caller
+        is in, or the exit of the one before)."""
+        if not traced:
+            return
+        rec = get_recorder()
+        dur_s = (self.phases.last_ns - t0_ns) / 1e9
+        # span starts stay wall-clock (timelines align on wall stamps)
+        start_w = time.time() - (self.phases.now_ns() - t0_ns) / 1e9
+        for s, ctx in traced:
+            rec.record(ctx, name, start_w, dur_s, stage_id=self.stage_id,
+                       args=args_of(s), **self.span_tags)
+
+    def _close_step(self, path: str, sched_out: SchedulerOutput, scheduled,
+                    new_total: int, overlapped: bool = False,
+                    fallback: Optional[str] = None) -> None:
+        """The step's time, read off the clock at the entry of the
+        ``omni.record`` the caller is in: ``device_ms`` is what the
+        engine thread spent waiting on the device (``omni.dispatch`` and
+        ``omni.readback`` up to the return of ``device_get``, a drained
+        predecessor's included), ``host_ms`` the rest of the step."""
+        took = self.phases.since_mark(
+            step=self._steps_completed,
+            waiting=len(self.scheduler.waiting),
+            running=len(self.scheduler.running))
+        device_ms = took["device_ms"]
+        host_ms = max(took["step_ms"] - device_ms, 0.0)
+        self.step_metrics.on_step(
+            step_ms=host_ms + device_ms, new_tokens=new_total,
+            prefill_tokens=_prompt_tokens_computed(sched_out),
+            host_ms=host_ms, device_ms=device_ms,
+            overlapped_host_ms=host_ms if overlapped else 0.0,
+        )
+        self._record_step(path, sched_out, scheduled, new_total,
+                          host_ms=host_ms, device_ms=device_ms,
+                          fallback=fallback, phases_ms=took["phases_ms"],
+                          cpu_ms=took["cpu_ms"])
 
     def _consolidate_hidden(self, finished) -> None:
         """Fold per-step hidden chunks into the next-stage payload
@@ -1623,12 +1669,11 @@ class LLMEngine:
     def _retire_step(self, inflight: _InflightStep):
         """Retire a dispatched step: the single lagged device_get, then
         token append / stop checks / latency bookkeeping.  Returns
-        (outputs, new_tokens, seconds spent blocked on the device)."""
-        rec = get_recorder()
-        t_g0, w_g0 = time.perf_counter(), time.time()
+        (outputs, new_tokens); what the get waited on the device is on
+        the clock (``omni.readback``)."""
+        t_g0 = self.phases.last_ns
         sampled = self.runner.retire_step(inflight.handle)
-        wait_s = time.perf_counter() - t_g0
-        with _phase("omni.update"):
+        with self.phases.phase("omni.update"):
             finished = self.scheduler.update_from_async_retire(
                 inflight.sched_out, sampled)
             self._consolidate_hidden(finished)
@@ -1645,30 +1690,28 @@ class LLMEngine:
                     if not s.request.is_finished
                     or s.request.request_id in just_finished]
             new_total = self._observe_token_latencies(live, finished)
-            dur = time.perf_counter() - t_g0
-            for s in scheds:
-                rec.record(s.request.additional_information.get("trace"),
-                           "retire", w_g0, dur, stage_id=self.stage_id,
-                           args={"batch": len(scheds)}, **self.span_tags)
+        with self.phases.phase("omni.record"):
+            self._record_spans(self._traced_rows(scheds), "retire", t_g0,
+                               lambda s: {"batch": len(scheds)})
         outs = [OmniRequestOutput.from_pipeline(r) for r in finished]
-        return outs, new_total, wait_s
+        return outs, new_total
 
-    def _drain_pipeline(self) -> tuple[list[OmniRequestOutput], float]:
+    def _drain_pipeline(self) -> list[OmniRequestOutput]:
         """Retire the in-flight step (if any) so the host state is fully
-        caught up before a synchronous step runs.  Returns (outputs,
-        seconds blocked on the device) — the caller folds the wait into
-        its step's device time so the host/device breakdown stays
-        honest across pipeline-to-sync transitions."""
+        caught up before a synchronous step runs.  Its wait on the
+        device is on the clock, so it lands in the device time of the
+        step that drained it and the host/device breakdown stays honest
+        across pipeline-to-sync transitions."""
         if self._inflight is None:
-            return [], 0.0
+            return []
         inflight, self._inflight = self._inflight, None
-        outs, new_total, wait_s = self._retire_step(inflight)
+        outs, new_total = self._retire_step(inflight)
         # the drained step has no on_step of its own (the sync step that
         # follows records this call's single on_step, and its per-request
         # deltas were already consumed here): credit the tokens directly
         # so throughput counters stay exact
         self.step_metrics.tokens_generated += new_total
-        return outs, wait_s
+        return outs
 
     # ------------------------------------------------------ kv tier moves
     def _drain_kv_moves(self) -> set[str]:
@@ -1745,12 +1788,11 @@ class LLMEngine:
         return failed
 
     # --------------------------------------------------- synchronous step
-    def _run_scheduled(self, sched_out: SchedulerOutput, t_step0: float,
+    def _run_scheduled(self, sched_out: SchedulerOutput,
                        skip_on_schedule: bool = False,
-                       drained_wait_s: float = 0.0,
                        fallback: Optional[str] = None
                        ) -> list[OmniRequestOutput]:
-        with _phase("omni.kv_moves"):
+        with self.phases.phase("omni.kv_moves"):
             failed_restores = self._drain_kv_moves()
         if failed_restores:
             # a restore came up short: this step's chunks for those
@@ -1823,67 +1865,45 @@ class LLMEngine:
             # only streaming requests idling for their next chunk remain
             return []
         self._starved_ticks = 0
-        rec = get_recorder()
         scheduled = sched_out.prefills + sched_out.decodes
-        with _phase("omni.record"):
+        with self.phases.phase("omni.record"):
             self._note_first_scheduled(scheduled)
             self._observe_saturation(sched_out)
-        t_ex0, w_ex0 = time.perf_counter(), time.time()
+        # the clock's stamps bound the intervals the journey spans
+        # report: no timer of the step's own
+        t_ex0 = self.phases.last_ns
         u0, p0 = self._padding_totals()
         run_out = self.runner.execute(
             sched_out, extract_kv=self.kv_transfer_sink is not None
         )
-        with _phase("omni.record"):
+        with self.phases.phase("omni.record"):
             self._observe_padding(u0, p0)
-            dur_ex = time.perf_counter() - t_ex0
-            for s in sched_out.prefills:
-                rec.record(s.request.additional_information.get("trace"),
-                           "prefill", w_ex0, dur_ex,
-                           stage_id=self.stage_id,
-                           args={"tokens": s.num_new_tokens,
-                                 "start_pos": s.start_pos},
-                           **self.span_tags)
-            for s in sched_out.decodes:
-                rec.record(s.request.additional_information.get("trace"),
-                           "decode", w_ex0, dur_ex,
-                           stage_id=self.stage_id,
-                           args={"tokens": s.num_new_tokens},
-                           **self.span_tags)
+            traced_p = self._traced_rows(sched_out.prefills)
+            traced_d = self._traced_rows(sched_out.decodes)
+            self._record_spans(
+                traced_p, "prefill", t_ex0,
+                lambda s: {"tokens": s.num_new_tokens,
+                           "start_pos": s.start_pos})
+            self._record_spans(
+                traced_d, "decode", t_ex0,
+                lambda s: {"tokens": s.num_new_tokens})
             if self.kv_transfer_sink is not None:
                 for req, _, _ in sched_out.kv_transfer_requests:
                     payload = run_out.extracted_kv.get(req.request_id)
                     if payload is not None:
                         self.kv_transfer_sink(req, payload)
-        with _phase("omni.update"):
-            t_up0, w_up0 = time.perf_counter(), time.time()
+        t_up0 = self.phases.last_ns
+        with self.phases.phase("omni.update"):
             finished = self.scheduler.update_from_output(
                 sched_out, run_out.sampled, run_out.kv_extracted_req_ids
             )
-            dur_up = time.perf_counter() - t_up0
-            for s in scheduled:
-                rec.record(s.request.additional_information.get("trace"),
-                           "sampling", w_up0, dur_up,
-                           stage_id=self.stage_id,
-                           args={"batch": len(scheduled)}, **self.span_tags)
             new_total = self._observe_token_latencies(scheduled, finished)
-        with _phase("omni.record"):
-            total_s = time.perf_counter() - t_step0
-            self.step_metrics.on_step(
-                step_ms=total_s * 1e3,
-                new_tokens=new_total,
-                prefill_tokens=_prompt_tokens_computed(sched_out),
-                # execute() syncs internally, so its span (plus any
-                # pipeline-drain wait that preceded it) is the
-                # device-bound portion; no host work overlaps it
-                host_ms=max(total_s - dur_ex - drained_wait_s, 0.0) * 1e3,
-                device_ms=(dur_ex + drained_wait_s) * 1e3,
-                overlapped_host_ms=0.0,
-            )
-            self._record_step(
-                "sync", sched_out, scheduled, new_total,
-                host_ms=max(total_s - dur_ex - drained_wait_s, 0.0) * 1e3,
-                device_ms=(dur_ex + drained_wait_s) * 1e3,
-                fallback=fallback)
+        with self.phases.phase("omni.record"):
+            self._record_spans(traced_p + traced_d, "sampling", t_up0,
+                               lambda s: {"batch": len(scheduled)})
+            # execute() syncs internally: no host work overlaps the wait
+            self._close_step("sync", sched_out, scheduled, new_total,
+                             fallback=fallback)
             self._consolidate_hidden(finished)
             if not self.scheduler.has_unfinished:
                 # no further step will run: drain transfers triggered

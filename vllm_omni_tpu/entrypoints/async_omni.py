@@ -18,12 +18,11 @@ import threading
 import time
 from typing import Any, AsyncIterator, Optional, Union
 
-from jax.profiler import TraceAnnotation as _phase
-
 from vllm_omni_tpu.analysis.runtime import traced
 from vllm_omni_tpu.config.stage import StageConfig
 from vllm_omni_tpu.entrypoints.omni import Omni
 from vllm_omni_tpu.entrypoints.omni_stage import StageRequest
+from vllm_omni_tpu.introspection import PhaseClock
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.outputs import OmniRequestOutput
 
@@ -270,15 +269,20 @@ class AsyncOmni:
                         if -1 in s.config.engine_input_source]
         entry_stage = entry_stages[0] if entry_stages else omni.stages[0]
         self._last_stats = time.monotonic()
+        # the phases of this loop beside the engine's (omni.step and
+        # below, docs/observability.md), in the profiler's own trace and
+        # on the entry stage's engine's clock: what this thread does
+        # while the device waits for it.  A stage in a process of its own
+        # has no engine here; the loop then keeps a clock of its own.
+        self._phases = getattr(getattr(entry_stage, "engine", None),
+                               "phases", None) or PhaseClock()
         while self._running:
-            # the phases of this loop in the profiler's own trace, beside
-            # the engine's (omni.step and below, docs/observability.md):
-            # what this thread does while the device waits for it
-            with _phase("omni.loop"):
+            with self._phases.phase("omni.loop"):
                 self._loop_once(entry_stage)
 
     def _loop_once(self, entry_stage) -> None:
         omni = self._omni
+        _phase = self._phases.phase
         # periodic engine-level stats heartbeat (reference: the
         # do_log_stats keep-alive task, omni_stage.py:1134-1146)
         now = time.monotonic()
@@ -370,6 +374,13 @@ class AsyncOmni:
                         self._emit(o.request_id, e)
                         self._emit(o.request_id, _SENTINEL)
         if not progressed and not pending:
-            # idle: avoid a hot spin on the GIL
-            with _phase("omni.idle_wait"):
+            # idle: avoid a hot spin on the GIL.  The engine returns
+            # finished requests only, so this wait is also taken after
+            # every step that finished none, with rows waiting for their
+            # next token: counted as a busy wait (ROADMAP S5)
+            busy = any(getattr(s, "has_unfinished", False)
+                       for s in omni.stages)
+            with _phase("omni.idle_wait") as p:
                 threading.Event().wait(0.002)
+            if busy:
+                self._phases.note_busy_wait(p.ns)

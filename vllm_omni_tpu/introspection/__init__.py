@@ -1,6 +1,6 @@
 """Introspection: the debugging spine of the serving stack.
 
-Four faces (docs/debugging.md):
+Five faces (docs/debugging.md):
 
 - **flight recorder** — bounded ring of per-step records appended from
   ``LLMEngine``'s step paths with zero device syncs, dumped as JSON on
@@ -11,7 +11,10 @@ Four faces (docs/debugging.md):
 - **/debug/z** — live JSON views served by the OpenAI server
   (debugz.py);
 - **device-memory ledger** — per-component HBM accounting with peak
-  watermarks, CPU-deterministic fallback (memory_ledger.py).
+  watermarks, CPU-deterministic fallback (memory_ledger.py);
+- **phase clock** — the engine thread's ``omni.*`` phases on the host's
+  clock on every step, the step's host/device split, and the stalls of
+  a second and more caught where they happen (phase_clock.py).
 
 This module owns the process-global engine registry: engines register
 at construction (weakly — registration must never extend an engine's
@@ -33,11 +36,13 @@ from vllm_omni_tpu.introspection.flight_recorder import (
     install_crash_hooks,
 )
 from vllm_omni_tpu.introspection.memory_ledger import DeviceMemoryLedger
+from vllm_omni_tpu.introspection.phase_clock import PhaseClock
 from vllm_omni_tpu.introspection.watchdog import StallWatchdog
 
 __all__ = [
     "FlightRecorder",
     "DeviceMemoryLedger",
+    "PhaseClock",
     "StallWatchdog",
     "build_dump",
     "capture_stacks",

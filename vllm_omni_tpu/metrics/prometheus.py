@@ -151,11 +151,13 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     # step-phase breakdown (async pipelined engine, docs/async_engine.md)
     "engine_step_host_ms": (
         "histogram",
-        "Host-side work per engine step (schedule, retire, bookkeeping)",
+        "Host-side work per engine step: the step less its wait on the "
+        "device (schedule, assemble, unpack, update, bookkeeping)",
         ("stage",)),
     "engine_step_device_ms": (
         "histogram",
-        "Device-bound wait per engine step (execute or lagged retire)",
+        "Time the engine thread waited on the device per engine step "
+        "(the jitted call's launch and the readback's device_get)",
         ("stage",)),
     "engine_step_overlap_ratio": (
         "gauge",
@@ -168,6 +170,22 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "counter",
         "Host step work milliseconds performed while a dispatched "
         "device step was in flight", ("stage",)),
+    # ---- the host's phases on the host's clock, every step
+    # (introspection/phase_clock.py, docs/observability.md)
+    "engine_host_phase_ms_total": (
+        "counter",
+        "Exclusive milliseconds the engine thread spent in a host phase "
+        "(omni.*: the phases of one turn of the loop sum to the turn)",
+        ("stage", "phase")),
+    "engine_stalls_total": (
+        "counter",
+        "Turns of the engine loop of a second or more and four times "
+        "the recent 99th percentile, with no compile in them",
+        ("stage",)),
+    "engine_stall_ms_total": (
+        "counter",
+        "Milliseconds of stalled turns above the recent 99th percentile",
+        ("stage",)),
     # ---- unified ragged batching (docs/ragged_batching.md)
     "engine_step_padding_efficiency": (
         "gauge",
@@ -669,6 +687,17 @@ def render_exposition(summary: dict, engine_snaps: dict,
                        overlap.get("ratio", 0.0))
             exp.sample("engine_step_overlapped_host_ms_total", labels,
                        overlap.get("overlapped_host_ms_total", 0.0))
+        phases = snap.get("phases")
+        if phases:
+            for phase, v in sorted((phases.get("by_name") or {}).items()):
+                exp.sample("engine_host_phase_ms_total",
+                           {**labels, "phase": phase}, v.get("ms", 0.0))
+        stalls = snap.get("stalls")
+        if stalls:
+            exp.sample("engine_stalls_total", labels,
+                       stalls.get("total", 0))
+            exp.sample("engine_stall_ms_total", labels,
+                       stalls.get("ms_total", 0.0))
         if snap.get("batched_tokens"):
             exp.histogram("engine_step_batched_tokens", labels,
                           snap["batched_tokens"])

@@ -337,9 +337,12 @@ class EngineStepMetrics:
         self.itl_ms = Histogram()
         self.step_ms = Histogram()
         # step-phase breakdown: host-side work vs. device-bound wait per
-        # step, plus how much of the host work ran while a dispatched
-        # step was still computing (the async pipeline's win — see
-        # docs/async_engine.md; sync steps overlap nothing)
+        # step (device_ms is what the engine thread waited on the
+        # device by its phase clock, host_ms the rest of the step:
+        # introspection/phase_clock.py), plus how much of the host work
+        # ran while a dispatched step was still computing (the async
+        # pipeline's win — see docs/async_engine.md; sync steps overlap
+        # nothing)
         self.host_ms = Histogram()
         self.device_ms = Histogram()
         # tokens per step (REAL tokens computed, before padding) — with

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from vllm_omni_tpu.core.scheduler import SchedulerOutput
+from vllm_omni_tpu.introspection.phase_clock import PhaseClock
 from vllm_omni_tpu.worker.model_runner import (
     RunnerOutput,
     _bucket,
@@ -37,6 +38,8 @@ class GenerationModelRunner:
         self._batch_buckets = _make_buckets(1, max(max_num_seqs, 1))
         self._seq_buckets = _make_buckets(16, max(max_model_len, 16))
         self._forward = jax.jit(model.forward)
+        # the engine replaces it with its own clock (ARModelRunner)
+        self.phases = PhaseClock()
 
     def precompile(self, prefill_shapes=(), progress_fn=None) -> int:
         """Warm the cond-free padded-batch forward for declared
@@ -84,19 +87,25 @@ class GenerationModelRunner:
         if hasattr(self.model, "batch_conditioning"):
             cond = self.model.batch_conditioning(
                 [sc.request for sc in scheds], b)
-        if cond is not None:
-            outputs = self._forward(
-                self.params, jnp.asarray(token_ids),
-                jnp.asarray(lengths), cond)
-        else:
-            outputs = self._forward(
-                self.params, jnp.asarray(token_ids), jnp.asarray(lengths)
-            )
-        # one pytree transfer, not a sync per output key (first
-        # omnilint OL2 harvest)
-        # omnilint: disable=OL2 - single batched sync per one-shot batch
-        outputs = {k: np.asarray(v)
-                   for k, v in jax.device_get(outputs).items()}
+        # the launch and the one transfer back are what this step waits
+        # on the device (``device_ms`` of the step's record)
+        with self.phases.phase("omni.dispatch") as p:
+            if cond is not None:
+                outputs = self._forward(
+                    self.params, jnp.asarray(token_ids),
+                    jnp.asarray(lengths), cond)
+            else:
+                outputs = self._forward(
+                    self.params, jnp.asarray(token_ids),
+                    jnp.asarray(lengths))
+            p.waited()
+        with self.phases.phase("omni.readback") as p:
+            # one pytree transfer, not a sync per output key (first
+            # omnilint OL2 harvest)
+            # omnilint: disable=OL2 - single batched sync per one-shot batch
+            outputs = jax.device_get(outputs)
+            p.waited()
+            outputs = {k: np.asarray(v) for k, v in outputs.items()}
         for i, sc in enumerate(scheds):
             sc.request.multimodal_output.update(
                 self.model.slice_output(outputs, i, int(lengths[i]))

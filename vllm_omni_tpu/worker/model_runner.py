@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import functools
 import secrets
-import time
 import zlib
 from dataclasses import dataclass, field
 from typing import Any, NamedTuple, Optional
@@ -51,6 +50,7 @@ from vllm_omni_tpu.core.scheduler import (
     SchedulerOutput,
     packed_rows_needed,
 )
+from vllm_omni_tpu.introspection.phase_clock import PhaseClock
 from vllm_omni_tpu.kvcache.quant import (
     dequantize_payload,
     is_quant_payload,
@@ -353,6 +353,9 @@ class ARModelRunner:
         # XLA compile reads as "compiling", never as a hung engine
         self.compile_stats = {"compiles": 0, "cache_hits": 0,
                               "compile_s": 0.0, "in_flight": 0}
+        # the host's phases on the host's clock; the engine replaces it
+        # with its own, so that a step's phases land on one clock
+        self.phases = PhaseClock()
         self._jit_seen: set[tuple] = set()
         # one (k, v) for each softmax-attention layer only; (pool, None)
         # where the layout is the shared latent one
@@ -726,20 +729,27 @@ class ARModelRunner:
         key = (kind,) + tuple(shape_key)
         if key in self._jit_seen:
             self.compile_stats["cache_hits"] += 1
-            # a host phase in the profiler's trace (docs/observability.md)
-            with jax.profiler.TraceAnnotation("omni.dispatch"):
-                return thunk()
+            # a host phase (docs/observability.md): the launch is time
+            # the engine thread waits on the device's runtime
+            with self.phases.phase("omni.dispatch") as p:
+                result = thunk()
+                p.waited()
+            return result
         self._jit_seen.add(key)
-        t0 = time.perf_counter()
         self.compile_stats["in_flight"] = 1
         try:
-            with jax.profiler.TraceAnnotation("omni.dispatch"):
+            with self.phases.phase("omni.dispatch") as p:
+                # after the phase is entered: where it is the outermost
+                # one (precompile, a runner driven alone) entering it
+                # begins the turn, which forgets what was noted before
+                self.phases.note_compile()  # this turn is no stall
                 result = thunk()
                 jax.block_until_ready(result)
+                p.waited()
         finally:
             self.compile_stats["in_flight"] = 0
         self.compile_stats["compiles"] += 1
-        self.compile_stats["compile_s"] += time.perf_counter() - t0
+        self.compile_stats["compile_s"] += p.ns / 1e9
         return result
 
     def memory_components(self) -> dict:
@@ -1163,54 +1173,60 @@ class ARModelRunner:
         scheds = decodes + prefills
         spec_rows = {i for i, s in enumerate(decodes)
                      if s.num_new_tokens > 1}
-        # host phases on the profiler's clock (docs/observability.md):
-        # packing, sampling tensors and host-to-device puts are
-        # omni.assemble; the jitted call alone is omni.dispatch (_run_jit)
-        with jax.profiler.TraceAnnotation("omni.assemble"):
-            asm = self._assemble_unified(scheds, spec_rows)
-            tensors, final = self._unified_sampling(scheds, "unified",
-                                                    asm.t_pad)
-            feed_dst: list[int] = []
-            feed_src: list[int] = []
-            for i, sc in enumerate(scheds):
-                if sc.start_pos >= sc.request.num_tokens and (
-                        prev is not None
-                        and sc.request.request_id in prev.rows):
-                    # input token sampled by the previous dispatch, still
-                    # device-resident
-                    feed_dst.append(int(asm.cu_q_lens[i]))
-                    feed_src.append(prev.rows[sc.request.request_id])
-            token_ids = jnp.asarray(asm.token_ids)
-            if feed_dst:
-                token_ids = token_ids.at[jnp.asarray(feed_dst)].set(
-                    prev.tokens[jnp.asarray(feed_src)])
-            # verify tokens are USEFUL work (each is a candidate position
-            # the model scores); only block-alignment slack pads
-            self._note_padding(int(asm.q_lens.sum()), asm.t_pad)
-            if spec_rows:
-                self.spec_stats["verify_steps"] += 1
-            kwargs = {}
-            if asm.embeds is not None:
-                kwargs["inputs_embeds"] = jnp.asarray(
-                    asm.embeds, dtype=self.params_dtype)
-                kwargs["embeds_mask"] = jnp.asarray(asm.embeds_mask)
-            if asm.deepstack is not None:
-                kwargs["deepstack"] = jnp.asarray(
-                    asm.deepstack, dtype=self.params_dtype)
-            args = (
-                jnp.asarray(asm.positions), jnp.asarray(asm.slots),
-                jnp.asarray(asm.tables), jnp.asarray(asm.seq_lens),
-                jnp.asarray(asm.cu_q_lens), jnp.asarray(asm.q_lens),
-                # numpy first: a Python list would compile a convert op
-                jnp.asarray(np.asarray([len(scheds)], np.int32)),
-                jnp.asarray(asm.verify_idx), jnp.asarray(asm.n_cand),
-                jnp.asarray(asm.drafts),
-                tensors.temperature, tensors.top_k, tensors.top_p,
-                tensors.keys,
+        # host phases (docs/observability.md): packing, sampling tensors
+        # and host-to-device puts are omni.assemble, one annotation in
+        # the profiler's trace and three children on the host's clock
+        # alone; the jitted call alone is omni.dispatch (_run_jit)
+        phase = self.phases.phase
+        with phase("omni.assemble"):
+            with phase("omni.assemble.pack", trace=False):
+                asm = self._assemble_unified(scheds, spec_rows)
+                feed_dst: list[int] = []
+                feed_src: list[int] = []
+                for i, sc in enumerate(scheds):
+                    if sc.start_pos >= sc.request.num_tokens and (
+                            prev is not None
+                            and sc.request.request_id in prev.rows):
+                        # input token sampled by the previous dispatch,
+                        # still device-resident
+                        feed_dst.append(int(asm.cu_q_lens[i]))
+                        feed_src.append(prev.rows[sc.request.request_id])
                 # the slot column of a model with recurrent layers
-                *map(jnp.asarray, self._slot_column(
+                slot_cols = self._slot_column(
                     scheds, self._batch_buckets[-1], asm.cu_q_lens,
-                    asm.t_pad)))
+                    asm.t_pad)
+                # verify tokens are USEFUL work (each is a candidate
+                # position the model scores); only block-alignment slack
+                # pads
+                self._note_padding(int(asm.q_lens.sum()), asm.t_pad)
+                if spec_rows:
+                    self.spec_stats["verify_steps"] += 1
+            with phase("omni.assemble.sampling", trace=False):
+                tensors, final = self._unified_sampling(scheds, "unified",
+                                                        asm.t_pad)
+            with phase("omni.assemble.put", trace=False):
+                token_ids = jnp.asarray(asm.token_ids)
+                if feed_dst:
+                    token_ids = token_ids.at[jnp.asarray(feed_dst)].set(
+                        prev.tokens[jnp.asarray(feed_src)])
+                kwargs = {}
+                if asm.embeds is not None:
+                    kwargs["inputs_embeds"] = jnp.asarray(
+                        asm.embeds, dtype=self.params_dtype)
+                    kwargs["embeds_mask"] = jnp.asarray(asm.embeds_mask)
+                if asm.deepstack is not None:
+                    kwargs["deepstack"] = jnp.asarray(
+                        asm.deepstack, dtype=self.params_dtype)
+                args = (
+                    jnp.asarray(asm.positions), jnp.asarray(asm.slots),
+                    jnp.asarray(asm.tables), jnp.asarray(asm.seq_lens),
+                    jnp.asarray(asm.cu_q_lens), jnp.asarray(asm.q_lens),
+                    # numpy first: a Python list would compile a convert op
+                    jnp.asarray(np.asarray([len(scheds)], np.int32)),
+                    jnp.asarray(asm.verify_idx), jnp.asarray(asm.n_cand),
+                    jnp.asarray(asm.drafts),
+                    tensors.temperature, tensors.top_k, tensors.top_p,
+                    tensors.keys, *map(jnp.asarray, slot_cols))
         outs, self.kv_caches = self._run_jit(
             "unified",
             # the deepstack LEVEL COUNT is part of the operand shape —
@@ -1243,46 +1259,51 @@ class ARModelRunner:
         still in flight) are gathered device-side from ``prev.tokens``.
         The engine retires the handle one step later (``retire_step``)."""
         self._step += 1
-        with jax.profiler.TraceAnnotation("omni.assemble"):
-            b = self._decode_bucket(len(scheds))
-            token_host = np.zeros((b,), np.int32)
-            feed_rows: list[int] = []
-            feed_src: list[int] = []
-            params_list = [_PAD_SAMPLING] * b
-            salts = [0] * b
-            want_lp = False
-            for i, sc in enumerate(scheds):
-                req = sc.request
-                if sc.start_pos < req.num_tokens:
-                    token_host[i] = req.all_token_ids[sc.start_pos]
-                else:
-                    # input token still in flight from the previous
-                    # dispatch
-                    feed_rows.append(i)
-                    feed_src.append(prev.rows[req.request_id])
-                params_list[i] = req.sampling_params
-                salts[i] = self._salt_of(req.request_id)
-                if req.sampling_params.logprobs is not None:
-                    want_lp = True
-            positions, slots, tables, ctx = self._assemble_decode_rows(
-                scheds, b)
-            token_ids = jnp.asarray(token_host)
-            if feed_rows:
-                token_ids = token_ids.at[jnp.asarray(feed_rows)].set(
-                    prev.tokens[jnp.asarray(feed_src)])
-            kind = "dispatch_lp" if want_lp else "dispatch"
-            fn = self._decode_lp_fn if want_lp else self._decode_sample_fn
-            key = (kind, b) + tuple(
-                (sc.request.request_id,) + _params_key(
-                    sc.request.sampling_params) for sc in scheds)
-            tensors = self._sampling_tensors(key, params_list, salts)
-            self._note_padding(len(scheds), b)
-            args = (
-                jnp.asarray(positions), jnp.asarray(slots),
-                jnp.asarray(tables), jnp.asarray(ctx),
-                tensors.temperature, tensors.top_k, tensors.top_p,
-                tensors.keys,
-                *map(jnp.asarray, self._slot_column(scheds, b)))
+        phase = self.phases.phase
+        with phase("omni.assemble"):
+            with phase("omni.assemble.pack", trace=False):
+                b = self._decode_bucket(len(scheds))
+                token_host = np.zeros((b,), np.int32)
+                feed_rows: list[int] = []
+                feed_src: list[int] = []
+                params_list = [_PAD_SAMPLING] * b
+                salts = [0] * b
+                want_lp = False
+                for i, sc in enumerate(scheds):
+                    req = sc.request
+                    if sc.start_pos < req.num_tokens:
+                        token_host[i] = req.all_token_ids[sc.start_pos]
+                    else:
+                        # input token still in flight from the previous
+                        # dispatch
+                        feed_rows.append(i)
+                        feed_src.append(prev.rows[req.request_id])
+                    params_list[i] = req.sampling_params
+                    salts[i] = self._salt_of(req.request_id)
+                    if req.sampling_params.logprobs is not None:
+                        want_lp = True
+                positions, slots, tables, ctx = self._assemble_decode_rows(
+                    scheds, b)
+                slot_cols = self._slot_column(scheds, b)
+                self._note_padding(len(scheds), b)
+            with phase("omni.assemble.sampling", trace=False):
+                kind = "dispatch_lp" if want_lp else "dispatch"
+                fn = (self._decode_lp_fn if want_lp
+                      else self._decode_sample_fn)
+                key = (kind, b) + tuple(
+                    (sc.request.request_id,) + _params_key(
+                        sc.request.sampling_params) for sc in scheds)
+                tensors = self._sampling_tensors(key, params_list, salts)
+            with phase("omni.assemble.put", trace=False):
+                token_ids = jnp.asarray(token_host)
+                if feed_rows:
+                    token_ids = token_ids.at[jnp.asarray(feed_rows)].set(
+                        prev.tokens[jnp.asarray(feed_src)])
+                args = (
+                    jnp.asarray(positions), jnp.asarray(slots),
+                    jnp.asarray(tables), jnp.asarray(ctx),
+                    tensors.temperature, tensors.top_k, tensors.top_p,
+                    tensors.keys, *map(jnp.asarray, slot_cols))
         outs, self.kv_caches = self._run_jit(
             kind, (b, self._kv_quant), lambda: fn(
                 self.params, token_ids, self.kv_caches, *args))
@@ -1302,15 +1323,17 @@ class ARModelRunner:
         spec-accepted lists), appends logprob entries and hidden
         chunks, and proposes the next drafts — every per-request side
         effect of the step happens here, behind the single transfer."""
-        with jax.profiler.TraceAnnotation("omni.readback"):
-            return self._readback(handle)
+        with self.phases.phase("omni.readback") as p:
+            # omnilint: disable=OL2 - the single lagged retire sync of the
+            # async pipeline: by the time the engine calls this, the NEXT
+            # step is already dispatched, so this get overlaps its compute
+            outs = jax.device_get(handle.outs)
+            # up to here the phase waited on the device; the rest unpacks
+            p.waited()
+            return self._unpack(handle, outs)
 
-    def _readback(self, handle: InflightDecode
-                  ) -> dict[str, "int | list[int]"]:
-        # omnilint: disable=OL2 - the single lagged retire sync of the
-        # async pipeline: by the time the engine calls this, the NEXT
-        # step is already dispatched, so this get overlaps its compute
-        outs = jax.device_get(handle.outs)
+    def _unpack(self, handle: InflightDecode, outs: dict
+                ) -> dict[str, "int | list[int]"]:
         self.sampler_steps[int(outs["sampler_path"])] += 1
         sampled: dict[str, "int | list[int]"] = {}
         if handle.kind == "decode":
