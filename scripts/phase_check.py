@@ -11,7 +11,10 @@ one JSON line with:
 - the window (the two snapshots): steps, the mean ``host_ms`` /
   ``device_ms`` / ``step_ms``, ``omni.assemble`` by child and what is
   left of it, every phase a step, the loop's share of the clock, the
-  busy waits, the stalls with their records, the collections;
+  busy waits, the stalls with their records, the collections, and how
+  often the two-slot pipeline engaged (the share of steps dispatched
+  ahead of their predecessor's retire, the drains, the fallbacks by
+  reason; absent for a program that keeps no such counts);
 - the tail, where ``--xplane`` names the run's trace (a ``.xplane.pb`` or
   a directory whose newest one is taken; a ``--trace 1`` run leaves its
   own under ``.benchmark_cache/trace/<cell>/``, a ``--trace 2`` run
@@ -81,6 +84,21 @@ def window(e0: dict, e1: dict) -> dict:
         "stall_records": records,
         "gc_ms": d("gc", "ms_total"),
         "gc_collections": {g: d("gc", "collections", g) for g in "012"},
+        **pipeline(e1, d),
+    }
+
+
+def pipeline(e1: dict, d) -> dict:
+    if "pipeline" not in e1:
+        return {}
+    ahead, sync = d("pipeline", "pipelined_steps"), d("pipeline", "sync_steps")
+    return {
+        "pipelined_steps": ahead, "sync_steps": sync,
+        "pipelined_share_pct": 100.0 * ahead / max(ahead + sync, 1),
+        "pipeline_drains": d("pipeline", "drains"),
+        "async_fallback": {k: d("async_fallback", k)
+                           for k in e1.get("async_fallback", {})
+                           if d("async_fallback", k)},
     }
 
 

@@ -26,8 +26,11 @@ def tiny_model():
 
 
 def _engine(params, cfg, **kw):
+    # the synchronous step unless a test asks for the pipelined one: the
+    # sync side of every oracle here is built by this default
     defaults = dict(num_pages=64, page_size=4, max_model_len=128,
-                    max_num_seqs=4, dtype=jnp.float32)
+                    max_num_seqs=4, dtype=jnp.float32,
+                    async_scheduling=False)
     defaults.update(kw)
     return LLMEngine(params, cfg, EngineConfig(**defaults))
 

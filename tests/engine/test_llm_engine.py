@@ -320,7 +320,9 @@ def test_step_metrics_and_snapshot(tiny_model):
     histograms populate from real steps, token counters add up, and the
     snapshot reports KV utilization + scheduler counters."""
     params, cfg = tiny_model
-    eng = _engine(params, cfg)
+    # the synchronous step's count of steps (the pipelined step runs one
+    # more, the overshoot; its counters: tests/engine/test_async_step.py)
+    eng = _engine(params, cfg, async_scheduling=False)
     outs = eng.generate(
         [[1, 2, 3], [4, 5, 6, 7]],
         SamplingParams(temperature=0.0, max_tokens=4, ignore_eos=True),
@@ -351,7 +353,8 @@ def test_engine_records_spans_for_traced_requests(tiny_model):
 
     params, cfg = tiny_model
     get_recorder().drain()
-    eng = _engine(params, cfg)
+    # the synchronous step's span names
+    eng = _engine(params, cfg, async_scheduling=False)
     ctx = new_trace_context("traced")
     eng.add_request([1, 2, 3],
                     SamplingParams(temperature=0.0, max_tokens=2,

@@ -110,10 +110,15 @@ def test_a_served_steps_phases_sum_to_its_host_and_device_time(
     assert set(snap["gc"]) == {"ms_total", "collections"}
 
 
-def test_one_name_two_clocks_and_the_children_of_assemble(monkeypatch):
+@pytest.mark.parametrize("async_scheduling", [False, None],
+                         ids=["sync", "default"])
+def test_one_name_two_clocks_and_the_children_of_assemble(
+        monkeypatch, async_scheduling):
     """What the helper hands the profiler, with or without a session: the
     names of before PR 36 and no other; and ``omni.assemble``, one
-    annotation there, is its three children on the engine's clock."""
+    annotation there, is its three children on the engine's clock.  The
+    pipelined step (the default) drains no tier moves, so it alone never
+    opens ``omni.kv_moves``."""
     opened: list = []
 
     class Spy:
@@ -127,7 +132,7 @@ def test_one_name_two_clocks_and_the_children_of_assemble(monkeypatch):
             opened.append((self.name, self.kwargs,
                            (time.perf_counter_ns() - self.t0) / 1e6))
 
-    engine = _engine()
+    engine = _engine(async_scheduling=async_scheduling)
     engine.generate([[1, 2, 3]], _sampled(2))  # compile outside
     first = engine.flight.total_steps
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
@@ -135,9 +140,10 @@ def test_one_name_two_clocks_and_the_children_of_assemble(monkeypatch):
     engine.generate([[1, 2, 3, 4, 5], [6, 7, 8]], _sampled())
     records = engine.flight.tail()[first:]
     names = {name for name, _, _ in opened}
-    assert names == {"omni.step", "omni.schedule", "omni.kv_moves",
-                     "omni.assemble", "omni.dispatch", "omni.readback",
-                     "omni.update", "omni.record"}
+    assert names == {"omni.step", "omni.schedule", "omni.assemble",
+                     "omni.dispatch", "omni.readback", "omni.update",
+                     "omni.record"} | (
+        {"omni.kv_moves"} if async_scheduling is False else set())
     steps = [kw["step_num"] for name, kw, _ in opened if name == "omni.step"]
     assert steps == [r["step"] for r in records]
     # the annotation's time against the clock's, summed over the steps
@@ -167,7 +173,9 @@ def test_the_loop_opens_its_four_names_and_counts_its_busy_waits(
     _ask(async_omni, [1, 2, 3], 12)
     time.sleep(0.05)  # the reply is out before its omni.emit has closed
     busy = engine.metrics_snapshot()["phases"]
-    assert set(busy["by_name"]) == PERF_MD_PHASES | set(ASSEMBLE)
+    # (all but omni.kv_moves: the pipelined step drains no tier moves)
+    assert set(busy["by_name"]) == (PERF_MD_PHASES | set(ASSEMBLE)) - {
+        "omni.kv_moves"}
     # a step that finished no request is followed by the wait
     assert 8 <= busy["busy_waits"] <= 13
     assert busy["busy_wait_ms"] >= 1.9 * busy["busy_waits"]
@@ -272,7 +280,9 @@ def test_journey_spans_are_recorded_for_traced_rows_only(monkeypatch):
             calls.append((ctx, name, start_ts, dur_s, kw))
 
     monkeypatch.setattr(llm_engine, "get_recorder", lambda: Recorder())
-    engine = _engine()
+    # the synchronous step's span names (the pipelined step's dispatch
+    # and retire: tests/engine/test_async_step.py)
+    engine = _engine(async_scheduling=False)
     sp = SamplingParams(temperature=0.0, max_tokens=3, ignore_eos=True)
     engine.generate([[1, 2, 3], [4, 5, 6, 7]], sp)
     assert calls == []  # no row carried a context: no call at all

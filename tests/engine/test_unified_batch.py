@@ -34,18 +34,26 @@ GREEDY = SamplingParams(temperature=0.0, max_tokens=10, ignore_eos=True)
 
 
 def _spy_execute(eng):
-    """Record (prefills, decodes, device dispatches) per execute call."""
+    """Record (prefills, decodes, device dispatches) per step, at the two
+    dispatch halves that the synchronous ``execute`` and the pipelined
+    step both call."""
     records = []
-    orig = eng.runner.execute
+    runner = eng.runner
 
-    def spy(sched_out, **kw):
-        d0 = eng.runner.dispatch_count
-        out = orig(sched_out, **kw)
-        records.append((len(sched_out.prefills), len(sched_out.decodes),
-                        eng.runner.dispatch_count - d0))
-        return out
+    def spy(orig, count):
+        def wrapped(*args):
+            d0 = runner.dispatch_count
+            out = orig(*args)
+            records.append(count(*args) + (runner.dispatch_count - d0,))
+            return out
+        return wrapped
 
-    eng.runner.execute = spy
+    runner._dispatch_unified = spy(
+        runner._dispatch_unified,
+        lambda decodes, prefills, prev: (len(prefills), len(decodes)))
+    runner.dispatch_decode = spy(
+        runner.dispatch_decode,
+        lambda scheds, prev=None: (0, len(scheds)))
     return records
 
 
@@ -192,7 +200,8 @@ def test_resume_chunk_past_prompt_not_mistaken_for_verify(tiny_model):
     sp = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
 
     def run(preempt: bool):
-        eng = _engine(params, cfg, **kw)
+        # the synchronous step: after three of them three tokens are out
+        eng = _engine(params, cfg, async_scheduling=False, **kw)
         outs = []
         eng.add_request(prompt, sp, request_id="a")
         steps = 0

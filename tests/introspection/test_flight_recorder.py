@@ -342,8 +342,11 @@ def test_sampler_path_counters_ride_the_record_and_the_snapshot():
 
     cfg = tfm.TransformerConfig.tiny(vocab_size=4 * CAP)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    # the synchronous step: a record carries its own step's path (the
+    # pipelined step's record carries its predecessor's, one step late)
     engine = LLMEngine(params, cfg, EngineConfig(
-        num_pages=32, page_size=4, max_model_len=64, max_num_seqs=4))
+        num_pages=32, page_size=4, max_model_len=64, max_num_seqs=4,
+        async_scheduling=False))
 
     def run(**sampling):
         engine.generate([[1, 2, 3], [4, 5, 6, 7]], SamplingParams(

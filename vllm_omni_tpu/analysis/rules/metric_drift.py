@@ -76,6 +76,7 @@ def synthetic_engine_snapshot() -> dict:
                                           "mixed": 0.4},
                      "window_steps": 128},
         "async_fallback": {"prefill": 4, "kv_transfer": 1},
+        "pipeline": {"pipelined_steps": 120, "sync_steps": 5, "drains": 3},
         "scheduler": {"waiting": 1, "running": 2, "preemptions": 1,
                       "rejections": 0},
         "kv": {"pages_total": 64, "pages_used": 8, "utilization": 0.125},

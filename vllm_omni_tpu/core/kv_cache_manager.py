@@ -238,15 +238,29 @@ class KVCacheManager:
         return len(self._state_slots)
 
     def can_allocate(self, request: Request, num_new_tokens: int) -> bool:
-        have = len(self._tables.get(request.request_id, ()))
-        need = self.pages_needed(request.num_computed_tokens + num_new_tokens)
+        grow, wgrow = self.pages_to_grow(request, num_new_tokens)
         if self.window and (
-                need - len(self._wtables.get(request.request_id, ()))
-                > len(self._wfree) + self._slidable(request)):
+                wgrow > len(self._wfree) + self._slidable(request)):
             return False
-        return need - have <= self.num_free_pages
+        return grow <= self.num_free_pages
+
+    def pages_to_grow(self, request: Request,
+                      num_new_tokens: int) -> tuple[int, int]:
+        """Pages ``allocate`` would take for the request to hold
+        ``num_new_tokens`` more, of the full kind and of the window kind
+        (what slid out of reach is not counted back: an upper bound)."""
+        need = self.pages_needed(request.num_computed_tokens + num_new_tokens)
+        rid = request.request_id
+        grow = max(need - len(self._tables.get(rid, ())), 0)
+        if not self.window:
+            return grow, 0
+        return grow, max(need - len(self._wtables.get(rid, ())), 0)
 
     # ------------------------------------------------- the window's pages
+    @property
+    def window_pages_free(self) -> int:
+        return len(self._wfree)
+
     @property
     def window_pages_in_use(self) -> int:
         return self.num_window_pages - 1 - len(self._wfree) \

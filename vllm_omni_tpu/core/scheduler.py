@@ -854,6 +854,31 @@ class ARScheduler:
             rows += self.packing.rows(1 + drafts)
         return rows
 
+    def running_rows_fit(self) -> bool:
+        """Whether the next ``schedule()`` can give every running row its
+        pages without preempting: each row's demand as the running loop
+        would ask it (a decode row's token, a verify row's candidates, a
+        chunk up to the whole budget), summed against what is free of each
+        kind of page.  The pipelined step asks before it schedules ahead
+        of a retire: a victim's in-flight token is dropped with its
+        progress, and a request that has outgrown the pool would recompute
+        for ever, each cycle's one new token being the one in flight."""
+        budget = self.config.max_num_batched_tokens
+        k = self.config.num_speculative_tokens
+        need = wneed = 0
+        for req in self.running:
+            remaining = (req.num_tokens + req.num_inflight_tokens
+                         - req.num_computed_tokens)
+            if req.num_inflight_tokens > 1 or remaining <= 0:
+                continue  # held a step, or idle
+            n_new = (min(remaining, budget) if self._needs_chunk(req)
+                     else 1 + min(len(req.spec_draft_tokens or ()), k))
+            grow, wgrow = self.kv.pages_to_grow(req, n_new)
+            need += grow
+            wneed += wgrow
+        return (need <= self.kv.num_free_pages
+                and wneed <= self.kv.window_pages_free)
+
     def _preempt(self, req: Request) -> None:
         """Preemption: free pages, reset progress, back to waiting.
         With kv_offload on, the victim's computed KV run is PARKED in

@@ -111,8 +111,11 @@ class EngineConfig:
     # host-synchronous KV movement (cross-stage transfer, tier-offload
     # drains) and streaming chunk intake run a synchronous step.
     # Greedy token streams are bit-identical to sync mode.
+    # None (nobody set it) is on for an AR engine and off for any other;
+    # False keeps the synchronous step (the oracle of
+    # tests/engine/test_async_step.py builds its sync side with it).
     # See docs/async_engine.md.
-    async_scheduling: bool = False
+    async_scheduling: Optional[bool] = None
     # tiered KV offload (docs/kv_cache.md): evicted prefix-cache pages
     # and preempted requests PARK their KV in a host-RAM pool (and
     # optionally a remote store) instead of dropping it; restores
@@ -254,6 +257,11 @@ class LLMEngine:
             # line of executables that can never dispatch
             config = dataclasses.replace(config, async_scheduling=False,
                                          unified_batching=False)
+        if config.async_scheduling is None:
+            # the two-slot step is the engine's step (a private copy, as
+            # above: the caller may build other engines from the object)
+            config = dataclasses.replace(
+                config, async_scheduling=config.worker_type == "ar")
         self._refuse_for_recurrent(model_cfg, config, draft_fn)
         self._refuse_for_window(model_cfg, config, draft_fn)
         self._refuse_for_latent(model_cfg, config, draft_fn)
@@ -497,6 +505,12 @@ class LLMEngine:
         # prefill row stops growing, which makes the unified win
         # directly visible on /metrics (async_fallback_total)
         self.async_fallback: dict[str, int] = {}
+        # how often the pipeline engages: steps by the path they took
+        # (the flight record's ``path``), and drains — an in-flight step
+        # retired with nothing dispatched behind it (a bubble, a fallback,
+        # or the queue ran dry), after which the next step overlaps nothing
+        self.steps_by_path: dict[str, int] = {"pipelined": 0, "sync": 0}
+        self.pipeline_drains = 0
         # request_id -> [first_token_ts, last_token_ts, tokens_seen]
         self._req_lat: dict[str, list] = {}
         self._trace_started: set[str] = set()
@@ -1382,6 +1396,11 @@ class LLMEngine:
                                 "window_steps": rf["window_steps"]}
         if self.config.async_scheduling:
             snap["async_fallback"] = dict(self.async_fallback)
+            snap["pipeline"] = {
+                "pipelined_steps": self.steps_by_path["pipelined"],
+                "sync_steps": self.steps_by_path["sync"],
+                "drains": self.pipeline_drains,
+            }
         # per-tenant heavy-hitter boards (metrics/attribution.py):
         # top-k per meter, inside the tenant-cardinality budget
         snap["attribution"] = self.attribution.snapshot()
@@ -1523,6 +1542,10 @@ class LLMEngine:
                 # chunk intake mutates the prompt between steps — the
                 # one remaining host-state hazard
                 return False, "streaming"
+        if not s.running_rows_fit():
+            # the coming schedule may preempt: it runs with every token
+            # retired, so that a victim loses no token it had sampled
+            return False, "kv_pressure"
         return True, None
 
     def _pipeline_eligible(self, sched_out: SchedulerOutput) -> bool:
@@ -1637,6 +1660,7 @@ class LLMEngine:
             step=self._steps_completed,
             waiting=len(self.scheduler.waiting),
             running=len(self.scheduler.running))
+        self.steps_by_path[path] += 1
         device_ms = took["device_ms"]
         host_ms = max(took["step_ms"] - device_ms, 0.0)
         self.step_metrics.on_step(
@@ -1705,6 +1729,7 @@ class LLMEngine:
         if self._inflight is None:
             return []
         inflight, self._inflight = self._inflight, None
+        self.pipeline_drains += 1
         outs, new_total = self._retire_step(inflight)
         # the drained step has no on_step of its own (the sync step that
         # follows records this call's single on_step, and its per-request

@@ -38,7 +38,9 @@ def _add_common(p: argparse.ArgumentParser):
                      default=None,
                      help="two-slot pipelined engine step: overlap host "
                           "scheduling/readback with device compute via "
-                          "device-resident sampled tokens (see "
+                          "device-resident sampled tokens.  The default "
+                          "for an AR stage since PR 38, so the flag only "
+                          "says what is already so (see "
                           "docs/async_engine.md)")
     eng.add_argument("--unified-batching", action="store_true",
                      default=None,

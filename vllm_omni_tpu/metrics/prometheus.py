@@ -229,13 +229,25 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     # async pipeline drain granularity (docs/async_engine.md): sync
     # steps per reason while async scheduling is on.  Since PR 11 only
     # host-state reasons exist (kv_transfer | kv_offload | streaming |
-    # reshaped) — the shape-based fallback matrix (spec / logprobs /
-    # collect_hidden / embeds / prefill) is deleted with the split
-    # executor and those label values can no longer be emitted
+    # kv_pressure | reshaped) — the shape-based fallback matrix (spec /
+    # logprobs / collect_hidden / embeds / prefill) is deleted with the
+    # split executor and those label values can no longer be emitted
     "async_fallback_total": (
         "counter",
         "Async pipeline steps that fell back to the synchronous path",
         ("stage", "reason")),
+    # how often the two-slot pipeline engages: rate() of this over
+    # rate(engine_steps_total) is the share of steps dispatched ahead of
+    # their predecessor's retire; each drain is a step after which the
+    # next one overlaps nothing
+    "pipelined_steps_total": (
+        "counter",
+        "Engine steps dispatched through the two-slot pipeline",
+        ("stage",)),
+    "pipeline_drains_total": (
+        "counter",
+        "In-flight steps retired with nothing dispatched behind them "
+        "(bubble, synchronous fallback, or an empty queue)", ("stage",)),
     # ---- serving-curve observability (docs/load_testing.md): SLO
     # attainment + goodput per tenant, admission-control shedding,
     # queueing, and per-phase saturation — the engine-side face of the
@@ -728,6 +740,12 @@ def render_exposition(summary: dict, engine_snaps: dict,
                 (snap.get("async_fallback") or {}).items()):
             exp.sample("async_fallback_total",
                        {**labels, "reason": reason}, count)
+        pipeline = snap.get("pipeline")
+        if pipeline:
+            exp.sample("pipelined_steps_total", labels,
+                       pipeline.get("pipelined_steps", 0))
+            exp.sample("pipeline_drains_total", labels,
+                       pipeline.get("drains", 0))
         # serving-curve observability: queue depth + shed ledger + SLO
         # attainment/goodput per tenant + queue-wait + saturation
         queue = snap.get("queue")

@@ -87,6 +87,22 @@ logger = init_logger(__name__)
 LOGPROBS_K = 20
 
 
+@jax.jit
+def _take_rows(tokens: jax.Array, cols: jax.Array) -> jax.Array:
+    """Rows ``cols[1]`` ([S]) of an in-flight step's tokens ([b]): one
+    program a batch bucket, however many rows are fed."""
+    return tokens[cols[1]]
+
+
+@jax.jit
+def _put_rows(token_ids: jax.Array, cols: jax.Array,
+              picked: jax.Array) -> jax.Array:
+    """``token_ids`` ([n]) with ``picked`` ([S]) written at ``cols[0]``
+    ([S]); an entry of ``n`` there is past the end and dropped: one
+    program a bucket of ``n``."""
+    return token_ids.at[cols[0]].set(picked, mode="drop")
+
+
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
     for b in buckets:
         if n <= b:
@@ -289,7 +305,12 @@ class ARModelRunner:
         self.async_scheduling = bool(async_scheduling)
         self.deterministic_decode = bool(deterministic_decode)
         self.mesh = mesh
+        # where a step's token ids live under a mesh (``_feed_tokens``)
+        self._replicated = None
         if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._replicated = NamedSharding(mesh, PartitionSpec())
             # Megatron-style TP inside shard_map: heads and MLP columns
             # divide across the tp axis; the per-layer code runs on LOCAL
             # shapes and cfg.tp_axis inserts the psum/all_gather
@@ -839,7 +860,11 @@ class ARModelRunner:
           shape per bucket; the candidate width V = 1 + draft k is
           fixed per runner — install the draft head first), and
         - the decode batch buckets × {plain, logprobs} of the dedicated
-          pure-decode step.
+          pure-decode step, and
+        - beside each of either, the two small programs by which a
+          pipelined step takes its in-flight input tokens from the step
+          before it (``_feed_tokens``): one a shape of tokens left, one
+          a shape of tokens fed.
 
         ``prefill_shapes`` is accepted for API compatibility; every
         packed size a prefill can produce already lands on a token
@@ -870,6 +895,13 @@ class ARModelRunner:
         def pos_shape(b):
             return (b, 3) if self.use_mrope else (b,)
 
+        def warm_feed(n, tokens):
+            # the pipelined step's feed (``_feed_tokens``): a step of ``n``
+            # token rows fed from a step that left ``tokens``, as placed
+            # and shaped as the executable just warmed leaves them
+            self._feed_tokens(np.zeros((n,), np.int32), [(0, 0)],
+                              InflightDecode(tokens=tokens, rows={}))
+
         if decode and self.draft_fn is None:
             # deterministic decode runs every step at the top bucket —
             # the smaller executables can never be dispatched.  A
@@ -882,14 +914,15 @@ class ARModelRunner:
                               else self._batch_buckets)
             for b in decode_buckets:
                 tables = jnp.zeros((b, self.max_pages_per_seq), jnp.int32)
-                zeros_b = jnp.zeros((b,), jnp.int32)
+                zeros_b = self._feed_tokens(np.zeros((b,), np.int32), [],
+                                            None)
                 t = SamplingTensors.build(
                     [_PAD_SAMPLING] * b, step=0,
                     base_seed=self._base_seed)
                 for kind, fn in (("dispatch", self._decode_sample_fn),
                                  ("dispatch_lp", self._decode_lp_fn)):
                     note(f"precompile {kind} b={b}")
-                    _, self.kv_caches = warm(
+                    outs, self.kv_caches = warm(
                         kind, (b, self._kv_quant), lambda fn=fn: fn(
                             self.params, zeros_b, self.kv_caches,
                             jnp.zeros(pos_shape(b), jnp.int32),
@@ -897,6 +930,7 @@ class ARModelRunner:
                             jnp.ones((b,), jnp.int32),
                             t.temperature, t.top_k, t.top_p, t.keys,
                             *map(jnp.asarray, self._slot_column((), b))))
+                warm_feed(b, outs["tokens"])
         # ONE executable per token bucket — the 1-D shape-cache line
         # that replaces the (batch, seq) grid
         s_max = self._batch_buckets[-1]
@@ -914,10 +948,12 @@ class ARModelRunner:
             note(f"precompile unified t={t_pad} v={v}")
             pos = (jnp.zeros((3, t_pad), jnp.int32) if self.use_mrope
                    else jnp.zeros((t_pad,), jnp.int32))
-            _, self.kv_caches = warm(
+            outs, self.kv_caches = warm(
                 "unified", (t_pad, v, False, False, self._kv_quant),
                 lambda: self._unified_fn(
-                    self.params, jnp.zeros((t_pad,), jnp.int32),
+                    self.params,
+                    self._feed_tokens(np.zeros((t_pad,), np.int32), [],
+                                      None),
                     self.kv_caches, pos,
                     jnp.full((t_pad,), -1, jnp.int32),
                     jnp.zeros((s_max, self.max_pages_per_seq),
@@ -932,6 +968,7 @@ class ARModelRunner:
                     t.temperature, t.top_k, t.top_p, t.keys,
                     *map(jnp.asarray, self._slot_column(
                         (), s_max, t_pad=t_pad))))
+            warm_feed(t_pad, outs["last_tok"])
         return built
 
     # ---------------------------------------------------------------- step
@@ -1181,16 +1218,14 @@ class ARModelRunner:
         with phase("omni.assemble"):
             with phase("omni.assemble.pack", trace=False):
                 asm = self._assemble_unified(scheds, spec_rows)
-                feed_dst: list[int] = []
-                feed_src: list[int] = []
-                for i, sc in enumerate(scheds):
-                    if sc.start_pos >= sc.request.num_tokens and (
-                            prev is not None
-                            and sc.request.request_id in prev.rows):
-                        # input token sampled by the previous dispatch,
-                        # still device-resident
-                        feed_dst.append(int(asm.cu_q_lens[i]))
-                        feed_src.append(prev.rows[sc.request.request_id])
+                # (packed row, row of ``prev``) of every input token the
+                # previous dispatch sampled, still device-resident
+                feed = [(int(asm.cu_q_lens[i]),
+                         prev.rows[sc.request.request_id])
+                        for i, sc in enumerate(scheds)
+                        if sc.start_pos >= sc.request.num_tokens
+                        and prev is not None
+                        and sc.request.request_id in prev.rows]
                 # the slot column of a model with recurrent layers
                 slot_cols = self._slot_column(
                     scheds, self._batch_buckets[-1], asm.cu_q_lens,
@@ -1205,10 +1240,7 @@ class ARModelRunner:
                 tensors, final = self._unified_sampling(scheds, "unified",
                                                         asm.t_pad)
             with phase("omni.assemble.put", trace=False):
-                token_ids = jnp.asarray(asm.token_ids)
-                if feed_dst:
-                    token_ids = token_ids.at[jnp.asarray(feed_dst)].set(
-                        prev.tokens[jnp.asarray(feed_src)])
+                token_ids = self._feed_tokens(asm.token_ids, feed, prev)
                 kwargs = {}
                 if asm.embeds is not None:
                     kwargs["inputs_embeds"] = jnp.asarray(
@@ -1264,8 +1296,7 @@ class ARModelRunner:
             with phase("omni.assemble.pack", trace=False):
                 b = self._decode_bucket(len(scheds))
                 token_host = np.zeros((b,), np.int32)
-                feed_rows: list[int] = []
-                feed_src: list[int] = []
+                feed: list[tuple[int, int]] = []
                 params_list = [_PAD_SAMPLING] * b
                 salts = [0] * b
                 want_lp = False
@@ -1276,8 +1307,7 @@ class ARModelRunner:
                     else:
                         # input token still in flight from the previous
                         # dispatch
-                        feed_rows.append(i)
-                        feed_src.append(prev.rows[req.request_id])
+                        feed.append((i, prev.rows[req.request_id]))
                     params_list[i] = req.sampling_params
                     salts[i] = self._salt_of(req.request_id)
                     if req.sampling_params.logprobs is not None:
@@ -1295,10 +1325,7 @@ class ARModelRunner:
                         sc.request.sampling_params) for sc in scheds)
                 tensors = self._sampling_tensors(key, params_list, salts)
             with phase("omni.assemble.put", trace=False):
-                token_ids = jnp.asarray(token_host)
-                if feed_rows:
-                    token_ids = token_ids.at[jnp.asarray(feed_rows)].set(
-                        prev.tokens[jnp.asarray(feed_src)])
+                token_ids = self._feed_tokens(token_host, feed, prev)
                 args = (
                     jnp.asarray(positions), jnp.asarray(slots),
                     jnp.asarray(tables), jnp.asarray(ctx),
@@ -1313,6 +1340,32 @@ class ARModelRunner:
             outs=outs, kind="decode", scheds=list(scheds),
             gens=[s.request.async_generation for s in scheds],
         )
+
+    def _feed_tokens(self, token_host: np.ndarray,
+                     feed: list[tuple[int, int]],
+                     prev: Optional[InflightDecode]) -> jax.Array:
+        """A step's token ids on the device.  ``feed`` pairs a row of
+        ``token_host`` with the row of ``prev.tokens`` that holds its
+        input token (sampled by the dispatch still in flight, never seen
+        by the host).  The pairs ride two columns (row fed, row taken) as
+        long as the top batch bucket, so the two programs' shapes follow the buckets of
+        ``prev.tokens`` and of ``token_host`` and never the number of rows
+        fed; ``precompile`` warms both lines.  Under a mesh the ids are
+        placed replicated whether fed or not (a fed array comes back
+        committed to the mesh, and an executable warmed on an uncommitted
+        one would compile again for it)."""
+        rep = self._replicated
+        token_ids = (jnp.asarray(token_host) if rep is None
+                     else jax.device_put(token_host, rep))
+        if not feed:
+            return token_ids
+        cols = np.empty((2, self._batch_buckets[-1]), np.int32)
+        cols[0], cols[1] = token_host.shape[0], 0
+        cols[:, : len(feed)] = np.asarray(feed, np.int32).T
+        cols = jnp.asarray(cols)  # one put for both columns
+        token_ids = _put_rows(token_ids, cols,
+                              _take_rows(prev.tokens, cols))
+        return token_ids if rep is None else jax.device_put(token_ids, rep)
 
     # ------------------------------------------------------------- retire
     def retire_step(self, handle: InflightDecode
