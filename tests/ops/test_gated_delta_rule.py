@@ -121,3 +121,40 @@ def test_convolution_carries_its_state_across_steps_and_rows():
         # the state is the last K - 1 inputs, oldest first
         np.testing.assert_array_equal(new_state[i],
                                       xs[i][done[i] - 2:done[i] + 1])
+
+
+def test_the_gathered_history_is_the_scattered_one_and_stays_in_range():
+    """``carried="gather"`` (the gated short convolution's form: PERF.md,
+    PR 39) gives the rows and the state that the scatter-add gives, on
+    sequences shorter than the kernel, empty slots and a packed tail of
+    padding, and every index it forms lies inside its array (the scatter's
+    sentinel row does not: ``checkify`` reads it as out of bounds)."""
+    from jax.experimental import checkify
+
+    c, k = 16, 3
+    step = (1, 5, 1, 2, 0, 0)
+    cu = _pack(step)
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(keys[0], (cu[-1] + 8, c))
+    w = jax.random.normal(keys[1], (k, c))
+    state = jax.random.normal(keys[2], (len(step), k - 1, c))
+    args = (x, w, state, jnp.asarray(cu, jnp.int32),
+            jnp.asarray(step, jnp.int32))
+    want_y, want_state = gdr.causal_conv_ragged(*args)
+    err, (y, new_state) = checkify.checkify(
+        lambda *a: gdr.causal_conv_ragged(*a, carried="gather"),
+        errors=checkify.index_checks)(*args)
+    assert err.get() is None, err.get()
+    real = np.zeros(x.shape[0], bool)
+    for start, n in zip(cu, step):
+        real[start:start + n] = True
+    np.testing.assert_allclose(y[real], want_y[real], atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(new_state, want_state)
+    assert bool(jnp.isfinite(y).all())
+    # a one-token sequence: the taps on (carried, carried, token)
+    np.testing.assert_allclose(
+        y[cu[0]], state[0, 0] * w[0] + state[0, 1] * w[1] + x[cu[0]] * w[2],
+        atol=1e-5, rtol=1e-5)
+    err, _ = checkify.checkify(gdr.causal_conv_ragged,
+                               errors=checkify.index_checks)(*args)
+    assert "out-of-bounds" in str(err.get())

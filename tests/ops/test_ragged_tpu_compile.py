@@ -140,6 +140,44 @@ def test_decode_entry_compiles_for_v5e(name, one_chip, monkeypatch):
     assert not moved, moved
 
 
+@pytest.mark.parametrize("entry", ["mixed", "decode"])
+def test_heads_of_64_compile_for_v5e(entry, one_chip, monkeypatch):
+    """LFM2-8B-A1B's attention through both public entries, auto dispatch
+    on a platform that compiles the kernels: 32 query and 8 kv heads of 64
+    over pools of two heads a row ([4, pages, 16, 128]), a 4 096-row mixed
+    step and the 256-row decode batch.  The kernel it lowers to is the
+    thinker's (a head of 128, a group of 8); nothing of the pool's size is
+    moved to widen the queries or to keep the outputs' own half."""
+    import re
+
+    from vllm_omni_tpu.ops import _dispatch
+    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    pool, max_pages, seqs = 32768, 400, 256
+
+    def sds(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cache = sds((4, pool, 16, 128), jnp.bfloat16)
+    if entry == "decode":
+        text = jax.jit(rpa.paged_attention).lower(
+            sds((seqs, 32, 64), jnp.bfloat16), cache, cache,
+            sds((seqs, max_pages)), sds((seqs,))).compile().as_text()
+    else:
+        text = jax.jit(rpa.ragged_paged_attention).lower(
+            sds((4096, 32, 64), jnp.bfloat16), cache, cache,
+            sds((seqs, max_pages)), sds((seqs + 1,)), sds((seqs,)),
+            sds((seqs,)), sds((1,))).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "_ragged_attention" in text
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|transpose|convert|gather)\(", text)
+        if np.prod([int(x) for x in m.group(1).split(",")])
+        >= 4 * pool * 16 * 128]
+    assert not moved, moved
+    assert not _dispatch.native_fallbacks
+
+
 def test_decode_step_holds_one_attention_call_a_layer(one_chip, monkeypatch):
     """A whole pure-decode step of the thinker at its published widths
     (two layers, 64 rows, the cell's pools donated) compiled for a v5e:

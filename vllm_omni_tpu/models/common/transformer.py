@@ -34,6 +34,10 @@ from vllm_omni_tpu.ops import (
 )
 from vllm_omni_tpu.ops import gated_delta_rule as gdr
 from vllm_omni_tpu.ops import indexer as indexer_ops
+from vllm_omni_tpu.ops.paged_attention import heads_a_row
+
+#: the kinds of layer whose sequences hold a state slot
+STATE_KINDS = ("linear_attention", "conv")
 
 
 @dataclass(frozen=True)
@@ -179,9 +183,17 @@ class TransformerConfig:
     # 0.03-0.13 (PERF.md, PR 28)
     residual_in_fp32: bool = False
     # ---- layers of several kinds, as data: one of "full_attention",
-    # "sliding_attention", "linear_attention" a layer (the published
-    # ``layer_types``); None => derived from ``full_attention_interval``
+    # "sliding_attention", "linear_attention", "conv" a layer (the
+    # published ``layer_types``); None => derived from
+    # ``full_attention_interval``
     layer_kinds: Optional[tuple[str, ...]] = None
+    # a "conv" layer is a gated short convolution (LFM2): ``[B | C | u] =
+    # x W_in``, a depth-wise causal convolution of ``shortconv_kernel``
+    # taps over ``B * u`` (no bias, no activation), gated by ``C``, then
+    # ``W_out``.  What a sequence carries through it is the last
+    # ``shortconv_kernel - 1`` rows of ``B * u`` and nothing else: a state
+    # slot of one array (docs/kv_cache.md, "Three kinds of state")
+    shortconv_kernel: int = 3
     # a "sliding_attention" layer is latent attention with widths of its
     # own (``sliding``) over the last ``sliding_window`` keys, the token
     # itself among them; its rows live in pages of a second kind that are
@@ -223,8 +235,19 @@ class TransformerConfig:
 
     @property
     def num_state_layers(self) -> int:
-        """Layers that hold a per-sequence recurrent state."""
-        return self.layer_types.count("linear_attention")
+        """Layers that hold a per-sequence state in a slot, not pages."""
+        return sum(kind in STATE_KINDS for kind in self.layer_types)
+
+    def state_shapes(self, kind: str) -> tuple[tuple[int, ...], ...]:
+        """What one sequence carries through a layer of ``kind``, an array
+        a shape: a Gated DeltaNet layer the convolution's last inputs and
+        the rule's matrix a value head, a gated short convolution its last
+        inputs alone (layouts: ops/gated_delta_rule.py)."""
+        if kind == "conv":
+            return ((self.shortconv_kernel - 1, self.hidden_size),)
+        return ((self.linear_conv_kernel_dim - 1, self.linear_conv_dim),
+                (self.linear_num_value_heads, self.linear_key_head_dim,
+                 self.linear_value_head_dim))
 
     def latent_kind(self, kind: str = "full_attention") -> LatentKind:
         """The latent widths of a kind of layer."""
@@ -247,8 +270,12 @@ class TransformerConfig:
         if self.kv_lora_rank:
             row = self.latent_kind(kind).row
             return KVLayout("latent", 1, -(-row // 128) * 128, 1, row)
-        return KVLayout("kv", self.num_kv_heads, self.head_dim, 2,
-                        self.head_dim)
+        # heads narrower than a 128-lane row lie side by side in one
+        # (ops/paged_attention.py heads_a_row): 8 kv heads of 64 are 4
+        # rows of 128, no lane of which is padding
+        pack = heads_a_row(self.head_dim, self.num_kv_heads)
+        return KVLayout("kv", self.num_kv_heads // pack,
+                        self.head_dim * pack, 2, self.head_dim * pack)
 
     @property
     def kv_layout(self) -> "KVLayout":
@@ -318,7 +345,8 @@ class KVLayout(NamedTuple):
     arrays of ``[heads, pages, page_size, width]``."""
 
     kind: str    # "kv": a K and a V pool of per-head rows | "latent"
-    heads: int   # kv heads a pool holds
+    heads: int   # rows a token has in a pool: its kv heads, or at a head
+    #              of 64 half as many (two heads to a 128-lane row)
     width: int   # lanes a row takes, declared padding included
     pools: int   # 2 (K, V) | 1 (the row is key and value at once)
     used: int    # lanes of a row that hold numbers (width - padding)
@@ -357,6 +385,8 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
         }
         if kind == "linear_attention":
             layer["linear_attn"] = _gdn_init(k, cfg, dtype)
+        elif kind == "conv":
+            layer["shortconv"] = _shortconv_init(k, cfg, dtype)
         elif cfg.kv_lora_rank:
             lk = cfg.latent_kind(kind)
             layer["mla"] = _mla_init(k, cfg, lk, dtype)
@@ -520,6 +550,25 @@ def _gdn_init(k, cfg: TransformerConfig, dtype):
         "norm": nn.rmsnorm_init(cfg.linear_value_head_dim, dtype),
         "out_proj": nn.linear_init(k[3], v_dim, cfg.hidden_size, bias=False,
                                    dtype=dtype),
+    }
+
+
+def _shortconv_init(k, cfg: TransformerConfig, dtype):
+    """One gated short convolution's weights, under the checkpoint's names
+    (``conv.in_proj``, ``conv.conv``, ``conv.out_proj``): ``in_proj``'s
+    columns are [B | C | u]; the taps lie [K, hidden] with ``w[K - 1]`` on
+    the current token, as ``ops/gated_delta_rule.py`` takes them (the
+    checkpoint keeps [hidden, 1, K]: a transpose in a loader)."""
+    kc = cfg.shortconv_kernel
+    return {
+        "in_proj": nn.linear_init(k[0], cfg.hidden_size,
+                                  3 * cfg.hidden_size, bias=False,
+                                  dtype=dtype),
+        "conv": {"w": jax.random.uniform(
+            k[5], (kc, cfg.hidden_size), dtype,
+            minval=-kc ** -0.5, maxval=kc ** -0.5)},
+        "out_proj": nn.linear_init(k[3], cfg.hidden_size, cfg.hidden_size,
+                                   bias=False, dtype=dtype),
     }
 
 
@@ -773,6 +822,26 @@ def _gdn_mixer(la, cfg: TransformerConfig, h, recur):
         return _out_proj(cfg, o.reshape(t, -1), la["out_proj"]["w"])
 
 
+def _shortconv_mixer(sc, cfg: TransformerConfig, h, recur):
+    """The gated short convolution on normed rows h [T, hidden]: ``[B | C
+    | u] = h W_in``; ``recur(B * u, taps)`` is the depth-wise causal
+    convolution over each sequence's rows (float32, no activation: the
+    silu after Gated DeltaNet's convolution is that path's own), which
+    supplies what differs between the forwards (where the sequence's last
+    inputs come from and go to); ``C`` gates it; then the out projection.
+    The products ``B * u`` are exact in float32 (two bfloat16 factors),
+    and that is what the state keeps."""
+    with jax.named_scope("omni.proj"):
+        bcu = nn.linear(sc["in_proj"], h)
+    with jax.named_scope("omni.shortconv"):
+        n = cfg.hidden_size
+        gate_b, gate_c, u = (bcu[:, i * n:(i + 1) * n].astype(jnp.float32)
+                             for i in range(3))
+        y = (gate_c * recur(gate_b * u, sc["conv"]["w"])).astype(h.dtype)
+    with jax.named_scope("omni.proj"):
+        return _out_proj(cfg, y, sc["out_proj"]["w"])
+
+
 def _join_lanes(parts, width: int):
     """``parts`` side by side on the last axis, zeros after them up to
     ``width``.  A leading part that is whole 128-lane tiles is joined as
@@ -912,7 +981,8 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     in the layer's weights) the same ``attend`` gets the absorbed query,
     the latent row as the key and no value (``_mla_mixer``); in a Gated
     DeltaNet layer (``"linear_attn"``) ``attend`` is the ``recur`` of
-    ``_gdn_mixer``.  The MLP is the sparse one where the layer holds
+    ``_gdn_mixer``, in a gated short convolution (``"shortconv"``) that of
+    ``_shortconv_mixer``.  The MLP is the sparse one where the layer holds
     ``experts``, a dense SwiGLU where it does not.  One body serves every
     forward so the variants
     cannot silently diverge."""
@@ -925,6 +995,10 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     if "linear_attn" in layer:
         o = _gdn_mixer(layer["linear_attn"], cfg,
                        h.reshape(-1, h.shape[-1]), attend).reshape(*b, -1)
+    elif "shortconv" in layer:
+        o = _shortconv_mixer(layer["shortconv"], cfg,
+                             h.reshape(-1, h.shape[-1]), attend).reshape(
+                                 *b, -1)
     elif "mla" in layer:
         o = _mla_mixer(layer, cfg, cfg.latent_kind(kind),
                        h.reshape(-1, h.shape[-1]), cos, sin,
@@ -956,55 +1030,68 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
 
 
 class HybridCaches(NamedTuple):
-    """The cache operand of a model with recurrent layers, where other
-    models pass their list of pages: two kinds of state in one pytree, so
-    that a step donates and rebinds both together (docs/kv_cache.md)."""
+    """The cache operand of a model with layers that carry a state, where
+    other models pass their list of pages: two kinds of cache in one
+    pytree, so that a step donates and rebinds both together
+    (docs/kv_cache.md)."""
 
     kv: list     # per softmax-attention layer: (k, v) pages
-    state: list  # per Gated DeltaNet layer: (conv, recurrent) state slots
+    # per layer that carries a state, its slots, a tuple of arrays
+    # ``[slots, ...]`` (``TransformerConfig.state_shapes``): (conv,
+    # recurrent) of a Gated DeltaNet layer, (taps,) of a short convolution
+    state: list
+
+
+#: the scope a kind of state's gathers and scatters run under, and the key
+#: of the layer's weights that says the layer is of that kind
+_STATE_SCOPE = {"linear_attn": "omni.gdn_state",
+                "shortconv": "omni.conv_state"}
+
+
+def _state_key(layer) -> Optional[str]:
+    return next((k for k in _STATE_SCOPE if k in layer), None)
 
 
 def _layer_caches(params, kv_caches, state_caches):
     """(layer, its cache) down the stack: the (k, v) pages of a
     softmax-attention layer ((latent pool, None) of a latent-attention
     layer: the write and the kernels take the absent value as "read it
-    from the key's row"), the (conv, recurrent) state slots of a Gated
-    DeltaNet layer.  ``kv_caches`` holds one entry for each attention
-    layer only, ``state_caches`` one for each GDN layer."""
+    from the key's row"), the state slots of a layer that carries one.
+    ``kv_caches`` holds one entry for each attention layer only,
+    ``state_caches`` one for each of the others."""
     kv, st = iter(kv_caches), iter(state_caches or ())
     for layer in params["layers"]:
-        yield layer, next(st if "linear_attn" in layer else kv)
+        yield layer, next(st if _state_key(layer) else kv)
 
 
-def _read_state(cache, slots, fresh):
-    """The rows' carried state out of their slots ([rows, ...]); zeros for
-    a row that starts its sequence (``fresh``), which is how a slot is
-    zeroed when it is handed out.  Padding rows carry a slot past the end:
-    they read a clipped row here and their write is dropped."""
-    with jax.named_scope("omni.gdn_state"):
-        conv = cache[0].at[slots].get(mode="clip")
-        rec = cache[1].at[slots].get(mode="clip")
-        return (jnp.where(fresh[:, None, None], 0, conv),
-                jnp.where(fresh[:, None, None, None], 0, rec))
+def _read_state(cache, slots, fresh, scope):
+    """The rows' carried state out of their slots ([rows, ...] an array of
+    the layer's state); zeros for a row that starts its sequence
+    (``fresh``), which is how a slot is zeroed when it is handed out.
+    Padding rows carry a slot past the end: they read a clipped row here
+    and their write is dropped."""
+    with jax.named_scope(scope):
+        rows = [a.at[slots].get(mode="clip") for a in cache]
+        return tuple(
+            jnp.where(fresh[(slice(None),) + (None,) * (a.ndim - 1)], 0, a)
+            for a in rows)
 
 
-def _write_state(cache, slots, conv, rec):
-    with jax.named_scope("omni.gdn_state"):
-        return (cache[0].at[slots].set(conv.astype(cache[0].dtype),
-                                       mode="drop"),
-                cache[1].at[slots].set(rec.astype(cache[1].dtype),
-                                       mode="drop"))
+def _write_state(cache, slots, state, scope):
+    with jax.named_scope(scope):
+        return tuple(a.at[slots].set(new.astype(a.dtype), mode="drop")
+                     for a, new in zip(cache, state))
 
 
-def _stateful_recur(cache, slots, fresh, new_states, rule):
-    """The ``recur`` of ``_gdn_mixer`` for a forward that carries state:
-    the rows' slots are read (``omni.gdn_state``), ``rule(mixed, g, beta,
-    conv_w, conv, rec)`` runs the chunked or the one-token form, and the
-    slots are written back into ``new_states``."""
-    def recur(mixed, g, beta, conv_w):
-        conv, rec = _read_state(cache, slots, fresh)
-        o, conv, rec = rule(mixed, g, beta, conv_w, conv, rec)
-        new_states.append(_write_state(cache, slots, conv, rec))
+def _stateful_recur(cache, slots, fresh, new_states, rule, scope):
+    """The ``recur`` of ``_gdn_mixer`` or ``_shortconv_mixer`` for a
+    forward that carries state: the rows' slots are read (under
+    ``scope``), ``rule(*the mixer's operands, *the state's arrays)`` runs
+    the ragged or the one-token form and returns the output and the new
+    state, and the slots are written back into ``new_states``."""
+    def recur(*operands):
+        o, *state = rule(*operands, *_read_state(cache, slots, fresh, scope))
+        new_states.append(_write_state(cache, slots, state, scope))
         return o
 
     return recur
@@ -1085,13 +1172,21 @@ def forward_hidden(
             jnp.arange(b + 1, dtype=jnp.int32) * s,
             jnp.full((b,), s, jnp.int32), cfg.gdn_heads)[0]
 
+    def conv_recur(v, conv_w):
+        return gdr.causal_conv_ragged(
+            v, conv_w,
+            jnp.zeros((b, conv_w.shape[0] - 1, v.shape[-1]), v.dtype),
+            jnp.arange(b + 1, dtype=jnp.int32) * s,
+            jnp.full((b,), s, jnp.int32), carried="gather")[0]
+
+    mixes = {"linear_attn": recur, "shortconv": conv_recur}
     layers = params["layers"]
     if drop_last_layers:
         layers = layers[:len(layers) - drop_last_layers]
     collected = {0: x} if 0 in collect_hidden_layers else {}
     for li, layer in enumerate(layers):
         x = _layer_step(layer, cfg, x, cos, sin,
-                        recur if "linear_attn" in layer else attend)
+                        mixes.get(_state_key(layer), attend))
         if li + 1 in collect_hidden_layers:
             collected[li + 1] = x
     if collect_hidden_layers:
@@ -1192,12 +1287,13 @@ def forward_unified(
     embeds/deepstack batches ride the unified dispatch instead of a
     separately padded executable.
 
-    A model with Gated DeltaNet layers passes ``HybridCaches`` as
-    ``kv_caches`` (beside the pages, for each such layer the convolution's
-    and the rule's state, one slot a sequence) and ``state_slots``: a
+    A model with layers that carry a state passes ``HybridCaches`` as
+    ``kv_caches`` (beside the pages, for each Gated DeltaNet layer the
+    convolution's and the rule's state, for each gated short convolution
+    its last inputs, one slot a sequence) and ``state_slots``: a
     sequence's rows read its slot at the chunk's start (zeros where the
     chunk starts the sequence) and write it at the chunk's end, through
-    the chunked form of the rule.
+    the ragged form of the layer's rule.
 
     A model with sliding-window layers passes ``window_pages``: the slot
     mapping and the page tables of the window kind of page, which its
@@ -1223,6 +1319,11 @@ def forward_unified(
         return indexer_ops.indexer_scores(
             q, w, pool, tables, cu_q_lens, q_lens, seq_lens, num_seqs)
 
+    ragged_rules = {
+        "linear_attn": lambda *a: gdr.gated_delta_net_ragged(
+            *a, cu_q_lens, q_lens, cfg.gdn_heads),
+        "shortconv": lambda *a: gdr.causal_conv_ragged(
+            *a, cu_q_lens, q_lens, carried="gather")}
     for i, (layer, cache) in enumerate(
             _layer_caches(params, kv_caches, state_caches)):
         kind = cfg.layer_types[i]
@@ -1232,13 +1333,14 @@ def forward_unified(
               else (slot_mapping, page_tables)), attention, scores)
 
         # a sequence starts where its context is this step's tokens alone
-        recur = _stateful_recur(
-            cache, state_slots, seq_lens == q_lens, new_states,
-            lambda *a: gdr.gated_delta_net_ragged(
-                *a, cu_q_lens, q_lens, cfg.gdn_heads))
-
-        x = _layer_step(layer, cfg, x, *ropes[kind],
-                        recur if "linear_attn" in layer else attend, kind)
+        # (a layer at a time, as the jaxpr has had it: the steps' digests
+        # in tests/ops/test_ragged_tpu_compile.py hold the order)
+        fresh = seq_lens == q_lens
+        state = _state_key(layer)
+        if state:
+            attend = _stateful_recur(cache, state_slots, fresh, new_states,
+                                     ragged_rules[state], _STATE_SCOPE[state])
+        x = _layer_step(layer, cfg, x, *ropes[kind], attend, kind)
         if deepstack is not None and i < deepstack.shape[0]:
             with jax.named_scope("omni.embed"):
                 x = x + deepstack[i].astype(x.dtype)
@@ -1260,9 +1362,9 @@ def forward_decode(
     window_pages: Optional[tuple] = None,     # (slots [B], tables [B, n])
 ):
     """One decode step over a batch of sequences with paged attention;
-    the Gated DeltaNet layers of a hybrid model update each row's state
-    slot through the one-token form of the rule (``HybridCaches`` and
-    ``state_slots`` as in ``forward_unified``).
+    the Gated DeltaNet and short-convolution layers of a hybrid model
+    update each row's state slot through the one-token form of their rule
+    (``HybridCaches`` and ``state_slots`` as in ``forward_unified``).
 
     Returns (hidden [B, hidden], new kv_caches of the type passed).
     """
@@ -1280,6 +1382,10 @@ def forward_decode(
         return indexer_ops.indexer_scores_decode(q, w, pool, tables,
                                                  context_lens)
 
+    decode_rules = {
+        "linear_attn": lambda *a: gdr.gated_delta_net_decode(
+            *a, cfg.gdn_heads),
+        "shortconv": gdr.causal_conv_update}
     for i, (layer, cache) in enumerate(
             _layer_caches(params, kv_caches, state_caches)):
         kind = cfg.layer_types[i]
@@ -1288,12 +1394,12 @@ def forward_decode(
             *(window_pages if kind == "sliding_attention"
               else (slot_mapping, block_tables)), attention, scores)
 
-        recur = _stateful_recur(
-            cache, state_slots, context_lens <= 1, new_states,
-            lambda *a: gdr.gated_delta_net_decode(*a, cfg.gdn_heads))
-
-        x = _layer_step(layer, cfg, x, *ropes[kind],
-                        recur if "linear_attn" in layer else attend, kind)
+        fresh = context_lens <= 1
+        state = _state_key(layer)
+        if state:
+            attend = _stateful_recur(cache, state_slots, fresh, new_states,
+                                     decode_rules[state], _STATE_SCOPE[state])
+        x = _layer_step(layer, cfg, x, *ropes[kind], attend, kind)
     if hybrid:
         new_caches = HybridCaches(new_caches, new_states)
     return _final_norm(params, cfg, x), new_caches

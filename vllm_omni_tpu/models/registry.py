@@ -179,6 +179,12 @@ _AR_MODELS: dict[str, _Entry] = {
     "Dots3NoteForCausalLM": _Entry(
         "vllm_omni_tpu.models.dots3_note", "real_factory"
     ),
+    # gated short convolutions (a state slot of the taps alone) beside
+    # grouped-query attention of 64-wide heads, two to a row of the pool
+    # (LFM2-8B-A1B; docs/kv_cache.md)
+    "Lfm2MoeForCausalLM": _Entry(
+        "vllm_omni_tpu.models.lfm2_moe", "real_factory"
+    ),
 }
 
 

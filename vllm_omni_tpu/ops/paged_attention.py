@@ -437,8 +437,8 @@ def write_tiling_reason(cache, tokens: int = 1, pools: int = 2) -> str:
     overrun its VMEM; a step whose plans overrun SMEM."""
     hkv, _, ps, d = cache.shape
     rows = 32 // cache.dtype.itemsize
-    if paged_tiling_reason(d, ps):
-        return paged_tiling_reason(d, ps)
+    if row_tiling_reason(d, ps):
+        return row_tiling_reason(d, ps)
     if ps % rows:
         return f"page_size {ps} % {rows} != 0 ({cache.dtype.name})"
     if _write_block(cache, pools) < 16:
@@ -481,10 +481,16 @@ def write_kv_cache(
     static under jit, so both layouts share one entry point.
     """
     if isinstance(k_cache, tuple):  # omnilint: disable=OL1 - pytree STRUCTURE branch (tuple vs array), static at trace time: jit specializes per layout by design
-        return (_write_kv_quant(k_cache, k_new, slot_mapping),
-                _write_kv_quant(v_cache, v_new, slot_mapping))
+        return (_write_kv_quant(k_cache, paired_rows(k_new, k_cache),
+                                slot_mapping),
+                _write_kv_quant(v_cache, paired_rows(v_new, v_cache),
+                                slot_mapping))
     # the shared (latent) layout is the same write over one pool
     shared = v_cache is None
+    # heads of 64 lie two to a row: a reshape of the step's rows
+    k_new = paired_rows(k_new, k_cache)
+    if not shared:
+        v_new = paired_rows(v_new, v_cache)
     tokens, hkv, _ = k_new.shape
     reason = write_tiling_reason(k_cache, tokens, 1 if shared else 2)
     if tokens * hkv > _SCATTER_MAX_ROWS and auto_use_pallas(
@@ -541,21 +547,99 @@ def paged_attention_ref(
     return o.reshape(b, h, vg.shape[-1]).astype(q.dtype)
 
 
-def paged_tiling_reason(head_dim: int, page_size: int) -> str:
-    """Why Mosaic cannot tile the paged kernels' (page_size, head_dim)
-    page buffers at this geometry ("" when it can): the lane dim (a
-    head's width, or the whole row of a shared pool: 128, 256 and 640 are
-    served) must be a multiple of 128 and the sublane dim of 8, in either
+#: the lanes of a row of a pool, and of a vector register
+LANES = 128
+
+
+def heads_a_row(head_dim: int, num_kv_heads: int = 2) -> int:
+    """kv heads that share a row of the pool: 2 at a head of 64 (an even
+    count of them), else 1.  A head of 64 padded to 128 lanes would double
+    the cache's bytes, so two neighbouring kv heads lie side by side in
+    one 128-lane row, ``[Hkv / 2, P, page, 128]``, which is a reshape of
+    the step's ``[T, Hkv, 64]`` rows and leaves no lane unused.  The paged
+    kernels then see a head of 128 whose group is the query heads of both
+    (``pair_queries``)."""
+    return 2 if 2 * head_dim == LANES and num_kv_heads % 2 == 0 else 1
+
+
+def paired_rows(new: jax.Array, cache) -> jax.Array:
+    """A step's ``[T, Hkv, D]`` rows as the pool ``cache`` lays them: two
+    heads to a row where the pool's rows are twice a head's width."""
+    width = cache_shape(cache)[-1]
+    if new.shape[-1] == width:
+        return new
+    return new.reshape(new.shape[0], -1, width)
+
+
+def _own_lanes(x: jax.Array, rows: int, pack: int, d: int):
+    """``x [T, H, ...]`` viewed ``[T, rows, pack, group, ...]`` and the
+    mask ``[1, 1, pack, 1, pack, 1]`` that is 1 where a head's position in
+    its row (axis 2) meets a lane block of the row (axis 4)."""
+    t, h = x.shape[:2]
+    own = jnp.eye(pack, dtype=x.dtype)[None, None, :, None, :, None]
+    return x.reshape(t, rows, pack, h // (rows * pack), -1, d), own
+
+
+def pair_queries(q: jax.Array, k_cache, scale=None):
+    """``q [T, H, D]`` and the softmax scale for a pool whose rows hold
+    ``pack = width / D`` kv heads side by side (``heads_a_row``): every
+    query head widened to the row, its numbers in its own kv head's lanes
+    and zeros in the others', so that a score over the whole row is the
+    score over its own head, at the head's own scale (``D ** -0.5`` where
+    the caller gave none: the kernels would take the row's width).  The
+    kernels then run a head of ``width`` whose group is ``pack`` times the
+    model's (at 32 query and 8 kv heads of 64: 4 rows of 128, a group of
+    8, the thinker's shape); the other head's lanes cost their products
+    (twice the algorithm's), not their bytes.  Query head ``h`` attends kv
+    head ``h // group``, lane block ``(h // group) % pack`` of row ``h //
+    (group * pack)``.  ``(q, scale)`` as given where the pool's rows are a
+    head's width."""
+    rows, _, _, width = cache_shape(k_cache)
+    t, h, d = q.shape
+    if width == d:
+        return q, scale
+    q6, own = _own_lanes(q, rows, width // d, d)
+    return ((q6 * own).reshape(t, h, width),
+            d ** -0.5 if scale is None else scale)
+
+
+def own_half(o: jax.Array, k_cache, d: int) -> jax.Array:
+    """Of the output ``[T, H, width]`` that the kernels give for paired
+    queries, every head's own ``d`` lanes (the rest is the other head's
+    values under this head's probabilities: dropped)."""
+    rows, _, _, width = cache_shape(k_cache)
+    t, h, _ = o.shape
+    if width == d:
+        return o
+    o6, own = _own_lanes(o, rows, width // d, d)
+    return jnp.sum(o6 * own, axis=4).reshape(t, h, d)
+
+
+def row_tiling_reason(width: int, page_size: int) -> str:
+    """Why Mosaic cannot tile the paged kernels' (page_size, width) page
+    buffers for a pool of ``width``-lane rows ("" when it can): the lane
+    dim must be a multiple of 128 and the sublane dim of 8, in either
     layout (bf16 and int8 page tiles both compiled and matched the
     reference at page 16 on a v5e, CHANGES.md PR 21).  The engine refuses
     such a geometry at construction on a platform that compiles the
     kernels; the ops count it (``_dispatch.auto_use_pallas``)."""
-    if head_dim % 128:
-        return (f"head_dim {head_dim} % 128 != 0 (a head's width, or a "
-                "shared pool's row, takes whole 128-lane tiles)")
+    if width % LANES:
+        return (f"head_dim {width} % 128 != 0 (a head's width, a pair of "
+                "64-wide heads, or a shared pool's row, takes whole "
+                "128-lane tiles)")
     if page_size % 8:
         return f"page_size {page_size} % 8 != 0"
     return ""
+
+
+def paged_tiling_reason(head_dim: int, page_size: int,
+                        num_kv_heads: int = 2) -> str:
+    """``row_tiling_reason`` of the pool that a model of this head width
+    (or shared row width) is given: 128, 256 and 640 are served as they
+    are, 64 two heads to a row (``heads_a_row``: an odd count of kv heads
+    cannot pair); 96 is not."""
+    return row_tiling_reason(
+        head_dim * heads_a_row(head_dim, num_kv_heads), page_size)
 
 
 def shared_tiling_reason(v_cache, v_dim) -> str:

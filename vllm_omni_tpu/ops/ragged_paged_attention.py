@@ -84,8 +84,10 @@ from vllm_omni_tpu.ops.paged_attention import (
     cache_is_quantized,
     cache_shape,
     gather_pages,
+    own_half,
     paged_attention_ref,
-    paged_tiling_reason,
+    pair_queries,
+    row_tiling_reason,
     shared_tiling_reason,
 )
 
@@ -811,8 +813,12 @@ def ragged_paged_attention(
     them."""
     quantized = cache_is_quantized(k_cache)
     k_data = cache_data(k_cache)
+    # heads of 64 lie two to a row of the pool: the kernel runs the row,
+    # at the head's own scale
+    head_dim = q.shape[-1]
+    q, scale = pair_queries(q, k_data, scale)
     if use_pallas is None:
-        reason = (paged_tiling_reason(q.shape[-1], k_data.shape[2])
+        reason = (row_tiling_reason(q.shape[-1], k_data.shape[2])
                   or shared_tiling_reason(v_cache, v_dim))
         if not reason and q.shape[0] % token_block:
             reason = (f"packed length {q.shape[0]} % token_block "
@@ -829,11 +835,11 @@ def ragged_paged_attention(
             a if x is None else x
             for x, a in zip((q_block, pages_per_block, dma_slots), auto))
     num_seqs = jnp.asarray(num_seqs, jnp.int32)
-    return _ragged_attention(
+    return own_half(_ragged_attention(
         q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
         num_seqs, scale, token_block, use_pallas, q_block,
         pages_per_block, dma_slots, v_dim, window=window, keep=keep,
-    )
+    ), k_data, head_dim)
 
 
 #: decode rows a grid cell of the decode entry holds.  A cell is only the
@@ -904,10 +910,13 @@ def paged_attention(
     ``ragged_paged_attention`` has them (the row's token sits at
     ``context_lens - 1``)."""
     k_data = cache_data(k_cache)
+    head_dim = q.shape[-1]
+    # heads of 64 lie two to a row of the pool
+    q, scale = pair_queries(q, k_data, scale)
     if use_pallas is None:
         use_pallas = auto_use_pallas(
             "paged_attention",
-            paged_tiling_reason(q.shape[-1], k_data.shape[2])
+            row_tiling_reason(q.shape[-1], k_data.shape[2])
             or shared_tiling_reason(v_cache, v_dim))
     group = q.shape[1] // k_data.shape[0]
     _, pages_per_block, dma_slots = auto_ragged_blocks(
@@ -916,7 +925,7 @@ def paged_attention(
         q_itemsize=q.dtype.itemsize,
         quantized=cache_is_quantized(k_cache),
         kv_pools=1 if v_cache is None else 2)
-    return _paged_attention(
+    return own_half(_paged_attention(
         q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
         v_dim, min(q.shape[0], DECODE_ROWS_A_CELL), pages_per_block,
-        dma_slots, window=window, keep=keep)
+        dma_slots, window=window, keep=keep), k_data, head_dim)

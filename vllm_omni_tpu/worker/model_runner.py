@@ -62,7 +62,7 @@ from vllm_omni_tpu.ops import _dispatch
 from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
 from vllm_omni_tpu.ops.paged_attention import (
     init_kv_cache,
-    paged_tiling_reason,
+    row_tiling_reason,
     shared_tiling_reason,
     write_kv_cache,
 )
@@ -288,11 +288,11 @@ class ARModelRunner:
         # the geometry is known here: on a platform that compiles the
         # paged kernels, one they cannot tile is refused now rather than
         # served through the O(T * max_ctx) gather reference
-        reason = paged_tiling_reason(layout.width, page_size)
+        reason = row_tiling_reason(layout.width, page_size)
         if self._latent and not reason:
             reason = shared_tiling_reason(None, cfg.kv_lora_rank)
         if self._window and not reason:
-            reason = (paged_tiling_reason(
+            reason = (row_tiling_reason(
                 cfg.kv_layout_of("sliding_attention").width, page_size)
                 or shared_tiling_reason(None, cfg.sliding.kv_lora_rank))
         if reason and _dispatch.pallas_mode() == "native":
@@ -301,7 +301,8 @@ class ARModelRunner:
                 f"({reason}; kv_cache_dtype={self.kv_cache_dtype}): pick "
                 "a page_size that is a multiple of 8 and a model whose "
                 "cache rows are whole 128-lane tiles (a head_dim of 128 "
-                "or 256, or a latent row padded to 640, are served)")
+                "or 256, an even count of kv heads of 64, or a latent row "
+                "padded to 640, are served)")
         self.async_scheduling = bool(async_scheduling)
         self.deterministic_decode = bool(deterministic_decode)
         self.mesh = mesh
@@ -323,10 +324,12 @@ class ARModelRunner:
 
             tp = dict(zip(mesh.axis_names, mesh.devices.shape)).get(
                 AXIS_TP, 1)
-            if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+            if cfg.num_heads % tp or cfg.num_kv_heads % tp \
+                    or layout.heads % tp:
                 raise ValueError(
                     f"tp={tp} must divide num_heads={cfg.num_heads} and "
-                    f"num_kv_heads={cfg.num_kv_heads}")
+                    f"num_kv_heads={cfg.num_kv_heads} (the pool's "
+                    f"{layout.heads} rows a token)")
             cfg = _dc.replace(cfg, tp_axis=AXIS_TP)
             params = shard_ar_params(params, mesh)
         self.params = params
@@ -390,7 +393,8 @@ class ARModelRunner:
             # over the window kind's pages at its own row width; a full
             # layer with an indexer holds, on the same pages as its rows,
             # the index keys' pool as a third entry
-            kinds = [k for k in cfg.layer_types if k != "linear_attention"]
+            kinds = [k for k in cfg.layer_types
+                     if k not in tfm.STATE_KINDS]
             wide = cfg.kv_layout_of("sliding_attention")
             for i, kind in enumerate(kinds):
                 if kind == "sliding_attention":
@@ -401,22 +405,21 @@ class ARModelRunner:
                     pages[i] += init_kv_cache(
                         1, num_pages, page_size, 1, cfg.index_head_dim,
                         dtype, shared=True)[0][:1]
-        # and, for each recurrent layer, the convolution's last inputs and
-        # the rule's state, both float32 (the rule's as the published
-        # implementation keeps it; the convolution's because its inputs
-        # are, see transformer._gdn_mixer), one slot a sequence (slots =
-        # max_num_seqs; layouts: ops/gated_delta_rule.py).  A row's slot
-        # index rides next to its page table; padding rows carry
-        # ``num_state_slots``, past the end, so their write is dropped
+        # and, for each layer that carries a state, what a sequence holds
+        # there (``cfg.state_shapes``: a Gated DeltaNet layer's last
+        # inputs of the convolution and the rule's matrices, a gated short
+        # convolution's last inputs alone), float32 (the rule's as the
+        # published implementation keeps it; the convolutions' because
+        # their inputs are, see transformer._gdn_mixer and
+        # _shortconv_mixer), one slot a sequence (slots = max_num_seqs;
+        # layouts: ops/gated_delta_rule.py).  A row's slot index rides
+        # next to its page table; padding rows carry ``num_state_slots``,
+        # past the end, so their write is dropped
         self.num_state_slots = max(max_num_seqs, 1) if self._hybrid else 0
         state = [
-            (jnp.zeros((self.num_state_slots,
-                        cfg.linear_conv_kernel_dim - 1,
-                        cfg.linear_conv_dim), jnp.float32),
-             jnp.zeros((self.num_state_slots, cfg.linear_num_value_heads,
-                        cfg.linear_key_head_dim,
-                        cfg.linear_value_head_dim), jnp.float32))
-            for _ in range(cfg.num_state_layers)]
+            tuple(jnp.zeros((self.num_state_slots, *shape), jnp.float32)
+                  for shape in cfg.state_shapes(kind))
+            for kind in cfg.layer_types if kind in tfm.STATE_KINDS]
         # the operand every step donates and rebinds
         self.kv_caches = (tfm.HybridCaches(pages, state) if self._hybrid
                           else pages)
