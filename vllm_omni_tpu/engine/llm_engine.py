@@ -14,6 +14,7 @@ OmniModelConfig.worker_type picks AR vs generation workers
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -53,6 +54,7 @@ from vllm_omni_tpu.ops.ragged_paged_attention import (
     DEFAULT_TOKEN_BLOCK,
     wide_q_tokens,
 )
+from vllm_omni_tpu.ops.ssd import chunk_slots
 from vllm_omni_tpu.outputs import OmniRequestOutput
 from vllm_omni_tpu.request import Request, RequestStatus
 from vllm_omni_tpu.sample.sampler import PATH_BOUNDED, PATH_FULL_SORT
@@ -358,6 +360,17 @@ class LLMEngine:
             self._window = (model_cfg.sliding_window
                             if model_cfg.num_window_layers else 0)
             self._index_topk = model_cfg.index_topk
+            # what a sequence's slot holds in ONE layer that carries a
+            # state, and in all of them (float32: the runner's slots)
+            per_layer = [4 * sum(math.prod(shape) for shape in
+                                 model_cfg.state_shapes(kind))
+                         for kind in model_cfg.layer_types
+                         if kind in tfm.STATE_KINDS]
+            self._state_bytes_per_slot = sum(per_layer)
+            self._state_bytes_per_slot_layer = max(per_layer, default=0)
+            # the chunk of a model with Mamba-2 layers: ``ssd_chunk_fill``
+            self._ssd_chunk = (model_cfg.mamba_chunk_size
+                               if "mamba" in model_cfg.layer_types else 0)
             if self._window or model_cfg.index_topk:
                 self._kv_kinds = self._kinds_of(model_cfg, config,
                                                 num_pages, itemsize)
@@ -1160,6 +1173,14 @@ class LLMEngine:
             # it has given its slot back by now, but was one of its rows
             step_metrics.state_slots.observe(
                 max(kv.state_slots_in_use, state_rows))
+            chunk = getattr(self, "_ssd_chunk", 0)
+            if chunk and unified:
+                # how full a Mamba-2 model's chunks ran (ops/ssd.py)
+                tokens, slots = chunk_slots(
+                    [s.num_new_tokens for s in scheduled], chunk)
+                if slots:
+                    step_metrics.ssd_chunk_fill.observe(
+                        100.0 * tokens / slots)
         tracker = getattr(self, "roofline", None)  # duck-typed fakes
         if tracker is not None:
             roofline = tracker.on_step(
@@ -1364,6 +1385,13 @@ class LLMEngine:
                 "state_resets_total": getattr(
                     self.scheduler, "num_state_resets", 0),
                 "prefix_hits_refused_total": kv.prefix_hits_refused,
+                # float32 bytes a sequence holds in all the layers that
+                # carry a state, and in the largest of them (beside
+                # ``kv.bytes_per_token``)
+                "bytes_per_slot": getattr(
+                    self, "_state_bytes_per_slot", None),
+                "bytes_per_slot_layer": getattr(
+                    self, "_state_bytes_per_slot_layer", None),
             }
         if self.kv_tiers is not None:
             st = self.kv_tiers.stats()

@@ -95,6 +95,10 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     "state_slots": (
         "histogram", "State slots in use, observed once a step",
         ("stage",)),
+    "ssd_chunk_fill": (
+        "histogram",
+        "Tokens over chunk slots x 100 of a ragged step's state-space "
+        "chunks", ("stage",)),
     "state_slot_allocs_total": (
         "counter", "State slots handed to a sequence with its first page",
         ("stage",)),
@@ -645,6 +649,8 @@ def render_exposition(summary: dict, engine_snaps: dict,
         if snap.get("sparse_keep_pct", {}).get("count"):
             exp.histogram("sparse_keep_pct", labels,
                           snap["sparse_keep_pct"])
+        if snap.get("ssd_chunk_fill", {}).get("count"):
+            exp.histogram("ssd_chunk_fill", labels, snap["ssd_chunk_fill"])
         tiers = snap.get("kv_tiers")
         if tiers:
             exp.sample("kv_prefix_hit_tokens_total", labels,

@@ -375,6 +375,11 @@ class EngineStepMetrics:
         # learned sparse attention, the share they attend:
         # 100 x sum min(ctx, top_k) / sum ctx over the step's new tokens
         self.sparse_keep_pct = Histogram(buckets=PERCENT_BUCKETS)
+        # how full the chunks of a Mamba-2 model's chunked form ran, once
+        # a ragged step that had any: 100 x tokens in chunks / chunk
+        # slots (ops/ssd.py chunk_slots; the one-token rows take the
+        # recurrent form and are in neither count)
+        self.ssd_chunk_fill = Histogram(buckets=PERCENT_BUCKETS)
         # SLO targets (None = unconfigured leg always passes) + the
         # per-tenant attainment/goodput ledger they gate
         self.slo_ttft_ms: Optional[float] = None
@@ -532,6 +537,7 @@ class EngineStepMetrics:
             "kv_pages_used": self.kv_pages_used.snapshot(),
             "kv_window_pages_used": self.kv_window_pages_used.snapshot(),
             "sparse_keep_pct": self.sparse_keep_pct.snapshot(),
+            "ssd_chunk_fill": self.ssd_chunk_fill.snapshot(),
             "saturation": dict(self.saturation),
             "slo": {
                 "targets": {"ttft_ms": self.slo_ttft_ms,

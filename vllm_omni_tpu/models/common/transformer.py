@@ -34,10 +34,11 @@ from vllm_omni_tpu.ops import (
 )
 from vllm_omni_tpu.ops import gated_delta_rule as gdr
 from vllm_omni_tpu.ops import indexer as indexer_ops
+from vllm_omni_tpu.ops import ssd
 from vllm_omni_tpu.ops.paged_attention import heads_a_row
 
 #: the kinds of layer whose sequences hold a state slot
-STATE_KINDS = ("linear_attention", "conv")
+STATE_KINDS = ("linear_attention", "conv", "mamba")
 
 
 @dataclass(frozen=True)
@@ -213,6 +214,33 @@ class TransformerConfig:
     # one sigmoid gate a head on the attention output, from the layer's
     # normed input (``attn_output_gate`` is the element-wise one)
     attn_head_gate: bool = False
+    # a "mamba" layer is Mamba-2 (Granite 4.0-H): ``[z | xBC | dt] = x
+    # W_in``, a depth-wise causal convolution of ``mamba_conv_kernel`` taps
+    # with a bias over ``xBC`` and a silu, then the state-space rule over
+    # ``mamba_num_heads`` heads of ``mamba_head_dim`` with one group of B
+    # and C of ``mamba_state_dim`` (ops/ssd.py), the gate ``silu(z)``, an
+    # RMSNorm over all heads and ``W_out``.  A sequence carries the
+    # convolution's last inputs and a ``[heads, head_dim, state_dim]``
+    # float32 state, which the rule updates where it lies in the slots
+    # (docs/kv_cache.md, "Four kinds of state")
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state_dim: int = 0
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 256
+    # attention without positions (``position_embedding_type`` "nope"):
+    # no table is built and neither queries nor keys are rotated
+    use_rope: bool = True
+    # the softmax scale of the per-head attention layers where it is not
+    # ``head_dim ** -0.5`` (None); the kernels take it as ``scale``
+    attention_multiplier: Optional[float] = None
+    # the Granite family's multipliers: the embedding's rows are
+    # multiplied by the first, every branch by the second before it joins
+    # the residual stream, the logits DIVIDED by the third.  At 1.0 no
+    # operation is emitted
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def layer_types(self) -> tuple[str, ...]:
@@ -242,9 +270,13 @@ class TransformerConfig:
         """What one sequence carries through a layer of ``kind``, an array
         a shape: a Gated DeltaNet layer the convolution's last inputs and
         the rule's matrix a value head, a gated short convolution its last
-        inputs alone (layouts: ops/gated_delta_rule.py)."""
+        inputs alone, a Mamba-2 layer the convolution's last inputs and the
+        rule's state a head (layouts: ops/gated_delta_rule.py, ops/ssd.py)."""
         if kind == "conv":
             return ((self.shortconv_kernel - 1, self.hidden_size),)
+        if kind == "mamba":
+            return ((self.mamba_conv_kernel - 1, self.mamba_conv_dim),
+                    self.mamba_dims)
         return ((self.linear_conv_kernel_dim - 1, self.linear_conv_dim),
                 (self.linear_num_value_heads, self.linear_key_head_dim,
                  self.linear_value_head_dim))
@@ -285,11 +317,14 @@ class TransformerConfig:
 
     def attn_kwargs_of(self, kind: str = "full_attention") -> dict:
         """What the paged attention ops are told beyond their operands:
-        nothing for per-head K and V pages (the scale is the head's); for
+        nothing for per-head K and V pages (the scale is the head's, or
+        the model's ``attention_multiplier`` where it states one); for
         a latent pool the scale of the un-absorbed head and the lanes of
         a row that are the value; for a sliding-window layer its window."""
         if not self.kv_lora_rank:
-            return {}
+            if self.attention_multiplier is None:
+                return {}
+            return {"scale": self.attention_multiplier}
         lk = self.latent_kind(kind)
         out = {"scale": lk.scale, "v_dim": lk.kv_lora_rank}
         if kind == "sliding_attention":
@@ -304,6 +339,18 @@ class TransformerConfig:
     def linear_conv_dim(self) -> int:
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
+    def mamba_dims(self) -> tuple[int, int, int]:
+        """(heads, a head's width, the state's width) of a Mamba-2 layer."""
+        return (self.mamba_num_heads, self.mamba_head_dim,
+                self.mamba_state_dim)
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of a Mamba-2 layer's convolution: u, B and C."""
+        return (self.mamba_num_heads * self.mamba_head_dim
+                + 2 * self.mamba_state_dim)
 
     @property
     def gdn_heads(self) -> tuple[int, int, int, int]:
@@ -387,6 +434,8 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
             layer["linear_attn"] = _gdn_init(k, cfg, dtype)
         elif kind == "conv":
             layer["shortconv"] = _shortconv_init(k, cfg, dtype)
+        elif kind == "mamba":
+            layer["mamba"] = _mamba_init(k, cfg, dtype)
         elif cfg.kv_lora_rank:
             lk = cfg.latent_kind(kind)
             layer["mla"] = _mla_init(k, cfg, lk, dtype)
@@ -568,6 +617,44 @@ def _shortconv_init(k, cfg: TransformerConfig, dtype):
             k[5], (kc, cfg.hidden_size), dtype,
             minval=-kc ** -0.5, maxval=kc ** -0.5)},
         "out_proj": nn.linear_init(k[3], cfg.hidden_size, cfg.hidden_size,
+                                   bias=False, dtype=dtype),
+    }
+
+
+def _mamba_init(k, cfg: TransformerConfig, dtype):
+    """One Mamba-2 mixer's weights, under the checkpoint's names
+    (``mamba.in_proj``, ``conv1d``, ``A_log``, ``dt_bias``, ``D``,
+    ``norm``, ``out_proj``): ``in_proj``'s columns are [z | xBC | dt]; the
+    taps lie [K, C] with ``w[K - 1]`` on the current token (the checkpoint
+    keeps [C, 1, K]: a transpose in a loader), here with a bias.  ``A_log``,
+    ``dt_bias`` and ``D`` are drawn as Mamba-2's own initialiser draws them
+    (A uniform in (1, 16), the time step log-uniform in [0.001, 0.1]
+    through the inverse softplus, D ones), so that a token's decay exp(a)
+    is neither 0 nor 1 and a state is carried over hundreds of tokens."""
+    n_h, d_h, n = cfg.mamba_dims
+    d_inner, kc = n_h * d_h, cfg.mamba_conv_kernel
+    ka, kd = jax.random.split(k[7])
+    kw, kb = jax.random.split(k[5])
+    dt = jnp.exp(jax.random.uniform(kd, (n_h,), jnp.float32)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return {
+        "in_proj": nn.linear_init(k[0], cfg.hidden_size,
+                                  d_inner + cfg.mamba_conv_dim + n_h,
+                                  bias=False, dtype=dtype),
+        # depthwise: w[K - 1] multiplies the current token; the published
+        # initialiser's bias is small and not zero
+        "conv": {"w": jax.random.uniform(
+            kw, (kc, cfg.mamba_conv_dim), dtype,
+            minval=-kc ** -0.5, maxval=kc ** -0.5),
+                 "b": jax.random.uniform(
+            kb, (cfg.mamba_conv_dim,), dtype,
+            minval=-kc ** -0.5, maxval=kc ** -0.5)},
+        "A_log": jnp.log(jax.random.uniform(
+            ka, (n_h,), jnp.float32, minval=1.0, maxval=16.0)).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "D": jnp.ones((n_h,), dtype),
+        "norm": nn.rmsnorm_init(d_inner, dtype),
+        "out_proj": nn.linear_init(k[3], d_inner, cfg.hidden_size,
                                    bias=False, dtype=dtype),
     }
 
@@ -774,6 +861,18 @@ def _embed_input(params, token_ids, inputs_embeds, embeds_mask):
     return x
 
 
+def _embed(params, cfg: TransformerConfig, token_ids, inputs_embeds,
+           embeds_mask):
+    """``_embed_input``, times the model's ``embedding_multiplier`` where
+    it has one."""
+    x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
+    if cfg.embedding_multiplier == 1.0:
+        return x
+    with jax.named_scope("omni.embed"):
+        return (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(
+            x.dtype)
+
+
 @jax.named_scope("omni.proj")
 def _final_norm(params, cfg: TransformerConfig, x):
     return _rms(cfg, x, params["final_norm"]).astype(
@@ -842,6 +941,37 @@ def _shortconv_mixer(sc, cfg: TransformerConfig, h, recur):
         return _out_proj(cfg, y, sc["out_proj"]["w"])
 
 
+def _mamba_mixer(mb, cfg: TransformerConfig, h, recur):
+    """The Mamba-2 sequence mixer on normed rows h [T, hidden]: ``[z | xBC
+    | dt] = h W_in``; ``recur(xBC, dt, a, taps, bias, D)`` is convolution,
+    silu, the state-space rule and the skip (ops/ssd.py), which supplies
+    what differs between the forwards (where a sequence's carried inputs
+    and state are, the chunked or the one-token form) and returns y [T,
+    H, P] float32; then the gate ``silu(z)`` BEFORE an RMSNorm over all
+    heads (one group), and the out projection.  Everything between the two
+    projections is float32, as in ``_gdn_mixer`` and for its reason."""
+    t = h.shape[0]
+    n_h, d_h, _ = cfg.mamba_dims
+    d_inner = n_h * d_h
+    with jax.named_scope("omni.proj"):
+        zxbcdt = jnp.dot(h, mb["in_proj"]["w"],
+                         preferred_element_type=jnp.float32)
+    with jax.named_scope("omni.ssd"):
+        z = zxbcdt[:, :d_inner]
+        xbc = zxbcdt[:, d_inner:d_inner + cfg.mamba_conv_dim]
+        # no clamp of the time step: the family's limits are 0 and infinity
+        dt = jax.nn.softplus(zxbcdt[:, d_inner + cfg.mamba_conv_dim:]
+                             + mb["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(mb["A_log"].astype(jnp.float32)) * dt
+        y = recur(xbc, dt, a, mb["conv"]["w"], mb["conv"]["b"], mb["D"])
+        y = y.reshape(t, d_inner) * jax.nn.silu(z)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
+        y = (y * mb["norm"]["w"].astype(jnp.float32)).astype(h.dtype)
+    with jax.named_scope("omni.proj"):
+        return _out_proj(cfg, y, mb["out_proj"]["w"])
+
+
 def _join_lanes(parts, width: int):
     """``parts`` side by side on the last axis, zeros after them up to
     ``width``.  A leading part that is whole 128-lane tiles is joined as
@@ -868,7 +998,9 @@ def _join_lanes(parts, width: int):
 def _kind_ropes(cfg: TransformerConfig, positions, mrope_positions=None):
     """{layer kind: (cos, sin)} for the kinds the stack has: one entry for
     every model but one whose sliding-window layers rotate at a base of
-    their own."""
+    their own; no table at all for a model without positions."""
+    if not cfg.use_rope:
+        return {kind: (None, None) for kind in set(cfg.layer_types)}
     first = _rope_tables(cfg, positions if mrope_positions is None
                          else mrope_positions)
     ropes = {kind: first for kind in set(cfg.layer_types)}
@@ -971,6 +1103,16 @@ def _out_proj(cfg: TransformerConfig, o, w):
     return o @ w
 
 
+def _join_residual(cfg: TransformerConfig, x, branch):
+    """``x + residual_multiplier * branch``: the product and the sum in
+    float32 and one rounding to the stream's type; the plain sum where the
+    model has no such multiplier."""
+    if cfg.residual_multiplier == 1.0:
+        return x + branch
+    return (x.astype(jnp.float32) + cfg.residual_multiplier
+            * branch.astype(jnp.float32)).astype(x.dtype)
+
+
 def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
                 kind: str = "full_attention"):
     """One transformer block: norm → mixer → residual → norm → MLP →
@@ -982,7 +1124,8 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     the latent row as the key and no value (``_mla_mixer``); in a Gated
     DeltaNet layer (``"linear_attn"``) ``attend`` is the ``recur`` of
     ``_gdn_mixer``, in a gated short convolution (``"shortconv"``) that of
-    ``_shortconv_mixer``.  The MLP is the sparse one where the layer holds
+    ``_shortconv_mixer``, in a Mamba-2 layer (``"mamba"``) that of
+    ``_mamba_mixer``.  The MLP is the sparse one where the layer holds
     ``experts``, a dense SwiGLU where it does not.  One body serves every
     forward so the variants
     cannot silently diverge."""
@@ -999,6 +1142,9 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
         o = _shortconv_mixer(layer["shortconv"], cfg,
                              h.reshape(-1, h.shape[-1]), attend).reshape(
                                  *b, -1)
+    elif "mamba" in layer:
+        o = _mamba_mixer(layer["mamba"], cfg, h.reshape(-1, h.shape[-1]),
+                         attend).reshape(*b, -1)
     elif "mla" in layer:
         o = _mla_mixer(layer, cfg, cfg.latent_kind(kind),
                        h.reshape(-1, h.shape[-1]), cos, sin,
@@ -1006,8 +1152,9 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     else:
         with jax.named_scope("omni.proj"):
             q, k, v, gate = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
-            q = _rope(cfg, q, cos, sin)
-            k = _rope(cfg, k, cos, sin)
+            if cfg.use_rope:
+                q = _rope(cfg, q, cos, sin)
+                k = _rope(cfg, k, cos, sin)
         o = attend(q, k, v).reshape(*b, -1)
         if gate is not None:
             # the output gate is part of gated attention: billed to it
@@ -1020,13 +1167,13 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
         if cfg.tp_axis is not None:
             # row-parallel o_proj: each shard holds a partial sum
             o = jax.lax.psum(o, cfg.tp_axis)
-        x = x + o
+        x = _join_residual(cfg, x, o)
         h = _rms(cfg, x, layer["post_norm"]).astype(act)
     with jax.named_scope("omni.moe" if "experts" in layer else "omni.mlp"):
         y = _mlp(layer, cfg, h)
         if cfg.tp_axis is not None:
             y = jax.lax.psum(y, cfg.tp_axis)
-        return x + y
+        return _join_residual(cfg, x, y)
 
 
 class HybridCaches(NamedTuple):
@@ -1038,14 +1185,21 @@ class HybridCaches(NamedTuple):
     kv: list     # per softmax-attention layer: (k, v) pages
     # per layer that carries a state, its slots, a tuple of arrays
     # ``[slots, ...]`` (``TransformerConfig.state_shapes``): (conv,
-    # recurrent) of a Gated DeltaNet layer, (taps,) of a short convolution
+    # recurrent) of a Gated DeltaNet layer, (taps,) of a short convolution,
+    # (conv, state) of a Mamba-2 layer
     state: list
 
 
 #: the scope a kind of state's gathers and scatters run under, and the key
 #: of the layer's weights that says the layer is of that kind
 _STATE_SCOPE = {"linear_attn": "omni.gdn_state",
-                "shortconv": "omni.conv_state"}
+                "shortconv": "omni.conv_state",
+                "mamba": "omni.ssd_state"}
+#: of a layer's state arrays, how many (the last ones) its rule updates
+#: where they lie in the slots, instead of on a copy of the step's rows: a
+#: Mamba-2 layer's [heads, head_dim, state_dim] state, 4 MB a sequence,
+#: which a decode step would otherwise copy out of the pool and back
+_STATE_IN_PLACE = {"mamba": 1}
 
 
 def _state_key(layer) -> Optional[str]:
@@ -1083,15 +1237,25 @@ def _write_state(cache, slots, state, scope):
                      for a, new in zip(cache, state))
 
 
-def _stateful_recur(cache, slots, fresh, new_states, rule, scope):
-    """The ``recur`` of ``_gdn_mixer`` or ``_shortconv_mixer`` for a
-    forward that carries state: the rows' slots are read (under
-    ``scope``), ``rule(*the mixer's operands, *the state's arrays)`` runs
-    the ragged or the one-token form and returns the output and the new
-    state, and the slots are written back into ``new_states``."""
+def _stateful_recur(cache, slots, fresh, new_states, rule, scope,
+                    in_place: int = 0):
+    """The ``recur`` of ``_gdn_mixer``, ``_shortconv_mixer`` or
+    ``_mamba_mixer`` for a forward that carries state: the rows' slots are
+    read (under ``scope``), ``rule(*the mixer's operands, *the state's
+    arrays)`` runs the ragged or the one-token form and returns the output
+    and the new state, and the slots are written back into ``new_states``.
+    The last ``in_place`` arrays of the layer's state are not read out:
+    the rule gets the pools themselves with ``slots`` and ``fresh`` after
+    them, and returns them updated."""
+    moved = cache[:len(cache) - in_place]
+    pools = cache[len(moved):]
+
     def recur(*operands):
-        o, *state = rule(*operands, *_read_state(cache, slots, fresh, scope))
-        new_states.append(_write_state(cache, slots, state, scope))
+        o, *state = rule(*operands, *_read_state(moved, slots, fresh, scope),
+                         *((*pools, slots, fresh) if pools else ()))
+        new_states.append(
+            _write_state(moved, slots, state[:len(moved)], scope)
+            + tuple(state[len(moved):]))
         return o
 
     return recur
@@ -1133,11 +1297,12 @@ def forward_hidden(
             "learned selection: a model with sliding-window or sparse "
             "layers is served through forward_unified / forward_decode")
     b, s = token_ids.shape
-    x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
+    x = _embed(params, cfg, token_ids, inputs_embeds, embeds_mask)
     if positions is None:
         shape = (b, s) if cfg.mrope_sections is None else (b, 3, s)
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], shape)
-    cos, sin = _rope_tables(cfg, positions)
+    cos, sin = _rope_tables(cfg, positions) if cfg.use_rope else (None,
+                                                                  None)
 
     def attend(q, k, v):
         with jax.named_scope("omni.attn"):
@@ -1157,6 +1322,7 @@ def forward_hidden(
                 v.reshape(b, s, -1, cfg.head_dim),
                 causal=True,
                 kv_mask=attn_mask,
+                **cfg.attn_kwargs,
             )
 
     def recur(mixed, g, beta, conv_w):
@@ -1179,7 +1345,19 @@ def forward_hidden(
             jnp.arange(b + 1, dtype=jnp.int32) * s,
             jnp.full((b,), s, jnp.int32), carried="gather")[0]
 
-    mixes = {"linear_attn": recur, "shortconv": conv_recur}
+    def ssd_recur(xbc, dt, a, conv_w, conv_b, d_skip):
+        # no cache: a pool of the batch's own, every slot fresh
+        return ssd.mamba2_ragged(
+            xbc, dt, a, conv_w, conv_b, d_skip,
+            jnp.zeros((b, conv_w.shape[0] - 1, xbc.shape[-1]), xbc.dtype),
+            jnp.zeros((b, *cfg.mamba_dims), jnp.float32),
+            jnp.arange(b, dtype=jnp.int32), jnp.ones((b,), bool),
+            jnp.arange(b + 1, dtype=jnp.int32) * s,
+            jnp.full((b,), s, jnp.int32), cfg.mamba_dims,
+            chunk=cfg.mamba_chunk_size)[0]
+
+    mixes = {"linear_attn": recur, "shortconv": conv_recur,
+             "mamba": ssd_recur}
     layers = params["layers"]
     if drop_last_layers:
         layers = layers[:len(layers) - drop_last_layers]
@@ -1206,7 +1384,13 @@ def forward_hidden(
 def logits_from_hidden(params, cfg: TransformerConfig, hidden: jax.Array):
     if cfg.tie_word_embeddings:
         # embed table is replicated under TP — logits already full
-        return hidden @ params["embed"]["w"].T
+        logits = hidden @ params["embed"]["w"].T
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
+    if cfg.logits_scaling != 1.0:
+        raise NotImplementedError("logits_scaling is built for a head "
+                                  "that is the embedding transposed")
     logits = nn.linear(params["lm_head"], hidden)
     if cfg.tp_axis is not None:
         # column-parallel lm_head: reassemble the vocab axis
@@ -1290,10 +1474,12 @@ def forward_unified(
     A model with layers that carry a state passes ``HybridCaches`` as
     ``kv_caches`` (beside the pages, for each Gated DeltaNet layer the
     convolution's and the rule's state, for each gated short convolution
-    its last inputs, one slot a sequence) and ``state_slots``: a
+    its last inputs, for each Mamba-2 layer the convolution's inputs and
+    the rule's state, one slot a sequence) and ``state_slots``: a
     sequence's rows read its slot at the chunk's start (zeros where the
     chunk starts the sequence) and write it at the chunk's end, through
-    the ragged form of the layer's rule.
+    the ragged form of the layer's rule (a Mamba-2 layer's state is
+    updated in its slot: ``_STATE_IN_PLACE``).
 
     A model with sliding-window layers passes ``window_pages``: the slot
     mapping and the page tables of the window kind of page, which its
@@ -1304,7 +1490,7 @@ def forward_unified(
     """
     hybrid = isinstance(kv_caches, HybridCaches)
     kv_caches, state_caches = kv_caches if hybrid else (kv_caches, None)
-    x = _embed_input(params, token_ids, inputs_embeds, embeds_mask)
+    x = _embed(params, cfg, token_ids, inputs_embeds, embeds_mask)
     # [3, T] -> the [B, 3, S] convention with B=1
     ropes = _kind_ropes(cfg, positions, None if cfg.mrope_sections is None
                         else positions[None])
@@ -1323,7 +1509,10 @@ def forward_unified(
         "linear_attn": lambda *a: gdr.gated_delta_net_ragged(
             *a, cu_q_lens, q_lens, cfg.gdn_heads),
         "shortconv": lambda *a: gdr.causal_conv_ragged(
-            *a, cu_q_lens, q_lens, carried="gather")}
+            *a, cu_q_lens, q_lens, carried="gather"),
+        "mamba": lambda *a: ssd.mamba2_ragged(
+            *a, cu_q_lens, q_lens, cfg.mamba_dims,
+            chunk=cfg.mamba_chunk_size)}
     for i, (layer, cache) in enumerate(
             _layer_caches(params, kv_caches, state_caches)):
         kind = cfg.layer_types[i]
@@ -1339,7 +1528,8 @@ def forward_unified(
         state = _state_key(layer)
         if state:
             attend = _stateful_recur(cache, state_slots, fresh, new_states,
-                                     ragged_rules[state], _STATE_SCOPE[state])
+                                     ragged_rules[state], _STATE_SCOPE[state],
+                                     _STATE_IN_PLACE.get(state, 0))
         x = _layer_step(layer, cfg, x, *ropes[kind], attend, kind)
         if deepstack is not None and i < deepstack.shape[0]:
             with jax.named_scope("omni.embed"):
@@ -1370,7 +1560,7 @@ def forward_decode(
     """
     hybrid = isinstance(kv_caches, HybridCaches)
     kv_caches, state_caches = kv_caches if hybrid else (kv_caches, None)
-    x = _embed_input(params, token_ids, None, None)  # [B, hidden]
+    x = _embed(params, cfg, token_ids, None, None)  # [B, hidden]
     ropes = _kind_ropes(cfg, positions)
     new_caches, new_states = [], []
 
@@ -1385,7 +1575,8 @@ def forward_decode(
     decode_rules = {
         "linear_attn": lambda *a: gdr.gated_delta_net_decode(
             *a, cfg.gdn_heads),
-        "shortconv": gdr.causal_conv_update}
+        "shortconv": gdr.causal_conv_update,
+        "mamba": lambda *a: ssd.mamba2_decode(*a, cfg.mamba_dims)}
     for i, (layer, cache) in enumerate(
             _layer_caches(params, kv_caches, state_caches)):
         kind = cfg.layer_types[i]
@@ -1398,7 +1589,8 @@ def forward_decode(
         state = _state_key(layer)
         if state:
             attend = _stateful_recur(cache, state_slots, fresh, new_states,
-                                     decode_rules[state], _STATE_SCOPE[state])
+                                     decode_rules[state], _STATE_SCOPE[state],
+                                     _STATE_IN_PLACE.get(state, 0))
         x = _layer_step(layer, cfg, x, *ropes[kind], attend, kind)
     if hybrid:
         new_caches = HybridCaches(new_caches, new_states)

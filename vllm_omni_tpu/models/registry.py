@@ -185,6 +185,12 @@ _AR_MODELS: dict[str, _Entry] = {
     "Lfm2MoeForCausalLM": _Entry(
         "vllm_omni_tpu.models.lfm2_moe", "real_factory"
     ),
+    # Mamba-2 layers whose state is updated in its slot, attention without
+    # positions on one layer in ten, four multipliers
+    # (granite-4.0-h-small; docs/kv_cache.md)
+    "GraniteMoeHybridForCausalLM": _Entry(
+        "vllm_omni_tpu.models.granite_moe_hybrid", "real_factory"
+    ),
 }
 
 
