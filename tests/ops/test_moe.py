@@ -327,3 +327,123 @@ def test_the_ep_shards_route_as_the_sigmoid_router_does(rng, devices8):
                                atol=2e-6, rtol=1e-5)
     with pytest.raises(NotImplementedError, match="all-to-all"):
         tfm._moe_mlp(layer, dataclasses.replace(cfg, moe_dispatch="a2a"), x)
+
+
+# ---- the combine (ISSUE 43): a gather by the sort's inverse and a float32
+# sum over a token's k picks, where a scatter-add of T*k rows stood
+def _scatter_add_combine(y, order, topk_w, held=None):
+    """What ended ``routed_moe`` / ``routed_moe_share`` until PR 43."""
+    t, k = topk_w.shape
+    w = topk_w.reshape(-1)[order]
+    y = y * w[:, None].astype(y.dtype)
+    if held is not None:
+        y = jnp.where(held.reshape(-1)[order][:, None], y, 0)
+    return jnp.zeros((t, y.shape[1]), y.dtype).at[order // k].add(y)
+
+
+def _sorted_pairs(t, k, h, width, e_held, seed=0):
+    """(y, order, topk_w, held, live rows): a router's picks over ``width``
+    experts of which the first ``e_held`` are here, sorted as
+    ``routed_moe_share`` sorts them."""
+    rs = np.random.default_rng(seed)
+    idx = np.stack([rs.permutation(width)[:k] for _ in range(t)])
+    topk_w = rs.uniform(0.1, 1.0, (t, k)).astype(np.float32)
+    held = idx < e_held
+    order = np.argsort(np.where(held, idx, e_held).reshape(-1),
+                       kind="stable").astype(np.int32)
+    y = rs.standard_normal((t * k, h)).astype(np.float32)
+    return (jnp.asarray(y), jnp.asarray(order), jnp.asarray(topk_w),
+            jnp.asarray(held), int(held.sum()))
+
+
+@pytest.mark.parametrize("t,k,h,width,e_held", [
+    (12, 2, 16, 4, 4), (5, 1, 16, 4, 4), (1, 4, 16, 8, 8), (1, 1, 8, 2, 2),
+    (64, 8, 32, 64, 8), (24, 10, 16, 72, 18), (7, 3, 16, 5, 0)],
+    ids=["t12k2", "k1", "t1", "t1k1", "share_1_in_8", "share_1_in_4",
+         "share_none_live"])
+def test_combine_equals_the_scatter_add_it_replaces(t, k, h, width, e_held):
+    y, order, topk_w, held, _ = _sorted_pairs(t, k, h, width, e_held)
+    mask = None if e_held == width else held
+    got = jax.jit(moe_ops._combine)(y, order, topk_w, mask)
+    assert got.shape == (t, h) and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(_scatter_add_combine(y, order, topk_w, mask)),
+        atol=2e-6, rtol=1e-5)
+    if e_held == 0:
+        assert not np.asarray(got).any()
+
+
+def test_a_share_masks_the_value_of_an_absent_pick_not_its_weight():
+    """The rows of ``y`` behind the last held group are whatever the
+    grouped matmul left there.  Poisoned with NaN they must not reach the
+    sum: ``NaN * 0`` is ``NaN``, so the mask is on the value."""
+    y, order, topk_w, held, live = _sorted_pairs(64, 8, 32, 64, 8)
+    assert 0 < live < 64 * 8
+    want = moe_ops._combine(y, order, topk_w, held)
+    got = moe_ops._combine(y.at[live:].set(jnp.nan), order, topk_w, held)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and a zero weight alone would not have done
+    zero_w = jnp.where(held, topk_w, 0.0)
+    assert np.isnan(np.asarray(moe_ops._combine(
+        y.at[live:].set(jnp.nan), order, zero_w))).any()
+
+
+def test_a_share_whose_absent_rows_are_poison_gives_what_it_gave(rng):
+    """The same through ``routed_moe_share``: the grouped matmul's rows
+    past its group sizes overwritten with NaN change nothing."""
+    x, rw, gu, dn = _mk_weights(rng, t=32, e=8)
+    want = moe_ops.routed_moe_share(x, rw, gu[4:6], dn[4:6], 2, 4)
+    real = moe_ops._grouped_mlp
+
+    def poisoned(xs, gate_up, down, group_sizes):
+        rows = jnp.arange(xs.shape[0])[:, None]
+        return jnp.where(rows < group_sizes.sum(),
+                         real(xs, gate_up, down, group_sizes), jnp.nan)
+
+    moe_ops._grouped_mlp = poisoned
+    try:
+        got = moe_ops.routed_moe_share(x, rw, gu[4:6], dn[4:6], 2, 4)
+    finally:
+        moe_ops._grouped_mlp = real
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(jnp.abs(want).max()) > 1e-3
+
+
+def test_the_sum_over_a_tokens_picks_is_float32():
+    """One token, eight picks of weight 1: 256 and seven ones.  A running
+    sum in bfloat16 never leaves 256 (257 is no bfloat16 and ties go to
+    the even neighbour); the float32 sum is 263, which rounds once, to
+    264."""
+    y = jnp.asarray([[256.0]] + [[1.0]] * 7, jnp.bfloat16)
+    order = jnp.arange(8, dtype=jnp.int32)
+    topk_w = jnp.ones((1, 8), jnp.float32)
+    got = moe_ops._combine(y, order, topk_w)
+    assert got.dtype == jnp.float32 and float(got[0, 0]) == 263.0
+    assert float(got.astype(jnp.bfloat16)[0, 0]) == 264.0
+    assert float(_scatter_add_combine(y, order, topk_w)[0, 0]) == 256.0
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_no_scatter_add_of_rows_is_left_in_routed_moe(rng):
+    """``routed_moe`` scatter-adds activations no more: the one
+    ``scatter-add`` left in it is ``bincount``'s, over int32 counts.  (The
+    share still ends in one: every form of ``_combine`` tried in its place
+    stopped dots3-note-prev's 2 048-row step on the chip: PERF.md, PR 43.)"""
+    x, rw, gu, dn = _mk_weights(rng, t=32, e=8)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: moe_ops.routed_moe(*a, 2))(x, rw, gu, dn)
+    adds = [e for e in _primitives(jaxpr.jaxpr)
+            if e.primitive.name == "scatter-add"]
+    assert all(e.outvars[0].aval.ndim == 1
+               and jnp.issubdtype(e.outvars[0].aval.dtype, jnp.integer)
+               for e in adds), adds
+    assert len(adds) <= 1

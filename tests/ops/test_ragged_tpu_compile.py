@@ -634,11 +634,14 @@ def test_without_a_window_or_a_selection_the_kernel_is_the_parents(
 #: (grouped-query attention with experts, GLM's latent attention,
 #: Qwen3-Next's hybrid stack), taken on 628fc51 as these tests trace them:
 #: layer kinds as data, a ``LatentKind`` a kind, the rescale, the head
-#: gate, the indexer and the second table leave their programs as they were
+#: gate, the indexer and the second table leave their programs as they were.
+#: Taken again at PR 43, which changes every expert layer's program by
+#: design (``ops/moe.py _combine``: a gather by the sort's inverse and a
+#: float32 sum over a token's picks where a scatter-add stood).
 STEPS = {
-    "gqa": ("bd6e7b7073a712b8", "d46c6428770d8a51"),
-    "glm": ("7777300f343087c0", "5eaccde9b5b5e930"),
-    "next": ("a34b365d82d480ce", "4c3391e96f4d6c84"),
+    "gqa": ("b96834a68e30334f", "2f7ce95ab51fe417"),
+    "glm": ("7bc4f27799f00ede", "e825658d5b3707a0"),
+    "next": ("ce74e8f7b40a9a44", "596873d85deaebd2"),
 }
 
 

@@ -10,12 +10,13 @@ all-to-all token dispatch, SURVEY.md §2.11).
 TPU-first mechanics:
 - **Local (single shard)**: sort token-expert pairs by expert id, run the
   expert MLPs as ONE grouped matmul per projection (``jax.lax.ragged_dot``
-  — rides the MXU with static [T*k, ...] shapes), scatter-add back with
+  — rides the MXU with static [T*k, ...] shapes), then gather each
+  token's k rows back by the sort's inverse and sum them in float32 under
   the renormalized router weights.  FLOPs scale with top-k, not E.
 - **Expert parallel**: ``shard_map`` over the ``ep`` mesh axis with the
   stacked expert weights sharded on their leading E axis.  Activations are
   replicated across ep; each shard computes only the pairs routed to its
-  local experts (masked to zero-weight elsewhere — pair count stays the
+  local experts (masked out of the sum elsewhere — pair count stays the
   static T*k, so no capacity drops and numerics match the dense oracle
   exactly), and the partial outputs combine with one ``psum``.  This is
   the GSPMD-friendly analogue of the reference's all-to-all dispatch; the
@@ -101,6 +102,24 @@ def _grouped_mlp(xs, gate_up, down, group_sizes):
     return jax.lax.ragged_dot(h, down, group_sizes)
 
 
+def _combine(y, order, topk_w, held=None):
+    """Expert-sorted rows ``y [T*k, H]`` -> one float32 row a token.  Pair
+    ``t*k + j`` sits at sorted row ``inv[t*k + j]`` (``inv`` the inverse of
+    ``order``, itself a sort: of a permutation, the cheapest way to it on
+    the chip), so one gather by ``inv`` brings each token's k rows together,
+    ``[T, k, H]``; they are weighted by ``topk_w [T, k]`` where it lies and
+    summed over k in float32, one rounding left to the caller
+    (``scripts/moe_combine_sweep.py`` has the forms this was chosen from).
+    ``held`` ([T, k] bool, or None for all) masks the VALUE of a pick whose
+    expert is not here: its row of ``y`` is whatever the grouped matmul
+    left behind its last group, and ``NaN * 0`` is ``NaN``."""
+    t, k = topk_w.shape
+    rows = y[jnp.argsort(order)].reshape(t, k, -1)
+    if held is not None:
+        rows = jnp.where(held[:, :, None], rows, 0)
+    return jnp.sum(rows.astype(jnp.float32) * topk_w[:, :, None], axis=1)
+
+
 def routed_moe(
     x: jax.Array,          # [T, hidden]
     router_w: jax.Array,   # [hidden, E]
@@ -111,22 +130,18 @@ def routed_moe(
     **router,  # scoring / bias / scale / float32_logits of ``router_topk``
 ) -> jax.Array:
     """Top-k routed MoE on one shard: sort pairs by expert, grouped
-    matmul, weighted scatter-add."""
-    t, hidden = x.shape
+    matmul, each token's k rows gathered back and summed under the
+    router's weights in float32 (``_combine``)."""
     e = gate_up.shape[0]
     k = num_experts_per_tok
     topk_idx, topk_w = router_topk(x, router_w, k, renormalize, **router)
 
     flat_e = topk_idx.reshape(-1)                    # [T*k]
-    flat_w = topk_w.reshape(-1)                      # [T*k]
     order = jnp.argsort(flat_e)                      # stable
-    token_of = order // k                            # source token per pair
-    xs = x[token_of]                                 # [T*k, hidden]
+    xs = x[order // k]                               # [T*k, hidden]
     group_sizes = jnp.bincount(flat_e, length=e)
     y = _grouped_mlp(xs, gate_up, down, group_sizes)  # [T*k, hidden]
-    y = y * flat_w[order][:, None].astype(y.dtype)
-    out = jnp.zeros((t, hidden), y.dtype).at[token_of].add(y)
-    return out.astype(x.dtype)
+    return _combine(y, order, topk_w).astype(x.dtype)
 
 
 def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
@@ -139,10 +154,15 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     wherever they live; a pick that lives elsewhere adds nothing here and
     nothing stands in for it.  The pair count stays the static T*k: the
     pairs of absent experts sort behind the last held expert's group,
-    where the grouped matmul computes no row, and are masked out of the
-    sum.  This is one chip's share of an expert-parallel deployment (the
-    model-configs cut) and, followed by a psum, the body of every ``ep``
-    shard.  ``router`` is ``router_topk``'s scoring, bias and scale: the
+    where the grouped matmul's rows are computed for nothing or left
+    undefined (the group sizes stop short of them), and their VALUES are
+    masked out of the sum.  The sum is still the scatter-add
+    ``routed_moe`` had until PR 43, in ``y``'s dtype: with ``_combine``
+    in its place, in five forms of it, the 2 048-row step of
+    dots3-note-prev never returned on the v5e (libtpu 0.0.34; PERF.md,
+    PR 43), and no share's cell has been seen to run it.  This is one
+    chip's share of an expert-parallel deployment (the model-configs
+    cut) and, followed by a psum, the body of every ``ep`` shard.  ``router`` is ``router_topk``'s scoring, bias and scale: the
     share of a sigmoid router with its correction bias routes as the
     whole model does."""
     e_held = gate_up.shape[0]
@@ -188,7 +208,7 @@ def _moe_a2a_shard(x, router_w, gate_up, down, k: int, capacity: int,
     Per-shard grouped-matmul rows = ep*C ≈ T*k*factor/ep — compute
     scales DOWN with ep (the property the masked-psum variant lacks;
     VERDICT r2 weak #9).  Pairs beyond a bucket's capacity are dropped
-    (combine weight 0); capacity_factor sizes the headroom.
+    (masked out of the combine); capacity_factor sizes the headroom.
     """
     ep = jax.lax.axis_size("ep")
     e_local = gate_up.shape[0]
@@ -197,7 +217,6 @@ def _moe_a2a_shard(x, router_w, gate_up, down, k: int, capacity: int,
 
     topk_idx, topk_w = router_topk(x, router_w, k, renormalize)
     flat_e = topk_idx.reshape(-1)                  # [P] global expert ids
-    flat_w = topk_w.reshape(-1)
     dest = flat_e // e_local                       # destination shard
     local_e = flat_e % e_local
 
@@ -237,10 +256,8 @@ def _moe_a2a_shard(x, router_w, gate_up, down, k: int, capacity: int,
         y.reshape(ep, capacity, hidden), "ep", 0, 0)  # back at sources
 
     got = ret[sdest, slot]                           # [P, H] per pair
-    w = jnp.where(keep, flat_w[order], 0.0)
-    out = jnp.zeros((tl, hidden), got.dtype).at[tok_of].add(
-        got * w[:, None].astype(got.dtype))
-    return out.astype(x.dtype)
+    kept = jnp.zeros_like(keep).at[order].set(keep).reshape(tl, k)
+    return _combine(got, order, topk_w, kept).astype(x.dtype)
 
 
 def routed_moe_ep_a2a(x, router_w, gate_up, down,
