@@ -1,6 +1,7 @@
 """ops/gated_delta_rule.py: the chunked form over a packed ragged batch
-against the token-by-token recurrence, and the convolution with its carried
-state against one pass over the whole sequence."""
+(the ``jax.numpy`` form, and the kernel in interpret mode at a head of 128
+lanes) against the token-by-token recurrence, and the convolution with its
+carried state against one pass over the whole sequence."""
 
 import jax
 import jax.numpy as jnp
@@ -9,16 +10,25 @@ import pytest
 
 from vllm_omni_tpu.ops import gated_delta_rule as gdr
 
-HV, DK, DV = 4, 16, 32
+#: (Hv, Dk, Dv) a form is tested at: the kernel takes heads of whole
+#: 128-lane tiles, all of them in a grid step or eight
+DIMS = {"jax_numpy": (4, 16, 32), "kernel": (2, 128, 128),
+        "kernel_two_blocks": (16, 128, 128)}
+FORMS = {
+    "jax_numpy": jax.jit(gdr.gated_delta_rule_ragged),
+    "kernel": lambda *a: gdr.gated_delta_rule_ragged(*a, use_pallas=True),
+}
+FORMS["kernel_two_blocks"] = FORMS["kernel"]
 
 
-def _inputs(key, t, decay=1.0):
+def _inputs(key, t, decay=1.0, dims=DIMS["jax_numpy"]):
+    hv, dk, dv = dims
     ks = jax.random.split(key, 5)
-    q = gdr.l2_normalize(jax.random.normal(ks[0], (t, HV, DK))) * DK ** -0.5
-    k = gdr.l2_normalize(jax.random.normal(ks[1], (t, HV, DK)))
-    v = jax.random.normal(ks[2], (t, HV, DV))
-    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (t, HV)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (t, HV)))
+    q = gdr.l2_normalize(jax.random.normal(ks[0], (t, hv, dk))) * dk ** -0.5
+    k = gdr.l2_normalize(jax.random.normal(ks[1], (t, hv, dk)))
+    v = jax.random.normal(ks[2], (t, hv, dv))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (t, hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (t, hv)))
     return q, k, v, g, beta
 
 
@@ -37,39 +47,64 @@ def _pack(lens, align=8):
 TOL = dict(atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("lens", [[150, 1, 64, 7], [1, 1, 1], [65], [200, 3]])
-def test_chunked_form_agrees_with_the_recurrence_on_ragged_segments(lens):
+@pytest.mark.parametrize("form,lens", [
+    ("jax_numpy", [150, 1, 64, 7]), ("jax_numpy", [1, 1, 1]),
+    ("jax_numpy", [65]), ("jax_numpy", [200, 3]),
+    # the kernel: every length around a chunk's 64 in one batch; a decode
+    # row between two sequences of several chunks; decode rows alone; a
+    # sequence with no token between two that have some; a grid over heads
+    ("kernel", [1, 8, 63, 64, 65, 200]), ("kernel", [150, 1, 64, 7]),
+    ("kernel", [1, 1, 1]), ("kernel", [70, 0, 3]),
+    ("kernel_two_blocks", [65, 1, 70]),
+])
+def test_chunked_form_agrees_with_the_recurrence_on_ragged_segments(
+        form, lens):
     """Lengths that are no multiple of 64, single-token rows between
-    prefill rows, a padded tail, and more sequence slots than sequences;
-    every sequence starts from a state of its own."""
+    prefill rows, a padded tail (so chunk slots that no chunk uses), and
+    more sequence slots than sequences; every sequence starts from a state
+    of its own.  The kernel also agrees with the ``jax.numpy`` form it
+    replaces where the platform compiles it."""
+    dims = DIMS[form]
     cu = _pack(lens)
     s_max = len(lens) + 2
     t = cu[-1] + 16
-    q, k, v, g, beta = _inputs(jax.random.PRNGKey(len(lens)), t)
-    state = jax.random.normal(jax.random.PRNGKey(7), (s_max, HV, DK, DV))
+    q, k, v, g, beta = _inputs(jax.random.PRNGKey(len(lens)), t, dims=dims)
+    state = jax.random.normal(jax.random.PRNGKey(7), (s_max, *dims))
     cu_arr = jnp.asarray(cu + [cu[-1]] * (s_max + 1 - len(cu)), jnp.int32)
     q_lens = jnp.asarray(lens + [0] * (s_max - len(lens)), jnp.int32)
-    o, new_state = jax.jit(gdr.gated_delta_rule_ragged)(
-        q, k, v, g, beta, state, cu_arr, q_lens)
+    o, new_state = FORMS[form](q, k, v, g, beta, state, cu_arr, q_lens)
+    if form != "jax_numpy":
+        plain_o, plain_s = FORMS["jax_numpy"](
+            q, k, v, g, beta, state, cu_arr, q_lens)
+        np.testing.assert_allclose(new_state, plain_s, **TOL)
     for i, n in enumerate(lens):
         sl = slice(cu[i], cu[i] + n)
+        if n == 0:
+            np.testing.assert_array_equal(new_state[i], state[i])
+            continue
         want_o, want_s = gdr.gated_delta_rule_scan(
             q[sl], k[sl], v[sl], g[sl], beta[sl], state[i])
         np.testing.assert_allclose(o[sl], want_o, **TOL)
         np.testing.assert_allclose(new_state[i], want_s, **TOL)
+        if form != "jax_numpy":
+            np.testing.assert_allclose(o[sl], plain_o[sl], **TOL)
     # a slot with no sequence keeps its state to the bit
     np.testing.assert_array_equal(new_state[len(lens):], state[len(lens):])
+    assert bool(jnp.isfinite(o).all())
 
 
-def test_a_chunk_whose_decay_underflows_gives_zero_not_nan():
+@pytest.mark.parametrize("form", ["jax_numpy", "kernel"])
+def test_a_chunk_whose_decay_underflows_gives_zero_not_nan(form):
     """exp(cumsum(g)) over 64 tokens of g = -8 is exp(-512): zero in
     float32.  The chunked form only ever exponentiates differences, so the
     outputs stay finite and equal the recurrence's."""
     lens = [130]
-    q, k, v, g, beta = _inputs(jax.random.PRNGKey(3), 136, decay=8.0)
+    dims = DIMS[form]
+    q, k, v, g, beta = _inputs(jax.random.PRNGKey(3), 136, decay=8.0,
+                               dims=dims)
     assert float(jnp.sum(g[:64, 0])) < -300
-    state = jnp.ones((1, HV, DK, DV))
-    o, new_state = gdr.gated_delta_rule_ragged(
+    state = jnp.ones((1, *dims))
+    o, new_state = FORMS[form](
         q, k, v, g, beta, state, jnp.asarray([0, 136], jnp.int32),
         jnp.asarray(lens, jnp.int32))
     want_o, want_s = gdr.gated_delta_rule_scan(
@@ -77,6 +112,63 @@ def test_a_chunk_whose_decay_underflows_gives_zero_not_nan():
     assert bool(jnp.all(jnp.isfinite(o)))
     np.testing.assert_allclose(o[:130], want_o, **TOL)
     np.testing.assert_allclose(new_state[0], want_s, **TOL)
+
+
+@pytest.mark.parametrize("form", ["jax_numpy", "kernel"])
+def test_a_run_of_one_key_is_solved_as_the_recurrence_solves_it(form):
+    """One token repeated gives every key of a chunk the same direction:
+    with beta near 1 and hardly any decay ``A`` is all ones under the
+    diagonal, its powers grow to 1e17 and ``(I + A)^-1 = (I - A)(I + A^2)
+    ... (I + A^32)``, exact on paper, is off by 1e10 in float32.
+    Substitution and the block merges stay at the recurrence's rounding."""
+    dims = DIMS[form]
+    t, n = 136, 130
+    q, k, v, _, _ = _inputs(jax.random.PRNGKey(5), t, dims=dims)
+    k = jnp.broadcast_to(k[:1], k.shape)
+    g = jnp.full((t, dims[0]), -1e-3)
+    beta = jnp.full((t, dims[0]), 0.999)
+    state = jax.random.normal(jax.random.PRNGKey(6), (1, *dims))
+    o, new_state = FORMS[form](
+        q, k, v, g, beta, state, jnp.asarray([0, t], jnp.int32),
+        jnp.asarray([n], jnp.int32))
+    want_o, want_s = gdr.gated_delta_rule_scan(
+        q[:n], k[:n], v[:n], g[:n], beta[:n], state[0])
+    np.testing.assert_allclose(o[:n], want_o, **TOL)
+    np.testing.assert_allclose(new_state[0], want_s, **TOL)
+
+
+@pytest.mark.parametrize("dims,state_dtype,chunk,why", [
+    (DIMS["kernel"], jnp.float32, 64, ""),
+    (DIMS["jax_numpy"], jnp.float32, 64, "off the 128-lane tiling"),
+    (DIMS["kernel"], jnp.bfloat16, 64, "not float32"),
+    (DIMS["kernel"], jnp.float32, 48, "no power of two"),
+])
+def test_where_pallas_compiles_only_a_shape_the_kernel_refuses_falls_back(
+        dims, state_dtype, chunk, why, monkeypatch):
+    """On a platform that compiles Pallas the kernel takes every call it
+    can; one it cannot runs the ``jax.numpy`` form and is counted as a
+    native fallback of ``"gated_delta_rule"`` with the reason."""
+    from vllm_omni_tpu.ops import _dispatch
+
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    monkeypatch.setattr(_dispatch, "native_fallbacks",
+                        type(_dispatch.native_fallbacks)())
+    taken = []
+    monkeypatch.setattr(gdr, "_chunked_kernel",
+                        lambda *a, **kw: taken.append(kw) or "kernel")
+    q, k, v, g, beta = _inputs(jax.random.PRNGKey(1), 72, dims=dims)
+    state = jnp.zeros((1, *dims), state_dtype)
+    got = gdr.gated_delta_rule_ragged(
+        q, k, v, g, beta, state, jnp.asarray([0, 72], jnp.int32),
+        jnp.asarray([70], jnp.int32), chunk=chunk)
+    reasons = [r for (op, r) in _dispatch.native_fallbacks
+               if op == "gated_delta_rule"]
+    if not why:
+        assert got == "kernel" and taken == [{"chunk": chunk}]
+        assert not reasons
+    else:
+        assert not taken and got[0].shape == (72, dims[0], dims[2])
+        assert len(reasons) == 1 and why in reasons[0]
 
 
 def _conv_whole(x, w):

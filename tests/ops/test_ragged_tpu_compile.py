@@ -317,6 +317,35 @@ def test_gated_delta_net_compiles_for_v5e(one_chip):
         *mixed(s_max), *states(s_max)).compile()
 
 
+@pytest.mark.parametrize("t", [2048, 4096])
+def test_gated_delta_rule_kernel_compiles_for_v5e(t, one_chip, monkeypatch):
+    """The chunked rule forced through its kernel at Qwen3-Next's widths
+    (32 value heads of 128 x 128, 32 sequences): ONE Mosaic call holds the
+    products, the inverse and the state's update, so the compiled text has
+    no triangular-solve custom call and no loop, and the chunks' tensors
+    outside the kernel stay far under what the ``jax.numpy`` form keeps."""
+    from vllm_omni_tpu.ops import gated_delta_rule as gdr
+
+    monkeypatch.setattr(gdr, "interpret_flag", lambda: False)
+    s_max, hv, d = 32, 32, 128
+
+    def sds(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert not gdr.kernel_reason(sds((t, hv, d)), sds((t, hv, d)),
+                                 sds((s_max, hv, d, d)))
+    compiled = jax.jit(
+        lambda *a: gdr.gated_delta_rule_ragged(*a, use_pallas=True)).lower(
+        sds((t, hv, d)), sds((t, hv, d)), sds((t, hv, d)), sds((t, hv)),
+        sds((t, hv)), sds((s_max, hv, d, d)), sds((s_max + 1,), jnp.int32),
+        sds((s_max,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "triangular" not in text.lower()
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 def test_sampler_keeps_its_selection_a_selection_on_v5e(one_chip):
     """``sample_tokens`` at the cells' shape compiles for a v5e with its
     bounded selection as the TopK custom call and ONE sort, the

@@ -23,10 +23,16 @@ exponent is a difference ``cumsum(g)_i - cumsum(g)_j`` with ``i >= j``, so
 nothing overflows and what underflows is a product that is zero in the
 recurrence too.
 
-There is no Pallas kernel yet: the ``jax.numpy`` forms below are the op on
-every platform (ISSUE 28 leaves the kernel to the PR whose trace shows
-these bound by launches or relayouts), so nothing here counts as a native
-fallback in ops/_dispatch.py.
+Where the platform compiles Pallas the chunked form is ONE kernel
+(``_chunked_kernel``: grid heads x chunks, a chunk's window of packed rows
+read where it lies, the chunk's tensors and its sequence's state in VMEM
+from ``K K^T`` to the state's update, the unit-lower-triangular inverse
+built from row operations and products); the ``jax.numpy`` form (a
+triangular solve, a scan of ``einsum``s) is the op elsewhere and what the
+kernel is tested against.  ops/_dispatch.py decides, and a shape the kernel
+cannot take (``kernel_reason``) is counted there as a native fallback of
+``"gated_delta_rule"``.  The convolution and the one-token form are
+``jax.numpy`` on every platform.
 
 Layouts: the convolution's state is ``[rows, K - 1, C]`` (the last ``K - 1``
 inputs of the sequence, oldest first; channels last so that the 8192
@@ -36,8 +42,14 @@ the current token; the recurrent state is ``[rows, Hv, Dk, Dv]`` float32.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
 
 #: tokens a chunk of the chunked form holds
 CHUNK = 64
@@ -52,12 +64,16 @@ def l2_normalize(x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def token_segments(cu_q_lens: jax.Array, q_lens: jax.Array, t: int):
+def token_segments(cu_q_lens: jax.Array, q_lens: jax.Array, t: int,
+                   method: str = "scan"):
     """For every packed row: (its sequence, its offset in the sequence's
-    segment, whether it is a real token of it)."""
+    segment, whether it is a real token of it).  ``method`` is
+    ``jnp.searchsorted``'s: its default is a loop, ``"compare_all"`` one
+    comparison of all with all (a few thousand pairs)."""
     s = q_lens.shape[0]
     rows = jnp.arange(t, dtype=jnp.int32)
-    seg = jnp.clip(jnp.searchsorted(cu_q_lens[1:], rows, side="right"),
+    seg = jnp.clip(jnp.searchsorted(cu_q_lens[1:], rows, side="right",
+                                    method=method),
                    0, s - 1).astype(jnp.int32)
     local = rows - cu_q_lens[seg]
     return seg, local, (local >= 0) & (local < q_lens[seg])
@@ -166,7 +182,7 @@ def _mm(spec, a, b):
 
 
 def gated_delta_rule_ragged(q, k, v, g, beta, state, cu_q_lens, q_lens,
-                            chunk: int = CHUNK):
+                            chunk: int = CHUNK, use_pallas=None):
     """The chunked form over a packed ragged batch.
 
     q, k [T, Hv, Dk] (normalised, q scaled; key heads already repeated to
@@ -178,7 +194,16 @@ def gated_delta_rule_ragged(q, k, v, g, beta, state, cu_q_lens, q_lens,
     Every sequence's tokens are regrouped into chunks of their own (at most
     ``T / chunk + S`` of them, a static count); one scan then walks the
     chunks in order, reading and writing the state of the chunk's
-    sequence."""
+    sequence.  Where the platform compiles Pallas the same chunks run
+    through ``_chunked_kernel`` (``use_pallas`` None: ops/_dispatch.py
+    decides); the ``jax.numpy`` form below is the op elsewhere, and what
+    the kernel is tested against."""
+    if use_pallas is None:
+        use_pallas = auto_use_pallas(
+            "gated_delta_rule", kernel_reason(q, v, state, chunk))
+    if use_pallas:
+        return _chunked_kernel(q, k, v, g, beta, state, cu_q_lens, q_lens,
+                               chunk=chunk)
     f32 = jnp.float32
     t, hv, dk = q.shape
     dv = v.shape[-1]
@@ -236,6 +261,267 @@ def gated_delta_rule_ragged(q, k, v, g, beta, state, cu_q_lens, q_lens,
     seg, off, _ = token_segments(cu_q_lens, q_lens, t)
     at = jnp.clip(first[seg] + off // chunk, 0, n - 1)
     return jnp.moveaxis(o_c, 2, 1)[at, off % chunk], state
+
+
+# ------------------------------------------------- the rule as one kernel
+#: rows of a diagonal block of ``I + A`` that substitution inverts; the
+#: blocks between them are merged by matrix products
+SUBSTITUTED = 16
+#: value heads a grid step of the kernel takes (a multiple of 8, or all of
+#: them): each is a chain of small dependent products, and the heads of a
+#: step are what the scheduler has to fill one chain's waits with
+#: another's work
+HEAD_BLOCK = 8
+
+
+def kernel_reason(q, v, state, chunk: int = CHUNK) -> str:
+    """Why the kernel cannot take this call ("" when it can): a head's
+    rows are whole 128-lane tiles, the state is float32 and a chunk is
+    diagonal blocks of ``SUBSTITUTED`` rows merged pairwise."""
+    dk, dv = q.shape[-1], v.shape[-1]
+    if dk % 128 or dv % 128:
+        return f"a head of [{dk}, {dv}] is off the 128-lane tiling"
+    if state.dtype != jnp.float32:
+        return f"the state is {state.dtype.name}, not float32"
+    if chunk < SUBSTITUTED or chunk & (chunk - 1):
+        return f"a chunk of {chunk} tokens is no power of two >= 16"
+    return ""
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), precision=PRECISION,
+        preferred_element_type=jnp.float32)
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for a strictly lower triangular ``a`` [C, C], from
+    products and row operations alone (Mosaic has no triangular solve):
+    each diagonal block of ``SUBSTITUTED`` rows by forward substitution
+    (column ``j`` of the block times the finished row ``j``, taken from
+    every row below it), then blocks merged pairwise by ``[[P, 0], [L,
+    Q]]^-1 = [[P^-1, 0], [-Q^-1 L P^-1, Q^-1]]`` (what flash-linear-
+    attention's ``solve_tril`` does)."""
+    c = a.shape[0]
+    nb, sub = c // SUBSTITUTED, SUBSTITUTED
+    # all diagonal blocks at once, [nb, sub, .]: ``diag`` their entries of
+    # ``a``, ``x`` their rows of the inverse (the identity to begin with)
+    diag = jnp.stack([a[i * sub:(i + 1) * sub, i * sub:(i + 1) * sub]
+                      for i in range(nb)])
+    shape = (nb, sub, c)
+    at = jax.lax.broadcasted_iota(jnp.int32, shape, 2) \
+        - sub * jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    x = (at == jax.lax.broadcasted_iota(jnp.int32, shape, 1)).astype(
+        jnp.float32)
+    for j in range(sub - 1):
+        x = x - diag[:, :, j:j + 1] * x[:, j:j + 1, :]
+    blocks = [x[i] for i in range(nb)]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    w = SUBSTITUTED
+    while w < c:
+        bit = w.bit_length() - 1
+        # the [w, w] block under the diagonal of every [2w, 2w] block
+        under = ((row >> (bit + 1)) == (col >> (bit + 1))) \
+            & (((row >> bit) & 1) == 1) & (((col >> bit) & 1) == 0)
+        # only the rows of every second block change: the MXU's time goes
+        # by the rows it is fed, so feed it those alone
+        lower = jnp.concatenate(blocks[1::2], axis=0)
+        inv = jnp.concatenate(blocks, axis=0)
+        lower = lower - _dot(
+            _dot(lower, jnp.where(under, a, 0.0), ((1,), (0,))), inv,
+            ((1,), (0,)))
+        blocks = [jnp.concatenate([blocks[2 * i], lower[i * w:(i + 1) * w]],
+                                  axis=0) for i in range(len(blocks) // 2)]
+        w *= 2
+    return blocks[0]
+
+
+def _chunk_kernel(
+    # scalar prefetch (SMEM), an entry a chunk
+    seq_ref,     # the chunk's sequence (read by the state's index map)
+    lo_ref,      # the packed row its window of C rows starts at (read by
+                 # the rows' index map)
+    blk_ref,     # its block of the per-chunk tables (an unused chunk: the
+                 # last real one's, so that nothing moves for it)
+    lead_ref,    # rows of the window in front of the chunk's first token
+    count_ref,   # its real tokens; 0: the chunk does not exist
+    first_ref,   # 1: the sequence's first chunk of the step
+    # inputs, a block of ``hb`` heads
+    q_ref,       # [C, hb, Dk]: the window's packed rows as they lie
+    k_ref,       # [C, hb, Dk]
+    v_ref,       # [C, hb, Dv]
+    g_ref,       # [hb, C]: cumsum(g) over the chunk's real tokens
+    b_ref,       # [hb, C]: beta, 0 where there is no token
+    glast_ref,   # [hb, max(Dk, Dv)]: cumsum(g) at the chunk's end
+    state_ref,   # [hb, Dk, Dv]: the sequence's state before the step
+    # outputs
+    o_ref,       # [C, hb * Dv]
+    state_out,   # [hb, Dk, Dv]: aliased to the states; it stays in VMEM
+                 # from a sequence's first chunk to its last
+):
+    del seq_ref, lo_ref, blk_ref
+    c = pl.program_id(1)
+    count, lead = count_ref[c], lead_ref[c]
+    chunk = q_ref.shape[0]
+    hb, dk, dv = state_ref.shape
+
+    @pl.when(first_ref[c] == 1)
+    def _():
+        state_out[...] = state_ref[...]
+
+    @pl.when(count > 0)
+    def _():
+        row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        at = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) - lead
+        live = (at >= 0) & (at < count)
+        nt, nn = ((1,), (1,)), ((1,), (0,))
+
+        def column(r):  # [1, C] along the lanes -> [C, 1] down the sublanes
+            return jnp.sum(jnp.where(row == col, r, 0.0), axis=1,
+                           keepdims=True)
+
+        def head(h, _):
+            # a row that is no token of the chunk is a token that changes
+            # nothing: k = v = 0, beta = 0, g = 0
+            q = jnp.where(live, q_ref[:, h, :], 0.0)
+            k = jnp.where(live, k_ref[:, h, :], 0.0)
+            v = jnp.where(live, v_ref[:, h, :], 0.0)
+            g_row = g_ref[pl.ds(h, 1), :]                   # [1, C]
+            gc, beta = column(g_row), column(b_ref[pl.ds(h, 1), :])
+            # exp(gc_i - gc_j) for i >= j: every exponent <= 0
+            decay = jnp.where(row >= col, jnp.exp(jnp.minimum(
+                gc - g_row, 0.0)), 0.0)
+            kb = k * beta
+            # [beta K; Q] K^T in one product: A above, Q K^T below
+            both = _dot(jnp.concatenate([kb, q], axis=0), k, nt)
+            a_mat = jnp.where(row > col, both[:chunk] * decay, 0.0)
+            attn = both[chunk:] * decay                     # diagonal kept
+            st = state_out[h]
+            # [beta K exp(gc); Q exp(gc)] S: what the state holds of the
+            # chunk's keys, and what the queries read of it
+            held = _dot(jnp.concatenate([kb, q], axis=0) * jnp.exp(
+                jnp.concatenate([gc, gc], axis=0)), st, nn)
+            # u - w S with u = X beta V, w = X beta K exp(gc), X = (I + A)^-1
+            v_new = _dot(_unit_lower_inverse(a_mat),
+                         v * beta - held[:chunk], nn)
+            o_ref[:, pl.ds(pl.multiple_of(h * dv, dv), dv)] = held[chunk:] \
+                + _dot(attn, v_new, nn)
+            g_last = glast_ref[pl.ds(h, 1), :]              # [1, lanes]
+            k_dec = k * jnp.exp(g_last[:, :dk] - gc)
+            state_out[h] = st * jnp.exp(g_last[:, :dv]) \
+                + _dot(k_dec, v_new, ((0,), (0,)))
+
+        # one trace of a head's program, written out ``hb`` times when it
+        # is lowered: the heads of a step are independent chains
+        jax.lax.fori_loop(0, hb, head, None, unroll=True)
+
+
+def _chunk_plan(cu_q_lens, q_lens, t: int, chunk: int):
+    """The chunks a step runs, as tables of ``ceil(t / chunk) + S``
+    entries (ops/ssd.py ``_chunk_plan`` is the model): for each its
+    sequence, its own index (an unused chunk: the last real one's), the
+    packed row its tokens start at, how many of them it holds (0: the
+    chunk does not exist) and whether it is its sequence's first; and
+    every sequence's first chunk.  Chunks of a sequence are adjacent."""
+    s = q_lens.shape[0]
+    per_seq = -(-q_lens // chunk)
+    ends = jnp.cumsum(per_seq)
+    total = ends[-1]
+    cid = jnp.arange(-(-t // chunk) + s, dtype=jnp.int32)
+    real = cid < total
+    cid = jnp.minimum(cid, jnp.maximum(total - 1, 0))
+    seq = jnp.clip(jnp.searchsorted(
+        ends, cid, side="right", method="compare_all"), 0, s - 1).astype(
+        jnp.int32)
+    offset = (cid - (ends[seq] - per_seq[seq])) * chunk     # in the sequence
+    count = jnp.where(real, jnp.clip(q_lens[seq] - offset, 0, chunk), 0)
+    first = jnp.concatenate(
+        [jnp.ones((1,), bool), seq[1:] != seq[:-1]]).astype(jnp.int32)
+    return (seq, cid, cu_q_lens[seq] + offset, count.astype(jnp.int32),
+            first, ends - per_seq)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "head_block"))
+def _chunked_kernel(q, k, v, g, beta, state, cu_q_lens, q_lens,
+                    chunk: int = CHUNK, head_block: int = HEAD_BLOCK):
+    """``gated_delta_rule_ragged`` as one ``pallas_call``: grid (blocks of
+    heads, chunks in order); a chunk's tensors and its sequence's state
+    stay in VMEM from the products ``K K^T`` and ``Q K^T`` through the
+    inverse to the state's update.  A chunk reads a window of ``chunk``
+    packed rows where they lie (the window of a chunk within ``chunk``
+    rows of the batch's end begins before it; the rows in front are
+    masked like those behind).  The state's block is found through the
+    chunk's sequence and aliased in to out: it is fetched when the
+    sequence changes, written back when the next one's chunk comes, and a
+    sequence with no token in the step is never touched."""
+    f32 = jnp.float32
+    hv, dk = q.shape[1:]
+    dv = v.shape[-1]
+    short = max(chunk - q.shape[0], 0)      # a batch under one window
+    q, k, v, g, beta = (
+        jnp.pad(a.astype(f32), ((0, short),) + ((0, 0),) * (a.ndim - 1))
+        for a in (q, k, v, g, beta))
+    t = q.shape[0]
+    # the heads of a window lie along its sublanes: whole tiles of 8, or all
+    hb = next((b for b in range(head_block - head_block % 8, 0, -8)
+               if hv % b == 0), hv)
+    nb = hv // hb
+    seq, blk, start, count, first, first_chunk = _chunk_plan(
+        cu_q_lens, q_lens, t, chunk)
+    n = seq.shape[0]
+    lo = jnp.clip(start, 0, t - chunk)
+    lead = start - lo
+    offs = jnp.arange(chunk, dtype=jnp.int32)[None, :]
+    rows = lo[:, None] + offs                               # [N, C]
+    valid = (offs >= lead[:, None]) & (offs < (lead + count)[:, None])
+
+    def by_block(a):  # [N, C, Hv] -> [N, Hv / hb, hb, C]
+        return jnp.transpose(a.reshape(n, chunk, nb, hb), (0, 2, 3, 1))
+
+    gc = jnp.cumsum(jnp.where(valid[..., None], g[rows], 0.0), axis=1)
+    bc = jnp.where(valid[..., None], beta[rows], 0.0)       # [N, C, Hv]
+    g_last = jnp.broadcast_to(
+        gc[:, -1].reshape(n, nb, hb, 1), (n, nb, hb, max(dk, dv)))
+
+    def window(d):  # element offsets: any packed row starts a window
+        return pl.BlockSpec(
+            (pl.Element(chunk), pl.Element(hb), pl.Element(d)),
+            lambda j, i, seq, lo, *_: (lo[i], j * hb, 0))
+
+    def table(*dims):
+        return pl.BlockSpec(
+            (None, None, *dims),
+            lambda j, i, seq, lo, blk, *_: (blk[i], j, 0, 0))
+
+    slot = pl.BlockSpec((None, hb, dk, dv),
+                        lambda j, i, seq, *_: (seq[i], j, 0, 0))
+    o_c, state = pl.pallas_call(
+        _chunk_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(nb, n),
+            in_specs=[window(dk), window(dk), window(dv), table(hb, chunk),
+                      table(hb, chunk), table(hb, max(dk, dv)), slot],
+            out_specs=[pl.BlockSpec(
+                (chunk, hb * dv),
+                lambda j, i, seq, lo, blk, *_: (blk[i], j)), slot]),
+        out_shape=[jax.ShapeDtypeStruct((n * chunk, hv * dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # operands count the six prefetched tables: the state is the 13th
+        input_output_aliases={12: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_flag(),
+        name="gated_delta_rule_chunks",
+    )(seq, lo, blk, lead, count, first, q, k, v, by_block(gc), by_block(bc),
+      g_last, state)
+    # back onto the packed rows; rows outside every segment read zeros
+    seg, off, real = token_segments(cu_q_lens, q_lens, t - short,
+                                    method="compare_all")
+    at = jnp.clip(first_chunk[seg] + off // chunk, 0, n - 1)
+    o = o_c[at * chunk + jnp.clip(off % chunk + lead[at], 0, chunk - 1)]
+    return jnp.where(real[:, None, None], o.reshape(-1, hv, dv), 0.0), state
 
 
 # ----------------------------------------------------------- the two steps
