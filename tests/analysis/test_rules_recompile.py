@@ -73,7 +73,7 @@ class R:
             self._run_jit("unified", (t,), lambda: 1)
 
     def dispatch(self, scheds):
-        b = self._decode_bucket(len(scheds))
+        b = _bucket(len(scheds), self._batch_buckets)
         self._run_jit("decode", (b,), lambda: 1)
         t = _bucket(sum(s.num_new_tokens for s in scheds),
                     self._token_buckets)

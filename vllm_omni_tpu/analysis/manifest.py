@@ -340,7 +340,7 @@ RECOMPILE: dict[str, tuple[str, ...]] = {
     "dispatch_fns": ("_run_jit",),
     # calls that BUCKET a raw count (their result is shape-safe even
     # when fed per-request ints)
-    "bucket_fns": ("_bucket", "_make_buckets", "_decode_bucket",
+    "bucket_fns": ("_bucket", "_make_buckets",
                    "_bucketed_prefill_shapes", "auto_blocks",
                    "auto_ragged_blocks"),
     # attributes holding precomputed bucket tables / static tile picks

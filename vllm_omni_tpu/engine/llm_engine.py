@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from vllm_omni_tpu.core.kv_cache_manager import KVCacheManager
+from vllm_omni_tpu.kvcache import layouts as cache_layouts
 from vllm_omni_tpu.kvcache.quant import (
     concat_payloads,
     payload_seq_len,
@@ -151,18 +152,6 @@ class EngineConfig:
     # the edge runs under the PR 3 retry policy + circuit breaker
     kv_offload_connector: Optional[str] = None
     kv_offload_connector_args: Optional[dict] = None
-    # pin the single-token decode family (sync and async dispatch)
-    # to the TOP batch bucket.  XLA fuses the
-    # [B]-leading decode matmuls differently per bucket shape, so the
-    # same request decoded next to 3 neighbours vs 7 can differ in the
-    # last bf16 bit — enough to flip a greedy argmax when logits run
-    # close.  With this on, a request's greedy stream is bit-stable
-    # under co-batch churn (arrivals, preemptions, offload restores),
-    # which is what lets an offloading engine be compared with a
-    # never-preempted oracle token for token.  Costs
-    # padded rows when the batch runs small; spec-decode verify and
-    # the unified token-packed path keep their dynamic shapes.
-    deterministic_decode: bool = False
     # precompile bucketed executables before serving: True warms every
     # decode batch bucket; a list of (batch, seq_len) pairs additionally
     # warms those prefill shapes.  A shape-cache miss mid-traffic stalls
@@ -264,9 +253,20 @@ class LLMEngine:
             # above: the caller may build other engines from the object)
             config = dataclasses.replace(
                 config, async_scheduling=config.worker_type == "ar")
-        self._refuse_for_recurrent(model_cfg, config, draft_fn)
-        self._refuse_for_window(model_cfg, config, draft_fn)
-        self._refuse_for_latent(model_cfg, config, draft_fn)
+        # what this model's cache layouts cannot carry is refused here, by
+        # name, before anything is built (kvcache/layouts.py)
+        refused = cache_layouts.refusal(model_cfg, [
+            mechanism for mechanism, asked in (
+                (cache_layouts.INT8, config.kv_cache_dtype == "int8"),
+                (cache_layouts.TRANSFER, config.kv_transfer is not None
+                 or config.engine_role != "colocated"),
+                (cache_layouts.OFFLOAD, config.kv_offload),
+                (cache_layouts.SPECULATIVE,
+                 config.num_speculative_tokens > 0 or draft_fn is not None),
+                (cache_layouts.TENSOR_PARALLEL,
+                 config.tensor_parallel_size > 1)) if asked])
+        if refused:
+            raise ValueError(refused)
         self.config = config
         self.eos_token_id = eos_token_id
         # tiered KV offload: the cold-side store + break-even policy
@@ -461,9 +461,7 @@ class LLMEngine:
                 max_model_len=config.max_model_len, dtype=config.dtype,
                 collect_hidden=config.collect_hidden, seed=config.seed,
                 max_num_seqs=config.max_num_seqs, mesh=mesh,
-                async_scheduling=config.async_scheduling,
                 max_num_batched_tokens=config.max_num_batched_tokens,
-                deterministic_decode=config.deterministic_decode,
                 num_window_pages=num_window_pages,
             )
         # the host's phases on the host's clock (introspection/
@@ -580,39 +578,6 @@ class LLMEngine:
                 "serving", n)
 
     @staticmethod
-    def _refuse_for_recurrent(model_cfg, config: "EngineConfig",
-                              draft_fn) -> None:
-        """A model with recurrent layers keeps part of every sequence in
-        a state slot (docs/kv_cache.md).  Whatever moves, copies or rolls
-        back pages alone would serve it wrongly, so each such mechanism
-        is refused here, by name, before anything is built; prefix hits
-        are refused one by one in ``KVCacheManager.match_prefix`` and
-        preemption recomputes from a zeroed slot.  State snapshots lift
-        these (ROADMAP, Reach)."""
-        if not getattr(model_cfg, "num_state_layers", 0):
-            return
-        refused = []
-        if config.kv_offload:
-            refused.append("kv_offload (parking / tiered offload of pages)")
-        if config.kv_transfer is not None or config.engine_role != "colocated":
-            refused.append("kv_transfer (inject_kv / extract_kv; engine_role "
-                           f"{config.engine_role!r})")
-        if config.num_speculative_tokens > 0 or draft_fn is not None:
-            refused.append("speculative decoding (verify rows cannot roll "
-                           "a recurrent state back)")
-        if config.kv_cache_dtype == "int8":
-            refused.append("kv_cache_dtype int8 (no quantized layout for "
-                           "the recurrent state)")
-        if config.tensor_parallel_size > 1:
-            refused.append("tensor_parallel_size > 1 (no sharded layout "
-                           "for the state cache)")
-        if refused:
-            raise ValueError(
-                "a model with recurrent (linear-attention) layers keeps "
-                "per-sequence state beside its KV pages, which these "
-                "mechanisms do not carry yet: " + "; ".join(refused))
-
-    @staticmethod
     def _kinds_of(model_cfg, config: "EngineConfig", num_pages: int,
                   itemsize: int) -> dict:
         """{layer kind: layers, row widths, bytes a cached token a layer,
@@ -644,75 +609,6 @@ class LLMEngine:
                     * (layout.width + index)),
                 "pages_total": pages}
         return kinds
-
-    @staticmethod
-    def _refuse_for_window(model_cfg, config: "EngineConfig",
-                           draft_fn) -> None:
-        """A model with sliding-window layers gives a page of the window
-        kind back once it slid out of reach (docs/kv_cache.md, "Two kinds
-        of page"), so whatever needs a sequence's whole past in its pages,
-        or rolls a sequence back, is refused here by name; prefix hits are
-        refused one by one in ``KVCacheManager.match_prefix`` and
-        preemption recomputes from position 0."""
-        if not getattr(model_cfg, "num_window_layers", 0):
-            return
-        refused = []
-        if config.kv_offload:
-            refused.append("kv_offload (a parked run would lack the window "
-                           "pages that were given back)")
-        if config.kv_transfer is not None or config.engine_role != "colocated":
-            refused.append("kv_transfer (the payload carries one kind of "
-                           f"page; engine_role {config.engine_role!r})")
-        if config.num_speculative_tokens > 0 or draft_fn is not None:
-            refused.append("speculative decoding (a rejected draft rolls "
-                           "back below pages already given back)")
-        if config.kv_cache_dtype == "int8":
-            refused.append("kv_cache_dtype int8 (no quantized layout for "
-                           "the window kind's rows)")
-        if config.tensor_parallel_size > 1:
-            refused.append("tensor_parallel_size > 1 (no sharded layout "
-                           "for the window kind's pool)")
-        if refused:
-            raise ValueError(
-                "a model with sliding-window layers keeps two kinds of "
-                "page, which these mechanisms do not carry yet: "
-                + "; ".join(refused))
-
-    @staticmethod
-    def _refuse_for_latent(model_cfg, config: "EngineConfig",
-                           draft_fn) -> None:
-        """A model with multi-head latent attention keeps one shared pool
-        a layer, a row of latent and rotary key a token, where the other
-        models keep a K and a V pool of per-head rows (docs/kv_cache.md).
-        Pages stay pages (page tables, prefix hits, preemption and chunked
-        prefill carry it unchanged); whatever reads or writes a page's
-        CONTENTS by the (K, V) layout is refused here, by name, before
-        anything is built (ROADMAP, Reach)."""
-        layout = getattr(model_cfg, "kv_layout", None)
-        if layout is None or layout.kind != "latent":
-            return
-        refused = []
-        if config.kv_cache_dtype == "int8":
-            refused.append("kv_cache_dtype int8 (no quantized layout for "
-                           "the latent row)")
-        if config.kv_transfer is not None or config.engine_role != "colocated":
-            refused.append("kv_transfer (the inject_kv / extract_kv payload "
-                           "is (K, V) per head; engine_role "
-                           f"{config.engine_role!r})")
-        if config.kv_offload:
-            refused.append("kv_offload (parking / tiered offload extract "
-                           "the same payload)")
-        if config.tensor_parallel_size > 1:
-            refused.append("tensor_parallel_size > 1 (one latent kv head "
-                           "serves every query head: no sharded layout)")
-        if config.num_speculative_tokens > 0 or draft_fn is not None:
-            refused.append("speculative decoding (the verify path over the "
-                           "latent pool is not built)")
-        if refused:
-            raise ValueError(
-                "a model with multi-head latent attention keeps one latent "
-                "pool a layer, which these mechanisms do not carry yet: "
-                + "; ".join(refused))
 
     # ------------------------------------------------------------- warmup
     def warmup(self, prefill_shapes=(), progress_fn=None) -> int:

@@ -115,13 +115,6 @@ def _add_common(p: argparse.ArgumentParser):
                           "adopt streamed KV and resume as decode; "
                           "colocated (default) is the classic single-"
                           "engine shape")
-    eng.add_argument("--deterministic-decode", action="store_true",
-                     default=None,
-                     help="pin decode batches to the top bucket so a "
-                          "request's greedy stream is bit-stable under "
-                          "co-batch churn (arrivals, preemptions, "
-                          "offload restores); costs padded rows when "
-                          "the batch runs small")
     p.add_argument(
         "--stats-path", default=None, metavar="PREFIX",
         help="stream per-stage + E2E stats to PREFIX.*.stats.jsonl")
@@ -146,7 +139,7 @@ _ENTRY_FLAGS = ("tensor_parallel_size", "max_model_len", "max_num_seqs",
                 "kv_offload_policy",
                 "kv_host_tier_bytes", "kv_offload_connector",
                 "slo_ttft_ms", "slo_tpot_ms", "max_queue_depth",
-                "wfq_scheduling", "engine_role", "deterministic_decode")
+                "wfq_scheduling", "engine_role")
 
 
 def _stage_overrides(args) -> dict:

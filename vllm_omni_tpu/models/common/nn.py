@@ -55,6 +55,13 @@ def linear(p, x):
     return y
 
 
+def out_proj(o, w, residual_in_fp32: bool):
+    """A mixer's output projection, in the residual stream's precision."""
+    if residual_in_fp32:
+        return jnp.dot(o, w, preferred_element_type=jnp.float32)
+    return o @ w
+
+
 def embedding_init(key, vocab: int, dim: int, dtype=jnp.float32):
     return {"w": jax.random.normal(key, (vocab, dim), dtype) * 0.02}
 

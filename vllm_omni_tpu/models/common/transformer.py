@@ -18,7 +18,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from vllm_omni_tpu.models.common import nn
+from vllm_omni_tpu.models.common import nn, state_layers
 from vllm_omni_tpu.ops import (
     apply_partial_rope,
     apply_rope,
@@ -32,13 +32,12 @@ from vllm_omni_tpu.ops import (
     silu_mul,
     write_kv_cache,
 )
-from vllm_omni_tpu.ops import gated_delta_rule as gdr
 from vllm_omni_tpu.ops import indexer as indexer_ops
-from vllm_omni_tpu.ops import ssd
 from vllm_omni_tpu.ops.paged_attention import heads_a_row
 
-#: the kinds of layer whose sequences hold a state slot
-STATE_KINDS = ("linear_attention", "conv", "mamba")
+#: the kinds of layer whose sequences hold a state slot: what is
+#: particular to each is its record of ``state_layers.KINDS``
+STATE_KINDS = tuple(state_layers.KINDS)
 
 
 @dataclass(frozen=True)
@@ -267,19 +266,9 @@ class TransformerConfig:
         return sum(kind in STATE_KINDS for kind in self.layer_types)
 
     def state_shapes(self, kind: str) -> tuple[tuple[int, ...], ...]:
-        """What one sequence carries through a layer of ``kind``, an array
-        a shape: a Gated DeltaNet layer the convolution's last inputs and
-        the rule's matrix a value head, a gated short convolution its last
-        inputs alone, a Mamba-2 layer the convolution's last inputs and the
-        rule's state a head (layouts: ops/gated_delta_rule.py, ops/ssd.py)."""
-        if kind == "conv":
-            return ((self.shortconv_kernel - 1, self.hidden_size),)
-        if kind == "mamba":
-            return ((self.mamba_conv_kernel - 1, self.mamba_conv_dim),
-                    self.mamba_dims)
-        return ((self.linear_conv_kernel_dim - 1, self.linear_conv_dim),
-                (self.linear_num_value_heads, self.linear_key_head_dim,
-                 self.linear_value_head_dim))
+        """What one sequence carries through a layer of ``kind`` (one of
+        ``STATE_KINDS``), an array a shape."""
+        return state_layers.KINDS[kind].shapes(self)
 
     def latent_kind(self, kind: str = "full_attention") -> LatentKind:
         """The latent widths of a kind of layer."""
@@ -430,12 +419,9 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
             "input_norm": norm_init(kn[0], cfg.hidden_size),
             "post_norm": norm_init(kn[1], cfg.hidden_size),
         }
-        if kind == "linear_attention":
-            layer["linear_attn"] = _gdn_init(k, cfg, dtype)
-        elif kind == "conv":
-            layer["shortconv"] = _shortconv_init(k, cfg, dtype)
-        elif kind == "mamba":
-            layer["mamba"] = _mamba_init(k, cfg, dtype)
+        record = state_layers.KINDS.get(kind)
+        if record:
+            layer[record.key] = record.init(k, cfg, dtype)
         elif cfg.kv_lora_rank:
             lk = cfg.latent_kind(kind)
             layer["mla"] = _mla_init(k, cfg, lk, dtype)
@@ -563,99 +549,6 @@ def _indexer_init(key, cfg: TransformerConfig, lk: LatentKind, dtype):
             maxval=0.1).astype(dtype)},
         "w": nn.linear_init(kw, cfg.hidden_size, n, bias=False,
                             dtype=dtype),
-    }
-
-
-def _gdn_init(k, cfg: TransformerConfig, dtype):
-    """One Gated DeltaNet mixer's weights.  The published checkpoint fuses
-    q, k, v and z into ``in_proj_qkvz`` and b, a into ``in_proj_ba``, both
-    interleaved by key head; here they are kept apart in the order the
-    convolution sees them ([q | k | v], then z; [b | a]) and the loader
-    de-interleaves (models/qwen3_next).  ``A_log`` and ``dt_bias`` are drawn
-    as the published initialiser draws them (A uniform in (0, 16), the time
-    step log-uniform in [0.001, 0.1] through the inverse softplus), so that
-    a token's decay exp(g) is neither 0 nor 1."""
-    n_v = cfg.linear_num_value_heads
-    v_dim = n_v * cfg.linear_value_head_dim
-    ka, kd = jax.random.split(k[7])
-    dt = jnp.exp(jax.random.uniform(kd, (n_v,), jnp.float32)
-                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
-    kc = cfg.linear_conv_kernel_dim
-    return {
-        "in_proj_qkv": nn.linear_init(k[0], cfg.hidden_size,
-                                      cfg.linear_conv_dim, bias=False,
-                                      dtype=dtype),
-        "in_proj_z": nn.linear_init(k[1], cfg.hidden_size, v_dim,
-                                    bias=False, dtype=dtype),
-        "in_proj_ba": nn.linear_init(k[2], cfg.hidden_size, 2 * n_v,
-                                     bias=False, dtype=dtype),
-        # depthwise, no bias: w[K - 1] multiplies the current token
-        "conv": {"w": jax.random.uniform(
-            k[5], (kc, cfg.linear_conv_dim), dtype,
-            minval=-kc ** -0.5, maxval=kc ** -0.5)},
-        "A_log": jnp.log(jax.random.uniform(
-            ka, (n_v,), jnp.float32, minval=1e-3, maxval=16.0)).astype(dtype),
-        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
-        "norm": nn.rmsnorm_init(cfg.linear_value_head_dim, dtype),
-        "out_proj": nn.linear_init(k[3], v_dim, cfg.hidden_size, bias=False,
-                                   dtype=dtype),
-    }
-
-
-def _shortconv_init(k, cfg: TransformerConfig, dtype):
-    """One gated short convolution's weights, under the checkpoint's names
-    (``conv.in_proj``, ``conv.conv``, ``conv.out_proj``): ``in_proj``'s
-    columns are [B | C | u]; the taps lie [K, hidden] with ``w[K - 1]`` on
-    the current token, as ``ops/gated_delta_rule.py`` takes them (the
-    checkpoint keeps [hidden, 1, K]: a transpose in a loader)."""
-    kc = cfg.shortconv_kernel
-    return {
-        "in_proj": nn.linear_init(k[0], cfg.hidden_size,
-                                  3 * cfg.hidden_size, bias=False,
-                                  dtype=dtype),
-        "conv": {"w": jax.random.uniform(
-            k[5], (kc, cfg.hidden_size), dtype,
-            minval=-kc ** -0.5, maxval=kc ** -0.5)},
-        "out_proj": nn.linear_init(k[3], cfg.hidden_size, cfg.hidden_size,
-                                   bias=False, dtype=dtype),
-    }
-
-
-def _mamba_init(k, cfg: TransformerConfig, dtype):
-    """One Mamba-2 mixer's weights, under the checkpoint's names
-    (``mamba.in_proj``, ``conv1d``, ``A_log``, ``dt_bias``, ``D``,
-    ``norm``, ``out_proj``): ``in_proj``'s columns are [z | xBC | dt]; the
-    taps lie [K, C] with ``w[K - 1]`` on the current token (the checkpoint
-    keeps [C, 1, K]: a transpose in a loader), here with a bias.  ``A_log``,
-    ``dt_bias`` and ``D`` are drawn as Mamba-2's own initialiser draws them
-    (A uniform in (1, 16), the time step log-uniform in [0.001, 0.1]
-    through the inverse softplus, D ones), so that a token's decay exp(a)
-    is neither 0 nor 1 and a state is carried over hundreds of tokens."""
-    n_h, d_h, n = cfg.mamba_dims
-    d_inner, kc = n_h * d_h, cfg.mamba_conv_kernel
-    ka, kd = jax.random.split(k[7])
-    kw, kb = jax.random.split(k[5])
-    dt = jnp.exp(jax.random.uniform(kd, (n_h,), jnp.float32)
-                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
-    return {
-        "in_proj": nn.linear_init(k[0], cfg.hidden_size,
-                                  d_inner + cfg.mamba_conv_dim + n_h,
-                                  bias=False, dtype=dtype),
-        # depthwise: w[K - 1] multiplies the current token; the published
-        # initialiser's bias is small and not zero
-        "conv": {"w": jax.random.uniform(
-            kw, (kc, cfg.mamba_conv_dim), dtype,
-            minval=-kc ** -0.5, maxval=kc ** -0.5),
-                 "b": jax.random.uniform(
-            kb, (cfg.mamba_conv_dim,), dtype,
-            minval=-kc ** -0.5, maxval=kc ** -0.5)},
-        "A_log": jnp.log(jax.random.uniform(
-            ka, (n_h,), jnp.float32, minval=1.0, maxval=16.0)).astype(dtype),
-        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
-        "D": jnp.ones((n_h,), dtype),
-        "norm": nn.rmsnorm_init(d_inner, dtype),
-        "out_proj": nn.linear_init(k[3], d_inner, cfg.hidden_size,
-                                   bias=False, dtype=dtype),
     }
 
 
@@ -885,93 +778,6 @@ def _rope(cfg: TransformerConfig, x, cos, sin):
     return apply_partial_rope(x, cos, sin)
 
 
-def _gdn_mixer(la, cfg: TransformerConfig, h, recur):
-    """The Gated DeltaNet sequence mixer on normed rows h [T, hidden]:
-    projections -> ``recur`` -> gated per-head norm -> out projection.
-    ``recur(mixed, g, beta, conv_w)`` supplies what differs between the
-    forwards (where the carried state comes from and goes to, and the
-    chunked or the one-token form of the rule; ops/gated_delta_rule.py)
-    and returns o [T, Hv, Dv] float32.  One body serves every forward, as
-    ``_layer_step`` does for attention."""
-    t = h.shape[0]
-    n_v, d_v = cfg.linear_num_value_heads, cfg.linear_value_head_dim
-    with jax.named_scope("omni.proj"):
-        # the mixer's inputs leave their matmuls in float32 and stay so up
-        # to the out projection: everything between is float32 arithmetic
-        # anyway, and a rounding in here reaches the residual stream at
-        # full weight (see ``residual_in_fp32``)
-        def f32_proj(name):
-            return jnp.dot(h, la[name]["w"],
-                           preferred_element_type=jnp.float32)
-
-        mixed = f32_proj("in_proj_qkv")
-        z = f32_proj("in_proj_z").reshape(t, n_v, d_v)
-        ba = f32_proj("in_proj_ba")
-    with jax.named_scope("omni.gdn"):
-        beta = jax.nn.sigmoid(ba[:, :n_v])
-        g = -jnp.exp(la["A_log"].astype(jnp.float32)) * jax.nn.softplus(
-            ba[:, n_v:] + la["dt_bias"].astype(jnp.float32))
-        o = recur(mixed, g, beta, la["conv"]["w"])
-        # per head: w * o * rsqrt(mean(o^2) + eps), then the silu(z) gate
-        o = o * jax.lax.rsqrt(
-            jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps)
-        o = (o * la["norm"]["w"].astype(jnp.float32)
-             * jax.nn.silu(z)).astype(h.dtype)
-    with jax.named_scope("omni.proj"):
-        return _out_proj(cfg, o.reshape(t, -1), la["out_proj"]["w"])
-
-
-def _shortconv_mixer(sc, cfg: TransformerConfig, h, recur):
-    """The gated short convolution on normed rows h [T, hidden]: ``[B | C
-    | u] = h W_in``; ``recur(B * u, taps)`` is the depth-wise causal
-    convolution over each sequence's rows (float32, no activation: the
-    silu after Gated DeltaNet's convolution is that path's own), which
-    supplies what differs between the forwards (where the sequence's last
-    inputs come from and go to); ``C`` gates it; then the out projection.
-    The products ``B * u`` are exact in float32 (two bfloat16 factors),
-    and that is what the state keeps."""
-    with jax.named_scope("omni.proj"):
-        bcu = nn.linear(sc["in_proj"], h)
-    with jax.named_scope("omni.shortconv"):
-        n = cfg.hidden_size
-        gate_b, gate_c, u = (bcu[:, i * n:(i + 1) * n].astype(jnp.float32)
-                             for i in range(3))
-        y = (gate_c * recur(gate_b * u, sc["conv"]["w"])).astype(h.dtype)
-    with jax.named_scope("omni.proj"):
-        return _out_proj(cfg, y, sc["out_proj"]["w"])
-
-
-def _mamba_mixer(mb, cfg: TransformerConfig, h, recur):
-    """The Mamba-2 sequence mixer on normed rows h [T, hidden]: ``[z | xBC
-    | dt] = h W_in``; ``recur(xBC, dt, a, taps, bias, D)`` is convolution,
-    silu, the state-space rule and the skip (ops/ssd.py), which supplies
-    what differs between the forwards (where a sequence's carried inputs
-    and state are, the chunked or the one-token form) and returns y [T,
-    H, P] float32; then the gate ``silu(z)`` BEFORE an RMSNorm over all
-    heads (one group), and the out projection.  Everything between the two
-    projections is float32, as in ``_gdn_mixer`` and for its reason."""
-    t = h.shape[0]
-    n_h, d_h, _ = cfg.mamba_dims
-    d_inner = n_h * d_h
-    with jax.named_scope("omni.proj"):
-        zxbcdt = jnp.dot(h, mb["in_proj"]["w"],
-                         preferred_element_type=jnp.float32)
-    with jax.named_scope("omni.ssd"):
-        z = zxbcdt[:, :d_inner]
-        xbc = zxbcdt[:, d_inner:d_inner + cfg.mamba_conv_dim]
-        # no clamp of the time step: the family's limits are 0 and infinity
-        dt = jax.nn.softplus(zxbcdt[:, d_inner + cfg.mamba_conv_dim:]
-                             + mb["dt_bias"].astype(jnp.float32))
-        a = -jnp.exp(mb["A_log"].astype(jnp.float32)) * dt
-        y = recur(xbc, dt, a, mb["conv"]["w"], mb["conv"]["b"], mb["D"])
-        y = y.reshape(t, d_inner) * jax.nn.silu(z)
-        y = y * jax.lax.rsqrt(
-            jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
-        y = (y * mb["norm"]["w"].astype(jnp.float32)).astype(h.dtype)
-    with jax.named_scope("omni.proj"):
-        return _out_proj(cfg, y, mb["out_proj"]["w"])
-
-
 def _join_lanes(parts, width: int):
     """``parts`` side by side on the last axis, zeros after them up to
     ``width``.  A leading part that is whole 128-lane tiles is joined as
@@ -1093,14 +899,8 @@ def _mla_mixer(layer, cfg: TransformerConfig, lk: LatentKind, h, cos, sin,
             gate = jax.nn.sigmoid(
                 nn.linear(layer["attn_gate"], h).astype(jnp.float32))
             o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
-        return _out_proj(cfg, o.reshape(t, -1), layer["o_proj"]["w"])
-
-
-def _out_proj(cfg: TransformerConfig, o, w):
-    """A mixer's output projection, in the residual stream's precision."""
-    if cfg.residual_in_fp32:
-        return jnp.dot(o, w, preferred_element_type=jnp.float32)
-    return o @ w
+        return nn.out_proj(o.reshape(t, -1), layer["o_proj"]["w"],
+                           cfg.residual_in_fp32)
 
 
 def _join_residual(cfg: TransformerConfig, x, branch):
@@ -1121,29 +921,20 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     causal / cached-context chunked / paged decode) and returns o with
     leading dims matching ``x``'s; in a latent-attention layer (``"mla"``
     in the layer's weights) the same ``attend`` gets the absorbed query,
-    the latent row as the key and no value (``_mla_mixer``); in a Gated
-    DeltaNet layer (``"linear_attn"``) ``attend`` is the ``recur`` of
-    ``_gdn_mixer``, in a gated short convolution (``"shortconv"``) that of
-    ``_shortconv_mixer``, in a Mamba-2 layer (``"mamba"``) that of
-    ``_mamba_mixer``.  The MLP is the sparse one where the layer holds
-    ``experts``, a dense SwiGLU where it does not.  One body serves every
-    forward so the variants
-    cannot silently diverge."""
+    the latent row as the key and no value (``_mla_mixer``); in a layer
+    that carries a state (``kind`` is one of ``state_layers.KINDS``)
+    ``attend`` is the ``recur`` of that kind's mixer.  The MLP is the sparse
+    one where the layer holds ``experts``, a dense SwiGLU where it does not.
+    One body serves every forward so the variants cannot silently diverge."""
     b = x.shape[:-1]
     act = layer["input_norm"]["w"].dtype
     if cfg.residual_in_fp32:
         x = x.astype(jnp.float32)
     with jax.named_scope("omni.proj"):
         h = _rms(cfg, x, layer["input_norm"]).astype(act)
-    if "linear_attn" in layer:
-        o = _gdn_mixer(layer["linear_attn"], cfg,
-                       h.reshape(-1, h.shape[-1]), attend).reshape(*b, -1)
-    elif "shortconv" in layer:
-        o = _shortconv_mixer(layer["shortconv"], cfg,
-                             h.reshape(-1, h.shape[-1]), attend).reshape(
-                                 *b, -1)
-    elif "mamba" in layer:
-        o = _mamba_mixer(layer["mamba"], cfg, h.reshape(-1, h.shape[-1]),
+    record = state_layers.KINDS.get(kind)
+    if record:
+        o = record.mixer(layer[record.key], cfg, h.reshape(-1, h.shape[-1]),
                          attend).reshape(*b, -1)
     elif "mla" in layer:
         o = _mla_mixer(layer, cfg, cfg.latent_kind(kind),
@@ -1162,7 +953,7 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
                 o = (o.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.reshape(*b, -1).astype(jnp.float32))).astype(o.dtype)
         with jax.named_scope("omni.proj"):
-            o = _out_proj(cfg, o, layer["o_proj"]["w"])
+            o = nn.out_proj(o, layer["o_proj"]["w"], cfg.residual_in_fp32)
     with jax.named_scope("omni.proj"):
         if cfg.tp_axis is not None:
             # row-parallel o_proj: each shard holds a partial sum
@@ -1184,38 +975,20 @@ class HybridCaches(NamedTuple):
 
     kv: list     # per softmax-attention layer: (k, v) pages
     # per layer that carries a state, its slots, a tuple of arrays
-    # ``[slots, ...]`` (``TransformerConfig.state_shapes``): (conv,
-    # recurrent) of a Gated DeltaNet layer, (taps,) of a short convolution,
-    # (conv, state) of a Mamba-2 layer
+    # ``[slots, ...]``, one a shape of ``TransformerConfig.state_shapes``
     state: list
 
 
-#: the scope a kind of state's gathers and scatters run under, and the key
-#: of the layer's weights that says the layer is of that kind
-_STATE_SCOPE = {"linear_attn": "omni.gdn_state",
-                "shortconv": "omni.conv_state",
-                "mamba": "omni.ssd_state"}
-#: of a layer's state arrays, how many (the last ones) its rule updates
-#: where they lie in the slots, instead of on a copy of the step's rows: a
-#: Mamba-2 layer's [heads, head_dim, state_dim] state, 4 MB a sequence,
-#: which a decode step would otherwise copy out of the pool and back
-_STATE_IN_PLACE = {"mamba": 1}
-
-
-def _state_key(layer) -> Optional[str]:
-    return next((k for k in _STATE_SCOPE if k in layer), None)
-
-
-def _layer_caches(params, kv_caches, state_caches):
-    """(layer, its cache) down the stack: the (k, v) pages of a
+def _layer_caches(params, cfg: TransformerConfig, kv_caches, state_caches):
+    """(layer, its kind, its cache) down the stack: the (k, v) pages of a
     softmax-attention layer ((latent pool, None) of a latent-attention
     layer: the write and the kernels take the absent value as "read it
     from the key's row"), the state slots of a layer that carries one.
     ``kv_caches`` holds one entry for each attention layer only,
     ``state_caches`` one for each of the others."""
     kv, st = iter(kv_caches), iter(state_caches or ())
-    for layer in params["layers"]:
-        yield layer, next(st if _state_key(layer) else kv)
+    for layer, kind in zip(params["layers"], cfg.layer_types):
+        yield layer, kind, next(st if kind in state_layers.KINDS else kv)
 
 
 def _read_state(cache, slots, fresh, scope):
@@ -1237,17 +1010,18 @@ def _write_state(cache, slots, state, scope):
                      for a, new in zip(cache, state))
 
 
-def _stateful_recur(cache, slots, fresh, new_states, rule, scope,
-                    in_place: int = 0):
-    """The ``recur`` of ``_gdn_mixer``, ``_shortconv_mixer`` or
-    ``_mamba_mixer`` for a forward that carries state: the rows' slots are
-    read (under ``scope``), ``rule(*the mixer's operands, *the state's
-    arrays)`` runs the ragged or the one-token form and returns the output
-    and the new state, and the slots are written back into ``new_states``.
-    The last ``in_place`` arrays of the layer's state are not read out:
-    the rule gets the pools themselves with ``slots`` and ``fresh`` after
-    them, and returns them updated."""
-    moved = cache[:len(cache) - in_place]
+def _stateful_recur(record: state_layers.StateKind, rule, cache, slots,
+                    fresh, new_states):
+    """The ``recur`` of ``record``'s mixer for a forward that carries
+    state: the rows' slots are read (under the record's scope),
+    ``rule(*the mixer's operands, *the state's arrays)`` runs the ragged
+    or the one-token form and returns the output and the new state, and the
+    slots are written back into ``new_states``.  The last
+    ``record.in_place`` arrays of the layer's state are not read out: the
+    rule gets the pools themselves with ``slots`` and ``fresh`` after them,
+    and returns them updated."""
+    scope = record.scope
+    moved = cache[:len(cache) - record.in_place]
     pools = cache[len(moved):]
 
     def recur(*operands):
@@ -1325,46 +1099,31 @@ def forward_hidden(
                 **cfg.attn_kwargs,
             )
 
-    def recur(mixed, g, beta, conv_w):
-        # no cache: every sequence starts from a zero state and the final
-        # state is dropped; the [B, S] batch is B packed segments of S rows
-        conv = jnp.zeros((b, conv_w.shape[0] - 1, mixed.shape[-1]),
-                         mixed.dtype)
-        rec = jnp.zeros((b, cfg.linear_num_value_heads,
-                         cfg.linear_key_head_dim,
-                         cfg.linear_value_head_dim), jnp.float32)
-        return gdr.gated_delta_net_ragged(
-            mixed, g, beta, conv_w, conv, rec,
-            jnp.arange(b + 1, dtype=jnp.int32) * s,
-            jnp.full((b,), s, jnp.int32), cfg.gdn_heads)[0]
+    def recur_of(record: state_layers.StateKind):
+        def recur(*operands):
+            # no cache: every sequence starts from a zero state and the
+            # final state is dropped; the [B, S] batch is B packed segments
+            # of S rows, and a kind whose rule updates a pool in place gets
+            # a pool of the batch's own, every slot fresh
+            state = [jnp.zeros((b, *shape), jnp.float32)
+                     for shape in record.shapes(cfg)]
+            if record.in_place:
+                state += [jnp.arange(b, dtype=jnp.int32),
+                          jnp.ones((b,), bool)]
+            rule = record.ragged(cfg, jnp.arange(b + 1, dtype=jnp.int32) * s,
+                                 jnp.full((b,), s, jnp.int32))
+            return rule(*operands, *state)[0]
 
-    def conv_recur(v, conv_w):
-        return gdr.causal_conv_ragged(
-            v, conv_w,
-            jnp.zeros((b, conv_w.shape[0] - 1, v.shape[-1]), v.dtype),
-            jnp.arange(b + 1, dtype=jnp.int32) * s,
-            jnp.full((b,), s, jnp.int32), carried="gather")[0]
+        return recur
 
-    def ssd_recur(xbc, dt, a, conv_w, conv_b, d_skip):
-        # no cache: a pool of the batch's own, every slot fresh
-        return ssd.mamba2_ragged(
-            xbc, dt, a, conv_w, conv_b, d_skip,
-            jnp.zeros((b, conv_w.shape[0] - 1, xbc.shape[-1]), xbc.dtype),
-            jnp.zeros((b, *cfg.mamba_dims), jnp.float32),
-            jnp.arange(b, dtype=jnp.int32), jnp.ones((b,), bool),
-            jnp.arange(b + 1, dtype=jnp.int32) * s,
-            jnp.full((b,), s, jnp.int32), cfg.mamba_dims,
-            chunk=cfg.mamba_chunk_size)[0]
-
-    mixes = {"linear_attn": recur, "shortconv": conv_recur,
-             "mamba": ssd_recur}
     layers = params["layers"]
     if drop_last_layers:
         layers = layers[:len(layers) - drop_last_layers]
     collected = {0: x} if 0 in collect_hidden_layers else {}
-    for li, layer in enumerate(layers):
+    for li, (layer, kind) in enumerate(zip(layers, cfg.layer_types)):
+        record = state_layers.KINDS.get(kind)
         x = _layer_step(layer, cfg, x, cos, sin,
-                        mixes.get(_state_key(layer), attend))
+                        recur_of(record) if record else attend, kind)
         if li + 1 in collect_hidden_layers:
             collected[li + 1] = x
     if collect_hidden_layers:
@@ -1479,7 +1238,7 @@ def forward_unified(
     sequence's rows read its slot at the chunk's start (zeros where the
     chunk starts the sequence) and write it at the chunk's end, through
     the ragged form of the layer's rule (a Mamba-2 layer's state is
-    updated in its slot: ``_STATE_IN_PLACE``).
+    updated in its slot: ``state_layers.StateKind.in_place``).
 
     A model with sliding-window layers passes ``window_pages``: the slot
     mapping and the page tables of the window kind of page, which its
@@ -1505,17 +1264,8 @@ def forward_unified(
         return indexer_ops.indexer_scores(
             q, w, pool, tables, cu_q_lens, q_lens, seq_lens, num_seqs)
 
-    ragged_rules = {
-        "linear_attn": lambda *a: gdr.gated_delta_net_ragged(
-            *a, cu_q_lens, q_lens, cfg.gdn_heads),
-        "shortconv": lambda *a: gdr.causal_conv_ragged(
-            *a, cu_q_lens, q_lens, carried="gather"),
-        "mamba": lambda *a: ssd.mamba2_ragged(
-            *a, cu_q_lens, q_lens, cfg.mamba_dims,
-            chunk=cfg.mamba_chunk_size)}
-    for i, (layer, cache) in enumerate(
-            _layer_caches(params, kv_caches, state_caches)):
-        kind = cfg.layer_types[i]
+    for i, (layer, kind, cache) in enumerate(
+            _layer_caches(params, cfg, kv_caches, state_caches)):
         attend = _paged_attend(
             cfg, kind, cache, new_caches,
             *(window_pages if kind == "sliding_attention"
@@ -1525,11 +1275,11 @@ def forward_unified(
         # (a layer at a time, as the jaxpr has had it: the steps' digests
         # in tests/ops/test_ragged_tpu_compile.py hold the order)
         fresh = seq_lens == q_lens
-        state = _state_key(layer)
-        if state:
-            attend = _stateful_recur(cache, state_slots, fresh, new_states,
-                                     ragged_rules[state], _STATE_SCOPE[state],
-                                     _STATE_IN_PLACE.get(state, 0))
+        record = state_layers.KINDS.get(kind)
+        if record:
+            attend = _stateful_recur(
+                record, record.ragged(cfg, cu_q_lens, q_lens), cache,
+                state_slots, fresh, new_states)
         x = _layer_step(layer, cfg, x, *ropes[kind], attend, kind)
         if deepstack is not None and i < deepstack.shape[0]:
             with jax.named_scope("omni.embed"):
@@ -1572,25 +1322,18 @@ def forward_decode(
         return indexer_ops.indexer_scores_decode(q, w, pool, tables,
                                                  context_lens)
 
-    decode_rules = {
-        "linear_attn": lambda *a: gdr.gated_delta_net_decode(
-            *a, cfg.gdn_heads),
-        "shortconv": gdr.causal_conv_update,
-        "mamba": lambda *a: ssd.mamba2_decode(*a, cfg.mamba_dims)}
-    for i, (layer, cache) in enumerate(
-            _layer_caches(params, kv_caches, state_caches)):
-        kind = cfg.layer_types[i]
+    for layer, kind, cache in _layer_caches(params, cfg, kv_caches,
+                                            state_caches):
         attend = _paged_attend(
             cfg, kind, cache, new_caches,
             *(window_pages if kind == "sliding_attention"
               else (slot_mapping, block_tables)), attention, scores)
 
         fresh = context_lens <= 1
-        state = _state_key(layer)
-        if state:
-            attend = _stateful_recur(cache, state_slots, fresh, new_states,
-                                     decode_rules[state], _STATE_SCOPE[state],
-                                     _STATE_IN_PLACE.get(state, 0))
+        record = state_layers.KINDS.get(kind)
+        if record:
+            attend = _stateful_recur(record, record.decode(cfg), cache,
+                                     state_slots, fresh, new_states)
         x = _layer_step(layer, cfg, x, *ropes[kind], attend, kind)
     if hybrid:
         new_caches = HybridCaches(new_caches, new_states)

@@ -36,7 +36,7 @@ Not served, and refused or absent by name:
   speculative decoding is refused for this cache at engine construction;
 - prefix reuse (a freed window page no longer holds the rows at a prefix's
   boundary: refused a hit and counted), ``kv_offload``, ``kv_transfer``, an
-  int8 cache, tensor parallelism (``LLMEngine._refuse_for_window``);
+  int8 cache, tensor parallelism (``kvcache/layouts.py``);
 - a loader for the checkpoint's tensor names: there is nothing here to
   test one on.  The weight tree keeps the checkpoint's names without their
   suffixes (``transformer._mla_init``, ``_indexer_init``);

@@ -14,9 +14,9 @@ scale is ``attention_multiplier`` 1/128, not ``head_dim ** -0.5``.
 The TPU build runs it on the shared functional transformer
 (models/common/transformer.py): the layer kinds are data (``layer_kinds``:
 ``"mamba"`` beside ``"full_attention"``), the fourth sequence mixer is
-``_mamba_mixer`` over ``ops/ssd.py``, whose state (the convolution's last
-three inputs, ``[3, 8448]``, and the rule's ``[128, 64, 128]`` float32, 4 MB
-a sequence a layer) takes a slot of the engine's state cache as Qwen3-
+``state_layers._mamba_mixer`` over ``ops/ssd.py``, whose state (the
+convolution's last three inputs, ``[3, 8448]``, and the rule's ``[128, 64,
+128]`` float32, 4 MB a sequence a layer) takes a slot of the engine's state cache as Qwen3-
 Next's and LFM2's do, and is updated where it lies (docs/kv_cache.md, "Four
 kinds of state"); ``use_rope=False`` builds no table and rotates no key;
 the sparse MLP is the thinker's softmax router renormalised over its picks,
@@ -31,7 +31,7 @@ Not served, and refused or absent by name:
   has);
 - a loader for the checkpoint's tensor names: there is nothing here to
   test one on.  The weight tree keeps the checkpoint's names without their
-  suffixes (``transformer._mamba_init``), the taps transposed.
+  suffixes (``state_layers._mamba_init``), the taps transposed.
 """
 
 from __future__ import annotations

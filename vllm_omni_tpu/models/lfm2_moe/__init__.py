@@ -12,7 +12,7 @@ eps 1e-5, and after the last layer one more norm (the checkpoint's
 The TPU build runs it on the shared functional transformer
 (models/common/transformer.py): the layer kinds are data (``layer_kinds``:
 ``"conv"`` beside ``"full_attention"``), the third sequence mixer is
-``_shortconv_mixer`` over ``ops/gated_delta_rule.py``'s causal
+``state_layers._shortconv_mixer`` over ``ops/gated_delta_rule.py``'s causal
 convolution, whose state (the last two rows of ``B * u``, ``[2, 2048]`` a
 layer, nothing else) takes a slot of the engine's state cache as Qwen3-
 Next's does (docs/kv_cache.md, "Three kinds of state"); the pages hold two
@@ -27,7 +27,7 @@ Not served, and refused or absent by name:
   hit, one by one (docs/kv_cache.md);
 - a loader for the checkpoint's tensor names: there is nothing here to
   test one on.  The weight tree keeps the checkpoint's names without their
-  suffixes (``transformer._shortconv_init``), the taps transposed.
+  suffixes (``state_layers._shortconv_init``), the taps transposed.
 """
 
 from __future__ import annotations

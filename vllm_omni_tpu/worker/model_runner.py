@@ -51,6 +51,7 @@ from vllm_omni_tpu.core.scheduler import (
     packed_rows_needed,
 )
 from vllm_omni_tpu.introspection.phase_clock import PhaseClock
+from vllm_omni_tpu.kvcache import layouts as cache_layouts
 from vllm_omni_tpu.kvcache.quant import (
     dequantize_payload,
     is_quant_payload,
@@ -228,9 +229,7 @@ class ARModelRunner:
         seed: Optional[int] = None,
         max_num_seqs: int = 64,
         mesh=None,  # 1-axis "tp" Mesh => tensor-parallel execution
-        async_scheduling: bool = False,
         max_num_batched_tokens: int = 2048,  # sizes the token buckets
-        deterministic_decode: bool = False,  # pin decode batches to one bucket
         kv_cache_dtype: str = "auto",  # auto | bf16 | int8 resident layout
         num_window_pages: int = 0,  # the window kind's pool (null page incl.)
     ):
@@ -259,24 +258,14 @@ class ARModelRunner:
                 "sizes it from max_num_seqs x (window + "
                 "max_num_batched_tokens)), and are not built beside "
                 "recurrent layers")
-        if self._latent and kv_cache_dtype == "int8":
-            raise ValueError(
-                "kv_cache_dtype int8: the latent cache has no quantized "
-                "layout (one absmax scale a page would span the latent "
-                "and the rotary key alike)")
-        if self._latent and mesh is not None:
-            raise ValueError(
-                "tensor parallelism: the latent cache is one kv head that "
-                "every query head reads, and has no sharded layout yet")
-        if self._hybrid and kv_cache_dtype == "int8":
-            raise ValueError(
-                "kv_cache_dtype int8: a model with recurrent layers keeps "
-                "a float32 state beside its pages and has no quantized "
-                "layout for it")
-        if self._hybrid and mesh is not None:
-            raise ValueError(
-                "tensor parallelism: the state cache of a model with "
-                "recurrent layers has no sharded layout yet")
+        # what this model's cache layouts cannot carry (a runner can be
+        # built without an engine, so it refuses for itself, from the
+        # engine's table)
+        self._cannot_carry = cache_layouts.cannot_carry(cfg)
+        if kv_cache_dtype == "int8":
+            self._refuse(cache_layouts.INT8)
+        if mesh is not None:
+            self._refuse(cache_layouts.TENSOR_PARALLEL)
         # int8 = the quantized resident layout (per-(head, page) absmax
         # scales, ops/paged_attention.py); auto/bf16 keep the dense
         # layout in the runner ``dtype``.  The flag is part of every
@@ -303,8 +292,6 @@ class ARModelRunner:
                 "cache rows are whole 128-lane tiles (a head_dim of 128 "
                 "or 256, an even count of kv heads of 64, or a latent row "
                 "padded to 640, are served)")
-        self.async_scheduling = bool(async_scheduling)
-        self.deterministic_decode = bool(deterministic_decode)
         self.mesh = mesh
         # where a step's token ids live under a mesh (``_feed_tokens``)
         self._replicated = None
@@ -410,9 +397,9 @@ class ARModelRunner:
         # inputs of the convolution and the rule's matrices, a gated short
         # convolution's last inputs alone), float32 (the rule's as the
         # published implementation keeps it; the convolutions' because
-        # their inputs are, see transformer._gdn_mixer and
-        # _shortconv_mixer), one slot a sequence (slots = max_num_seqs;
-        # layouts: ops/gated_delta_rule.py).  A row's slot index rides
+        # their inputs are, see the mixers of models/common/state_layers),
+        # one slot a sequence (slots = max_num_seqs; layouts:
+        # ops/gated_delta_rule.py).  A row's slot index rides
         # next to its page table; padding rows carry ``num_state_slots``,
         # past the end, so their write is dropped
         self.num_state_slots = max(max_num_seqs, 1) if self._hybrid else 0
@@ -717,14 +704,7 @@ class ARModelRunner:
         executable's input shapes."""
         import inspect
 
-        if self._latent:
-            raise ValueError(
-                "speculative decoding: the verify rows' path over a "
-                "latent cache is not built")
-        if self._hybrid:
-            raise ValueError(
-                "speculative decoding: a rejected draft cannot roll a "
-                "recurrent state back, and this model has recurrent layers")
+        self._refuse(cache_layouts.SPECULATIVE)
         self.draft_fn = draft_fn
         self.num_draft_tokens = num_draft_tokens
         self._lay_token_line()
@@ -832,20 +812,6 @@ class ARModelRunner:
             col[i] = sc.state_slot
         return (col,)
 
-    def _decode_bucket(self, n: int) -> int:
-        """Batch bucket for the single-token decode family.  With
-        ``deterministic_decode`` every decode step pads to the TOP
-        bucket: XLA fuses the [B]-leading decode matmuls differently
-        per bucket shape, so the same row decoded in a bucket-4 batch
-        and a bucket-8 batch can differ in the last bf16 bit — enough
-        to flip a greedy argmax on near-flat logits.  One fixed bucket
-        makes a request's stream invariant to co-batch occupancy
-        (preemptions and arrivals stop perturbing OTHER requests'
-        tokens) at the cost of padded rows when the batch runs small."""
-        if self.deterministic_decode:
-            return self._batch_buckets[-1]
-        return _bucket(n, self._batch_buckets)
-
     # ---------------------------------------------------------- precompile
     def precompile(self, prefill_shapes=(), decode: bool = True,
                    progress_fn=None) -> int:
@@ -906,16 +872,11 @@ class ARModelRunner:
                               InflightDecode(tokens=tokens, rows={}))
 
         if decode and self.draft_fn is None:
-            # deterministic decode runs every step at the top bucket —
-            # the smaller executables can never be dispatched.  A
-            # runner with a draft head never dispatches the [B]-row
+            # a runner with a draft head never dispatches the [B]-row
             # decode path at all (_plain_decode_only routes every
             # decode batch unified), so its buckets would be pure
             # warmup waste.
-            decode_buckets = (self._batch_buckets[-1:]
-                              if self.deterministic_decode
-                              else self._batch_buckets)
-            for b in decode_buckets:
+            for b in self._batch_buckets:
                 tables = jnp.zeros((b, self.max_pages_per_seq), jnp.int32)
                 zeros_b = self._feed_tokens(np.zeros((b,), np.int32), [],
                                             None)
@@ -1297,7 +1258,7 @@ class ARModelRunner:
         phase = self.phases.phase
         with phase("omni.assemble"):
             with phase("omni.assemble.pack", trace=False):
-                b = self._decode_bucket(len(scheds))
+                b = _bucket(len(scheds), self._batch_buckets)
                 token_host = np.zeros((b,), np.int32)
                 feed: list[tuple[int, int]] = []
                 params_list = [_PAD_SAMPLING] * b
@@ -1678,7 +1639,8 @@ class ARModelRunner:
         the cross-path no-double-quantize contract); quantized into a
         dense pool dequantizes first; dense into an int8 pool quantizes
         through the write op's shared rounding."""
-        self._refuse_kv_transfer("inject_kv")
+        self._refuse(cache_layouts.TRANSFER, "inject_kv",
+                     NotImplementedError)
         if len(payload) != len(self.kv_caches):
             raise ValueError(
                 f"KV payload has {len(payload)} layers, cache has "
@@ -1744,26 +1706,21 @@ class ARModelRunner:
         return seq_len
 
     # -------------------------------------------------------- kv extraction
-    def _refuse_kv_transfer(self, what: str) -> None:
-        if self._latent:
-            raise NotImplementedError(
-                f"{what}: a latent cache holds one shared pool a layer, "
-                "which the (K, V) page payload does not describe (KV "
-                "transfer, parking and offload are refused for it at "
-                "engine construction)")
-        if self._hybrid:
-            raise NotImplementedError(
-                f"{what}: a model with recurrent layers keeps part of a "
-                "sequence in state slots, which a page payload does not "
-                "carry (KV transfer, parking and offload are refused for "
-                "it at engine construction)")
+    def _refuse(self, mechanism: str, what: Optional[str] = None,
+                error=ValueError) -> None:
+        """Raise where this model's cache layouts cannot carry
+        ``mechanism`` (kvcache/layouts.py), naming the entry point."""
+        reason = self._cannot_carry[mechanism]
+        if reason:
+            raise error(f"{what or mechanism}: {reason}")
 
     def _extract_layer_slices(self, ids, seq_len: int) -> list:
         """Per-layer device slices for one page run.  Dense pools emit
         [Hkv, seq_len, D] halves; int8 pools emit the quantized wire
         layout ((data[:, :seq_len], page scales)) — the bytes leave the
         device as stored, so a later inject restores them bit-exact."""
-        self._refuse_kv_transfer("extract_kv")
+        self._refuse(cache_layouts.TRANSFER, "extract_kv",
+                     NotImplementedError)
         slices = []
         for k_cache, v_cache in self.kv_caches:
             if isinstance(k_cache, tuple):
