@@ -160,9 +160,9 @@ def test_a_share_computes_only_the_pairs_of_its_own_experts(rng):
     seen = {}
     real = moe_ops._grouped_mlp
 
-    def spy(xs, gate_up, down, group_sizes):
+    def spy(xs, gate_up, down, group_sizes, *act):
         seen["sizes"] = np.asarray(group_sizes)
-        return real(xs, gate_up, down, group_sizes)
+        return real(xs, gate_up, down, group_sizes, *act)
 
     moe_ops._grouped_mlp = spy
     try:
@@ -397,10 +397,10 @@ def test_a_share_whose_absent_rows_are_poison_gives_what_it_gave(rng):
     want = moe_ops.routed_moe_share(x, rw, gu[4:6], dn[4:6], 2, 4)
     real = moe_ops._grouped_mlp
 
-    def poisoned(xs, gate_up, down, group_sizes):
+    def poisoned(xs, gate_up, down, group_sizes, *act):
         rows = jnp.arange(xs.shape[0])[:, None]
         return jnp.where(rows < group_sizes.sum(),
-                         real(xs, gate_up, down, group_sizes), jnp.nan)
+                         real(xs, gate_up, down, group_sizes, *act), jnp.nan)
 
     moe_ops._grouped_mlp = poisoned
     try:

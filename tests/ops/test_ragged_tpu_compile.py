@@ -858,3 +858,107 @@ def test_granite_weights_are_made_beside_their_results(one_chip):
         (2,), jnp.uint32, sharding=one_chip)).compile().memory_analysis()
     assert 6.5e9 < memory.output_size_in_bytes < 6.56e9
     assert memory.temp_size_in_bytes < 1.5e9
+
+
+# ------------------------------------------- NVIDIA-Nemotron-3-Super (PR 46)
+@pytest.mark.parametrize("entry", ["decode", "ragged"])
+def test_grouped_state_space_rule_compiles_in_place_for_v5e(entry, one_chip,
+                                                            monkeypatch):
+    """ops/ssd.py at Nemotron-3-Super's widths (the same 128 heads of 64
+    on a state of 128, with EIGHT groups of B and C and chunks of 128):
+    the recurrent kernel hands a block of 32 heads its two groups as a
+    ``[2, 128]`` block of ``[R, 4, 2, 128]``, and the chunked form takes the
+    heads a group at a time; both compile for a v5e with the pool donated
+    and no operation that copies it."""
+    from vllm_omni_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "interpret_flag", lambda: False)
+    h, p, n = SSD_DIMS
+    slots, groups = 128, 8
+
+    def sds(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def rows(r):
+        return (sds((r, h, p)), sds((r, h)), sds((r, h)),
+                sds((r, groups, n)), sds((r, groups, n)),
+                sds((slots, h, p, n)))
+
+    if entry == "decode":
+        compiled = jax.jit(
+            lambda *a: ssd.ssd_decode(*a, use_pallas=True),
+            donate_argnums=(5,)).lower(
+            *rows(128), sds((128,), jnp.int32),
+            sds((128,), jnp.bool_)).compile()
+    else:
+        compiled = jax.jit(
+            lambda *a: ssd.ssd_ragged(*a, chunk=128, use_pallas=True),
+            donate_argnums=(5,)).lower(
+            *rows(2048), sds((128,), jnp.int32), sds((128,), jnp.bool_),
+            sds((129,), jnp.int32), sds((128,), jnp.int32)).compile()
+    _no_pool_copies(compiled, slots)
+
+
+def test_nemotron_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """A whole pure-decode step of the benchmark's Nemotron-3-Super cut at
+    its published widths (eleven layers of one sublayer each, 128 rows, the
+    cell's pages and slots donated) compiles for a v5e: one
+    ``_ragged_attention`` call (32 query heads on 2 kv heads: a group of
+    16), one ``_recurrent_inplace`` a Mamba-2 layer, no kernel that fell
+    back, and the arguments the issue counts (12.1 GB) beside under half a
+    gigabyte of temporaries.  The weights are made in one jitted call
+    beside their 9.30 GB with under 2.5 GB of temporaries (the calibration
+    pass that balances the routers runs the float32 reference's layers on
+    512 tokens in the same call)."""
+    import functools
+    import re
+
+    from benchmark import spec
+    from benchmark.archs import nemotron_h as arch
+    from vllm_omni_tpu.models.common import transformer
+    from vllm_omni_tpu.ops import _dispatch, ssd
+
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    for module in (rpa, pa, ssd):
+        monkeypatch.setattr(module, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    config = spec.load_json(
+        spec.ROOT / "benchmark/configs/nemotron3super-l11-ep4.json")
+    cfg = arch.program_config(config)
+    init = functools.partial(arch.init_weights, cfg=cfg, dtype=jnp.bfloat16)
+    made = jax.jit(init).lower(jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=one_chip)).compile().memory_analysis()
+    assert 9.29e9 < made.output_size_in_bytes < 9.31e9
+    assert made.temp_size_in_bytes < 2.5e9
+
+    def sds(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    b, slots = 128, config["engine"]["max_num_seqs"]
+    lay = cfg.kv_layout
+    pool = sds((lay.heads, config["engine"]["num_pages"], 16, lay.width),
+               jnp.bfloat16)
+    caches = transformer.HybridCaches(
+        [(pool, pool)] * cfg.num_kv_layers,
+        [tuple(sds((slots, *shape), jnp.float32)
+               for shape in cfg.state_shapes(kind))
+         for kind in cfg.layer_types if kind in transformer.STATE_KINDS])
+    assert (len(caches.kv), len(caches.state)) == (1, 5)
+    compiled = jax.jit(
+        lambda p, tok, pos, kv, sl, tables, lens, ss:
+        transformer.forward_decode(p, cfg, tok, pos, kv, sl, tables, lens,
+                                   state_slots=ss),
+        donate_argnums=3).lower(
+        params, sds((b,)), sds((b,)), caches, sds((b,)), sds((b, 400)),
+        sds((b,)), sds((b,))).compile()
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*custom-call\([^\n]*"
+                       r"tpu_custom_call", compiled.as_text())
+    assert calls.count("_ragged_attention") == 1
+    assert calls.count("_recurrent_inplace") == 5
+    assert not _dispatch.native_fallbacks
+    memory = compiled.memory_analysis()
+    assert 12.0e9 < memory.argument_size_in_bytes < 12.2e9
+    assert memory.temp_size_in_bytes < 0.5e9

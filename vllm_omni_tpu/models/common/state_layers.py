@@ -115,7 +115,8 @@ def _shortconv_init(k, cfg, dtype):
 def _mamba_init(k, cfg, dtype):
     """One Mamba-2 mixer's weights, under the checkpoint's names
     (``mamba.in_proj``, ``conv1d``, ``A_log``, ``dt_bias``, ``D``,
-    ``norm``, ``out_proj``): ``in_proj``'s columns are [z | xBC | dt]; the
+    ``norm``, ``out_proj``): ``in_proj``'s columns are [z | xBC | dt], xBC
+    being u, then B a group, then C a group (``mamba_n_groups``); the
     taps lie [K, C] with ``w[K - 1]`` on the current token (the checkpoint
     keeps [C, 1, K]: a transpose in a loader), here with a bias.  ``A_log``,
     ``dt_bias`` and ``D`` are drawn as Mamba-2's own initialiser draws them
@@ -213,12 +214,15 @@ def _mamba_mixer(mb, cfg, h, recur):
     silu, the state-space rule and the skip (ops/ssd.py), which supplies
     what differs between the forwards (where a sequence's carried inputs
     and state are, the chunked or the one-token form) and returns y [T,
-    H, P] float32; then the gate ``silu(z)`` BEFORE an RMSNorm over all
-    heads (one group), and the out projection.  Everything between the two
-    projections is float32, as in ``_gdn_mixer`` and for its reason."""
+    H, P] float32; then the gate ``silu(z)`` BEFORE an RMSNorm taken a
+    group's channels at a time (all heads at once where there is one group;
+    ``d_inner / mamba_n_groups`` channels where there are several, the
+    weight being every channel's own), and the out projection.  Everything
+    between the two projections is float32, as in ``_gdn_mixer`` and for
+    its reason."""
     t = h.shape[0]
     n_h, d_h, _ = cfg.mamba_dims
-    d_inner = n_h * d_h
+    d_inner, groups = n_h * d_h, cfg.mamba_n_groups
     with jax.named_scope("omni.proj"):
         zxbcdt = jnp.dot(h, mb["in_proj"]["w"],
                          preferred_element_type=jnp.float32)
@@ -231,9 +235,12 @@ def _mamba_mixer(mb, cfg, h, recur):
         a = -jnp.exp(mb["A_log"].astype(jnp.float32)) * dt
         y = recur(xbc, dt, a, mb["conv"]["w"], mb["conv"]["b"], mb["D"])
         y = y.reshape(t, d_inner) * jax.nn.silu(z)
+        if groups > 1:
+            y = y.reshape(t, groups, d_inner // groups)
         y = y * jax.lax.rsqrt(
             jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
-        y = (y * mb["norm"]["w"].astype(jnp.float32)).astype(h.dtype)
+        y = (y.reshape(t, d_inner)
+             * mb["norm"]["w"].astype(jnp.float32)).astype(h.dtype)
     with jax.named_scope("omni.proj"):
         return nn.out_proj(y, mb["out_proj"]["w"], cfg.residual_in_fp32)
 
@@ -274,8 +281,9 @@ KINDS = {
         ragged=lambda cfg, cu_q_lens, q_lens: (
             lambda *a: ssd.mamba2_ragged(
                 *a, cu_q_lens, q_lens, cfg.mamba_dims,
-                chunk=cfg.mamba_chunk_size)),
+                chunk=cfg.mamba_chunk_size, groups=cfg.mamba_n_groups)),
         decode=lambda cfg: (
-            lambda *a: ssd.mamba2_decode(*a, cfg.mamba_dims)),
+            lambda *a: ssd.mamba2_decode(*a, cfg.mamba_dims,
+                                         groups=cfg.mamba_n_groups)),
         in_place=1),
 }

@@ -33,11 +33,18 @@ from vllm_omni_tpu.ops import (
     write_kv_cache,
 )
 from vllm_omni_tpu.ops import indexer as indexer_ops
+from vllm_omni_tpu.ops.activation import UNGATED
 from vllm_omni_tpu.ops.paged_attention import heads_a_row
 
 #: the kinds of layer whose sequences hold a state slot: what is
 #: particular to each is its record of ``state_layers.KINDS``
 STATE_KINDS = tuple(state_layers.KINDS)
+#: the kind of a layer that is its feed-forward part ALONE (Nemotron-H's
+#: ``E``): one norm, the sparse or dense MLP, the residual.  It has no
+#: sequence mixer, so no weights of one, no pages and no state slot; and a
+#: stack that has such layers is written ONE sublayer a layer, so its
+#: mixer layers have no feed-forward part either (``one_sublayer``)
+FEED_FORWARD = "feed_forward"
 
 
 @dataclass(frozen=True)
@@ -121,6 +128,18 @@ class TransformerConfig:
     # when ops.moe.set_ep_mesh was called) | "dense" (oracle: all experts
     # compute all tokens)
     moe_dispatch: str = "routed"
+    # the MLPs (dense, routed experts, shared expert alike): gated
+    # (``gate_up`` [.., 2 I]: ``act(gate) * up``, silu the one built) or
+    # ungated (``up`` [.., I]: ``down(act(up(x)))``, Nemotron-H's relu2).
+    # The forward reads which from the weights (``"gate_up"`` or ``"up"``)
+    mlp_gated: bool = True
+    mlp_activation: str = "silu"
+    # latent experts (> 0; Nemotron-H's ``moe_latent_size``): the routed
+    # experts read and write rows of this width, between a projection
+    # hidden -> latent before the dispatch and one latent -> hidden after
+    # the combine (``fc1_latent_proj`` / ``fc2_latent_proj``); the router
+    # and the shared expert still read the model's own row
+    moe_latent_size: int = 0
     # Tensor parallelism (Megatron col/row sharding over a mesh axis).
     # When set, the forward runs INSIDE shard_map over this axis with
     # per-shard weights (heads and MLP columns divided): psum after
@@ -183,8 +202,9 @@ class TransformerConfig:
     # 0.03-0.13 (PERF.md, PR 28)
     residual_in_fp32: bool = False
     # ---- layers of several kinds, as data: one of "full_attention",
-    # "sliding_attention", "linear_attention", "conv" a layer (the
-    # published ``layer_types``); None => derived from
+    # "sliding_attention", "linear_attention", "conv", "mamba",
+    # "feed_forward" a layer (the published ``layer_types``, or the
+    # letters of a ``hybrid_override_pattern``); None => derived from
     # ``full_attention_interval``
     layer_kinds: Optional[tuple[str, ...]] = None
     # a "conv" layer is a gated short convolution (LFM2): ``[B | C | u] =
@@ -216,17 +236,19 @@ class TransformerConfig:
     # a "mamba" layer is Mamba-2 (Granite 4.0-H): ``[z | xBC | dt] = x
     # W_in``, a depth-wise causal convolution of ``mamba_conv_kernel`` taps
     # with a bias over ``xBC`` and a silu, then the state-space rule over
-    # ``mamba_num_heads`` heads of ``mamba_head_dim`` with one group of B
-    # and C of ``mamba_state_dim`` (ops/ssd.py), the gate ``silu(z)``, an
-    # RMSNorm over all heads and ``W_out``.  A sequence carries the
-    # convolution's last inputs and a ``[heads, head_dim, state_dim]``
-    # float32 state, which the rule updates where it lies in the slots
-    # (docs/kv_cache.md, "Four kinds of state")
+    # ``mamba_num_heads`` heads of ``mamba_head_dim`` with
+    # ``mamba_n_groups`` groups of B and C of ``mamba_state_dim`` (head h
+    # reads group h // (heads / groups); ops/ssd.py), the gate ``silu(z)``,
+    # an RMSNorm a group's channels at a time and ``W_out``.  A sequence
+    # carries the convolution's last inputs and a ``[heads, head_dim,
+    # state_dim]`` float32 state, which the rule updates where it lies in
+    # the slots (docs/kv_cache.md, "Four kinds of state")
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     mamba_state_dim: int = 0
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 256
+    mamba_n_groups: int = 1
     # attention without positions (``position_embedding_type`` "nope"):
     # no table is built and neither queries nor keys are rotated
     use_rope: bool = True
@@ -251,9 +273,23 @@ class TransformerConfig:
             for i in range(self.num_layers))
 
     @property
+    def one_sublayer(self) -> bool:
+        """Whether a layer is ONE sublayer, a mixer or a feed-forward part
+        (the stack has ``FEED_FORWARD`` layers), and not a mixer and then a
+        feed-forward part."""
+        return FEED_FORWARD in self.layer_types
+
+    @property
+    def kv_layer_types(self) -> tuple[str, ...]:
+        """The kinds of the layers that hold KV pages, in layer order: the
+        attention ones, neither a state kind nor a layer without a mixer."""
+        return tuple(kind for kind in self.layer_types
+                     if kind not in STATE_KINDS and kind != FEED_FORWARD)
+
+    @property
     def num_kv_layers(self) -> int:
         """Layers that hold KV pages (the softmax-attention ones)."""
-        return self.num_layers - self.num_state_layers
+        return len(self.kv_layer_types)
 
     @property
     def num_window_layers(self) -> int:
@@ -337,9 +373,10 @@ class TransformerConfig:
 
     @property
     def mamba_conv_dim(self) -> int:
-        """Channels of a Mamba-2 layer's convolution: u, B and C."""
+        """Channels of a Mamba-2 layer's convolution: u, then B and C a
+        group."""
         return (self.mamba_num_heads * self.mamba_head_dim
-                + 2 * self.mamba_state_dim)
+                + 2 * self.mamba_n_groups * self.mamba_state_dim)
 
     @property
     def gdn_heads(self) -> tuple[int, int, int, int]:
@@ -398,8 +435,6 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
         params["lm_head"] = nn.linear_init(
             keys[1], cfg.hidden_size, cfg.vocab_size, bias=False, dtype=dtype
         )
-    q_dim = cfg.num_heads * cfg.head_dim
-    kv_dim = cfg.num_kv_heads * cfg.head_dim
 
     def norm_init(key, dim):
         if not cfg.norm_zero_centered:
@@ -414,91 +449,125 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
     for i, kind in enumerate(cfg.layer_types):
         k = jax.random.split(keys[i + 3], 8)
         kn = jax.random.split(k[4], 4)
-        qkv_bias = cfg.attention_bias
-        layer = {
-            "input_norm": norm_init(kn[0], cfg.hidden_size),
-            "post_norm": norm_init(kn[1], cfg.hidden_size),
-        }
-        record = state_layers.KINDS.get(kind)
-        if record:
-            layer[record.key] = record.init(k, cfg, dtype)
-        elif cfg.kv_lora_rank:
-            lk = cfg.latent_kind(kind)
-            layer["mla"] = _mla_init(k, cfg, lk, dtype)
-            layer["o_proj"] = nn.linear_init(
-                k[3], lk.num_heads * lk.v_head_dim, cfg.hidden_size,
-                bias=False, dtype=dtype)
-            if cfg.attn_head_gate:
-                layer["attn_gate"] = nn.linear_init(
-                    jax.random.fold_in(k[3], 1), cfg.hidden_size,
-                    lk.num_heads, bias=False, dtype=dtype)
-            if cfg.index_topk and kind == "full_attention":
-                layer["indexer"] = _indexer_init(
-                    jax.random.fold_in(k[3], 2), cfg, lk, dtype)
-        else:
-            # gated attention doubles q_proj: a head's columns are [q | gate]
-            q_out = q_dim * (2 if cfg.attn_output_gate else 1)
-            layer.update({
-                "q_proj": nn.linear_init(k[0], cfg.hidden_size, q_out, bias=qkv_bias, dtype=dtype),
-                "k_proj": nn.linear_init(k[1], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
-                "v_proj": nn.linear_init(k[2], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
-                "o_proj": nn.linear_init(k[3], q_dim, cfg.hidden_size, bias=False, dtype=dtype),
-            })
-        if cfg.moe and i >= cfg.first_k_dense_replace:
-            e = cfg.num_experts
-            inter = cfg.moe_intermediate_size or cfg.intermediate_size
-            scale_in = 1.0 / (cfg.hidden_size ** 0.5)
-            scale_out = 1.0 / (inter ** 0.5)
-            k6, k7 = jax.random.split(k[6])
-            layer["router"] = nn.linear_init(
-                k[5], cfg.hidden_size, cfg.router_experts or e, bias=False,
-                dtype=dtype
-            )
-            if cfg.router_bias:
-                # the learned correction of the selection; float32 as the
-                # checkpoint keeps it.  The published initialiser writes
-                # zeros: a little noise here keeps "selected on s + b,
-                # weighted by s" visible to every comparison
-                layer["router"]["e_bias"] = jax.random.uniform(
-                    jax.random.fold_in(k[5], 1),
-                    (cfg.router_experts or e,), jnp.float32,
-                    minval=-0.05, maxval=0.05)
-            # stacked expert weights: leading E axis is the EP shard axis
-            layer["experts"] = {
-                "gate_up": jax.random.uniform(
-                    k6, (e, cfg.hidden_size, 2 * inter), dtype,
-                    minval=-scale_in, maxval=scale_in,
-                ),
-                "down": jax.random.uniform(
-                    k7, (e, inter, cfg.hidden_size), dtype,
-                    minval=-scale_out, maxval=scale_out,
-                ),
-            }
-            if cfg.shared_expert_size:
-                ks1, ks2, ks3 = jax.random.split(k[7], 3)
-                layer["shared_expert"] = {
-                    "gate_up": nn.linear_init(
-                        ks1, cfg.hidden_size, 2 * cfg.shared_expert_size,
-                        bias=False, dtype=dtype),
-                    "down": nn.linear_init(
-                        ks2, cfg.shared_expert_size, cfg.hidden_size,
-                        bias=False, dtype=dtype),
-                }
-                if cfg.shared_expert_gate:
-                    layer["shared_gate"] = nn.linear_init(
-                        ks3, cfg.hidden_size, 1, bias=False, dtype=dtype)
-        else:
-            layer["gate_up"] = nn.linear_init(
-                k[4], cfg.hidden_size, 2 * cfg.intermediate_size, bias=False, dtype=dtype
-            )
-            layer["down"] = nn.linear_init(
-                k[5], cfg.intermediate_size, cfg.hidden_size, bias=False, dtype=dtype
-            )
-        if cfg.qk_norm and kind == "full_attention" and not cfg.kv_lora_rank:
-            layer["q_norm"] = norm_init(kn[2], cfg.head_dim)
-            layer["k_norm"] = norm_init(kn[3], cfg.head_dim)
+        # a layer is its mixer's half (``input_norm`` and the mixer) and
+        # its feed-forward half (``post_norm`` and the MLP).  In a stack of
+        # one sublayer a layer it holds ONE of the two, and what it holds
+        # is what ``_layer_step`` runs: nothing stands in for the other
+        layer = {}
+        if kind != FEED_FORWARD:
+            layer["input_norm"] = norm_init(kn[0], cfg.hidden_size)
+            layer.update(_mixer_init(k, kn, cfg, kind, norm_init, dtype))
+        if kind == FEED_FORWARD or not cfg.one_sublayer:
+            layer["post_norm"] = norm_init(kn[1], cfg.hidden_size)
+            layer.update(_mlp_init(
+                k, cfg, cfg.moe and i >= cfg.first_k_dense_replace, dtype))
         params["layers"].append(layer)
     return params
+
+
+def _mixer_init(k, kn, cfg: TransformerConfig, kind: str, norm_init, dtype):
+    """The sequence mixer's weights of a layer of ``kind``, from the
+    layer's eight keys ``k`` and its norms' four ``kn``."""
+    record = state_layers.KINDS.get(kind)
+    if record:
+        return {record.key: record.init(k, cfg, dtype)}
+    if cfg.kv_lora_rank:
+        lk = cfg.latent_kind(kind)
+        mixer = {"mla": _mla_init(k, cfg, lk, dtype),
+                 "o_proj": nn.linear_init(
+                     k[3], lk.num_heads * lk.v_head_dim, cfg.hidden_size,
+                     bias=False, dtype=dtype)}
+        if cfg.attn_head_gate:
+            mixer["attn_gate"] = nn.linear_init(
+                jax.random.fold_in(k[3], 1), cfg.hidden_size,
+                lk.num_heads, bias=False, dtype=dtype)
+        if cfg.index_topk and kind == "full_attention":
+            mixer["indexer"] = _indexer_init(
+                jax.random.fold_in(k[3], 2), cfg, lk, dtype)
+        return mixer
+    q_dim = cfg.num_heads * cfg.head_dim
+    kv_dim = cfg.num_kv_heads * cfg.head_dim
+    qkv_bias = cfg.attention_bias
+    # gated attention doubles q_proj: a head's columns are [q | gate]
+    q_out = q_dim * (2 if cfg.attn_output_gate else 1)
+    mixer = {
+        "q_proj": nn.linear_init(k[0], cfg.hidden_size, q_out, bias=qkv_bias, dtype=dtype),
+        "k_proj": nn.linear_init(k[1], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
+        "v_proj": nn.linear_init(k[2], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
+        "o_proj": nn.linear_init(k[3], q_dim, cfg.hidden_size, bias=False, dtype=dtype),
+    }
+    if cfg.qk_norm and kind == "full_attention":
+        mixer["q_norm"] = norm_init(kn[2], cfg.head_dim)
+        mixer["k_norm"] = norm_init(kn[3], cfg.head_dim)
+    return mixer
+
+
+def _mlp_init(k, cfg: TransformerConfig, sparse: bool, dtype):
+    """The feed-forward part's weights of a layer, from the layer's eight
+    keys: the router, the stacked experts and the shared expert where the
+    layer is ``sparse``, a dense MLP of ``intermediate_size`` where it is
+    not.  Gated MLPs keep gate and up fused (``gate_up``), ungated ones
+    have ``up`` alone: the forward reads which from the name."""
+    up_name, up_cols = ("gate_up", 2) if cfg.mlp_gated else ("up", 1)
+    if not sparse:
+        return {
+            up_name: nn.linear_init(
+                k[4], cfg.hidden_size, up_cols * cfg.intermediate_size,
+                bias=False, dtype=dtype),
+            "down": nn.linear_init(
+                k[5], cfg.intermediate_size, cfg.hidden_size, bias=False,
+                dtype=dtype),
+        }
+    e = cfg.num_experts
+    inter = cfg.moe_intermediate_size or cfg.intermediate_size
+    # what an expert reads and writes: the model's row, or the latent's
+    # between its two projections
+    width = cfg.moe_latent_size or cfg.hidden_size
+    scale_in = 1.0 / (width ** 0.5)
+    scale_out = 1.0 / (inter ** 0.5)
+    k6, k7 = jax.random.split(k[6])
+    mlp = {"router": nn.linear_init(
+        k[5], cfg.hidden_size, cfg.router_experts or e, bias=False,
+        dtype=dtype)}
+    if cfg.router_bias:
+        # the learned correction of the selection; float32 as the
+        # checkpoint keeps it.  The published initialiser writes zeros: a
+        # little noise here keeps "selected on s + b, weighted by s"
+        # visible to every comparison
+        mlp["router"]["e_bias"] = jax.random.uniform(
+            jax.random.fold_in(k[5], 1), (cfg.router_experts or e,),
+            jnp.float32, minval=-0.05, maxval=0.05)
+    # stacked expert weights: leading E axis is the EP shard axis
+    mlp["experts"] = {
+        up_name: jax.random.uniform(
+            k6, (e, width, up_cols * inter), dtype,
+            minval=-scale_in, maxval=scale_in),
+        "down": jax.random.uniform(
+            k7, (e, inter, width), dtype,
+            minval=-scale_out, maxval=scale_out),
+    }
+    if cfg.moe_latent_size:
+        # the checkpoint's names without their suffix
+        mlp["fc1_latent_proj"] = nn.linear_init(
+            jax.random.fold_in(k[6], 1), cfg.hidden_size, width,
+            bias=False, dtype=dtype)
+        mlp["fc2_latent_proj"] = nn.linear_init(
+            jax.random.fold_in(k[6], 2), width, cfg.hidden_size,
+            bias=False, dtype=dtype)
+    if cfg.shared_expert_size:
+        ks1, ks2, ks3 = jax.random.split(k[7], 3)
+        mlp["shared_expert"] = {
+            up_name: nn.linear_init(
+                ks1, cfg.hidden_size, up_cols * cfg.shared_expert_size,
+                bias=False, dtype=dtype),
+            "down": nn.linear_init(
+                ks2, cfg.shared_expert_size, cfg.hidden_size,
+                bias=False, dtype=dtype),
+        }
+        if cfg.shared_expert_gate:
+            mlp["shared_gate"] = nn.linear_init(
+                ks3, cfg.hidden_size, 1, bias=False, dtype=dtype)
+    return mlp
 
 
 def _mla_init(k, cfg: TransformerConfig, lk: LatentKind, dtype):
@@ -582,15 +651,41 @@ def _rms(cfg: TransformerConfig, x, norm):
     return rms_norm(x, w, cfg.rms_eps)
 
 
-def _moe_mlp_dense(layer, cfg: TransformerConfig, x):
+def _ungated_act(weights, cfg: TransformerConfig):
+    """None for a gated MLP (its weights hold ``gate_up``: ``silu(gate) *
+    up``, the one gated form built); the activation of an ungated one
+    (``up`` alone), by the configuration's name for it."""
+    if "gate_up" in weights:
+        if cfg.mlp_activation != "silu":
+            raise NotImplementedError(
+                f"a gated MLP with {cfg.mlp_activation!r}: only silu")
+        return None
+    return UNGATED[cfg.mlp_activation]
+
+
+def _dense_mlp(weights, cfg: TransformerConfig, x):
+    """One dense MLP (a layer's own, or a shared expert): ``down(silu(gate)
+    * up)`` where it is gated, ``down(act(up(x)))`` where it is not."""
+    act = _ungated_act(weights, cfg)
+    if act is None:
+        return nn.linear(weights["down"],
+                         silu_mul(nn.linear(weights["gate_up"], x)))
+    return nn.linear(weights["down"], act(nn.linear(weights["up"], x)))
+
+
+def _moe_mlp_dense(layer, cfg: TransformerConfig, x, rows=None):
     """Dense-dispatch MoE oracle: every expert computes every token,
     combined with the (renormalized) top-k router weights as a [T, E]
     mask.  Kept as the numerics oracle for the routed path (and the
     GSPMD fallback when neither routing mode applies); a k/E FLOP waste
-    at real geometries (VERDICT r1 weak#4)."""
+    at real geometries (VERDICT r1 weak#4).  The router reads ``x``, the
+    experts take ``rows`` (None: ``x`` itself; else its latent)."""
     from vllm_omni_tpu.ops import moe as moe_ops
 
     t = x.shape[0]
+    rows = x if rows is None else rows
+    experts = layer["experts"]
+    act = _ungated_act(experts, cfg)
     topk_idx, topk_w = moe_ops.router_topk(
         x, layer["router"]["w"], cfg.num_experts_per_tok,
         cfg.moe_renormalize, **_router_kwargs(layer, cfg))
@@ -599,13 +694,14 @@ def _moe_mlp_dense(layer, cfg: TransformerConfig, x):
                         jnp.float32).at[
         jnp.arange(t)[:, None], topk_idx
     ].set(topk_w)
-    held = layer["experts"]["gate_up"].shape[0]
+    held = experts["down"].shape[0]
     if held != combine.shape[-1]:  # an expert share: the held columns only
         combine = jax.lax.dynamic_slice_in_dim(
             combine, cfg.expert_offset, held, axis=1)
-    h = jnp.einsum("th,ehf->etf", x, layer["experts"]["gate_up"])
-    h = silu_mul(h)
-    y = jnp.einsum("etf,efh->eth", h, layer["experts"]["down"])
+    h = jnp.einsum("th,ehf->etf", rows,
+                   experts["gate_up" if act is None else "up"])
+    h = silu_mul(h) if act is None else act(h)
+    y = jnp.einsum("etf,efh->eth", h, experts["down"])
     return jnp.einsum("eth,te->th", y, combine.astype(x.dtype))
 
 
@@ -626,18 +722,43 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
     """Top-k MoE dispatch.  Default: routed grouped-matmul (ops/moe.py —
     FLOPs scale with top-k, not E), expert-parallel over the mesh ``ep``
     axis when one is registered via ``ops.moe.set_ep_mesh``.  The dense
-    path (``cfg.moe_dispatch == "dense"``) is the test oracle."""
+    path (``cfg.moe_dispatch == "dense"``) is the test oracle.
+
+    What the experts are, their weights say: gated SwiGLU (``gate_up``) or
+    ungated (``up``, the configuration's activation).  Where the layer
+    holds the two latent projections the experts work in the latent: the
+    router and the shared expert read the model's row ``x``, the dispatch
+    takes ``x W_1`` and its result goes back through ``W_2`` (both under
+    ``omni.moe_latent``).  On an expert share ``W_2`` is applied to what
+    the held experts gave: it is linear and has no bias, so the shares of
+    a deployment still add up to the layer."""
     lead = x.shape[:-1]
     x = x.reshape(-1, x.shape[-1])
+    experts = layer["experts"]
+    up = experts.get("gate_up", experts.get("up"))
+    # what the dispatch is told beyond the gated, full-width default: {}
+    # for every model that has neither ungated nor latent experts
+    form = {}
+    if "gate_up" not in experts:
+        form["act"] = _ungated_act(experts, cfg)
+    rows = x
+    if "fc1_latent_proj" in layer:
+        with jax.named_scope("omni.moe_latent"):
+            rows = nn.linear(layer["fc1_latent_proj"], x)
+        form["route_on"] = x
     if cfg.moe_dispatch == "dense":
-        out = _moe_mlp_dense(layer, cfg, x)
+        out = _moe_mlp_dense(layer, cfg, x, rows)
     else:
         from vllm_omni_tpu.ops import moe as moe_ops
 
         mesh = moe_ops.ep_mesh()
         router = _router_kwargs(layer, cfg)
-        shared_out = layer["router"]["w"].shape[-1] != \
-            layer["experts"]["gate_up"].shape[0]
+        shared_out = layer["router"]["w"].shape[-1] != up.shape[0]
+        if mesh is not None and form:
+            raise NotImplementedError(
+                "ungated or latent experts over an ep mesh are not built "
+                "(the exchange at the latent's width); one chip serves "
+                "them whole or as an expert share")
         if mesh is not None and cfg.moe_dispatch == "a2a":
             if router:
                 raise NotImplementedError(
@@ -646,16 +767,16 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
                     "scaled router rides the psum dispatch (moe_dispatch "
                     "'routed') or an expert share")
             out = moe_ops.routed_moe_ep_a2a(
-                x, layer["router"]["w"], layer["experts"]["gate_up"],
-                layer["experts"]["down"], cfg.num_experts_per_tok, mesh,
+                x, layer["router"]["w"], up, experts["down"],
+                cfg.num_experts_per_tok, mesh,
                 renormalize=cfg.moe_renormalize,
             )
         elif mesh is not None:
             # the shards route as routed_moe does (the activations'
             # dtype) unless the router says otherwise
             out = moe_ops.routed_moe_ep(
-                x, layer["router"]["w"], layer["experts"]["gate_up"],
-                layer["experts"]["down"], cfg.num_experts_per_tok, mesh,
+                x, layer["router"]["w"], up, experts["down"],
+                cfg.num_experts_per_tok, mesh,
                 renormalize=cfg.moe_renormalize, **router,
             )
         elif shared_out:
@@ -663,23 +784,23 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
             # over the router's whole width, compute what the experts
             # held here give, no exchange
             out = moe_ops.routed_moe_share(
-                x, layer["router"]["w"], layer["experts"]["gate_up"],
-                layer["experts"]["down"], cfg.num_experts_per_tok,
-                cfg.expert_offset, renormalize=cfg.moe_renormalize,
-                **router,
+                rows, layer["router"]["w"], up, experts["down"],
+                cfg.num_experts_per_tok, cfg.expert_offset,
+                renormalize=cfg.moe_renormalize, **router, **form,
             )
         else:
             out = moe_ops.routed_moe(
-                x, layer["router"]["w"], layer["experts"]["gate_up"],
-                layer["experts"]["down"], cfg.num_experts_per_tok,
-                renormalize=cfg.moe_renormalize, **router,
+                rows, layer["router"]["w"], up, experts["down"],
+                cfg.num_experts_per_tok,
+                renormalize=cfg.moe_renormalize, **router, **form,
             )
+    if "fc2_latent_proj" in layer:
+        with jax.named_scope("omni.moe_latent"):
+            out = nn.linear(layer["fc2_latent_proj"], out)
     if "shared_expert" in layer:
         # always-on shared expert: sigmoid-gated per token where the
         # layer has the gate, added whole where it has none
-        se = nn.linear(layer["shared_expert"]["down"],
-                       silu_mul(nn.linear(layer["shared_expert"]["gate_up"],
-                                          x)))
+        se = _dense_mlp(layer["shared_expert"], cfg, x)
         if "shared_gate" in layer:
             gate = jax.nn.sigmoid(
                 nn.linear(layer["shared_gate"], x).astype(jnp.float32))
@@ -691,7 +812,7 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
 def _mlp(layer, cfg: TransformerConfig, x):
     if "experts" in layer:
         return _moe_mlp(layer, cfg, x)
-    return nn.linear(layer["down"], silu_mul(nn.linear(layer["gate_up"], x)))
+    return _dense_mlp(layer, cfg, x)
 
 
 @jax.named_scope("omni.proj")
@@ -916,7 +1037,10 @@ def _join_residual(cfg: TransformerConfig, x, branch):
 def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
                 kind: str = "full_attention"):
     """One transformer block: norm → mixer → residual → norm → MLP →
-    residual.  In a softmax-attention layer the mixer is qkv+rope →
+    residual; or, in a stack written one sublayer a layer (Nemotron-H),
+    the ONE of those two halves that the layer's weights hold (an
+    ``input_norm`` with its mixer, or a ``post_norm`` with its MLP).  In a
+    softmax-attention layer the mixer is qkv+rope →
     ``attend(q, k, v)``, which supplies the attention variant (dense
     causal / cached-context chunked / paged decode) and returns o with
     leading dims matching ``x``'s; in a latent-attention layer (``"mla"``
@@ -926,10 +1050,27 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     ``attend`` is the ``recur`` of that kind's mixer.  The MLP is the sparse
     one where the layer holds ``experts``, a dense SwiGLU where it does not.
     One body serves every forward so the variants cannot silently diverge."""
-    b = x.shape[:-1]
-    act = layer["input_norm"]["w"].dtype
     if cfg.residual_in_fp32:
         x = x.astype(jnp.float32)
+    if "input_norm" in layer:
+        x = _mixer_half(layer, cfg, x, cos, sin, attend, kind)
+    if "post_norm" not in layer:
+        return x
+    with jax.named_scope("omni.proj"):
+        h = _rms(cfg, x, layer["post_norm"]).astype(
+            layer["post_norm"]["w"].dtype)
+    with jax.named_scope("omni.moe" if "experts" in layer else "omni.mlp"):
+        y = _mlp(layer, cfg, h)
+        if cfg.tp_axis is not None:
+            y = jax.lax.psum(y, cfg.tp_axis)
+        return _join_residual(cfg, x, y)
+
+
+def _mixer_half(layer, cfg: TransformerConfig, x, cos, sin, attend,
+                kind: str):
+    """``x + mixer(rms(x))``: the first half of ``_layer_step``."""
+    b = x.shape[:-1]
+    act = layer["input_norm"]["w"].dtype
     with jax.named_scope("omni.proj"):
         h = _rms(cfg, x, layer["input_norm"]).astype(act)
     record = state_layers.KINDS.get(kind)
@@ -958,13 +1099,7 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
         if cfg.tp_axis is not None:
             # row-parallel o_proj: each shard holds a partial sum
             o = jax.lax.psum(o, cfg.tp_axis)
-        x = _join_residual(cfg, x, o)
-        h = _rms(cfg, x, layer["post_norm"]).astype(act)
-    with jax.named_scope("omni.moe" if "experts" in layer else "omni.mlp"):
-        y = _mlp(layer, cfg, h)
-        if cfg.tp_axis is not None:
-            y = jax.lax.psum(y, cfg.tp_axis)
-        return _join_residual(cfg, x, y)
+        return _join_residual(cfg, x, o)
 
 
 class HybridCaches(NamedTuple):
@@ -985,10 +1120,12 @@ def _layer_caches(params, cfg: TransformerConfig, kv_caches, state_caches):
     layer: the write and the kernels take the absent value as "read it
     from the key's row"), the state slots of a layer that carries one.
     ``kv_caches`` holds one entry for each attention layer only,
-    ``state_caches`` one for each of the others."""
+    ``state_caches`` one for each layer that carries a state; a layer that
+    is its feed-forward part alone has no mixer and so no cache (None)."""
     kv, st = iter(kv_caches), iter(state_caches or ())
     for layer, kind in zip(params["layers"], cfg.layer_types):
-        yield layer, kind, next(st if kind in state_layers.KINDS else kv)
+        yield layer, kind, (None if kind == FEED_FORWARD else next(
+            st if kind in state_layers.KINDS else kv))
 
 
 def _read_state(cache, slots, fresh, scope):

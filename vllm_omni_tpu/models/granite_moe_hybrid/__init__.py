@@ -27,8 +27,9 @@ Not served, and refused or absent by name:
 - what the engine refuses for any model with state slots (offload,
   transfer, speculation, an int8 cache, tensor parallelism) and a prefix
   hit, one by one (docs/kv_cache.md);
-- more than one group of B and C (``mamba_n_groups`` 1 is what the model
-  has);
+- (more than one group of B and C IS served since PR 46:
+  ``TransformerConfig.mamba_n_groups``, models/nemotron_h; this model has
+  one, and with one the traced program is the one it was);
 - a loader for the checkpoint's tensor names: there is nothing here to
   test one on.  The weight tree keeps the checkpoint's names without their
   suffixes (``state_layers._mamba_init``), the taps transposed.

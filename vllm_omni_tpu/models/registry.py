@@ -191,6 +191,12 @@ _AR_MODELS: dict[str, _Entry] = {
     "GraniteMoeHybridForCausalLM": _Entry(
         "vllm_omni_tpu.models.granite_moe_hybrid", "real_factory"
     ),
+    # layers of ONE sublayer (a Mamba-2 mixer with eight groups of B and C,
+    # attention without positions, or ungated latent experts alone)
+    # (NVIDIA-Nemotron-3-Super-120B-A12B; docs/kv_cache.md)
+    "NemotronHForCausalLM": _Entry(
+        "vllm_omni_tpu.models.nemotron_h", "real_factory"
+    ),
 }
 
 

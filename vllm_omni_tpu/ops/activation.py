@@ -19,3 +19,13 @@ def silu_mul(gate_up: jax.Array) -> jax.Array:
 def gelu_tanh_mul(gate_up: jax.Array) -> jax.Array:
     gate, up = jnp.split(gate_up, 2, axis=-1)
     return jax.nn.gelu(gate, approximate=True) * up
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """``relu(x)^2``: the activation of an UNGATED MLP (Nemotron-H's
+    ``mlp_hidden_act`` "relu2"): ``down(relu2(up(x)))``, no gate half."""
+    return jnp.square(jax.nn.relu(x))
+
+
+#: the activation of an ungated MLP, by the name a configuration gives it
+UNGATED = {"relu2": relu2, "silu": jax.nn.silu}

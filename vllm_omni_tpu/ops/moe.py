@@ -95,10 +95,13 @@ def router_topk(x, router_w, num_experts_per_tok: int,
     return topk_idx, topk_w
 
 
-def _grouped_mlp(xs, gate_up, down, group_sizes):
-    """One grouped-matmul MLP over expert-sorted rows."""
+def _grouped_mlp(xs, gate_up, down, group_sizes, act=None):
+    """One grouped-matmul MLP over expert-sorted rows: gated SwiGLU
+    (``gate_up`` [E, H, 2 I]: ``silu(gate) * up``) where ``act`` is None,
+    else ungated, ``down(act(up(x)))`` with ``gate_up`` the experts' ``up``
+    [E, H, I] alone."""
     h = jax.lax.ragged_dot(xs, gate_up, group_sizes)
-    h = silu_mul(h)
+    h = silu_mul(h) if act is None else act(h)
     return jax.lax.ragged_dot(h, down, group_sizes)
 
 
@@ -127,26 +130,35 @@ def routed_moe(
     down: jax.Array,       # [E, inter, hidden]
     num_experts_per_tok: int,
     renormalize: bool = True,
+    act=None,       # ungated experts: their activation, ``gate_up`` their up
+    route_on=None,  # the rows the router reads, where they are not ``x``
     **router,  # scoring / bias / scale / float32_logits of ``router_topk``
 ) -> jax.Array:
     """Top-k routed MoE on one shard: sort pairs by expert, grouped
     matmul, each token's k rows gathered back and summed under the
-    router's weights in float32 (``_combine``)."""
+    router's weights in float32 (``_combine``).  ``act`` makes the experts
+    ungated (``_grouped_mlp``).  ``route_on`` [T, router's input width]
+    separates the rows the router scores from the rows the experts take:
+    latent experts (Nemotron-H) are picked on the model's row and work on
+    its projection ``x`` into a narrower latent, whose width the result
+    keeps."""
     e = gate_up.shape[0]
     k = num_experts_per_tok
-    topk_idx, topk_w = router_topk(x, router_w, k, renormalize, **router)
+    topk_idx, topk_w = router_topk(x if route_on is None else route_on,
+                                   router_w, k, renormalize, **router)
 
     flat_e = topk_idx.reshape(-1)                    # [T*k]
     order = jnp.argsort(flat_e)                      # stable
     xs = x[order // k]                               # [T*k, hidden]
     group_sizes = jnp.bincount(flat_e, length=e)
-    y = _grouped_mlp(xs, gate_up, down, group_sizes)  # [T*k, hidden]
+    y = _grouped_mlp(xs, gate_up, down, group_sizes, act)  # [T*k, hidden]
     return _combine(y, order, topk_w).astype(x.dtype)
 
 
 def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
                      renormalize: bool = True,
-                     float32_logits: bool = True, **router) -> jax.Array:
+                     float32_logits: bool = True, act=None, route_on=None,
+                     **router) -> jax.Array:
     """What the experts held here give: ``gate_up`` / ``down`` stack the
     experts ``expert_offset .. expert_offset + E_held`` of a router that
     is ``router_w.shape[-1]`` wide.  Every token is routed over the whole
@@ -164,10 +176,13 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     chip's share of an expert-parallel deployment (the model-configs
     cut) and, followed by a psum, the body of every ``ep`` shard.  ``router`` is ``router_topk``'s scoring, bias and scale: the
     share of a sigmoid router with its correction bias routes as the
-    whole model does."""
+    whole model does.  ``act`` and ``route_on`` as ``routed_moe`` takes
+    them: the share of latent experts is a latent row, and a projection
+    out of the latent that is linear and has no bias may be applied to
+    each share's own part (the shares still add up)."""
     e_held = gate_up.shape[0]
     topk_idx, topk_w = router_topk(
-        x, router_w, k, renormalize,
+        x if route_on is None else route_on, router_w, k, renormalize,
         **{"float32_logits": float32_logits, **router})
     flat_e = topk_idx.reshape(-1) - expert_offset
     mine = (flat_e >= 0) & (flat_e < e_held)
@@ -177,7 +192,7 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     token_of = order // k
     xs = x[token_of]
     group_sizes = jnp.bincount(local_e, length=e_held + 1)[:e_held]
-    y = _grouped_mlp(xs, gate_up, down, group_sizes)
+    y = _grouped_mlp(xs, gate_up, down, group_sizes, act)
     w = topk_w.reshape(-1)[order]
     y = jnp.where(mine[order][:, None], y * w[:, None].astype(y.dtype), 0)
     out = jnp.zeros((x.shape[0], x.shape[1]), y.dtype).at[token_of].add(y)

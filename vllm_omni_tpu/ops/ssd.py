@@ -7,10 +7,12 @@ state width) kept in float32::
     S_t = exp(a_t) * S_{t-1} + dt_t * u_t B_t^T      # a_t = -exp(A_log) dt_t
     y_t = S_t C_t
 
-``B_t`` and ``C_t`` ``[N]`` are shared by all heads (one group), ``u_t``
+``B_t`` and ``C_t`` ``[N]`` are a GROUP's: head ``h`` of ``H`` reads those of
+group ``h // (H / G)``.  With one group (Granite 4.0-H) they are ``[T, N]``
+and every head's; with several (Nemotron-H: eight) ``[T, G, N]``.  ``u_t``
 ``[P]``, ``a_t <= 0`` and ``dt_t > 0`` are a head's.  (Reference: "Transformers
-are SSMs", Dao and Gu 2024, and the ``mamba2`` mixer of the Granite 4.0-H
-family's public configuration.)
+are SSMs", Dao and Gu 2024, and the ``mamba2`` mixers of the Granite 4.0-H
+and Nemotron-H families' public configurations.)
 
 A sequence's state lives in its slot of one pool ``[slots, H, P, N]`` a
 layer (4 MB a sequence at the published 128 x 64 x 128), and that pool is
@@ -78,19 +80,29 @@ HEAD_BLOCK = 32
 # ----------------------------------------------------------------- oracle
 def ssd_scan(u, dt, a, b, c, state):
     """The recurrence token by token over one sequence (u [T, H, P]; dt, a
-    [T, H]; b, c [T, N]; state [H, P, N]): the plain form both served forms
-    are tested against, never the served path."""
+    [T, H]; b, c [T, N], or [T, G, N] a group; state [H, P, N]): the plain
+    form both served forms are tested against, never the served path."""
     def step(s, x):
         u_t, dt_t, a_t, b_t, c_t = x
         s = jnp.exp(a_t)[:, None, None] * s \
-            + (dt_t[:, None] * u_t)[:, :, None] * b_t[None, None, :]
-        return s, jnp.sum(s * c_t[None, None, :], axis=-1)
+            + (dt_t[:, None] * u_t)[:, :, None] * b_t[:, None, :]
+        return s, jnp.sum(s * c_t[:, None, :], axis=-1)
 
     f32 = jnp.float32
+    b, c = _per_head(b, u.shape[1]), _per_head(c, u.shape[1])
     state, y = jax.lax.scan(
         step, state.astype(f32),
         tuple(x.astype(f32) for x in (u, dt, a, b, c)))
     return y, state
+
+
+def _per_head(x, heads: int):
+    """B or C a head: ``[R, N]`` (one group, every head's) or ``[R, G, N]``
+    -> ``[R, H, N]``, head ``h`` reading group ``h // (H / G)``."""
+    if x.ndim == 2:
+        return jnp.broadcast_to(x[:, None, :],
+                                (x.shape[0], heads, x.shape[-1]))
+    return jnp.repeat(x, heads // x.shape[1], axis=1)
 
 
 # ------------------------------------------------- one token a row, in place
@@ -101,8 +113,8 @@ def _recurrent_kernel(
     # inputs
     xdt_ref,     # [1, 1, P, hb]: dt * u, heads along the lanes
     dec_ref,     # [1, 1, P, hb]: exp(a), a head's value down its column
-    b_ref,       # [1, 1, N]
-    c_ref,       # [1, 1, N]
+    b_ref,       # [1, 1, N]; with several groups [1, 1, gb, N]: the gb
+    c_ref,       # groups whose heads the block holds, hb / gb heads each
     pool_ref,    # [1, hb, P, N]: the row's slot (aliased to pool_out)
     # outputs
     y_ref,       # [1, 1, P, hb]
@@ -115,8 +127,14 @@ def _recurrent_kernel(
     @pl.when(live)
     def _():
         keep = jnp.where(flags >= 2, 0.0, 1.0)  # a new sequence: zeros
-        b_row, c_row = b_ref[0], c_ref[0]        # [1, N]
-        for h in range(pool_ref.shape[1]):
+        hb = pool_ref.shape[1]
+        if len(b_ref.shape) == 3:
+            rows = [(b_ref[0], c_ref[0])]            # [1, N], every head's
+        else:
+            rows = [(b_ref[0, 0, g:g + 1], c_ref[0, 0, g:g + 1])
+                    for g in range(b_ref.shape[2])]
+        for h in range(hb):
+            b_row, c_row = rows[h * len(rows) // hb]
             # a head's column [P, 1] broadcasts along the lanes, B and C's
             # row [1, N] down the sublanes
             s = (dec_ref[0, 0, :, h:h + 1] * keep) * pool_ref[0, h] \
@@ -153,6 +171,17 @@ def _redirect(slots, live, num_slots: int):
     return jnp.clip(slots[src], 0, num_slots - 1).astype(jnp.int32)
 
 
+def _group_blocks(heads: int, head_block: int, groups: int
+                  ) -> tuple[int, int]:
+    """(groups a head block spans, head blocks a group spans) of ``groups``
+    groups of B and C over ``heads`` heads taken ``head_block`` at a time:
+    one of the two is 1, or the kernel is refused (``kernel_reason``).  B
+    and C go in as ``[R, G / gb, gb, N]`` and a block of ``(1, 1, gb, N)``
+    is whole in its last two axes, whatever ``gb``."""
+    per_group = heads // groups
+    return max(head_block // per_group, 1), max(per_group // head_block, 1)
+
+
 def _vmem_limit(block_bytes: int) -> dict:
     """The kernel's VMEM where its four pool buffers (in and out, double-
     buffered) outgrow the compiler's default scope of 16 MB."""
@@ -165,8 +194,9 @@ def _recurrent_inplace(u, dt, a, b, c, pool, slots, fresh, live,
                        head_block: int = HEAD_BLOCK):
     """The Pallas form of one token a row: grid (head blocks, rows), a
     block of the pool ``[head_block, P, N]`` found through the row's slot
-    id, the pool aliased in and out.  Returns (y [R, H, P] float32, the
-    pool)."""
+    id, the pool aliased in and out.  With several groups of B and C a
+    block is handed its own heads' groups (``_group_blocks``).  Returns
+    (y [R, H, P] float32, the pool)."""
     f32 = jnp.float32
     r, h, p = u.shape
     n = b.shape[-1]
@@ -182,7 +212,18 @@ def _recurrent_inplace(u, dt, a, b, c, pool, slots, fresh, live,
                                     (r, h, p)))
     flags = live.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
     row = pl.BlockSpec((1, 1, p, hb), lambda j, i, *_: (i, j, 0, 0))
-    vec = pl.BlockSpec((1, 1, n), lambda j, i, *_: (i, 0, 0))
+    if b.ndim == 2:  # one group: every block reads the row's one B and C
+        vec = pl.BlockSpec((1, 1, n), lambda j, i, *_: (i, 0, 0))
+
+        def by_group(x):
+            return x.astype(f32)[:, None, :]
+    else:
+        gb, span = _group_blocks(h, hb, b.shape[1])
+        vec = pl.BlockSpec((1, 1, gb, n),
+                           lambda j, i, *_: (i, j // span, 0, 0))
+
+        def by_group(x):  # [R, G, N] -> [R, G / gb, gb, N]
+            return x.astype(f32).reshape(r, -1, gb, n)
     slot = pl.BlockSpec((1, hb, p, n),
                         lambda j, i, slots_ref, _: (slots_ref[i], j, 0, 0))
     y, pool = pl.pallas_call(
@@ -200,7 +241,7 @@ def _recurrent_inplace(u, dt, a, b, c, pool, slots, fresh, live,
             **_vmem_limit(hb * p * n * 4)),
         interpret=interpret_flag(),
     )(_redirect(slots, live, pool.shape[0]), flags, xdt, dec,
-      b.astype(f32)[:, None, :], c.astype(f32)[:, None, :], pool)
+      by_group(b), by_group(c), pool)
     return jnp.transpose(y, (0, 1, 3, 2)).reshape(r, h, p), pool
 
 
@@ -211,19 +252,24 @@ def _recurrent_gather(u, dt, a, b, c, pool, slots, fresh, live):
     f32 = jnp.float32
     s = pool.at[slots].get(mode="clip")
     s = jnp.where(fresh[:, None, None, None], 0.0, s)
+
+    def a_head(x):  # B or C against the states: [R, 1 or H, 1, N]
+        if x.ndim == 2:
+            return x.astype(f32)[:, None, None, :]
+        return _per_head(x.astype(f32), u.shape[1])[:, :, None, :]
+
     s = jnp.exp(a.astype(f32))[..., None, None] * s \
-        + (dt.astype(f32)[..., None] * u.astype(f32))[..., None] \
-        * b.astype(f32)[:, None, None, :]
-    y = jnp.sum(s * c.astype(f32)[:, None, None, :], axis=-1)
+        + (dt.astype(f32)[..., None] * u.astype(f32))[..., None] * a_head(b)
+    y = jnp.sum(s * a_head(c), axis=-1)
     at = jnp.where(live, slots, pool.shape[0])
     return (jnp.where(live[:, None, None], y, 0.0),
             pool.at[at].set(s.astype(pool.dtype), mode="drop"))
 
 
-def kernel_reason(pool) -> str:
+def kernel_reason(pool, groups: int = 1) -> str:
     """Why the recurrent kernel cannot take this pool ("" when it can): a
     block of it is whole float32 tiles with the state's width along the
-    lanes."""
+    lanes, and holds whole groups of B and C or lies within one."""
     _, h, p, n = pool.shape
     if pool.dtype != jnp.float32:
         return f"the pool is {pool.dtype.name}, not float32"
@@ -231,13 +277,18 @@ def kernel_reason(pool) -> str:
         return f"a head's state [{p}, {n}] is no whole (8, 128) tiles"
     if h % min(HEAD_BLOCK, h):
         return f"{h} heads % {HEAD_BLOCK} != 0"
+    per_group, hb = h // groups, min(HEAD_BLOCK, h)
+    if h % groups or (hb % per_group and per_group % hb):
+        return (f"{groups} groups of B and C over {h} heads do not tile "
+                f"head blocks of {hb}")
     return ""
 
 
 def ssd_decode(u, dt, a, b, c, pool, slots, fresh, live=None,
                use_pallas=None):
-    """One token a row: u [R, H, P]; dt, a [R, H]; b, c [R, N]; ``pool``
-    [slots, H, P, N] float32; ``slots`` [R] each row's slot (past the end
+    """One token a row: u [R, H, P]; dt, a [R, H]; b, c [R, N], or [R, G,
+    N] a group; ``pool`` [slots, H, P, N] float32; ``slots`` [R] each row's
+    slot (past the end
     for a padding row), ``fresh`` [R] whether the row starts its sequence
     (its state is zeros, whatever the slot held), ``live`` [R] the rows to
     compute (None: those whose slot exists).  Returns (y [R, H, P] float32,
@@ -248,7 +299,9 @@ def ssd_decode(u, dt, a, b, c, pool, slots, fresh, live=None,
     else:
         live = live & (slots < pool.shape[0])
     if use_pallas is None:
-        use_pallas = auto_use_pallas("ssd_decode", kernel_reason(pool))
+        use_pallas = auto_use_pallas(
+            "ssd_decode",
+            kernel_reason(pool, 1 if b.ndim == 2 else b.shape[1]))
     form = _recurrent_inplace if use_pallas else _recurrent_gather
     return form(u, dt, a, b, c, pool, slots, fresh, live)
 
@@ -279,8 +332,9 @@ def _mm(spec, x, y):
 @functools.partial(jax.jit, static_argnames=("chunk", "use_pallas"))
 def ssd_ragged(u, dt, a, b, c, pool, slots, fresh, cu_q_lens, q_lens,
                chunk: int = CHUNK, use_pallas=None):
-    """A packed ragged batch: u [T, H, P]; dt, a [T, H]; b, c [T, N];
-    ``pool`` [slots, H, P, N]; ``slots`` and ``fresh`` [S] (a sequence's
+    """A packed ragged batch: u [T, H, P]; dt, a [T, H]; b, c [T, N], or
+    [T, G, N] a group; ``pool`` [slots, H, P, N]; ``slots`` and ``fresh``
+    [S] (a sequence's
     slot, past the end where there is none; whether its context is this
     step's tokens alone); ``cu_q_lens`` [S + 1], ``q_lens`` [S].  Returns
     (y [T, H, P] float32, finite rows that nobody reads outside the
@@ -303,6 +357,29 @@ def ssd_ragged(u, dt, a, b, c, pool, slots, fresh, cu_q_lens, q_lens,
         cu_q_lens, q_lens, slots, num_slots, t, q)
     idx = jnp.arange(q, dtype=jnp.int32)
     causal = idx[:, None] >= idx[None, :]
+    # the three products that read B or C: without a group axis each is
+    # every head's; with one (``g`` groups) a head reads its group's, so
+    # the heads are taken a group at a time ([G, H / G]) on both sides
+    h, g = u.shape[1], (0 if b.ndim == 2 else b.shape[1])
+
+    def scores(c_w, b_w):      # C B^T against the decay [H, q, q]
+        if not g:
+            return _mm("tn,sn->ts", c_w, b_w)[None]
+        return jnp.repeat(_mm("tgn,sgn->gts", c_w, b_w), h // g, axis=0)
+
+    def read(c_w, s0):         # C S^T: [q, H, P]
+        if not g:
+            return _mm("tn,hpn->thp", c_w, s0)
+        return _mm("tgn,gkpn->tgkp", c_w,
+                   s0.reshape(g, h // g, *s0.shape[1:])).reshape(
+            q, h, s0.shape[1])
+
+    def write(to_end, b_w):    # sum_s x_s B_s^T: [H, P, N]
+        if not g:
+            return _mm("thp,tn->hpn", to_end, b_w)
+        return _mm("tgkp,tgn->gkpn",
+                   to_end.reshape(q, g, h // g, to_end.shape[-1]),
+                   b_w).reshape(h, to_end.shape[-1], b_w.shape[-1])
 
     def one_chunk(carry):
         k, y, pool = carry
@@ -319,19 +396,17 @@ def ssd_ragged(u, dt, a, b, c, pool, slots, fresh, cu_q_lens, q_lens,
         a_w = jnp.where(mine[:, None], window(a), 0.0)         # [q, H]
         xdt = jnp.where(mine[:, None], window(dt), 0.0)[..., None] \
             * window(u)                                        # [q, H, P]
-        b_w, c_w = window(b), window(c)                        # [q, N]
+        b_w, c_w = window(b), window(c)                # [q, N] a group
         la = jnp.cumsum(a_w, axis=0)                           # [q, H]
         decay = jnp.exp(jnp.where(
             causal[None], la.T[:, :, None] - la.T[:, None, :], -jnp.inf))
-        within = _mm("hts,shp->thp",
-                     _mm("tn,sn->ts", c_w, b_w)[None] * decay, xdt)
+        within = _mm("hts,shp->thp", scores(c_w, b_w) * decay, xdt)
         slot = slots[i]
         s0 = jax.lax.dynamic_index_in_dim(pool, slot, keepdims=False)
         s0 = jnp.where(fresh[i] & (start == cu_q_lens[i]), 0.0, s0)
-        carried = jnp.exp(la)[..., None] * _mm("tn,hpn->thp", c_w, s0)
+        carried = jnp.exp(la)[..., None] * read(c_w, s0)
         to_end = jnp.exp(la[-1][None, :] - la)[..., None] * xdt
-        s1 = jnp.exp(la[-1])[:, None, None] * s0 \
-            + _mm("thp,tn->hpn", to_end, b_w)
+        s1 = jnp.exp(la[-1])[:, None, None] * s0 + write(to_end, b_w)
         y = jax.lax.dynamic_update_slice_in_dim(
             y, jnp.where(mine[:, None, None], within + carried, window(y)),
             lo, axis=0)
@@ -355,35 +430,44 @@ def chunk_slots(q_lens, chunk: int = CHUNK) -> tuple[int, int]:
 
 
 # ----------------------------------------------------------- the two steps
-def _split(xbc, dims):
-    """silu(conv output) [T, H P + 2 N] -> u [T, H, P], B and C [T, N]."""
+def _split(xbc, dims, groups: int = 1):
+    """silu(conv output) [T, H P + 2 G N] -> u [T, H, P], B and C [T, N]
+    (one group) or [T, G, N]."""
     h, p, n = dims
     t = xbc.shape[0]
-    return (xbc[:, :h * p].reshape(t, h, p), xbc[:, h * p:h * p + n],
-            xbc[:, h * p + n:])
+    u = xbc[:, :h * p].reshape(t, h, p)
+    if groups == 1:
+        return u, xbc[:, h * p:h * p + n], xbc[:, h * p + n:]
+    gn = groups * n
+    return (u, xbc[:, h * p:h * p + gn].reshape(t, groups, n),
+            xbc[:, h * p + gn:].reshape(t, groups, n))
 
 
 def mamba2_ragged(xbc, dt, a, conv_w, conv_b, d_skip, conv_state, pool,
-                  slots, fresh, cu_q_lens, q_lens, dims, chunk: int = CHUNK):
+                  slots, fresh, cu_q_lens, q_lens, dims, chunk: int = CHUNK,
+                  groups: int = 1):
     """Convolution (with its bias), activation, the rule and the skip for
-    a packed batch.  xbc [T, H P + 2 N]; dt, a [T, H] float32; conv_state
+    a packed batch.  xbc [T, H P + 2 G N] (``groups`` G); dt, a [T, H]
+    float32; conv_state
     [S, K - 1, C]: the sequences' carried inputs before the step (zeros
     where they start); ``pool``, ``slots``, ``fresh`` as ``ssd_ragged``
     takes them; ``dims`` = (H, P, N).  Returns (y [T, H, P] float32, the
     new conv state [S, K - 1, C], the pool)."""
     y, conv_state = gdr.causal_conv_ragged(
         xbc, conv_w, conv_state, cu_q_lens, q_lens, carried="gather")
-    u, b, c = _split(jax.nn.silu(y + conv_b.astype(jnp.float32)), dims)
+    u, b, c = _split(jax.nn.silu(y + conv_b.astype(jnp.float32)), dims,
+                     groups)
     y, pool = ssd_ragged(u, dt, a, b, c, pool, slots, fresh, cu_q_lens,
                          q_lens, chunk=chunk)
     return y + d_skip.astype(jnp.float32)[None, :, None] * u, conv_state, pool
 
 
 def mamba2_decode(xbc, dt, a, conv_w, conv_b, d_skip, conv_state, pool,
-                  slots, fresh, dims):
+                  slots, fresh, dims, groups: int = 1):
     """The same for one token a row (xbc [R, C], conv_state [R, K - 1,
     C])."""
     y, conv_state = gdr.causal_conv_update(xbc, conv_w, conv_state)
-    u, b, c = _split(jax.nn.silu(y + conv_b.astype(jnp.float32)), dims)
+    u, b, c = _split(jax.nn.silu(y + conv_b.astype(jnp.float32)), dims,
+                     groups)
     y, pool = ssd_decode(u, dt, a, b, c, pool, slots, fresh)
     return y + d_skip.astype(jnp.float32)[None, :, None] * u, conv_state, pool

@@ -380,8 +380,7 @@ class ARModelRunner:
             # over the window kind's pages at its own row width; a full
             # layer with an indexer holds, on the same pages as its rows,
             # the index keys' pool as a third entry
-            kinds = [k for k in cfg.layer_types
-                     if k not in tfm.STATE_KINDS]
+            kinds = cfg.kv_layer_types
             wide = cfg.kv_layout_of("sliding_attention")
             for i, kind in enumerate(kinds):
                 if kind == "sliding_attention":
