@@ -192,9 +192,11 @@ def test_decode_step_holds_one_attention_call_a_layer(one_chip, monkeypatch):
     from vllm_omni_tpu.models.common import transformer
     from vllm_omni_tpu.ops import _dispatch
 
+    from vllm_omni_tpu.ops import grouped_matmul
+
     pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
-    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
-    monkeypatch.setattr(pa, "interpret_flag", lambda: False)
+    for module in (rpa, pa, grouped_matmul):
+        monkeypatch.setattr(module, "interpret_flag", lambda: False)
     monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
     layers, b = 2, 64
     config = dict(spec.load_json(
@@ -904,7 +906,9 @@ def test_nemotron_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     its published widths (eleven layers of one sublayer each, 128 rows, the
     cell's pages and slots donated) compiles for a v5e: one
     ``_ragged_attention`` call (32 query heads on 2 kv heads: a group of
-    16), one ``_recurrent_inplace`` a Mamba-2 layer, no kernel that fell
+    16), one ``_recurrent_inplace`` a Mamba-2 layer, the experts' two
+    grouped matmuls of each expert layer as the few-rows kernel (2 816
+    pairs over a router of 512: 5.5 rows an expert), no kernel that fell
     back, and the arguments the issue counts (12.1 GB) beside under half a
     gigabyte of temporaries.  The weights are made in one jitted call
     beside their 9.30 GB with under 2.5 GB of temporaries (the calibration
@@ -916,10 +920,10 @@ def test_nemotron_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     from benchmark import spec
     from benchmark.archs import nemotron_h as arch
     from vllm_omni_tpu.models.common import transformer
-    from vllm_omni_tpu.ops import _dispatch, ssd
+    from vllm_omni_tpu.ops import _dispatch, grouped_matmul, ssd
 
     pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
-    for module in (rpa, pa, ssd):
+    for module in (rpa, pa, ssd, grouped_matmul):
         monkeypatch.setattr(module, "interpret_flag", lambda: False)
     monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
     config = spec.load_json(
@@ -958,7 +962,60 @@ def test_nemotron_decode_step_compiles_for_v5e(one_chip, monkeypatch):
                        r"tpu_custom_call", compiled.as_text())
     assert calls.count("_ragged_attention") == 1
     assert calls.count("_recurrent_inplace") == 5
+    assert calls.count("grouped_matmul_128x1024x2688_rows") == 5
+    assert calls.count("grouped_matmul_128x2688x1024_rows") == 5
     assert not _dispatch.native_fallbacks
     memory = compiled.memory_analysis()
     assert 12.0e9 < memory.argument_size_in_bytes < 12.2e9
     assert memory.temp_size_in_bytes < 0.5e9
+
+
+#: the experts' few-rows kernel at the widths of the cells whose steps it
+#: serves, at their decode steps and at the largest rows each keeps
+#: resident: (P, router width, E, K, N) of the gate/up (or up) product,
+#: the down product's (K, N) being (N / 2 or N, K), and which of the two
+#: products take the kernel there
+GROUPED = {
+    "thinker_64_rows": (512, 128, 128, 2048, 1536, 768, "up down"),
+    "thinker_256_rows": (2048, 128, 128, 2048, 1536, 768, "up down"),
+    "thinker_512_rows": (4096, 128, 128, 2048, 1536, 768, "down"),
+    "lfm2_256_rows": (1024, 32, 32, 2048, 3584, 1792, "up down"),
+    "lfm2_512_rows": (2048, 32, 32, 2048, 3584, 1792, "up down"),
+    "granite_128_rows": (1280, 72, 18, 4096, 1536, 768, "up down"),
+    "granite_256_rows": (2560, 72, 18, 4096, 1536, 768, "up"),
+    "nemotron_128_rows": (2816, 512, 128, 1024, 2688, 2688, "up down"),
+    "qwen3next_256_rows": (2560, 512, 128, 2048, 1024, 512, "up down"),
+    "qwen3next_512_rows": (5120, 512, 128, 2048, 1024, 512, "up"),
+    "dots3_256_rows": (2048, 256, 32, 5120, 3072, 1536, "up down"),
+    "glm_512_rows": (2048, 64, 64, 2048, 3072, 1536, "up down"),
+    # under a row an expert the op keeps ragged_dot; forced, the kernel
+    # pads the rows up to a window
+    "forced_1_row": (8, 128, 128, 2048, 1536, 768, ""),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPED))
+def test_grouped_matmul_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
+    """The products of an expert MLP that take the few-rows kernel, at the
+    tiles ``tiles()`` picks: ONE Mosaic call each (the offsets and the
+    block map beside it are a handful of scalar operations), and the VMEM
+    it asks for is what ``choice`` counted and under the budget."""
+    from vllm_omni_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "interpret_flag", lambda: False)
+    p, width, e, k, n, inter, kernel = GROUPED[name]
+
+    def sds(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    for product, kk, nn in (("up", k, n), ("down", inter, k)):
+        took = gm.choice(p, width, kk, nn) == "kernel"
+        assert took == (product in kernel.split())
+        if not took and not name.startswith("forced"):
+            continue
+        text = jax.jit(
+            lambda r, w, s: gm.grouped_matmul(r, w, s, width, True)).lower(
+            sds((p, kk)), sds((e, kk, nn)),
+            sds((e,), jnp.int32)).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert "ragged-dot" not in text

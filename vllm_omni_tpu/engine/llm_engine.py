@@ -1030,6 +1030,12 @@ class LLMEngine:
             if step_metrics is not None:
                 step_metrics.sampler_bounded_steps_total += bounded
                 step_metrics.sampler_full_sort_steps_total += full_sort
+        # dispatches since the last record whose executable holds the
+        # experts' few-rows kernel (ops/grouped_matmul.py)
+        held = getattr(self.runner, "grouped_kernel_steps", 0)  # fakes
+        if held and step_metrics is not None:
+            step_metrics.moe_grouped_kernel_steps_total += held
+            self.runner.grouped_kernel_steps = 0
         # sequences whose recurrent state this step read and wrote (every
         # scheduled row of a model with recurrent layers; 0 otherwise):
         # what a roofline of the Gated DeltaNet layers needs

@@ -418,6 +418,10 @@ class EngineStepMetrics:
         # sort of the whole vocabulary (sample/sampler.py)
         self.sampler_bounded_steps_total = 0
         self.sampler_full_sort_steps_total = 0
+        # steps whose executable runs the experts' grouped matmul as the
+        # few-rows kernel (ops/grouped_matmul.py): decided as the step's
+        # program is traced, added from its bucket, no device read
+        self.moe_grouped_kernel_steps_total = 0
 
     def on_schedule(self, waiting: int, running: int) -> None:
         self.num_waiting = waiting
@@ -564,6 +568,10 @@ class EngineStepMetrics:
                 "bounded_steps_total": self.sampler_bounded_steps_total,
                 "full_sort_steps_total":
                     self.sampler_full_sort_steps_total,
+            },
+            "moe": {
+                "grouped_kernel_steps_total":
+                    self.moe_grouped_kernel_steps_total,
             },
             "overlap": {
                 "ratio": round(self.overlap_ratio, 4),

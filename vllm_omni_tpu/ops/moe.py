@@ -10,7 +10,8 @@ all-to-all token dispatch, SURVEY.md §2.11).
 TPU-first mechanics:
 - **Local (single shard)**: sort token-expert pairs by expert id, run the
   expert MLPs as ONE grouped matmul per projection (``jax.lax.ragged_dot``
-  — rides the MXU with static [T*k, ...] shapes), then gather each
+  — rides the MXU with static [T*k, ...] shapes; at few rows an expert
+  the Pallas kernel of ``ops/grouped_matmul.py``), then gather each
   token's k rows back by the sort's inverse and sum them in float32 under
   the renormalized router weights.  FLOPs scale with top-k, not E.
 - **Expert parallel**: ``shard_map`` over the ``ep`` mesh axis with the
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from vllm_omni_tpu.ops.activation import silu_mul
+from vllm_omni_tpu.ops.grouped_matmul import grouped_matmul
 
 # Engine-configured mesh for EP dispatch (set once before tracing; the
 # transformer's pure functions read it at trace time).
@@ -95,14 +97,16 @@ def router_topk(x, router_w, num_experts_per_tok: int,
     return topk_idx, topk_w
 
 
-def _grouped_mlp(xs, gate_up, down, group_sizes, act=None):
+def _grouped_mlp(xs, gate_up, down, group_sizes, act=None, width=None):
     """One grouped-matmul MLP over expert-sorted rows: gated SwiGLU
     (``gate_up`` [E, H, 2 I]: ``silu(gate) * up``) where ``act`` is None,
     else ungated, ``down(act(up(x)))`` with ``gate_up`` the experts' ``up``
-    [E, H, I] alone."""
-    h = jax.lax.ragged_dot(xs, gate_up, group_sizes)
+    [E, H, I] alone.  ``width`` is the router's, where the experts here
+    are some of a wider router's (``ops/grouped_matmul.py`` picks each
+    product's form from the rows an expert can expect)."""
+    h = grouped_matmul(xs, gate_up, group_sizes, width)
     h = silu_mul(h) if act is None else act(h)
-    return jax.lax.ragged_dot(h, down, group_sizes)
+    return grouped_matmul(h, down, group_sizes, width)
 
 
 def _combine(y, order, topk_w, held=None):
@@ -192,7 +196,8 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     token_of = order // k
     xs = x[token_of]
     group_sizes = jnp.bincount(local_e, length=e_held + 1)[:e_held]
-    y = _grouped_mlp(xs, gate_up, down, group_sizes, act)
+    y = _grouped_mlp(xs, gate_up, down, group_sizes, act,
+                     router_w.shape[-1])
     w = topk_w.reshape(-1)[order]
     y = jnp.where(mine[order][:, None], y * w[:, None].astype(y.dtype), 0)
     out = jnp.zeros((x.shape[0], x.shape[1]), y.dtype).at[token_of].add(y)

@@ -61,6 +61,7 @@ from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.models.common import transformer as tfm
 from vllm_omni_tpu.ops import _dispatch
 from vllm_omni_tpu.ops.autotune import auto_ragged_blocks
+from vllm_omni_tpu.ops.grouped_matmul import kernel_calls
 from vllm_omni_tpu.ops.paged_attention import (
     init_kv_cache,
     row_tiling_reason,
@@ -648,6 +649,11 @@ class ARModelRunner:
         # sampler's filter went (indexed by sample/sampler.py PATH_*; read
         # in the step's own readback, consumed by LLMEngine._record_step)
         self.sampler_steps = [0, 0, 0]
+        # dispatches since the engine last looked whose executable holds
+        # the experts' few-rows kernel, and the executables that do: the
+        # op's own choice, seen as each is traced (``_run_jit``)
+        self.grouped_kernel_steps = 0
+        self._grouped_kernel_keys: set = set()
         # width of upstream embeds accepted by this model: the embed_proj
         # input dim when present (thinker width for the talker), else the
         # model's own hidden size
@@ -732,6 +738,7 @@ class ARModelRunner:
         key = (kind,) + tuple(shape_key)
         if key in self._jit_seen:
             self.compile_stats["cache_hits"] += 1
+            self.grouped_kernel_steps += key in self._grouped_kernel_keys
             # a host phase (docs/observability.md): the launch is time
             # the engine thread waits on the device's runtime
             with self.phases.phase("omni.dispatch") as p:
@@ -740,6 +747,7 @@ class ARModelRunner:
             return result
         self._jit_seen.add(key)
         self.compile_stats["in_flight"] = 1
+        traced = kernel_calls()
         try:
             with self.phases.phase("omni.dispatch") as p:
                 # after the phase is entered: where it is the outermost
@@ -751,6 +759,9 @@ class ARModelRunner:
                 p.waited()
         finally:
             self.compile_stats["in_flight"] = 0
+        if kernel_calls() > traced:
+            self._grouped_kernel_keys.add(key)
+            self.grouped_kernel_steps += 1
         self.compile_stats["compiles"] += 1
         self.compile_stats["compile_s"] += p.ns / 1e9
         return result
@@ -932,6 +943,7 @@ class ARModelRunner:
                     *map(jnp.asarray, self._slot_column(
                         (), s_max, t_pad=t_pad))))
             warm_feed(t_pad, outs["last_tok"])
+        self.grouped_kernel_steps = 0  # a warm-up dispatch is no step
         return built
 
     # ---------------------------------------------------------------- step
