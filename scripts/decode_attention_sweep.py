@@ -21,13 +21,17 @@ Each form is checked against ``paged_attention_ref`` on the same device,
 then timed by the host's clock around ``block_until_ready`` (median of
 ``--reps`` calls) and by the device's own time under a profiler trace
 (operations whose printed name holds ``attention``, and all operations,
-per call).  ``--tiny`` runs a small version anywhere (interpret mode on
-the CPU): a rehearsal of the script, never a speed.
+per call).  A row also holds a digest of the output's bytes for each kv
+head: ``--compare parent change`` reads two trees' files and says
+whether every (shape, form) gave the same numbers head for head.
+``--tiny`` runs a small version anywhere (interpret mode on the CPU): a
+rehearsal of the script, never a speed.
 """
 
 import argparse
 import functools
 import glob
+import hashlib
 import json
 import os
 import statistics
@@ -57,6 +61,13 @@ SHAPES = {
     "thinker_chat_64_int8": (64, 63, 4, 8, 128, None, 8192, 528, (64, 1280, 330), True),
     "qwen3next_16": (16, 16, 2, 8, 256, None, 32768, 2080, (3000, 24000, 8192), False),
     "glm_24": (32, 24, 1, 20, 640, 512, 16384, 1104, (8192, 9400, None), False),
+    # PR 49: the cells of 256 and 128 decode rows (LFM2's heads of 64 lie
+    # two to a row of the pool: 4 rows of 128, a group of 8; granite has
+    # 8 kv heads, its group of 4 padded to a sublane tile; Nemotron 2)
+    "lfm2_256": (256, 255, 4, 8, 128, None, 16384, 400, (192, 6144, 800), False),
+    "granite_128": (128, 127, 8, 4, 128, None, 16384, 400, (192, 6144, 800), False),
+    "nemotron_128": (128, 127, 2, 16, 128, None, 16384, 400, (192, 6144, 800), False),
+    "dots3_24": (32, 24, 1, 128, 640, 512, 16384, 1104, (8192, 9400, None), False),
 }
 TINY = {
     "tiny_two_pools": (4, 3, 2, 2, 128, None, 64, 8, (1, 100, None), False),
@@ -179,14 +190,46 @@ def device_us(fn, args, calls: int) -> tuple[float, float]:
     return attn / calls / 1e3, total / calls / 1e3
 
 
+def _file(tree: str, tiny: bool) -> str:
+    return os.path.join(
+        "chiprun_out",
+        f"decode_attention_sweep_{tree}{'_tiny' if tiny else ''}.json")
+
+
+def compare(a: str, b: str, tiny: bool = False) -> int:
+    """A line a (shape, form) both trees ran: kernel time and whether the
+    outputs' digests agree for every kv head; 1 if any pair differs."""
+    rows = {t: {(r["shape"], r["form"]): r
+                for r in json.load(open(_file(t, tiny)))["rows"]}
+            for t in (a, b)}
+    differ = 0
+    for key in rows[a]:
+        ra, rb = rows[a][key], rows[b].get(key)
+        if rb is None or "digests" not in ra or "digests" not in rb:
+            continue
+        same = ra["digests"] == rb["digests"]
+        differ += not same
+        ta, tb = (r.get("kernel_us") or r["host_ms"] * 1e3 for r in (ra, rb))
+        print(f"{key[0]:<22} {key[1]:<16} {a} {ta:9.1f} us  {b} {tb:9.1f} us"
+              f"  {100 * (tb / ta - 1):+6.1f}%  "
+              + ("equal head for head" if same else "DIFFER"))
+    return 1 if differ else 0
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tree", default="change")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=33)
     ap.add_argument("--only", default="")
+    ap.add_argument("--forms", default="",
+                    help="comma-separated forms; all where empty")
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--compare", nargs=2, metavar="TREE",
+                    help="read two trees' files; no chip")
     ns = ap.parse_args()
+    if ns.compare:
+        raise SystemExit(compare(*ns.compare, tiny=ns.tiny))
     global USE_PALLAS
     USE_PALLAS = True if ns.tiny else None
     dev = jax.devices()[0]
@@ -196,7 +239,7 @@ def main() -> None:
         assert dev.platform == "tpu", "times come from the chip only"
     rows = []
     for name, shape in shapes.items():
-        if ns.only and ns.only not in name:
+        if ns.only and not any(o in name for o in ns.only.split(",")):
             continue
         *args, v_dim, longest = build(shape, ns.seed, dtype)
         q, k, v, tables, lens = args
@@ -204,6 +247,8 @@ def main() -> None:
         want = np.asarray(reference(q, k, v, tables[:, :cols], lens, v_dim),
                           np.float32)
         for form, fn in forms_of(shape).items():
+            if ns.forms and form not in ns.forms.split(","):
+                continue
             run = functools.partial(jitted, fn=fn, v_dim=v_dim)
             row = {"tree": ns.tree, "shape": name, "form": form}
             try:
@@ -222,14 +267,20 @@ def main() -> None:
                 # mean of page 0 from the gather reference: not compared
                 real = np.asarray(lens) > 0
                 row["max_err"] = float(np.abs(got - want)[real].max())
+                # the output's bytes, kv head by kv head: two trees whose
+                # digests agree gave the same numbers head for head
+                hkv = shape[2]
+                row["digests"] = [
+                    hashlib.sha256(np.ascontiguousarray(
+                        got.reshape(got.shape[0], hkv, -1)[:, h])
+                        .tobytes()).hexdigest()[:16] for h in range(hkv)]
                 row["ok"] = bool(row["max_err"] <= tol)
             except Exception as e:  # a refused form is a finding
                 row["refused"] = str(e).splitlines()[0][:200]
             rows.append(row)
             print(json.dumps(row), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    name = f"decode_attention_sweep_{ns.tree}{'_tiny' if ns.tiny else ''}.json"
-    with open(os.path.join("chiprun_out", name), "w") as f:
+    with open(_file(ns.tree, ns.tiny), "w") as f:
         json.dump({"device": dev.device_kind, "tree": ns.tree,
                    "rows": rows}, f, indent=1)
     raise SystemExit(1 if any(not r.get("ok", True) for r in rows) else 0)

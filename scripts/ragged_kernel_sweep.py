@@ -5,18 +5,22 @@ pages_per_block, dma_slots) triples (ops/autotune.py
 
     chiprun -- python -m scripts.ragged_kernel_sweep
 
-Each case is one packed step (Hkv 4, group 8, D 128, page 16, a pool of
-8192 pages, 64 metadata rows over 528 table columns, bf16): decode rows
+Each case is one packed step (the thinker's geometry: Hkv 4, group 8,
+D 128, page 16, a pool of 8192 pages, 64 metadata rows over 528 table
+columns, bf16; ``--geo`` names another cell's: PR 49): decode rows
 first, then one prefill chunk, packed as ``ARModelRunner`` packs them.
 Every candidate is checked against a plain per-sequence attention
 computed in float32 on the same device, then timed (median of
 ``--reps`` calls, each ended by ``block_until_ready``).  A candidate the
 compiler refuses is reported, not fatal.  ``--tiny`` runs a small
 version anywhere (the CPU takes the kernel in interpret mode): a
-rehearsal of the script, never a speed.
+rehearsal of the script, never a speed.  A row also holds a digest of
+the output's bytes for each kv head; ``--compare A.json B.json`` says
+whether two trees' files agree head for head (no chip).
 """
 
 import argparse
+import hashlib
 import importlib
 import json
 import os
@@ -42,6 +46,15 @@ CASES = [
 ]
 TINY_CASES = [("tiny", 128, 3, 40, 64, 96)]
 
+# kv heads, group, row width, value width of a shared pool or None
+GEOS = {
+    "thinker": (4, 8, 128, None),    # LFM2's paired heads of 64 too
+    "qwen3next": (2, 8, 256, None),
+    "granite": (8, 4, 128, None),
+    "nemotron": (2, 16, 128, None),
+    "glm": (1, 20, 640, 512),        # one latent kv head: the control
+}
+
 # (q_block, pages_per_block, dma_slots); the first row is the tile the
 # kernel had before PR 26 (one 8-token block, one page an iteration)
 CANDIDATES = [
@@ -51,7 +64,8 @@ CANDIDATES = [
 ]
 
 
-def build(case, hkv, group, d, page, pool, s_max, max_pages, dtype, seed):
+def build(case, hkv, group, d, page, pool, s_max, max_pages, dtype, seed,
+          v_dim=None):
     """One packed step: (args of _ragged_attention, per-sequence specs)."""
     _, t, n_dec, dec_ctx, chunk, chunk_ctx = case
     rng = np.random.default_rng(seed)
@@ -76,7 +90,8 @@ def build(case, hkv, group, d, page, pool, s_max, max_pages, dtype, seed):
     q = jax.random.normal(kq, (t, hkv * group, d), jnp.float32).astype(dtype)
     shape = (hkv, pool, page, d)
     k_cache = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
-    v_cache = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
+    v_cache = None if v_dim else jax.random.normal(
+        kv, shape, jnp.float32).astype(dtype)
     args = (q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(cu),
             jnp.asarray(q_lens), jnp.asarray(seq_lens),
             jnp.asarray([len(specs)], jnp.int32))
@@ -96,21 +111,42 @@ def _dense_seq(q, k, v, ctx_len):
     return jnp.einsum("hgtc,hcd->thgd", p, v.astype(jnp.float32))
 
 
-def reference(args, specs, cu, page):
+def reference(args, specs, cu, page, v_dim=None):
     q, k_cache, v_cache, tables = args[:4]
     t, h, d = q.shape
     hkv = k_cache.shape[0]
-    out = np.zeros((t, h, d), np.float32)
+    dv = v_dim or d
+    out = np.zeros((t, h, dv), np.float32)
     for i, (ctx, qn) in enumerate(specs):
         pn = -(-ctx // page)
         ids = tables[i, :pn]
         k = k_cache[:, ids].reshape(hkv, pn * page, d)[:, :ctx]
-        v = v_cache[:, ids].reshape(hkv, pn * page, d)[:, :ctx]
+        v = k[..., :dv] if v_cache is None else (
+            v_cache[:, ids].reshape(hkv, pn * page, d)[:, :ctx])
         lo = int(cu[i])
         qi = q[lo: lo + qn].reshape(qn, hkv, h // hkv, d)
         out[lo: lo + qn] = np.asarray(
-            _dense_seq(qi, k, v, ctx)).reshape(qn, h, d)
+            _dense_seq(qi, k, v, ctx)).reshape(qn, h, dv)
     return out
+
+
+def compare(a: str, b: str) -> int:
+    """A line a (case, tile) both files hold; 1 if any output differs."""
+    rows = [{(r["case"], r["q_block"], r["pages_per_block"],
+              r["dma_slots"]): r for r in json.load(open(f))["rows"]}
+            for f in (a, b)]
+    differ = 0
+    for key, ra in rows[0].items():
+        rb = rows[1].get(key)
+        if rb is None or "digests" not in ra or "digests" not in rb:
+            continue
+        same = ra["digests"] == rb["digests"]
+        differ += not same
+        print(f"{ra.get('geo', ''):<10} {key[0]:<12} {key[1:]} "
+              f"{ra['ms']:8.3f} ms  {rb['ms']:8.3f} ms  "
+              f"{100 * (rb['ms'] / ra['ms'] - 1):+6.1f}%  "
+              + ("equal head for head" if same else "DIFFER"))
+    return 1 if differ else 0
 
 
 def main():
@@ -122,7 +158,13 @@ def main():
                     help="time the picker's tile alone: the control of a "
                          "PR that changes the kernel, parent beside change")
     ap.add_argument("--out", default="chiprun_out/ragged_sweep.json")
+    ap.add_argument("--geo", default="thinker", choices=sorted(GEOS))
+    ap.add_argument("--compare", nargs=2, metavar="JSON",
+                    help="two trees' --out files: times, and whether the "
+                         "outputs agree head for head; no chip")
     ns = ap.parse_args()
+    if ns.compare:
+        raise SystemExit(compare(*ns.compare))
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
     if ns.tiny:
@@ -131,13 +173,16 @@ def main():
         cases, cands, tol = TINY_CASES, [(8, 1, 2), (16, 2, 2), (32, 4, 3)], 1e-4
     else:
         assert dev.platform == "tpu", "times come from the chip only"
-        geo = dict(hkv=4, group=8, d=128, page=16, pool=8192, s_max=64,
-                   max_pages=528, dtype=jnp.bfloat16)
+        hkv, group, d, v_dim = GEOS[ns.geo]
+        geo = dict(hkv=hkv, group=group, d=d, page=16, pool=8192, s_max=64,
+                   max_pages=528, dtype=jnp.bfloat16, v_dim=v_dim)
         cases, cands, tol = CASES, CANDIDATES, 4e-2
+    v_dim = geo.get("v_dim")
     auto = auto_ragged_blocks(
         head_dim=geo["d"], page_size=geo["page"], group=geo["group"],
         kv_itemsize=jnp.dtype(geo["dtype"]).itemsize,
-        q_itemsize=jnp.dtype(geo["dtype"]).itemsize)
+        q_itemsize=jnp.dtype(geo["dtype"]).itemsize,
+        kv_pools=1 if v_dim else 2)
     print(f"auto_ragged_blocks -> {auto}")
     if ns.auto_only:
         cands = [auto]
@@ -146,12 +191,13 @@ def main():
     rows = []
     for case in cases:
         args, specs, cu = build(case, seed=ns.seed, **geo)
-        want = reference(args, specs, cu, geo["page"])
+        want = reference(args, specs, cu, geo["page"], v_dim)
         for bq, ppb, slots in cands:
             def run(a=args, bq=bq, ppb=ppb, slots=slots):
                 return rpa._ragged_attention(
-                    *a, None, TB, True, bq, ppb, slots)
-            row = {"case": case[0], "q_block": bq, "pages_per_block": ppb,
+                    *a, None, TB, True, bq, ppb, slots, v_dim)
+            row = {"geo": ns.geo, "case": case[0], "q_block": bq,
+                   "pages_per_block": ppb,
                    "dma_slots": slots, "auto": (bq, ppb, slots) == auto}
             try:
                 got = np.asarray(jax.block_until_ready(run()), np.float32)
@@ -163,6 +209,11 @@ def main():
                 row["ms"] = statistics.median(times)
                 row["max_err"] = float(np.abs(got - want).max())
                 row["ok"] = bool(row["max_err"] <= tol)
+                row["digests"] = [
+                    hashlib.sha256(np.ascontiguousarray(
+                        got.reshape(got.shape[0], geo["hkv"], -1)[:, h])
+                        .tobytes()).hexdigest()[:16]
+                    for h in range(geo["hkv"])]
             except Exception as e:  # a refused candidate is a finding
                 row["refused"] = str(e).splitlines()[0][:200]
             rows.append(row)

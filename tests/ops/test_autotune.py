@@ -72,6 +72,71 @@ def test_auto_ragged_blocks_derives_the_tile_from_the_shapes(group,
     assert slots == 2
 
 
+#: (head_dim, group, kv pools, kv heads) of every cell's attention, and
+#: the tile it ran before the kernel held every kv head in one grid cell
+#: (ISSUE 49): a narrower tile would give the gain back
+CELL_TILES = {
+    "thinker_and_lfm2_pairs": ((128, 8, 2, 4), (64, 32, 2)),
+    "qwen3next": ((256, 8, 2, 2), (64, 32, 2)),
+    "granite4h": ((128, 4, 2, 8), (128, 32, 2)),
+    "nemotron3super": ((128, 16, 2, 2), (32, 32, 2)),
+    "glm47flash": ((640, 20, 1, 1), (16, 32, 2)),
+    "dots3_full": ((640, 128, 1, 1), (8, 32, 2)),
+    "dots3_sliding": ((1152, 64, 1, 1), (8, 32, 2)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TILES))
+def test_the_head_axis_is_counted_and_keeps_every_cells_tile(cell):
+    """``_ragged_need`` counts the q / output blocks, the accumulator and
+    the KV buffers once a kv head, the score block once; the picker
+    still gives every cell the tile it had; and what the kernel then
+    asks Mosaic for covers the whole under ``RAGGED_VMEM_MAX``."""
+    from vllm_omni_tpu.ops.autotune import (
+        MOSAIC_VMEM,
+        RAGGED_VMEM_MAX,
+        _ragged_need,
+        ragged_vmem_limit,
+    )
+    (d, group, pools, hkv), tile = CELL_TILES[cell]
+    geo = dict(head_dim=d, page_size=16, group=group, kv_itemsize=2,
+               q_itemsize=2, kv_pools=pools)
+    assert auto_ragged_blocks(**geo) == tile
+    assert auto_ragged_blocks(**geo, kv_heads=hkv) == tile
+    bq, ppb, slots = tile
+    one, whole = (_ragged_need(bq, ppb, slots, d, 16, group, 2, 2, False,
+                               pools, heads) for heads in (1, hkv))
+    scores = 4 * bq * group * ppb * 16 * 4
+    assert whole - scores == hkv * (one - scores)
+    # asking Mosaic for VMEM is not free (LFM2's 2 048-token step never
+    # returned with 14 MB asked: PERF.md, PR 49): only dots3's tiles ask
+    limit = ragged_vmem_limit(d, 16, group, 2, 2, False, pools, hkv, tile)
+    assert (limit is None) == (not cell.startswith("dots3"))
+    assert limit is None and whole <= MOSAIC_VMEM or (
+        whole < limit <= RAGGED_VMEM_MAX)
+    # the thinker's four heads: 512 KB of KV buffers (two pools, two
+    # slots of 512 keys) and 768 KB of q, output and accumulator a head,
+    # 9 MB in all
+    if cell == "thinker_and_lfm2_pairs":
+        assert whole - one == 3 * (2 * 2 * 512 * 128 * 2 + 512 * 128 * 12)
+        assert whole == 9 * 2 ** 20
+
+
+def test_a_head_count_past_the_most_vmem_narrows_the_tile():
+    """128 kv heads of 128 lanes with no GQA would hold 96 MB at the
+    one-head tile: the picker halves the KV block until half as much
+    again fits ``RAGGED_VMEM_MAX``; the benchmark's head counts never
+    get there."""
+    from vllm_omni_tpu.ops.autotune import RAGGED_VMEM_MAX, _ragged_need
+    geo = dict(head_dim=128, page_size=16, group=1, kv_itemsize=2,
+               q_itemsize=2)
+    one = auto_ragged_blocks(**geo)
+    many = auto_ragged_blocks(**geo, kv_heads=128)
+    assert many[0] == one[0] and many[1] < one[1]
+    assert _ragged_need(*many, 128, 16, 1, 2, 2, False, 2,
+                        128) * 3 // 2 <= RAGGED_VMEM_MAX
+
+
 def test_auto_ragged_blocks_guaranteed_fit():
     """A VMEM budget below every candidate degrades to the smallest
     working set the kernel supports (one 8-token sub-block, one page,

@@ -192,6 +192,26 @@ def test_ragged_quant_kernel_matches_quant_ref(name, use_pallas):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("name", ["mixed", "kv_blocks_ragged_tail"])
+@pytest.mark.parametrize("hkv", [1, 2, 4, 8])
+def test_ragged_quant_kernel_reads_each_heads_own_scales(hkv, name):
+    """ISSUE 49: a grid cell holds every kv head, and its scale block is
+    ``[Hkv * nsub, max_pages]``, a row a (head, sub-block).  The heads'
+    scales are spread over a factor of nine here, so a head that read
+    another head's row, or another sub-block's, would be far off."""
+    (q, _, quant, tables, cu, q_lens, seq_lens, n) = _build_pair(
+        CASES[name], hkv, 32, 4, s_max=6, max_pages=12, seed=23 + hkv)
+    gain = jnp.asarray(1 + 8 * np.arange(hkv) / max(hkv - 1, 1),
+                       jnp.float32)[:, None]
+    kq, vq = ((data, scale * gain) for data, scale in quant)
+    got = ragged_paged_attention(q, kq, vq, tables, cu, q_lens, seq_lens,
+                                 n, use_pallas=True, **TILES.get(name, {}))
+    ref = ragged_paged_attention_ref(q, kq, vq, tables, cu, q_lens,
+                                     seq_lens, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=1e-4)
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_ragged_quant_vs_f32_oracle_per_head_bounds(name):
     """Quantized attention vs the full-precision cache, bounded PER

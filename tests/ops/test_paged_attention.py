@@ -309,6 +309,12 @@ DECODE_POOLS = {
     # Qwen3-Next's gated attention
     "w256_2kv": dict(hkv=2, group=8, d=256),
     "int8": dict(hkv=2, group=8, d=128, quantized=True),
+    # ISSUE 49: a row's tile holds every kv head (LFM2 and the thinker 4,
+    # granite 8 with its group of 4 padded to a sublane tile; an int8
+    # scale a (head, page))
+    "group8_4kv": dict(hkv=4, group=8, d=128),
+    "group4_8kv": dict(hkv=8, group=4, d=128),
+    "int8_4kv": dict(hkv=4, group=2, d=128, quantized=True),
 }
 BLOCK = 32 * PAGE  # keys a KV block (16 pages at float32 rows of 256+)
 DECODE_CONTEXTS = {
@@ -324,9 +330,8 @@ DECODE_CONTEXTS = {
 }
 
 
-@pytest.mark.parametrize("contexts", sorted(DECODE_CONTEXTS))
-@pytest.mark.parametrize("pool", sorted(DECODE_POOLS))
-def test_decode_entry_matches_the_reference(pool, contexts):
+def _decode_batch_of(pool, contexts):
+    """(q, k, v, tables, lens, v_dim, hkv, group) of a decode batch."""
     geo, lens = DECODE_POOLS[pool], DECODE_CONTEXTS[contexts]
     hkv, group, d = geo["hkv"], geo["group"], geo["d"]
     v_dim, quantized = geo.get("v_dim"), geo.get("quantized", False)
@@ -349,7 +354,16 @@ def test_decode_entry_matches_the_reference(pool, contexts):
 
     k, v = half(), None if v_dim else half()
     q = jnp.asarray(rs.standard_normal((b, hkv * group, d)), jnp.float32)
-    tables, lens = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+    return (q, k, v, jnp.asarray(tables), jnp.asarray(lens, jnp.int32),
+            v_dim, hkv, group)
+
+
+@pytest.mark.parametrize("contexts", sorted(DECODE_CONTEXTS))
+@pytest.mark.parametrize("pool", sorted(DECODE_POOLS))
+def test_decode_entry_matches_the_reference(pool, contexts):
+    q, k, v, tables, lens, v_dim, hkv, group = _decode_batch_of(
+        pool, contexts)
+    b, d = q.shape[0], q.shape[-1]
     got = np.asarray(paged_attention(q, k, v, tables, lens,
                                      use_pallas=True, v_dim=v_dim))
     want = np.asarray(paged_attention_ref(q, k, v, tables, lens,
@@ -359,6 +373,33 @@ def test_decode_entry_matches_the_reference(pool, contexts):
     # a float32 pool is computed in float32 (int8 rows are exact in it)
     np.testing.assert_allclose(got[real], want[real], atol=2e-5)
     assert np.all(got[~real] == 0.0)
+
+
+@pytest.mark.parametrize("contexts", ["edges", "empty_rows",
+                                      "seventeen_rows"])
+@pytest.mark.parametrize("pool", sorted(
+    p for p, geo in DECODE_POOLS.items() if geo["hkv"] > 1))
+def test_decode_entry_gives_each_kv_head_what_it_gives_alone(pool, contexts):
+    """ISSUE 49: a row's tile attends every kv head against a KV block
+    that came in once.  Head ``h`` of it equals, exactly, the entry on a
+    pool of that head alone, which is the parent's grid cell of the head
+    (the same KV blocks, the same order, the same hand-off to the next
+    row)."""
+    q, k, v, tables, lens, v_dim, hkv, group = _decode_batch_of(
+        pool, contexts)
+
+    def one(cache, head):
+        if isinstance(cache, tuple):
+            return tuple(x[head: head + 1] for x in cache)
+        return cache[head: head + 1]
+
+    got = np.asarray(paged_attention(q, k, v, tables, lens,
+                                     use_pallas=True))
+    for head in range(hkv):
+        heads = slice(head * group, (head + 1) * group)
+        alone = paged_attention(q[:, heads], one(k, head), one(v, head),
+                                tables, lens, use_pallas=True)
+        np.testing.assert_array_equal(got[:, heads], np.asarray(alone))
 
 
 def test_auto_dispatch_counts_native_fallbacks(monkeypatch):

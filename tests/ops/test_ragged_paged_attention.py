@@ -581,3 +581,84 @@ def test_no_window_and_no_selection_trace_to_the_kernel_that_was():
     keep = jnp.ones((q.shape[0], tables.shape[1] * 16), bool)
     assert call(keep=keep) != plain and call(window=21) != plain
     assert "has_keep" not in plain and "window" not in plain
+
+
+# ------------------------------- every kv head in one grid cell (ISSUE 49)
+# The grid lost its kv-head axis: a KV block comes in once, by one strided
+# copy a page, and the tile attends every kv head against it in a loop.
+_HEAD_VARIANTS = ["two_pools", "int8", "window", "keep"]
+
+
+def _quantize(pool):
+    """``[Hkv, P, page, D]`` float -> (int8 data, ``[Hkv, P]`` scales):
+    a scale a (kv head, page), so a head that read another's scales
+    would show (the heads' ranges differ fivefold)."""
+    x = np.asarray(pool) * (1 + 4 * np.arange(pool.shape[0])
+                            / max(pool.shape[0] - 1, 1))[:, None, None, None]
+    scale = np.maximum(np.abs(x).max(axis=(2, 3)), 1e-6) / 127
+    return (jnp.asarray(np.round(x / scale[..., None, None]), jnp.int8),
+            jnp.asarray(scale, jnp.float32))
+
+
+def _head_step(hkv, variant, group=2):
+    """The step of ``test_mixed_step_with_a_padding_sub_block_between``
+    (decode rows whose tiles hand on within a q block and across two, a
+    sub-block nobody owns, a chunk with one wide tile) at ``hkv`` kv
+    heads: (args, kwargs, rows of the hole)."""
+    specs = [(9, 1), (30, 1), (1, 1), (17, 1), (25, 1), (60, 37)]
+    h, d, page = hkv * group, 32, 4
+    q, kc, vc, tables, cu, q_lens, seq_lens, n, _ = _pack(
+        specs, h, hkv, d, page, s_max=8, max_pages=16, seed=5 + hkv)
+    lo = int(cu[len(specs) - 1])
+    q = jnp.concatenate([q[:lo], jnp.zeros((TB, h, d), q.dtype), q[lo:]])
+    cu = cu.at[len(specs) - 1:].add(TB)
+    kw = dict(q_block=16, pages_per_block=2)
+    if variant == "int8":
+        kc, vc = _quantize(kc), _quantize(vc)
+    elif variant == "window":
+        kw["window"] = 11
+    elif variant == "keep":
+        pos = _positions(cu, q_lens, seq_lens, n, q.shape[0])
+        kw["keep"] = jnp.asarray(_random_keep(pos, tables.shape[1] * page))
+    return (q, kc, vc, tables, cu, q_lens, seq_lens, n), kw, (lo, lo + TB)
+
+
+def _one_head(cache, head):
+    if isinstance(cache, tuple):
+        return tuple(x[head: head + 1] for x in cache)
+    return cache[head: head + 1]
+
+
+@pytest.mark.parametrize("variant", _HEAD_VARIANTS)
+@pytest.mark.parametrize("hkv", [1, 2, 4, 8])
+def test_a_mixed_step_at_every_head_count(hkv, variant):
+    """One algorithm at 1, 2, 4 and 8 kv heads, over two pools, int8
+    pools with a scale a (head, page), a window and a selection: the
+    kernel against the reference on every row; the hole stays zero."""
+    args, kw, (lo, hi) = _head_step(hkv, variant)
+    tile = {k: kw.pop(k) for k in ("q_block", "pages_per_block")}
+    got = ragged_paged_attention(*args, use_pallas=True, **tile, **kw)
+    ref = ragged_paged_attention_ref(*args, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=3e-5)
+    assert not np.asarray(got)[lo:hi].any()
+
+
+@pytest.mark.parametrize("variant", _HEAD_VARIANTS)
+@pytest.mark.parametrize("hkv", [2, 4, 8])
+def test_each_kv_head_of_a_mixed_step_is_that_head_alone(hkv, variant):
+    """A head's result is the parent's digit for digit: the parent ran a
+    kv head's tiles in grid cells of their own (grid ``(Hkv, NBW)``), which
+    is what this kernel does for a pool of ONE kv head (its loop has one
+    turn).  So head ``h`` of the whole call equals, exactly, the call on
+    ``pool[h]`` and that head's query heads: the same KV blocks in the
+    same order, nothing shared between heads but the copies."""
+    (q, kc, vc, *meta), kw, _ = _head_step(hkv, variant)
+    group = q.shape[1] // hkv
+    got = np.asarray(ragged_paged_attention(q, kc, vc, *meta,
+                                            use_pallas=True, **kw))
+    for head in range(hkv):
+        heads = slice(head * group, (head + 1) * group)
+        alone = ragged_paged_attention(
+            q[:, heads], _one_head(kc, head), _one_head(vc, head), *meta,
+            use_pallas=True, **kw)
+        np.testing.assert_array_equal(got[:, heads], np.asarray(alone))

@@ -98,6 +98,15 @@ RAGGED_MAX_PAGES_PER_BLOCK = 32
 RAGGED_MAX_Q_BLOCK = 128
 
 
+#: (kv head, sub-block) rows of the int8 layout's scale blocks a grid
+#: cell: two ``[rows, max_pages]`` float32 blocks, double-buffered, in a
+#: v5e's 1 MB of SMEM beside the page tables (the thinker's decode entry
+#: runs 4 heads x 16 rows over 528 table columns: 528 KB)
+RAGGED_SCALE_ROWS = 64
+
+#: what Mosaic gives a kernel that asks for nothing (a v5e)
+MOSAIC_VMEM = 16 * 1024 * 1024
+
 #: the most VMEM the ragged kernel asks Mosaic for, where its smallest
 #: tile does not fit ``RAGGED_VMEM_CAP`` (a v5e has 128 MiB; Mosaic's own
 #: limit is 16 MiB until a kernel asks for more)
@@ -105,17 +114,25 @@ RAGGED_VMEM_MAX = 64 * 1024 * 1024
 
 
 def _ragged_need(bq, ppb, slots, head_dim, page_size, group, kv_itemsize,
-                 q_itemsize, quantized, kv_pools) -> int:
-    """Bytes of VMEM the ragged kernel's buffers take at these blocks."""
+                 q_itemsize, quantized, kv_pools, kv_heads=1) -> int:
+    """Bytes of VMEM the ragged kernel's buffers take at these blocks.
+    A grid cell holds a q block's rows of EVERY kv head and a KV block's
+    rows of every kv head (one strided copy a page brings them all), so
+    the q and output blocks, the accumulator and the KV buffers count
+    ``kv_heads`` times; the score block is one head's, whose turn it
+    is."""
     rows, kvb = bq * group, ppb * page_size
     # q block and output block (double-buffered by the grid
-    # pipeline) + f32 accumulator
-    need = rows * head_dim * (4 * q_itemsize + 4)
+    # pipeline) + f32 accumulator.  (A head's running max and sum, two
+    # [rows, 1] f32 columns of 512 B a row, are left to the margin, as
+    # when they were the loop's carry: counting them moves the tile at
+    # 258 of 3840 geometries.)
+    need = kv_heads * rows * head_dim * (4 * q_itemsize + 4)
     # f32 score block and its live temporaries (mask, p, p in the
     # matmul dtype)
     need += 4 * rows * kvb * 4
     # K and V buffers, or the one buffer of a shared pool
-    need += kv_pools * slots * kvb * head_dim * kv_itemsize
+    need += kv_heads * kv_pools * slots * kvb * head_dim * kv_itemsize
     if quantized:
         # the float staging copy of the block being consumed (k, v)
         need += 2 * kvb * head_dim * 4
@@ -124,22 +141,40 @@ def _ragged_need(bq, ppb, slots, head_dim, page_size, group, kv_itemsize,
 
 def ragged_vmem_limit(head_dim: int, page_size: int, group: int = 1,
                       kv_itemsize: int = 2, q_itemsize: int = 4,
-                      quantized: bool = False, kv_pools: int = 2):
-    """None where the kernel's smallest q tile (the packer's 8 tokens x
-    ``group`` heads) fits ``RAGGED_VMEM_CAP`` with whole KV blocks, as at
-    every geometry served before PR 34: the kernel then asks Mosaic for
-    nothing.  Else the bytes it asks for (``vmem_limit_bytes``) and
-    ``auto_ragged_blocks`` budgets with: 128 latent heads on one kv head
-    are 1024 rows of 640 lanes a tile, which at the cap left one page an
-    iteration."""
-    ppb = max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
-                     RAGGED_KV_BLOCK // page_size))
-    need = _ragged_need(8, ppb, 2, head_dim, page_size, max(group, 1),
-                        kv_itemsize, q_itemsize, quantized, kv_pools)
-    if need <= RAGGED_VMEM_CAP:
-        return None
-    # half as much again: Mosaic's own temporaries beside the model's
-    return min(need + need // 2, RAGGED_VMEM_MAX)
+                      quantized: bool = False, kv_pools: int = 2,
+                      kv_heads: int = 1, blocks=None):
+    """The bytes the kernel asks Mosaic for (``vmem_limit_bytes``), or
+    None: it then asks for nothing and Mosaic's own ``MOSAIC_VMEM``
+    stands.  None where the kernel's smallest q tile (the packer's 8
+    tokens x ``group`` heads) of one kv head fits ``RAGGED_VMEM_CAP``
+    with whole KV blocks, as at every geometry served before PR 34;
+    else what that tile needs, which ``auto_ragged_blocks`` budgets a
+    head's tile with: 128 latent heads on one kv head are 1024 rows of
+    640 lanes a tile, which at the cap left one page an iteration.
+    ``blocks``, the (q_block, pages_per_block, dma_slots) the kernel
+    runs with its ``kv_heads``, adds the one case the head axis brings:
+    the whole past ``MOSAIC_VMEM`` (no cell's shape: the thinker's four
+    heads count 9 MB, granite's eight 14.5, and both compile with
+    nothing asked, ``tests/ops/test_ragged_tpu_compile.py``).  **Asking
+    is not free**: with ``vmem_limit_bytes`` of 14 MB on this kernel
+    LFM2's 2 048-token step program never returned on the chip (libtpu
+    0.0.34) and with nothing asked the same program did (PERF.md, PR
+    49; ROADMAP D21), so the kernel asks only where it must."""
+    def need(tile, heads):
+        return _ragged_need(*tile, head_dim, page_size, max(group, 1),
+                            kv_itemsize, q_itemsize, quantized, kv_pools,
+                            heads)
+
+    def asked(bytes_):
+        # half as much again: Mosaic's own temporaries beside the model's
+        return min(bytes_ + bytes_ // 2, RAGGED_VMEM_MAX)
+
+    one = need((8, max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
+                              RAGGED_KV_BLOCK // page_size)), 2), 1)
+    limit = None if one <= RAGGED_VMEM_CAP else asked(one)
+    if blocks is not None and need(blocks, kv_heads) > MOSAIC_VMEM:
+        limit = max(limit or 0, asked(need(blocks, kv_heads)))
+    return limit
 
 
 @functools.lru_cache(maxsize=64)
@@ -152,9 +187,19 @@ def auto_ragged_blocks(
     vmem_cap_bytes: int = RAGGED_VMEM_CAP,
     quantized: bool = False,
     kv_pools: int = 2,
+    kv_heads: int = 1,
 ) -> tuple[int, int, int]:
     """(q_block, pages_per_block, dma_slots) for the ragged
     paged-attention kernel.
+
+    The tile is ONE kv head's: the budget counts a head's q rows,
+    accumulator, score block and share of the KV buffers, which is what
+    a turn of the kernel's head loop works on and what the sweep timed.
+    ``kv_heads`` multiplies the buffers, not the tile: the kernel asks
+    Mosaic for the whole (``ragged_vmem_limit``), and only a head count
+    whose whole overruns ``RAGGED_VMEM_MAX`` narrows the choice (no
+    shape of the benchmark's cells: 1, 2, 4 and 8 kv heads keep the
+    tile they had).
 
     The search runs PER LAYOUT (the lru key includes ``quantized``): the
     int8 layout halves the KV DMA buffers (kv_itemsize is the int8
@@ -188,6 +233,8 @@ def auto_ragged_blocks(
     bq = 8
     while bq * 2 * group <= RAGGED_ROWS and bq < RAGGED_MAX_Q_BLOCK:
         bq *= 2
+    while quantized and kv_heads * (bq // 8) > RAGGED_SCALE_ROWS and bq > 8:
+        bq //= 2  # the scale blocks of every kv head lie in SMEM
     ppb = max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
                      RAGGED_KV_BLOCK // page_size))
     slots = 2
@@ -199,9 +246,12 @@ def auto_ragged_blocks(
             kv_pools) or vmem_cap_bytes
 
     def fits(bq, ppb):
-        return _ragged_need(bq, ppb, slots, head_dim, page_size, group,
-                            kv_itemsize, q_itemsize, quantized,
-                            kv_pools) <= vmem_cap_bytes
+        def need(heads):
+            return _ragged_need(bq, ppb, slots, head_dim, page_size, group,
+                                kv_itemsize, q_itemsize, quantized,
+                                kv_pools, heads)
+        return (need(1) <= vmem_cap_bytes
+                and need(kv_heads) * 3 // 2 <= RAGGED_VMEM_MAX)
 
     while not fits(bq, ppb):
         if ppb > 1:

@@ -43,23 +43,33 @@ sits at ``seq_lens[i] - q_lens[i] + j`` and attends keys at positions
 decode row is the degenerate ``q_lens[i] == 1`` case — last position,
 full context — so decodes and prefill chunks need no special-casing.
 
-The kernel: the grid is (kv-head, global q block of ``q_block``
-tokens), and an in-jit SMEM lookup (``_block_owners``) says what each
-block holds.  A block inside ONE sequence's segment — nearly every
-block of a prefill chunk — attends as one ``q_block x group``-row tile;
+The kernel: the grid is (global q block of ``q_block`` tokens,), a
+cell holding the block's rows of EVERY kv head (``[Hkv, nsub,
+token_block * group, D]``), and an in-jit SMEM lookup
+(``_block_owners``) says what each block holds.  A block inside ONE
+sequence's segment — nearly every block of a prefill chunk — attends,
+kv head by kv head, as one ``q_block x group``-row tile;
 a block that holds several sequences (decode rows, a segment's head or
 tail: the packer puts decodes first, so a chunk starts at a multiple of
 8, not of ``q_block``) walks its ``token_block``-token sub-blocks, each
 against its own sequence's pages.  Either way the cell owns its output
 rows exclusively.  A tile online-softmaxes over KV blocks of
 ``pages_per_block`` pages — that many page DMAs into one contiguous
-double-buffered VMEM buffer, one wait, one ``rows x D x keys`` product
-and one back, in the stored dtype with float32 accumulation — and its
-loop ends at the tile's last causal page; only the blocks crossing the
-diagonal are masked.  The sub-block tiles are short (a decode row's
-context is a KV block or a few), so each starts the first KV block of
-the tile after it while it still computes, across grid cells too: no
-such tile waits for a copy it has only just asked for, but the first.
+double-buffered VMEM buffer ``[dma_slots, Hkv, keys, D]``, each ONE
+strided copy of the page's rows of all kv heads (``pool[:, page_id]``:
+until PR 49 the grid had a kv-head axis and every head issued its own),
+one wait, then for each kv head in turn (a ``fori_loop``, each head's
+running max and sum in scratch beside its accumulator; one kv head
+needs neither) one ``rows x D x keys`` product and one back, in the
+stored dtype with float32 accumulation — and its loop ends at the
+tile's last causal page; only the blocks crossing the diagonal are
+masked.  A head's arithmetic and its order of blocks do not depend on
+the other heads.  The sub-block
+tiles are short (a decode row's context is a KV block or a few), so
+each starts the first KV block of the tile after it (the next
+sub-block, then the next q block: a tile is a sub-block's rows of every
+kv head) while it still computes, across grid cells too: no such tile
+waits for a copy it has only just asked for, but the first.
 ``auto_ragged_blocks`` (ops/autotune.py) derives
 (q_block, pages_per_block, dma_slots) from group, head dim, page size
 and cache dtype; ``ragged_paged_attention_ref`` is the XLA fallback /
@@ -78,7 +88,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from vllm_omni_tpu.ops._dispatch import auto_use_pallas, interpret_flag
-from vllm_omni_tpu.ops.autotune import auto_ragged_blocks, ragged_vmem_limit
+from vllm_omni_tpu.ops.autotune import (
+    RAGGED_SCALE_ROWS,
+    auto_ragged_blocks,
+    ragged_vmem_limit,
+)
 from vllm_omni_tpu.ops.paged_attention import (
     cache_data,
     cache_is_quantized,
@@ -252,15 +266,19 @@ def _ragged_kernel(
     slen_ref,      # [S] context lengths
     tables_ref,    # [S, max_pages]
     # inputs
-    q_ref,         # [nsub, token_block * group, D] VMEM: one q block
+    q_ref,         # [Hkv, nsub, token_block * group, D] VMEM: one q block
     k_hbm,         # [Hkv, P, page, D] ANY/HBM (int8 when quantized)
     # v_hbm likewise, unless the layout is shared (the value is then the
     # first Dv lanes of the key block: one copy of a page, not two);
-    # quantized only: k_sc_ref/v_sc_ref [nsub, max_pages] SMEM — per
-    # sub-block, its sequence's per-page scales in page-table order —
-    # then outputs o_ref [nsub, token_block * group, Dv] and scratch
-    # k_buf/v_buf [dma_slots, ppb * page, D] (no v_buf when shared),
-    # sems [dma_slots, 2], acc_scr [nsub * token_block * group, Dv],
+    # quantized only: k_sc_ref/v_sc_ref [Hkv * nsub, max_pages] SMEM —
+    # per (kv head, sub-block), row ``head * nsub + sub``, the
+    # sub-block's sequence's per-page scales in page-table order — then
+    # outputs o_ref [Hkv, nsub, token_block * group, Dv] and scratch
+    # k_buf/v_buf [dma_slots, Hkv, ppb * page, D] (no v_buf when shared),
+    # sems [dma_slots, 2], acc_scr [Hkv, nsub * token_block * group, Dv],
+    # m_scr/l_scr [Hkv, nsub * token_block * group, 1] where Hkv > 1
+    # (each head's running max and sum, which outlive its turn of a KV
+    # block; one kv head's are the KV loop's carry),
     # pipe [2] SMEM: what one narrow tile leaves the next (see _narrow).
     # With a selection (``has_keep``): keep_hbm [T / token_block,
     # token_block * keep_rep, L] f32 ANY/HBM after the pools (0 where a
@@ -268,8 +286,8 @@ def _ragged_kernel(
     # slab; a row a token where the group is whole sublane tiles, else
     # one a (token, head) as the scores lie) and keep_buf [dma_slots,
     # nsub | 1, token_block * keep_rep, ppb * page] after the KV buffers:
-    # a KV block's columns come in with the block; sems then
-    # [dma_slots, 3]
+    # a KV block's columns come in with the block, once for every kv
+    # head; sems then [dma_slots, 3]
     *refs,
     page_size: int,
     token_block: int,
@@ -293,7 +311,8 @@ def _ragged_kernel(
     o_ref, k_buf = refs.pop(0), refs.pop(0)
     v_buf = None if shared else refs.pop(0)
     keep_buf = refs.pop(0) if has_keep else None
-    sems, acc_scr, pipe = refs
+    sems, acc_scr, *ml_scr, pipe = refs
+    m_scr, l_scr = ml_scr or (None, None)  # several kv heads only
     # a window or a selection can hide a whole KV block from a row, so
     # every block is masked and a hidden key's probability is set to 0
     # (without either, block 0 holds key 0, which every row sees)
@@ -301,10 +320,10 @@ def _ragged_kernel(
     # the pools a KV block is copied from, each into its buffer
     halves = ((k_hbm, k_buf),) if shared else ((k_hbm, k_buf),
                                                (v_hbm, v_buf))
-    kvh = pl.program_id(0)
-    j = pl.program_id(1)   # GLOBAL q block of nsub sub-blocks: the grid
-    #                        is (Hkv, NBW), run in order (_narrow needs it)
-    nbw = pl.num_programs(1)
+    j = pl.program_id(0)   # GLOBAL q block of nsub sub-blocks: the grid
+    #                        is (NBW,), run in order (_narrow needs it)
+    nbw = pl.num_programs(0)
+    hkv = q_ref.shape[0]
     sub_rows = token_block * group
     kvb = ppb * page_size  # keys per KV block (one loop iteration)
     unroll = math.gcd(ppb, _PAGE_COPY_UNROLL)
@@ -334,10 +353,19 @@ def _ragged_kernel(
         # position), and the loop body needs no branch
         return jnp.minimum(blk * ppb + p, last_page)
 
-    def start_block(head, i, last_page, slot, blk, sub0, subs):
+    def each_head(fn):
+        # one kv head needs no loop
+        if hkv == 1:
+            fn(0)
+        else:
+            jax.lax.fori_loop(0, hkv, lambda h, c: (fn(h), c)[1], 0)
+
+    def start_block(i, last_page, slot, blk, sub0, subs):
         """Start the copies of KV block ``blk`` of sequence ``i`` into
-        ``slot``; with a selection, also the block's columns of the
-        tile's ``subs`` sub-blocks from sub-block ``sub0`` on."""
+        ``slot``, a page's rows of EVERY kv head in one strided copy
+        (``[:, page_id]`` of the pool, ``[Hkv, page, D]``); with a
+        selection, also the block's columns of the tile's ``subs``
+        sub-blocks from sub-block ``sub0`` on."""
         if has_keep:
             pltpu.make_async_copy(
                 keep_hbm.at[pl.ds(sub0, subs), :,
@@ -353,7 +381,7 @@ def _ragged_kernel(
                             page_size)
                 for half, (hbm, buf) in enumerate(halves):
                     pltpu.make_async_copy(
-                        hbm.at[head, page_id], buf.at[slot, dst],
+                        hbm.at[:, page_id], buf.at[slot, :, dst],
                         sems.at[slot, half]).start()
             return carry
 
@@ -370,13 +398,16 @@ def _ragged_kernel(
             part = keep_buf.at[slot, pl.ds(0, subs)]
             pltpu.make_async_copy(part, part, sems.at[slot, 2]).wait()
 
-    def attend(i, tok0, sub, ntok, q, write, sub0, base=0, primed=None,
+    def attend(i, tok0, sub, ntok, q_of, write, sub0, base=0, primed=None,
                hand_off=None):
         """Online softmax of one q tile — ``ntok`` packed tokens of
         sequence ``i`` from its chunk token ``tok0`` (rows pack
-        ``group`` query heads per token, token-major) — over KV blocks
-        of ``ppb`` pages; ``write`` takes the f32 [ntok * group, D]
-        result.  ``sub`` names the tile's row of the scale blocks.
+        ``group`` query heads per token, token-major), every kv head's
+        — over KV blocks of ``ppb`` pages: a block comes in once, and
+        each kv head attends it in turn.  ``q_of(head)`` is the head's
+        [ntok * group, D] rows and ``write(head, out)`` takes its f32
+        [ntok * group, Dv] result.  ``sub`` names the tile's row of a
+        head's scale block.
         Block ``b`` lives in DMA slot ``(base + b) % dma_slots``; where
         ``primed`` holds, block 0 is already on its way there (the tile
         before started it), and ``hand_off(slot)`` is called once, when
@@ -403,15 +434,14 @@ def _ragged_kernel(
             # block ``blk`` of this tile, or what follows its last one
             @pl.when(blk < n_blk)
             def _own():
-                start_block(kvh, i, last_page, slot_of(blk), blk, sub0,
-                            subs)
+                start_block(i, last_page, slot_of(blk), blk, sub0, subs)
 
             if hand_off is not None:
                 @pl.when(blk == n_blk)
                 def _next():
                     hand_off(slot_of(blk))
 
-        def page_scales(sc_ref, blk):
+        def page_scales(sc_ref, head, blk):
             # [1, kvb] per-key scale of the block's pages (int8 layout):
             # scalar SMEM reads at each page's table position
             col_page = jax.lax.div(
@@ -420,7 +450,8 @@ def _ragged_kernel(
             out = jnp.zeros((1, kvb), jnp.float32)
             for p in range(ppb):
                 out = jnp.where(col_page == p,
-                                sc_ref[sub, page_idx(last_page, blk, p)],
+                                sc_ref[head * nsub + sub,
+                                       page_idx(last_page, blk, p)],
                                 out)
             return out
 
@@ -429,13 +460,12 @@ def _ragged_kernel(
             # prime the pipeline: up to ``dma_slots - 1`` KV blocks in
             # flight before the loop body consumes block 0
             if primed is None:
-                start_block(kvh, i, last_page, slot_of(blk0), blk0, sub0,
-                            subs)
+                start_block(i, last_page, slot_of(blk0), blk0, sub0, subs)
             else:
                 @pl.when(jnp.logical_not(primed))
                 def _cold():
-                    start_block(kvh, i, last_page, slot_of(blk0), blk0,
-                                sub0, subs)
+                    start_block(i, last_page, slot_of(blk0), blk0, sub0,
+                                subs)
             for ahead in range(1, dma_slots - 1):
                 fetch_ahead(blk0 + ahead)
 
@@ -450,17 +480,13 @@ def _ragged_kernel(
             # only the diagonal blocks need
             n_full = blk0 if bounded else jnp.minimum(
                 jax.lax.div(ctx_len - q_len + tok0 + 1, kvb), n_blk)
-            acc = acc_scr.at[pl.ds(0, rows)]
-
-            def body(masked, blk, carry):
-                m_prev, l_prev = carry  # acc lives in scratch
-                slot = slot_of(blk)
-                # keep the pipeline ``dma_slots - 1`` blocks deep: the
-                # slot being refilled is the one consumed longest ago
-                fetch_ahead(blk + dma_slots - 1)
-                wait_block(slot, subs)
-                k = k_buf[slot]
-                v = k[:, :d] if shared else v_buf[slot]
+            def turn(masked, blk, slot, head, m_prev, l_prev):
+                """Kv head ``head``'s turn of KV block ``blk``: its
+                accumulator in place, its new running (max, sum) back."""
+                q = q_of(head)
+                acc = acc_scr.at[head, pl.ds(0, rows)]
+                k = k_buf[slot, head]
+                v = k[:, :d] if shared else v_buf[slot, head]
                 if k.dtype != q.dtype:
                     # int8 values are exact in any float dtype: the
                     # page's (head, page) scale multiplies its score and
@@ -473,7 +499,7 @@ def _ragged_kernel(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 if quantized:
-                    s = s * page_scales(k_sc_ref, blk)
+                    s = s * page_scales(k_sc_ref, head, blk)
                 if has_keep:
                     # the selection holds causality too (a token keeps
                     # no key above itself): its 0 / -1e30 is added, a
@@ -506,45 +532,90 @@ def _ragged_kernel(
                 l_new = l_prev * alpha + jnp.sum(p, axis=-1,
                                                  keepdims=True)
                 if quantized:
-                    p = p * page_scales(v_sc_ref, blk)
+                    p = p * page_scales(v_sc_ref, head, blk)
                 acc[...] = acc[...] * alpha + jnp.dot(
                     p.astype(v.dtype), v,
                     preferred_element_type=jnp.float32)
                 return m_new, l_new
 
-            acc[...] = jnp.zeros((rows, d), jnp.float32)
+            def body(masked, blk, carry):
+                slot = slot_of(blk)
+                # keep the pipeline ``dma_slots - 1`` blocks deep: the
+                # slot being refilled is the one consumed longest ago
+                fetch_ahead(blk + dma_slots - 1)
+                wait_block(slot, subs)
+                if hkv == 1:
+                    # one kv head: its running (max, sum) is the carry,
+                    # as it always was (in scratch they cost GLM's and
+                    # dots3's calls 4-12% on the chip: PERF.md, PR 49)
+                    return turn(masked, blk, slot, 0, *carry)
+
+                def head_turn(head):
+                    # several: each head's outlive its turn in scratch
+                    # (the heads are a loop, so that the program holds
+                    # the turn once whatever Hkv is: ``_PAGE_COPY_UNROLL``
+                    # has what an unrolled body costs every executable
+                    # at set-up)
+                    at = (head, pl.ds(0, rows))
+                    m_new, l_new = turn(masked, blk, slot, head, m_scr[at],
+                                        l_scr[at])
+                    m_scr[at] = m_new
+                    l_scr[at] = l_new
+
+                each_head(head_turn)
+                return carry
+
+            def finish(head, l_fin):
+                write(head, jnp.where(
+                    row_tok < q_len,
+                    acc_scr[head, pl.ds(0, rows)] / l_fin, 0.0))
+
+            acc_scr[:, pl.ds(0, rows)] = jnp.zeros((hkv, rows, d),
+                                                   jnp.float32)
             m0 = jnp.full((rows, 1), _NEG_INF, jnp.float32)
             # the first real block's alpha is an exact 0, so the ones
             # never count; they keep the discarded alignment-tail rows
             # away from 0 / 0
             l0 = jnp.ones((rows, 1), jnp.float32)
             carry = (m0, l0)
+            if hkv > 1:
+                m_scr[:, pl.ds(0, rows)] = jnp.broadcast_to(
+                    m0, (hkv, rows, 1))
+                l_scr[:, pl.ds(0, rows)] = jnp.broadcast_to(
+                    l0, (hkv, rows, 1))
+                carry = 0
             if not bounded:
                 carry = jax.lax.fori_loop(
                     0, n_full, functools.partial(body, False), carry)
-            _, l_fin = jax.lax.fori_loop(
+            carry = jax.lax.fori_loop(
                 n_full, n_blk, functools.partial(body, True), carry)
-            write(jnp.where(row_tok < q_len, acc[...] / l_fin, 0.0))
+            if hkv == 1:
+                finish(0, carry[1])
+            else:
+                each_head(lambda head: finish(
+                    head, l_scr[head, pl.ds(0, rows)]))
 
         @pl.when(n_blk <= 0)
         def _empty():
             # the defensive empty-context case owns its rows too
-            write(jnp.zeros((rows, d), jnp.float32))
+            each_head(lambda head: write(
+                head, jnp.zeros((rows, d), jnp.float32)))
 
-    @pl.when((kvh == 0) & (j == 0))
+    @pl.when(j == 0)
     def _first_cell():
         pipe[0] = 0  # scratch starts undefined: nothing is in flight
 
     def _wide(w):
         # the whole block inside one sequence's segment (nearly every
         # block of a prefill chunk): all its rows attend as ONE tile
-        def write(out):
-            o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+        def write(head, out):
+            o_ref[head] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
         attend(w, j * (nsub * token_block) - cu_ref[w], 0,
                nsub * token_block,
-               q_ref[...].reshape(nsub * sub_rows, q_ref.shape[-1]), write,
-               j * nsub if has_keep else 0)
+               lambda head: q_ref[head].reshape(nsub * sub_rows,
+                                                q_ref.shape[-1]),
+               write, j * nsub if has_keep else 0)
 
     def _narrow():
         # several sequences (decode rows, a segment's head or tail) or
@@ -553,17 +624,18 @@ def _ragged_kernel(
         # or a few), so a tile that primed its own pipeline would spend
         # most of its time waiting for its first block: each instead
         # starts the FIRST block of the tile after it, in grid order
-        # (sub-block, q block, kv head; across grid cells too: scratch
-        # outlives a cell), as the block that follows its own last one,
+        # (sub-block, q block; a tile is a sub-block's rows of every kv
+        # head; across grid cells too: scratch outlives a cell), as the
+        # block that follows its own last one,
         # and says so in ``pipe`` ([0]: block 0 of the coming tile is in
         # flight, [1]: in which slot; that tile then counts its slots
         # from there).  The slot is the one its own pipeline would fill
         # next, so no wait of this tile counts the copy's bytes.
-        cells = nbw * nsub  # sub-blocks a kv head
+        cells = nbw * nsub  # sub-blocks of the packed step
 
         def tile_of(at):
-            """(sequence or -1, its chunk token) of sub-block ``at`` of
-            a kv head, -1 too where its q block attends as one tile."""
+            """(sequence or -1, its chunk token) of sub-block ``at``,
+            -1 too where its q block attends as one tile."""
             i = sub_seq_ref[at]
             if wide:
                 i = jnp.where(wide_seq_ref[jax.lax.div(at, nsub)] < 0, i, -1)
@@ -575,22 +647,19 @@ def _ragged_kernel(
             # clamp for SMEM reads; the tile below runs only when active
             i_safe = jnp.maximum(i, 0)
 
-            def write(out):
-                o_ref[sub] = out.astype(o_ref.dtype)
+            def write(head, out):
+                o_ref[head, sub] = out.astype(o_ref.dtype)
 
             def hand_off(slot):
-                last = (kvh == pl.num_programs(0) - 1) & (at == cells - 1)
-                nxt = jnp.where(at == cells - 1, 0, at + 1)
-                head = jnp.where(at == cells - 1, kvh + 1, kvh)
+                nxt = jnp.minimum(at + 1, cells - 1)  # clamp: SMEM reads
                 i_nxt, tok0_nxt = tile_of(nxt)
                 i_nxt_safe = jnp.maximum(i_nxt, 0)
                 last_page, n_blk, first = extent(i_nxt_safe, tok0_nxt,
                                                  token_block)
 
-                @pl.when(jnp.logical_not(last) & (i_nxt >= 0) & (n_blk > 0))
+                @pl.when((at < cells - 1) & (i_nxt >= 0) & (n_blk > 0))
                 def _start():
-                    start_block(head, i_nxt_safe, last_page, slot, first,
-                                nxt, 1)
+                    start_block(i_nxt_safe, last_page, slot, first, nxt, 1)
                     pipe[0] = 1
                     pipe[1] = slot
 
@@ -599,14 +668,15 @@ def _ragged_kernel(
                 primed = pipe[0] == 1
                 base = jnp.where(primed, pipe[1], 0)
                 pipe[0] = 0
-                attend(i_safe, tok0, sub, token_block, q_ref[sub], write,
-                       at, base, primed, hand_off)
+                attend(i_safe, tok0, sub, token_block,
+                       lambda head: q_ref[head, sub], write, at, base,
+                       primed, hand_off)
 
             @pl.when(i < 0)
             def _padding():
                 # padding sub-blocks own their rows too — zero them so
                 # padded rows of the packed hidden state stay zero
-                write(jnp.zeros((sub_rows, d), jnp.float32))
+                o_ref[:, sub] = jnp.zeros((hkv, sub_rows, d), o_ref.dtype)
 
             return carry
 
@@ -672,10 +742,12 @@ def _ragged_attention(
     ).reshape(hkv, nbw, nsub, sub_rows, d)
     wide_seq, sub_seq = _block_owners(
         cu_q_lens, q_lens, num_seqs, nbw, nsub, token_block)
+    tile_rows = (nsub if wide else 1) * sub_rows  # of a kv head
 
     def q_spec(width):
-        return pl.BlockSpec((None, None, nsub, sub_rows, width),
-                            lambda kvh, j, *_: (kvh, j, 0, 0, 0),
+        # a q block's rows of every kv head: one grid cell
+        return pl.BlockSpec((hkv, None, nsub, sub_rows, width),
+                            lambda j, *_: (0, j, 0, 0, 0),
                             memory_space=pltpu.VMEM)
 
     in_specs = [q_spec(d)] + [pl.BlockSpec(memory_space=pl.ANY)] * (
@@ -712,36 +784,41 @@ def _ragged_attention(
             (dma_slots, nsub if wide else 1, token_block * keep_rep, kvb),
             jnp.float32)]
     if quantized:
-        # per SUB-BLOCK, its sequence's scales in page-table order (a
-        # q block may hold several sequences): [Hkv, NBW, nsub,
-        # max_pages], one (nsub, max_pages) SMEM block a grid cell.
-        # Padding sub-blocks never touch theirs.
+        # per (kv head, SUB-BLOCK), the sub-block's sequence's scales in
+        # page-table order (a q block may hold several sequences):
+        # [NBW, Hkv * nsub, max_pages], one (Hkv * nsub, max_pages) SMEM
+        # block a grid cell.  Padding sub-blocks never touch theirs.
         sub_tables = page_tables.astype(jnp.int32)[jnp.maximum(sub_seq, 0)]
-        sc_spec = pl.BlockSpec((None, None, nsub, max_pages),
-                               lambda kvh, j, *_: (kvh, j, 0, 0),
+        sc_spec = pl.BlockSpec((None, hkv * nsub, max_pages),
+                               lambda j, *_: (j, 0, 0),
                                memory_space=pltpu.SMEM)
         in_specs += [sc_spec, sc_spec]
+        sub_tables = sub_tables.reshape(nbw, nsub, max_pages)
         operands += [
-            sc[:, sub_tables].reshape(hkv, nbw, nsub, max_pages)
+            jnp.swapaxes(sc[:, sub_tables], 0, 1).reshape(
+                nbw, hkv * nsub, max_pages)
             for sc in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(hkv, nbw),
+        grid=(nbw,),
         in_specs=in_specs,
         out_specs=q_spec(dv),
         scratch_shapes=[
-            pltpu.VMEM((dma_slots, ppb * page_size, d), k_data.dtype)
+            pltpu.VMEM((dma_slots, hkv, ppb * page_size, d), k_data.dtype)
         ] * (1 if shared else 2) + keep_scratch + [
             pltpu.SemaphoreType.DMA((dma_slots, 3 if keep_scratch else 2)),
-            pltpu.VMEM(((nsub if wide else 1) * sub_rows, dv), jnp.float32),
+            pltpu.VMEM((hkv, tile_rows, dv), jnp.float32),
+        ] + [pltpu.VMEM((hkv, tile_rows, 1), jnp.float32)] * (
+            2 if hkv > 1 else 0) + [
             pltpu.SMEM((2,), jnp.int32),
         ],
     )
-    # None at every geometry whose smallest tile fits the picker's cap
+    # None where every kv head's buffers at these blocks fit the
+    # picker's cap
     vmem_limit = ragged_vmem_limit(
-        d, page_size, -(-group // 8) * 8 if token_block == 1 else group,
-        k_data.dtype.itemsize, q.dtype.itemsize, quantized,
-        1 if shared else 2)
+        d, page_size, group, k_data.dtype.itemsize, q.dtype.itemsize,
+        quantized, 1 if shared else 2, kv_heads=hkv,
+        blocks=(q_block, ppb, dma_slots))
     out = pl.pallas_call(
         functools.partial(
             _ragged_kernel,
@@ -830,7 +907,8 @@ def ragged_paged_attention(
             group=q.shape[1] // k_data.shape[0],
             kv_itemsize=k_data.dtype.itemsize,
             q_itemsize=q.dtype.itemsize,
-            quantized=quantized, kv_pools=1 if v_cache is None else 2)
+            quantized=quantized, kv_pools=1 if v_cache is None else 2,
+            kv_heads=k_data.shape[0])
         q_block, pages_per_block, dma_slots = (
             a if x is None else x
             for x, a in zip((q_block, pages_per_block, dma_slots), auto))
@@ -846,7 +924,7 @@ def ragged_paged_attention(
 #: unit in which queries come in and outputs go out (the KV pipeline
 #: runs on from one cell's last row into the next cell's first), so it
 #: is kept small: the int8 layout's per-row scale tables are SMEM blocks
-#: a cell, two of ``[rows, max_pages]`` float32.
+#: a cell, two of ``[Hkv * rows, max_pages]`` float32.
 DECODE_ROWS_A_CELL = 16
 
 
@@ -898,8 +976,9 @@ def paged_attention(
     of the ragged kernel.  The batch is laid out, inside the jit, as
     ``B`` segments of ONE token with ``token_block = 1``: sequence ``i``
     is packed row ``i``, its query heads of a kv head (padded to whole
-    sublane tiles) are a tile's rows, and the tile walks its context in
-    the kernel's KV blocks with the next row's first block in flight
+    sublane tiles) are a tile's rows of that head, and the tile walks
+    its context in the kernel's KV blocks, every kv head attending a
+    block in turn, with the next row's first block in flight
     (``_ragged_kernel``'s narrow path; a row of context 0 is a padding
     row and reads zeros).  Callers keep ``B`` rows: nothing is packed
     eightfold.  An explicit ``use_pallas=True`` is honored as-is (kernel
@@ -918,14 +997,18 @@ def paged_attention(
             "paged_attention",
             row_tiling_reason(q.shape[-1], k_data.shape[2])
             or shared_tiling_reason(v_cache, v_dim))
-    group = q.shape[1] // k_data.shape[0]
+    hkv = k_data.shape[0]
+    group = q.shape[1] // hkv
+    quantized = cache_is_quantized(k_cache)
     _, pages_per_block, dma_slots = auto_ragged_blocks(
         head_dim=q.shape[-1], page_size=k_data.shape[2],
         group=-(-group // 8) * 8, kv_itemsize=k_data.dtype.itemsize,
-        q_itemsize=q.dtype.itemsize,
-        quantized=cache_is_quantized(k_cache),
-        kv_pools=1 if v_cache is None else 2)
+        q_itemsize=q.dtype.itemsize, quantized=quantized,
+        kv_pools=1 if v_cache is None else 2, kv_heads=hkv)
+    rows = min(q.shape[0], DECODE_ROWS_A_CELL)
+    if quantized:  # a scale row a (kv head, decode row) in SMEM
+        rows = min(rows, max(1, RAGGED_SCALE_ROWS // hkv))
     return own_half(_paged_attention(
         q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
-        v_dim, min(q.shape[0], DECODE_ROWS_A_CELL), pages_per_block,
-        dma_slots, window=window, keep=keep), k_data, head_dim)
+        v_dim, rows, pages_per_block, dma_slots, window=window, keep=keep),
+        k_data, head_dim)

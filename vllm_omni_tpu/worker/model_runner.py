@@ -345,7 +345,8 @@ class ARModelRunner:
                 kv_itemsize=(1 if self._kv_quant
                              else jnp.dtype(dtype).itemsize),
                 q_itemsize=jnp.dtype(dtype).itemsize,
-                quantized=self._kv_quant, kv_pools=layout.pools))
+                quantized=self._kv_quant, kv_pools=layout.pools,
+                kv_heads=max(layout.heads, 1)))
         self._token_block = DEFAULT_TOKEN_BLOCK
         self._max_num_batched_tokens = max_num_batched_tokens
         self._max_model_len = max_model_len
