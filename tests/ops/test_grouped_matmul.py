@@ -177,6 +177,9 @@ KERNEL_STEPS = {
     "nemotron3super-l11-ep4": (32, 128, 128),  # 704 over 512; 5 632: 79 / 61
     "qwen3next-l8-ep4": (64, 512, 256),  # 640 over 512 up; 5 120: 48.0 / 49
     "dots3note-l5-ep8": (32, 256, 256),  # 256 over 256 up; 4 096: 67 / 67
+    # 128 pairs over a router of 128 up (16 decode rows; the cell's 24 rows
+    # are 1.5 rows an expert, all on the 16 held); 4 096 pairs past 48 MB
+    "commandaplus-l4-ep8": (16, 256, 256),
 }
 
 

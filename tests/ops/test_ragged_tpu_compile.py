@@ -1035,3 +1035,99 @@ def test_grouped_matmul_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
             sds((e,), jnp.int32)).compile().as_text()
         assert text.count("tpu_custom_call") == 1
         assert "ragged-dot" not in text
+
+
+# ------------------ command-a-plus-05-2026 (ISSUE 50): a window over
+# per-head pages at 8 kv heads x 16 query heads, a parallel block
+@pytest.mark.parametrize("entry", ["mixed", "decode"])
+def test_commandaplus_steps_compile_for_v5e(entry, one_chip, monkeypatch):
+    """The benchmark's Command A+ cut at its published widths (one period:
+    three sliding layers and a full one, 16 of 128 experts held, the whole
+    262 144-row vocabulary) compiles for a v5e with the cell's two pools
+    donated: a 2 048-token mixed step and the 24-row decode bucket, each
+    one ``_ragged_attention`` call a layer (three of them told the
+    window), the rotation as the half-split kernel on the sliding layers'
+    q and k alone (the full layer rotates nothing), no kernel that fell
+    back (what the attention kernel asks of Mosaic for VMEM, nothing, is
+    held in ``tests/ops/test_window_per_head.py``); the arguments the issue
+    counts (11.35 GB of weights, 1.07 + 1.82 GB of pages) beside well
+    under a gigabyte of temporaries.  The weights are made in one jitted
+    call beside their 11.35 GB with next to no temporaries."""
+    import functools
+    import re
+
+    from benchmark import spec
+    from benchmark.archs import cohere2_moe as arch
+    from vllm_omni_tpu.models.common import transformer
+    from vllm_omni_tpu.ops import _dispatch, grouped_matmul
+    from vllm_omni_tpu.ops import rope as rope_ops
+
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    for module in (rpa, pa, grouped_matmul, rope_ops):
+        monkeypatch.setattr(module, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    config = spec.load_json(
+        spec.ROOT / "benchmark/configs/commandaplus-l4-ep8.json")
+    cfg = arch.program_config(config)
+    init = functools.partial(arch.init_weights, cfg=cfg, dtype=jnp.bfloat16)
+    if entry == "mixed":
+        made = jax.jit(init).lower(jax.ShapeDtypeStruct(
+            (2,), jnp.uint32, sharding=one_chip)).compile().memory_analysis()
+        assert 11.34e9 < made.output_size_in_bytes < 11.36e9
+        assert made.temp_size_in_bytes < 0.5e9
+
+    def sds(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    engine = config["engine"]
+    seqs, page = engine["max_num_seqs"], engine["page_size"]
+    max_pages = engine["max_model_len"] // page
+    window_pages = -(-seqs * (cfg.sliding_window
+                              + engine["max_num_batched_tokens"])
+                     // page) + seqs + 1
+    assert window_pages == 9241
+    lay = cfg.kv_layout_of("sliding_attention")
+    assert lay == cfg.kv_layout and (lay.heads, lay.width) == (8, 128)
+    pools = {
+        "full_attention": sds((8, engine["num_pages"], page, 128),
+                              jnp.bfloat16),
+        "sliding_attention": sds((8, window_pages, page, 128),
+                                 jnp.bfloat16)}
+    caches = [(pools[kind],) * 2 for kind in cfg.kv_layer_types]
+    if entry == "mixed":
+        t = engine["max_num_batched_tokens"]
+        compiled = jax.jit(
+            lambda p, tok, pos, kv, sl, tables, lens, cu, q_lens, n, wsl, wt:
+            transformer.forward_unified(
+                p, cfg, tok, pos, kv, sl, tables, lens, cu, q_lens, n,
+                window_pages=(wsl, wt)), donate_argnums=3).lower(
+            params, sds((t,)), sds((t,)), caches, sds((t,)),
+            sds((seqs, max_pages)), sds((seqs,)), sds((seqs + 1,)),
+            sds((seqs,)), sds((1,)), sds((t,)),
+            sds((seqs, max_pages))).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, tok, pos, kv, sl, tables, lens, wsl, wt:
+            transformer.forward_decode(p, cfg, tok, pos, kv, sl, tables,
+                                       lens, window_pages=(wsl, wt)),
+            donate_argnums=3).lower(
+            params, sds((seqs,)), sds((seqs,)), caches, sds((seqs,)),
+            sds((seqs, max_pages)), sds((seqs,)), sds((seqs,)),
+            sds((seqs, max_pages))).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*custom-call\([^\n]*"
+                       r"tpu_custom_call", text)
+    assert calls.count("_ragged_attention") == 4
+    assert calls.count("_apply_rope") == 2 * 3
+    if entry == "decode":
+        # 24 x 8 picks over a router of 128: 1.5 rows an expert, the
+        # few-rows kernel on the 16 held
+        assert calls.count("grouped_matmul_16x4096x8192_rows") == 4
+        assert calls.count("grouped_matmul_16x4096x4096_rows") == 4
+    assert not _dispatch.native_fallbacks
+    memory = compiled.memory_analysis()
+    assert 14.2e9 < memory.argument_size_in_bytes < 14.3e9
+    assert memory.temp_size_in_bytes < 0.8e9

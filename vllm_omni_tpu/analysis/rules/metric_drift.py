@@ -95,6 +95,7 @@ def synthetic_engine_snapshot() -> dict:
         "kv_pages_used": hist,
         "kv_window_pages_used": hist,
         "sparse_keep_pct": hist,
+        "window_keep_pct": hist,
         "ssd_chunk_fill": hist,
         "window": {"prefix_hits_refused_total": 2, "pages_freed_total": 9},
         "state": {"state_slot_allocs_total": 5, "state_resets_total": 1,

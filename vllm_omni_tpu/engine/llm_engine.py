@@ -1067,6 +1067,11 @@ class LLMEngine:
                     100.0 * (capped["prefill_ctx_kept"]
                              + capped["decode_ctx_kept"])
                     / (prefill_ctx + decode_ctx))
+            if "decode_ctx_window" in capped:
+                step_metrics.window_keep_pct.observe(
+                    100.0 * (capped["prefill_ctx_window"]
+                             + capped["decode_ctx_window"])
+                    / (prefill_ctx + decode_ctx))
             if getattr(kv, "window", 0):
                 step_metrics.kv_window_pages_used.observe(
                     kv.window_pages_in_use)

@@ -83,6 +83,10 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "histogram",
         "Share of the visible keys a step's rows attend on sparse layers",
         ("stage",)),
+    "window_keep_pct": (
+        "histogram",
+        "Share of the visible keys a step's rows attend on sliding layers",
+        ("stage",)),
     "window_prefix_hits_refused_total": (
         "counter",
         "Prefix-cache hits not handed out because freed window pages "
@@ -642,6 +646,8 @@ def render_exposition(summary: dict, engine_snaps: dict,
         if window:
             exp.histogram("kv_window_pages_used", labels,
                           snap["kv_window_pages_used"])
+            exp.histogram("window_keep_pct", labels,
+                          snap["window_keep_pct"])
             exp.sample("window_prefix_hits_refused_total", labels,
                        window.get("prefix_hits_refused_total", 0))
             exp.sample("window_pages_freed_total", labels,

@@ -375,6 +375,11 @@ class EngineStepMetrics:
         # learned sparse attention, the share they attend:
         # 100 x sum min(ctx, top_k) / sum ctx over the step's new tokens
         self.sparse_keep_pct = Histogram(buckets=PERCENT_BUCKETS)
+        # of the keys a step's rows see on the sliding layers of a model
+        # with window layers, the share the window leaves them:
+        # 100 x sum min(ctx, window) / sum ctx over the step's new tokens
+        # (at 100 nothing a step ran was cut by the window)
+        self.window_keep_pct = Histogram(buckets=PERCENT_BUCKETS)
         # how full the chunks of a Mamba-2 model's chunked form ran, once
         # a ragged step that had any: 100 x tokens in chunks / chunk
         # slots (ops/ssd.py chunk_slots; the one-token rows take the
@@ -541,6 +546,7 @@ class EngineStepMetrics:
             "kv_pages_used": self.kv_pages_used.snapshot(),
             "kv_window_pages_used": self.kv_window_pages_used.snapshot(),
             "sparse_keep_pct": self.sparse_keep_pct.snapshot(),
+            "window_keep_pct": self.window_keep_pct.snapshot(),
             "ssd_chunk_fill": self.ssd_chunk_fill.snapshot(),
             "saturation": dict(self.saturation),
             "slo": {

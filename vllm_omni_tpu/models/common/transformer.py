@@ -214,10 +214,12 @@ class TransformerConfig:
     # ``shortconv_kernel - 1`` rows of ``B * u`` and nothing else: a state
     # slot of one array (docs/kv_cache.md, "Three kinds of state")
     shortconv_kernel: int = 3
-    # a "sliding_attention" layer is latent attention with widths of its
-    # own (``sliding``) over the last ``sliding_window`` keys, the token
-    # itself among them; its rows live in pages of a second kind that are
-    # given back once they slide out of reach (docs/kv_cache.md)
+    # a "sliding_attention" layer attends the last ``sliding_window`` keys,
+    # the token itself among them; its rows live in pages of a second kind
+    # that are given back once they slide out of reach (docs/kv_cache.md).
+    # In a model with latent attention it is latent attention with widths
+    # of its own (``sliding``); in any other it is the model's per-head
+    # attention, and its pages hold (K, V) rows as the full layers' do
     sliding: Optional[LatentKind] = None
     sliding_window: int = 0
     # learned sparse attention on the full layers (index_topk > 0): an
@@ -262,6 +264,26 @@ class TransformerConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # ---- the Cohere family's block (Command A+).  ONE norm a layer, read
+    # by the mixer and by the MLP, whose branches join the stream in one
+    # sum: ``x + mixer(n(x)) + mlp(n(x))``.  Such a layer's weights hold
+    # no ``post_norm``; the initialiser and ``_layer_step`` read this
+    parallel_block: bool = False
+    # the stack's norm (every layer's and the final one) is a LayerNorm:
+    # the row's mean subtracted, then its variance's rsqrt, a weight and
+    # no bias, ``rms_eps`` its epsilon
+    norm_subtract_mean: bool = False
+    # the kinds of layer whose queries and keys are rotated, where not
+    # every kind is (None): a model that rotates its sliding layers and
+    # leaves its full layers without positions names the one.  A rotary
+    # embedding that pairs NEIGHBOURING dims (``rope_gptj``) is served by
+    # the half-split rotation over q and k columns permuted once in the
+    # weight tree (``ops/rope.py pairs_to_halves``)
+    rope_kinds: Optional[tuple[str, ...]] = None
+    # the shared expert's output times this before it is added: the MEAN
+    # of n shared experts is one shared expert of n times the width at
+    # 1 / n.  At 1.0 no operation is emitted
+    shared_expert_scale: float = 1.0
 
     @property
     def layer_types(self) -> tuple[str, ...]:
@@ -345,13 +367,14 @@ class TransformerConfig:
         nothing for per-head K and V pages (the scale is the head's, or
         the model's ``attention_multiplier`` where it states one); for
         a latent pool the scale of the un-absorbed head and the lanes of
-        a row that are the value; for a sliding-window layer its window."""
+        a row that are the value; for a sliding-window layer of either
+        layout its window."""
         if not self.kv_lora_rank:
-            if self.attention_multiplier is None:
-                return {}
-            return {"scale": self.attention_multiplier}
-        lk = self.latent_kind(kind)
-        out = {"scale": lk.scale, "v_dim": lk.kv_lora_rank}
+            out = ({} if self.attention_multiplier is None
+                   else {"scale": self.attention_multiplier})
+        else:
+            lk = self.latent_kind(kind)
+            out = {"scale": lk.scale, "v_dim": lk.kv_lora_rank}
         if kind == "sliding_attention":
             out["window"] = self.sliding_window
         return out
@@ -458,7 +481,8 @@ def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
             layer["input_norm"] = norm_init(kn[0], cfg.hidden_size)
             layer.update(_mixer_init(k, kn, cfg, kind, norm_init, dtype))
         if kind == FEED_FORWARD or not cfg.one_sublayer:
-            layer["post_norm"] = norm_init(kn[1], cfg.hidden_size)
+            if not cfg.parallel_block:  # else ``input_norm`` feeds both
+                layer["post_norm"] = norm_init(kn[1], cfg.hidden_size)
             layer.update(_mlp_init(
                 k, cfg, cfg.moe and i >= cfg.first_k_dense_replace, dtype))
         params["layers"].append(layer)
@@ -643,9 +667,18 @@ def _qkv(layer, cfg: TransformerConfig, x):
 
 
 def _rms(cfg: TransformerConfig, x, norm):
-    """RMSNorm with the model's weight convention: plain ``w``, or
-    ``(1 + w)`` in float32 where the weights are stored zero-centred."""
+    """The stack's norm.  RMSNorm with the model's weight convention:
+    plain ``w``, or ``(1 + w)`` in float32 where the weights are stored
+    zero-centred; or, where the configuration says so
+    (``norm_subtract_mean``), a LayerNorm with a weight and no bias, its
+    mean and variance over the row in float32."""
     w = norm["w"]
+    if cfg.norm_subtract_mean:
+        xc = x.astype(jnp.float32)
+        xc = xc - jnp.mean(xc, axis=-1, keepdims=True)
+        var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+        return (xc * jax.lax.rsqrt(var + cfg.rms_eps)
+                * w.astype(jnp.float32)).astype(x.dtype)
     if cfg.norm_zero_centered:
         w = 1.0 + w.astype(jnp.float32)
     return rms_norm(x, w, cfg.rms_eps)
@@ -799,12 +832,16 @@ def _moe_mlp(layer, cfg: TransformerConfig, x):
             out = nn.linear(layer["fc2_latent_proj"], out)
     if "shared_expert" in layer:
         # always-on shared expert: sigmoid-gated per token where the
-        # layer has the gate, added whole where it has none
+        # layer has the gate, added whole where it has none (several
+        # shared experts are ONE of their summed width: their sum, or at
+        # ``shared_expert_scale`` 1 / n their mean)
         se = _dense_mlp(layer["shared_expert"], cfg, x)
         if "shared_gate" in layer:
             gate = jax.nn.sigmoid(
                 nn.linear(layer["shared_gate"], x).astype(jnp.float32))
             se = gate.astype(se.dtype) * se
+        if cfg.shared_expert_scale != 1.0:  # a mean of shared experts
+            se = se * jnp.asarray(cfg.shared_expert_scale, se.dtype)
         out = out + se
     return out.reshape(*lead, out.shape[-1])
 
@@ -925,7 +962,9 @@ def _join_lanes(parts, width: int):
 def _kind_ropes(cfg: TransformerConfig, positions, mrope_positions=None):
     """{layer kind: (cos, sin)} for the kinds the stack has: one entry for
     every model but one whose sliding-window layers rotate at a base of
-    their own; no table at all for a model without positions."""
+    their own; no table at all for a model without positions, and
+    ``(None, None)`` for a kind that a model with ``rope_kinds`` leaves
+    unrotated."""
     if not cfg.use_rope:
         return {kind: (None, None) for kind in set(cfg.layer_types)}
     first = _rope_tables(cfg, positions if mrope_positions is None
@@ -934,6 +973,9 @@ def _kind_ropes(cfg: TransformerConfig, positions, mrope_positions=None):
     if cfg.sliding is not None:
         ropes["sliding_attention"] = _rope_tables(cfg, positions,
                                                   cfg.sliding)
+    if cfg.rope_kinds is not None:
+        ropes.update({kind: (None, None) for kind in ropes
+                      if kind not in cfg.rope_kinds})
     return ropes
 
 
@@ -1039,7 +1081,10 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     """One transformer block: norm → mixer → residual → norm → MLP →
     residual; or, in a stack written one sublayer a layer (Nemotron-H),
     the ONE of those two halves that the layer's weights hold (an
-    ``input_norm`` with its mixer, or a ``post_norm`` with its MLP).  In a
+    ``input_norm`` with its mixer, or a ``post_norm`` with its MLP); or,
+    under ``cfg.parallel_block`` (a mixer AND an MLP under the layer's one
+    ``input_norm``: the Cohere family), ``x + mixer(n(x)) +
+    mlp(n(x))``, the two branches joined in one sum.  In a
     softmax-attention layer the mixer is qkv+rope →
     ``attend(q, k, v)``, which supplies the attention variant (dense
     causal / cached-context chunked / paged decode) and returns o with
@@ -1053,26 +1098,50 @@ def _layer_step(layer, cfg: TransformerConfig, x, cos, sin, attend,
     if cfg.residual_in_fp32:
         x = x.astype(jnp.float32)
     if "input_norm" in layer:
-        x = _mixer_half(layer, cfg, x, cos, sin, attend, kind)
+        with jax.named_scope("omni.proj"):
+            h = _rms(cfg, x, layer["input_norm"]).astype(
+                layer["input_norm"]["w"].dtype)
+        o = _mixer(layer, cfg, h, cos, sin, attend, kind)
+        if cfg.parallel_block:
+            # one norm a layer: the MLP reads ``h`` too
+            return _join_branches(cfg, x, o, _mlp_branch(layer, cfg, h))
+        with jax.named_scope("omni.proj"):
+            x = _join_residual(cfg, x, o)
     if "post_norm" not in layer:
         return x
     with jax.named_scope("omni.proj"):
         h = _rms(cfg, x, layer["post_norm"]).astype(
             layer["post_norm"]["w"].dtype)
+    y = _mlp_branch(layer, cfg, h)
+    with jax.named_scope("omni.moe" if "experts" in layer else "omni.mlp"):
+        return _join_residual(cfg, x, y)
+
+
+def _mlp_branch(layer, cfg: TransformerConfig, h):
+    """The feed-forward part's branch of normed rows ``h``, whole under
+    tensor parallelism."""
     with jax.named_scope("omni.moe" if "experts" in layer else "omni.mlp"):
         y = _mlp(layer, cfg, h)
         if cfg.tp_axis is not None:
             y = jax.lax.psum(y, cfg.tp_axis)
-        return _join_residual(cfg, x, y)
+        return y
 
 
-def _mixer_half(layer, cfg: TransformerConfig, x, cos, sin, attend,
-                kind: str):
-    """``x + mixer(rms(x))``: the first half of ``_layer_step``."""
-    b = x.shape[:-1]
-    act = layer["input_norm"]["w"].dtype
-    with jax.named_scope("omni.proj"):
-        h = _rms(cfg, x, layer["input_norm"]).astype(act)
+@jax.named_scope("omni.proj")
+def _join_branches(cfg: TransformerConfig, x, o, y):
+    """``x + residual_multiplier * (o + y)`` of a parallel block: the sum
+    in float32 and ONE rounding to the stream's type, where two sums in
+    the stream's type would round twice."""
+    both = o.astype(jnp.float32) + y.astype(jnp.float32)
+    if cfg.residual_multiplier != 1.0:
+        both = cfg.residual_multiplier * both
+    return (x.astype(jnp.float32) + both).astype(x.dtype)
+
+
+def _mixer(layer, cfg: TransformerConfig, h, cos, sin, attend, kind: str):
+    """``mixer(h)`` for normed rows ``h``: the branch that the first half
+    of ``_layer_step`` adds to the stream, whole under tensor parallelism."""
+    b = h.shape[:-1]
     record = state_layers.KINDS.get(kind)
     if record:
         o = record.mixer(layer[record.key], cfg, h.reshape(-1, h.shape[-1]),
@@ -1084,7 +1153,7 @@ def _mixer_half(layer, cfg: TransformerConfig, x, cos, sin, attend,
     else:
         with jax.named_scope("omni.proj"):
             q, k, v, gate = _qkv(layer, cfg, h.reshape(-1, h.shape[-1]))
-            if cfg.use_rope:
+            if cos is not None:  # a kind, or a model, without positions
                 q = _rope(cfg, q, cos, sin)
                 k = _rope(cfg, k, cos, sin)
         o = attend(q, k, v).reshape(*b, -1)
@@ -1095,11 +1164,11 @@ def _mixer_half(layer, cfg: TransformerConfig, x, cos, sin, attend,
                     gate.reshape(*b, -1).astype(jnp.float32))).astype(o.dtype)
         with jax.named_scope("omni.proj"):
             o = nn.out_proj(o, layer["o_proj"]["w"], cfg.residual_in_fp32)
-    with jax.named_scope("omni.proj"):
-        if cfg.tp_axis is not None:
+    if cfg.tp_axis is not None:
+        with jax.named_scope("omni.proj"):
             # row-parallel o_proj: each shard holds a partial sum
             o = jax.lax.psum(o, cfg.tp_axis)
-        return _join_residual(cfg, x, o)
+    return o
 
 
 class HybridCaches(NamedTuple):
@@ -1202,7 +1271,7 @@ def forward_hidden(
     (pipeline_flux2_klein.py:247-302).  When set, the concatenation is
     returned instead of the final hidden states.
     """
-    if cfg.sliding is not None or cfg.index_topk:
+    if cfg.num_window_layers or cfg.index_topk:
         raise NotImplementedError(
             "the cache-less full-sequence forward has no window and no "
             "learned selection: a model with sliding-window or sparse "
@@ -1212,8 +1281,7 @@ def forward_hidden(
     if positions is None:
         shape = (b, s) if cfg.mrope_sections is None else (b, 3, s)
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], shape)
-    cos, sin = _rope_tables(cfg, positions) if cfg.use_rope else (None,
-                                                                  None)
+    ropes = _kind_ropes(cfg, positions)
 
     def attend(q, k, v):
         with jax.named_scope("omni.attn"):
@@ -1259,7 +1327,7 @@ def forward_hidden(
     collected = {0: x} if 0 in collect_hidden_layers else {}
     for li, (layer, kind) in enumerate(zip(layers, cfg.layer_types)):
         record = state_layers.KINDS.get(kind)
-        x = _layer_step(layer, cfg, x, cos, sin,
+        x = _layer_step(layer, cfg, x, *ropes[kind],
                         recur_of(record) if record else attend, kind)
         if li + 1 in collect_hidden_layers:
             collected[li + 1] = x

@@ -197,6 +197,12 @@ _AR_MODELS: dict[str, _Entry] = {
     "NemotronHForCausalLM": _Entry(
         "vllm_omni_tpu.models.nemotron_h", "real_factory"
     ),
+    # a parallel block under one LayerNorm, a window over per-head (K, V)
+    # pages on three layers in four, rotary a kind of layer, four shared
+    # experts averaged (command-a-plus-05-2026; docs/kv_cache.md)
+    "Cohere2MoeForCausalLM": _Entry(
+        "vllm_omni_tpu.models.cohere2_moe", "real_factory"
+    ),
 }
 
 

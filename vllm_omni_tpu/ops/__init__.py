@@ -12,9 +12,11 @@ from vllm_omni_tpu.ops.rmsnorm import rms_norm, rms_norm_ref
 from vllm_omni_tpu.ops.rope import (
     apply_partial_rope,
     apply_rope,
+    apply_rope_pairs_ref,
     apply_rope_ref,
     compute_rope_freqs,
     compute_mrope_freqs,
+    pairs_to_halves,
 )
 from vllm_omni_tpu.ops.attention import flash_attention, attention_ref
 from vllm_omni_tpu.ops.paged_attention import (
@@ -37,9 +39,11 @@ __all__ = [
     "rms_norm_ref",
     "apply_partial_rope",
     "apply_rope",
+    "apply_rope_pairs_ref",
     "apply_rope_ref",
     "compute_rope_freqs",
     "compute_mrope_freqs",
+    "pairs_to_halves",
     "flash_attention",
     "attention_ref",
     "cache_data",

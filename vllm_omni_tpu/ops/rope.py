@@ -8,7 +8,11 @@ reference implements in model_executor/layers/rotary_embedding/mrope.py:25);
 the Pallas kernel then applies the rotation to Q and K in one fused pass.
 
 Convention: GPT-NeoX half-split rotation (x1 = x[..., :d/2], x2 = x[..., d/2:]),
-the layout used by the Qwen model families.
+the layout used by the Qwen model families.  A model whose rotary embedding
+pairs NEIGHBOURING dims (GPT-J's: ``position_embedding_type`` ``rope_gptj``)
+is served by the same rotation over q and k columns permuted once in the
+weight tree (``pairs_to_halves``): the scores are the published ones, and
+no activation is shuffled across lanes at run time.
 """
 
 from __future__ import annotations
@@ -89,6 +93,33 @@ def apply_rope_ref(
     # 64-wide halves there aborts the XLA TPU compiler (libtpu 0.0.34,
     # fusion_emitter "IsFusibleUnalignedDUS" check) at [256, 32, 128]
     return jnp.stack([o1, o2], axis=-2).reshape(x.shape).astype(x.dtype)
+
+
+def apply_rope_pairs_ref(x: jax.Array, cos: jax.Array, sin: jax.Array
+                         ) -> jax.Array:
+    """The neighbouring-pair rotation as published (GPT-J):
+    ``(y[2i], y[2i+1]) = (x[2i] c_i - x[2i+1] s_i, x[2i+1] c_i + x[2i]
+    s_i)`` over ``[T, H, D]`` with the tables of ``compute_rope_freqs``.
+    The oracle ``pairs_to_halves`` is held to; nothing serves it (a
+    stride-2 walk of the lanes is a relayout of every q and k row)."""
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def pairs_to_halves(w: jax.Array, head_dim: int) -> jax.Array:
+    """The columns of a q or k projection ``[in, heads * head_dim]`` whose
+    checkpoint rotates neighbouring pairs, reordered a head at a time to
+    evens then odds: ``apply_rope`` (half-split) over ``x W'`` is then
+    ``apply_rope_pairs_ref`` over ``x W`` with each head's dims in that
+    order, and a score ``q . k`` does not depend on the order of the dims
+    both share.  Done once where a weight tree is made (a loader; the
+    benchmark's seeded draw), never on activations."""
+    lead = w.shape[:-1]
+    return jnp.swapaxes(
+        w.reshape(*lead, -1, head_dim // 2, 2), -1, -2).reshape(w.shape)
 
 
 def apply_partial_rope(x: jax.Array, cos: jax.Array, sin: jax.Array

@@ -282,9 +282,11 @@ class ARModelRunner:
         if self._latent and not reason:
             reason = shared_tiling_reason(None, cfg.kv_lora_rank)
         if self._window and not reason:
-            reason = (row_tiling_reason(
+            reason = row_tiling_reason(
                 cfg.kv_layout_of("sliding_attention").width, page_size)
-                or shared_tiling_reason(None, cfg.sliding.kv_lora_rank))
+            if self._latent and not reason:
+                reason = shared_tiling_reason(None,
+                                              cfg.sliding.kv_lora_rank)
         if reason and _dispatch.pallas_mode() == "native":
             raise ValueError(
                 f"the paged attention kernels cannot tile this geometry "
@@ -379,7 +381,8 @@ class ARModelRunner:
         )
         if self._window or cfg.index_topk:
             # pools by the layer's kind, in layer order: a sliding layer's
-            # over the window kind's pages at its own row width; a full
+            # over the window kind's pages in the kind's own layout (a
+            # latent row at its own width, or (K, V) rows a head); a full
             # layer with an indexer holds, on the same pages as its rows,
             # the index keys' pool as a third entry
             kinds = cfg.kv_layer_types
@@ -388,7 +391,7 @@ class ARModelRunner:
                 if kind == "sliding_attention":
                     pages[i] = init_kv_cache(
                         1, num_window_pages, page_size, wide.heads,
-                        wide.width, dtype, shared=True)[0]
+                        wide.width, dtype, shared=wide.kind == "latent")[0]
                 elif cfg.index_topk:
                     pages[i] += init_kv_cache(
                         1, num_pages, page_size, 1, cfg.index_head_dim,
