@@ -1971,3 +1971,14 @@ class LLMEngine:
             results[out.request_id] = out
         return [results[rid] for rid in
                 sorted(results, key=lambda r: order.get(r, 1 << 30))]
+
+    # (last in the file so that no line above it moved: the persistent
+    # compile cache's key holds the source positions of what is traced)
+    @property
+    def work_steps(self) -> int:
+        """Steps that did device work, lifetime: one a flight record (a
+        step dispatched or run) and one a predecessor retired without a
+        record of its own (``_drain_pipeline``).  A zero-scheduled tick
+        moves ``_steps_completed`` and not this: the orchestrator's loop
+        waits after a turn in which it stood still (OmniStage.poll)."""
+        return self.flight.total_steps + self.pipeline_drains

@@ -334,9 +334,12 @@ class AsyncOmni:
                     self._emit(rid, e)
                     self._emit(rid, _SENTINEL)
                 continue
+            # a stage that delivered outputs advanced, and so did one
+            # whose poll ran a step that did device work or a diffusion
+            # batch, whether or not anything finished (OmniStage.advanced)
+            progressed |= bool(outs) or getattr(stage, "advanced", False)
             if not outs:
                 continue
-            progressed = True
             with _phase("omni.emit"):
                 # errored outputs terminate their streams and are not
                 # forwarded downstream
@@ -374,10 +377,16 @@ class AsyncOmni:
                         self._emit(o.request_id, e)
                         self._emit(o.request_id, _SENTINEL)
         if not progressed and not pending:
-            # idle: avoid a hot spin on the GIL.  The engine returns
-            # finished requests only, so this wait is also taken after
-            # every step that finished none, with rows waiting for their
-            # next token: counted as a busy wait (ROADMAP S5)
+            # nothing advanced this turn (no stage ran a step that did
+            # device work, none delivered an output, the intake was
+            # empty): wait, so as not to spin on the GIL.  With work
+            # unfinished it is a busy wait, and what is left to wait for
+            # is out of this thread's hands: a stage in a process of its
+            # own, or an engine whose step scheduled nothing (a streaming
+            # request idling for its next chunk, pages pinned by a
+            # transfer awaiting its ACK).  There the wait is also what
+            # spaces the ticks the engine counts before it declares a
+            # waiting request starved (LLMEngine._run_scheduled)
             busy = any(getattr(s, "has_unfinished", False)
                        for s in omni.stages)
             with _phase("omni.idle_wait") as p:

@@ -122,6 +122,14 @@ def _sp_equal(a: dict, b: dict) -> bool:
 
 
 class OmniStage:
+    #: whether the last ``poll()`` advanced the stage, finished outputs or
+    #: none: its engine ran a step that dispatched or retired device work
+    #: (a zero-scheduled tick did not), or a diffusion batch ran.  The
+    #: orchestrator's loop waits only after a turn in which no stage did
+    #: (AsyncOmni._loop_once).  The proxy of a stage in another process
+    #: (ProcStage) polls an inbox, advances nothing here and leaves it so.
+    advanced = False
+
     def __init__(self, config: StageConfig):
         self.config = config
         self.stage_id = config.stage_id
@@ -347,10 +355,14 @@ class OmniStage:
         """Advance the stage's engine and return newly finished outputs
         (the in-proc analogue of try_collect, omni_stage.py:572)."""
         outs: list[OmniRequestOutput] = []
+        self.advanced = False
         if self.config.stage_type == "llm":
             if self.engine.has_unfinished_requests:
+                before = self.engine.work_steps
                 outs = self.engine.step()
+                self.advanced = self.engine.work_steps != before
         else:
+            self.advanced = bool(self._pending)
             outs = self._run_diffusion_batch()
         decode_text = (self.tokenizer is not None
                        and self.config.final_output_type == "text")
