@@ -12,23 +12,21 @@ never returns on the device holds the machine until the call's limit: PR
 executable prints a line as it starts (to ``chiprun_out/warmup_probe.log``
 too, which comes back when the call is killed), and one that outlasts the
 deadline dumps every thread's stack and ends the process with code 1, so
-the last line names the executable that stopped.  The line that ends the
-warm-up says what it was made of: the seconds JAX itself reports for
-tracing (``jaxpr_trace_duration``), for lowering
-(``jaxpr_to_mlir_module_duration``) and for compiling or loading from the
-persistent cache (``backend_compile_duration``), summed over the warm-up
-(PERF.md, PR 48: a warm set-up is the first two).  ``PROBE_ONLY_T=256,512``
-warms those token buckets' unified executables alone (with
-``PROBE_ONLY_B=64`` also those decode buckets' two executables each, and
-the largest bucket's) and ends the process there: what a new kernel's
-first step programs cost to try, a minute of weights and half a minute an
-executable.  A builder's tool: the driver
-reads none of it.
+the last line names the executable that stopped.  The probe counts
+nothing itself (PR 52): each line also says what the executable before it
+took, from the program's own record of it (``compile_stats.executables``;
+``compile.current`` on ``/debug/z`` names the one being built), and the
+line that ends the warm-up prints the ``startup`` spans so far and the
+``compile`` totals: tracing, lowering, compiling or loading, first runs
+(PERF.md section 5).  ``PROBE_ONLY_T=256,512`` warms those token buckets'
+unified executables alone (with ``PROBE_ONLY_B=64`` also those decode
+buckets' two executables each, and the largest bucket's) and ends the
+process there: what a new kernel's first step programs cost to try.  A
+builder's tool: the driver reads none of it.
 """
 
 from __future__ import annotations
 
-import collections
 import faulthandler
 import os
 import sys
@@ -54,26 +52,28 @@ def say(msg: str) -> None:
 
 def main() -> None:
     from vllm_omni_tpu.engine import llm_engine
+    from vllm_omni_tpu.introspection import phase_clock
 
     warm = llm_engine.LLMEngine.warmup
 
-    def progress(msg: str) -> None:
-        faulthandler.cancel_dump_traceback_later()
-        faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
-        say(msg)
-
-    import jax
-
-    parts: collections.Counter = collections.Counter()  # event -> seconds
-
-    def add(event, secs, **kw):
-        parts[event] += secs
-
-    jax.monitoring.register_event_duration_secs_listener(add)
+    def record(stats) -> str:
+        """The program's own account of the set-up so far."""
+        snap = stats.snapshot()
+        spans = {s["name"]: s["ms"]
+                 for s in phase_clock.startup.snapshot()["spans"]}
+        rows = snap.pop("executables")
+        return (f"spans (ms) {spans}; compile {snap}; slowest "
+                f"{max(rows, key=lambda r: r['total_s'], default=None)}")
 
     def warmup(self, prefill_shapes=(), progress_fn=None):
         runner = self.runner
-        parts.clear()
+
+        def progress(msg: str) -> None:
+            faulthandler.cancel_dump_traceback_later()
+            faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+            done = runner.compile_stats.executables
+            say(f"{msg}  (before it: {done[-1] if done else None})")
+
         say(f"warm-up begins: decode buckets {runner._batch_buckets}, "
             f"token buckets {runner._token_buckets} up to the first over "
             f"{runner._warm_token_cap}")
@@ -89,17 +89,15 @@ def main() -> None:
             built = runner.precompile(decode=bool(ONLY_B),
                                       progress_fn=progress)
             faulthandler.cancel_dump_traceback_later()
-            say(f"PROBE_ONLY_T: {built} executables returned, "
-                f"{runner.compile_stats}")
+            say(f"PROBE_ONLY_T: {built} executables returned; "
+                f"{record(runner.compile_stats)}")
             sys.stdout.flush()
             os._exit(0)
         built = warm(self, prefill_shapes=prefill_shapes,
                      progress_fn=progress)
         faulthandler.cancel_dump_traceback_later()
-        say(f"warm-up ended: {built} executables, {runner.compile_stats}")
-        say("warm-up by part: " + ", ".join(
-            f"{event.rsplit('/', 1)[-1]} {secs:.2f} s"
-            for event, secs in sorted(parts.items())))
+        say(f"warm-up ended: {built} executables; "
+            f"{record(runner.compile_stats)}")
         return built
 
     llm_engine.LLMEngine.warmup = warmup

@@ -107,8 +107,9 @@ BENCH_PATHS: tuple[str, ...] = (
     # around a jax dispatch in them syncs (or says why it must not).
     # model_runner.py also carries the unified ragged dispatch
     # (_run_unified/dispatch_unified) and the compile-telemetry timing
-    # in _run_jit, whose fresh-compile branch must block_until_ready
-    # before stopping the clock
+    # in _run_jit, whose fresh-compile branch (phase_clock.py
+    # CompileStats.first_call) must block_until_ready before stopping
+    # the clock
     "vllm_omni_tpu/engine/llm_engine.py",
     "vllm_omni_tpu/worker/model_runner.py",
     # the open-loop runner times around async dispatch (arrival ->

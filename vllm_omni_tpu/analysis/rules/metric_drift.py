@@ -68,7 +68,18 @@ def synthetic_engine_snapshot() -> dict:
         "padding": {"useful_tokens_total": 42, "padded_tokens_total": 64,
                     "efficiency": 0.6563},
         "compile": {"compiles": 9, "cache_hits": 120,
-                    "compile_s": 33.5},
+                    "compile_s": 33.5, "trace_s": 11.0, "lower_s": 15.5,
+                    "backend_s": 5.75},
+        # the process's start-up record (engine_setup_seconds{phase})
+        "startup": {"now_ms": 90000.0, "stamps": {"serving": 61000.0},
+                    "spans": [
+                        {"name": "omni.setup", "stage": None,
+                         "t0_ms": 14000.0, "ms": 47000.0, "self_ms": 310.0,
+                         "parent": None},
+                        {"name": "omni.setup.warmup", "stage": 0,
+                         "t0_ms": 28000.0, "ms": 32500.0,
+                         "self_ms": 32500.0,
+                         "parent": "omni.setup.engine"}]},
         # live roofline attribution (metrics/roofline.py):
         # engine_step_mfu + the phase-labeled engine_step_mbu
         "roofline": {"mfu": 0.31, "mbu": {"prefill": 0.12,

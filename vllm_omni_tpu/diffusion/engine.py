@@ -29,6 +29,7 @@ from vllm_omni_tpu.diffusion.request import (
     OmniDiffusionRequest,
     OmniDiffusionSamplingParams,
 )
+from vllm_omni_tpu.introspection.phase_clock import startup
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.metrics.stats import Histogram
 from vllm_omni_tpu.models.registry import DiffusionModelRegistry
@@ -187,10 +188,11 @@ class DiffusionEngine:
                 quant_at_init = True
         if from_ckpt:
             # diffusers-format checkpoint directory: real weights
-            self.pipeline = pipeline_cls.from_pretrained(
-                od_config.model, dtype=dtype, seed=od_config.seed,
-                cache_config=cache_config, mesh=mesh, **extra_kwargs,
-            )
+            with startup.span("omni.setup.weights"):
+                self.pipeline = pipeline_cls.from_pretrained(
+                    od_config.model, dtype=dtype, seed=od_config.seed,
+                    cache_config=cache_config, mesh=mesh, **extra_kwargs,
+                )
             if solver and hasattr(self.pipeline.cfg, "scheduler"):
                 # from_pretrained builds its own config; re-apply the
                 # override (it was validated above) before any denoise
@@ -214,10 +216,11 @@ class DiffusionEngine:
                     "building %s with random-init weights",
                     od_config.model, arch,
                 )
-            self.pipeline = pipeline_cls(
-                pipe_cfg, dtype=dtype, seed=od_config.seed,
-                cache_config=cache_config, mesh=mesh, **extra_kwargs,
-            )
+            with startup.span("omni.setup.weights"):
+                self.pipeline = pipeline_cls(
+                    pipe_cfg, dtype=dtype, seed=od_config.seed,
+                    cache_config=cache_config, mesh=mesh, **extra_kwargs,
+                )
         if quant_at_init:
             pass  # already quantized block-by-block during init
         elif od_config.quantization in ("int8", "fp8", "int4"):
@@ -252,10 +255,11 @@ class DiffusionEngine:
         self._num_batches = 0
         self._gen_seconds = Histogram(buckets=_GEN_SECONDS_BUCKETS)
         if warmup:
-            self._warmup()
+            with startup.span("omni.setup.warmup"):
+                self._warmup()
 
     def metrics_snapshot(self) -> dict:
-        return {"diffusion": {
+        return {"startup": startup.snapshot(), "diffusion": {
             "requests_total": self._num_requests,
             "batches_total": self._num_batches,
             "gen_seconds": self._gen_seconds.snapshot(),

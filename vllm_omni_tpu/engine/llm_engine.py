@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -35,6 +36,7 @@ from vllm_omni_tpu.introspection import (
     PhaseClock,
     register_engine,
 )
+from vllm_omni_tpu.introspection.phase_clock import startup
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.metrics.roofline import (
     capped_ctx_positions,
@@ -454,20 +456,24 @@ class LLMEngine:
                         f"tensor_parallel_size={tp} but only "
                         f"{len(devs)} devices visible")
                 mesh = Mesh(_np.array(devs[:tp]), (AXIS_TP,))
-            self.runner = ARModelRunner(
-                params, model_cfg,
-                num_pages=num_pages, page_size=config.page_size,
-                kv_cache_dtype=config.kv_cache_dtype,
-                max_model_len=config.max_model_len, dtype=config.dtype,
-                collect_hidden=config.collect_hidden, seed=config.seed,
-                max_num_seqs=config.max_num_seqs, mesh=mesh,
-                max_num_batched_tokens=config.max_num_batched_tokens,
-                num_window_pages=num_window_pages,
-            )
+            # the pages, the state slots, the window pools
+            with startup.span("omni.setup.cache"):
+                self.runner = ARModelRunner(
+                    params, model_cfg,
+                    num_pages=num_pages, page_size=config.page_size,
+                    kv_cache_dtype=config.kv_cache_dtype,
+                    max_model_len=config.max_model_len, dtype=config.dtype,
+                    collect_hidden=config.collect_hidden, seed=config.seed,
+                    max_num_seqs=config.max_num_seqs, mesh=mesh,
+                    max_num_batched_tokens=config.max_num_batched_tokens,
+                    num_window_pages=num_window_pages,
+                )
         # the host's phases on the host's clock (introspection/
         # phase_clock.py): one clock for the engine thread, which the
         # runner's halves of a step and the orchestrator's loop charge too
         self.phases = self.runner.phases = PhaseClock()
+        # a compile under traffic is recorded with this engine's step
+        self.runner.compile_stats.engine = weakref.ref(self)
         if (draft_fn is not None and config.num_speculative_tokens > 0
                 and hasattr(self.runner, "set_draft_fn")):
             self.runner.set_draft_fn(
@@ -622,16 +628,22 @@ class LLMEngine:
         fn = getattr(self.runner, "precompile", None)
         if fn is None:
             return 0
-        built = fn(prefill_shapes=prefill_shapes, progress_fn=progress_fn)
-        stats = getattr(self.runner, "compile_stats", None)
-        if stats is not None:
-            # the shape-cache telemetry baseline: compiles past this
-            # line are mid-traffic stalls (jit_compiles_total on
-            # /metrics keeps counting them)
-            logger.info(
-                "warmup compiled %d executables in %.1fs "
-                "(%d cache hits)", stats["compiles"],
-                stats["compile_s"], stats["cache_hits"])
+        with startup.span("omni.setup.warmup"):
+            built = fn(prefill_shapes=prefill_shapes,
+                       progress_fn=progress_fn)
+        stats = self.runner.compile_stats
+        parts = stats["trace_s"] + stats["lower_s"] + stats["backend_s"]
+        # the shape-cache telemetry baseline: compiles past this line
+        # are mid-traffic stalls (jit_compiles_total on /metrics keeps
+        # counting them)
+        logger.info(
+            "warmup compiled %d executables in %.1fs: tracing %.1fs, "
+            "lowering %.1fs, compiling or loading %.1fs (%d of the "
+            "persistent cache's, %d new to it), first runs %.1fs "
+            "(%d cache hits)", stats["compiles"], stats["compile_s"],
+            stats["trace_s"], stats["lower_s"], stats["backend_s"],
+            stats["persistent_hits"], stats["persistent_misses"],
+            max(stats["compile_s"] - parts, 0.0), stats["cache_hits"])
         return built
 
     # ------------------------------------------------------------- intake
@@ -960,6 +972,9 @@ class LLMEngine:
                 "stage_id": self.stage_id,
                 "waiting": len(self.scheduler.waiting),
                 "running": len(self.scheduler.running),
+                # the executable being built, where a compile is in
+                # flight: a trip's dump names it
+                "compiling": getattr(compile_stats, "current", None),
             },
         }
 
@@ -1315,9 +1330,8 @@ class LLMEngine:
                 "parked_tokens": kv.parked_tokens,
                 "offload_evictions": kv.offload_evictions,
             }
-        compile_stats = getattr(self.runner, "compile_stats", None)
-        if compile_stats is not None:
-            snap["compile"] = dict(compile_stats)
+        # the executables by name (engine_compile_seconds_total{part})
+        snap["compile"] = self.runner.compile_stats.snapshot()
         # the host's phases, stalls and collections, lifetime totals
         # (engine_host_phase_ms_total{phase}, engine_stalls_total,
         # engine_stall_ms_total on /metrics)

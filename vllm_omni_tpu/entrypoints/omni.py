@@ -27,6 +27,7 @@ from vllm_omni_tpu.config.stage import (
 )
 from vllm_omni_tpu.distributed.connectors import ConnectorFactory, make_key
 from vllm_omni_tpu.entrypoints.omni_stage import OmniStage, StageRequest
+from vllm_omni_tpu.introspection.phase_clock import startup
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.metrics.stats import OrchestratorAggregator
 from vllm_omni_tpu.outputs import OmniRequestOutput
@@ -101,36 +102,39 @@ class Omni:
         # process-disaggregated stages spawn workers (ready handshake
         # inside ProcStage); in-proc stages build engines directly
         self.stages = []
-        for cfg in configs:
-            if cfg.runtime.process:
-                from vllm_omni_tpu.entrypoints.stage_proc import ProcStage
+        # ``omni.setup`` of the start-up record: the stages' weights,
+        # caches and warm-ups open their spans inside it
+        with startup.span("omni.setup"):
+            for cfg in configs:
+                if cfg.runtime.process:
+                    from vllm_omni_tpu.entrypoints.stage_proc import ProcStage
 
-                env = cfg.runtime.device_env
-                if not env:
-                    # env-sniffed default (CUDA_VISIBLE_DEVICES analogue)
-                    # — deliberately NOT current_platform(): that would
-                    # initialize jax in the parent and acquire the TPU
-                    # the children need
-                    from vllm_omni_tpu.platforms import (
-                        default_stage_device_env,
-                    )
+                    env = cfg.runtime.device_env
+                    if not env:
+                        # env-sniffed default (CUDA_VISIBLE_DEVICES analogue)
+                        # — deliberately NOT current_platform(): that would
+                        # initialize jax in the parent and acquire the TPU
+                        # the children need
+                        from vllm_omni_tpu.platforms import (
+                            default_stage_device_env,
+                        )
 
-                    env = default_stage_device_env(cfg.runtime.devices)
-                if getattr(cfg.runtime, "supervise", True):
-                    # supervised by default: worker crash/hang becomes
-                    # restart + redeliver instead of a dead stage
-                    # (resilience/supervisor.py)
-                    from vllm_omni_tpu.resilience.supervisor import (
-                        StageSupervisor,
-                    )
+                        env = default_stage_device_env(cfg.runtime.devices)
+                    if getattr(cfg.runtime, "supervise", True):
+                        # supervised by default: worker crash/hang becomes
+                        # restart + redeliver instead of a dead stage
+                        # (resilience/supervisor.py)
+                        from vllm_omni_tpu.resilience.supervisor import (
+                            StageSupervisor,
+                        )
 
-                    self.stages.append(
-                        StageSupervisor(cfg, device_env=env))
+                        self.stages.append(
+                            StageSupervisor(cfg, device_env=env))
+                    else:
+                        self.stages.append(ProcStage(cfg, device_env=env))
                 else:
-                    self.stages.append(ProcStage(cfg, device_env=env))
-            else:
-                self.stages.append(OmniStage(cfg))
-                self.memory_accountant.snapshot(cfg.stage_id)
+                    self.stages.append(OmniStage(cfg))
+                    self.memory_accountant.snapshot(cfg.stage_id)
         self.metrics = OrchestratorAggregator(len(configs), stats_path)
         # per-request distributed tracing: a trace context created at
         # arrival, re-stamped on every stage handoff, closed at final

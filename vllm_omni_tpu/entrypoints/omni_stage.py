@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 from vllm_omni_tpu.config.stage import StageConfig
+from vllm_omni_tpu.introspection.phase_clock import startup
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.metrics.stats import StageRequestStats
 from vllm_omni_tpu.outputs import OmniRequestOutput
@@ -187,7 +188,8 @@ class OmniStage:
                     "(separate from the model path) — set it with "
                     f"--stage-override '{self.stage_id}."
                     'model_factory_args={"model_dir": "/path"}\'')
-            params, model_cfg, eos = factory(**factory_args)
+            with startup.span("omni.setup.weights", stage=self.stage_id):
+                params, model_cfg, eos = factory(**factory_args)
             # voice registry: engine_args.voices maps name -> conditioning
             # assets (speaker_embedding / reference_mel); the serving
             # layer advertises the names (/v1/audio/voices) and vocoder
@@ -242,15 +244,19 @@ class OmniStage:
                     and eng_kwargs.get("num_speculative_tokens", 0) > 0):
                 if isinstance(draft_factory, str):
                     draft_factory = _import_obj(draft_factory)
-                draft_fn = draft_factory(
-                    params, model_cfg,
-                    eng_kwargs["num_speculative_tokens"],
-                )
+                with startup.span("omni.setup.draft", stage=self.stage_id):
+                    draft_fn = draft_factory(
+                        params, model_cfg,
+                        eng_kwargs["num_speculative_tokens"],
+                    )
             # EngineConfig(warmup=...) in the stage YAML precompiles the
             # bucketed executables inside LLMEngine.__init__ — before
             # the stage reports ready, so traffic never hits a compile
-            engine = LLMEngine(params, model_cfg, EngineConfig(**eng_kwargs),
-                               eos_token_id=eos, draft_fn=draft_fn)
+            # (its spans ``omni.setup.cache`` and ``.warmup`` open inside)
+            with startup.span("omni.setup.engine", stage=self.stage_id):
+                engine = LLMEngine(
+                    params, model_cfg, EngineConfig(**eng_kwargs),
+                    eos_token_id=eos, draft_fn=draft_fn)
             if engine.config.kv_transfer is not None:
                 # extracted KV rides the stage output (D2H2D v1); the
                 # consuming stage's input processor forwards it into
@@ -266,7 +272,8 @@ class OmniStage:
             from vllm_omni_tpu.diffusion.engine import DiffusionEngine
 
             od = OmniDiffusionConfig.from_kwargs(**args)
-            return DiffusionEngine.make_engine(od)
+            with startup.span("omni.setup.engine", stage=self.stage_id):
+                return DiffusionEngine.make_engine(od)
         raise ValueError(f"unknown stage_type {self.config.stage_type!r}")
 
     # ------------------------------------------------------------- intake

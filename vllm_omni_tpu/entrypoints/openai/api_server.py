@@ -25,6 +25,7 @@ from typing import Any, Optional
 import numpy as np
 
 from vllm_omni_tpu.entrypoints.async_omni import AsyncOmni
+from vllm_omni_tpu.introspection.phase_clock import startup
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.version import __version__
 
@@ -1014,6 +1015,8 @@ def build_server(
     state = ServerState(omni, model or "omni")
     handler = type("BoundHandler", (OmniRequestHandler,), {"state": state})
     server = ThreadingHTTPServer((host, port), handler)
+    # the socket listens and the orchestrator's loop turns
+    startup.stamp("serving")
     return server, state
 
 

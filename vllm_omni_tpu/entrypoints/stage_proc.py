@@ -278,6 +278,8 @@ def _stage_worker_serve(config: StageConfig, chan,
     # omni-0..N), and a permanent set would silently drop the reuse —
     # and grow for the worker's lifetime.
     seen_ids: set[str] = set()
+    # fresh executables when the set-up's record last rode a frame
+    setup_shipped_at: Optional[int] = -1
     while running:
         if watch_parent and os.getppid() != parent:
             # orchestrator died (shm rings carry no EOF the way a socket
@@ -372,6 +374,14 @@ def _stage_worker_serve(config: StageConfig, chan,
                     msg["spans"] = spans
                 metrics = stage.engine_metrics_snapshot()
                 if metrics:
+                    # the set-up's record (the start-up spans, the
+                    # executables by name: tens of KB) rides the first
+                    # frame, and again only once a fresh executable
+                    # was built; every other frame carries the scalars
+                    compiles = (metrics.get("compile") or {}).get("compiles")
+                    if compiles == setup_shipped_at:
+                        metrics = _without_setup_record(metrics)
+                    setup_shipped_at = compiles
                     msg["metrics"] = metrics
                 resilience = resilience_metrics.snapshot()
                 if resilience:
@@ -737,7 +747,8 @@ class ProcStage(OmniStage):
                     get_recorder().extend(spans)
                 metrics = msg.get("metrics")
                 if metrics:
-                    self._engine_metrics = metrics
+                    self._engine_metrics = _with_setup_record(
+                        metrics, self._engine_metrics)
                 resilience = msg.get("resilience")
                 if resilience:
                     # latest worker-lifetime resilience counters; merged
@@ -850,3 +861,26 @@ class ProcStage(OmniStage):
                 self._proc.terminate()
         except Exception:
             pass
+
+
+# ------------------------------------------------ the set-up's record, once
+def _without_setup_record(snap: dict) -> dict:
+    """An engine snapshot less what does not change between two fresh
+    executables: ``startup`` and ``compile.executables``."""
+    snap = {k: v for k, v in snap.items() if k != "startup"}
+    if "compile" in snap:
+        snap["compile"] = {k: v for k, v in snap["compile"].items()
+                           if k != "executables"}
+    return snap
+
+
+def _with_setup_record(snap: dict, last: dict) -> dict:
+    """``snap`` as the worker holds it: what ``_without_setup_record``
+    took out comes from ``last``, the snapshot kept so far."""
+    if "startup" not in snap and "startup" in last:
+        snap = {**snap, "startup": last["startup"]}
+    kept = (last.get("compile") or {}).get("executables")
+    if kept is not None and "executables" not in snap.get("compile", {}):
+        snap = {**snap, "compile": {**snap.get("compile", {}),
+                                    "executables": kept}}
+    return snap

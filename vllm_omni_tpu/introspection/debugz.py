@@ -128,7 +128,10 @@ def engine_debug(engine) -> dict:
             "token_buckets": list(getattr(runner, "_token_buckets", ())),
             "shapes_seen": len(getattr(runner, "_jit_seen", ()) or ()),
         }
-        doc["compile"] = dict(getattr(runner, "compile_stats", {}) or {})
+        # the executables by name; ``current`` names the one a warm-up
+        # or a step is building right now
+        stats = getattr(runner, "compile_stats", None)
+        doc["compile"] = stats.snapshot() if stats is not None else {}
     ledger = getattr(engine, "memory", None)
     if ledger is not None:
         doc["device_memory"] = ledger.snapshot()

@@ -234,6 +234,19 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "counter",
         "Cumulative seconds spent blocked on fresh compiles "
         "(first call per shape, to completion)", ("stage",)),
+    # ---- the set-up on the program's own clock
+    # (introspection/phase_clock.py, docs/observability.md "Set-up")
+    "engine_compile_seconds_total": (
+        "counter",
+        "Seconds of the fresh executables' first calls by part: JAX's "
+        "own tracing, lowering, and compiling or loading from the "
+        "persistent cache (backend), and the rest (run): they sum to "
+        "jit_compile_seconds_total", ("stage", "part")),
+    "engine_setup_seconds": (
+        "gauge",
+        "Seconds of the process's start-up record by span (omni.setup "
+        "and its children, summed over stages): a span's whole "
+        "duration, children included", ("stage", "phase")),
     # async pipeline drain granularity (docs/async_engine.md): sync
     # steps per reason while async scheduling is on.  Since PR 11 only
     # host-state reasons exist (kv_transfer | kv_offload | streaming |
@@ -748,6 +761,24 @@ def render_exposition(summary: dict, engine_snaps: dict,
                        compile_stats.get("cache_hits", 0))
             exp.sample("jit_compile_seconds_total", labels,
                        compile_stats.get("compile_s", 0.0))
+            parts = {part: compile_stats.get(f"{part}_s", 0.0)
+                     for part in ("trace", "lower", "backend")}
+            parts["run"] = max(compile_stats.get("compile_s", 0.0)
+                               - sum(parts.values()), 0.0)
+            for part, secs in parts.items():
+                exp.sample("engine_compile_seconds_total",
+                           {**labels, "part": part}, secs)
+        setup_s: dict = {}
+        for span in (snap.get("startup") or {}).get("spans") or ():
+            # the process's record: every engine shows it, each under
+            # its own stage the spans that belong to no other
+            if span.get("ms") is not None and str(span.get("stage")) in (
+                    "None", str(sid)):
+                setup_s[span["name"]] = (setup_s.get(span["name"], 0.0)
+                                         + span["ms"] / 1e3)
+        for phase, secs in sorted(setup_s.items()):
+            exp.sample("engine_setup_seconds",
+                       {**labels, "phase": phase}, secs)
         for reason, count in sorted(
                 (snap.get("async_fallback") or {}).items()):
             exp.sample("async_fallback_total",

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from vllm_omni_tpu.core.scheduler import SchedulerOutput
-from vllm_omni_tpu.introspection.phase_clock import PhaseClock
+from vllm_omni_tpu.introspection.phase_clock import CompileStats, PhaseClock
 from vllm_omni_tpu.worker.model_runner import (
     RunnerOutput,
     _bucket,
@@ -40,6 +40,8 @@ class GenerationModelRunner:
         self._forward = jax.jit(model.forward)
         # the engine replaces it with its own clock (ARModelRunner)
         self.phases = PhaseClock()
+        # the warm-up's executables by name, as ARModelRunner keeps them
+        self.compile_stats = CompileStats()
 
     def precompile(self, prefill_shapes=(), progress_fn=None) -> int:
         """Warm the cond-free padded-batch forward for declared
@@ -56,10 +58,14 @@ class GenerationModelRunner:
                 prefill_shapes, self._batch_buckets, self._seq_buckets):
             if progress_fn is not None:
                 progress_fn(f"precompile generation b={b} s={s}")
-            out = self._forward(
-                self.params, jnp.zeros((b, s), jnp.int32),
-                jnp.full((b,), s, jnp.int32))
-            jax.block_until_ready(out)
+            with self.compile_stats.warming_up(), \
+                    self.compile_stats.first_call(
+                        self.phases, ("generation", b, s)) as p:
+                out = self._forward(
+                    self.params, jnp.zeros((b, s), jnp.int32),
+                    jnp.full((b,), s, jnp.int32))
+                jax.block_until_ready(out)
+                p.waited()
             built += 1
         return built
 

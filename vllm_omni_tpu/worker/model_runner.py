@@ -50,7 +50,7 @@ from vllm_omni_tpu.core.scheduler import (
     SchedulerOutput,
     packed_rows_needed,
 )
-from vllm_omni_tpu.introspection.phase_clock import PhaseClock
+from vllm_omni_tpu.introspection.phase_clock import CompileStats, PhaseClock
 from vllm_omni_tpu.kvcache import layouts as cache_layouts
 from vllm_omni_tpu.kvcache.quant import (
     dequantize_payload,
@@ -360,14 +360,15 @@ class ARModelRunner:
         # padding efficiency: real tokens vs. padded device rows
         self.useful_tokens = 0
         self.padded_tokens = 0
-        # jit shape-cache telemetry: fresh compiles vs. cache hits and
-        # cumulative first-call (compile-dominated) seconds, keyed by
-        # this runner's own (kind, shape) signatures
+        # jit shape-cache telemetry: fresh compiles vs. cache hits, keyed
+        # by this runner's own (kind, shape) signatures.  "compile_s" is
+        # the fresh signatures' first calls to completion: the sum of
+        # the four parts kept beside it and an executable (trace_s,
+        # lower_s, backend_s and the rest, the first run).
         # "in_flight" is the stall watchdog's compile-stall signal: set
         # around the fresh-compile branch of _run_jit so a mid-traffic
         # XLA compile reads as "compiling", never as a hung engine
-        self.compile_stats = {"compiles": 0, "cache_hits": 0,
-                              "compile_s": 0.0, "in_flight": 0}
+        self.compile_stats = CompileStats()
         # the host's phases on the host's clock; the engine replaces it
         # with its own, so that a step's phases land on one clock
         self.phases = PhaseClock()
@@ -750,24 +751,14 @@ class ARModelRunner:
                 p.waited()
             return result
         self._jit_seen.add(key)
-        self.compile_stats["in_flight"] = 1
         traced = kernel_calls()
-        try:
-            with self.phases.phase("omni.dispatch") as p:
-                # after the phase is entered: where it is the outermost
-                # one (precompile, a runner driven alone) entering it
-                # begins the turn, which forgets what was noted before
-                self.phases.note_compile()  # this turn is no stall
-                result = thunk()
-                jax.block_until_ready(result)
-                p.waited()
-        finally:
-            self.compile_stats["in_flight"] = 0
+        with self.compile_stats.first_call(self.phases, key) as p:
+            result = thunk()
+            jax.block_until_ready(result)
+            p.waited()
         if kernel_calls() > traced:
             self._grouped_kernel_keys.add(key)
             self.grouped_kernel_steps += 1
-        self.compile_stats["compiles"] += 1
-        self.compile_stats["compile_s"] += p.ns / 1e9
         return result
 
     def memory_components(self) -> dict:
@@ -864,7 +855,8 @@ class ARModelRunner:
 
         def warm(kind, key, thunk):
             nonlocal built
-            res = self._run_jit(kind, key, thunk)
+            with self.compile_stats.warming_up():
+                res = self._run_jit(kind, key, thunk)
             built += 1
             return res
 
