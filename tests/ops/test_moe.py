@@ -10,6 +10,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+# ``uncapped_share``: ``routed_moe_share`` as it was until PR 53, which is
+# its definition (the chip's sweep times it beside the tree's)
+from scripts.moe_share_rows_sweep import (
+    plan,
+    share_cap,
+    share_form,
+    uncapped_share,
+)
 from vllm_omni_tpu.models.common import transformer as tfm
 from vllm_omni_tpu.ops import moe as moe_ops
 from vllm_omni_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -410,6 +418,152 @@ def test_a_share_whose_absent_rows_are_poison_gives_what_it_gave(rng):
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert float(jnp.abs(want).max()) > 1e-3
+
+
+# ---- the share against its definition (ISSUE 53): the absent pairs' rows
+# are masked by their place in the sorted order, and one sort carries each
+# pair's token and weight there; ``uncapped_share`` is the function as it
+# was, which gathered the mask and the weights by the sort's order
+def _steered(rng, k, lift, to=(4, 8), t=64, e=16, hidden=16, inter=8):
+    """Weights of a 16-wide router whose experts ``to`` get ``lift`` added
+    to their logit on every token (``x``'s first column is 1): +50 sends
+    every pick there, -50 none, 0 leaves the router as drawn."""
+    x, rw, gu, dn = _mk_weights(rng, t=t, hidden=hidden, e=e, inter=inter)
+    x = x.at[:, 0].set(1.0)
+    rw = rw.at[0, to[0]:to[1]].add(lift)
+    return x, rw, gu, dn
+
+
+def _held(x, rw, k, offset, e_held, **router):
+    """(held pairs, each held group's (start, end) in the sorted order) of
+    the share at ``offset``."""
+    idx, _ = moe_ops.router_topk(x, rw, k, **router)
+    sizes = np.bincount(np.asarray(idx).reshape(-1) - offset + e_held,
+                        minlength=3 * e_held)[e_held:2 * e_held]
+    ends = np.cumsum(sizes)
+    return int(ends[-1]), list(zip(ends - sizes, ends))
+
+
+#: every form of the cut that PR 53 tried (scripts/moe_share_rows_sweep.py):
+#: all exact here; on the chip each stopped dots3's 2 048-token step
+CUTS = ("windows", "first_then_windows", "static_windows", "one_window",
+        "cond")
+
+
+@pytest.mark.parametrize("k,lift,to", [
+    (4, 0.0, (4, 8)), (4, 50.0, (4, 8)), (4, -50.0, (4, 8)),
+    (2, 50.0, (4, 6))],
+    ids=["uniform", "every_pick_held", "no_pick_held",
+         "cap_splits_a_group"])
+@pytest.mark.parametrize("form", ("tree", "tree_jit") + CUTS)
+def test_a_share_equals_the_share_as_it_was(rng, form, k, lift, to):
+    """Experts 4-7 of sixteen, 64 tokens.  Whatever the router does the
+    share is its definition: under a uniform router, where every pick is
+    held (the sorted order has no absent row to mask, and a cut works on
+    the rows behind its ``cap`` too: no pair dropped), where none is
+    (zeros, and nothing read from rows nobody wrote), and where a cut's
+    ``cap`` falls inside an expert's group."""
+    x, rw, gu, dn = _steered(rng, k, lift, to)
+    held, groups = _held(x, rw, k, 4, 4)
+    cap = share_cap(64 * k, 4, 16)
+    assert cap == 24 * k < 64 * k
+    assert held == {50.0: 64 * k, -50.0: 0}.get(lift, held)
+    if to == (4, 6):
+        assert held > cap and any(lo < cap < hi for lo, hi in groups)
+    share = share_form(form.removesuffix("_jit"))
+
+    def call(*a):
+        return share(*a, k, 4)
+
+    got = (jax.jit(call) if form == "tree_jit" else call)(
+        x, rw, gu[4:8], dn[4:8])
+    want = uncapped_share(x, rw, gu[4:8], dn[4:8], k, 4)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=1e-5)
+    if lift < 0:
+        assert not np.asarray(got).any()
+    else:
+        assert float(jnp.abs(want).max()) > 1e-3
+
+
+@pytest.mark.parametrize("form", ["ungated_latent", "sigmoid_bias"])
+def test_a_share_takes_every_router_and_expert_form_as_it_did(rng, form):
+    """Ungated experts on a latent row the router does not read, and a
+    sigmoid router whose correction bias sends every pick to the held
+    experts: the share is still its definition."""
+    from vllm_omni_tpu.ops.activation import relu2
+
+    x, rw, gu, dn = _steered(rng, 4, 0.0)
+    if form == "ungated_latent":
+        h = jax.random.normal(jax.random.PRNGKey(9), (64, 24))
+        rw = jax.random.normal(jax.random.PRNGKey(10), (24, 16)) * 0.5
+        kw = dict(act=relu2, route_on=h)
+        gu = gu[:, :, :8]  # the experts' ``up`` alone
+    else:
+        bias = jnp.zeros((16,)).at[4:8].set(2.0)
+        kw = dict(scoring="sigmoid", bias=bias, scale=1.8)
+        idx, _ = moe_ops.router_topk(x, rw, 4, **kw)
+        assert bool(jnp.all((idx >= 4) & (idx < 8)))
+    got = moe_ops.routed_moe_share(x, rw, gu[4:8], dn[4:8], 4, 4, **kw)
+    want = uncapped_share(x, rw, gu[4:8], dn[4:8], 4, 4, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=1e-5)
+    assert float(jnp.abs(want).max()) > 1e-3
+
+
+def test_a_share_gathers_no_scalar_by_the_sorts_order(rng):
+    """The two passes over all T * k pairs that PR 53 took out: the mask
+    and the weights were gathered by ``argsort``'s order, at 10 ns a
+    scalar on the chip.  The gathers left are
+    of rows: the tokens' (``x[token_of]``), and ``router_topk``'s own."""
+    x, rw, gu, dn = _mk_weights(rng, t=32, e=8)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: moe_ops.routed_moe_share(*a, 2, 4))(x, rw, gu[4:6],
+                                                       dn[4:6])
+    flat = [e for e in _primitives(jaxpr.jaxpr)
+            if e.primitive.name == "gather" and e.outvars[0].aval.ndim == 1]
+    assert not flat, flat
+    was = jax.make_jaxpr(
+        lambda *a: uncapped_share(*a, 2, 4))(x, rw, gu[4:6], dn[4:6])
+    assert len([e for e in _primitives(was.jaxpr)
+                if e.primitive.name == "gather"
+                and e.outvars[0].aval.ndim == 1]) == 2
+
+
+@pytest.mark.parametrize("pairs,e_held,width,cap", [
+    (20480, 128, 512, 7680), (16384, 32, 256, 3072), (45056, 128, 512, 16896),
+    (20480, 18, 72, 7680), (80, 128, 512, 32), (64, 32, 256, 16),
+    (8, 2, 8, 8), (256, 4, 16, 96), (64, 8, 8, 64)],
+    ids=["qwen3next_2048", "dots3_2048", "nemotron_2048", "granite_2048",
+         "ep4_8_tokens", "ep8_8_tokens", "never_over_the_pairs", "tiny",
+         "every_expert_held"])
+def test_a_cuts_cap_is_the_expected_pairs_and_a_half_in_whole_tiles(
+        pairs, e_held, width, cap):
+    assert share_cap(pairs, e_held, width) == cap
+
+
+@pytest.mark.parametrize("cell,caps", [
+    ("qwen3_next", (2, 4, 8, 16, 32, 48)),
+    ("dots3note", (2, 4, 8, 16, 32, 48)),
+    ("granite4h", (3, 6, 12, 48, 80, 144)),
+    ("nemotron3s", (3, 6, 12, 48, 80, 144)),
+    ("commandaplus", (2, 4, 8, 32, 48, 96))])
+def test_the_plan_of_a_tiny_share(cell, caps):
+    """``scripts.moe_share_rows_sweep --plan`` on the tiny cell of each of
+    the five share configurations (steps of 1, 2, 4, 16, 32 and 64 rows):
+    a cut's ``cap`` is the pairs where they are few, then 1.5 times the
+    held experts' part of them in whole sublane tiles, and off the
+    kernel's tiling both products keep ``ragged_dot`` with and without."""
+    import importlib
+
+    tiny = importlib.import_module(f"tests.benchmark.test_{cell}_cell").TINY
+    lines = plan(tiny)
+    assert [ln["rows"] for ln in lines] == [1, 2, 4, 16, 32, 64]
+    assert tuple(ln["cap"] for ln in lines) == caps
+    assert all(ln["cap"] <= ln["pairs"] for ln in lines)
+    assert lines[-1]["cap"] < lines[-1]["pairs"]
+    assert not any(ln["changed"] for ln in lines)
 
 
 def test_the_sum_over_a_tokens_picks_is_float32():

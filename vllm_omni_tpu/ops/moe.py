@@ -172,18 +172,25 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     pairs of absent experts sort behind the last held expert's group,
     where the grouped matmul's rows are computed for nothing or left
     undefined (the group sizes stop short of them), and their VALUES are
-    masked out of the sum.  The sum is still the scatter-add
-    ``routed_moe`` had until PR 43, in ``y``'s dtype: with ``_combine``
-    in its place, in five forms of it, the 2 048-row step of
+    masked out of the sum by their place in the sorted order (the rows
+    from ``group_sizes.sum()`` on).  One sort carries each pair's token
+    and weight to its sorted row: gathered by the sort's order, T*k
+    scalars cost the chip 10 ns each (PERF.md, PR 53).  The sum
+    is still the scatter-add ``routed_moe`` had until PR 43, over all
+    T*k rows and in ``y``'s dtype: with ``_combine`` in its place, in
+    five forms of it, and with the held rows alone under the ``while``
+    or the ``lax.cond`` that keeps such a sum exact when a step holds more
+    of them than expected, in four forms of that, the 2 048-row step of
     dots3-note-prev never returned on the v5e (libtpu 0.0.34; PERF.md,
-    PR 43), and no share's cell has been seen to run it.  This is one
-    chip's share of an expert-parallel deployment (the model-configs
-    cut) and, followed by a psum, the body of every ``ep`` shard.  ``router`` is ``router_topk``'s scoring, bias and scale: the
-    share of a sigmoid router with its correction bias routes as the
-    whole model does.  ``act`` and ``route_on`` as ``routed_moe`` takes
-    them: the share of latent experts is a latent row, and a projection
-    out of the latent that is linear and has no bias may be applied to
-    each share's own part (the shares still add up)."""
+    PRs 43 and 53; ROADMAP D20).  This is one chip's share of an
+    expert-parallel deployment (the model-configs cut) and, followed by
+    a psum, the body of every ``ep`` shard.  ``router`` is
+    ``router_topk``'s scoring, bias and scale: the share of a sigmoid
+    router with its correction bias routes as the whole model does.
+    ``act`` and ``route_on`` as ``routed_moe`` takes them: the share of
+    latent experts is a latent row, and a projection out of the latent
+    that is linear and has no bias may be applied to each share's own
+    part (the shares still add up)."""
     e_held = gate_up.shape[0]
     topk_idx, topk_w = router_topk(
         x if route_on is None else route_on, router_w, k, renormalize,
@@ -192,14 +199,14 @@ def routed_moe_share(x, router_w, gate_up, down, k: int, expert_offset,
     mine = (flat_e >= 0) & (flat_e < e_held)
     local_e = jnp.where(mine, flat_e, e_held)
 
-    order = jnp.argsort(local_e)
-    token_of = order // k
-    xs = x[token_of]
+    pairs = jnp.arange(local_e.shape[0])
+    _, token_of, w = jax.lax.sort(
+        (local_e, pairs // k, topk_w.reshape(-1)), num_keys=1)
     group_sizes = jnp.bincount(local_e, length=e_held + 1)[:e_held]
-    y = _grouped_mlp(xs, gate_up, down, group_sizes, act,
+    y = _grouped_mlp(x[token_of], gate_up, down, group_sizes, act,
                      router_w.shape[-1])
-    w = topk_w.reshape(-1)[order]
-    y = jnp.where(mine[order][:, None], y * w[:, None].astype(y.dtype), 0)
+    held = (pairs < group_sizes.sum())[:, None]
+    y = jnp.where(held, y * w[:, None].astype(y.dtype), 0)
     out = jnp.zeros((x.shape[0], x.shape[1]), y.dtype).at[token_of].add(y)
     return out.astype(x.dtype)
 
