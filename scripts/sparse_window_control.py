@@ -3,7 +3,8 @@ sliding-window layers (and learned sparse attention, or rotary on one kind
 of layer) when one of the things it is there to see is taken out of the
 SERVED path: the controls that say what the check's limit can see (ISSUE
 34; ISSUE 50: ``--workload commandaplus_agent_sat --off window | nope |
-precision``).
+precision``; ISSUE 55: ``--workload mimov25_agent_sat --off sink | window |
+vscale | precision``).
 
     chiprun -- python3 -m scripts.sparse_window_control \
         --workload dots3note_agent_sat --seed 3400000101 --off selection
@@ -17,7 +18,10 @@ is replaced by "everything it saw"); ``window`` makes the sliding layers
 attend their whole context (the kernels are not told the window, and no
 window page is given back); ``nope`` rotates the queries and keys of the
 layers that a model with ``rope_kinds`` leaves without positions (the
-configuration's ``rope_kinds`` is not read); ``precision`` gives paged
+configuration's ``rope_kinds`` is not read); ``sink`` leaves the learned
+sink out of the sliding layers' softmax (the kernels are not handed the
+layer's ``sinks``); ``vscale`` leaves the values unscaled
+(``attention_value_scale`` read as 1); ``precision`` gives paged
 attention its
 operands (the queries, every cached row, and the index keys) with float8's
 three bits of mantissa where the configuration states bfloat16's seven
@@ -95,8 +99,8 @@ def main() -> None:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, nargs="+", required=True)
     ap.add_argument("--off", default="none",
-                    choices=("none", "selection", "window", "nope",
-                             "precision"))
+                    choices=("none", "selection", "window", "nope", "sink",
+                             "vscale", "precision"))
     ap.add_argument("--picks", action="store_true")
     ap.add_argument("--allow-cpu", action="store_true")
     args = ap.parse_args()
@@ -124,6 +128,15 @@ def main() -> None:
             setattr(tfm, name, lambda *a, _op=op, **kw: _op(
                 *a, **{k: v for k, v in kw.items() if k != "window"}))
         KVCacheManager._slid_out = lambda self, request: 0
+    elif args.off == "sink":
+        for name in ("paged_attention", "ragged_paged_attention"):
+            op = getattr(tfm, name)
+            setattr(tfm, name, lambda *a, _op=op, **kw: _op(
+                *a, **{k: v for k, v in kw.items() if k != "sinks"}))
+    elif args.off == "vscale":
+        qkv = tfm._qkv
+        tfm._qkv = lambda layer, cfg, x: qkv(
+            layer, dataclasses.replace(cfg, attention_value_scale=1.0), x)
     elif args.off == "nope":
         kind_ropes = tfm._kind_ropes
         tfm._kind_ropes = lambda cfg, *a: kind_ropes(

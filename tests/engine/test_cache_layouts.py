@@ -24,6 +24,7 @@ MODELS = {
     "dots3_note": ("dots3_note", ("window pages", "latent row")),
     "lfm2_moe": ("lfm2_moe", ("recurrent state",)),
     "granite_moe_hybrid": ("granite_moe_hybrid", ("recurrent state",)),
+    "mimo_v2": ("mimo_v2", ("window pages", "pools of two shapes")),
 }
 #: mechanism -> an engine configuration that asks for it
 ASKS = {

@@ -180,6 +180,9 @@ KERNEL_STEPS = {
     # 128 pairs over a router of 128 up (16 decode rows; the cell's 24 rows
     # are 1.5 rows an expert, all on the 16 held); 4 096 pairs past 48 MB
     "commandaplus-l4-ep8": (16, 256, 256),
+    # 256 pairs over a router of 256 up (32 rows; the cell's 24 decode rows
+    # are 0.75 rows an expert and keep ragged_dot); 4 096 pairs past 48 MB
+    "mimov25-l7-ep16": (32, 256, 256),
 }
 
 

@@ -1131,3 +1131,209 @@ def test_commandaplus_steps_compile_for_v5e(entry, one_chip, monkeypatch):
     memory = compiled.memory_analysis()
     assert 14.2e9 < memory.argument_size_in_bytes < 14.3e9
     assert memory.temp_size_in_bytes < 0.8e9
+
+
+# ------------------ MiMo-V2.5 (ISSUE 55): a sink in the softmax, keys of
+# 192 in rows of 256 lanes beside values of 128, kv heads a kind of layer
+@pytest.mark.parametrize("entry", ["mixed", "decode"])
+def test_mimo_steps_compile_for_v5e(entry, one_chip, monkeypatch):
+    """The benchmark's MiMo-V2.5 cut at its published widths (a full layer
+    with the dense MLP, five sliding layers and a full one; 16 of 256
+    experts held; the whole vocabulary) compiles for a v5e with the cell's
+    pools donated: a 2 048-token mixed step and the 24-row decode bucket,
+    each one ``_ragged_attention`` call a layer (five of them told the
+    window and handed the sinks), K pools ``[Hkv, P, 16, 256]`` beside V
+    pools ``[Hkv, P, 16, 128]`` with 8 kv heads under the window table and
+    4 under the full one, the page write a pool at a time (K and V rows
+    differ in width), no kernel that fell back and nothing asked of Mosaic
+    for VMEM; the arguments the issue counts (9.05 GB of weights, 1.61 +
+    1.62 GB of pages) beside well under a gigabyte of temporaries.  The
+    weights are made in one jitted call beside their 9.05 GB."""
+    import functools
+    import re
+
+    from benchmark import spec
+    from benchmark.archs import mimo_v2 as arch
+    from vllm_omni_tpu.models.common import transformer
+    from vllm_omni_tpu.ops import _dispatch, autotune, grouped_matmul
+    from vllm_omni_tpu.ops import rope as rope_ops
+
+    pa = importlib.import_module("vllm_omni_tpu.ops.paged_attention")
+    for module in (rpa, pa, grouped_matmul, rope_ops):
+        monkeypatch.setattr(module, "interpret_flag", lambda: False)
+    monkeypatch.setattr(_dispatch, "pallas_mode", lambda: "native")
+    asked = []
+    limit = autotune.ragged_vmem_limit
+    monkeypatch.setattr(
+        rpa, "ragged_vmem_limit",
+        lambda *a, **k: asked.append(limit(*a, **k)) or asked[-1])
+    config = spec.load_json(
+        spec.ROOT / "benchmark/configs/mimov25-l7-ep16.json")
+    cfg = arch.program_config(config)
+    init = functools.partial(arch.init_weights, cfg=cfg, dtype=jnp.bfloat16)
+    if entry == "mixed":
+        made = jax.jit(init).lower(jax.ShapeDtypeStruct(
+            (2,), jnp.uint32, sharding=one_chip)).compile().memory_analysis()
+        assert 9.04e9 < made.output_size_in_bytes < 9.06e9
+        assert made.temp_size_in_bytes < 0.5e9
+
+    def sds(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    assert params["layers"][1]["sinks"].dtype == jnp.float32
+    engine = config["engine"]
+    seqs, page = engine["max_num_seqs"], engine["page_size"]
+    max_pages = engine["max_model_len"] // page
+    window_pages = -(-seqs * (cfg.sliding_window
+                              + engine["max_num_batched_tokens"])
+                     // page) + seqs + 1
+    assert window_pages == 3289
+    pages = {"full_attention": engine["num_pages"],
+             "sliding_attention": window_pages}
+    caches = []
+    for kind in cfg.kv_layer_types:
+        lay = cfg.kv_layout_of(kind)
+        assert (lay.width, lay.used, lay.v_width) == (256, 192, 128)
+        assert lay.heads == {"full_attention": 4, "sliding_attention": 8}[kind]
+        caches.append(tuple(
+            sds((lay.heads, pages[kind], page, width), jnp.bfloat16)
+            for width in (lay.width, lay.v_width)))
+    if entry == "mixed":
+        t = engine["max_num_batched_tokens"]
+        compiled = jax.jit(
+            lambda p, tok, pos, kv, sl, tables, lens, cu, q_lens, n, wsl, wt:
+            transformer.forward_unified(
+                p, cfg, tok, pos, kv, sl, tables, lens, cu, q_lens, n,
+                window_pages=(wsl, wt)), donate_argnums=3).lower(
+            params, sds((t,)), sds((t,)), caches, sds((t,)),
+            sds((seqs, max_pages)), sds((seqs,)), sds((seqs + 1,)),
+            sds((seqs,)), sds((1,)), sds((t,)),
+            sds((seqs, max_pages))).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, tok, pos, kv, sl, tables, lens, wsl, wt:
+            transformer.forward_decode(p, cfg, tok, pos, kv, sl, tables,
+                                       lens, window_pages=(wsl, wt)),
+            donate_argnums=3).lower(
+            params, sds((seqs,)), sds((seqs,)), caches, sds((seqs,)),
+            sds((seqs, max_pages)), sds((seqs,)), sds((seqs,)),
+            sds((seqs, max_pages))).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*custom-call\([^\n]*"
+                       r"tpu_custom_call", text)
+    assert calls.count("_ragged_attention") == 7
+    if entry == "mixed":
+        # K and V rows differ in width: two page writes a layer
+        assert calls.count("_write_pages") == 2 * 7
+    assert not _dispatch.native_fallbacks
+    assert asked and not any(asked)  # nothing asked of Mosaic
+    memory = compiled.memory_analysis()
+    # the untied head (1.25 GB) is no argument of the forward to the
+    # hidden states: 9.05 - 1.25 + 1.61 + 1.62 GB
+    assert 11.0e9 < memory.argument_size_in_bytes < 11.1e9
+    assert memory.temp_size_in_bytes < 0.8e9
+
+
+#: the whole decode step and a 32-token mixed step at the tiny widths of
+#: the three served families that ``STEPS`` does not hold (two kinds of
+#: page over latent rows with an indexer; a window over per-head pages
+#: under a parallel block; a sublayer a layer with latent experts), and
+#: the window kernel's two entries at the two window cells' real geometry,
+#: taken on 4d69fda, the parent of PR 55: kv heads, a rotary base and a
+#: sink a KIND of layer, a value narrower than its key, the value scale and
+#: the kernels' ``sinks`` operand leave all ten programs as they were.
+WINDOWED_STEPS = {
+    "cohere2_moe": ("6af0ed4177ceeb33", "99877045cc27344c"),
+    "dots3_note": ("30fae29c292e2459", "be6ff162bf1349a4"),
+    "nemotron_h": ("e291af98b3f43461", "5bdb3d3827b8db25"),
+}
+WINDOWED_KERNELS = {
+    "commandaplus": (dict(hkv=8, group=16, d=128, v_dim=None, window=4096,
+                          pool=9241),
+                     ("07806c483f817e35", "5a2fae752c48782b")),
+    "dots3_window": (dict(hkv=1, group=128, d=1152, v_dim=1024, window=513,
+                          pool=5155),
+                     ("6f74a933fff9fdc2", "f8d632a9dd7cae4f")),
+}
+
+
+def _digests(*jaxprs):
+    import hashlib
+
+    return tuple(hashlib.sha256(str(x).encode()).hexdigest()[:16]
+                 for x in jaxprs)
+
+
+@pytest.mark.parametrize("name", sorted(WINDOWED_STEPS))
+def test_kinds_of_heads_leave_the_other_models_steps_the_parents(name):
+    import functools
+
+    from vllm_omni_tpu.models.common import transformer as tfm
+
+    cfg = importlib.import_module(
+        f"vllm_omni_tpu.models.{name}").tiny_config()
+    i32, f32 = jnp.int32, jnp.float32
+
+    def sds(dims, dtype=i32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    params = jax.eval_shape(functools.partial(
+        tfm.init_params, cfg=cfg, dtype=f32), jax.random.PRNGKey(0))
+    kv = []
+    for kind in cfg.kv_layer_types:
+        lay = cfg.kv_layout_of(kind)
+        pool = sds((lay.heads, 32, 16, lay.width), f32)
+        entry = (pool, None if lay.pools == 1 else pool)
+        if cfg.index_topk and kind == "full_attention":
+            entry += (sds((1, 32, 16, cfg.index_head_dim), f32),)
+        kv.append(entry)
+    extra, decode_pages, mixed_pages = {}, {}, {}
+    if cfg.num_state_layers:
+        kv = tfm.HybridCaches(kv, [
+            tuple(sds((4, *shape), f32) for shape in cfg.state_shapes(kind))
+            for kind in cfg.layer_types if kind in tfm.STATE_KINDS])
+        extra = {"state_slots": sds((4,))}
+    if cfg.num_window_layers:
+        decode_pages = {"window_pages": (sds((4,)), sds((4, 8)))}
+        mixed_pages = {"window_pages": (sds((32,)), sds((4, 8)))}
+    decode = jax.make_jaxpr(
+        lambda p, kv, tok, pos, slots, tables, ctx, **e: tfm.forward_decode(
+            p, cfg, tok, pos, kv, slots, tables, ctx, **e))(
+        params, kv, sds((4,)), sds((4,)), sds((4,)), sds((4, 8)), sds((4,)),
+        **extra, **decode_pages)
+    mixed = jax.make_jaxpr(
+        lambda p, kv, tok, pos, slots, tables, lens, cu, q_lens, n, **e:
+        tfm.forward_unified(p, cfg, tok, pos, kv, slots, tables, lens, cu,
+                            q_lens, n, **e))(
+        params, kv, sds((32,)), sds((32,)), sds((32,)), sds((4, 8)),
+        sds((4,)), sds((5,)), sds((4,)), sds((1,)), **extra, **mixed_pages)
+    assert _digests(decode, mixed) == WINDOWED_STEPS[name]
+
+
+@pytest.mark.parametrize("name", sorted(WINDOWED_KERNELS))
+def test_without_sinks_the_window_kernel_is_the_parents(name, monkeypatch):
+    shape, want = WINDOWED_KERNELS[name]
+    hkv, group, d, v_dim, window, pool = (
+        shape[k] for k in ("hkv", "group", "d", "v_dim", "window", "pool"))
+    monkeypatch.setattr(rpa, "interpret_flag", lambda: False)
+
+    def sds(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    cache = sds((hkv, pool, 16, d), jnp.bfloat16)
+    value = None if v_dim else cache
+    decode = jax.make_jaxpr(lambda q, k, v, tables, lens: rpa.paged_attention(
+        q, k, v, tables, lens, use_pallas=True, v_dim=v_dim, window=window))(
+        sds((24, hkv * group, d), jnp.bfloat16), cache, value,
+        sds((24, 1104)), sds((24,)))
+    mixed = jax.make_jaxpr(
+        lambda q, k, v, tables, cu, q_lens, seq_lens:
+        rpa.ragged_paged_attention(q, k, v, tables, cu, q_lens, seq_lens, 3,
+                                   use_pallas=True, v_dim=v_dim,
+                                   window=window))(
+        sds((2048, hkv * group, d), jnp.bfloat16), cache, value,
+        sds((24, 1104)), sds((25,)), sds((24,)), sds((24,)))
+    assert _digests(decode, mixed) == want

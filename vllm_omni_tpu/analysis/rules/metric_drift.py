@@ -90,7 +90,10 @@ def synthetic_engine_snapshot() -> dict:
         "pipeline": {"pipelined_steps": 120, "sync_steps": 5, "drains": 3},
         "scheduler": {"waiting": 1, "running": 2, "preemptions": 1,
                       "rejections": 0},
-        "kv": {"pages_total": 64, "pages_used": 8, "utilization": 0.125},
+        "kv": {"pages_total": 64, "pages_used": 8, "utilization": 0.125,
+               "kinds": {"sliding_attention": {
+                   "heads": 8, "row_width": 256, "row_used": 192,
+                   "pages_total": 33}}},
         "prefix_cache": {"enabled": True, "hits": 2, "hit_tokens": 16},
         "kv_tiers": {
             "hbm_pages": 8, "host_pages": 3, "remote_pages": 1,

@@ -350,7 +350,8 @@ class LLMEngine:
             # a cached token's bytes in ONE layer, declared padding (and
             # an int8 page's scales) included, and in all of them
             self._kv_bytes_per_token_layer = bytes_per_token(
-                1, layout.heads, config.page_size, layout.width,
+                1, layout.heads, config.page_size,
+                layout.lanes // layout.pools,
                 quantized=self._kv_quant, itemsize=itemsize,
                 pools=layout.pools)
             self._kv_bytes_per_token = (
@@ -586,9 +587,13 @@ class LLMEngine:
     @staticmethod
     def _kinds_of(model_cfg, config: "EngineConfig", num_pages: int,
                   itemsize: int) -> dict:
-        """{layer kind: layers, row widths, bytes a cached token a layer,
-        pages} of a model whose attention layers are of two kinds or keep
-        an index key beside the row (``kv.kinds`` of the snapshot)."""
+        """{layer kind: layers, the rows a token has in a pool (its kv
+        heads), row widths, bytes a cached token a layer, pages} of a model
+        whose attention layers are of two kinds or keep an index key beside
+        the row (``kv.kinds`` of the snapshot).  ``row_width`` and
+        ``row_used`` are the K pool's row (the one pool's of a latent
+        layer); ``value_width`` the V pool's, and ``lanes_padding_pct``
+        the share of a cached token's bytes that are declared padding."""
         kinds = {}
         page = config.page_size
         for kind in ("full_attention", "sliding_attention"):
@@ -608,11 +613,15 @@ class LLMEngine:
                                    + config.max_num_batched_tokens)
                           // page) + seqs + 1
             kinds[kind] = {
-                "layers": layers, "row_width": layout.width,
-                "row_used": layout.used, "index_width": index,
+                "layers": layers, "heads": layout.heads,
+                "row_width": layout.width, "row_used": layout.used,
+                "value_width": layout.value_width,
+                "lanes_padding_pct": round(
+                    100.0 * (1 - layout.lanes_used / layout.lanes), 2),
+                "index_width": index,
                 "bytes_per_token_layer": float(
-                    itemsize * layout.pools * layout.heads
-                    * (layout.width + index)),
+                    itemsize * layout.heads
+                    * (layout.lanes + layout.pools * index)),
                 "pages_total": pages}
         return kinds
 

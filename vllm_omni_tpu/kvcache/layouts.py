@@ -2,13 +2,14 @@
 
 A model's cache is pages of per-head (K, V) rows unless its configuration
 says otherwise, and five mechanisms read, move, shard or roll back a page's
-contents by that layout.  Three layouts are served beside it
+contents by that layout.  Four layouts are served beside it
 (docs/kv_cache.md): a state slot a sequence beside the pages (recurrent
 layers), pages of a second kind that are given back once they slide out of
-reach (sliding-window layers), and one shared pool of latent rows a layer
-(multi-head latent attention).  None of the five carries any of the three
-yet, each for a reason of its own: this table is the one place that says
-which and why.  ``LLMEngine`` refuses what a configuration asks for before
+reach (sliding-window layers), one shared pool of latent rows a layer
+(multi-head latent attention), and per-head pools of two shapes (a key
+wider than its value, or kv heads that differ by kind of layer).  None of
+the five carries any of the four yet, each for a reason of its own: this
+table is the one place that says which and why.  ``LLMEngine`` refuses what a configuration asks for before
 anything is built (``refusal``); ``ARModelRunner``, which can be built
 without an engine, refuses at its own entry points from the same cells
 (``cannot_carry``).  Prefix hits and preemption are not here: the cache
@@ -36,6 +37,13 @@ class Layout(NamedTuple):
 def _latent(model_cfg) -> bool:
     layout = getattr(model_cfg, "kv_layout", None)
     return layout is not None and layout.kind == "latent"
+
+
+def _two_shapes(model_cfg) -> bool:
+    if _latent(model_cfg) or not hasattr(model_cfg, "value_dim"):
+        return False
+    return (model_cfg.value_dim != model_cfg.head_dim
+            or model_cfg.sliding_heads is not None)
 
 
 LAYOUTS = {
@@ -78,6 +86,23 @@ LAYOUTS = {
                       "built",
          TENSOR_PARALLEL: "the latent cache is one kv head that every query "
                           "head reads, and has no sharded layout yet"}),
+    "pools of two shapes": Layout(
+        _two_shapes,
+        "a model whose keys are wider than its values, or whose kinds of "
+        "layer differ in their kv heads, keeps K and V pools of more than "
+        "one shape",
+        {INT8: "the quantized layout has one row width for K and V, and "
+               "one absmax scale a page would span a key's padding lanes",
+         TRANSFER: "the inject_kv / extract_kv payload of a transfer or a "
+                   "non-colocated engine_role is [Hkv, seq, D] halves of "
+                   "ONE head count and ONE width for every layer",
+         OFFLOAD: "parking / tiered offload extract the same one-shape "
+                  "payload",
+         SPECULATIVE: "the verify rows' path over K and V pools of "
+                      "different widths and head counts is not built",
+         TENSOR_PARALLEL: "two kinds of layer divide their kv heads by "
+                          "different counts, and no sharded layout holds a "
+                          "V pool narrower than its K pool"}),
 }
 
 

@@ -79,6 +79,19 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
     "kv_window_pages_used": (
         "histogram",
         "Pages of the window kind held, observed once a step", ("stage",)),
+    # the pools a KIND of attention layer, where a model has two kinds or
+    # keeps an index key (the snapshot's ``kv.kinds``)
+    "kv_kind_heads": (
+        "gauge", "Rows a cached token has in a pool of this kind of layer "
+        "(its kv heads)", ("stage", "kind")),
+    "kv_kind_row_width": (
+        "gauge", "Lanes a K row (a latent layer's one row) takes in this "
+        "kind's pool, padding included", ("stage", "kind")),
+    "kv_kind_row_used": (
+        "gauge", "Of those lanes, the ones that hold numbers",
+        ("stage", "kind")),
+    "kv_kind_pages_total": (
+        "gauge", "Pages of this kind's pool", ("stage", "kind")),
     "sparse_keep_pct": (
         "histogram",
         "Share of the visible keys a step's rows attend on sparse layers",
@@ -641,6 +654,11 @@ def render_exposition(summary: dict, engine_snaps: dict,
             exp.sample("kv_pages_used", labels, kv.get("pages_used", 0))
             exp.sample("kv_page_utilization", labels,
                        kv.get("utilization", 0.0))
+            for kind, rec in sorted((kv.get("kinds") or {}).items()):
+                for field in ("heads", "row_width", "row_used",
+                              "pages_total"):
+                    exp.sample(f"kv_kind_{field}", {**labels, "kind": kind},
+                               rec.get(field, 0))
         if "kv_pages_used" in snap:
             exp.histogram("kv_pages_used_per_step", labels,
                           snap["kv_pages_used"])

@@ -123,7 +123,7 @@ class ModelGeometry:
             # what a cached position holds, by the layout (a K and a V
             # row a kv head, or a latent layer's one row)
             kv_bytes_per_pos=float(
-                kv_layers * layout.pools * layout.heads * layout.used
+                kv_layers * layout.heads * layout.lanes_used
                 * dtype_bytes),
             expert_bytes=float(expert_params * dtype_bytes),
             num_experts=experts, experts_per_tok=top_k,

@@ -34,7 +34,7 @@ from vllm_omni_tpu.ops import (
 )
 from vllm_omni_tpu.ops import indexer as indexer_ops
 from vllm_omni_tpu.ops.activation import UNGATED
-from vllm_omni_tpu.ops.paged_attention import heads_a_row
+from vllm_omni_tpu.ops.paged_attention import heads_a_row, key_row_width
 
 #: the kinds of layer whose sequences hold a state slot: what is
 #: particular to each is its record of ``state_layers.KINDS``
@@ -70,6 +70,21 @@ class LatentKind:
     @property
     def scale(self) -> float:
         return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+
+@dataclass(frozen=True)
+class HeadKind:
+    """One kind of per-head attention layer, where a model's kinds differ
+    in more than their window: its kv heads, its rotary base, and whether
+    its softmax holds a learned sink a query head (a logit in the
+    denominator with no value: ``ops/paged_attention.py sink_softmax``).
+    A model with such layers has two of these; the full kind's is read
+    from ``TransformerConfig``'s own fields (``head_kind``).  The heads'
+    widths are the model's: ``head_dim`` a key, ``v_head_dim`` a value."""
+
+    num_kv_heads: int
+    rope_theta: float
+    sink: bool = False
 
 
 @dataclass(frozen=True)
@@ -222,6 +237,16 @@ class TransformerConfig:
     # attention, and its pages hold (K, V) rows as the full layers' do
     sliding: Optional[LatentKind] = None
     sliding_window: int = 0
+    # ... and where the sliding layers of a model with PER-HEAD attention
+    # differ from its full layers in their kv heads, their rotary base or
+    # a sink, the sliding kind's record (None: they differ in the window
+    # alone).  A per-head value narrower than the key is ``v_head_dim``
+    # (0: the key's ``head_dim``; the latent fields above give it its
+    # meaning for a latent model), and ``attention_value_scale``
+    # multiplies every value before it is cached and attended (at 1.0 no
+    # operation is emitted)
+    sliding_heads: Optional[HeadKind] = None
+    attention_value_scale: float = 1.0
     # learned sparse attention on the full layers (index_topk > 0): an
     # indexer of ``index_n_heads`` heads of ``index_head_dim`` scores every
     # cached token (one index key a token, in a pool beside the latent
@@ -337,6 +362,18 @@ class TransformerConfig:
                           self.qk_rope_head_dim, self.v_head_dim,
                           self.rope_theta)
 
+    def head_kind(self, kind: str = "full_attention") -> HeadKind:
+        """The kv heads, rotary base and sink of a kind of per-head
+        attention layer."""
+        if kind == "sliding_attention" and self.sliding_heads is not None:
+            return self.sliding_heads
+        return HeadKind(self.num_kv_heads, self.rope_theta)
+
+    @property
+    def value_dim(self) -> int:
+        """A per-head value's width."""
+        return self.v_head_dim or self.head_dim
+
     @property
     def rotary_dim(self) -> int:
         if self.kv_lora_rank:
@@ -352,8 +389,15 @@ class TransformerConfig:
         # heads narrower than a 128-lane row lie side by side in one
         # (ops/paged_attention.py heads_a_row): 8 kv heads of 64 are 4
         # rows of 128, no lane of which is padding
-        pack = heads_a_row(self.head_dim, self.num_kv_heads)
-        return KVLayout("kv", self.num_kv_heads // pack,
+        kv_heads = self.head_kind(kind).num_kv_heads
+        if self.value_dim != self.head_dim:
+            # a key wider than its value: a K pool and a V pool of
+            # different row widths, a key of one and a half lane tiles in
+            # two (``key_row_width``: 192 in 256, 64 lanes of zeros)
+            return KVLayout("kv", kv_heads, key_row_width(self.head_dim),
+                            2, self.head_dim, self.value_dim)
+        pack = heads_a_row(self.head_dim, kv_heads)
+        return KVLayout("kv", kv_heads // pack,
                         self.head_dim * pack, 2, self.head_dim * pack)
 
     @property
@@ -446,6 +490,27 @@ class KVLayout(NamedTuple):
     width: int   # lanes a row takes, declared padding included
     pools: int   # 2 (K, V) | 1 (the row is key and value at once)
     used: int    # lanes of a row that hold numbers (width - padding)
+    # lanes of a V pool's row where it is not as wide as the K pool's
+    # (0: ``width``, as every model's but one whose key is wider than its
+    # value); a V row holds no padding
+    v_width: int = 0
+
+    @property
+    def value_width(self) -> int:
+        """Lanes a V pool's row takes (a shared pool has none)."""
+        return (self.v_width or self.width) if self.pools == 2 else 0
+
+    @property
+    def lanes(self) -> int:
+        """Lanes a cached token takes a head in this layer's pools
+        together, padding included."""
+        return self.width + self.value_width
+
+    @property
+    def lanes_used(self) -> int:
+        """Of those, the lanes that hold numbers."""
+        return self.used + (self.value_width
+                            and (self.v_width or self.used))
 
 
 def init_params(key, cfg: TransformerConfig, dtype=jnp.float32):
@@ -509,17 +574,27 @@ def _mixer_init(k, kn, cfg: TransformerConfig, kind: str, norm_init, dtype):
             mixer["indexer"] = _indexer_init(
                 jax.random.fold_in(k[3], 2), cfg, lk, dtype)
         return mixer
+    hk = cfg.head_kind(kind)
     q_dim = cfg.num_heads * cfg.head_dim
-    kv_dim = cfg.num_kv_heads * cfg.head_dim
+    kv_dim = hk.num_kv_heads * cfg.head_dim
+    v_dim = hk.num_kv_heads * cfg.value_dim
+    o_dim = cfg.num_heads * cfg.value_dim
     qkv_bias = cfg.attention_bias
     # gated attention doubles q_proj: a head's columns are [q | gate]
     q_out = q_dim * (2 if cfg.attn_output_gate else 1)
     mixer = {
         "q_proj": nn.linear_init(k[0], cfg.hidden_size, q_out, bias=qkv_bias, dtype=dtype),
         "k_proj": nn.linear_init(k[1], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
-        "v_proj": nn.linear_init(k[2], cfg.hidden_size, kv_dim, bias=qkv_bias, dtype=dtype),
-        "o_proj": nn.linear_init(k[3], q_dim, cfg.hidden_size, bias=False, dtype=dtype),
+        "v_proj": nn.linear_init(k[2], cfg.hidden_size, v_dim, bias=qkv_bias, dtype=dtype),
+        "o_proj": nn.linear_init(k[3], o_dim, cfg.hidden_size, bias=False, dtype=dtype),
     }
+    if hk.sink:
+        # one learned logit a query head, float32 as the softmax that
+        # reads it.  A trained sink holds a fair part of a row's mass;
+        # the draw is wide enough that its presence shows
+        mixer["sinks"] = jax.random.uniform(
+            jax.random.fold_in(k[3], 3), (cfg.num_heads,), jnp.float32,
+            minval=-1.0, maxval=1.0)
     if cfg.qk_norm and kind == "full_attention":
         mixer["q_norm"] = norm_init(kn[2], cfg.head_dim)
         mixer["k_norm"] = norm_init(kn[3], cfg.head_dim)
@@ -659,7 +734,10 @@ def _qkv(layer, cfg: TransformerConfig, x):
     else:
         q = nn.linear(layer["q_proj"], x).reshape(t, -1, cfg.head_dim)
     k = nn.linear(layer["k_proj"], x).reshape(t, -1, cfg.head_dim)
-    v = nn.linear(layer["v_proj"], x).reshape(t, -1, cfg.head_dim)
+    v = nn.linear(layer["v_proj"], x).reshape(t, -1, cfg.value_dim)
+    if cfg.attention_value_scale != 1.0:
+        v = (v.astype(jnp.float32) * cfg.attention_value_scale).astype(
+            v.dtype)
     if cfg.qk_norm:
         q = _rms(cfg, q, layer["q_norm"])
         k = _rms(cfg, k, layer["k_norm"])
@@ -860,12 +938,14 @@ def _rope_tables(cfg: TransformerConfig, positions,
     1-D rope: positions [B, S] (prefill) or [B] (decode).  MRoPE
     (cfg.mrope_sections set): [B, 3, S] or [B, 3] — the three streams are
     flattened batch-major to match the activations' [B*S] layout.
-    ``kind``: the tables of a kind of latent layer that has a rotary
-    width and base of its own.
+    ``kind``: the tables of a kind of layer that has a rotary base of its
+    own (a latent kind: and a rotary width; a per-head kind: the model's).
     """
     if kind is not None:
         return compute_rope_freqs(
-            positions.reshape(-1), kind.qk_rope_head_dim, kind.rope_theta)
+            positions.reshape(-1),
+            kind.qk_rope_head_dim if isinstance(kind, LatentKind)
+            else cfg.rotary_dim, kind.rope_theta)
     if cfg.mrope_sections is None:
         return compute_rope_freqs(
             positions.reshape(-1), cfg.rotary_dim, cfg.rope_theta
@@ -970,9 +1050,9 @@ def _kind_ropes(cfg: TransformerConfig, positions, mrope_positions=None):
     first = _rope_tables(cfg, positions if mrope_positions is None
                          else mrope_positions)
     ropes = {kind: first for kind in set(cfg.layer_types)}
-    if cfg.sliding is not None:
-        ropes["sliding_attention"] = _rope_tables(cfg, positions,
-                                                  cfg.sliding)
+    own = cfg.sliding or cfg.sliding_heads
+    if own is not None:
+        ropes["sliding_attention"] = _rope_tables(cfg, positions, own)
     if cfg.rope_kinds is not None:
         ropes.update({kind: (None, None) for kind in ropes
                       if kind not in cfg.rope_kinds})
@@ -1156,7 +1236,9 @@ def _mixer(layer, cfg: TransformerConfig, h, cos, sin, attend, kind: str):
             if cos is not None:  # a kind, or a model, without positions
                 q = _rope(cfg, q, cos, sin)
                 k = _rope(cfg, k, cos, sin)
-        o = attend(q, k, v).reshape(*b, -1)
+        # a layer whose softmax has a learned sink says so by its weights
+        o = attend(q, k, v, **({"sinks": layer["sinks"]}
+                               if "sinks" in layer else {})).reshape(*b, -1)
         if gate is not None:
             # the output gate is part of gated attention: billed to it
             with jax.named_scope("omni.attn"):
@@ -1295,6 +1377,10 @@ def forward_hidden(
                     causal=True, kv_mask=attn_mask,
                     scale=cfg.attn_kwargs["scale"])
                 return o[..., :cfg.kv_lora_rank]
+            if cfg.value_dim != cfg.head_dim:
+                raise NotImplementedError(
+                    "the cache-less forward takes a value as wide as its "
+                    "key")
             return flash_attention(
                 q.reshape(b, s, -1, cfg.head_dim),
                 k.reshape(b, s, -1, cfg.head_dim),
@@ -1373,11 +1459,12 @@ def _paged_attend(cfg: TransformerConfig, kind: str, cache, new_caches,
     at the same slots, and where the tables reach past ``index_topk`` keys
     ``scores(q, w, index_pool, tables)`` and the selection by rank give the
     attention its ``keep`` (a context that short keeps every key, by the
-    shapes of the call)."""
+    shapes of the call).  A layer with a learned sink hands its ``sinks``
+    through to the attention."""
     sliding = kind == "sliding_attention"
     kwargs = cfg.attn_kwargs_of(kind)
 
-    def attend(q, k, v, index=None):
+    def attend(q, k, v, index=None, **sinks):
         k_cache, v_cache = cache[:2]
         with jax.named_scope("omni.kv_write"):
             k_cache, v_cache = write_kv_cache(k_cache, v_cache, k, v, slots)
@@ -1396,7 +1483,8 @@ def _paged_attend(cfg: TransformerConfig, kind: str, cache, new_caches,
                         cfg.index_topk)
         with jax.named_scope(
                 "omni.attn_window" if sliding else "omni.attn"):
-            return attention(q, k_cache, v_cache, tables, **kwargs, **keep)
+            return attention(q, k_cache, v_cache, tables, **kwargs, **keep,
+                             **sinks)
 
     return attend
 

@@ -203,6 +203,12 @@ _AR_MODELS: dict[str, _Entry] = {
     "Cohere2MoeForCausalLM": _Entry(
         "vllm_omni_tpu.models.cohere2_moe", "real_factory"
     ),
+    # five 128-key window layers with a learned sink in their softmax to
+    # one full layer, keys of 192 beside values of 128, kv heads and a
+    # rotary base a kind of layer (MiMo-V2.5; docs/kv_cache.md)
+    "MiMoV2ForCausalLM": _Entry(
+        "vllm_omni_tpu.models.mimo_v2", "real_factory"
+    ),
 }
 
 

@@ -17,6 +17,7 @@ below every candidate shrinks the choice instead of crashing.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 #: f32 score-block element budget for the dense kernel (~8 MB)
 SCORE_CAP = 2_097_152
@@ -188,6 +189,8 @@ def auto_ragged_blocks(
     quantized: bool = False,
     kv_pools: int = 2,
     kv_heads: int = 1,
+    window: Optional[int] = None,
+    sinks: bool = False,
 ) -> tuple[int, int, int]:
     """(q_block, pages_per_block, dma_slots) for the ragged
     paged-attention kernel.
@@ -228,7 +231,22 @@ def auto_ragged_blocks(
 
     A budget below the first choice halves the KV block, then the q
     tile; the guaranteed-fit fallback is the smallest working set the
-    kernel supports, (8, 1, 2), rather than a failure."""
+    kernel supports, (8, 1, 2), rather than a failure.
+
+    ``sinks`` (a call whose softmax has a learned sink a head; ``window``
+    is then its window, where it has one) is the one kind of call that
+    tells the picker more than its shapes, and only such a call's tile
+    follows it: every other keeps the tile it had.  A window under
+    ``RAGGED_KV_BLOCK`` keys caps the KV block at the window (a power of
+    two of pages): a tile of ``q_block`` tokens reaches ``q_block +
+    window - 1`` keys, and at 128 keys a block of 512 would be mostly
+    keys that no row admits.  And the q tile is halved until the WHOLE,
+    every kv head's buffers and the ``[rows, 1]`` float32 columns
+    (running maximum, sum and the sinks' block: 512 B a row each in
+    VMEM, a column of 128-lane registers), fits ``MOSAIC_VMEM``, so that
+    the kernel asks Mosaic for nothing (``ragged_vmem_limit``: asking
+    stopped a step program once): 8 kv heads of 8 query heads over rows
+    of 256 lanes count 18.5 MB at 64 tokens and 10 MB at 32."""
     group = max(group, 1)
     bq = 8
     while bq * 2 * group <= RAGGED_ROWS and bq < RAGGED_MAX_Q_BLOCK:
@@ -237,6 +255,11 @@ def auto_ragged_blocks(
         bq //= 2  # the scale blocks of every kv head lie in SMEM
     ppb = max(1, min(RAGGED_MAX_PAGES_PER_BLOCK,
                      RAGGED_KV_BLOCK // page_size))
+    if sinks and window is not None:
+        reach = 1
+        while reach * page_size < window:
+            reach *= 2
+        ppb = min(ppb, reach)
     slots = 2
     if vmem_cap_bytes == RAGGED_VMEM_CAP:
         # a geometry whose smallest tile overruns the cap budgets with
@@ -260,4 +283,15 @@ def auto_ragged_blocks(
             bq //= 2
         else:
             break
+
+    def whole(bq):
+        # the q and output blocks of every kv head, the accumulators, the
+        # buffers, and four columns a row (maximum, sum, the sinks' block
+        # twice: the grid pipeline double-buffers an input)
+        return _ragged_need(bq, ppb, slots, head_dim, page_size, group,
+                            kv_itemsize, q_itemsize, quantized, kv_pools,
+                            kv_heads) + 4 * kv_heads * bq * group * 512
+
+    while sinks and bq > 8 and whole(bq) > MOSAIC_VMEM:
+        bq //= 2
     return bq, ppb, slots

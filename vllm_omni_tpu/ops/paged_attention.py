@@ -84,16 +84,20 @@ def init_kv_cache(
     dtype=jnp.bfloat16,
     quantized: bool = False,
     shared: bool = False,
+    v_head_dim: Optional[int] = None,
 ):
     """Allocate per-layer (k, v) caches; ``shared`` allocates the one-pool
     layout ``(pool, None)`` whose rows are key and value at once
-    (``head_dim`` is then the row's whole width).
+    (``head_dim`` is then the row's whole width).  ``v_head_dim``: the V
+    pool's rows where they are not as wide as the K pool's (a key of 192
+    in a row of 256 lanes beside a value of 128).
 
     ``quantized`` allocates the int8 layout: each half is
     ``(data int8, scale f32 [Hkv, P])``; zero scales mean "never
     written" and dequantize to the same zeros the bf16 pool starts
     with."""
     shape = (num_kv_heads, num_pages, page_size, head_dim)
+    v_shape = shape[:3] + (v_head_dim or head_dim,)
     if shared:
         if quantized:
             raise ValueError("the shared (latent) layout has no int8 form")
@@ -102,12 +106,12 @@ def init_kv_cache(
         return [
             ((jnp.zeros(shape, jnp.int8),
               jnp.zeros((num_kv_heads, num_pages), jnp.float32)),
-             (jnp.zeros(shape, jnp.int8),
+             (jnp.zeros(v_shape, jnp.int8),
               jnp.zeros((num_kv_heads, num_pages), jnp.float32)))
             for _ in range(num_layers)
         ]
     return [
-        (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        (jnp.zeros(shape, dtype), jnp.zeros(v_shape, dtype))
         for _ in range(num_layers)
     ]
 
@@ -492,10 +496,20 @@ def write_kv_cache(
     if not shared:
         v_new = paired_rows(v_new, v_cache)
     tokens, hkv, _ = k_new.shape
-    reason = write_tiling_reason(k_cache, tokens, 1 if shared else 2)
+    # a V pool narrower than the K pool (a key of 192 in 256 lanes beside
+    # a value of 128) is a pool of its own to the page write, which lays
+    # a layer's pools side by side only where their rows are one width
+    apart = not shared and k_cache.shape[-1] != v_cache.shape[-1]
+    pools = 1 if shared or apart else 2
+    reason = write_tiling_reason(k_cache, tokens, pools) or (
+        apart and write_tiling_reason(v_cache, tokens, pools)) or ""
     if tokens * hkv > _SCATTER_MAX_ROWS and auto_use_pallas(
             "write_kv_cache", reason):
-        out = _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping)
+        if apart:
+            out = (_write_pages(k_cache, None, k_new, None, slot_mapping)
+                   + _write_pages(v_cache, None, v_new, None, slot_mapping))
+        else:
+            out = _write_pages(k_cache, v_cache, k_new, v_new, slot_mapping)
     elif shared:
         out = (_scatter_rows(k_cache, k_new, slot_mapping),)
     else:
@@ -514,9 +528,12 @@ def paged_attention_ref(
     v_dim: Optional[int] = None,
     window: Optional[int] = None,
     keep: Optional[jax.Array] = None,  # [B, >= max_pages * page] bool
+    sinks: Optional[jax.Array] = None,  # [H] float32
 ):
     """``window``: the row's token (at ``context_lens - 1``) admits the
-    last ``window`` keys only; ``keep``: and only those it marks."""
+    last ``window`` keys only; ``keep``: and only those it marks;
+    ``sinks``: one logit a query head in the softmax's denominator, with
+    no value (``sink_softmax``)."""
     b, h, d = q.shape
     hkv, _, page, _ = cache_shape(k_cache)
     group = h // hkv
@@ -531,7 +548,7 @@ def paged_attention_ref(
         vg = kg[..., :v_dim]
     else:
         vg = jnp.moveaxis(gather_pages(v_cache, block_tables), 0, 1).reshape(
-            b, hkv, max_pages * page, d
+            b, hkv, max_pages * page, -1
         )
     qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
     s = jnp.einsum("bngd,bnld->bngl", qg, kg.astype(jnp.float32)) * scale
@@ -542,9 +559,25 @@ def paged_attention_ref(
     if keep is not None:
         mask &= keep[:, None, None, :max_pages * page]
     s = jnp.where(mask, s, _NEG_INF)
-    p_ = jax.nn.softmax(s, axis=-1)
+    if sinks is None:
+        p_ = jax.nn.softmax(s, axis=-1)
+    else:
+        p_ = sink_softmax(s, mask, sinks.reshape(1, hkv, group, 1))
     o = jnp.einsum("bngl,bnld->bngd", p_, vg.astype(jnp.float32))
     return o.reshape(b, h, vg.shape[-1]).astype(q.dtype)
+
+
+def sink_softmax(s: jax.Array, mask: jax.Array, sink: jax.Array):
+    """Softmax of the scores ``s`` over the last axis with one more logit
+    a row, ``sink`` (broadcast against ``s`` with a last axis of 1), in
+    the DENOMINATOR alone: ``exp(s - m) / (exp(sink - m) + sum exp(s -
+    m))``, ``m = max(sink, max s)`` in float32.  The sink has no value:
+    a row's weights sum to less than one, and a row that admits no key
+    (``mask`` all false) weighs nothing."""
+    sink = sink.astype(jnp.float32)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sink)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m))
 
 
 #: the lanes of a row of a pool, and of a vector register
@@ -568,7 +601,38 @@ def paired_rows(new: jax.Array, cache) -> jax.Array:
     width = cache_shape(cache)[-1]
     if new.shape[-1] == width:
         return new
+    if width % new.shape[-1]:
+        return pad_lanes(new, width)
     return new.reshape(new.shape[0], -1, width)
+
+
+def pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """``x`` with zeros after its last axis up to ``width``: a key of 192
+    as it lies in a pool's row of 256 lanes (``key_row_width``), and the
+    query that meets it (zeros meet zeros: the score is the head's own).
+    Put together in blocks as wide as the two widths' common divisor on
+    the axis before the last, then reshaped: a ``concatenate`` of a
+    192-wide and a 64-wide piece on the last axis is the unaligned join
+    that aborts the XLA TPU compiler (``ops/rope.py apply_rope_ref``)."""
+    d = x.shape[-1]
+    g = math.gcd(width, d)
+    lead = x.shape[:-1]
+    return jnp.concatenate(
+        [x.reshape(*lead, d // g, g),
+         jnp.zeros((*lead, (width - d) // g, g), x.dtype)],
+        axis=-2).reshape(*lead, width)
+
+
+def key_row_width(head_dim: int) -> int:
+    """Lanes a K row takes in the pool: the head's width, but whole
+    128-lane tiles for a head wider than one tile (192 -> 256, of which 64
+    are zeros).  The paged kernels bring a page in as ``[page, width]``
+    tiles, and one and a half lane tiles is no tiling; the padding costs
+    its bytes in the pool and in every read (docs/kv_cache.md, "A key
+    wider than its value"; ROADMAP, Speed)."""
+    if head_dim > LANES and head_dim % LANES:
+        return -(-head_dim // LANES) * LANES
+    return head_dim
 
 
 def _own_lanes(x: jax.Array, rows: int, pack: int, d: int):
@@ -598,9 +662,13 @@ def pair_queries(q: jax.Array, k_cache, scale=None):
     t, h, d = q.shape
     if width == d:
         return q, scale
+    own_scale = d ** -0.5 if scale is None else scale
+    if width % d:
+        # one head a row, narrower than the row's lane tiles
+        # (``key_row_width``): zeros where the pool's rows hold zeros
+        return pad_lanes(q, width), own_scale
     q6, own = _own_lanes(q, rows, width // d, d)
-    return ((q6 * own).reshape(t, h, width),
-            d ** -0.5 if scale is None else scale)
+    return (q6 * own).reshape(t, h, width), own_scale
 
 
 def own_half(o: jax.Array, k_cache, d: int) -> jax.Array:
@@ -609,7 +677,7 @@ def own_half(o: jax.Array, k_cache, d: int) -> jax.Array:
     values under this head's probabilities: dropped)."""
     rows, _, _, width = cache_shape(k_cache)
     t, h, _ = o.shape
-    if width == d:
+    if width == d or width % d:  # one head a row: the output is its own
         return o
     o6, own = _own_lanes(o, rows, width // d, d)
     return jnp.sum(o6 * own, axis=4).reshape(t, h, d)

@@ -36,6 +36,14 @@ Token-packed layout (the metadata contract, see docs/ragged_batching.md):
   ``v_dim`` lanes, the value; the kernel brings a KV block in once and
   reads both from it, and the output is ``[T, H, v_dim]``.
 - ``num_seqs`` — sequences actually present (rows past it are padding).
+- a V pool may be narrower than the K pool (a key of 192 in a row of 256
+  lanes beside a value of 128): the value's width is the V pool's, and
+  the output is ``[T, H, that]``.
+- ``sinks [H]`` float32 — one learned logit a query head in the
+  softmax's DENOMINATOR, with no value: the online softmax's running
+  maximum and sum start at ``(sinks[h], 1)`` where they otherwise start
+  at ``(-inf, 0)``, and nothing else changes.  It is no key of the cache
+  and costs no page.
 
 Causality is per-token global positions: query ``j`` of sequence ``i``
 sits at ``seq_lens[i] - q_lens[i] + j`` and attends keys at positions
@@ -103,6 +111,7 @@ from vllm_omni_tpu.ops.paged_attention import (
     pair_queries,
     row_tiling_reason,
     shared_tiling_reason,
+    sink_softmax,
 )
 
 _NEG_INF = -1e30
@@ -143,6 +152,7 @@ def ragged_paged_attention_ref(
     v_dim: Optional[int] = None,
     window: Optional[int] = None,
     keep: Optional[jax.Array] = None,  # [T, >= max_ctx] bool
+    sinks: Optional[jax.Array] = None,  # [H] float32
 ):
     """Pure-XLA reference with identical semantics (fp32 softmax).
 
@@ -182,7 +192,7 @@ def ragged_paged_attention_ref(
     else:
         vg = jnp.transpose(
             gather_pages(v_cache, page_tables), (1, 2, 3, 0, 4)
-        ).reshape(s_max, max_ctx, hkv, d)[seq_of]
+        ).reshape(s_max, max_ctx, hkv, -1)[seq_of]
     qg = q.reshape(t, hkv, group, d).astype(jnp.float32)
     s = jnp.einsum("thgd,tlhd->thgl", qg, kg.astype(jnp.float32)) * scale
     k_pos = jnp.arange(max_ctx)
@@ -196,11 +206,15 @@ def ragged_paged_attention_ref(
     if keep is not None:
         mask &= keep[:, :max_ctx]
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.where(mask[:, None, None, :], jnp.exp(s - m), 0.0)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o = jnp.einsum("thgl,tlhd->thgd", p / l_safe, vg.astype(jnp.float32))
+    if sinks is None:
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.where(mask[:, None, None, :], jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        p = p / jnp.where(l == 0.0, 1.0, l)
+    else:
+        p = sink_softmax(s, mask[:, None, None, :],
+                         sinks.reshape(1, hkv, group, 1))
+    o = jnp.einsum("thgl,tlhd->thgd", p, vg.astype(jnp.float32))
     return o.reshape(t, h, vg.shape[-1]).astype(q.dtype)
 
 
@@ -287,7 +301,11 @@ def _ragged_kernel(
     # one a (token, head) as the scores lie) and keep_buf [dma_slots,
     # nsub | 1, token_block * keep_rep, ppb * page] after the KV buffers:
     # a KV block's columns come in with the block, once for every kv
-    # head; sems then [dma_slots, 3]
+    # head; sems then [dma_slots, 3].
+    # With sinks (``has_sinks``): sink_ref [Hkv, tile rows, 1] f32 VMEM
+    # after them, a tile's rows of every kv head's running maximum as
+    # they START (row r of kv head h holds the sink of query head h *
+    # group + r % group)
     *refs,
     page_size: int,
     token_block: int,
@@ -302,10 +320,12 @@ def _ragged_kernel(
     window: Optional[int] = None,
     has_keep: bool = False,
     keep_rep: int = 1,
+    has_sinks: bool = False,
 ):
     refs = list(refs)
     v_hbm = None if shared else refs.pop(0)
     keep_hbm = refs.pop(0) if has_keep else None
+    sink_ref = refs.pop(0) if has_sinks else None
     k_sc_ref, v_sc_ref = (refs.pop(0), refs.pop(0)) if quantized \
         else (None, None)
     o_ref, k_buf = refs.pop(0), refs.pop(0)
@@ -577,10 +597,15 @@ def _ragged_kernel(
             # never count; they keep the discarded alignment-tail rows
             # away from 0 / 0
             l0 = jnp.ones((rows, 1), jnp.float32)
-            carry = (m0, l0)
+            if has_sinks:
+                # a sink: the running maximum starts at the head's
+                # learned logit and the sum at its ``exp(b - b) = 1``,
+                # which now DOES count (no value goes with it)
+                m0 = sink_ref[:, pl.ds(0, rows)]
+            carry = (m0[0], l0) if has_sinks else (m0, l0)
             if hkv > 1:
-                m_scr[:, pl.ds(0, rows)] = jnp.broadcast_to(
-                    m0, (hkv, rows, 1))
+                m_scr[:, pl.ds(0, rows)] = m0 if has_sinks else \
+                    jnp.broadcast_to(m0, (hkv, rows, 1))
                 l_scr[:, pl.ds(0, rows)] = jnp.broadcast_to(
                     l0, (hkv, rows, 1))
                 carry = 0
@@ -699,6 +724,7 @@ def _ragged_attention(
     q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
     num_seqs, scale, token_block, use_pallas, q_block, pages_per_block,
     dma_slots, v_dim=None, max_q_len=None, window=None, keep=None,
+    sinks=None,
 ):
     """``max_q_len``: the longest segment the caller can hand in, where
     it knows (the decode entry: 1).  At ``token_block`` or under, no
@@ -706,20 +732,21 @@ def _ragged_attention(
     sub-block and the one-tile path is not traced into the kernel.
     ``window`` (static) and ``keep`` ([T, L] bool, L at least the page
     tables' reach) as ``ragged_paged_attention`` has them; without
-    either the kernel traces as it did before they existed."""
+    either the kernel traces as it did before they existed.  ``sinks``
+    ``[H]`` float32 likewise; the value's width is the V pool's."""
     t, h, d = q.shape
     quantized = isinstance(k_cache, tuple)
     shared = v_cache is None
-    dv = v_dim if shared else d
     k_data, k_scale = k_cache if quantized else (k_cache, None)
     v_data, v_scale = v_cache if quantized else (v_cache, None)
+    dv = v_dim if shared else v_data.shape[-1]
     hkv, _, page_size, _ = k_data.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if not use_pallas:
         return ragged_paged_attention_ref(
             q, k_cache, v_cache, page_tables, cu_q_lens, q_lens,
-            seq_lens, num_seqs, scale, v_dim, window, keep,
+            seq_lens, num_seqs, scale, v_dim, window, keep, sinks,
         )
     if t % token_block or q_block % token_block:
         raise ValueError(
@@ -783,6 +810,16 @@ def _ragged_attention(
         keep_scratch = [pltpu.VMEM(
             (dma_slots, nsub if wide else 1, token_block * keep_rep, kvb),
             jnp.float32)]
+    if sinks is not None:
+        # a tile's rows of every kv head, token-major as the scores lie:
+        # row r of kv head n starts at the sink of query head n * group
+        # + r % group.  One block, the same for every grid cell
+        operands.append(jnp.tile(
+            sinks.astype(jnp.float32).reshape(hkv, 1, group),
+            (1, tile_rows // group, 1)).reshape(hkv, tile_rows, 1))
+        in_specs.append(pl.BlockSpec((hkv, tile_rows, 1),
+                                     lambda j, *_: (0, 0, 0),
+                                     memory_space=pltpu.VMEM))
     if quantized:
         # per (kv head, SUB-BLOCK), the sub-block's sequence's scales in
         # page-table order (a q block may hold several sequences):
@@ -804,8 +841,10 @@ def _ragged_attention(
         in_specs=in_specs,
         out_specs=q_spec(dv),
         scratch_shapes=[
-            pltpu.VMEM((dma_slots, hkv, ppb * page_size, d), k_data.dtype)
-        ] * (1 if shared else 2) + keep_scratch + [
+            pltpu.VMEM((dma_slots, hkv, ppb * page_size, width),
+                       k_data.dtype)
+            for width in ((d,) if shared else (d, dv))
+        ] + keep_scratch + [
             pltpu.SemaphoreType.DMA((dma_slots, 3 if keep_scratch else 2)),
             pltpu.VMEM((hkv, tile_rows, dv), jnp.float32),
         ] + [pltpu.VMEM((hkv, tile_rows, 1), jnp.float32)] * (
@@ -835,6 +874,7 @@ def _ragged_attention(
             **({} if window is None else {"window": window}),
             **({"has_keep": True, "keep_rep": keep_rep}
                if keep_scratch else {}),
+            **({} if sinks is None else {"has_sinks": True}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qx.shape[:-1] + (dv,), q.dtype),
@@ -866,6 +906,7 @@ def ragged_paged_attention(
     v_dim: Optional[int] = None,
     window: Optional[int] = None,
     keep: Optional[jax.Array] = None,
+    sinks: Optional[jax.Array] = None,
 ):
     """Mixed prefill+decode paged attention over a token-packed batch.
 
@@ -886,8 +927,9 @@ def ragged_paged_attention(
     bool (``L`` at least ``max_pages * page_size``): packed row ``t``
     admits key ``s`` iff ``keep[t, s]`` (a selection; it holds causality
     itself: no key above the token is kept); its columns come into VMEM
-    with each KV block.  Neither changes what the kernel is without
-    them."""
+    with each KV block.  ``sinks`` ``[H]`` float32: a learned logit a
+    query head in the softmax's denominator (module docstring).  None of
+    them changes what the kernel is without them."""
     quantized = cache_is_quantized(k_cache)
     k_data = cache_data(k_cache)
     # heads of 64 lie two to a row of the pool: the kernel runs the row,
@@ -908,7 +950,7 @@ def ragged_paged_attention(
             kv_itemsize=k_data.dtype.itemsize,
             q_itemsize=q.dtype.itemsize,
             quantized=quantized, kv_pools=1 if v_cache is None else 2,
-            kv_heads=k_data.shape[0])
+            kv_heads=k_data.shape[0], **_tile_hints(window, sinks))
         q_block, pages_per_block, dma_slots = (
             a if x is None else x
             for x, a in zip((q_block, pages_per_block, dma_slots), auto))
@@ -917,7 +959,14 @@ def ragged_paged_attention(
         q, k_cache, v_cache, page_tables, cu_q_lens, q_lens, seq_lens,
         num_seqs, scale, token_block, use_pallas, q_block,
         pages_per_block, dma_slots, v_dim, window=window, keep=keep,
+        sinks=sinks,
     ), k_data, head_dim)
+
+
+def _tile_hints(window, sinks) -> dict:
+    """What ``auto_ragged_blocks`` is told of a call beyond its shapes:
+    nothing for a call without sinks, whose tile is the one it had."""
+    return {} if sinks is None else {"window": window, "sinks": True}
 
 
 #: decode rows a grid cell of the decode entry holds.  A cell is only the
@@ -935,6 +984,7 @@ DECODE_ROWS_A_CELL = 16
 def _paged_attention(
     q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
     v_dim, q_block, pages_per_block, dma_slots, window=None, keep=None,
+    sinks=None,
 ):
     b, h, d = q.shape
     if scale is None:
@@ -942,7 +992,7 @@ def _paged_attention(
     if not use_pallas:
         return paged_attention_ref(
             q, k_cache, v_cache, block_tables, context_lens, scale, v_dim,
-            window, keep)
+            window, keep, sinks)
     hkv = cache_shape(k_cache)[0]
     group = h // hkv
     # a row's query heads of one kv head are a tile's rows: whole
@@ -950,13 +1000,16 @@ def _paged_attention(
     group_p = -(-group // 8) * 8
     qx = jnp.pad(q.reshape(b, hkv, group, d),
                  ((0, 0), (0, 0), (0, group_p - group), (0, 0)))
+    if sinks is not None:  # the padding heads' rows are dropped below
+        sinks = jnp.pad(sinks.reshape(hkv, group),
+                        ((0, 0), (0, group_p - group))).reshape(-1)
     lens = context_lens.astype(jnp.int32)
     out = _ragged_attention(
         qx.reshape(b, hkv * group_p, d), k_cache, v_cache, block_tables,
         jnp.arange(b + 1, dtype=jnp.int32), (lens > 0).astype(jnp.int32),
         lens, jnp.asarray(b, jnp.int32), scale, 1, True, q_block,
         pages_per_block, dma_slots, v_dim, max_q_len=1, window=window,
-        keep=keep)
+        keep=keep, sinks=sinks)
     return out.reshape(b, hkv, group_p, -1)[:, :, :group].reshape(b, h, -1)
 
 
@@ -971,6 +1024,7 @@ def paged_attention(
     v_dim: Optional[int] = None,
     window: Optional[int] = None,
     keep: Optional[jax.Array] = None,  # [B, L] bool
+    sinks: Optional[jax.Array] = None,  # [H] float32
 ):
     """Single-token-per-sequence paged decode attention: the decode entry
     of the ragged kernel.  The batch is laid out, inside the jit, as
@@ -985,7 +1039,7 @@ def paged_attention(
     tests rely on it; unsupported shapes then fail loudly at compile).
     ``v_cache=None`` is the shared layout: the value is the first
     ``v_dim`` lanes of the key row, read from the key's tile, and the
-    output is ``[B, H, v_dim]``.  ``window`` and ``keep`` as
+    output is ``[B, H, v_dim]``.  ``window``, ``keep`` and ``sinks`` as
     ``ragged_paged_attention`` has them (the row's token sits at
     ``context_lens - 1``)."""
     k_data = cache_data(k_cache)
@@ -1004,11 +1058,13 @@ def paged_attention(
         head_dim=q.shape[-1], page_size=k_data.shape[2],
         group=-(-group // 8) * 8, kv_itemsize=k_data.dtype.itemsize,
         q_itemsize=q.dtype.itemsize, quantized=quantized,
-        kv_pools=1 if v_cache is None else 2, kv_heads=hkv)
+        kv_pools=1 if v_cache is None else 2, kv_heads=hkv,
+        **_tile_hints(window, sinks))
     rows = min(q.shape[0], DECODE_ROWS_A_CELL)
     if quantized:  # a scale row a (kv head, decode row) in SMEM
         rows = min(rows, max(1, RAGGED_SCALE_ROWS // hkv))
     return own_half(_paged_attention(
         q, k_cache, v_cache, block_tables, context_lens, scale, use_pallas,
-        v_dim, rows, pages_per_block, dma_slots, window=window, keep=keep),
+        v_dim, rows, pages_per_block, dma_slots, window=window, keep=keep,
+        sinks=sinks),
         k_data, head_dim)

@@ -278,7 +278,9 @@ class ARModelRunner:
         # the geometry is known here: on a platform that compiles the
         # paged kernels, one they cannot tile is refused now rather than
         # served through the O(T * max_ctx) gather reference
-        reason = row_tiling_reason(layout.width, page_size)
+        reason = row_tiling_reason(layout.width, page_size) \
+            or row_tiling_reason(layout.value_width or layout.width,
+                                 page_size)
         if self._latent and not reason:
             reason = shared_tiling_reason(None, cfg.kv_lora_rank)
         if self._window and not reason:
@@ -378,21 +380,23 @@ class ARModelRunner:
         pages = init_kv_cache(
             cfg.num_kv_layers, num_pages, page_size, layout.heads,
             layout.width, dtype, quantized=self._kv_quant,
-            shared=self._latent,
+            shared=self._latent, v_head_dim=layout.v_width or None,
         )
         if self._window or cfg.index_topk:
             # pools by the layer's kind, in layer order: a sliding layer's
             # over the window kind's pages in the kind's own layout (a
-            # latent row at its own width, or (K, V) rows a head); a full
-            # layer with an indexer holds, on the same pages as its rows,
-            # the index keys' pool as a third entry
+            # latent row at its own width, or (K, V) rows a head, of the
+            # kind's own count of kv heads); a full layer with an indexer
+            # holds, on the same pages as its rows, the index keys' pool
+            # as a third entry
             kinds = cfg.kv_layer_types
             wide = cfg.kv_layout_of("sliding_attention")
             for i, kind in enumerate(kinds):
                 if kind == "sliding_attention":
                     pages[i] = init_kv_cache(
                         1, num_window_pages, page_size, wide.heads,
-                        wide.width, dtype, shared=wide.kind == "latent")[0]
+                        wide.width, dtype, shared=wide.kind == "latent",
+                        v_head_dim=wide.v_width or None)[0]
                 elif cfg.index_topk:
                     pages[i] += init_kv_cache(
                         1, num_pages, page_size, 1, cfg.index_head_dim,
