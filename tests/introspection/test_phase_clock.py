@@ -71,6 +71,33 @@ def test_self_times_of_nested_phases_sum_to_the_outer_one():
     assert clock.snapshot()["phases"]["by_name"]["omni.loop"]["n"] == 2
 
 
+def test_what_is_called_as_a_phase_opens_runs_on_the_enclosing_phases_time():
+    """``on_enter``: the orchestrator hands the streams' items over as
+    ``omni.readback`` opens; what that costs is the step's own time, not
+    the wait on the device."""
+    t = FakeTime()
+    clock = t.clock()
+    calls = []
+
+    def hand_over():
+        calls.append(len(clock._stack))
+        t.run(0.5)
+
+    clock.on_enter("omni.readback", hand_over)
+    with clock.phase("omni.step", trace=False):
+        t.run(1.0)
+        with clock.phase("omni.dispatch", trace=False):
+            t.run(2.0)
+        with clock.phase("omni.readback", trace=False) as p:
+            t.wait(4.0)
+            p.waited()
+    # called once, before the phase stood on the stack
+    assert calls == [1]
+    assert by_name(clock) == {"omni.step": 1.5, "omni.dispatch": 2.0,
+                              "omni.readback": 4.0}
+    assert clock.wait_ns == 4 * MS
+
+
 def test_a_steps_phases_sum_to_the_step_and_its_wait_is_device_time():
     t = FakeTime()
     clock = t.clock()

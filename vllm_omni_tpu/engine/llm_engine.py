@@ -1675,7 +1675,7 @@ class LLMEngine:
         with self.phases.phase("omni.record"):
             self._record_spans(self._traced_rows(scheds), "retire", t_g0,
                                lambda s: {"batch": len(scheds)})
-        outs = [OmniRequestOutput.from_pipeline(r) for r in finished]
+        outs = step_outputs(live, finished)
         return outs, new_total
 
     def _drain_pipeline(self) -> list[OmniRequestOutput]:
@@ -1898,7 +1898,7 @@ class LLMEngine:
                         self.kv_transfer_sink(req, payload)
                     self.scheduler.update_from_output(
                         SchedulerOutput(), {}, {req.request_id})
-            return [OmniRequestOutput.from_pipeline(r) for r in finished]
+            return step_outputs(scheduled, finished)
 
     def _observe_token_latencies(self, scheduled, finished) -> int:
         """TTFT / ITL / TPOT bookkeeping from the host-visible token
@@ -2005,3 +2005,8 @@ class LLMEngine:
         moves ``_steps_completed`` and not this: the orchestrator's loop
         waits after a turn in which it stood still (OmniStage.poll)."""
         return self.flight.total_steps + self.pipeline_drains
+
+
+# (imported after the file's last traced line, for the same reason: what
+# a step hands over, partial outputs for streaming requests included)
+from vllm_omni_tpu.engine.stream import step_outputs  # noqa: E402

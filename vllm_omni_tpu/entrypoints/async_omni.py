@@ -31,6 +31,15 @@ logger = init_logger(__name__)
 _SENTINEL = object()
 
 
+def _deliver(items: list) -> None:
+    """``items``: a delivery, its item, the next delivery, its item, ...
+    (flat, so that a step's hand-over allocates one list and not a tuple
+    a row)."""
+    it = iter(items)
+    for put in it:
+        put(next(it))
+
+
 class AsyncOmni:
     def __init__(
         self,
@@ -45,10 +54,18 @@ class AsyncOmni:
             1 for s in self._omni.stages if s.config.final_output
         )
         self._intake: queue.Queue = queue.Queue()
-        # request_id -> (event loop, asyncio.Queue)
+        # request_id -> (event loop, the stream's delivery: a callable
+        # for that loop's thread, ``_admit``)
         self._streams: dict[str, tuple[asyncio.AbstractEventLoop,
-                                       asyncio.Queue]] = {}
+                                       Any]] = {}
         self._finals_seen: dict[str, int] = {}
+        # what the engine thread has for the streams and has not handed
+        # over yet: event loop -> [delivery, item, delivery, item, ...], one
+        # hand-over a loop (``_flush``), however many rows of a step stream
+        self._outbox: dict[asyncio.AbstractEventLoop, list] = {}
+        # ids whose reader has gone (``abort``), for the engine thread to
+        # take out of the stages
+        self._aborted: queue.SimpleQueue = queue.SimpleQueue()
         self._req_counter = itertools.count()
         self._running = True
         # pause gate (reference: pause_generation/resume_generation,
@@ -169,15 +186,81 @@ class AsyncOmni:
         sampling_params: Optional[dict] = None,
         request_id: Optional[str] = None,
         deadline_s: Optional[float] = None,
+        stream: bool = False,
     ) -> AsyncIterator[OmniRequestOutput]:
         """Submit one request; yields one OmniRequestOutput per final stage
         (reference: AsyncOmni.generate, async_omni.py:235).
         ``deadline_s`` bounds the request end-to-end; expiry surfaces as
-        a ``deadline_exceeded`` error output (HTTP 504 at the server)."""
+        a ``deadline_exceeded`` error output (HTTP 504 at the server).
+        ``stream``: also yield, before the text stage's final output, a
+        partial output (``finished`` false) for every step that added
+        tokens to the reply: it holds the step's token ids alone and, as
+        ``text``, what they add to the reply; the final output is the
+        whole reply as without it (docs/serving.md, "Streaming")."""
+        request_id = self._request_id(request_id)
+        out_q: asyncio.Queue = asyncio.Queue()
+        await self._admit(request_id, prompt, sampling_params, deadline_s,
+                          stream, out_q.put_nowait)
+        try:
+            while True:
+                item = await out_q.get()
+                if item is _SENTINEL:
+                    return
+                if isinstance(item, Exception):
+                    raise item
+                yield item
+        finally:
+            self._forget(request_id)
+
+    async def generate_to(
+        self,
+        sink,
+        prompt: Union[str, list[int], dict],
+        sampling_params: Optional[dict] = None,
+        request_id: Optional[str] = None,
+        deadline_s: Optional[float] = None,
+        stream: bool = False,
+    ) -> str:
+        """``generate`` for a caller that takes the outputs as calls and
+        not as an iteration: ``sink.feed(item)`` for every output (and
+        for an exception that ends the request), then ``sink.close()``,
+        each on the calling event loop's thread and straight from the
+        engine thread's hand-over, with no queue and no task between
+        them: at a token an event and hundreds of streams that is most
+        of what an event costs the loop.  Returns the request's id once
+        it is admitted; what ``generate`` would raise before that, it
+        raises."""
+        request_id = self._request_id(request_id)
+
+        def put(item) -> None:
+            if item is _SENTINEL:
+                self._forget(request_id)
+                sink.close()
+            else:
+                sink.feed(item)
+
+        await self._admit(request_id, prompt, sampling_params, deadline_s,
+                          stream, put)
+        return request_id
+
+    def _forget(self, request_id: str) -> None:
+        self._streams.pop(request_id, None)
+        self._finals_seen.pop(request_id, None)
+
+    def _request_id(self, request_id: Optional[str]) -> str:
         if request_id is None:
-            request_id = f"async-{next(self._req_counter)}"
-        elif request_id in self._streams:
+            return f"async-{next(self._req_counter)}"
+        if request_id in self._streams:
             raise ValueError(f"request_id {request_id!r} already in flight")
+        return request_id
+
+    async def _admit(self, request_id: str, prompt, sampling_params,
+                     deadline_s: Optional[float], stream: bool,
+                     put) -> None:
+        """Build the request, pass the pause gate, register ``put`` as
+        the stream's delivery (called on this event loop's thread with
+        every output, with an exception, and last with the sentinel) and
+        hand the request to the engine thread."""
         # pause gate: block intake until resume_generation (reference:
         # "New generation/encoding requests are blocked until resume").
         # The gate check and the stream registration below share
@@ -194,6 +277,7 @@ class AsyncOmni:
             req = StageRequest(request_id=request_id,
                                prompt_token_ids=list(prompt),
                                sampling_params=sp)
+        req.stream = bool(stream)
         # trace context + deadline BEFORE enqueue: the engine thread may
         # drain the intake the instant the put lands.  A caller-supplied
         # trace_id (the server's traceparent / x-omni-trace-id join)
@@ -206,7 +290,6 @@ class AsyncOmni:
             request_id,
             req.deadline_s if req.deadline_s is not None else deadline_s)
         loop = asyncio.get_running_loop()
-        out_q: asyncio.Queue = asyncio.Queue()
         while True:
             with self._pause_lock:
                 if self._resume_event.is_set():
@@ -218,7 +301,7 @@ class AsyncOmni:
                         raise ValueError(
                             f"request_id {request_id!r} already in "
                             "flight")
-                    self._streams[request_id] = (loop, out_q)
+                    self._streams[request_id] = (loop, put)
                     self._finals_seen[request_id] = 0
                     # enqueue INSIDE the lock: a put after release could
                     # slip past a concurrent pause's intake-empty check
@@ -234,34 +317,123 @@ class AsyncOmni:
                     "paused")
             await asyncio.sleep(0.01)
         self._omni.metrics.record_arrival(request_id)
-        try:
-            while True:
-                item = await out_q.get()
-                if item is _SENTINEL:
-                    return
-                if isinstance(item, Exception):
-                    raise item
-                yield item
-        finally:
-            self._streams.pop(request_id, None)
-            self._finals_seen.pop(request_id, None)
 
     def abort(self, request_id: str) -> None:
-        """Best-effort abort: drop the stream; in-flight stage work for the
-        request completes and is discarded."""
+        """Drop the stream, and have the engine thread take the request
+        out of every stage on its next turn: its pages are freed and no
+        output of it follows (a stage that is mid-way through a batch
+        that holds it, as a diffusion stage is, finishes the batch; what
+        it delivers then finds no stream and is discarded)."""
         entry = self._streams.pop(request_id, None)
         if entry is not None:
-            loop, q = entry
-            loop.call_soon_threadsafe(q.put_nowait, _SENTINEL)
+            loop, put = entry
+            loop.call_soon_threadsafe(put, _SENTINEL)
+            self._aborted.put(request_id)
         self._omni.trace_finish(request_id)
 
     # --------------------------------------------------------- engine loop
-    def _emit(self, request_id: str, item) -> None:
+    def _emit(self, request_id: str, item,
+              outbox: Optional[dict] = None) -> None:
         entry = self._streams.get(request_id)
         if entry is None:
             return
-        loop, q = entry
-        loop.call_soon_threadsafe(q.put_nowait, item)
+        loop, put = entry
+        if outbox is None:
+            outbox = self._outbox
+        items = outbox.get(loop)
+        if items is None:
+            items = outbox[loop] = []
+        items.append(put)
+        items.append(item)
+
+    def _flush(self) -> None:
+        """Hand over what ``_emit`` gathered."""
+        if self._outbox:
+            outbox, self._outbox = self._outbox, {}
+            self._hand_over(outbox)
+
+    @staticmethod
+    def _hand_over(outbox: dict) -> None:
+        """One wake-up of each event loop, whose thread then makes the
+        streams' deliveries in order."""
+        for loop, items in outbox.items():
+            try:
+                loop.call_soon_threadsafe(_deliver, items)
+            except RuntimeError:
+                # the loop was closed with items still on their way to it
+                # (its requests were aborted, or their caller is gone):
+                # nobody is left to read them
+                pass
+
+    def _emit_outputs(self, stage, outs: list) -> None:
+        """A poll's outputs to their streams, in the order the stage
+        gave them (a request's partials stand before its final), and the
+        finals on to the stages that consume them.  A partial output (a
+        streaming request's new tokens, ``finished`` false) goes to its
+        stream and nowhere else: it is no final, is not forwarded and
+        ends nothing.  A batch with no partial in it is handed over at
+        once (``_flush``), as every batch was before requests streamed;
+        one that holds partials, so a step's worth of events for the
+        writer, waits for the moment the engine thread next waits for the
+        device (``_engine_loop``), or for the return of the next poll
+        that had no such moment, but for a request's FIRST partial, which
+        leaves at once: a user waits for the first token, it is the first
+        item its stream is given, and a step brings one or two of them
+        where it brings hundreds of later ones."""
+        omni = self._omni
+        final_type = (stage.config.final_output_type
+                      if stage.config.final_output else None)
+        finals = []
+        partials = False
+        firsts: dict = {}
+        for o in outs:
+            rid = o.request_id
+            if not o.finished:
+                partials = True
+                if final_type is not None:
+                    o.final_output_type = final_type
+                    # the first one carries the first token's instant
+                    # (engine/stream.py), the others nothing
+                    self._emit(rid, o, firsts if o.metrics else None)
+            elif o.is_error:
+                # errored outputs terminate their streams and are not
+                # forwarded downstream
+                omni.metrics.record_finish(rid)
+                omni.trace_finish(rid)
+                self._emit(rid, o)
+                self._emit(rid, _SENTINEL)
+            else:
+                finals.append(o)
+                if final_type is None:
+                    continue
+                o.final_output_type = final_type
+                self._emit(rid, o)
+                if rid not in self._finals_seen:
+                    continue  # aborted: its stream and its count are gone
+                seen = self._finals_seen[rid] = self._finals_seen[rid] + 1
+                if seen >= self._n_finals:
+                    # E2E spans through the LAST final output
+                    omni.metrics.record_finish(rid)
+                    omni.trace_finish(rid)
+                    self._emit(rid, _SENTINEL)
+        if finals:
+            try:
+                omni._forward(stage, finals)
+            except Exception as e:
+                # scope the failure to the requests in this batch
+                logger.exception("forward from stage %d failed",
+                                 stage.stage_id)
+                for o in finals:
+                    # terminate the stream's trace too: the sync
+                    # generate() sweeps leftover contexts at the end,
+                    # the online loop has no such sweep
+                    omni.trace_finish(o.request_id)
+                    self._emit(o.request_id, e)
+                    self._emit(o.request_id, _SENTINEL)
+        if firsts:
+            self._hand_over(firsts)
+        if not partials:
+            self._flush()
 
     def _engine_loop(self) -> None:
         omni = self._omni
@@ -276,6 +448,16 @@ class AsyncOmni:
         # has no engine here; the loop then keeps a clock of its own.
         self._phases = getattr(getattr(entry_stage, "engine", None),
                                "phases", None) or PhaseClock()
+        # the streams' items leave as an engine starts to wait for the
+        # device: their writer, which shares the interpreter lock with
+        # this thread, then works while this thread sleeps.  Handed over
+        # at ``omni.emit``, a step's events were written while this
+        # thread assembled the next step, and every event cost the
+        # assembly what it cost the writer (PERF.md, PR 56).
+        for stage in omni.stages:
+            clock = getattr(getattr(stage, "engine", None), "phases", None)
+            if clock is not None:
+                clock.on_enter("omni.readback", self._flush)
         while self._running:
             with self._phases.phase("omni.loop"):
                 self._loop_once(entry_stage)
@@ -319,6 +501,13 @@ class AsyncOmni:
                         omni.trace_finish(r.request_id)
                         self._emit(r.request_id, e)
                         self._emit(r.request_id, _SENTINEL)
+                    self._flush()
+            # after the intake: a request is registered and queued before
+            # anyone can abort it, so its abort finds it in a stage
+            while not self._aborted.empty():
+                rid = self._aborted.get_nowait()
+                for stage in omni.stages:
+                    stage.abort(rid)
         # 2. step stages + forward
         progressed = False
         for stage in omni.stages:
@@ -333,49 +522,19 @@ class AsyncOmni:
                     omni.trace_finish(rid)
                     self._emit(rid, e)
                     self._emit(rid, _SENTINEL)
+                self._flush()
                 continue
             # a stage that delivered outputs advanced, and so did one
             # whose poll ran a step that did device work or a diffusion
             # batch, whether or not anything finished (OmniStage.advanced)
             progressed |= bool(outs) or getattr(stage, "advanced", False)
+            # partials that met no wait for the device in this poll (an
+            # engine gone idle, a step with nothing to retire) leave now
+            self._flush()
             if not outs:
                 continue
             with _phase("omni.emit"):
-                # errored outputs terminate their streams and are not
-                # forwarded downstream
-                errs = [o for o in outs if o.is_error]
-                outs = [o for o in outs if not o.is_error]
-                for o in errs:
-                    omni.metrics.record_finish(o.request_id)
-                    omni.trace_finish(o.request_id)
-                    self._emit(o.request_id, o)
-                    self._emit(o.request_id, _SENTINEL)
-                if not outs:
-                    continue
-                if stage.config.final_output:
-                    for o in outs:
-                        o.final_output_type = stage.config.final_output_type
-                        self._emit(o.request_id, o)
-                        seen = self._finals_seen.get(o.request_id, 0) + 1
-                        self._finals_seen[o.request_id] = seen
-                        if seen >= self._n_finals:
-                            # E2E spans through the LAST final output
-                            omni.metrics.record_finish(o.request_id)
-                            omni.trace_finish(o.request_id)
-                            self._emit(o.request_id, _SENTINEL)
-                try:
-                    omni._forward(stage, outs)
-                except Exception as e:
-                    # scope the failure to the requests in this batch
-                    logger.exception("forward from stage %d failed",
-                                     stage.stage_id)
-                    for o in outs:
-                        # terminate the stream's trace too: the sync
-                        # generate() sweeps leftover contexts at the end,
-                        # the online loop has no such sweep
-                        omni.trace_finish(o.request_id)
-                        self._emit(o.request_id, e)
-                        self._emit(o.request_id, _SENTINEL)
+                self._emit_outputs(stage, outs)
         if not progressed and not pending:
             # nothing advanced this turn (no stage ran a step that did
             # device work, none delivered an output, the intake was

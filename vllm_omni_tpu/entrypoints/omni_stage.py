@@ -26,6 +26,7 @@ from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.metrics.stats import StageRequestStats
 from vllm_omni_tpu.outputs import OmniRequestOutput
 from vllm_omni_tpu.sampling_params import SamplingParams
+from vllm_omni_tpu.utils.tokenizer import stream_decoder
 
 logger = init_logger(__name__)
 
@@ -57,6 +58,11 @@ class StageRequest:
     # Receiving stages convert it to their own monotonic expiry and
     # enforce it at admission + every step; <= 0 means already expired.
     deadline_s: Optional[float] = None
+    # the client asked for the reply as it is made: a stage that is the
+    # pipeline's text output then yields, beside its final output, a
+    # partial output a step (``OmniStage.poll``).  Any other stage, and a
+    # stage in a process of its own, takes no notice of it.
+    stream: bool = False
 
 
 def _import_obj(path: str):
@@ -143,6 +149,10 @@ class OmniStage:
         self._submit_ts: dict[str, float] = {}
         self._trace_ctx: dict[str, dict] = {}
         self.request_stats: list[StageRequestStats] = []
+        # request_id -> the incremental decoder of a streaming request's
+        # reply (utils/tokenizer.py stream_decoder): the ids not yet
+        # turned into text, until the request's final output
+        self._stream_decoders: dict[str, Any] = {}
         # spans/metrics from the engine must carry the pipeline position
         if hasattr(self.engine, "stage_id"):
             self.engine.stage_id = self.stage_id
@@ -346,6 +356,9 @@ class OmniStage:
                     injected_kv=injected_kv,
                     # remaining budget -> this process's monotonic clock
                     deadline_ts=expiry_ts(r.deadline_s),
+                    # partial outputs only where their text has a reader:
+                    # the stage whose text is the pipeline's output
+                    stream=r.stream and self._streams_text,
                     **mm_kwargs,
                 )
         else:
@@ -357,10 +370,33 @@ class OmniStage:
                 r._deadline_ts = expiry_ts(r.deadline_s)
             self._pending.extend(reqs)
 
+    @property
+    def _streams_text(self) -> bool:
+        return (self.tokenizer is not None and self.config.final_output
+                and self.config.final_output_type == "text")
+
+    def abort(self, request_id: str) -> None:
+        """Drop a request whose reader has gone (a client that closed its
+        stream, ``AsyncOmni.abort``): the engine frees its pages and no
+        output of it follows.  On the thread that polls the stage."""
+        if self.config.stage_type == "llm":
+            self.engine.abort_request(request_id)
+        else:
+            self._pending = [r for r in self._pending
+                             if r.request_id != request_id]
+        self._stream_decoders.pop(request_id, None)
+        self._submit_ts.pop(request_id, None)
+        self._trace_ctx.pop(request_id, None)
+
     # -------------------------------------------------------------- drive
     def poll(self) -> list[OmniRequestOutput]:
-        """Advance the stage's engine and return newly finished outputs
-        (the in-proc analogue of try_collect, omni_stage.py:572)."""
+        """Advance the stage's engine and return its new outputs: those
+        of requests that finished (the in-proc analogue of try_collect,
+        omni_stage.py:572) and, for a request that streams, a partial
+        output a step whose text is what the step's tokens add to the
+        reply.  The partials' texts joined are a prefix of the final's
+        text (an incomplete trailing character is held back until it is
+        whole), and the final's text is the whole reply as ever."""
         outs: list[OmniRequestOutput] = []
         self.advanced = False
         if self.config.stage_type == "llm":
@@ -375,6 +411,17 @@ class OmniStage:
                        and self.config.final_output_type == "text")
         for o in outs:
             o.stage_id = self.stage_id
+            if not o.finished:
+                # a partial: the new ids' text, and nothing recorded
+                dec = self._stream_decoders.get(o.request_id)
+                if dec is None:
+                    dec = self._stream_decoders[o.request_id] = \
+                        stream_decoder(self.tokenizer)
+                for c in o.outputs:
+                    c.text = dec.feed(c.token_ids)
+                continue
+            if self._stream_decoders:
+                self._stream_decoders.pop(o.request_id, None)
             if decode_text:
                 for c in o.outputs:
                     if c.text is None:

@@ -25,6 +25,10 @@ from typing import Any, Optional
 import numpy as np
 
 from vllm_omni_tpu.entrypoints.async_omni import AsyncOmni
+from vllm_omni_tpu.entrypoints.openai.chat_stream import (
+    ChatStream,
+    FlushQueue,
+)
 from vllm_omni_tpu.introspection.phase_clock import startup
 from vllm_omni_tpu.logger import init_logger
 from vllm_omni_tpu.version import __version__
@@ -43,8 +47,19 @@ class ServerState:
             target=self.loop.run_forever, daemon=True, name="omni-asyncio"
         )
         self._loop_thread.start()
+        # the streamed replies with a delta to write (chat_stream.py):
+        # that loop's thread writes them in one pass
+        self.flushes = FlushQueue()
 
     def shutdown(self):
+        stream = self.omni.metrics.stream
+        if stream.requests_total:
+            first = stream.first_event_ms.snapshot()
+            logger.info(
+                "streams: %d requests, %d events, %d tokens; first token "
+                "to first event p50 %.2f ms, p99 %.2f ms",
+                stream.requests_total, stream.events_total,
+                stream.tokens_total, first["p50"], first["p99"])
         self.omni.shutdown()
         self.loop.call_soon_threadsafe(self.loop.stop)
 
@@ -79,29 +94,23 @@ class ServerState:
 
         return asyncio.run_coroutine_threadsafe(_run(), self.loop).result()
 
-    def stream(self, prompt, sampling_params, request_id=None):
-        """Sync iterator over final outputs (SSE bridging)."""
-        import queue as _queue
-
-        q: _queue.Queue = _queue.Queue()
-        done = object()
+    def stream(self, prompt, sampling_params, request_id, sink) -> None:
+        """Run one request that streams (``AsyncOmni.generate_to``):
+        every output (a partial output a step, then the finals; an
+        exception that ends it) goes to ``sink.feed`` and then
+        ``sink.close()`` is called, both on this loop's thread, which so
+        is the one writer of every stream
+        (entrypoints/openai/chat_stream.py).  Returns at once."""
 
         async def _run():
             try:
-                async for o in self.omni.generate(prompt, sampling_params,
-                                                  request_id):
-                    q.put(o)
-            except Exception as e:  # surfaced as an SSE error event
-                q.put(e)
-            finally:
-                q.put(done)
+                await self.omni.generate_to(sink, prompt, sampling_params,
+                                            request_id, stream=True)
+            except Exception as e:  # never admitted: the sink's refusal
+                sink.feed(e)
+                sink.close()
 
         asyncio.run_coroutine_threadsafe(_run(), self.loop)
-        while True:
-            item = q.get()
-            if item is done:
-                return
-            yield item
 
     def collect_many(self, jobs: list[tuple]) -> list[list]:
         """Run several (prompt, sampling_params, request_id) jobs
@@ -338,11 +347,6 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
         self.send_header("Cache-Control", "no-cache")
         self.send_header("Transfer-Encoding", "chunked")
         self.end_headers()
-
-    def _sse_send(self, obj) -> None:
-        payload = ("data: " + (obj if isinstance(obj, str)
-                               else json.dumps(obj)) + "\n\n").encode()
-        self.wfile.write(f"{len(payload):x}\r\n".encode() + payload + b"\r\n")
 
     def _sse_end(self):
         self.wfile.write(b"0\r\n\r\n")
@@ -586,42 +590,20 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
             if n > 1:
                 return self._error(400, "streaming with n > 1 is not "
                                    "supported")
-            stream_iter = self.state.stream(prompt, sp, rid)
-            # peek the FIRST item before committing to SSE: an error
-            # before any output (shed at admission, expired deadline,
-            # invalid prompt) still gets its REAL HTTP status — a 429
-            # buried inside a 200 SSE stream would hide the back-off
-            # contract from every streaming client
-            first = next(stream_iter, None)
-            if isinstance(first, Exception):
-                return self._error(500, str(first), "internal_error")
-            if first is not None and first.is_error:
-                self._surface_error([first])
-                return
-            self._sse_start()
-            if first is not None:
-                for chunk in self._chat_chunks(first, rid, created):
-                    self._sse_send(chunk)
-            for out in stream_iter:
-                if isinstance(out, Exception):
-                    self._sse_send({"error": {"message": str(out),
-                                              "type": "internal_error",
-                                              "code": 500}})
-                    break
-                if out.is_error:
-                    # mid-stream failure: the status line is long gone,
-                    # so the SSE error event carries the taxonomy
-                    # (type + would-be HTTP code) for clients to act on
-                    code, etype = self._ERROR_KIND_HTTP.get(
-                        out.error_kind, (500, "internal_error"))
-                    self._sse_send({"error": {
-                        "message": out.error_message or "request failed",
-                        "type": etype, "code": code}})
-                    break
-                for chunk in self._chat_chunks(out, rid, created):
-                    self._sse_send(chunk)
-            self._sse_send("[DONE]")
-            self._sse_end()
+            # the reply leaves as it is made: the loop's thread writes an
+            # event a step while this thread sleeps, and this thread
+            # writes the rest (chat_stream.py).  The FIRST item decides
+            # the response: an error before any output (shed at
+            # admission, expired deadline, invalid prompt) still gets its
+            # REAL HTTP status
+            opts = body.get("stream_options")
+            reply = ChatStream(
+                self, rid, created,
+                include_usage=bool(isinstance(opts, dict)
+                                   and opts.get("include_usage")))
+            self.state.stream(prompt, sp, rid, reply)
+            reply.done.wait()
+            reply.finish()
             return
         # n choices fan out as independent requests with distinct seeds
         # (vLLM n>1 semantics; batching stages batch them) — n == 1 is
@@ -724,24 +706,15 @@ class OmniRequestHandler(BaseHTTPRequestHandler):
         return content
 
     def _chat_chunks(self, out, rid: str, created: int):
+        """The events of a final output that is no text (a text output's
+        are ``ChatStream``'s)."""
         base = {
             "id": rid,
             "object": "chat.completion.chunk",
             "created": created,
             "model": self.state.model_name,
         }
-        if out.final_output_type == "text" and out.outputs:
-            choice = {
-                "index": 0,
-                "delta": {"role": "assistant",
-                          "content": out.outputs[0].text},
-                "finish_reason": out.outputs[0].finish_reason,
-            }
-            if out.outputs[0].logprobs is not None:
-                choice["logprobs"] = {"content": self._logprob_content(
-                    out.outputs[0].token_ids, out.outputs[0].logprobs)}
-            yield {**base, "choices": [choice]}
-        elif out.final_output_type == "audio" and "audio" in out.multimodal_output:
+        if out.final_output_type == "audio" and "audio" in out.multimodal_output:
             # stream the waveform in bounded chunks so playback can start
             # before the full clip is serialized (reference: chunked audio
             # deltas, serving_chat.py:539 + chunk adapter)

@@ -326,8 +326,7 @@ def _stage_worker_serve(config: StageConfig, chan,
                 except (ConnectionError, OSError, ValueError):
                     pass
             elif t == "abort":
-                if stage.config.stage_type == "llm":
-                    stage.engine.abort_request(msg["request_id"])
+                stage.abort(msg["request_id"])
             elif t == "profile_start":
                 stage.start_profile(msg["trace_dir"])
             elif t == "profile_stop":
@@ -715,6 +714,9 @@ class ProcStage(OmniStage):
             if r.trace:
                 self._trace_ctx[r.request_id] = r.trace
             self._inflight.add(r.request_id)
+            # a stage in a process of its own keeps one output a request:
+            # partial outputs would each cross the channel (docs/serving.md)
+            r.stream = False
         if self._fatal is None:
             try:
                 self._locked_send({"type": "submit", "requests": reqs})
@@ -727,6 +729,22 @@ class ProcStage(OmniStage):
                 # why the worker was lost, not just that it was.
                 self._fatal = (f"submit failed: "
                                f"{type(e).__name__}: {e}".rstrip(": "))
+
+    def abort(self, request_id: str) -> None:
+        """``OmniStage.abort`` through the channel: the worker's engine
+        frees the request and no output of it follows."""
+        if request_id not in self._inflight:
+            return
+        self._inflight.discard(request_id)
+        self._started_ids.discard(request_id)
+        self._submit_ts.pop(request_id, None)
+        self._trace_ctx.pop(request_id, None)
+        if self._fatal is None:
+            try:
+                self._locked_send({"type": "abort",
+                                   "request_id": request_id})
+            except (ConnectionError, OSError, ValueError):
+                pass  # a dead worker holds nothing to free
 
     # -------------------------------------------------------------- drive
     def poll(self) -> list[OmniRequestOutput]:

@@ -112,9 +112,15 @@ class _Phase:
         self.ns = 0
 
     def __enter__(self) -> "_Phase":
+        clock = self.clock
+        if clock._on_enter:
+            # before the phase's time starts: the caller's is the
+            # enclosing phase's, not this one's
+            fn = clock._on_enter.get(self.name)
+            if fn is not None:
+                fn()
         if self.ann is not None:
             self.ann.__enter__()
-        clock = self.clock
         self.t0 = clock.last_ns = clock.now_ns()
         if not clock._stack:
             clock._begin_turn()
@@ -165,6 +171,9 @@ class PhaseClock:
         #: name -> [exclusive ns, entries], lifetime
         self._totals: dict[str, list] = {}
         self._stack: list[_Phase] = []
+        #: name -> what to call, on this clock's thread, each time that
+        #: phase opens (``on_enter``)
+        self._on_enter: dict[str, Callable[[], None]] = {}
         #: the stamp of the engine thread's last phase entry or exit:
         #: what the lifetime totals are complete up to
         self.last_ns = now_ns()
@@ -209,6 +218,15 @@ class PhaseClock:
         else:
             ann = jax.profiler.StepTraceAnnotation(name, step_num=step_num)
         return _Phase(self, name, ann)
+
+    def on_enter(self, name: str, fn: Callable[[], None]) -> None:
+        """Have ``fn()`` called on this clock's thread each time the
+        phase ``name`` is about to open, on the enclosing phase's time.
+        ``omni.readback`` opens as the engine thread starts to wait for
+        the device: the orchestrator hands the streams' partial outputs
+        over there (``AsyncOmni._flush``), so that their writer works
+        while this thread sleeps and not while it assembles a step."""
+        self._on_enter[name] = fn
 
     def note_compile(self) -> None:
         """A fresh compile ran in this turn: it is no stall."""

@@ -317,6 +317,19 @@ METRIC_SPECS: dict[str, tuple[str, str, tuple[str, ...]]] = {
         "histogram",
         "HTTP accept to engine arrival, per request the front end stamped",
         ("stage",)),
+    # ---- the streamed replies of the serving layer (docs/serving.md,
+    # "Streaming"): process-wide, written by the streams' one writer
+    "stream_requests_total": (
+        "counter", "Chat requests answered as a stream of events", ()),
+    "stream_events_total": (
+        "counter", "Content events written to streams", ()),
+    "stream_tokens_total": (
+        "counter",
+        "Tokens those events carried (over events: tokens an event)", ()),
+    "stream_first_event_ms": (
+        "histogram",
+        "Engine's first token to the write of the first content event",
+        ()),
     "phase_saturation_ratio": (
         "gauge",
         "Fraction of the capacity ceiling used per phase (prefill/"
@@ -636,6 +649,13 @@ def render_exposition(summary: dict, engine_snaps: dict,
         exp.sample("transfer_count_total", labels, e.get("transfers", 0))
         exp.sample("transfer_bytes_total", labels, e.get("bytes", 0))
         exp.sample("transfer_ms_total", labels, e.get("ms", 0.0))
+    stream = summary.get("stream")
+    if stream:
+        for key in ("requests_total", "events_total", "tokens_total"):
+            exp.sample(f"stream_{key}", {}, stream.get(key, 0))
+        if stream.get("first_event_ms", {}).get("count"):
+            exp.histogram("stream_first_event_ms", {},
+                          stream["first_event_ms"])
     for sid, snap in sorted(engine_snaps.items()):
         if not snap:
             continue

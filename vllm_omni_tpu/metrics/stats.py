@@ -599,6 +599,34 @@ def nearest_rank_pct(xs: list, p: float) -> float:
     return xs[min(len(xs) - 1, idx)]
 
 
+class StreamStats:
+    """What the serving layer streamed (``/v1/chat/completions`` with
+    ``"stream": true``): requests, content events and the tokens they
+    carried (tokens over events is what an event held: 1 while the
+    writer keeps up with the steps, above 1 where deltas that waited
+    went out as one), and from the engine's first-token instant to the
+    write of the first content event, the part of a client's time to
+    first token that is the hand-over's own.  Plain counts with no lock:
+    the event loop's thread writes the streams' events and so nearly
+    all of these; what a handler's thread writes at a reply's end (a
+    text final behind an audio one) can lose an increment to it, and
+    nothing reads them to a count's precision.  Read by ``/metrics``."""
+
+    def __init__(self):
+        self.requests_total = 0
+        self.events_total = 0
+        self.tokens_total = 0
+        self.first_event_ms = Histogram()
+
+    def snapshot(self) -> dict:
+        return {
+            "requests_total": self.requests_total,
+            "events_total": self.events_total,
+            "tokens_total": self.tokens_total,
+            "first_event_ms": self.first_event_ms.snapshot(),
+        }
+
+
 class OrchestratorAggregator:
     """``stats_path`` is a path *prefix*: per-stage request records stream
     to ``{prefix}.stage{N}.stats.jsonl`` and E2E records to
@@ -618,6 +646,7 @@ class OrchestratorAggregator:
         self._recent_e2e_ms: deque = deque(maxlen=window)
         self.num_finished = 0
         self._stats_path = stats_path
+        self.stream = StreamStats()
 
     def _append_jsonl(self, suffix: str, record: dict) -> None:
         with open(f"{self._stats_path}.{suffix}.stats.jsonl", "a") as f:
@@ -699,4 +728,5 @@ class OrchestratorAggregator:
                 "p90_ms": round(pct(0.90), 2),
                 "p99_ms": round(pct(0.99), 2),
             },
+            "stream": self.stream.snapshot(),
         }

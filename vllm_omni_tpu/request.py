@@ -91,6 +91,11 @@ class Request:
     # clock).  None = no deadline.  Enforced at scheduler admission and
     # on every engine step.
     deadline_ts: Optional[float] = None
+    # the request asked to be streamed: every step that appends tokens to
+    # it hands them over as a partial output (engine/stream.py), where a
+    # request that did not ask yields its one final output and nothing
+    # before it
+    stream: bool = False
 
     # ----- mutable engine state -----
     status: RequestStatus = RequestStatus.WAITING
@@ -113,6 +118,11 @@ class Request:
     # (spec-decode multi-accept steps skip entries — the verify path
     # has no per-token sampling distribution to report)
     output_logprobs: list = field(default_factory=list)
+    # of the two lists above, the entries a partial output has already
+    # handed over (engine/stream.py); a preemption keeps both lists, so
+    # neither count ever moves back
+    num_streamed: int = 0
+    num_streamed_logprobs: int = 0
     num_computed_tokens: int = 0
     kv_transfer: KVTransferState = KVTransferState.NONE
     # block-id snapshot taken at transfer trigger, truncated to seq len

@@ -161,6 +161,14 @@ class StageSupervisor:
                 return
             self._stage.submit(reqs)
 
+    def abort(self, request_id: str) -> None:
+        """``OmniStage.abort``: a request nobody reads any more is not
+        redelivered either."""
+        with self._lock:
+            self._tracked.pop(request_id, None)
+            self._redelivered.discard(request_id)
+            self._stage.abort(request_id)
+
     def poll(self) -> list[OmniRequestOutput]:
         outs = self._stage.poll()
         with self._lock:
